@@ -1,11 +1,11 @@
 # Dev loops (reference parity: top-level Makefile + per-service Makefile.ci).
 
 PY ?= python
-TEST_ENV = PYTHONPATH= JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
+TEST_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 IMAGE ?= seldon-core-tpu/platform:latest
 
-.PHONY: lint test test-fast bench dryrun protos native install-bundle image release clean profile-smoke distill-smoke replica-smoke chaos-smoke kvtier-smoke
+.PHONY: lint test test-fast bench chip-smoke dryrun protos native install-bundle image release clean profile-smoke distill-smoke replica-smoke chaos-smoke kvtier-smoke
 
 lint:  ## invariant linter (trace-safety / commit-point / registry-drift / phase-registry / ladder)
 	$(PY) -m seldon_core_tpu.tools.lint
@@ -31,8 +31,11 @@ distill-smoke:  ## tiny feature-draft distillation through the CLI (the pytest s
 test-fast: lint  ## skip the slow model/parallel tests
 	$(PY) -m pytest tests/ -q -x --ignore=tests/test_models_heavy.py --ignore=tests/test_parallel.py
 
-bench:  ## one-line JSON benchmark on the attached accelerator
+bench:  ## one-line JSON benchmark on the attached accelerator (fails without one)
 	$(PY) bench.py
+
+chip-smoke:  ## both serving tiers over real HTTP on the attached chip, each checked against an independent forward (fails without one; --chips 4 for the cross-chip paths)
+	$(PY) chip_smoke.py
 
 dryrun:  ## compile-check the multichip path on 8 virtual devices
 	$(TEST_ENV) $(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')"
@@ -54,5 +57,5 @@ release:  ## VERSION=x.y.z make release — bump + tag (push tags to publish via
 	$(PY) -m seldon_core_tpu.tools.release $(VERSION) --tag
 
 clean:
-	rm -rf .pytest_cache deploy/rendered seldon_core_tpu/native/_fastcodec.so*
+	rm -rf .pytest_cache .jax_cache deploy/rendered seldon_core_tpu/native/_fastcodec.so*
 	find . -name __pycache__ -type d -exec rm -rf {} +

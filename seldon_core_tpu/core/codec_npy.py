@@ -41,7 +41,7 @@ def array_from_npy(raw: bytes) -> np.ndarray:
     would otherwise be arbitrary-code-execution on the serving path."""
     try:
         arr = np.load(io.BytesIO(raw), allow_pickle=False)
-    except Exception as e:  # noqa: BLE001 - wire input, map to error taxonomy
+    except Exception as e:  # noqa: BLE001 - wire input, map to the error codes
         raise APIException(
             ErrorCode.ENGINE_INVALID_JSON, f"bad npy payload: {e}"
         ) from e

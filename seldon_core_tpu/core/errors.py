@@ -1,4 +1,4 @@
-"""Error taxonomy.
+"""Error codes.
 
 Parity: reference engine APIException enum
 (engine/src/main/java/io/seldon/engine/exception/APIException.java) and the
@@ -13,14 +13,14 @@ import enum
 
 
 class ErrorCode(enum.Enum):
-    # (code, http_status, message) — engine taxonomy
+    # (code, http_status, message) — engine codes
     ENGINE_INVALID_JSON = (101, 400, "Invalid JSON")
     ENGINE_INVALID_ENDPOINT_URL = (102, 500, "Invalid endpoint URL")
     ENGINE_MICROSERVICE_ERROR = (103, 500, "Microservice error")
     ENGINE_INVALID_ABTEST = (104, 500, "Error happened in AB Test routing")
     ENGINE_INVALID_ROUTING = (105, 500, "Invalid graph routing")
     ENGINE_INVALID_RESPONSE = (106, 500, "Invalid microservice response")
-    # api-frontend taxonomy
+    # api-frontend codes
     APIFE_INVALID_JSON = (201, 400, "Invalid JSON")
     APIFE_INVALID_ENDPOINT_URL = (202, 500, "Invalid endpoint URL")
     APIFE_MICROSERVICE_ERROR = (203, 500, "Microservice error")

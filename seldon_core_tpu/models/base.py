@@ -193,9 +193,9 @@ class ModelRuntime:
         #   mantissa); models that take ids cast to int32 themselves.
         # - outputs come back float32: bf16 is a compute/storage dtype, not
         #   a wire dtype — clients can't decode it (npy has no bf16) and
-        #   bf16 device->host readback pays a slow conversion fallback
-        #   (measured ~5x the f32 readback on this harness). The cast runs
-        #   inside jit, fused into the last op; integer outputs pass through.
+        #   bf16 device->host readback pays a conversion on the host. The
+        #   cast runs inside jit, fused into the last op; integer outputs
+        #   pass through.
         low_precision = jnp.dtype(self.dtype).itemsize < 4
         self._low_precision = low_precision
 
@@ -453,7 +453,7 @@ class JaxModelUnit(Unit):
     async def transform_input(self, msg: SeldonMessage) -> SeldonMessage:
         if msg.data is None:
             # opaque binData/strData reached a tensor model: reject with the
-            # reference error taxonomy instead of np.asarray(None) blowing
+            # reference error codes instead of np.asarray(None) blowing
             # up into a bare 500 (npy binData was already decoded at the
             # serving ingress; anything left here is undecodable)
             from seldon_core_tpu.core.errors import APIException, ErrorCode

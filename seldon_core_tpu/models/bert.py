@@ -25,7 +25,7 @@ from seldon_core_tpu.models.zoo import ModelSpec, register_model
 
 
 # Host-side numpy init (see models/resnet.py): one device_put instead of one
-# compiled rng program per tensor — matters on tunneled/remote devices.
+# compiled rng program per tensor.
 import numpy as np
 
 
@@ -59,29 +59,22 @@ def _layer_init(rng, hidden, ffn):
     }
 
 
-def _pallas_eligible(k: jax.Array) -> bool:
-    """The hand-tiled kernel needs the KV axis to divide its 128 block (no
-    in-kernel masking) and a jax build with pltpu types (interpret mode
-    included). Shapes are static at trace time so this resolves during
-    compilation, never per request."""
-    from seldon_core_tpu.ops.pallas_flash import pallas_available
-
-    return pallas_available() and k.shape[2] % 128 == 0
-
-
 def _default_attention(q, k, v):
-    """seq-length-adaptive: dense einsum below FLASH_MIN_SEQ; above it, the
-    Pallas flash kernel (ops/pallas_flash — VMEM-streamed online softmax on
-    the MXU) on the TPU backend, pure-JAX blockwise elsewhere. The length
-    policy constant lives in ops/attention so seq-parallel local bodies
-    can't drift from it."""
+    """seq-length-adaptive: dense einsum below FLASH_MIN_SEQ; above it
+    blockwise, and from PALLAS_MIN_SEQ the COMPILED Pallas flash kernel
+    (ops/pallas_flash — VMEM-streamed online softmax on the MXU) on every
+    backend but the CPU, which has no Mosaic and keeps blockwise. The
+    kernel needs the KV axis to divide its 128 block (no in-kernel
+    masking); shapes are static at trace time so this resolves during
+    compilation, never per request. The length policy constants live in
+    ops/attention so seq-parallel local bodies can't drift from them."""
     from seldon_core_tpu.ops.attention import FLASH_MIN_SEQ, PALLAS_MIN_SEQ
 
     if q.shape[2] >= FLASH_MIN_SEQ:
         if (
             q.shape[2] >= PALLAS_MIN_SEQ
-            and jax.default_backend() == "tpu"
-            and _pallas_eligible(k)
+            and jax.default_backend() != "cpu"
+            and k.shape[2] % 128 == 0
         ):
             from seldon_core_tpu.ops.pallas_flash import flash_attention
 
@@ -95,25 +88,21 @@ def _default_attention(q, k, v):
 
 
 def _pallas_attention(q, k, v):
-    """Forced-Pallas impl (attn_kernel=pallas): interpret mode off-TPU, so a
-    CI deployment on the CPU mesh exercises the same kernel code path the
-    chip compiles with Mosaic. Falls back to blockwise only when the kernel
-    is not viable (pltpu-less build, or a static KV length its block sizes
-    can't tile), mirroring _default_attention. Short sequences (<= one KV
+    """Forced-Pallas impl (attn_kernel=pallas): compiled with Mosaic on an
+    accelerator; on the CPU backend it asks for interpret mode, so a CI
+    deployment on the CPU mesh exercises the same kernel code path. Falls
+    back to blockwise only for a static KV length the kernel's block sizes
+    can't tile, mirroring _default_attention. Short sequences (<= one KV
     block) tile trivially — _kv_block caps the block at the sequence."""
-    from seldon_core_tpu.ops.pallas_flash import (
-        DEFAULT_BLOCK_K,
-        flash_attention,
-        pallas_available,
-    )
+    from seldon_core_tpu.ops.pallas_flash import DEFAULT_BLOCK_K, flash_attention
 
     sk = k.shape[2]
     # sublane alignment (16 for bf16) + either the 128-lane tiling or a
     # single-KV-block fit (the kernel caps its block at the sequence)
-    if pallas_available() and sk % 16 == 0 and (
-        sk % 128 == 0 or sk <= DEFAULT_BLOCK_K
-    ):
-        return flash_attention(q, k, v)
+    if sk % 16 == 0 and (sk % 128 == 0 or sk <= DEFAULT_BLOCK_K):
+        return flash_attention(
+            q, k, v, interpret=jax.default_backend() == "cpu"
+        )
     from seldon_core_tpu.ops.attention import blockwise_attention
 
     return blockwise_attention(q, k, v, block_size=512)
@@ -383,9 +372,9 @@ def build_bert_base(
     params = init_bert(seed, num_classes=num_classes, max_len=max_len)
     return ModelSpec(
         # attn_kernel is a deployment knob (auto|pallas|blockwise): auto
-        # routes long sequences to the Pallas flash kernel on the TPU
-        # backend and blockwise elsewhere; pallas forces the kernel
-        # (interpret mode off-TPU) so CI serving configs reach it
+        # routes long sequences to the compiled Pallas flash kernel off
+        # the CPU backend and blockwise on it; pallas forces the kernel
+        # (interpret mode on the CPU backend) so CI serving configs reach it
         _apply_for_kernel(attn_kernel),
         params,
         (seq,),  # serving seq length (buckets handle the batch axis)

@@ -3,7 +3,8 @@
 The C++ side (fastcodec.cpp) parses/serializes the ndarray number matrix —
 the dominant CPU cost of a REST prediction once the graph runs in-process.
 This module compiles it on first use (cached .so next to the source,
-rebuilt when the .cpp is newer) and exposes:
+rebuilt whenever the .cpp's content hash differs from the one the .so was
+built from) and exposes:
 
     find_ndarray_span(raw: bytes) -> (start, end) | None
     parse_ndarray(raw: bytes) -> np.ndarray (float32, 1D or 2D) | None
@@ -19,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -31,15 +33,33 @@ log = logging.getLogger(__name__)
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "fastcodec.cpp")
 _SO = os.path.join(_HERE, "_fastcodec.so")
+# sha256 of the .cpp the .so was built from. File times say nothing in a
+# copied or checked-out tree (a stale .so can be newer than an edited
+# source), so freshness is decided by content.
+_SO_STAMP = _SO + ".src-sha256"
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_failed = False
 
 
+def _src_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stamped_digest() -> str | None:
+    try:
+        with open(_SO_STAMP) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
 def _build() -> str | None:
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+        digest = _src_digest()
+        if os.path.exists(_SO) and _stamped_digest() == digest:
             return _SO
         # pid-unique temp name: concurrent processes (platform + microservice
         # on one host) may both build; a shared .tmp path would interleave
@@ -54,7 +74,15 @@ def _build() -> str | None:
             if res.returncode != 0:
                 log.warning("fastcodec build failed: %s", res.stderr.decode()[:500])
                 return None
+            # stamp AFTER the .so is in place, via its own atomic rename: a
+            # crash between the two leaves a stamp-less (= rebuilt) .so,
+            # never a stamp that vouches for an older binary
+            with contextlib.suppress(OSError):
+                os.unlink(_SO_STAMP)
             os.replace(tmp, _SO)
+            with open(tmp, "w") as f:
+                f.write(digest + "\n")
+            os.replace(tmp, _SO_STAMP)
         finally:
             # failed/timed-out builds must not strand pid-unique temp files
             # in the package dir (they are never overwritten by later pids)

@@ -54,12 +54,11 @@ def combine_stats(m1, l1, o1, m2, l2, o2):
 # default (models/bert.py) and the seq-parallel local bodies (ops/ulysses.py)
 FLASH_MIN_SEQ = 1024
 
-# seq length from which the TPU backend routes to the hand-tiled Pallas
-# kernel (ops/pallas_flash) instead of this pure-JAX blockwise path.
-# Measured on the v5e harness (bf16, 12 heads, d=64, RTT-differenced):
-# parity at 2k/4k, 2.2x at 8k, 2.4x at 16k — blockwise's per-step
-# [.., sq, block] score tensors go HBM-bound while the kernel keeps the
-# working set in VMEM. 4096 is the conservative crossover (>= parity).
+# seq length from which an accelerator backend routes to the hand-tiled
+# Pallas kernel (ops/pallas_flash) instead of this pure-JAX blockwise path:
+# blockwise's per-step [.., sq, block] score tensors go through HBM while
+# the kernel keeps its working set in VMEM. The crossover on the current
+# chip is not measured (bench.py pallas_long_seq is the leg).
 PALLAS_MIN_SEQ = 4096
 
 
@@ -111,9 +110,7 @@ def blockwise_attention(
         jnp.zeros((b, h, sq, d), q.dtype),
     )
     if vary_axes:
-        from seldon_core_tpu.parallel.compat import pvary
-
-        init = tuple(pvary(x, vary_axes) for x in init)
+        init = tuple(lax.pcast(x, vary_axes, to="varying") for x in init)
     (m, l, o), _ = lax.scan(body, init, jnp.arange(n_blocks))
     return o / l[..., None]
 
@@ -121,20 +118,17 @@ def blockwise_attention(
 def causal_attention_auto(q, k, v) -> jax.Array:
     """Backend-adaptive CAUSAL attention — the one policy shared by every
     causal consumer (decoder prefill today): dense below FLASH_MIN_SEQ,
-    blockwise above it, the Pallas causal kernel on the TPU backend from
-    PALLAS_MIN_SEQ when the KV axis tiles. Mirrors models/bert.py's
-    non-causal `_default_attention` thresholds so the two policies cannot
-    drift apart in spirit."""
+    blockwise above it, the COMPILED Pallas causal kernel from
+    PALLAS_MIN_SEQ when the KV axis tiles — on every backend but the CPU,
+    which has no Mosaic. Mirrors models/bert.py's non-causal
+    `_default_attention` thresholds so the two policies cannot drift
+    apart in spirit."""
     s = q.shape[2]
     if s >= FLASH_MIN_SEQ:
-        if s >= PALLAS_MIN_SEQ and jax.default_backend() == "tpu" and k.shape[2] % 128 == 0:
-            from seldon_core_tpu.ops.pallas_flash import (
-                flash_attention,
-                pallas_available,
-            )
+        if s >= PALLAS_MIN_SEQ and jax.default_backend() != "cpu" and k.shape[2] % 128 == 0:
+            from seldon_core_tpu.ops.pallas_flash import flash_attention
 
-            if pallas_available():
-                return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True)
         return blockwise_attention(q, k, v, block_size=512, causal=True)
     return naive_attention(q, k, v, causal=True)
 

@@ -14,18 +14,16 @@ accumulation — the MXU's native mode and ~2x the f32 rate; softmax stats
 stay f32 for exactness. Stats are stored lane-replicated ([block_q, 128])
 and re-collapsed with a max over lanes, the standard Mosaic-friendly layout.
 
-On non-TPU backends (tests run on the 8-device CPU mesh) the same kernel
-runs in interpreter mode, so numerics are covered everywhere while the
-compiled path exercises Mosaic only on real hardware.
+The kernel compiles under Mosaic by default. Interpreter mode exists for
+the CPU backend only, and only where a caller passes ``interpret=True``
+(the tests, and the serving policies' explicit CPU branch): a kernel call
+that reaches an accelerator is compiled or it raises.
 
-Measured on the v5e harness (bench.py pallas_long_seq, bf16, 12 heads,
-d=64, RTT-differenced): crossover vs the pure-JAX blockwise path is
-~seq 4k (parity there, within run noise); at 8k the kernel wins ~2x and at
-16k ~2.4x — blockwise's per-step score tensors go HBM-bound while the
-kernel keeps its working set in VMEM. Block defaults from a 9-point sweep
-at seq 8k: block_q 512 / block_k 2048 (5.34 ms vs 6.25 at the previous
-1024 KV block). models/bert.py routes long sequences here on the TPU
-backend (PALLAS_MIN_SEQ policy).
+Speed vs the pure-JAX blockwise path on the current chip: not measured
+(bench.py pallas_long_seq is the leg). The block defaults (block_q 512 /
+block_k 2048) and the PALLAS_MIN_SEQ routing threshold in ops/attention.py
+are carried-over design choices until that leg has run. models/bert.py
+routes long sequences here off the CPU backend.
 """
 
 from __future__ import annotations
@@ -35,28 +33,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend only exists on TPU-enabled builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # noqa: BLE001
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128  # stats are stored lane-replicated at this width
-# default KV block (flash_attention block_k): 9-point sweep at seq 8k on
-# the v5e harness picked 2048; the bert routing policy reuses it as the
-# single-block-fit bound for non-128-multiple sequences
+# default KV block (flash_attention block_k); the bert routing policy
+# reuses it as the single-block-fit bound for non-128-multiple sequences
 DEFAULT_BLOCK_K = 2048
-
-
-def pallas_available() -> bool:
-    """Whether this jax build can run the kernel at all (compiled OR
-    interpret — both need the pltpu memory-space types for scratch). The
-    routing policy in models/bert.py checks this before selecting the
-    kernel so a pltpu-less build serves blockwise instead of raising."""
-    return _HAS_PLTPU
 
 
 def _flash_kernel(
@@ -152,27 +135,32 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = DEFAULT_BLOCK_K,
     causal: bool = False,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """q,k,v: [batch, heads, seq, head_dim] -> same shape.
 
     ``causal=True`` applies the autoregressive mask with whole KV blocks
     above the diagonal skipped (no dots issued) — decoder-style scoring;
-    seq-parallel causal long-context goes through ring_attention. Chip
-    measurements at seq 8192: 2.5x over the pure-JAX causal blockwise
-    path; ~1.1x under the non-causal kernel (the skip saves MXU work but
-    the block pipeline still prefetches skipped KV blocks — a triangular
-    grid would reclaim that DMA, a known upgrade)."""
+    seq-parallel causal long-context goes through ring_attention. The
+    skip saves MXU work but the block pipeline still prefetches skipped
+    KV blocks — a triangular grid would reclaim that DMA, a known upgrade.
+
+    ``interpret=True`` runs the Pallas interpreter and is accepted on the
+    CPU backend only; the default compiles the kernel (and so fails on
+    the CPU backend, which has no Mosaic)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu" or not _HAS_PLTPU
+    if interpret and jax.default_backend() != "cpu":
+        raise ValueError(
+            "flash_attention(interpret=True) is for the CPU backend; on "
+            f"'{jax.default_backend()}' the kernel must run compiled"
+        )
 
     # pad head_dim to the 128 lane width: zero-padded K dims add 0 to every
     # dot product and padded V dims are sliced off, so numerics are
-    # unchanged (scale uses the original d). Measured: Mosaic at d=64
-    # un-padded is ~2x SLOWER than padded-128 (lane under-utilization), so
-    # the pad applies on the compiled path; interpret mode skips it.
+    # unchanged (scale uses the original d). It keeps Mosaic's lanes full
+    # at d=64, so the pad applies on the compiled path; interpret mode
+    # skips it.
     orig_d = d
     if not interpret and d % _LANES:
         pad_d = _LANES - d % _LANES
@@ -194,12 +182,6 @@ def flash_attention(
     n_q = qf.shape[1] // block_q
     n_kv = sk // block_k
 
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "pallas TPU support unavailable in this jax build — use "
-            "ops.attention.blockwise_attention (the serving policy in "
-            "models/bert.py only routes here when the kernel is viable)"
-        )
     kernel = functools.partial(
         _flash_kernel,
         n_kv=n_kv,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from seldon_core_tpu.parallel.compat import pvary, shard_map as _shard_map
-
 from seldon_core_tpu.ops.attention import NEG_INF, _block_stats, combine_stats
 
 
@@ -57,9 +55,9 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool, seq_per_dev:
     # mixed data+seq mesh that includes the batch axis) to match the loop
     # outputs
     init = (
-        pvary(jnp.full((b, h, s), NEG_INF, q.dtype), vary_axes),
-        pvary(jnp.zeros((b, h, s), q.dtype), vary_axes),
-        pvary(jnp.zeros((b, h, s, d), q.dtype), vary_axes),
+        lax.pcast(jnp.full((b, h, s), NEG_INF, q.dtype), vary_axes, to="varying"),
+        lax.pcast(jnp.zeros((b, h, s), q.dtype), vary_axes, to="varying"),
+        lax.pcast(jnp.zeros((b, h, s, d), q.dtype), vary_axes, to="varying"),
         k,
         v,
     )
@@ -90,7 +88,7 @@ def ring_attention(
     batch_entry = data_axis if data_axis in mesh.shape else None
     spec = P(batch_entry, None, seq_axis, None)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(
             _ring_attention_local,
             axis_name=seq_axis,

@@ -39,8 +39,6 @@ from seldon_core_tpu.ops.attention import (
     naive_attention,
 )
 
-from seldon_core_tpu.parallel.compat import shard_map as _shard_map
-
 
 def _local_attention(q, k, v, causal: bool, vary_axes: tuple):
     # same dense/blockwise policy boundary as the single-device default
@@ -90,7 +88,7 @@ def ulysses_attention(
         raise ValueError(f"ulysses: seq {seq} not divisible by seq-axis size {n}")
     batch_entry = data_axis if data_axis in mesh.shape else None
     spec = P(batch_entry, None, seq_axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(
             _ulysses_local,
             axis_name=seq_axis,

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from seldon_core_tpu.parallel.compat import pvary
-
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
 
@@ -67,8 +65,10 @@ def _pipeline_local(
         )
         return (recv_next, outs), None
 
-    init_recv = pvary(jnp.zeros(mb_shape, x_micro.dtype), (axis_name,))
-    init_outs = pvary(jnp.zeros_like(x_micro), (axis_name,))
+    init_recv = lax.pcast(
+        jnp.zeros(mb_shape, x_micro.dtype), (axis_name,), to="varying"
+    )
+    init_outs = lax.pcast(jnp.zeros_like(x_micro), (axis_name,), to="varying")
     (_, outs), _ = lax.scan(tick, (init_recv, init_outs), jnp.arange(ticks))
     # broadcast the last stage's buffer to every device so the caller gets a
     # replicated result (psum of zeros elsewhere)
@@ -100,9 +100,7 @@ def pipeline_apply(
                 f"'{pipe_axis}' axis has {n_stages} devices — they must match"
             )
     param_specs = jax.tree.map(lambda _: P(pipe_axis), stage_params)
-    from seldon_core_tpu.parallel.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local, stage_fn=stage_fn, axis_name=pipe_axis),
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -271,6 +271,9 @@ def main() -> None:
     if args.no_grpc:
         args.grpc_port = None
     logging.basicConfig(level=logging.INFO)
+    from seldon_core_tpu.utils.compile_cache import enable_compile_cache
+
+    log.info("compile cache: %s", enable_compile_cache())
     asyncio.run(_amain(args))
 
 

@@ -423,6 +423,9 @@ def main() -> None:
     )
     args = p.parse_args()
     logging.basicConfig(level=logging.INFO)
+    from seldon_core_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     asyncio.run(_amain(args))
 
 

@@ -280,6 +280,9 @@ def main(argv=None):
     parser.add_argument("--no-batch", action="store_true")
     parser.add_argument("--warmup", action="store_true")
     args = parser.parse_args(argv)
+    from seldon_core_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     asyncio.run(_amain(args))
 
 

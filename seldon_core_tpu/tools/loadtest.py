@@ -414,14 +414,16 @@ def run_load_multiprocess(
     per = users // workers
     extras = users % workers
 
-    # workers must import this package regardless of the caller's cwd;
-    # PREPEND the repo root — wiping PYTHONPATH would drop sitecustomize
-    # entries the interpreter environment depends on
+    # workers must import this package regardless of the caller's cwd
     repo_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
+    # a worker is a pure HTTP client and never imports jax
+    # (tests/test_tools.py); the pin keeps it off the chip its parent may
+    # hold even if a future import changes that
+    env["JAX_PLATFORMS"] = "cpu"
 
     with tempfile.TemporaryDirectory(prefix="loadtest_") as tmp:
         procs: list[tuple[subprocess.Popen, str]] = []

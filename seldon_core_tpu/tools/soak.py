@@ -976,6 +976,10 @@ def main(argv=None) -> None:
                 + str(max(8, args.tp, args.replicas))
             ).strip()
 
+    from seldon_core_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     def _run(fault_spec=None) -> dict:
         return asyncio.run(
             soak(

@@ -17,9 +17,8 @@ import sys
 
 import jax
 
-# platform + collectives must be pinned before any backend init; the env
-# vars alone are not enough on hosts that pre-register a TPU plugin
-jax.config.update("jax_platforms", "cpu")
+# the launcher pins JAX_PLATFORMS=cpu; the CPU collectives backend has no
+# env var and must be chosen before any backend init
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 from seldon_core_tpu.parallel.mesh import initialize_distributed  # noqa: E402

@@ -659,8 +659,10 @@ def test_default_attention_selects_pallas_on_tpu_backend():
     orig_kernel = pallas_flash.flash_attention
 
     def fake_kernel(q, k, v, **kw):
+        # off the CPU backend the policy must ask for the COMPILED kernel
+        assert not kw.get("interpret", False)
         calls.append(k.shape)
-        return orig_kernel(q, k, v, interpret=True, **kw)
+        return q
 
     orig_backend = jax_mod.default_backend
     pallas_flash.flash_attention = fake_kernel
@@ -684,28 +686,24 @@ def test_default_attention_selects_pallas_on_tpu_backend():
         pallas_flash.flash_attention = orig_kernel
 
 
-def test_pallas_unavailable_falls_back_to_blockwise():
-    """Code-review r5: a jax build without pltpu types must serve blockwise
-    on every policy path (auto on TPU backend, forced attn_kernel=pallas) —
-    never raise from the predict path."""
+def test_flash_attention_never_interprets_off_cpu():
+    """"On the chip but not compiled" is impossible: interpret mode is
+    refused on any backend but the CPU, and the default (compiled) call
+    fails loudly on the CPU backend instead of quietly interpreting."""
     import jax as jax_mod
 
-    from seldon_core_tpu.models import bert as bert_mod
-    from seldon_core_tpu.ops import pallas_flash
+    from seldon_core_tpu.ops.pallas_flash import flash_attention
 
-    orig_flag = pallas_flash._HAS_PLTPU
+    q = jnp.ones((1, 1, 128, 32), jnp.float32)
     orig_backend = jax_mod.default_backend
-    pallas_flash._HAS_PLTPU = False
     jax_mod.default_backend = lambda: "tpu"
     try:
-        q = jnp.ones((1, 1, 4096, 32), jnp.float32)
-        out = bert_mod._default_attention(q, q, q)  # auto policy
-        assert out.shape == q.shape
-        out = bert_mod._pallas_attention(q, q, q)  # forced knob
-        assert out.shape == q.shape
+        with pytest.raises(ValueError, match="interpret"):
+            flash_attention(q, q, q, interpret=True)
     finally:
-        pallas_flash._HAS_PLTPU = orig_flag
         jax_mod.default_backend = orig_backend
+    with pytest.raises(Exception, match="(?i)interpret|cpu"):
+        jax_mod.block_until_ready(flash_attention(q, q, q))
 
 
 def test_ulysses_heads_mesh_mismatch_rejected_at_build():

@@ -329,3 +329,36 @@ def test_http_parse_head_hardening():
     big = b"Bearer " + b"a" * 5000
     req = b"GET /p HTTP/1.1\r\nAuthorization: " + big + b"\r\n\r\n"
     assert native.parse_http_head(req) is None
+
+
+def test_stale_library_is_rebuilt_by_content_not_by_file_time(tmp_path, monkeypatch):
+    """A copied or checked-out tree guarantees nothing about file times: a
+    stale .so NEWER than an edited .cpp must not be picked up. Freshness is
+    the .cpp's content hash, stamped beside the .so."""
+    import os
+    import shutil
+
+    src = shutil.copy(native._SRC, tmp_path / "fastcodec.cpp")
+    so = tmp_path / "_fastcodec.so"
+    stamp = tmp_path / "_fastcodec.so.src-sha256"
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_SO_STAMP", str(stamp))
+
+    so.write_bytes(b"stale build of some older source")  # newer than the .cpp
+    assert os.path.getmtime(so) >= os.path.getmtime(src)
+    assert native._build() == str(so)
+    assert so.read_bytes()[:4] == b"\x7fELF"  # rebuilt, not trusted
+    assert stamp.read_text().strip() == native._src_digest()
+
+    built = so.read_bytes()
+    so.write_bytes(built + b"\0")  # a marker a rebuild would erase
+    assert native._build() == str(so)
+    assert so.read_bytes() == built + b"\0"  # same source: no rebuild
+
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    os.utime(src, (1, 1))  # the edit even LOOKS older than the .so
+    assert native._build() == str(so)
+    assert so.read_bytes()[:4] == b"\x7fELF" and so.read_bytes() != built + b"\0"
+    assert stamp.read_text().strip() == native._src_digest()

@@ -86,7 +86,7 @@ def test_ring_attention_rejects_ragged_seq():
 def test_flash_attention_matches_naive():
     q, k, v = _qkv(s=64, d=16)
     ref = naive_attention(q, k, v)
-    got = flash_attention(q, k, v, block_q=16, block_k=16)
+    got = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
@@ -98,14 +98,14 @@ def test_flash_attention_q_padding():
     k = jnp.asarray(rng.standard_normal((b, h, 64, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, 64, d)), jnp.float32)
     ref = naive_attention(q, k, v)
-    got = flash_attention(q, k, v, block_q=16, block_k=16)
+    got = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 def test_flash_attention_rejects_ragged_kv():
     q, k, v = _qkv(s=40)
     with pytest.raises(ValueError):
-        flash_attention(q, k, v, block_q=16, block_k=16)
+        flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
 
 
 def test_flash_attention_causal_matches_naive():
@@ -115,10 +115,10 @@ def test_flash_attention_causal_matches_naive():
     spans several blocks)."""
     q, k, v = _qkv(s=64, d=16)
     ref = naive_attention(q, k, v, causal=True)
-    got = flash_attention(q, k, v, block_q=16, block_k=16, causal=True)
+    got = flash_attention(q, k, v, block_q=16, block_k=16, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
     # mismatched block sizes exercise the straddling-block mask
-    got2 = flash_attention(q, k, v, block_q=32, block_k=16, causal=True)
+    got2 = flash_attention(q, k, v, block_q=32, block_k=16, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
@@ -131,5 +131,5 @@ def test_flash_attention_causal_with_q_padding():
     k = jnp.asarray(rng.standard_normal((b, h, 64, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, 64, d)), jnp.float32)
     ref = naive_attention(q, k, v, causal=True)
-    got = flash_attention(q, k, v, block_q=16, block_k=16, causal=True)
+    got = flash_attention(q, k, v, block_q=16, block_k=16, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)

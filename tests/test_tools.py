@@ -717,3 +717,30 @@ def test_soak_trace_summary_attributes_slowest_traces():
         # slowest-first ordering
         totals = [e["total_ms"] for e in summary]
         assert totals == sorted(totals, reverse=True)
+
+
+def test_loadtest_worker_never_touches_jax():
+    """One process per chip: loadtest workers are children of a process
+    that may hold the chip, so the module they run must not import jax (and
+    the launcher pins JAX_PLATFORMS=cpu in their environment besides)."""
+    import subprocess
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [
+            sys.executable, "-c",
+            "import sys, seldon_core_tpu.tools.loadtest; "
+            "print(sorted(m for m in sys.modules if m.split('.')[0] in ('jax', 'jaxlib')))",
+        ],
+        capture_output=True, text=True, timeout=60, env=env,
+    )
+    assert out.returncode == 0, out.stderr[-1000:]
+    assert out.stdout.strip() == "[]"
+    import inspect
+
+    from seldon_core_tpu.tools import loadtest
+
+    assert 'env["JAX_PLATFORMS"] = "cpu"' in inspect.getsource(
+        loadtest.run_load_multiprocess
+    )

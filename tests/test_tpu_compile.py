@@ -1,0 +1,157 @@
+"""Compile-only guards for the chip: the kernels and step programs of the
+main path, at the widths chip_smoke.py serves, handed to the TPU compiler
+for a DESCRIBED v5e:2x2 topology (no chip attached, nothing runs).
+
+What interpret mode and the CPU mesh cannot see — a Mosaic tiling refusal,
+a program that does not partition, a donation the TPU compiler rejects —
+fails here at no chip time. A compile that passes is not a chip run.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    # a compile for a described device is written to the persistent cache but
+    # cannot be read back without a chip (JAX warns and recompiles): keep it off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _smoke_decoder():
+    """The generative geometry chip_smoke.py serves: decoder params plus the
+    scheduler sizes of the example CR it boots."""
+    from seldon_core_tpu.models.decoder import init_decoder
+
+    g = chip_smoke.REAL_GEOMETRY
+    gp = g["gen_params"]
+    with open(
+        os.path.join(chip_smoke.DEPLOYMENTS, "tiny_gpt_tensor_parallel.json")
+    ) as f:
+        tpu = json.load(f)["spec"]["predictors"][0]["tpu"]
+    params = init_decoder(
+        0, vocab=g["gen_vocab"], hidden=gp["hidden"], layers=gp["layers"],
+        ffn=gp["ffn"], max_len=gp["max_len"],
+    )
+    ps = tpu["decode_kv_page_size"]
+    return params, {
+        "n_slots": tpu["decode_slots"],
+        "n_pages": tpu["decode_kv_pages"],
+        "page_size": ps,
+        "pages_per_slot": -(-(gp["seq"] + gp["max_new_tokens"]) // ps),
+    }
+
+
+def _step_args(params, geo, kv_dtype, param_sh, pool_sh_for, small_sh):
+    """ShapeDtypeStructs for decode_scheduler._fused_step — shapes only:
+    there is no device to hold an array."""
+    from seldon_core_tpu.models.decoder import paged_kv_init
+
+    n = geo["n_slots"]
+    p = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), params, param_sh
+    )
+    pool = tuple(
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=pool_sh_for(s))
+        for s in jax.eval_shape(
+            lambda: paged_kv_init(
+                params, geo["n_pages"], geo["page_size"], kv_dtype=kv_dtype
+            )
+        )
+    )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=small_sh)
+
+    i32, f32 = jnp.int32, jnp.float32
+    return p, pool, (
+        arr((n, geo["pages_per_slot"]), i32),  # block tables
+        arr((n,), i32), arr((n,), i32),  # tokens, positions
+        arr((n,), f32), arr((n,), i32),  # temperatures, top-k
+        arr((), i32), arr((), i32),  # seed, tick
+    )
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_compiles_at_bert_base_long_context(topo, causal):
+    from seldon_core_tpu.ops.attention import PALLAS_MIN_SEQ
+    from seldon_core_tpu.ops.pallas_flash import flash_attention
+
+    q = jax.ShapeDtypeStruct(
+        (1, 12, PALLAS_MIN_SEQ, 64), jnp.bfloat16,
+        sharding=SingleDeviceSharding(topo.devices[0]),
+    )
+    compiled = (
+        jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=causal))
+        .lower(q, q, q)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not the interpreter
+
+
+def test_fused_paged_decode_step_compiles_on_the_int8_pool(topo):
+    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params, geo = _smoke_decoder()
+    p, pool, rest = _step_args(
+        params, geo, "int8", jax.tree.map(lambda _: one, params), lambda s: one, one
+    )
+    compiled = jax.jit(_fused_step, donate_argnums=(1,)).lower(p, pool, *rest).compile()
+    # the donated pool comes back in place: the step allocates no second pool
+    # (>=: the TPU layout pads the pool's minor dims to its tile)
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in pool)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_tp4_sharded_decode_step_compiles(topo):
+    from seldon_core_tpu.parallel.tp import decoder_param_shardings, kv_sharding
+    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("tp",))
+    rep = NamedSharding(mesh, P())
+    params, geo = _smoke_decoder()
+    p, pool, rest = _step_args(
+        params, geo, "", decoder_param_shardings(params, mesh, "tp"),
+        lambda s: kv_sharding(mesh, "tp", s), rep,
+    )
+    pool_sh = tuple(x.sharding for x in pool)
+    compiled = (
+        jax.jit(_fused_step, donate_argnums=(1,), out_shardings=(rep, pool_sh))
+        .lower(p, pool, *rest)
+        .compile()
+    )
+    # Megatron/Pope: each residual branch ends in an all-reduce
+    assert "all-reduce" in compiled.as_text()
+    # the page pool is head-sharded: a device holds a quarter of it (in the
+    # TPU's (8, 128) float32 tiling, which pads head_dim 64 to 128 lanes)
+    head_dim = pool[0].shape[-1]
+    padded = sum(
+        int(np.prod(s.shape)) // head_dim * 128 * s.dtype.itemsize for s in pool
+    )
+    assert compiled.memory_analysis().alias_size_in_bytes == padded // 4
