@@ -1,0 +1,68 @@
+import collections
+import json
+import os
+
+import pytest
+from conftest import BENCH
+
+from harness import traffic as T
+
+
+def _mix(name):
+    with open(os.path.join(BENCH, "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "traffic")) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_two_seeds_offer_the_same_work(mix):
+    a = T.build_plan(_mix(mix), seed=7, seconds=45)
+    b = T.build_plan(_mix(mix), seed=2**31 + 11, seconds=45)
+    assert T.offered_work(a) == T.offered_work(b)
+
+
+def test_seed_changes_token_ids_and_lane_order_only():
+    mix = _mix("batch-unshared")
+    a = T.build_plan(mix, seed=1, seconds=45)
+    b = T.build_plan(mix, seed=2, seconds=45)
+    assert [[r["max_new"] for r in lane] for lane in a["clients"]] != [
+        [r["max_new"] for r in lane] for lane in b["clients"]
+    ]
+    req = a["clients"][0][0]
+    assert T.token_ids(1, 50257, req) != T.token_ids(2, 50257, req)
+    assert T.token_ids(1, 50257, req) == T.token_ids(1, 50257, req)
+    assert len(T.token_ids(2**31 + 5, 50257, req)) == mix["prompt_len"]
+
+
+def test_lanes_hold_the_table_once_and_prompts_fit():
+    mix = _mix("batch-unshared")
+    assert len(mix["lanes"]) == mix["clients"] == 16
+    cycled = [m for lane in mix["lanes"] for m in lane[mix["cycle_from"]:]]
+    assert collections.Counter(cycled) == collections.Counter(mix["output_table"])
+    assert all(mix["prompt_len"] + m <= 1024 for m in mix["output_table"])
+    sums = [sum(lane[mix["cycle_from"]:]) for lane in mix["lanes"]]
+    assert max(sums) <= 1.6 * min(sums)  # no lane carries far more than another
+
+
+def test_open_loop_count_is_the_rate_times_the_window():
+    mix = _mix("sysprompt-open")
+    for seconds in (10, 45, 51):
+        plan = T.build_plan(mix, seed=3, seconds=seconds)
+        due = [a for a in plan["arrivals"] if a["measured"]]
+        assert len(due) == round(mix["rate_rps"] * seconds)
+        times = [a["due"] for a in due]
+        assert times == sorted(times)
+        assert mix["ramp_s"] <= times[0] and times[-1] < mix["ramp_s"] + seconds
+    fams = collections.Counter(a["prefix"] for a in T.build_plan(mix, seed=3, seconds=400)["arrivals"] if a["measured"])
+    total = sum(fams.values())
+    assert [round(fams[k] / total, 1) for k in range(4)] == mix["prefix_shares"]
+
+
+def test_shared_prefix_is_shared_and_tails_are_not():
+    base = {"prompt_len": 64, "prefix_len": 48, "prefix": 1}
+    a = T.token_ids(9, 512, {**base, "uid": 1})
+    b = T.token_ids(9, 512, {**base, "uid": 2})
+    c = T.token_ids(9, 512, {**base, "prefix": 2, "uid": 1})
+    assert a[:48] == b[:48] and a[48:] != b[48:] and a[:48] != c[:48]
