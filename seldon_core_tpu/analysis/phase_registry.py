@@ -3,11 +3,11 @@
 PR 11 added per-round host-phase attribution (``PHASES`` / ``P_*``)
 beside PR 9's per-family dispatch split (``FAMILIES`` / ``F_*``); both
 registries live in telemetry/flight.py and are consumed by the decode
-scheduler's ``_timed_call(F_X, ...)`` / ``with self._phase(P_X):``
-sites. Two drift modes matter (the registry-drift family's lesson
+scheduler's ``_timed_call(F_X, ...)`` / ``with self._dispatch(F_X):`` /
+``with self._phase(P_X):`` sites. Two drift modes matter (the registry-drift family's lesson
 applied to the new registry):
 
-- PH001: a ``_timed_call`` / ``_phase`` site whose first argument is not
+- PH001: a ``_timed_call`` / ``_dispatch`` / ``_phase`` site whose first argument is not
   a registered ``F_*``/``P_*`` constant. A raw index compiles and runs
   fine — it just silently mis-attributes the round (or walks off the
   fixed array), and nothing downstream can tell.
@@ -30,7 +30,7 @@ REGISTRY_SUFFIX = "telemetry/flight.py"
 REGISTRY_TUPLES = ("FAMILIES", "PHASES")
 _CONST_RE = re.compile(r"^[FP]_[A-Z0-9_]+$")
 # call names whose FIRST argument must be a registry constant
-TIMER_FUNCS = ("_timed_call", "_phase")
+TIMER_FUNCS = ("_timed_call", "_phase", "_dispatch")
 
 
 def _call_name(node: ast.Call) -> str:

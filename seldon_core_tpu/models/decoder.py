@@ -580,6 +580,28 @@ def chunk_prefill(
 #   into the attention gather.
 
 
+# Device scopes of the paged path: ``jax.named_scope`` names that every op of
+# the fused paged programs carries in its HLO ``op_name`` (metadata only:
+# the compiled program is the same instruction for instruction), so a
+# profiler trace reads device time by WHAT the op does, not by a shape that
+# changes with the page count. The benchmark's per-layer metrics and
+# docs/observability.md "Reading a device trace" key on these strings.
+SCOPE_EMBED = "embed"  # token + position embedding lookup
+SCOPE_QKV = "qkv"  # ln1, the fused q/k/v projection, head split
+SCOPE_KV_WRITE = "kv_write"  # the scatter of new K/V through the block tables
+SCOPE_POOL_RESTACK = "pool_restack"  # per-layer pool slice a[li] and the closing jnp.stack
+SCOPE_KV_GATHER = "kv_gather"  # page gather into the virtual contiguous cache
+SCOPE_ATTN = "attn"  # scores, mask, softmax, context
+SCOPE_ATTN_OUT = "attn_out"  # output projection + residual
+SCOPE_MLP = "mlp"  # ln2, mlp_in, gelu, mlp_out + residual
+SCOPE_LM_HEAD = "lm_head"  # ln_f + vocabulary projection
+SCOPE_SAMPLE = "sample"  # key derivation, last-position pick, sampling (the fused programs)
+PAGED_SCOPES = (
+    SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_POOL_RESTACK, SCOPE_KV_GATHER,
+    SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_MLP, SCOPE_LM_HEAD, SCOPE_SAMPLE,
+)
+
+
 def paged_kv_init(
     params: dict, n_pages: int, page_size: int, dtype=jnp.float32, kv_dtype: str = ""
 ) -> tuple:
@@ -623,6 +645,7 @@ def _quant_rows(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     return q, scale, zp
 
 
+@jax.named_scope(SCOPE_KV_WRITE)
 def _paged_write(kv: tuple, k, v, bt, positions, counts):
     """Scatter the dispatch's new K/V (k, v: [n, h, m, hd], slot i's entry j
     at positions[i] + j) into the per-layer pool slices through the block
@@ -663,6 +686,7 @@ def _paged_write(kv: tuple, k, v, bt, positions, counts):
     )
 
 
+@jax.named_scope(SCOPE_KV_GATHER)
 def _paged_gather(kv: tuple, bt) -> tuple[jax.Array, jax.Array]:
     """Gather each slot's pages into a virtual contiguous cache
     [n, h, max_pages * page_size, hd] in f32 (the flat path's attention
@@ -692,30 +716,34 @@ def _layer_step_paged(p, x, kv, bt, positions, h, counts=None):
     the pool back through a page gather (so in-dispatch queries see the
     keys earlier queries of the same dispatch just wrote, exactly like the
     flat path's write-then-read). Returns (x_out, new per-layer kv)."""
-    normed = _ln(p["ln1"], x)
-    qkv = normed @ p["qkv"]["w"].astype(x.dtype) + p["qkv"]["b"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = _split_heads(q, h)  # [n, h, m, hd]
-    k = _split_heads(k, h)
-    v = _split_heads(v, h)
+    with jax.named_scope(SCOPE_QKV):
+        normed = _ln(p["ln1"], x)
+        qkv = normed @ p["qkv"]["w"].astype(x.dtype) + p["qkv"]["b"].astype(x.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = _split_heads(q, h)  # [n, h, m, hd]
+        k = _split_heads(k, h)
+        v = _split_heads(v, h)
     kv = _paged_write(kv, k, v, bt, positions, counts)
     cache_k, cache_v = _paged_gather(kv, bt)  # f32 virtual caches
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32), cache_k) * scale
-    m = x.shape[1]
-    q_pos = positions[:, None] + jnp.arange(m)[None, :]  # [n, m]
-    valid = jnp.arange(cache_k.shape[2])[None, None, :] <= q_pos[:, :, None]
-    s = jnp.where(valid[:, None, :, :], s, -1e30)
-    p_attn = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("nhqk,nhkd->nhqd", p_attn, cache_v)
-    ctx = _merge_heads(ctx.astype(x.dtype))
-    x = x + ctx @ p["attn_out"]["w"].astype(x.dtype) + p["attn_out"]["b"].astype(x.dtype)
-    normed2 = _ln(p["ln2"], x)
-    hdn = jax.nn.gelu(
-        normed2 @ p["mlp_in"]["w"].astype(x.dtype) + p["mlp_in"]["b"].astype(x.dtype),
-        approximate=False,
-    )
-    x = x + hdn @ p["mlp_out"]["w"].astype(x.dtype) + p["mlp_out"]["b"].astype(x.dtype)
+    with jax.named_scope(SCOPE_ATTN):
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+        s = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32), cache_k) * scale
+        m = x.shape[1]
+        q_pos = positions[:, None] + jnp.arange(m)[None, :]  # [n, m]
+        valid = jnp.arange(cache_k.shape[2])[None, None, :] <= q_pos[:, :, None]
+        s = jnp.where(valid[:, None, :, :], s, -1e30)
+        p_attn = jax.nn.softmax(s, axis=-1)
+        ctx = jnp.einsum("nhqk,nhkd->nhqd", p_attn, cache_v)
+        ctx = _merge_heads(ctx.astype(x.dtype))
+    with jax.named_scope(SCOPE_ATTN_OUT):
+        x = x + ctx @ p["attn_out"]["w"].astype(x.dtype) + p["attn_out"]["b"].astype(x.dtype)
+    with jax.named_scope(SCOPE_MLP):
+        normed2 = _ln(p["ln2"], x)
+        hdn = jax.nn.gelu(
+            normed2 @ p["mlp_in"]["w"].astype(x.dtype) + p["mlp_in"]["b"].astype(x.dtype),
+            approximate=False,
+        )
+        x = x + hdn @ p["mlp_out"]["w"].astype(x.dtype) + p["mlp_out"]["b"].astype(x.dtype)
     return x, kv
 
 
@@ -732,17 +760,22 @@ def _paged_forward(params, pool, bt, tokens, positions, counts=None):
     heads = _heads(params)
     m = tokens.shape[1]
     max_len = params["pos_emb"].shape[0]
-    x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
-    x = x + jnp.asarray(params["pos_emb"])[pidx]
+    with jax.named_scope(SCOPE_EMBED):
+        x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
+        pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
+        x = x + jnp.asarray(params["pos_emb"])[pidx]
     per_comp: list[list] = [[] for _ in pool]
     for li, lp in enumerate(params["layers"]):
-        layer_kv = tuple(a[li] for a in pool)
+        with jax.named_scope(SCOPE_POOL_RESTACK):
+            layer_kv = tuple(a[li] for a in pool)
         x, layer_kv = _layer_step_paged(lp, x, layer_kv, bt, positions, heads, counts)
         for acc, a in zip(per_comp, layer_kv):
             acc.append(a)
-    logits = _logits(params, x)  # [n, m, vocab]
-    return logits, x, tuple(jnp.stack(acc) for acc in per_comp)
+    with jax.named_scope(SCOPE_LM_HEAD):
+        logits = _logits(params, x)  # [n, m, vocab]
+    with jax.named_scope(SCOPE_POOL_RESTACK):
+        new_pool = tuple(jnp.stack(acc) for acc in per_comp)
+    return logits, x, new_pool
 
 
 def paged_decode_step(params, pool, bt, tokens, positions):

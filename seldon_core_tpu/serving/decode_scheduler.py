@@ -138,8 +138,15 @@ from seldon_core_tpu.core.message import Meta, SeldonMessage
 from seldon_core_tpu.engine.resilience import current_deadline
 from seldon_core_tpu.metrics import NullMetrics
 from seldon_core_tpu import telemetry
+from seldon_core_tpu.telemetry import flight as flight_mod
 from seldon_core_tpu.telemetry import profile as profile_mod
 from seldon_core_tpu.telemetry.flight import (
+    ANN_DISPATCH,
+    ANN_ENQUEUE,
+    ANN_IDLE_WAIT,
+    ANN_PHASE,
+    ANN_READBACK,
+    ANN_ROUND,
     F_CHUNK,
     F_COPY,
     F_DRAFT,
@@ -161,6 +168,7 @@ from seldon_core_tpu.telemetry.flight import (
 )
 from seldon_core_tpu.telemetry.flight import register as flight_register
 from seldon_core_tpu.models.decoder import (
+    SCOPE_SAMPLE,
     decoder_dims,
     draft_propose,
     draft_propose_features,
@@ -208,8 +216,9 @@ def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick):
     ``tick`` is a traced scalar, so the per-step RNG key needs
     no host-side split and the program never recompiles."""
     logits, _hidden, pool = paged_decode_step(params, pool, bt, tokens, positions)
-    key = jax.random.fold_in(jax.random.key(seed), tick)
-    return sample_tokens(logits, temps, topks, key), pool
+    with jax.named_scope(SCOPE_SAMPLE):
+        key = jax.random.fold_in(jax.random.key(seed), tick)
+        return sample_tokens(logits, temps, topks, key), pool
 
 
 def _scatter_prefill_rows(cache_k, cache_v, k_new, v_new, row_for_slot, valid_slot):
@@ -245,11 +254,12 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
     IS admission's prompt compute — a whole wave prefills in one dispatch
     at the top bucket, or spread over rounds when chunking is on."""
     logits, _hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
-    c = ids.shape[1]
-    idx = jnp.clip(counts - 1, 0, c - 1)
-    last = logits[jnp.arange(ids.shape[0]), idx]  # [n, vocab]
-    key = jax.random.fold_in(jax.random.key(seed), tick)
-    return sample_tokens(last, temps, topks, key), pool
+    with jax.named_scope(SCOPE_SAMPLE):
+        c = ids.shape[1]
+        idx = jnp.clip(counts - 1, 0, c - 1)
+        last = logits[jnp.arange(ids.shape[0]), idx]  # [n, vocab]
+        key = jax.random.fold_in(jax.random.key(seed), tick)
+        return sample_tokens(last, temps, topks, key), pool
 
 
 def _fused_draft_admit(params, dcache_k, dcache_v, ids, row_for_slot, valid_slot):
@@ -582,6 +592,119 @@ class _PipelineGate:
         )
 
 
+class _EnqueueSpan:
+    """``with d.enqueue(F_X):`` round the call(s) of the jitted program:
+    the calling thread's ``ANN_ENQUEUE`` annotation; a family other than
+    the dispatch's own (the draft of a speculative round pair) gets its
+    wall booked to its own busy column and carved out of the pair's."""
+
+    __slots__ = ("d", "family", "t0", "ann")
+
+    def __init__(self, d: "_Dispatch", family: int):
+        self.d = d
+        self.family = family
+
+    def __enter__(self):
+        self.ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        d = self.d
+        if self.family != d.family:
+            dt = time.perf_counter_ns() - self.t0
+            d.s._rb_busy[self.family] += dt
+            d.carved += dt
+        self.ann.__exit__(None, None, None)
+        return False
+
+
+class _Dispatch:
+    """One device dispatch as the round loop sees it — THE place a
+    dispatch is timed into the round's flight frame and named for a
+    profiler session; ``_timed_call``, the pipelined step and the
+    speculative round pairs all go through ``with self._dispatch(F_X) as
+    d:`` (one preallocated handle per family; the loop awaits each
+    dispatch, so a family never nests in itself).
+
+    On the loop the handle spans hand-off to readback return
+    (``ANN_DISPATCH``): that wall is the family's ``busy_ns``, and the part
+    after the enqueue->readback mark its ``rdb_ns``. Inside it, on
+    whichever thread does the work: ``d.enqueue()`` round the program
+    call(s), ``await d.readback(fn)`` for the blocking host read, or
+    ``await d.run(fn)`` for a ``_do_*`` closure that makes both and calls
+    ``_mark_enqueued()`` between them."""
+
+    __slots__ = ("s", "family", "t0", "carved", "ann", "exec_ann")
+
+    def __init__(self, sched: "DecodeScheduler", family: int):
+        self.s = sched
+        self.family = family
+        self.carved = 0
+        self.exec_ann = None
+
+    def __enter__(self):
+        s = self.s
+        s._rb_mark_ns = 0
+        s._dispatch_open = self
+        self.carved = 0
+        self.ann = flight_mod.annotate(ANN_DISPATCH[self.family])
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t2 = time.perf_counter_ns()
+        s = self.s
+        mark = s._rb_mark_ns or t2
+        s._rb_busy[self.family] += t2 - self.t0 - self.carved
+        s._rb_rdb[self.family] += t2 - mark
+        self.ann.__exit__(None, None, None)
+        return False
+
+    def enqueue(self, family: int | None = None) -> _EnqueueSpan:
+        return _EnqueueSpan(self, self.family if family is None else family)
+
+    def mark(self) -> None:
+        """The enqueue->readback boundary (``_mark_enqueued``): the
+        family's wall splits here, and inside ``run`` the thread's
+        annotation turns from enqueue to readback."""
+        self.s._rb_mark_ns = time.perf_counter_ns()
+        if self.exec_ann is not None:
+            self.exec_ann.__exit__(None, None, None)
+            self.exec_ann = flight_mod.annotate(ANN_READBACK[self.family])
+
+    async def run(self, fn):
+        """A ``_do_*`` closure through ``_device_call``: program call,
+        ``_mark_enqueued()``, blocking read — annotated on the thread that
+        runs it. No mark = the whole call is enqueue (the copy ladder
+        reads nothing back)."""
+
+        def call():
+            self.exec_ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
+            try:
+                return fn()
+            finally:
+                self.exec_ann.__exit__(None, None, None)
+                self.exec_ann = None
+
+        return await self.s._device_call(call)
+
+    async def readback(self, fn):
+        """The blocking host read of a dispatch the loop enqueued itself
+        (the pipelined rounds): marks, then runs ``fn`` through
+        ``_device_call`` under ``ANN_READBACK``."""
+        self.s._rb_mark_ns = time.perf_counter_ns()
+
+        def call():
+            ann = flight_mod.annotate(ANN_READBACK[self.family])
+            try:
+                return fn()
+            finally:
+                ann.__exit__(None, None, None)
+
+        return await self.s._device_call(call)
+
+
 class _PendingAdmit:
     """One flight-decided admission (shadow round state): the decision's
     operands held UN-installed until ``_apply_pending`` — the reconcile
@@ -724,7 +847,8 @@ class _Seq:
     __slots__ = (
         "prompt", "max_new", "temperature", "top_k", "spec_k", "tree_widths",
         "on_token", "future", "uid",
-        "tokens", "slot", "pos", "t_enqueued", "t_first_token", "t_last_token",
+        "tokens", "slot", "pos", "t_enqueued", "t_admitted", "t_first_token",
+        "t_last_token",
         "deadline", "trace_ctxs", "gen_spans",
         "prefilling", "prefill_pos", "prefix_len", "chunk_cap",
         "cache_prefix", "chunk_idx",
@@ -751,6 +875,7 @@ class _Seq:
         self.slot = -1
         self.pos = 0
         self.t_enqueued = time.perf_counter()
+        self.t_admitted = 0.0  # slot assignment (_install_admit)
         self.t_first_token = 0.0
         self.t_last_token = 0.0
         self.deadline = 0.0  # admission deadline (0 = none)
@@ -1329,6 +1454,9 @@ class DecodeScheduler:
         # pipelined-decode ROADMAP item is designed against. Rides the
         # flight kill switch (disabled timer = shared no-op handles).
         self._phases = PhaseTimer(enabled=self.flight.enabled)
+        self._dispatches = tuple(_Dispatch(self, f) for f in range(len(ANN_DISPATCH)))
+        self._dispatch_open: _Dispatch | None = None
+        self._round_ann = None  # the open ANN_ROUND trace annotation
         # ENGINE_FLIGHT_SYNC_TIMING=on: block on every dispatch so the
         # per-family flight columns are ground-truth device wall
         # (calibration runs — throughput pays the pipeline stall)
@@ -1362,7 +1490,7 @@ class DecodeScheduler:
         # the in-flight dispatch it hid behind (host-only observability
         # state; single-writer: _overlap_window)
         self._in_overlap = False
-        self._round_reset()
+        self._round_reset(open_round=False)
 
     def _commit_kv(self, params, arrs):
         """Commit cache/pool buffers to their serving-steady sharding
@@ -1859,6 +1987,8 @@ class DecodeScheduler:
         seq.tokens.append(tok)
         if len(seq.tokens) == 1:
             seq.t_first_token = now
+            self._rb_prefill += int((now - seq.t_admitted) * 1e9)
+            self._rb_first_tokens += 1
             ttft = now - seq.t_enqueued
             self._metrics.decode_ttft(self._deployment, ttft)
             if self.prefix_enabled:
@@ -2259,9 +2389,11 @@ class DecodeScheduler:
         )
 
     # --------------------------------------------------- round flight frame
-    def _round_reset(self, t_ns: int | None = None) -> None:
+    def _round_reset(self, t_ns: int | None = None, open_round: bool = True) -> None:
         """Reset the per-round flight accumulators (one set of plain int
-        attrs — written on the hot path, read only at _commit_round)."""
+        attrs — written on the hot path, read only at _commit_round) and
+        turn the round's trace annotation over (``_round_mark``;
+        ``open_round=False`` only where no loop runs: construction)."""
         self._rb_busy = [0, 0, 0, 0, 0]  # ns per flight.FAMILIES entry
         self._rb_rdb = [0, 0, 0, 0, 0]  # blocked-readback share of busy
         self._rb_mark_ns = 0
@@ -2279,6 +2411,12 @@ class DecodeScheduler:
         self._rb_probe = False
         self._rb_widths = ()
         self._rb_promotions = 0
+        # the time to first token as the program sees it, split at slot
+        # assignment: queue wait of this round's admissions, admission ->
+        # first token of this round's first emissions (and their count)
+        self._rb_admit_wait = 0
+        self._rb_prefill = 0
+        self._rb_first_tokens = 0
         # stale shadow admissions (a round error between the overlap
         # window and the reconcile): the normal flow drains the list at
         # _apply_pending before the round commits, so anything still here
@@ -2289,12 +2427,29 @@ class DecodeScheduler:
                 self.pool.alloc.retire(p.slot)
             self._pending_admits.clear()
         self._phases.reset()
+        self._round_mark(open_round)
+
+    def _round_mark(self, open_next: bool) -> None:
+        """End the open ANN_ROUND trace annotation and, on the running
+        loop, start the next: one per round from ``_round_reset`` to
+        ``_commit_round``, awaits included. Its ``round`` stat is the index
+        the round's FlightFrame will commit under and ``t_ns`` the round
+        clock's start, so a profiler session joins a trace round to its
+        frame and the recorder's clock to the trace's."""
+        if self._round_ann is not None:
+            self._round_ann.__exit__(None, None, None)
+            self._round_ann = None
+        if open_next:
+            self._round_ann = flight_mod.annotate(
+                ANN_ROUND, round=self.flight.rounds, t_ns=self._rb_t0
+            )
 
     def _phase(self, p: int):
         """The round's host-phase ``with`` handle for a flight P_*
         constant (telemetry/flight.PhaseTimer — innermost-phase
-        attribution, no-op under the flight kill switch). Never hold a
-        phase across a device dispatch: busy time is _timed_call's."""
+        attribution, no-op under the flight kill switch; each handle also
+        writes its ``decode.phase.<name>`` trace annotation). Never hold a
+        phase across a device dispatch: busy time is _dispatch's."""
         return self._phases.phase(p)
 
     def _mark_enqueued(self) -> None:
@@ -2308,16 +2463,19 @@ class DecodeScheduler:
         back). Under ENGINE_FLIGHT_SYNC_TIMING the closures block on the
         dispatch first, making the enqueue column ground-truth device
         wall."""
-        self._rb_mark_ns = time.perf_counter_ns()
+        self._dispatch_open.mark()
+
+    def _dispatch(self, family: int) -> _Dispatch:
+        """The ``with``-handle for one dispatch of a flight F_* family:
+        THE timing-and-naming point of every dispatch (``_Dispatch``)."""
+        return self._dispatches[family]
 
     async def _timed_call(self, family: int, fn):
         """_device_call with the dispatch's wall time attributed to one
         fused program family in the current round's flight frame, split
         enqueue vs blocked readback at the closure's _mark_enqueued()."""
-        t0 = time.perf_counter_ns()
-        self._rb_mark_ns = 0
-        try:
-            out = await self._device_call(fn)
+        with self._dispatches[family] as d:  # = self._dispatch(family)
+            out = await d.run(fn)
             if self._faults is not None:
                 # chaos readback stall: the dispatch completed but the
                 # host-transfer wait drags — attributed to the family's
@@ -2326,11 +2484,6 @@ class DecodeScheduler:
                 if stall > 0:
                     await asyncio.sleep(stall)
             return out
-        finally:
-            t2 = time.perf_counter_ns()
-            mark = self._rb_mark_ns or t2
-            self._rb_busy[family] += t2 - t0
-            self._rb_rdb[family] += t2 - mark
 
     def _commit_round(self, mode: str, *, step: bool) -> None:
         """THE single per-round commit point: round stats, prometheus round
@@ -2340,68 +2493,72 @@ class DecodeScheduler:
         marks rounds that ran a decode/verify dispatch; chunk-only rounds
         keep stat_steps' historical meaning (decode steps, not prefill
         rounds) but still record a frame."""
-        t_c0 = time.perf_counter_ns()
-        active = self._rb_active if step else self.active
-        if step:
-            self.stat_steps += 1
-            self.stat_occupancy_sum += active / self.n_slots
-            self._metrics.decode_step(self._deployment, active, self.n_slots)
-        # freeze the phase array BEFORE the round clock stops so the
-        # commit phase (this function's own cost so far) stays inside the
-        # gap it is attributed to — sum(phase_ns) <= gap_ns by
-        # construction; the frame build below lands in the next round
-        phase_ns = (
-            self._phases.commit(P_COMMIT, t_c0)
-            if self.flight.enabled
-            else ()
-        )
-        now_ns = time.perf_counter_ns()
-        busy = sum(self._rb_busy)
-        gap = max(now_ns - self._rb_t0 - busy, 0)
-        if self.flight.enabled:
-            # the kill switch removes the whole frame cost (pool snapshot,
-            # slot scan, frame object), not just the ring store
-            snap = self.pool.alloc.snapshot()
-            prefilling = sum(
-                1 for s in self._slots if s is not None and s.prefilling
+        # the whole commit is named for a profiler session (the phase
+        # TIMER stops earlier, where the frame's clock does: below)
+        with flight_mod.annotate(ANN_PHASE[P_COMMIT]):
+            t_c0 = time.perf_counter_ns()
+            active = self._rb_active if step else self.active
+            if step:
+                self.stat_steps += 1
+                self.stat_occupancy_sum += active / self.n_slots
+                self._metrics.decode_step(self._deployment, active, self.n_slots)
+            # freeze the phase array BEFORE the round clock stops so the
+            # commit phase (this function's own cost so far) stays inside the
+            # gap it is attributed to — sum(phase_ns) <= gap_ns by
+            # construction; the frame build below lands in the next round
+            phase_ns = (
+                self._phases.commit(P_COMMIT, t_c0)
+                if self.flight.enabled
+                else ()
             )
-            self.flight.record(
-                FlightFrame(
-                    self.flight.rounds, now_ns, mode, active, prefilling,
-                    len(self._waiting), self._rb_admitted, self._rb_retired,
-                    self._rb_blocked, self._rb_tokens, self._rb_accepted,
-                    self._rb_proposed, self._rb_depth, tuple(self._rb_busy),
-                    gap, snap["free"], snap["live"], snap["prefix"],
-                    self._rb_cow, phase_ns, tuple(self._rb_rdb),
-                    self._rb_overlap, self._rb_probe, tuple(self._rb_widths),
-                    self._rb_promotions,
+            now_ns = time.perf_counter_ns()
+            busy = sum(self._rb_busy)
+            gap = max(now_ns - self._rb_t0 - busy, 0)
+            if self.flight.enabled:
+                # the kill switch removes the whole frame cost (pool snapshot,
+                # slot scan, frame object), not just the ring store
+                snap = self.pool.alloc.snapshot()
+                prefilling = sum(
+                    1 for s in self._slots if s is not None and s.prefilling
                 )
-            )
-            if self.spec_enabled:
-                # adaptive-speculation state for /decode/health: the tuned
-                # shape, the controller's EWMA, and the effective depth
-                # the NEXT round will see (latest-wins attribute — the
-                # per-round history is in the frames)
-                self.flight.spec_state = {
-                    "tree": getattr(self, "_tree_text", ""),
-                    "widths": list(self._rb_widths),
-                    "nodes": (
-                        self.spec_tree.nodes_for_widths(self._rb_widths)
-                        if self.spec_tree is not None and self._rb_widths
-                        else 0
-                    ),
-                    "accept_ewma": round(self._adapt.rate, 4),
-                    "depth": self._rb_depth,
-                    "probes": self._adapt.probes,
-                }
-        self._metrics.decode_round(self._deployment, busy / 1e9, gap / 1e9)
-        if self.flight.enabled and self.flight.rounds % 64 == 0:
-            # refresh the cumulative bubble gauge off the O(1) totals —
-            # per-64-rounds, not per-round, so the gauge write never shows
-            # up in the recorder's own overhead budget
-            self._metrics.decode_bubble(
-                self._deployment, self.flight.bubble_fraction()
-            )
+                self.flight.record(
+                    FlightFrame(
+                        self.flight.rounds, now_ns, mode, active, prefilling,
+                        len(self._waiting), self._rb_admitted, self._rb_retired,
+                        self._rb_blocked, self._rb_tokens, self._rb_accepted,
+                        self._rb_proposed, self._rb_depth, tuple(self._rb_busy),
+                        gap, snap["free"], snap["live"], snap["prefix"],
+                        self._rb_cow, phase_ns, tuple(self._rb_rdb),
+                        self._rb_overlap, self._rb_probe, tuple(self._rb_widths),
+                        self._rb_promotions, self._rb_admit_wait,
+                        self._rb_prefill, self._rb_first_tokens,
+                    )
+                )
+                if self.spec_enabled:
+                    # adaptive-speculation state for /decode/health: the tuned
+                    # shape, the controller's EWMA, and the effective depth
+                    # the NEXT round will see (latest-wins attribute — the
+                    # per-round history is in the frames)
+                    self.flight.spec_state = {
+                        "tree": getattr(self, "_tree_text", ""),
+                        "widths": list(self._rb_widths),
+                        "nodes": (
+                            self.spec_tree.nodes_for_widths(self._rb_widths)
+                            if self.spec_tree is not None and self._rb_widths
+                            else 0
+                        ),
+                        "accept_ewma": round(self._adapt.rate, 4),
+                        "depth": self._rb_depth,
+                        "probes": self._adapt.probes,
+                    }
+            self._metrics.decode_round(self._deployment, busy / 1e9, gap / 1e9)
+            if self.flight.enabled and self.flight.rounds % 64 == 0:
+                # refresh the cumulative bubble gauge off the O(1) totals —
+                # per-64-rounds, not per-round, so the gauge write never shows
+                # up in the recorder's own overhead budget
+                self._metrics.decode_bubble(
+                    self._deployment, self.flight.bubble_fraction()
+                )
         self._round_reset(now_ns)
 
     async def _run_copies(self, copies: list[tuple[int, int]]) -> None:
@@ -2477,6 +2634,8 @@ class DecodeScheduler:
         self._slots[slot] = seq
         self.stat_admitted += 1
         self._rb_admitted += 1
+        seq.t_admitted = time.perf_counter()
+        self._rb_admit_wait += int((seq.t_admitted - seq.t_enqueued) * 1e9)
         if self.feature_draft:
             # the head's attention window opens at the computed suffix: the
             # prefix-reused span has no draft-side K/V (the chunk rounds
@@ -2943,11 +3102,10 @@ class DecodeScheduler:
         if finishing and self.spec_enabled and not self.feature_draft:
             # (feature mode needs no transition-time draft prefill — the
             # head's prompt K/V was teacher-forced by the chunk dispatches)
-            td = time.perf_counter_ns()
-            self._draft_admit([i for _, i in finishing])
             # async dispatch: this is enqueue cost; the device time lands
             # in the next dispatch's blocked readback
-            self._rb_busy[F_DRAFT] += time.perf_counter_ns() - td
+            with self._dispatch(F_DRAFT) as d, d.enqueue():
+                self._draft_admit([i for _, i in finishing])
         t2 = telemetry.now_ns()
         with self._phase(P_SCATTER):
             for seq, i in finishing:
@@ -2990,69 +3148,66 @@ class DecodeScheduler:
 
         def _do_spec():
             # the draft/verify wall split feeds the flight frame's per-
-            # family attribution, the verify side split again into enqueue
-            # vs blocked readback: with async dispatch the draft and
-            # verify-enqueue segments are host-side dispatch cost and the
-            # verify readback carries the blocked wait of the whole round
-            # pair. ENGINE_FLIGHT_SYNC_TIMING blocks after each program so
-            # both columns become ground-truth per-dispatch device wall.
-            td0 = time.perf_counter_ns()
+            # family attribution (d.enqueue(F_DRAFT) books the draft's
+            # segment to its own column), the verify side split again into
+            # enqueue vs blocked readback at _mark_enqueued(): with async
+            # dispatch the draft and verify-enqueue segments are host-side
+            # dispatch cost and the verify readback carries the blocked
+            # wait of the whole round pair. ENGINE_FLIGHT_SYNC_TIMING
+            # blocks after each program so both columns become
+            # ground-truth per-dispatch device wall.
             feat = None  # the feature carry (feature-draft deployments only)
             if self.feature_draft:
-                node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
-                    self.draft_params, self._dck, self._dcv, self._feat, toks,
-                    pos, self._draft_start, temps, topks, self._seed, tick, tree,
-                )
-                if self._sync_timing:
-                    jax.block_until_ready(node_toks)
-                td1 = time.perf_counter_ns()
+                with d.enqueue(F_DRAFT):
+                    node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
+                        self.draft_params, self._dck, self._dcv, self._feat, toks,
+                        pos, self._draft_start, temps, topks, self._seed, tick, tree,
+                    )
+                    if self._sync_timing:
+                        jax.block_until_ready(node_toks)
                 out_t, acc, state, dck, dcv, feat = self._ftree_verify_fn(
                     self.params, self.pool.state, bt, toks, node_toks, blogits,
                     nk, nv, dck, dcv, self._feat, fmask, pos, wlimits, temps,
                     topks, self._seed, tick, tree,
                 )
             elif tree is not None:
-                node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
-                    self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                    topks, self._seed, tick, tree,
-                )
-                if self._sync_timing:
-                    jax.block_until_ready(node_toks)
-                td1 = time.perf_counter_ns()
+                with d.enqueue(F_DRAFT):
+                    node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
+                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
+                        topks, self._seed, tick, tree,
+                    )
+                    if self._sync_timing:
+                        jax.block_until_ready(node_toks)
                 out_t, acc, state, dck, dcv = self._tree_verify_fn(
                     self.params, self.pool.state, bt, toks, node_toks, blogits,
                     nk, nv, dck, dcv, pos, wlimits, temps, topks,
                     self._seed, tick, tree,
                 )
             else:
-                drafts, dlogits, dck, dcv = self._draft_fn(
-                    self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                    topks, self._seed, tick, self.spec_k,
-                )
-                if self._sync_timing:
-                    jax.block_until_ready(drafts)
-                td1 = time.perf_counter_ns()
+                with d.enqueue(F_DRAFT):
+                    drafts, dlogits, dck, dcv = self._draft_fn(
+                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
+                        topks, self._seed, tick, self.spec_k,
+                    )
+                    if self._sync_timing:
+                        jax.block_until_ready(drafts)
                 out_t, acc, state = self._verify_fn(
                     self.params, self.pool.state, bt, toks, drafts, dlogits, pos,
                     limits, temps, topks, self._seed, tick,
                 )
             if self._sync_timing:
                 jax.block_until_ready(out_t)
-            tv = time.perf_counter_ns()
-            out_t, acc = np.asarray(out_t), np.asarray(acc)
-            td2 = time.perf_counter_ns()
-            return out_t, acc, state, dck, dcv, feat, td1 - td0, tv - td1, td2 - tv
+            self._mark_enqueued()
+            return np.asarray(out_t), np.asarray(acc), state, dck, dcv, feat
 
         t0 = telemetry.now_ns()
-        out_t, acc, self.pool.state, self._dck, self._dcv, feat, d_ns, v_enq, v_rdb = (
-            await self._device_call(_do_spec)
-        )
+        with self._dispatch(F_VERIFY) as d:
+            out_t, acc, self.pool.state, self._dck, self._dcv, feat = await d.run(
+                _do_spec
+            )
         if feat is not None:
             self._feat = feat
         t1 = telemetry.now_ns()
-        self._rb_busy[F_DRAFT] += d_ns
-        self._rb_busy[F_VERIFY] += v_enq + v_rdb
-        self._rb_rdb[F_VERIFY] += v_rdb
         # dispatch-time occupancy, committed (with steps/metrics) at the
         # round's single _commit_round point
         self._rb_active = self.active
@@ -3072,53 +3227,51 @@ class DecodeScheduler:
         here (_pipeline_on forces the serial twin)."""
         tree = self.spec_tree
         t0 = telemetry.now_ns()
-        td0 = time.perf_counter_ns()
-        if self.feature_draft:
-            node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
-                self.draft_params, self._dck, self._dcv, self._feat, toks,
-                pos, self._draft_start, temps, topks, self._seed, tick, tree,
+        with self._dispatch(F_VERIFY) as d:
+            if self.feature_draft:
+                with d.enqueue(F_DRAFT):
+                    node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
+                        self.draft_params, self._dck, self._dcv, self._feat, toks,
+                        pos, self._draft_start, temps, topks, self._seed, tick, tree,
+                    )
+                with d.enqueue():
+                    out_dev, acc_dev, state, dck, dcv, self._feat = self._ftree_verify_fn(
+                        self.params, self.pool.state, bt, toks, node_toks, blogits,
+                        nk, nv, dck, dcv, self._feat, fmask, pos, wlimits, temps,
+                        topks, self._seed, tick, tree,
+                    )
+            elif tree is not None:
+                with d.enqueue(F_DRAFT):
+                    node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
+                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
+                        topks, self._seed, tick, tree,
+                    )
+                with d.enqueue():
+                    out_dev, acc_dev, state, dck, dcv = self._tree_verify_fn(
+                        self.params, self.pool.state, bt, toks, node_toks, blogits,
+                        nk, nv, dck, dcv, pos, wlimits, temps, topks,
+                        self._seed, tick, tree,
+                    )
+            else:
+                with d.enqueue(F_DRAFT):
+                    drafts, dlogits, dck, dcv = self._draft_fn(
+                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
+                        topks, self._seed, tick, self.spec_k,
+                    )
+                with d.enqueue():
+                    out_dev, acc_dev, state = self._verify_fn(
+                        self.params, self.pool.state, bt, toks, drafts, dlogits, pos,
+                        limits, temps, topks, self._seed, tick,
+                    )
+            self.pool.state = state
+            self._dck = dck
+            self._dcv = dcv
+            self._rb_active = self.active  # dispatch-time occupancy
+            self._overlap_window()
+            out_t, acc = await d.readback(
+                lambda: (np.asarray(out_dev), np.asarray(acc_dev))
             )
-            td1 = time.perf_counter_ns()
-            out_dev, acc_dev, state, dck, dcv, self._feat = self._ftree_verify_fn(
-                self.params, self.pool.state, bt, toks, node_toks, blogits,
-                nk, nv, dck, dcv, self._feat, fmask, pos, wlimits, temps,
-                topks, self._seed, tick, tree,
-            )
-        elif tree is not None:
-            node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
-                self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                topks, self._seed, tick, tree,
-            )
-            td1 = time.perf_counter_ns()
-            out_dev, acc_dev, state, dck, dcv = self._tree_verify_fn(
-                self.params, self.pool.state, bt, toks, node_toks, blogits,
-                nk, nv, dck, dcv, pos, wlimits, temps, topks,
-                self._seed, tick, tree,
-            )
-        else:
-            drafts, dlogits, dck, dcv = self._draft_fn(
-                self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                topks, self._seed, tick, self.spec_k,
-            )
-            td1 = time.perf_counter_ns()
-            out_dev, acc_dev, state = self._verify_fn(
-                self.params, self.pool.state, bt, toks, drafts, dlogits, pos,
-                limits, temps, topks, self._seed, tick,
-            )
-        self.pool.state = state
-        self._dck = dck
-        self._dcv = dcv
-        self._rb_active = self.active  # dispatch-time occupancy
-        self._overlap_window()
-        t2 = time.perf_counter_ns()
-        out_t, acc = await self._device_call(
-            lambda: (np.asarray(out_dev), np.asarray(acc_dev))
-        )
-        t3 = time.perf_counter_ns()
         t1 = telemetry.now_ns()
-        self._rb_busy[F_DRAFT] += td1 - td0
-        self._rb_busy[F_VERIFY] += t3 - td1
-        self._rb_rdb[F_VERIFY] += t3 - t2
         self._consume_spec(out_t, acc, limits, wlimits, t0, t1)
 
     def _consume_spec(self, out_t, acc, limits, wlimits, t0: int, t1: int) -> None:
@@ -3209,26 +3362,22 @@ class DecodeScheduler:
         apart as the frame's overlap_ns); rdb is the true post-overlap
         block. Sync-timing runs never come here (_pipeline_on forces the
         serial path)."""
-        t0 = time.perf_counter_ns()
-        if self.feature_draft:
-            nxt_dev, self._feat, state = self._step_f_fn(
-                self.params, self.pool.state, bt, toks, pos, self._feat,
-                fmask, temps, topks, self._seed, tick,
-            )
-        else:
-            nxt_dev, state = self._step_fn(
-                self.params, self.pool.state, bt, toks, pos, temps, topks,
-                self._seed, tick,
-            )
-        self.pool.state = state
-        self._rb_active = self.active  # dispatch-time occupancy
-        self._overlap_window()
-        t2 = time.perf_counter_ns()
-        nxt = await self._device_call(lambda: np.asarray(nxt_dev))
-        t3 = time.perf_counter_ns()
-        self._rb_busy[F_STEP] += t3 - t0
-        self._rb_rdb[F_STEP] += t3 - t2
-        return nxt
+        with self._dispatch(F_STEP) as d:
+            with d.enqueue():
+                if self.feature_draft:
+                    nxt_dev, self._feat, state = self._step_f_fn(
+                        self.params, self.pool.state, bt, toks, pos, self._feat,
+                        fmask, temps, topks, self._seed, tick,
+                    )
+                else:
+                    nxt_dev, state = self._step_fn(
+                        self.params, self.pool.state, bt, toks, pos, temps, topks,
+                        self._seed, tick,
+                    )
+            self.pool.state = state
+            self._rb_active = self.active  # dispatch-time occupancy
+            self._overlap_window()
+            return await d.readback(lambda: np.asarray(nxt_dev))
 
     async def _run(self) -> None:
         try:
@@ -3251,7 +3400,8 @@ class DecodeScheduler:
                         if self._closed:
                             return
                         self._wake.clear()
-                        await self._wake.wait()
+                        with flight_mod.annotate(ANN_IDLE_WAIT):
+                            await self._wake.wait()
                         # idle wait is not decode bubble: restart the
                         # round clock so the next frame's host gap is the
                         # loop's own, not the queue's silence
@@ -3510,6 +3660,8 @@ class DecodeScheduler:
             self._free = list(range(self.n_slots - 1, -1, -1))
             self._waiting.clear()
             self._reset_device_state()
+        finally:
+            self._round_mark(False)
 
     def _reset_device_state(self) -> None:
         """Error-path device-state rebuild: the pool state (and in spec

@@ -27,6 +27,7 @@ import logging
 from typing import Awaitable, Callable, Mapping
 
 from seldon_core_tpu.serving.wire import WireRequest, WireResponse, WireStreamResponse
+from seldon_core_tpu.telemetry import flight
 
 log = logging.getLogger(__name__)
 
@@ -422,9 +423,12 @@ class HttpProtocol(asyncio.Protocol):
                     break
                 if not chunk:
                     continue
-                self._transport.write(
-                    f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n"
-                )
+                # named for a profiler session: what the decode loop's
+                # thread does between a round's phases
+                with flight.annotate(flight.ANN_SSE_WRITE):
+                    self._transport.write(
+                        f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n"
+                    )
         finally:
             # close the event source DETERMINISTICALLY: on client
             # disconnect the break above leaves the async generator

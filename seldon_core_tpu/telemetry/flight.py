@@ -15,7 +15,9 @@ to a bounded ring —
   ``slots``: every slot occupied);
 - tokens emitted, speculation accepted/proposed and the effective depth the
   adaptive controller chose;
-- per-dispatch wall time split **device-busy vs host-gap** ("bubble"), the
+- per-dispatch wall time split **dispatch wall vs host-gap** ("bubble":
+  the host's clock from its call to the readback's return — NOT device-busy
+  time, which only a device trace gives), the
   busy side attributed per fused program family
   (``chunk``/``step``/``draft``/``verify``/``copy``) and split again into
   **enqueue vs blocked readback** per family (``rdb_ns``), so on
@@ -64,6 +66,7 @@ Layered on top:
 from __future__ import annotations
 
 import os
+import sys
 import time
 
 
@@ -74,7 +77,7 @@ from seldon_core_tpu.utils.env import (
     ENGINE_FLIGHT_SYNC_TIMING,
 )
 
-# fused program families a round's device-busy time is attributed to; the
+# fused program families a round's dispatch wall ("busy") is attributed to; the
 # indices are the positions in FlightFrame.busy_ns
 FAMILIES = ("chunk", "step", "draft", "verify", "copy")
 F_CHUNK, F_STEP, F_DRAFT, F_VERIFY, F_COPY = range(5)
@@ -106,6 +109,46 @@ PHASES = (
 N_PHASES = len(PHASES)
 _ZERO_PHASES = (0,) * N_PHASES
 _ZERO_FAMILIES = (0,) * len(FAMILIES)
+
+# What a ``jax.profiler`` session needs to put a round's time to a name:
+# the program writes these trace annotations itself and always, on the
+# thread that does the work ("off" is "no profiler session": one check and a
+# shared no-op then). One prefix, the names built from PHASES and FAMILIES
+# (docs/observability.md "Reading a device trace" has the table).
+ANN_PREFIX = "decode."
+ANN_ROUND = ANN_PREFIX + "round"  # loop: _round_reset -> _commit_round, awaits included
+ANN_IDLE_WAIT = ANN_PREFIX + "idle_wait"  # loop: no work (not a bubble)
+ANN_SSE_WRITE = ANN_PREFIX + "sse_write"  # loop: one stream flush (serving/fast_http.py)
+ANN_PHASE = tuple(f"{ANN_PREFIX}phase.{p}" for p in PHASES)  # loop: _PhaseCtx
+ANN_DISPATCH = tuple(f"{ANN_PREFIX}dispatch.{f}" for f in FAMILIES)  # loop: hand-off -> readback return
+ANN_ENQUEUE = tuple(f"{ANN_PREFIX}enqueue.{f}" for f in FAMILIES)  # the calling thread: program call -> enqueued
+ANN_READBACK = tuple(f"{ANN_PREFIX}readback.{f}" for f in FAMILIES)  # the calling thread: the blocking host read
+
+
+def annotate(name: str, **kw):
+    """THE emit helper: start a ``jax.profiler.TraceAnnotation`` (a
+    complete event on the calling thread's line of the trace, ``kw`` as its
+    stats) and return it; the caller ends it with ``.__exit__(None, None,
+    None)`` on the same thread. Every annotation of the decode tier goes
+    through here (the tests stub this one name). With no profiler session
+    (``TraceAnnotation.is_enabled()``, the check the annotation makes
+    itself) it hands back the shared no-op, as it does in a process that
+    never imported JAX and so can hold no session (this module must stay
+    importable without JAX — the gateway and the linters import the
+    telemetry package)."""
+    global _trace_annotation, _session_on
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return _NOOP_CTX
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation, _session_on = TraceAnnotation, TraceAnnotation.is_enabled
+    if not _session_on():
+        return _NOOP_CTX
+    return _trace_annotation(name, **kw)
+
+
+_trace_annotation = _session_on = None
 
 _DEFAULT_CAPACITY = 2048
 # frames carried per auto-dump (span events are capped at
@@ -174,6 +217,7 @@ class _PhaseCtx:
         if stack:
             t._acct(stack[-1], now - t._mark)
         stack.append(self.p)
+        t._anns.append(annotate(ANN_PHASE[self.p]))
         t._mark = now
         return self
 
@@ -185,6 +229,7 @@ class _PhaseCtx:
             # scheduler never does) drops the span instead of raising
             # into the decode loop
             t._acct(t._stack.pop(), now - t._mark)
+            t._anns.pop().__exit__(None, None, None)
         t._mark = now
         return False
 
@@ -206,13 +251,15 @@ class PhaseTimer:
     """Per-round host-phase accumulator behind the scheduler's
     ``with self._phase(P_X):`` blocks: a fixed ``ns`` array aligned with
     PHASES, reset at ``_round_reset`` and frozen into each FlightFrame at
-    ``_commit_round``. Nested phases attribute to the INNERMOST phase
+    ``_commit_round``. Every handle also writes its ``ANN_PHASE`` trace
+    annotation (overlap mode too), so a profiler session sees the phases
+    on the device trace's clock. Nested phases attribute to the INNERMOST phase
     (self-time semantics — an ``_emit`` inside the accept walk counts as
     ``emit_slo``, not twice), so phase sums stay <= the round's gap.
     Disabled (the ENGINE_FLIGHT kill switch) every handle is a shared
     no-op and the arrays stay zero."""
 
-    __slots__ = ("ns", "enabled", "overlap_ns", "_overlap", "_stack", "_mark", "_ctxs")
+    __slots__ = ("ns", "enabled", "overlap_ns", "_overlap", "_stack", "_anns", "_mark", "_ctxs")
 
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
@@ -225,6 +272,7 @@ class PhaseTimer:
         self.overlap_ns = 0
         self._overlap = False
         self._stack: list[int] = []
+        self._anns: list = []  # the open phases' trace annotations, aligned with _stack
         self._mark = 0
         self._ctxs = tuple(_PhaseCtx(self, p) for p in range(N_PHASES))
 
@@ -251,6 +299,8 @@ class PhaseTimer:
         self.overlap_ns = 0
         self._overlap = False
         self._stack.clear()
+        while self._anns:
+            self._anns.pop().__exit__(None, None, None)
 
     def commit(self, p: int, t0_ns: int) -> tuple:
         """Attribute ``now - t0_ns`` to phase ``p`` (the commit point's own
@@ -262,29 +312,44 @@ class PhaseTimer:
         return tuple(self.ns)
 
     @staticmethod
-    def measure_overhead(n: int = 2000, phases_per_round: int = 8) -> float:
-        """Measured per-round phase-timer cost in µs (``phases_per_round``
-        enter/exit pairs incl. one nested pair) — what PARITY.md documents
-        beside the frame-append cost and the tier-1 guard budgets."""
+    def measure_overhead(
+        n: int = 2000, phases_per_round: int = 8, dispatches_per_round: int = 2
+    ) -> float:
+        """Measured per-round cost in µs of the phase timer AND the round's
+        trace annotations with no profiler session: ``phases_per_round``
+        enter/exit pairs incl. one nested pair (each writes its ANN_PHASE
+        annotation), one ANN_ROUND with its two stats, and per dispatch
+        the ANN_DISPATCH / ANN_ENQUEUE / ANN_READBACK triple — what
+        PARITY.md documents beside the frame-append cost and the tier-1
+        guard budgets. A served round of 16 generating slots enters
+        ``emit_slo`` once per token: ``phases_per_round=40`` is its size."""
         t = PhaseTimer(enabled=True)
         t0 = time.perf_counter_ns()
-        for _ in range(n):
+        for i in range(n):
+            rnd = annotate(ANN_ROUND, round=i, t_ns=t0)
             for p in range(max(phases_per_round - 2, 1)):
                 with t.phase(p % N_PHASES):
                     pass
+            for f in range(dispatches_per_round):
+                d = annotate(ANN_DISPATCH[f])
+                annotate(ANN_ENQUEUE[f]).__exit__(None, None, None)
+                annotate(ANN_READBACK[f]).__exit__(None, None, None)
+                d.__exit__(None, None, None)
             with t.phase(P_ACCEPT_WALK):
                 with t.phase(P_EMIT_SLO):
                     pass
             t.reset()
+            rnd.__exit__(None, None, None)
         return round((time.perf_counter_ns() - t0) / n / 1e3, 3)
 
 
 class FlightFrame:
     """One scheduler round, compact. ``busy_ns`` is a 5-tuple aligned with
-    FAMILIES (enqueue + blocked readback per family); ``rdb_ns`` the
+    FAMILIES (dispatch wall, host call to readback return: enqueue +
+    blocked readback per family — not device time); ``rdb_ns`` the
     blocked-readback share of each family (enqueue = busy - rdb);
     ``phase_ns`` the host gap attributed per PHASES entry; ``gap_ns`` the
-    round's host bubble (wall - device busy); ``overlap_ns`` the host work
+    round's host bubble (wall - dispatch wall); ``overlap_ns`` the host work
     the PIPELINED loop ran inside a dispatch's busy window (hidden under
     the in-flight dispatch — inside busy, NOT part of the gap, which is
     exactly why pipelining shrinks bubble_fraction); ``probe`` marks a
@@ -294,7 +359,12 @@ class FlightFrame:
     never read as genuine accept degradation; ``spec_widths`` the tuned
     per-depth width ceiling the round ran under (tree rounds only);
     ``promotions`` the prefix entries promoted device-ward from the slow
-    KV tiers (host/store/sibling) during the round's admissions."""
+    KV tiers (host/store/sibling) during the round's admissions;
+    ``admit_wait_ns`` the summed queue wait (submit -> slot assignment) of
+    this round's ``admitted`` requests, ``prefill_ns`` the summed
+    admission -> first-token time of the ``first_tokens`` requests whose
+    first token this round emitted — the two halves of the time to first
+    token as the program sees it."""
 
     __slots__ = (
         "seq", "t_ns", "mode", "active", "prefilling", "queued",
@@ -302,6 +372,7 @@ class FlightFrame:
         "spec_depth", "busy_ns", "gap_ns", "kv_free", "kv_live",
         "kv_prefix", "cow", "phase_ns", "rdb_ns", "overlap_ns",
         "probe", "spec_widths", "promotions",
+        "admit_wait_ns", "prefill_ns", "first_tokens",
     )
 
     def __init__(
@@ -310,6 +381,7 @@ class FlightFrame:
         busy_ns, gap_ns, kv_free, kv_live, kv_prefix, cow,
         phase_ns=_ZERO_PHASES, rdb_ns=_ZERO_FAMILIES, overlap_ns=0,
         probe=False, spec_widths=(), promotions=0,
+        admit_wait_ns=0, prefill_ns=0, first_tokens=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -336,6 +408,9 @@ class FlightFrame:
         self.probe = probe
         self.spec_widths = spec_widths
         self.promotions = promotions
+        self.admit_wait_ns = admit_wait_ns
+        self.prefill_ns = prefill_ns
+        self.first_tokens = first_tokens
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -377,6 +452,10 @@ class FlightFrame:
             d["overlap_us"] = round(self.overlap_ns / 1e3, 1)
         if self.admitted:
             d["admitted"] = self.admitted
+            d["admit_wait_us"] = round(self.admit_wait_ns / 1e3, 1)
+        if self.first_tokens:
+            d["first_tokens"] = self.first_tokens
+            d["prefill_us"] = round(self.prefill_ns / 1e3, 1)
         if self.retired:
             d["retired"] = self.retired
         if self.blocked:
@@ -582,6 +661,7 @@ class FlightRecorder:
         overlap = 0
         tokens = admitted = retired = accepted = proposed = 0
         promotions = 0
+        admit_wait = prefill = first_tokens = 0
         occ = 0.0
         modes: dict[str, int] = {}
         blocked: dict[str, int] = {}
@@ -600,6 +680,9 @@ class FlightRecorder:
             admitted += f.admitted
             retired += f.retired
             promotions += f.promotions
+            admit_wait += f.admit_wait_ns
+            prefill += f.prefill_ns
+            first_tokens += f.first_tokens
             accepted += f.accepted
             proposed += f.proposed
             occ += f.active / self.n_slots
@@ -662,6 +745,12 @@ class FlightRecorder:
         }
         if promotions:
             out["promotions"] = promotions
+        if admitted:
+            # time to first token, split where the program can: queue wait
+            # per admission, admission -> first token per first emission
+            out["admit_wait_ms_mean"] = round(admit_wait / admitted / 1e6, 3)
+        if first_tokens:
+            out["prefill_ms_mean"] = round(prefill / first_tokens / 1e6, 3)
         if proposed:
             # accept_rate excludes PROBE rounds: a depth-1 recovery probe
             # or a full-shape width probe accepts badly BY DESIGN (that is

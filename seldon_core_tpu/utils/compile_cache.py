@@ -13,13 +13,12 @@ code and tests never touch it.
 from __future__ import annotations
 
 import os
+import re
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # <checkout>/.jax_cache (git-ignored): the parent of the package directory
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    ".jax_cache",
-)
+DEFAULT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 def enable_compile_cache() -> str | None:
@@ -36,6 +35,15 @@ def enable_compile_cache() -> str | None:
     # 1 s minimum compile time would admit almost none of them (the
     # minimum entry size already defaults to 0 = admit everything)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the key covers the ops' metadata: by default it does not, and a cache
+    # warmed by a build whose programs differ only in their named scopes
+    # (models/decoder.py PAGED_SCOPES) hands back executables without them —
+    # a device trace then names nothing. Source paths enter the key relative
+    # to the checkout, so a checkout at another path still hits.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex", re.escape(CHECKOUT_ROOT + os.sep)
+    )
     if os.environ.get(CACHE_DIR_ENV):
         return os.environ[CACHE_DIR_ENV]
     if jax.config.jax_platforms == "cpu":

@@ -32,6 +32,15 @@ def test_env_var_set_means_no_directory_set_in_code(monkeypatch, tmp_path):
     assert seen["returned"] == str(tmp_path)
     # the decode tier's sub-second programs are admitted either way
     assert seen["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    # the key covers op metadata (a cache warmed by a build without the
+    # named scopes must not hand its executables to one with them), with
+    # source paths relative to the checkout so another checkout still hits
+    assert seen["jax_compilation_cache_include_metadata_in_key"] is True
+    import re
+
+    assert re.sub(seen["jax_hlo_source_file_canonicalization_regex"], "",
+                  os.path.join(REPO, "seldon_core_tpu", "models", "decoder.py")) == (
+        os.path.join("seldon_core_tpu", "models", "decoder.py"))
     assert jax.config.jax_persistent_cache_min_entry_size_bytes <= 0
 
 

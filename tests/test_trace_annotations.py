@@ -1,0 +1,335 @@
+"""The decode round names its own time (ISSUE 26): the scheduler writes
+``jax.profiler`` trace annotations for every host state of a round and
+``jax.named_scope`` names into the paged programs, itself and always.
+
+What this file pins:
+
+1. every ``flight.PHASES`` / ``flight.FAMILIES`` name is emitted as an
+   annotation by a scheduler round, through the ONE emit helper
+   (``flight.annotate``) — serial, pipelined, chunked, speculative and
+   prefix/CoW rounds alike (the pipelined step is where a patched-on
+   annotation used to be missed);
+2. ``decode.round``'s ``round`` stat is the committed frame's index;
+3. the two time-to-first-token counters of the FlightFrame sum to the
+   per-request stamps;
+4. the compiled ``_fused_step`` / ``_fused_chunk`` carry every scope name;
+5. a real CPU profiler session records the annotations with their stats;
+6. the per-round cost with no session stays inside the overhead budget.
+"""
+
+import asyncio
+import glob
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models import decoder
+from seldon_core_tpu.models.decoder import init_decoder, paged_kv_init
+from seldon_core_tpu.serving import decode_scheduler as ds
+from seldon_core_tpu.serving.decode_scheduler import DecodeScheduler
+from seldon_core_tpu.telemetry import flight as flight_mod
+from seldon_core_tpu.telemetry.flight import FAMILIES, PHASES, PhaseTimer
+
+SEQ = 8
+MAX_NEW = 8
+VOCAB = 64
+
+
+def _params(**kw):
+    return init_decoder(seed=3, vocab=VOCAB, hidden=32, layers=1, ffn=64, max_len=32, **kw)
+
+
+class _Recorder:
+    """Stands in for ``flight.annotate``: every call is one event, ended
+    by the handle's ``__exit__`` as the real TraceAnnotation is."""
+
+    def __init__(self):
+        self.events: list[dict] = []
+
+    def __call__(self, name, **kw):
+        ev = {"name": name, "kw": kw, "thread": threading.get_ident(), "open": True, "exits": 0}
+        self.events.append(ev)
+        return _Handle(ev)
+
+    def names(self) -> set:
+        return {e["name"] for e in self.events}
+
+
+class _Handle:
+    def __init__(self, ev):
+        self.ev = ev
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.ev["open"] = False
+        self.ev["exits"] += 1
+        return False
+
+
+def _shared_prompts(n, shared, seed):
+    ids = np.random.default_rng(seed).integers(0, VOCAB, (n, SEQ)).astype(np.int32)
+    ids[1:, :shared] = ids[0, :shared]
+    return ids
+
+
+def _drive(s, ids, first_kw=None):
+    async def go():
+        outs = [await s.submit(ids[0], **(first_kw or {}))]
+        outs += await asyncio.gather(*(s.submit(r) for r in ids[1:]))
+        await s.close()
+        return outs
+
+    return asyncio.run(go())
+
+
+# what each kind of round must name, beyond what every kind does
+COMMON = {"round", "phase.admit", "phase.alloc", "phase.scatter", "phase.emit_slo", "phase.commit",
+          "dispatch.chunk", "enqueue.chunk", "readback.chunk"}
+STEP = {"phase.sampling", "dispatch.step", "enqueue.step", "readback.step"}
+SPEC = {"phase.accept_walk", "dispatch.verify", "enqueue.verify", "readback.verify",
+        "dispatch.draft", "enqueue.draft"}
+CONFIGS = {
+    "plain-serial": (dict(n_slots=2), False, COMMON | STEP),
+    "plain-pipelined": (dict(n_slots=2), True, COMMON | STEP),
+    "chunk-pipelined": (dict(n_slots=2, prefill_chunk=4), True, COMMON | STEP),
+    "spec-serial": (dict(n_slots=2, spec_k=3), False, COMMON | SPEC),
+    "spec-pipelined": (dict(n_slots=2, spec_k=3), True, COMMON | SPEC),
+    "prefix-cow": (dict(n_slots=2, prefix_slots=4, prefill_chunk=4, kv_page_size=4, kv_pages=14), True,
+                   COMMON | STEP | {"phase.prefix_match", "dispatch.copy", "enqueue.copy"}),
+}
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """Each configuration served once with the emit helper stubbed:
+    {config: (recorder, scheduler)}."""
+    out = {}
+    real = flight_mod.annotate
+    try:
+        for name, (kw, pipelined, _want) in CONFIGS.items():
+            kw = dict(kw)
+            if "spec_k" in kw:
+                kw["draft_params"] = _params(resid_scale=0.1)
+            s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, **kw)
+            s.warmup()
+            s.pipeline_enabled = pipelined
+            rec = _Recorder()
+            flight_mod.annotate = rec
+            try:
+                if name == "prefix-cow":
+                    _drive(s, _shared_prompts(10, shared=5, seed=11), {"cache_prefix": 5})
+                else:
+                    _drive(s, _shared_prompts(5, shared=0, seed=1))
+            finally:
+                flight_mod.annotate = real
+            out[name] = (rec, s)
+    finally:
+        flight_mod.annotate = real
+    return out
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_a_round_names_its_host_states(recorded, config):
+    rec, s = recorded[config]
+    want = {flight_mod.ANN_PREFIX + n for n in CONFIGS[config][2]}
+    assert want <= rec.names(), sorted(want - rec.names())
+    # every annotation that was started was ended, exactly once
+    assert all(not e["open"] and e["exits"] == 1 for e in rec.events)
+    # only registered names, all under the one prefix
+    registered = (
+        {flight_mod.ANN_ROUND, flight_mod.ANN_IDLE_WAIT, flight_mod.ANN_SSE_WRITE}
+        | set(flight_mod.ANN_PHASE) | set(flight_mod.ANN_DISPATCH)
+        | set(flight_mod.ANN_ENQUEUE) | set(flight_mod.ANN_READBACK)
+    )
+    assert rec.names() <= registered
+    assert all(n.startswith(flight_mod.ANN_PREFIX) for n in registered)
+    assert (s.stat_pipelined_rounds > 0) == CONFIGS[config][1]
+    # a dispatch holds its enqueue and its readback: order on the recorder's clock
+    order = [e["name"] for e in rec.events]
+    for fam in ("chunk", "step", "verify"):
+        d = flight_mod.ANN_PREFIX + "dispatch." + fam
+        if d in order:
+            i = order.index(d)
+            assert order.index(flight_mod.ANN_PREFIX + "enqueue." + fam) > i
+            assert order.index(flight_mod.ANN_PREFIX + "readback." + fam) > i
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_round_annotation_carries_the_frame_index_and_clock(recorded, config):
+    """One ``decode.round`` per committed frame (plus the ones an idle wait
+    or the loop's end closed without a frame); ``round`` is the index the
+    frame committed under and ``t_ns`` the round clock's start, so a trace
+    round joins its FlightFrame and the recorder's clock the trace's."""
+    rec, s = recorded[config]
+    rounds = [e["kw"] for e in rec.events if e["name"] == flight_mod.ANN_ROUND]
+    frames = s.flight.snapshot()
+    assert frames and len(rounds) >= len(frames)
+    by_index: dict[int, list] = {}
+    for kw in rounds:
+        assert set(kw) == {"round", "t_ns"}
+        by_index.setdefault(kw["round"], []).append(kw["t_ns"])
+    for f in frames:
+        # the LAST annotation opened under a frame's index is the round that
+        # committed it (an idle wait restarts the round under the same index)
+        assert f.seq in by_index, (f.seq, sorted(by_index))
+        assert by_index[f.seq][-1] <= f.t_ns
+    starts = [kw["t_ns"] for kw in rounds]
+    assert starts == sorted(starts)
+
+
+def test_every_registered_phase_and_family_is_emitted(recorded):
+    seen = set().union(*(rec.names() for rec, _ in recorded.values()))
+    for i, p in enumerate(PHASES):
+        assert flight_mod.ANN_PHASE[i] == f"decode.phase.{p}" and flight_mod.ANN_PHASE[i] in seen, p
+    for i, f in enumerate(FAMILIES):
+        assert flight_mod.ANN_DISPATCH[i] == f"decode.dispatch.{f}" and flight_mod.ANN_DISPATCH[i] in seen, f
+        assert flight_mod.ANN_ENQUEUE[i] == f"decode.enqueue.{f}" and flight_mod.ANN_ENQUEUE[i] in seen, f
+    # draft and copy dispatches read nothing back; the others do
+    for f in ("chunk", "step", "verify"):
+        assert f"decode.readback.{f}" in seen
+    assert flight_mod.ANN_IDLE_WAIT in seen  # the loop waited for its first request
+
+
+def test_one_helper_times_the_pipelined_step_and_timed_call():
+    """The pipelined step has no timing of its own any more: it and
+    ``_timed_call`` both go through ``_dispatch`` (busy = the handle's
+    wall, rdb = the part after the mark), so the frame's columns and the
+    annotations cannot drift apart."""
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2)
+    s.warmup()
+    _drive(s, _shared_prompts(4, shared=0, seed=2))
+    frames = [f for f in s.flight.snapshot() if f.mode == "plain"]
+    assert frames and s.stat_pipelined_rounds > 0
+    step = flight_mod.F_STEP
+    assert all(0 < f.rdb_ns[step] <= f.busy_ns[step] for f in frames)
+    import inspect
+
+    src = inspect.getsource(DecodeScheduler._step_round_pipelined)
+    assert "self._dispatch(F_STEP)" in src and "perf_counter_ns" not in src
+    assert "self._dispatches[family]" in inspect.getsource(DecodeScheduler._timed_call)
+
+
+def test_ttft_counters_sum_to_the_request_stamps():
+    """admit_wait_ns / prefill_ns / first_tokens over the frames are the
+    sums of the per-request stamps (t_enqueued, t_admitted, t_first_token)."""
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2, prefill_chunk=4)
+    s.warmup()
+    seqs = []
+    install = s._install_admit
+
+    def spy(seq, *a, **kw):
+        seqs.append(seq)
+        return install(seq, *a, **kw)
+
+    s._install_admit = spy
+    _drive(s, _shared_prompts(7, shared=0, seed=4))
+    frames = s.flight.snapshot()
+    assert len(seqs) == 7 == sum(f.admitted for f in frames) == sum(f.first_tokens for f in frames)
+    assert all(q.t_enqueued <= q.t_admitted <= q.t_first_token for q in seqs)
+    assert sum(f.admit_wait_ns for f in frames) == sum(int((q.t_admitted - q.t_enqueued) * 1e9) for q in seqs)
+    assert sum(f.prefill_ns for f in frames) == sum(int((q.t_first_token - q.t_admitted) * 1e9) for q in seqs)
+    # five of seven waited for a slot; every prompt took two chunk rounds
+    assert sum(f.admit_wait_ns for f in frames) > 0 and sum(f.prefill_ns for f in frames) > 0
+    d = next(f for f in frames if f.first_tokens).to_dict()
+    assert d["first_tokens"] >= 1 and d["prefill_us"] > 0
+    agg = s.flight.aggregate()
+    assert agg["admit_wait_ms_mean"] >= 0 and agg["prefill_ms_mean"] > 0
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_compiled_fused_programs_carry_every_scope(program):
+    """The scope names reach the compiled program's op metadata (what a
+    device trace's op events carry) — metadata only, no instruction."""
+    params = _params()
+    pool = paged_kv_init(params, 8, 4)
+    n = 2
+    bt = jnp.zeros((n, 4), jnp.int32)
+    vec = jnp.zeros((n,), jnp.int32)
+    temps = jnp.zeros((n,), jnp.float32)
+    if program == "step":
+        args = (params, pool, bt, vec, vec, temps, vec, 0, jnp.int32(1))
+        fn = ds._fused_step
+    else:
+        args = (params, pool, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1))
+        fn = ds._fused_chunk
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert len(set(decoder.PAGED_SCOPES)) == len(decoder.PAGED_SCOPES) == 10
+    for scope in decoder.PAGED_SCOPES:
+        assert f"/{scope}/" in text, scope
+
+
+def test_a_cpu_profiler_session_records_the_annotations(tmp_path):
+    """The real emit path, end to end: a short ``jax.profiler`` session
+    round a served batch holds ``decode.round`` with its two stats and the
+    phase / dispatch / enqueue / readback events."""
+    from jax.profiler import ProfileData
+
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2)
+    s.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _drive(s, _shared_prompts(3, shared=0, seed=5))
+    finally:
+        jax.profiler.stop_trace()
+    path = max(glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")),
+               key=os.path.getmtime)
+    events = [e for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events if e.name.startswith("decode.")]
+    names = {e.name for e in events}
+    assert {"decode.round", "decode.phase.admit", "decode.phase.emit_slo", "decode.dispatch.step",
+            "decode.enqueue.step", "decode.readback.step", "decode.dispatch.chunk"} <= names
+    committed = {f.seq for f in s.flight.snapshot()}
+    seen = set()
+    for e in events:
+        if e.name == "decode.round":
+            stats = dict(e.stats)
+            assert set(stats) == {"round", "t_ns"}
+            seen.add(int(stats["round"]))
+    assert committed <= seen
+    # with no session the helper hands back the shared no-op
+    assert flight_mod.annotate("decode.round", round=0, t_ns=0) is flight_mod._NOOP_CTX
+
+
+def test_annotations_stay_inside_the_overhead_budget():
+    """No session: the synthetic round (8 phases, a round annotation, two
+    dispatch triples) stays inside the recorder's CI budget, and a served
+    round's size (16 slots: ~40 phase entries) inside four times it."""
+    assert PhaseTimer.measure_overhead(2000) < 50.0
+    assert PhaseTimer.measure_overhead(1000, phases_per_round=40) < 200.0
+
+
+def test_each_sse_flush_is_named(monkeypatch):
+    """What else the loop thread does between a round's phases: one
+    ``decode.sse_write`` per chunk the stream writer flushes."""
+    from seldon_core_tpu.serving.fast_http import HttpProtocol
+    from seldon_core_tpu.serving.wire import WireStreamResponse, sse_frame
+
+    class Transport:
+        def __init__(self):
+            self.wrote = []
+
+        def write(self, b):
+            self.wrote.append(bytes(b))
+
+    async def events():
+        for i in range(3):
+            yield sse_frame({"token": i})
+        yield b""  # an empty chunk is skipped, not flushed
+
+    rec = _Recorder()
+    monkeypatch.setattr(flight_mod, "annotate", rec)
+    proto = object.__new__(HttpProtocol)
+    proto._transport, proto._closing = Transport(), False
+    asyncio.run(proto._write_stream(WireStreamResponse(events())))
+    assert [e["name"] for e in rec.events] == [flight_mod.ANN_SSE_WRITE] * 3
+    assert all(not e["open"] for e in rec.events)
+    assert sum(b"data: " in w for w in proto._transport.wrote) == 3
