@@ -555,13 +555,26 @@ def chunk_prefill(
 # ------------------------------------------------------------- paged KV
 # Block-table KV memory (serving/kv_pool.py owns the allocator): instead of
 # one contiguous [L, n_slots, h, max_ctx, hd] row per slot, K/V lives in a
-# shared page pool [L, n_pages, h, page_size, hd] and each slot carries a
-# static-shape block table [max_pages] of physical page ids. The attention
-# building blocks below mirror decode_step / verify_step / chunk_prefill
-# exactly — same masks, same einsums, same f32 accumulation — but read the
-# cache through a pool gather and write through a per-token page/offset
-# scatter, so two slots sharing a system prompt REFERENCE the same pages
-# (vLLM's PagedAttention memory model) instead of each holding a copy.
+# shared page pool of TOKEN ROWS [L, n_pages, page_size, h*hd] and each slot
+# carries a static-shape block table [max_pages] of physical page ids. The
+# attention building blocks below mirror decode_step / verify_step /
+# chunk_prefill exactly — same masks, same einsums, same f32 accumulation —
+# but read the cache through a pool gather and write through a per-token
+# (layer, page, row) scatter, so two slots sharing a system prompt REFERENCE
+# the same pages (vLLM's PagedAttention memory model) instead of each
+# holding a copy.
+#
+# Why token rows: every paged program takes the WHOLE pool, donated, and
+# updates it in place — one scatter per layer at (li, page, row) into the
+# [L, ...] array, one gather pool[li, bt] out of it; no per-layer slice, no
+# restack. The TPU's scatter wants the scattered dimensions major and the
+# written row minor; with heads between page and row ([L, P, h, ps, hd]) the
+# compiler converts the pool to token rows and back around every write (a
+# whole-pool copy per layer: half of a fused step on the chip, ~7 GiB of
+# temporaries; PERF.md section 6, PR 27). A token's K (or V) for all heads
+# is one contiguous h*hd row — what the qkv projection emits, no head
+# transpose — and h*hd is whole lane tiles where head_dim 64 alone pads to
+# 128. The head split happens on the gathered rows, not in the pool.
 #
 # Conventions the scheduler relies on:
 # - physical page 0 is a reserved junk sink: free slots' block tables are
@@ -575,9 +588,12 @@ def chunk_prefill(
 # - pool state is a flat tuple pytree: (k, v) in fp mode, or
 #   (k_q, k_scale, k_zp, v_q, v_scale, v_zp) with int8 payloads and ONE
 #   (scale, zero-point) pair per page row (= per cached token, shared
-#   across heads) stored page-resident beside the payload — copy-on-write
-#   and sharing move the scales with their page, and dequantization fuses
-#   into the attention gather.
+#   across heads) stored page-resident [L, n_pages, page_size] beside the
+#   payload — copy-on-write and sharing move the scales with their page,
+#   and dequantization fuses into the attention gather. Pages stay at axis
+#   1 of every component, so everything that deals in page indices
+#   (paged_copy, allocator, prefix cache, replica seeding, host tier)
+#   never sees the row layout.
 
 
 # Device scopes of the paged path: ``jax.named_scope`` names that every op of
@@ -588,8 +604,7 @@ def chunk_prefill(
 # docs/observability.md "Reading a device trace" key on these strings.
 SCOPE_EMBED = "embed"  # token + position embedding lookup
 SCOPE_QKV = "qkv"  # ln1, the fused q/k/v projection, head split
-SCOPE_KV_WRITE = "kv_write"  # the scatter of new K/V through the block tables
-SCOPE_POOL_RESTACK = "pool_restack"  # per-layer pool slice a[li] and the closing jnp.stack
+SCOPE_KV_WRITE = "kv_write"  # the in-place scatter of new K/V rows through the block tables
 SCOPE_KV_GATHER = "kv_gather"  # page gather into the virtual contiguous cache
 SCOPE_ATTN = "attn"  # scores, mask, softmax, context
 SCOPE_ATTN_OUT = "attn_out"  # output projection + residual
@@ -597,8 +612,8 @@ SCOPE_MLP = "mlp"  # ln2, mlp_in, gelu, mlp_out + residual
 SCOPE_LM_HEAD = "lm_head"  # ln_f + vocabulary projection
 SCOPE_SAMPLE = "sample"  # key derivation, last-position pick, sampling (the fused programs)
 PAGED_SCOPES = (
-    SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_POOL_RESTACK, SCOPE_KV_GATHER,
-    SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_MLP, SCOPE_LM_HEAD, SCOPE_SAMPLE,
+    SCOPE_EMBED, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_KV_GATHER, SCOPE_ATTN,
+    SCOPE_ATTN_OUT, SCOPE_MLP, SCOPE_LM_HEAD, SCOPE_SAMPLE,
 )
 
 
@@ -607,7 +622,7 @@ def paged_kv_init(
 ) -> tuple:
     """Zeroed page pool state tuple (see module comment for the layout)."""
     d = decoder_dims(params)
-    shape = (d["layers"], n_pages, d["heads"], page_size, d["head_dim"])
+    shape = (d["layers"], n_pages, page_size, d["heads"] * d["head_dim"])
     if kv_dtype == "int8":
         sshape = (d["layers"], n_pages, page_size)
         # scale 1 / zp 0: dequantized junk pages read back as exact zeros,
@@ -633,28 +648,28 @@ def paged_copy(pool: tuple, src: jax.Array, dst: jax.Array) -> tuple:
 
 
 def _quant_rows(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-row asymmetric int8: x[N, h, hd] -> (q[N, h, hd] int8, scale[N],
-    zp[N]) with q = round((x - zp) / scale) in [-127, 127]."""
-    lo = jnp.min(x, axis=(1, 2))
-    hi = jnp.max(x, axis=(1, 2))
+    """Per-row asymmetric int8: x[N, w] -> (q[N, w] int8, scale[N], zp[N])
+    with q = round((x - zp) / scale) in [-127, 127]; a row is one token's
+    K (or V) across all heads."""
+    lo = jnp.min(x, axis=1)
+    hi = jnp.max(x, axis=1)
     zp = (hi + lo) * 0.5
     scale = jnp.maximum((hi - lo) / 254.0, 1e-8)
-    q = jnp.clip(
-        jnp.round((x - zp[:, None, None]) / scale[:, None, None]), -127, 127
-    ).astype(jnp.int8)
+    q = jnp.clip(jnp.round((x - zp[:, None]) / scale[:, None]), -127, 127).astype(jnp.int8)
     return q, scale, zp
 
 
 @jax.named_scope(SCOPE_KV_WRITE)
-def _paged_write(kv: tuple, k, v, bt, positions, counts):
-    """Scatter the dispatch's new K/V (k, v: [n, h, m, hd], slot i's entry j
-    at positions[i] + j) into the per-layer pool slices through the block
-    tables. Invalid entries — beyond counts[i], or past the virtual length
-    — are redirected to junk page 0 instead of masked in place, which is
-    what lets free/prefilling slots ride static-shape dispatches without
-    owning writable pages."""
-    n, h, m, hd = k.shape
-    ps = kv[0].shape[2]
+def _paged_write(pool: tuple, li: int, k, v, bt, positions, counts):
+    """Scatter the dispatch's new K/V rows (k, v: [n, m, h*hd], slot i's
+    entry j at positions[i] + j) into layer ``li`` of the WHOLE pool
+    through the block tables — one in-place update at (li, page, row) per
+    component. Invalid entries — beyond counts[i], or past the virtual
+    length — are redirected to junk page 0 instead of masked in place,
+    which is what lets free/prefilling slots ride static-shape dispatches
+    without owning writable pages."""
+    n, m, w = k.shape
+    ps = pool[0].shape[2]
     n_log = bt.shape[1]
     gp = positions[:, None] + jnp.arange(m)[None, :]  # [n, m] global positions
     lp = jnp.clip(gp // ps, 0, n_log - 1)
@@ -665,66 +680,61 @@ def _paged_write(kv: tuple, k, v, bt, positions, counts):
     phys = jnp.where(ok, phys, 0)
     pf = phys.reshape(-1)
     of = (gp % ps).reshape(-1)
-    kt = k.transpose(0, 2, 1, 3).reshape(n * m, h, hd)  # per-token rows
-    vt = v.transpose(0, 2, 1, 3).reshape(n * m, h, hd)
-    if len(kv) == 2:
-        pk, pv = kv
+    kt = k.reshape(n * m, w)  # per-token rows
+    vt = v.reshape(n * m, w)
+    if len(pool) == 2:
+        pk, pv = pool
         return (
-            pk.at[pf, :, of, :].set(kt.astype(pk.dtype)),
-            pv.at[pf, :, of, :].set(vt.astype(pv.dtype)),
+            pk.at[li, pf, of].set(kt.astype(pk.dtype)),
+            pv.at[li, pf, of].set(vt.astype(pv.dtype)),
         )
-    kq, sk, zk, vq, sv, zv = kv
+    kq, sk, zk, vq, sv, zv = pool
     qk, sck, zpk = _quant_rows(kt.astype(jnp.float32))
     qv, scv, zpv = _quant_rows(vt.astype(jnp.float32))
     return (
-        kq.at[pf, :, of, :].set(qk),
-        sk.at[pf, of].set(sck),
-        zk.at[pf, of].set(zpk),
-        vq.at[pf, :, of, :].set(qv),
-        sv.at[pf, of].set(scv),
-        zv.at[pf, of].set(zpv),
+        kq.at[li, pf, of].set(qk),
+        sk.at[li, pf, of].set(sck),
+        zk.at[li, pf, of].set(zpk),
+        vq.at[li, pf, of].set(qv),
+        sv.at[li, pf, of].set(scv),
+        zv.at[li, pf, of].set(zpv),
     )
 
 
 @jax.named_scope(SCOPE_KV_GATHER)
-def _paged_gather(kv: tuple, bt) -> tuple[jax.Array, jax.Array]:
-    """Gather each slot's pages into a virtual contiguous cache
-    [n, h, max_pages * page_size, hd] in f32 (the flat path's attention
-    accumulation dtype). int8 mode fuses the per-page-row dequant here."""
-    if len(kv) == 2:
-        k = jnp.take(kv[0], bt, axis=0).astype(jnp.float32)  # [n, P, h, ps, hd]
-        v = jnp.take(kv[1], bt, axis=0).astype(jnp.float32)
+def _paged_gather(pool: tuple, li: int, bt, h: int) -> tuple[jax.Array, jax.Array]:
+    """Gather each slot's pages of layer ``li`` out of the whole pool into
+    a virtual contiguous cache [n, h, max_pages * page_size, hd] in f32
+    (the flat path's attention accumulation dtype): whole token rows come
+    out, and the head split is a reshape + transpose of the GATHERED rows
+    (a layout the TPU compiler assigns to the scores' operand, not a copy;
+    the CPU backend keeps the flat path's reduction order, hence its
+    bits). int8 mode fuses the per-page-row dequant here."""
+    if len(pool) == 2:
+        k = pool[0][li, bt].astype(jnp.float32)  # [n, P, ps, h*hd]
+        v = pool[1][li, bt].astype(jnp.float32)
     else:
-        kq, sk, zk, vq, sv, zv = kv
-        k = jnp.take(kq, bt, axis=0).astype(jnp.float32)
-        v = jnp.take(vq, bt, axis=0).astype(jnp.float32)
-        k = k * jnp.take(sk, bt, axis=0)[:, :, None, :, None] + jnp.take(
-            zk, bt, axis=0
-        )[:, :, None, :, None]
-        v = v * jnp.take(sv, bt, axis=0)[:, :, None, :, None] + jnp.take(
-            zv, bt, axis=0
-        )[:, :, None, :, None]
-    n, p, h, ps, hd = k.shape
-    k = k.transpose(0, 2, 1, 3, 4).reshape(n, h, p * ps, hd)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(n, h, p * ps, hd)
-    return k, v
+        kq, sk, zk, vq, sv, zv = pool
+        k = kq[li, bt].astype(jnp.float32) * sk[li, bt][..., None] + zk[li, bt][..., None]
+        v = vq[li, bt].astype(jnp.float32) * sv[li, bt][..., None] + zv[li, bt][..., None]
+    n, p, ps, w = k.shape
+    return _split_heads(k.reshape(n, p * ps, w), h), _split_heads(v.reshape(n, p * ps, w), h)
 
 
-def _layer_step_paged(p, x, kv, bt, positions, h, counts=None):
+def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None):
     """_layer_step_slots reworked onto the page pool: same math, but the
-    new K/V scatters through the block tables first and attention reads
-    the pool back through a page gather (so in-dispatch queries see the
-    keys earlier queries of the same dispatch just wrote, exactly like the
-    flat path's write-then-read). Returns (x_out, new per-layer kv)."""
+    new K/V rows scatter through the block tables into layer ``li`` of the
+    pool first and attention reads them back through a page gather (so
+    in-dispatch queries see the keys earlier queries of the same dispatch
+    just wrote, exactly like the flat path's write-then-read). Returns
+    (x_out, new pool)."""
     with jax.named_scope(SCOPE_QKV):
         normed = _ln(p["ln1"], x)
         qkv = normed @ p["qkv"]["w"].astype(x.dtype) + p["qkv"]["b"].astype(x.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = jnp.split(qkv, 3, axis=-1)  # k, v stay token rows [n, m, h*hd]
         q = _split_heads(q, h)  # [n, h, m, hd]
-        k = _split_heads(k, h)
-        v = _split_heads(v, h)
-    kv = _paged_write(kv, k, v, bt, positions, counts)
-    cache_k, cache_v = _paged_gather(kv, bt)  # f32 virtual caches
+    pool = _paged_write(pool, li, k, v, bt, positions, counts)
+    cache_k, cache_v = _paged_gather(pool, li, bt, h)  # f32 virtual caches
     with jax.named_scope(SCOPE_ATTN):
         scale = 1.0 / (q.shape[-1] ** 0.5)
         s = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32), cache_k) * scale
@@ -744,7 +754,7 @@ def _layer_step_paged(p, x, kv, bt, positions, h, counts=None):
             approximate=False,
         )
         x = x + hdn @ p["mlp_out"]["w"].astype(x.dtype) + p["mlp_out"]["b"].astype(x.dtype)
-    return x, kv
+    return x, pool
 
 
 def _paged_forward(params, pool, bt, tokens, positions, counts=None):
@@ -754,9 +764,11 @@ def _paged_forward(params, pool, bt, tokens, positions, counts=None):
     residual-stream output (pre-``ln_f``), the per-position FEATURE an
     EAGLE-style draft head conditions on (data-only: same static shapes,
     and XLA dead-code-eliminates the extra output inside fused programs
-    that drop it). Junk queries clip the position table like the flat
-    verify/chunk paths — their logits are never read and their writes are
-    junk-redirected."""
+    that drop it). The pool tuple threads through the layers whole: each
+    layer's write is a scatter into it, so a caller that donates the pool
+    gets it updated in place. Junk queries clip the position table like
+    the flat verify/chunk paths — their logits are never read and their
+    writes are junk-redirected."""
     heads = _heads(params)
     m = tokens.shape[1]
     max_len = params["pos_emb"].shape[0]
@@ -764,18 +776,11 @@ def _paged_forward(params, pool, bt, tokens, positions, counts=None):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
         pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
         x = x + jnp.asarray(params["pos_emb"])[pidx]
-    per_comp: list[list] = [[] for _ in pool]
     for li, lp in enumerate(params["layers"]):
-        with jax.named_scope(SCOPE_POOL_RESTACK):
-            layer_kv = tuple(a[li] for a in pool)
-        x, layer_kv = _layer_step_paged(lp, x, layer_kv, bt, positions, heads, counts)
-        for acc, a in zip(per_comp, layer_kv):
-            acc.append(a)
+        x, pool = _layer_step_paged(lp, x, pool, li, bt, positions, heads, counts)
     with jax.named_scope(SCOPE_LM_HEAD):
         logits = _logits(params, x)  # [n, m, vocab]
-    with jax.named_scope(SCOPE_POOL_RESTACK):
-        new_pool = tuple(jnp.stack(acc) for acc in per_comp)
-    return logits, x, new_pool
+    return logits, x, pool
 
 
 def paged_decode_step(params, pool, bt, tokens, positions):
@@ -1095,7 +1100,7 @@ def draft_propose_tree(
     )
 
 
-def _layer_tree_paged(p, x, kv, bt, positions, h, mask):
+def _layer_tree_paged(p, x, pool, li, bt, positions, h, mask):
     """One layer of the widened TARGET tree verify over the page pool:
     all ``width`` blocks at once, attention over the gathered cache
     (entries strictly before ``pos`` — nothing speculative lives there)
@@ -1108,35 +1113,33 @@ def _layer_tree_paged(p, x, kv, bt, positions, h, mask):
     K/V for the commit."""
     normed = _ln(p["ln1"], x)
     qkv = normed @ p["qkv"]["w"].astype(x.dtype) + p["qkv"]["b"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    q, k_rows, v_rows = jnp.split(qkv, 3, axis=-1)  # [n, m, h*hd] each
     q = _split_heads(q, h)  # [n, h, m, hd]
-    k = _split_heads(k, h)
-    v = _split_heads(v, h)
-    n, hh, m, hd = k.shape
-    if len(kv) == 6:
+    k = _split_heads(k_rows, h)
+    v = _split_heads(v_rows, h)
+    if len(pool) == 6:
         # int8 pool: quantize-dequantize the in-block K/V per token row —
         # the exact transform _paged_write/_paged_gather would apply
-        def _rt(t):
-            rows = t.transpose(0, 2, 1, 3).reshape(n * m, hh, hd).astype(jnp.float32)
-            qr, sc, zp = _quant_rows(rows)
-            deq = qr.astype(jnp.float32) * sc[:, None, None] + zp[:, None, None]
-            return deq.reshape(n, m, hh, hd).transpose(0, 2, 1, 3)
+        def _rt(rows):
+            qr, sc, zp = _quant_rows(rows.reshape(-1, rows.shape[-1]).astype(jnp.float32))
+            deq = qr.astype(jnp.float32) * sc[:, None] + zp[:, None]
+            return _split_heads(deq.reshape(rows.shape), h)
 
-        k_att, v_att = _rt(k), _rt(v)
+        k_att, v_att = _rt(k_rows), _rt(v_rows)
     else:
         # fp pool: round-trip through the pool dtype (no-op at float32)
-        k_att = k.astype(kv[0].dtype).astype(jnp.float32)
-        v_att = v.astype(kv[0].dtype).astype(jnp.float32)
-    cache_k, cache_v = _paged_gather(kv, bt)  # f32 virtual caches
+        k_att = k.astype(pool[0].dtype).astype(jnp.float32)
+        v_att = v.astype(pool[0].dtype).astype(jnp.float32)
+    cache_k, cache_v = _paged_gather(pool, li, bt, h)  # f32 virtual caches
     scale = 1.0 / (q.shape[-1] ** 0.5)
     qf = q.astype(jnp.float32)
     s_cache = jnp.einsum("nhqd,nhkd->nhqk", qf, cache_k) * scale
-    valid = jnp.arange(cache_k.shape[2])[None, None, None, :] < positions[:, None, None, None]
+    c_len = cache_k.shape[2]
+    valid = jnp.arange(c_len)[None, None, None, :] < positions[:, None, None, None]
     s_cache = jnp.where(valid, s_cache, -1e30)
     s_blk = jnp.einsum("nhqd,nhkd->nhqk", qf, k_att) * scale
     s_blk = jnp.where(jnp.asarray(mask)[None, None, :, :], s_blk, -1e30)
     p_attn = jax.nn.softmax(jnp.concatenate([s_cache, s_blk], axis=-1), axis=-1)
-    c_len = cache_k.shape[2]
     ctx = jnp.einsum("nhqk,nhkd->nhqd", p_attn[..., :c_len], cache_v) + jnp.einsum(
         "nhqk,nhkd->nhqd", p_attn[..., c_len:], v_att
     )
@@ -1176,8 +1179,7 @@ def paged_tree_verify(
     mask = tree.ancestor_mask
     nk, nv = [], []
     for li, lp in enumerate(params["layers"]):
-        layer_kv = tuple(a[li] for a in pool)
-        x, k, v = _layer_tree_paged(lp, x, layer_kv, bt, positions, heads, mask)
+        x, k, v = _layer_tree_paged(lp, x, pool, li, bt, positions, heads, mask)
         nk.append(k)
         nv.append(v)
     logits = _logits(params, x)  # [n, width, V]
@@ -1201,14 +1203,12 @@ def paged_tree_commit(
     k_sel = jnp.take_along_axis(new_k, idx, axis=3)  # [L, n, h, D+1, hd]
     v_sel = jnp.take_along_axis(new_v, idx, axis=3)
     counts = n_acc + 1
-    per_comp: list[list] = [[] for _ in pool]
     for li in range(L):
-        layer_kv = _paged_write(
-            tuple(a[li] for a in pool), k_sel[li], v_sel[li], bt, positions, counts
+        pool = _paged_write(
+            pool, li, _merge_heads(k_sel[li]), _merge_heads(v_sel[li]),
+            bt, positions, counts,
         )
-        for acc, a in zip(per_comp, layer_kv):
-            acc.append(a)
-    return tuple(jnp.stack(acc) for acc in per_comp)
+    return pool
 
 
 def draft_tree_commit(

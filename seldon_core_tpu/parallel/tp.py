@@ -8,12 +8,13 @@ turn those dispatches into SPMD programs over a named device mesh
 low-latency decode partitioning of Pope et al., *Efficiently Scaling
 Transformer Inference* (2022):
 
-- **attention sharded on the head axis**: the paged KV pool
-  ``[L, n_pages, h, page_size, hd]``, the draft's flat slot cache
-  ``[L, n_slots, h, ctx, hd]``, and every per-head attention tensor
-  carry ``h`` split over the mesh axis — each device runs its heads'
-  scores/softmax/context entirely locally (per-head attention has no
-  cross-head reduction);
+- **attention sharded on the head axis**: the paged KV pool of token
+  rows ``[L, n_pages, page_size, h*hd]`` (its last axis split into
+  ``tp`` contiguous blocks is ``h/tp`` whole heads each), the draft's
+  flat slot cache ``[L, n_slots, h, ctx, hd]``, and every per-head
+  attention tensor carry ``h`` split over the mesh axis — each device
+  runs its heads' scores/softmax/context entirely locally (per-head
+  attention has no cross-head reduction);
 - **FFN sharded on the hidden axis**: ``mlp_in`` column-parallel
   (output ``ffn`` axis), ``mlp_out`` row-parallel (input ``ffn`` axis);
 - **row-parallel output projections**: ``attn_out``'s input axis is
@@ -179,12 +180,17 @@ def decoder_param_shardings(params: dict, mesh: Mesh, axis: str):
 
 
 def kv_sharding(mesh: Mesh, axis: str, arr) -> NamedSharding:
-    """Sharding for one KV-cache buffer: the 5-D layouts — page pool
-    ``[L, n_pages, h, page_size, hd]`` and flat slot cache
-    ``[L, n_slots, h, ctx, hd]`` — both carry heads at axis 2 and shard
-    there; everything else (int8 scale/zero-point planes, which have no
-    head axis) replicates."""
-    if getattr(arr, "ndim", 0) == 5:
+    """Sharding for one KV-cache buffer, told apart by rank: the 4-D paged
+    payload ``[L, n_pages, page_size, h*hd]`` shards its last axis (a
+    token row split into ``tp`` contiguous blocks is ``h/tp`` whole heads
+    each — ``decode_mesh_problems`` rejects ``h % tp != 0``); the 5-D flat
+    slot cache ``[L, n_slots, h, ctx, hd]`` carries heads at axis 2 and
+    shards there; everything else (the 3-D int8 scale/zero-point planes,
+    which have no head axis) replicates."""
+    ndim = getattr(arr, "ndim", 0)
+    if ndim == 4:
+        return NamedSharding(mesh, P(None, None, None, axis))
+    if ndim == 5:
         return NamedSharding(mesh, P(None, None, axis, None, None))
     return NamedSharding(mesh, P())
 

@@ -17,11 +17,13 @@ scheduling over a vLLM-style PAGED KV pool into the stack:
 - Tokens stream to the caller as they are chosen (``on_token``), which is
   what the fast ingress's SSE endpoint forwards to clients.
 - Paged KV memory (serving/kv_pool.py): K/V lives in ONE device-resident
-  page pool ``[L, n_pages, h, page_size, hd]`` shared by live slots and
-  the prefix cache; each slot carries a static-shape block table and the
-  attention programs gather through it (vLLM's PagedAttention memory
-  model). Slot memory stops being ``n_slots * max_ctx`` worst-case: a
-  host-side allocator tracks per-page refcounts, copies-on-write at the
+  page pool of token rows ``[L, n_pages, page_size, h*hd]`` shared by
+  live slots and the prefix cache; each slot carries a static-shape block
+  table and the attention programs gather through it (vLLM's
+  PagedAttention memory model). The fused programs take the pool donated
+  and write it in place — one scatter per layer into the whole array.
+  Slot memory stops being ``n_slots * max_ctx`` worst-case: a host-side
+  allocator tracks per-page refcounts, copies-on-write at the
   first divergent write into a shared page, reclaims unreferenced prefix
   pages LRU-first, and admits sequences against a reservation invariant
   instead of deadlocking when an explicit ``tpu.decode_kv_pages`` budget
@@ -2249,7 +2251,8 @@ class DecodeScheduler:
         """Per-shard audit of the device pools on a decode mesh (the soak
         harness runs this beside the allocator's host-side ``check()``):
         every pool/draft-cache component must be laid out across exactly
-        the mesh devices, 5-D payloads carrying heads/tp per shard and
+        the mesh devices, the payloads carrying heads/tp per shard (the
+        4-D pool's token-row axis, the 5-D draft cache's head axis) and
         replicated components full-size. Raises AssertionError on any
         divergence; returns a small report dict."""
         if self.mesh is None:
@@ -2269,11 +2272,12 @@ class DecodeScheduler:
                     f"{name}: shards on {len(devs)} devices, mesh has "
                     f"{len(mesh_devices)}"
                 )
-            want = list(arr.shape)
-            if arr.ndim == 5:
-                if want[2] % self.tp:
-                    raise AssertionError(f"{name}: head axis {want[2]} % tp != 0")
-                want[2] //= self.tp
+            try:  # the ONE rule of which axis carries heads
+                want = list(
+                    kv_sharding(self.mesh, self._tp_axis, arr).shard_shape(arr.shape)
+                )
+            except ValueError as e:
+                raise AssertionError(f"{name}: head axis % tp != 0 ({e})") from e
             for s in arr.addressable_shards:
                 if list(s.data.shape) != want:
                     raise AssertionError(

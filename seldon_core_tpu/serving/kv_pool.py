@@ -6,10 +6,12 @@ reader's slot row — HBM, not compute, capped concurrent users per chip.
 This module replaces both with vLLM-style block-table paging (Kwon et al.,
 SOSP 2023):
 
-- ONE device-resident page pool ``[L, n_pages, h, page_size, hd]`` that
-  live slots AND the prefix cache allocate from (models/decoder.py
-  ``paged_kv_init`` / ``paged_copy`` own the device layout; the paged
-  attention programs gather K/V through per-slot block tables);
+- ONE device-resident page pool of token rows ``[L, n_pages, page_size,
+  h*hd]`` that live slots AND the prefix cache allocate from
+  (models/decoder.py ``paged_kv_init`` / ``paged_copy`` own the device
+  layout; the paged attention programs gather K/V through per-slot block
+  tables and scatter new rows into the donated pool in place). This
+  module deals in page indices — axis 1 of every component — only;
 - a host-side allocator (``PageAllocator``): free list, per-page
   refcounts, copy-on-write on the first divergent write into a shared
   page, and LRU reclaim of prefix pins when the free list runs dry;

@@ -217,6 +217,116 @@ def test_paged_blocks_match_flat_chunk_and_decode_logits():
     )
 
 
+# ------------------------------------------------ the token-row pool layout
+
+
+def _np_quant_rows(x):
+    """The per-row asymmetric int8 quantiser, in plain numpy float32."""
+    lo, hi = x.min(axis=1), x.max(axis=1)
+    zp = (hi + lo) * np.float32(0.5)
+    scale = np.maximum((hi - lo) / np.float32(254.0), np.float32(1e-8))
+    q = np.clip(np.round((x - zp[:, None]) / scale[:, None]), -127, 127).astype(np.int8)
+    return q, scale, zp
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_paged_write_then_gather_round_trips_against_a_numpy_page_table(kv_dtype):
+    """What one layer's write stores and its gather reads back, held to a
+    plain numpy page table (a dict of token rows by (page, row)): a run
+    crossing a page boundary, a ``counts`` mask, rows past the virtual
+    length (both junk-redirected to page 0), unmapped table entries. Same
+    values, bit for bit, as the head-major pool stored — only where they
+    sit changed — and no other layer of the pool is touched."""
+    from seldon_core_tpu.models.decoder import (
+        _paged_gather, _paged_write, decoder_dims, paged_kv_init,
+    )
+
+    params = _params()
+    d = decoder_dims(params)
+    h, w = d["heads"], d["heads"] * d["head_dim"]
+    n, m, ps, pps, li = 3, 5, 4, 3, 1
+    n_pages = 1 + n * pps
+    rng = np.random.default_rng(7)
+    bt = np.arange(1, n_pages, dtype=np.int32).reshape(n, pps)
+    bt[1, 2] = 0  # an unmapped tail entry: reads the junk page
+    positions = np.array([2, 0, pps * ps - 2], np.int32)  # slot 2 runs off the end
+    counts = np.array([m, 2, m], np.int32)  # slot 1 persists two rows only
+    k = rng.standard_normal((n, m, w)).astype(np.float32)
+    v = rng.standard_normal((n, m, w)).astype(np.float32)
+
+    pool = _paged_write(
+        paged_kv_init(params, n_pages, ps, kv_dtype=kv_dtype), li,
+        jnp.asarray(k), jnp.asarray(v), jnp.asarray(bt), jnp.asarray(positions),
+        jnp.asarray(counts),
+    )
+    got_k, got_v = (np.asarray(a) for a in _paged_gather(pool, li, jnp.asarray(bt), h))
+    assert got_k.shape == (n, h, pps * ps, d["head_dim"])
+
+    table_k, table_v = {}, {}  # (page, row) -> the token row read back
+    for i in range(n):
+        for j in range(int(counts[i])):
+            pos = int(positions[i]) + j
+            if pos >= pps * ps or bt[i, pos // ps] == 0:
+                continue  # junk-redirected
+            for table, src in ((table_k, k), (table_v, v)):
+                row = src[i, j]
+                if kv_dtype == "int8":
+                    q, sc, zp = _np_quant_rows(row[None, :])
+                    row = (q.astype(np.float32) * sc[:, None] + zp[:, None])[0]
+                table[(int(bt[i, pos // ps]), pos % ps)] = row
+    assert len(table_k) == 5 + 2 + 2
+    for i in range(n):
+        for pos in range(pps * ps):
+            page = int(bt[i, pos // ps])
+            if page == 0:
+                continue  # the junk sink holds whatever was redirected
+            for table, got in ((table_k, got_k), (table_v, got_v)):
+                want = table.get((page, pos % ps), np.zeros(w, np.float32))
+                np.testing.assert_array_equal(got[i, :, pos, :].reshape(w), want)
+    for comp in pool:  # every other layer is still its init
+        others = np.delete(np.asarray(comp), li, axis=0)
+        assert np.all(others == others.flat[0])
+    if kv_dtype == "int8":
+        assert pool[0].dtype == jnp.int8 and pool[0].shape == (d["layers"], n_pages, ps, w)
+        assert pool[1].shape == (d["layers"], n_pages, ps)
+        assert np.abs(got_k[0, :, 2, :].reshape(w) - k[0, 0]).max() > 0  # it IS quantized
+    else:
+        assert pool[0].dtype == jnp.float32 and pool[0].shape == (d["layers"], n_pages, ps, w)
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_paged_copy_moves_a_pages_rows_and_planes_together(kv_dtype):
+    """Copy-on-write's primitive deals in page indices only: every layer's
+    token rows of the source page, and in int8 mode their scale and
+    zero-point planes, land in the destination page; no other page moves."""
+    from seldon_core_tpu.models.decoder import (
+        _paged_write, decoder_dims, paged_copy, paged_kv_init,
+    )
+
+    params = _params()
+    d = decoder_dims(params)
+    w = d["heads"] * d["head_dim"]
+    ps, n_pages = 4, 6
+    rng = np.random.default_rng(9)
+    pool = paged_kv_init(params, n_pages, ps, kv_dtype=kv_dtype)
+    bt = jnp.asarray([[1, 2]], jnp.int32)
+    for li in range(d["layers"]):
+        rows = jnp.asarray(rng.standard_normal((1, 2 * ps, w)).astype(np.float32))
+        pool = _paged_write(pool, li, rows, -rows, bt, jnp.zeros(1, jnp.int32), None)
+    before = [np.asarray(a) for a in pool]
+    after = [
+        np.asarray(a)
+        for a in paged_copy(pool, jnp.asarray([2, 0], jnp.int32), jnp.asarray([4, 0], jnp.int32))
+    ]
+    assert len(after) == (6 if kv_dtype == "int8" else 2)
+    for b, a in zip(before, after):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(a[:, 4], b[:, 2])
+        assert np.any(a[:, 4] != b[:, 4])  # the destination did change
+        keep = [0, 1, 2, 3, 5]
+        np.testing.assert_array_equal(a[:, keep], b[:, keep])
+
+
 # ------------------------------------------------ scheduler over the pool
 
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -129,6 +130,44 @@ def test_fused_paged_decode_step_compiles_on_the_int8_pool(topo):
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
 
+def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo):
+    """The benchmark cell's pool geometry (gpt2-large: 20 heads of 64, 720
+    pages of 16, 16 slots of 44 pages, float32; 8 of its 36 layers, shapes
+    only): the step's per-layer scatter lands in the donated pool itself.
+    The head-major layout this replaced compiled to a layout copy of one
+    layer's pool before every scatter and a restack of the whole pool —
+    three pools' worth of temporaries. Eight layers, not four: the gathered
+    virtual cache of one layer (16 slots x 704 positions x 1280 x K and V,
+    0.11 GiB) is the floor of the temporaries whatever the depth, and only
+    from eight layers up is a quarter of the pool above it."""
+    from seldon_core_tpu.models.decoder import init_decoder
+    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(
+        lambda: init_decoder(0, vocab=50257, hidden=1280, layers=8, ffn=5120, max_len=1024)
+    )
+    geo = {"n_slots": 16, "n_pages": 720, "page_size": 16, "pages_per_slot": 44}
+    p, pool, rest = _step_args(
+        params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
+    )
+    compiled = jax.jit(_fused_step, donate_argnums=(1,)).lower(p, pool, *rest).compile()
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in pool)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 4
+    # no copy of the pool, or of one layer of it, in the entry computation
+    entry = re.search(r"ENTRY [^{]*\{(.*?)\n\}", compiled.as_text(), re.S).group(1)
+    ops = [
+        (op, int(np.prod([int(d) for d in dims.split(",") if d])))
+        for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", entry)
+    ]
+    pool_elems = int(np.prod(pool[0].shape))
+    sized = [op for op, n in ops if n in (pool_elems, pool_elems // pool[0].shape[0])]
+    assert "fusion" in sized  # the scatters' own fusions: the pattern reads the text
+    assert not [op for op in sized if op.startswith("copy")], sized
+
+
 def test_tp4_sharded_decode_step_compiles(topo):
     from seldon_core_tpu.parallel.tp import decoder_param_shardings, kv_sharding
     from seldon_core_tpu.serving.decode_scheduler import _fused_step
@@ -148,10 +187,12 @@ def test_tp4_sharded_decode_step_compiles(topo):
     )
     # Megatron/Pope: each residual branch ends in an all-reduce
     assert "all-reduce" in compiled.as_text()
-    # the page pool is head-sharded: a device holds a quarter of it (in the
-    # TPU's (8, 128) float32 tiling, which pads head_dim 64 to 128 lanes)
-    head_dim = pool[0].shape[-1]
-    padded = sum(
-        int(np.prod(s.shape)) // head_dim * 128 * s.dtype.itemsize for s in pool
+    # the page pool is head-sharded: a device holds a quarter of every token
+    # row (in the TPU's (8, 128) float32 tiling, which pads the smoke
+    # geometry's one-head shard of 64 lanes to 128)
+    shard_w = pool[0].shape[-1] // 4
+    padded_w = -(-shard_w // 128) * 128
+    per_device = sum(
+        int(np.prod(s.shape[:-1])) * padded_w * s.dtype.itemsize for s in pool
     )
-    assert compiled.memory_analysis().alias_size_in_bytes == padded // 4
+    assert compiled.memory_analysis().alias_size_in_bytes == per_device

@@ -259,7 +259,7 @@ def test_compiled_fused_programs_carry_every_scope(program):
         args = (params, pool, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1))
         fn = ds._fused_chunk
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert len(set(decoder.PAGED_SCOPES)) == len(decoder.PAGED_SCOPES) == 10
+    assert len(set(decoder.PAGED_SCOPES)) == len(decoder.PAGED_SCOPES) == 9
     for scope in decoder.PAGED_SCOPES:
         assert f"/{scope}/" in text, scope
 
