@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from seldon_core_tpu.ops.paged_attention import paged_attention_decode, slot_lengths
+
 
 def _dense(rng: np.random.Generator, n_in: int, n_out: int) -> dict:
     scale = (2.0 / (n_in + n_out)) ** 0.5
@@ -625,8 +627,8 @@ def chunk_prefill(
 SCOPE_EMBED = "embed"  # token + position embedding lookup
 SCOPE_QKV = "qkv"  # ln1, the fused q/k/v projection, head split
 SCOPE_KV_WRITE = "kv_write"  # the in-place scatter of new K/V rows through the block tables
-SCOPE_KV_GATHER = "kv_gather"  # page gather into the virtual contiguous cache
-SCOPE_ATTN = "attn"  # scores, mask, softmax, context
+SCOPE_KV_GATHER = "kv_gather"  # page gather into the virtual contiguous cache (kernel path: the slots' lengths)
+SCOPE_ATTN = "attn"  # scores, mask, softmax, context (kernel path: the paged-attention kernel, fetches included)
 SCOPE_ATTN_OUT = "attn_out"  # output projection + residual
 SCOPE_MLP = "mlp"  # ln2, mlp_in, gelu, mlp_out + residual
 SCOPE_LM_HEAD = "lm_head"  # ln_f + vocabulary projection
@@ -748,30 +750,54 @@ def _paged_gather(pool: tuple, li: int, bt, h: int) -> tuple[jax.Array, jax.Arra
     return _split_heads(k.reshape(n, p * ps, w), h), _split_heads(v.reshape(n, p * ps, w), h)
 
 
-def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None):
+def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None, attn_kernel=""):
     """_layer_step_slots reworked onto the page pool: same math, but the
     new K/V rows scatter through the block tables into layer ``li`` of the
-    pool first and attention reads them back through a page gather (so
-    in-dispatch queries see the keys earlier queries of the same dispatch
-    just wrote, exactly like the flat path's write-then-read). Returns
-    (x_out, new pool)."""
+    pool first and attention reads them back (so in-dispatch queries see
+    the keys earlier queries of the same dispatch just wrote, exactly like
+    the flat path's write-then-read). Two read sides:
+
+    - a page gather into a float32 virtual cache and the flat path's
+      attention over it — every dispatch but the fused decode step on a
+      TPU, and the oracle that is bit-identical to the flat path;
+    - where ``attn_kernel`` (static; "" | "mosaic" | "interpret") asks for
+      it AND the dispatch has one query a slot against the two-component
+      float pool: the Pallas decode kernel (ops/paged_attention.py), which
+      takes the WHOLE pool and ``li`` — no per-layer slice — and fetches
+      only the pages each slot's length covers. What is left of the gather
+      there, the lengths (one fusion for all layers once the compiler has
+      merged them), stays under the ``kv_gather`` scope.
+
+    Returns (x_out, new pool)."""
+    kernel = bool(attn_kernel) and x.shape[1] == 1 and len(pool) == 2
     with jax.named_scope(SCOPE_QKV):
         normed = _ln(p["ln1"], x)
         qkv = normed @ p["qkv"]["w"].astype(x.dtype) + p["qkv"]["b"].astype(x.dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)  # k, v stay token rows [n, m, h*hd]
-        q = _split_heads(q, h)  # [n, h, m, hd]
+        if not kernel:
+            q = _split_heads(q, h)  # [n, h, m, hd]
     pool = _paged_write(pool, li, k, v, bt, positions, counts)
-    cache_k, cache_v = _paged_gather(pool, li, bt, h)  # f32 virtual caches
-    with jax.named_scope(SCOPE_ATTN):
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32), cache_k) * scale
-        m = x.shape[1]
-        q_pos = positions[:, None] + jnp.arange(m)[None, :]  # [n, m]
-        valid = jnp.arange(cache_k.shape[2])[None, None, :] <= q_pos[:, :, None]
-        s = jnp.where(valid[:, None, :, :], s, -1e30)
-        p_attn = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("nhqk,nhkd->nhqd", p_attn, cache_v)
-        ctx = _merge_heads(ctx.astype(x.dtype))
+    if kernel:
+        with jax.named_scope(SCOPE_KV_GATHER):
+            lengths = slot_lengths(positions, pool[0].shape[2], bt.shape[1])
+        with jax.named_scope(SCOPE_ATTN):
+            scale = 1.0 / ((q.shape[-1] // h) ** 0.5)
+            ctx = paged_attention_decode(
+                q[:, 0, :].astype(jnp.float32) * scale, pool[0], pool[1], li, bt,
+                lengths, heads=h, interpret=attn_kernel == "interpret",
+            )[:, None, :].astype(x.dtype)
+    else:
+        cache_k, cache_v = _paged_gather(pool, li, bt, h)  # f32 virtual caches
+        with jax.named_scope(SCOPE_ATTN):
+            scale = 1.0 / (q.shape[-1] ** 0.5)
+            s = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32), cache_k) * scale
+            m = x.shape[1]
+            q_pos = positions[:, None] + jnp.arange(m)[None, :]  # [n, m]
+            valid = jnp.arange(cache_k.shape[2])[None, None, :] <= q_pos[:, :, None]
+            s = jnp.where(valid[:, None, :, :], s, -1e30)
+            p_attn = jax.nn.softmax(s, axis=-1)
+            ctx = jnp.einsum("nhqk,nhkd->nhqd", p_attn, cache_v)
+            ctx = _merge_heads(ctx.astype(x.dtype))
     with jax.named_scope(SCOPE_ATTN_OUT):
         x = x + ctx @ p["attn_out"]["w"].astype(x.dtype) + p["attn_out"]["b"].astype(x.dtype)
     with jax.named_scope(SCOPE_MLP):
@@ -784,7 +810,7 @@ def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None):
     return x, pool
 
 
-def _paged_forward(params, pool, bt, tokens, positions, counts=None):
+def _paged_forward(params, pool, bt, tokens, positions, counts=None, attn_kernel=""):
     """Shared body of the paged decode/verify/chunk programs: tokens[n, m]
     with slot i's query j at positions[i] + j; returns (logits[n, m, vocab],
     hidden[n, m, d], new pool state) — ``hidden`` is the final layer's
@@ -795,7 +821,11 @@ def _paged_forward(params, pool, bt, tokens, positions, counts=None):
     layer's write is a scatter into it, so a caller that donates the pool
     gets it updated in place. Junk queries clip the position table like
     the flat verify/chunk paths — their logits are never read and their
-    writes are junk-redirected."""
+    writes are junk-redirected.
+
+    ``attn_kernel`` asks for the Pallas decode kernel on the layers' read
+    side (``_layer_step_paged``); a dispatch it does not fit keeps the
+    gather whatever was asked."""
     heads = _heads(params)
     m = tokens.shape[1]
     max_len = params["pos_emb"].shape[0]
@@ -804,18 +834,21 @@ def _paged_forward(params, pool, bt, tokens, positions, counts=None):
         pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
         x = x + jnp.asarray(params["pos_emb"])[pidx]
     for li, lp in enumerate(params["layers"]):
-        x, pool = _layer_step_paged(lp, x, pool, li, bt, positions, heads, counts)
+        x, pool = _layer_step_paged(lp, x, pool, li, bt, positions, heads, counts, attn_kernel)
     with jax.named_scope(SCOPE_LM_HEAD):
         logits = _logits(params, x)  # [n, m, vocab]
     return logits, x, pool
 
 
-def paged_decode_step(params, pool, bt, tokens, positions):
+def paged_decode_step(params, pool, bt, tokens, positions, attn_kernel=""):
     """decode_step over the page pool: consume tokens[n] at positions[n],
     return (logits[n, vocab], hidden[n, d], pool) — K/V written through
     block tables; ``hidden`` is the consumed position's final-layer
-    feature (what a feature-level draft conditions the next round on)."""
-    logits, hidden, pool = _paged_forward(params, pool, bt, tokens[:, None], positions)
+    feature (what a feature-level draft conditions the next round on).
+    ``attn_kernel``: see ``_paged_forward``."""
+    logits, hidden, pool = _paged_forward(
+        params, pool, bt, tokens[:, None], positions, attn_kernel=attn_kernel
+    )
     return logits[:, 0, :], hidden[:, 0, :], pool
 
 

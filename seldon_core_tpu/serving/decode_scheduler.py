@@ -205,6 +205,7 @@ from seldon_core_tpu.serving.affinity_router import (
     capture_prefix_len,
     usable_prefix_len,
 )
+from seldon_core_tpu.ops.paged_attention import mosaic_tiles, pages_read
 from seldon_core_tpu.serving.kv_host_tier import KVHostTier
 from seldon_core_tpu.serving.kv_pool import PagedKVPool
 from seldon_core_tpu.persistence.state import make_state_store
@@ -214,14 +215,16 @@ log = logging.getLogger(__name__)
 OnToken = Callable[[int, int], None]  # (token_id, index-within-generation)
 
 
-def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick):
+def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick, *, attn_kernel=""):
     """One device program per scheduler step: paged decode_step + sampling
     + key derivation fused into a single dispatch. Per-step host->device
     traffic is the block tables plus four tiny vectors, and the readback
     one [n_slots] int32 — the per-step floor is ONE dispatch, not three.
     ``tick`` is a traced scalar, so the per-step RNG key needs
-    no host-side split and the program never recompiles."""
-    logits, _hidden, pool = paged_decode_step(params, pool, bt, tokens, positions)
+    no host-side split and the program never recompiles. ``attn_kernel``
+    (bound by ``_family_programs``, never traced) is the layers' read side:
+    ``_step_attn_kernel``'s answer."""
+    logits, _hidden, pool = paged_decode_step(params, pool, bt, tokens, positions, attn_kernel)
     with jax.named_scope(SCOPE_SAMPLE):
         key = jax.random.fold_in(jax.random.key(seed), tick)
         return sample_tokens(logits, temps, topks, key), pool
@@ -268,10 +271,40 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
         return sample_tokens(last, temps, topks, key), pool
 
 
+def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int) -> str:
+    """How the fused decode step's attention reads the pool — THE place the
+    choice is made, from what the scheduler can observe and nothing else (no
+    knob): "mosaic", the Pallas kernel that reads the pool's pages in place
+    and stops at each slot's length (ops/paged_attention.py), where the
+    family is the GPT-2 block, the pool is the two-component float pool,
+    there is no decode mesh, the pool lies on one device that is a TPU, and
+    Mosaic can tile the pool's rows and pages (``mosaic_tiles``: gpt2-xl's
+    rows of 1600 and pages of 4 rows are outside it);
+    "" — the page gather and the flat path's attention — everywhere else:
+    the int8 pool, a tensor-parallel mesh, the second family, a geometry
+    the kernel cannot tile, the CPU backend (where the gather is the oracle
+    that is bit-identical to the flat path). The dispatch's shape is the
+    program's own to see: only one query a slot takes the kernel
+    (models/decoder.py ``_layer_step_paged``), so chunk, verify and tree
+    programs gather whatever this says."""
+    if family is not gpt2_family or mesh is not None or len(pool_state) != 2:
+        return ""
+    devices = pool_state[0].sharding.device_set
+    if len(devices) != 1 or next(iter(devices)).platform != "tpu":
+        return ""
+    _layers, _pages, page_size, row_width = pool_state[0].shape
+    if not mosaic_tiles(row_width, heads, page_size, pool_state[0].dtype):
+        return ""
+    return "mosaic"
+
+
 @functools.lru_cache(maxsize=None)
-def _family_programs(family):
+def _family_programs(family, attn_kernel=""):
     """(``_fused_step``, ``_fused_chunk``) of a decoder family. The GPT-2
-    family (models/decoder.py, the module itself) has the two above. A
+    family (models/decoder.py, the module itself) has the two above; with
+    ``attn_kernel`` (static: ``_step_attn_kernel``'s answer) its step is
+    ``_fused_step`` with the layers' read side bound to it, under the same
+    name. A
     family that counts what its forward does (``frame_counters``,
     models/moe_decoder.py) gets its own pair under the SAME names — a
     device trace calls both families' programs ``jit__fused_step`` — with
@@ -281,7 +314,11 @@ def _family_programs(family):
     transfer. The chunk's head runs on each slot's last real row only.
     Cached per family: equal configurations share compiled programs."""
     if family is gpt2_family:
-        return _fused_step, _fused_chunk
+        if not attn_kernel:
+            return _fused_step, _fused_chunk
+        step = functools.partial(_fused_step, attn_kernel=attn_kernel)
+        step.__name__ = step.__qualname__ = _fused_step.__name__
+        return step, _fused_chunk
 
     def sample_and_count(logits, counted, temps, topks, seed, tick):
         with jax.named_scope(SCOPE_SAMPLE):
@@ -1335,6 +1372,11 @@ class DecodeScheduler:
             step_kw = verify_kw = draft_kw = draft_admit_kw = {}
             draft_tree_kw = tree_verify_kw = {}
             step_f_kw = chunk_f_kw = ftree_verify_kw = {}
+        # the plain step's read side (the feature twins keep the gather)
+        self._attn_kernel = (
+            "" if self.feature_draft
+            else _step_attn_kernel(self.family, self.pool.state, self.mesh, dims["heads"])
+        )
         if self.feature_draft:
             # feature mode swaps the step/chunk pair for feature-carrying
             # twins (the chunk one also teacher-forces the head's prompt
@@ -1346,7 +1388,7 @@ class DecodeScheduler:
                 _fused_chunk_feat, donate_argnums=(2, 4, 5, 9), **chunk_f_kw
             )
         else:
-            step, chunk = _family_programs(self.family)
+            step, chunk = _family_programs(self.family, self._attn_kernel)
             self._step_fn = jax.jit(step, donate_argnums=(1,), **step_kw)
             self._chunk_fn = jax.jit(chunk, donate_argnums=(1,), **step_kw)
         if self.spec_enabled:
@@ -1707,6 +1749,17 @@ class DecodeScheduler:
         # label = slot count)
         self._metrics.compile(self._deployment, self.n_slots, time.perf_counter() - t0)
         self._warmup_compile_counts = self.compile_counts()
+
+    def _step_attn_pages(self, pos: np.ndarray) -> tuple[int, int]:
+        """(pages a plain step's attention reads for one layer's K, pages
+        its block tables name) from the positions the round built — no
+        readback. The kernel path (``_attn_kernel``) fetches each slot's
+        ``ceil((pos + 1) / page_size)`` pages: a free slot's one junk page, a
+        prefilling slot's up to its cursor; the gather path all of them."""
+        table = self.n_slots * self.pool.pages_per_slot
+        if not self._attn_kernel:
+            return table, table
+        return int(pages_read(pos, self.pool.page_size, self.pool.pages_per_slot).sum()), table
 
     def _split_counts(self, toks: np.ndarray) -> np.ndarray:
         """A counting family's step or chunk readback (``frame_counters``):
@@ -2487,6 +2540,8 @@ class DecodeScheduler:
         self._rb_admit_wait = 0
         self._rb_prefill = 0
         self._rb_first_tokens = 0
+        # pages the plain step's attention read, of the pages its tables name
+        self._rb_attn_pages = (0, 0)
         # a counting family's per-dispatch counts, summed over the round
         # (nothing to build each round for a family that counts nothing)
         if self._frame_counters:
@@ -2618,6 +2673,7 @@ class DecodeScheduler:
                         self._rb_overlap, self._rb_probe, tuple(self._rb_widths),
                         self._rb_promotions, self._rb_admit_wait,
                         self._rb_prefill, self._rb_first_tokens,
+                        *self._rb_attn_pages,
                         **(
                             dict(zip(self._frame_counters, self._rb_counts.tolist()))
                             if self._frame_counters
@@ -3685,6 +3741,7 @@ class DecodeScheduler:
                     await asyncio.sleep(0)
                     continue
 
+                self._rb_attn_pages = self._step_attn_pages(pos)
                 if pipelined:
                     nxt = await self._step_round_pipelined(
                         bt, toks, pos, temps, topks, fmask, tick

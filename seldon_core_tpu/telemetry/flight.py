@@ -364,7 +364,13 @@ class FlightFrame:
     this round's ``admitted`` requests, ``prefill_ns`` the summed
     admission -> first-token time of the ``first_tokens`` requests whose
     first token this round emitted — the two halves of the time to first
-    token as the program sees it; ``moe_rows`` / ``moe_experts_hit`` /
+    token as the program sees it; ``attn_pages_read`` / ``attn_pages_table``
+    the pages a plain round's fused step read for one layer's attention
+    (summed over slots) and the pages its block tables name (``n_slots x
+    pages_per_slot``), counted on the host from the round's positions:
+    equal on the gather path, read < table where the paged-attention kernel
+    stops at each slot's length, 0 / 0 in a round without a plain step;
+    ``moe_rows`` / ``moe_experts_hit`` /
     ``moe_load_max`` what a sparse-expert family's programs counted in the
     round's chunk and step dispatches (models/moe_decoder.py, real rows
     only): token rows routed, distinct experts with a row and the fullest
@@ -378,6 +384,7 @@ class FlightFrame:
         "kv_prefix", "cow", "phase_ns", "rdb_ns", "overlap_ns",
         "probe", "spec_widths", "promotions",
         "admit_wait_ns", "prefill_ns", "first_tokens",
+        "attn_pages_read", "attn_pages_table",
         "moe_rows", "moe_experts_hit", "moe_load_max",
     )
 
@@ -388,6 +395,7 @@ class FlightFrame:
         phase_ns=_ZERO_PHASES, rdb_ns=_ZERO_FAMILIES, overlap_ns=0,
         probe=False, spec_widths=(), promotions=0,
         admit_wait_ns=0, prefill_ns=0, first_tokens=0,
+        attn_pages_read=0, attn_pages_table=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
     ):
         self.seq = seq
@@ -418,6 +426,8 @@ class FlightFrame:
         self.admit_wait_ns = admit_wait_ns
         self.prefill_ns = prefill_ns
         self.first_tokens = first_tokens
+        self.attn_pages_read = attn_pages_read
+        self.attn_pages_table = attn_pages_table
         self.moe_rows = moe_rows
         self.moe_experts_hit = moe_experts_hit
         self.moe_load_max = moe_load_max
@@ -482,6 +492,8 @@ class FlightFrame:
             d["cow"] = self.cow
         if self.promotions:
             d["promotions"] = self.promotions
+        if self.attn_pages_table:
+            d["attn_pages"] = [self.attn_pages_read, self.attn_pages_table]
         if self.moe_rows:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
         return d

@@ -98,6 +98,18 @@ def _step_args(params, geo, kv_dtype, param_sh, pool_sh_for, small_sh):
     )
 
 
+def _pool_sized_entry_ops(text: str, pool) -> list[str]:
+    """Opcodes of the entry computation's instructions whose result is as
+    large as one pool component, or as one layer of it."""
+    entry = re.search(r"ENTRY [^{]*\{(.*?)\n\}", text, re.S).group(1)
+    ops = [
+        (op, int(np.prod([int(d) for d in dims.split(",") if d])))
+        for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", entry)
+    ]
+    pool_elems = int(np.prod(pool[0].shape))
+    return [op for op, n in ops if n in (pool_elems, pool_elems // pool[0].shape[0])]
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_compiles_at_bert_base_long_context(topo, causal):
     from seldon_core_tpu.ops.attention import PALLAS_MIN_SEQ
@@ -157,15 +169,83 @@ def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes / 4
     # no copy of the pool, or of one layer of it, in the entry computation
-    entry = re.search(r"ENTRY [^{]*\{(.*?)\n\}", compiled.as_text(), re.S).group(1)
-    ops = [
-        (op, int(np.prod([int(d) for d in dims.split(",") if d])))
-        for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", entry)
-    ]
-    pool_elems = int(np.prod(pool[0].shape))
-    sized = [op for op, n in ops if n in (pool_elems, pool_elems // pool[0].shape[0])]
+    sized = _pool_sized_entry_ops(compiled.as_text(), pool)
     assert "fusion" in sized  # the scatters' own fusions: the pattern reads the text
     assert not [op for op in sized if op.startswith("copy")], sized
+
+
+def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo):
+    """The same cell geometry with the step's attention in the Pallas decode
+    kernel (ops/paged_attention.py; ``attn_kernel`` is the static argument the
+    scheduler's ``_step_attn_kernel`` answers on a TPU): Mosaic takes the
+    kernel at the real widths, the kernel takes the WHOLE donated pool — which
+    still aliases, with no copy of it or of one layer of it — and the gathered
+    float32 cache (0.11 GiB) is gone from the temporaries."""
+    from seldon_core_tpu.models.decoder import init_decoder
+    from seldon_core_tpu.serving.decode_scheduler import _family_programs, gpt2_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(
+        lambda: init_decoder(0, vocab=50257, hidden=1280, layers=8, ffn=5120, max_len=1024)
+    )
+    geo = {"n_slots": 16, "n_pages": 720, "page_size": 16, "pages_per_slot": 44}
+    p, pool, rest = _step_args(
+        params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
+    )
+    step, _chunk = _family_programs(gpt2_family, "mosaic")
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 8  # a kernel call a layer: Mosaic, not the interpreter
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in pool)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # under the gather path's floor, one layer's K and V virtual caches
+    # (that step compiles to 0.142 GiB of temporaries, this one to 0.012)
+    assert mem.temp_size_in_bytes < (2 * 16 * 704 * 1280 * 4) // 4
+    sized = _pool_sized_entry_ops(text, pool)
+    assert "fusion" in sized  # the scatters' own fusions
+    assert not [op for op in sized if op.startswith("copy")], sized
+    # what is left of the gather keeps its scope, and the kernel lies under attn
+    assert re.search(r'op_name="jit\(_fused_step\)/kv_gather/', text)
+    assert re.search(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_step\)/attn/', text)
+
+
+@pytest.mark.parametrize(
+    "hidden, page_size, want",
+    [
+        (1280, 16, "mosaic"),  # the gpt2-large cells
+        (256, 8, "mosaic"),  # chip_smoke's width, the smallest page Mosaic takes
+        (1600, 16, ""),  # gpt2-xl: 25 heads of 64, a row that is not whole 128-lane tiles
+        (1280, 4, ""),  # a page under one sublane tile
+    ],
+)
+def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, hidden, page_size, want):
+    """``_step_attn_kernel`` on a described TPU: the kernel where Mosaic can
+    tile the pool's rows and pages, the gather path where it cannot — and
+    the step it chose compiles either way. Asking for the kernel at a
+    refused geometry is a named error before Mosaic sees it."""
+    from seldon_core_tpu.models.decoder import decoder_dims, init_decoder
+    from seldon_core_tpu.serving.decode_scheduler import (
+        _family_programs, _step_attn_kernel, gpt2_family,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(
+        lambda: init_decoder(0, vocab=1024, hidden=hidden, layers=2, ffn=4 * hidden, max_len=256)
+    )
+    geo = {"n_slots": 4, "n_pages": 64, "page_size": page_size, "pages_per_slot": 96 // page_size}
+    p, pool, rest = _step_args(
+        params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
+    )
+    got = _step_attn_kernel(gpt2_family, pool, None, decoder_dims(params)["heads"])
+    assert got == want
+    step, _chunk = _family_programs(gpt2_family, got)
+    text = jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).compile().as_text()
+    assert ("tpu_custom_call" in text) == bool(want)
+    if not want:
+        forced, _chunk = _family_programs(gpt2_family, "mosaic")
+        with pytest.raises(ValueError, match="mosaic_tiles"):
+            jax.jit(forced, donate_argnums=(1,)).lower(p, pool, *rest)
 
 
 def test_tp4_sharded_decode_step_compiles(topo):
