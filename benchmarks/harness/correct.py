@@ -64,3 +64,14 @@ def judge_probabilities(served: np.ndarray, reference: np.ndarray) -> dict:
         "max_prob_diff": diff,
         "tolerance": BF16_PROB_TOL,
     }
+
+
+def compared(**verdicts: dict) -> dict:
+    """{name: {"value", "limit"}} of every number a run's verdicts compared,
+    for the result's line and the run's last lines on standard error."""
+    out = {}
+    for when, v in verdicts.items():
+        for key in ("worst_logit_gap", "max_prob_diff"):
+            if key in v:
+                out[f"{key}.{when}"] = {"value": v[key], "limit": v["tolerance"]}
+    return out

@@ -96,6 +96,7 @@ class Generative:
         self.seq, self.vocab = int(unit["seq"]), int(unit["vocab"])
         self.geometry = {k: int(unit[k]) for k in ("hidden", "layers", "ffn", "vocab")}
         self.n_head = int(config["reference"]["n_head"])
+        self.config = config
         s = self.sched
         # the serving builder warn-and-disables what it cannot serve: a cell
         # must measure the mechanisms its configuration names
@@ -197,13 +198,18 @@ class Generative:
         is padded with repeats to the sample's rows: one compiled shape."""
         import numpy as np
 
+        import inspect
+
         from harness.correct import judge_generated
 
+        # a reference that reads its sizes from the configuration's file takes them
+        # from the configuration as it is run (the rehearsal's tiny one)
+        sizes = {"config": self.config} if "config" in inspect.signature(ref.logits).parameters else {}
         width, first = self.seq + REFERENCE_NEW_TOKENS, self.seq - 1
         served = [s[:width] for s in served]
         ids = np.asarray([served[r % len(served)] for r in range(self._sample_rows)], np.int64)
         exact, noisy = (
-            np.asarray(ref.logits(self.params, ids, first, n_head=self.n_head, precision=p), np.float64)
+            np.asarray(ref.logits(self.params, ids, first, n_head=self.n_head, precision=p, **sizes), np.float64)
             for p in ("highest", "default")
         )
         return judge_generated(served, exact[: len(served)], noisy[: len(served)], first)

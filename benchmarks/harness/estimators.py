@@ -83,6 +83,16 @@ def upper_plateau_share(gaps, factor: float = 1.5) -> float | None:
     return sum(1 for g in gaps if g > factor * base) / len(gaps)
 
 
+def histogram(values, width: float) -> dict:
+    """{bin's lower edge in ms: count} over bins of ``width`` seconds, empty
+    bins left out: where the gaps' plateaus lie, and how many gaps on each."""
+    out: dict[int, int] = {}
+    for v in values:
+        k = int(v // width)
+        out[k] = out.get(k, 0) + 1
+    return {str(round(1e3 * k * width)): n for k, n in sorted(out.items())}
+
+
 def spread(values) -> float:
     """Distance between the first and third quartile as a share of the
     median, by ``statistics.quantiles(values, n=4)`` — the driver's rule."""

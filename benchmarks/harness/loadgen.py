@@ -156,10 +156,23 @@ async def _open_request(session, plan, req, t_load: float, recs: list):
     await _stream(session, plan, req, rec)
 
 
+async def _heartbeat(stalls: list, every_s: float = 0.005, over_s: float = 0.02) -> None:
+    """A timer that does nothing: [time due, seconds late] each time it woke
+    ``over_s`` or more late. A generator that sends late while this timer is
+    late too was not busy: the process, or the machine, stood still."""
+    while True:
+        due = time.monotonic() + every_s
+        await asyncio.sleep(every_s)
+        late = time.monotonic() - due
+        if late >= over_s:
+            stalls.append([due, late])
+
+
 async def _amain(plan: dict) -> dict:
     import aiohttp
 
     recs: list = []
+    stalls: list = []
     conn = aiohttp.TCPConnector(limit=0)
     timeout = aiohttp.ClientTimeout(total=None)
     async with aiohttp.ClientSession(connector=conn, timeout=timeout) as session:
@@ -173,6 +186,7 @@ async def _amain(plan: dict) -> dict:
         t0 = t_load + plan["ramp_s"]
         t1 = t0 + plan["seconds"]
         await _sleep_until(t_load)
+        beat = asyncio.ensure_future(_heartbeat(stalls))
         if plan["generator"] == "closed":
             tasks = [
                 asyncio.ensure_future(_closed_client(session, plan, c, lane, headers, t1, recs))
@@ -186,10 +200,10 @@ async def _amain(plan: dict) -> dict:
             ]
             stop = t1 + plan["first_token_grace_s"]
         await asyncio.wait(tasks, timeout=max(0.0, stop - time.monotonic()))
-        for t in tasks:
+        for t in [*tasks, beat]:
             t.cancel()
-        await asyncio.gather(*tasks, return_exceptions=True)
-    return {"t_load": t_load, "t0": t0, "t1": t1, "requests": recs}
+        await asyncio.gather(*tasks, beat, return_exceptions=True)
+    return {"t_load": t_load, "t0": t0, "t1": t1, "requests": recs, "stalls": stalls}
 
 
 def main() -> int:

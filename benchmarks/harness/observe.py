@@ -30,11 +30,13 @@ def observations(dep, config, traffic, cell, obs, setup_s, args, device) -> dict
     if traffic["generator"] == "open":
         attempted = len(measured)
         failed = sum(1 for r in measured if r.get("error") or not r["token_times"])
-        late = [r["sent"] - r["due"] for r in measured if "sent" in r]
+        # (seconds late, second of the window it was due in), the latest first
+        o_late = sorted(((r["sent"] - r["due"], r["due"] - t0) for r in measured if "sent" in r), reverse=True)
+        late = [d for d, _ in o_late]
     else:
         attempted = len(ended)
         failed = sum(1 for r in ended if r.get("error"))
-        late = []
+        late, o_late = [], []
     gaps = est.gaps_in_window(streams, t0, t1)
     o = {
         "cell": cell["name"], "tier": dep.tier, "config": config, "traffic": traffic,
@@ -51,6 +53,13 @@ def observations(dep, config, traffic, cell, obs, setup_s, args, device) -> dict
         "requests_seen": len(reqs), "attempted": attempted, "failed": failed, "errors": errors,
         "generator_late_ms_max": 1e3 * max(late) if late else None,
         "generator_late_ms_mean": 1e3 * sum(late) / len(late) if late else None,
+        "generator_late_ms_p95": 1e3 * est.quantile(late, 0.95)["value"] if late else None,
+        # the latest few with the second of the window they were due in, beside the moments at
+        # which the load child's idle timer woke late: a late send inside one is the process or
+        # the machine standing still, not a generator that cannot keep up
+        "generator_latest_ms_at_s": [[1e3 * d, at] for d, at in o_late[:4]],
+        "generator_late_over_5ms": sum(1 for d in late if d > 0.005),
+        "child_stalls_ms_at_s": [[1e3 * d, at - t0] for at, d in obs["report"].get("stalls", []) if t0 <= at < t1][:12],
     }
     if dep.tier == "generative":
         rate = est.aligned_rate(o["token_times"], t0, t1)
@@ -59,6 +68,7 @@ def observations(dep, config, traffic, cell, obs, setup_s, args, device) -> dict
         tok = sum(f.tokens for f in inside)
         o["chunk_gap_share"] = (sum(f.tokens for f in with_chunk) / tok) if tok else None
         bs = est.bursts([t for t in o["token_times"] if t0 <= t < t1])
+        p95 = est.quantile(gaps, 0.95)["value"] if gaps else 0.0
         silences = sorted(((b[0] - a[0], a[0] - t0) for a, b in zip(bs, bs[1:])), reverse=True)[:2]
         o["client_summary"].update(
             first_burst_after_t0_s=bs[0][0] - t0 if bs else None,
@@ -67,9 +77,16 @@ def observations(dep, config, traffic, cell, obs, setup_s, args, device) -> dict
             tokens_per_s_aligned=rate and rate["value"], tokens_per_s_naive=rate and rate["naive"],
             aligned_detail=rate,
             gaps=len(gaps),
+            gap_hist_5ms=est.histogram(gaps, 0.005),
+            # the plateau the 95th percentile sits on, finer: the gaps within a fifth of it
+            gap_hist_1ms_near_p95=est.histogram([g for g in gaps if 0.8 * p95 <= g < 1.2 * p95], 0.001),
             chunk_share_of_gaps_by_flight_rounds=o["chunk_gap_share"],
             chunk_share_of_gaps_by_client_plateau=est.upper_plateau_share(gaps),
             rounds_in_window=len(obs["frames"]),
+            # the server's own long rounds (a frame's time less the frame before it), on the same clock
+            server_rounds_over_100ms_at_s=[
+                [(b.t_ns - a.t_ns) / 1e6, b.t_ns / 1e9 - t0]
+                for a, b in zip(obs["frames"], obs["frames"][1:]) if b.t_ns - a.t_ns > 100e6][:12],
             admitted_in_window=obs["after"]["admitted"] - obs["before"]["admitted"],
         )
     else:

@@ -43,7 +43,7 @@ ANN_PREFIX = "decode."
 OP_NAME_STAT = "tf_op"  # the event-metadata stat that carries an instruction's HLO op_name
 SCOPES = ("embed", "qkv", "kv_write", "pool_restack", "kv_gather", "attn", "attn_out", "mlp",
           "lm_head", "sample")
-STEP_MARK = "fused_step"
+STEP_MARK, CHUNK_MARK = "fused_step", "fused_chunk"
 # states that name idle time; ROUND_ONLY and NONE are what idle_named_pct leaves out
 ENQUEUE, READBACK, PHASE, SSE_WRITE, IDLE_WAIT, ROUND_ONLY, NONE = (
     "enqueue", "readback", "phase", "sse_write", "idle_wait", "round_only", "none")
@@ -84,6 +84,15 @@ def read_scoped(path: str) -> dict:
                     if name.startswith(ANN_PREFIX) or name == WINDOW:
                         out["host"].append([name, start, dur, thread, {k: str(v) for k, v in stats.items()}])
     return out
+
+
+def _is_wait(label: str) -> bool:
+    """An async wait the compiler adds: the start or the end of its own
+    prefetch (``slice-start`` / ``slice-done`` of a layer's weights into VMEM,
+    ``copy-start`` / ``copy-done``) or the ``custom-call`` that joins the
+    pieces. A plain ``copy`` is work (a layout change), not a wait."""
+    kind = label.split(" ", 1)[0]
+    return kind.endswith(("-start", "-done")) or kind == "custom-call"
 
 
 def _scope_of(op_name: str) -> str | None:
@@ -221,18 +230,22 @@ def step_by_scope(events: dict, mark: str = STEP_MARK) -> dict | None:
     ``mark`` dispatches of the slice, by program scope. None where the
     slice holds no such dispatch with ops. Keys: ``dispatches``, ``op_s``
     (all op self time in them), ``by_scope`` {scope: s} (only scopes that
-    were found), ``unscoped_s``, ``module_s`` (the dispatches' own time)
-    and, to say which time could not be named: ``unscoped_ops`` {label: s}
-    and ``unscoped_by_next`` {scope of the next scoped op of the same
-    dispatch: s} (the compiler's layout copies and prefetch waits carry no
-    op_name; what follows them is most often what they were for — a hint
-    for a reader, not a metric)."""
+    were found, by the op's own ``op_name``), ``waits_by_scope`` {scope: s}
+    (async waits without an op_name, ``_is_wait``, given to the scope of the
+    next scoped op of the same dispatch: the compiler prefetches a layer's
+    weights for the op that follows; the readers count them under that
+    scope), ``unscoped_s`` (what is left), ``module_s`` (the dispatches' own
+    time) and, to say which time could not be named: ``unscoped_ops``
+    {label: s} and ``unscoped_by_next`` {scope of the next scoped op: s}
+    (layout copies carry no op_name either; what follows them is most often
+    what they were for — a hint for a reader, not a metric)."""
     win = [e for e in events["host"] if e[0] == WINDOW]
     if not win:
         return None
     t0, t1 = win[0][1], win[0][1] + win[0][2]
     dispatches, module_s, op_s = 0, 0.0, 0.0
     by_scope: dict[str, float] = {}
+    waits: dict[str, float] = {}
     unscoped_ops: dict[str, float] = {}
     by_next: dict[str, float] = {}
     for plane in sorted(events["devices"]):
@@ -258,13 +271,15 @@ def step_by_scope(events: dict, mark: str = STEP_MARK) -> dict | None:
                 if scope is not None:
                     by_scope[scope] = by_scope.get(scope, 0.0) + own
                     nxt = scope
+                elif nxt != NONE and _is_wait(label):
+                    waits[nxt] = waits.get(nxt, 0.0) + own
                 else:
                     unscoped_ops[label] = unscoped_ops.get(label, 0.0) + own
                     by_next[nxt] = by_next.get(nxt, 0.0) + own
     if not dispatches:
         return None
     top = dict(sorted(unscoped_ops.items(), key=lambda kv: -kv[1])[:12])
-    return {"dispatches": dispatches, "op_s": op_s, "by_scope": by_scope,
+    return {"dispatches": dispatches, "op_s": op_s, "by_scope": by_scope, "waits_by_scope": waits,
             "unscoped_s": sum(unscoped_ops.values()), "module_s": module_s,
             "unscoped_ops": top, "unscoped_by_next": by_next}
 
@@ -286,7 +301,7 @@ def _self_times(ops: list) -> list:
 
 def reduce_scoped(events: dict) -> dict:
     return {"idle": idle_by_state(events), "step": step_by_scope(events),
-            "op_name_stat": events.get("op_name_stat")}
+            "chunk": step_by_scope(events, CHUNK_MARK), "op_name_stat": events.get("op_name_stat")}
 
 
 # ----------------------------------------------------- what the readers call
@@ -305,6 +320,22 @@ def of_run(o: dict) -> dict | None:
     return _of_file(newest_xplane(TRACE_DIR))
 
 
+def per_dispatch_ms(r: dict | None) -> dict | None:
+    """A ``step_by_scope`` reduction as milliseconds a dispatch, for a run's
+    earlier lines (PERF.md section 5 is written from them): each scope with
+    its waits, the waits alone, the module's own time and what has no name."""
+    if not r:
+        return None
+    n = r["dispatches"]
+    return {
+        "dispatches": n, "module_ms": 1e3 * r["module_s"] / n, "op_ms": 1e3 * r["op_s"] / n,
+        "by_scope_ms": {k: 1e3 * scoped_s(r, k) / n for k in sorted(set(r["by_scope"]) | set(r["waits_by_scope"]))},
+        "waits_ms": {k: 1e3 * v / n for k, v in r["waits_by_scope"].items()},
+        "unscoped_ms": 1e3 * r["unscoped_s"] / n,
+        "unscoped_ops_ms": {k: 1e3 * v / n for k, v in r["unscoped_ops"].items()},
+    }
+
+
 def idle_ms_per_round(o: dict, *states: str) -> float | None:
     """Idle per round in the given states, ms; None without annotations."""
     r = of_run(o)
@@ -315,13 +346,22 @@ def idle_ms_per_round(o: dict, *states: str) -> float | None:
 
 
 def step_scope_ms(o: dict, *scopes: str) -> float | None:
-    """Device time per fused-step dispatch in ops under the given scopes,
-    ms; None where no op under any of them was found."""
+    """Device time per fused-step dispatch in ops under the given scopes and
+    in the compiler's waits for them, ms; None where no op under any of them
+    was found."""
     r = of_run(o)
     step = r and r["step"]
     if not step or not any(s in step["by_scope"] for s in scopes):
         return None
-    return 1e3 * sum(step["by_scope"].get(s, 0.0) for s in scopes) / step["dispatches"]
+    return 1e3 * sum(scoped_s(step, s) for s in scopes) / step["dispatches"]
+
+
+def scoped_s(step: dict, scope: str | None = None) -> float:
+    """Seconds of a ``step_by_scope`` reduction under ``scope`` (under any,
+    without one): the ops that carry it and the waits given to it."""
+    if scope is None:
+        return sum(step["by_scope"].values()) + sum(step["waits_by_scope"].values())
+    return step["by_scope"].get(scope, 0.0) + step["waits_by_scope"].get(scope, 0.0)
 
 
 # -------------------------------------------------- a trace small enough to keep

@@ -163,7 +163,19 @@ def device_facts() -> dict:
             "memory_peak_bytes": int(max(peaks))}
 
 
+def read_metrics(bench: dict, cell: dict, o: dict, traced: bool) -> dict:
+    """The cell's metrics of one group, each from its reader file; a reader
+    that finds nothing to read leaves its metric out."""
+    metrics = {}
+    for m, reader in cells.readers(ROOT, bench, cell, traced):
+        value = reader.read(o)
+        if value is not None and math.isfinite(value):
+            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return metrics
+
+
 async def amain(args, bench: dict, cell: dict, config: dict, traffic: dict) -> int:
+    from harness.correct import compared
     from harness.deploy import TIERS
     from harness.observe import observations
     from harness.traffic import build_plan
@@ -194,7 +206,7 @@ async def amain(args, bench: dict, cell: dict, config: dict, traffic: dict) -> i
     plan = build_plan(traffic, seed=args.seed, seconds=args.seconds)
     plan.update(url=dep.url, path=dep.path, auth=dep.auth, seed=args.seed, vocab=dep.vocab, seq=dep.seq)
     if args.sweep:
-        return await sweep(args, dep, traffic, plan)
+        return await sweep(args, dep, bench, cell, config, traffic, plan)
     t = time.monotonic()
     obs = await run_load(dep, plan, trace=bool(args.trace))
     setup["load_child_start_and_ramp_s"] = obs["t0"] - t
@@ -212,26 +224,30 @@ async def amain(args, bench: dict, cell: dict, config: dict, traffic: dict) -> i
     if args.trace:
         say(phase="trace", lines=o["trace_lines"], families=o["trace"]["families"],
             window_s=o["trace"]["window_s"], busy_s=o["trace"]["busy_s"])
+        from harness import scopes
+
+        scoped = scopes.of_run(o) or {}
+        say(phase="scopes", step=scopes.per_dispatch_ms(scoped.get("step")),
+            chunk=scopes.per_dispatch_ms(scoped.get("chunk")))
         if args.keep_trace_events:
             from harness.trace import trimmed
 
             os.makedirs(os.path.dirname(os.path.abspath(args.keep_trace_events)), exist_ok=True)
             with open(args.keep_trace_events, "w") as f:
                 json.dump(trimmed(o["trace_events"], 0.3), f)
-    metrics = {}
-    for m, reader in cells.readers(ROOT, bench, cell, bool(args.trace)):
-        value = reader.read(o)
-        if value is not None and math.isfinite(value):
-            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     result = {
         "correct": bool(verdict["ok"] and after["ok"]),
         "attempted": o["attempted"],
         "failed": o["failed"],
-        "metrics": metrics,
+        "metrics": read_metrics(bench, cell, o, bool(args.trace)),
         "device": o["device"],
     }
     if args.trace and o["trace"]:
         result["breakdown"] = {"device_ops": o["trace"]["device_ops"], "idle_gaps": o["trace"]["idle_gaps"]}
+    # each number compared beside its limit: last in the result's line, and the last lines on standard error
+    result["compared"] = compared(before_window=verdict, window_answers=after)
+    for name, c in result["compared"].items():
+        print(f"run.py: compared {name} = {c['value']!r}, limit {c['limit']!r}", file=sys.stderr, flush=True)
     if args.rehearse:
         say(phase="rehearsal", note="CPU backend, tiny sizes: never a result", would_report=result)
         return 3
@@ -239,30 +255,38 @@ async def amain(args, bench: dict, cell: dict, config: dict, traffic: dict) -> i
     return 0
 
 
-async def sweep(args, dep, traffic: dict, plan0: dict) -> int:
+async def sweep(args, dep, bench: dict, cell: dict, config: dict, traffic: dict, plan0: dict) -> int:
     """The knee, once: several fixed rates in one process after one set-up.
     A rate is sustained when the not-yet-admitted backlog does not grow from
-    the window's first third to its last."""
+    the window's first third to its last. Beside each rate, what the cell's
+    own readers make of that window (every metric that needs no trace), the
+    gaps' histogram and how late the generator sent: the rate is chosen from
+    them (PERF.md section 4)."""
+    from harness.observe import observations
     from harness.traffic import build_plan
 
+    untraced = argparse.Namespace(trace=0)
     for rate in [float(r) for r in args.sweep.split(",")]:
-        plan = build_plan({**traffic, "rate_rps": rate}, seed=args.seed, seconds=args.seconds)
+        at_rate = {**traffic, "rate_rps": rate}
+        plan = build_plan(at_rate, seed=args.seed, seconds=args.seconds)
         plan.update({k: plan0[k] for k in ("url", "path", "auth", "seed", "vocab", "seq")})
         obs = await run_load(dep, plan, trace=False, sample_queue=True)
         q = obs["queue_samples"]
         third = args.seconds / 3
         first = [d for t, d, _ in q if t < third]
         last = [d for t, d, _ in q if t >= 2 * third]
-        reqs = [r for r in obs["report"]["requests"] if r.get("measured")]
-        ttft = sorted(r["token_times"][0] - r["due"] for r in reqs if r["token_times"])
-        say(phase="sweep", rate_rps=rate, due=len(reqs), first_tokens=len(ttft),
+        o = observations(dep, config, at_rate, cell, obs, None, untraced, device_facts())
+        readings = {**read_metrics(bench, cell, o, False), **read_metrics(bench, cell, o, True)}
+        client = o["client_summary"]
+        say(phase="sweep", rate_rps=rate, due=o["attempted"], failed=o["failed"], errors=client["errors"],
             backlog_first_third=sum(first) / max(len(first), 1),
             backlog_last_third=sum(last) / max(len(last), 1),
             backlog_max=max([d for _, d, _ in q] or [0]),
             active_mean=sum(a for _, _, a in q) / max(len(q), 1),
-            ttft_p50_ms=1e3 * ttft[len(ttft) // 2] if ttft else None,
-            ttft_max_ms=1e3 * ttft[-1] if ttft else None,
-            tokens_per_s=(obs["after"]["tokens"] - obs["before"]["tokens"]) / args.seconds)
+            tokens_per_s_counted=(obs["after"]["tokens"] - obs["before"]["tokens"]) / args.seconds,
+            **{name: m["value"] for name, m in readings.items()},
+            **{k: client[k] for k in ("gap_hist_5ms", "gap_hist_1ms_near_p95", "generator_late_ms_max", "generator_late_over_5ms",
+                                      "child_stalls_ms_at_s")})
         await asyncio.sleep(20.0)  # drain: cancelled streams retire, the queue empties
     return 3
 

@@ -76,6 +76,20 @@ def test_every_cell_reports_what_the_contract_asks(bench):
             assert m["unit"] == "%"
 
 
+def test_a_metric_is_listed_once_and_read_from_its_own_group(bench):
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    assert not e2e & {m["name"] for m in bench["per_layer"]}  # promoted or not, listed once
+    for name in e2e:  # an end-to-end metric is the benchmark's own reading: metrics/<name>.py
+        assert os.path.exists(os.path.join(BENCH, "metrics", name + ".py")), name
+        assert not os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py")), name
+    for m in bench["per_layer"]:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
+        listed = [w for w in bench["workloads"] if "workloads" not in m or w["name"] in m["workloads"]]
+        assert listed, m["name"]
+        for w in listed:  # what it should move is reported by each cell that lists it
+            assert m["moves"] in {x["name"] for x in cells.cell_metrics(bench, w, "end_to_end")}, (m["name"], w["name"])
+
+
 def test_every_name_has_its_files(bench, request):
     candidate = request.node.callspec.params["bench"]
     for w in bench["workloads"]:

@@ -22,7 +22,7 @@ def _one_round():
     """Device busy [0.10, 0.40) and [0.45, 0.65); a round that names most of
     what lies between, and one that names nothing."""
     ops = [("fusion", 0.10, 0.05, "jit(_fused_step)/jit(main)/qkv/dot_general:"),
-           ("copy", 0.15, 0.10, ""),
+           ("copy-done f32[3840]", 0.15, 0.10, ""),
            ("fusion", 0.25, 0.05, "jit(_fused_step)/jit(main)/kv_write/scatter:"),
            ("while", 0.30, 0.10, "jit(_fused_step)/jit(main)/sample/while:"),
            ("fusion", 0.32, 0.04, "jit(_fused_step)/jit(main)/sample/sort:"),
@@ -86,13 +86,37 @@ def test_op_time_by_scope_counts_self_time_and_whole_dispatches():
     assert step["dispatches"] == 1 and step["module_s"] == pytest.approx(0.30)
     # the while holds its body's sort on the same line: 0.10 in all, not 0.14
     assert step["by_scope"] == pytest.approx({"qkv": 0.05, "kv_write": 0.05, "sample": 0.10})
-    assert step["unscoped_s"] == pytest.approx(0.10) and step["unscoped_ops"] == pytest.approx({"copy": 0.10})
-    assert step["unscoped_by_next"] == pytest.approx({"kv_write": 0.10})
-    assert sum(step["by_scope"].values()) + step["unscoped_s"] == pytest.approx(step["op_s"])
+    # the 0.10 of the copy-done before the scatter: an async wait without an op_name, counted
+    # under the scope of the op it is for
+    assert step["waits_by_scope"] == pytest.approx({"kv_write": 0.10})
+    assert sc.scoped_s(step, "kv_write") == pytest.approx(0.15) and sc.scoped_s(step) == pytest.approx(0.30)
+    assert step["unscoped_s"] == pytest.approx(0.0) and step["unscoped_ops"] == {} == step["unscoped_by_next"]
+    assert sc.scoped_s(step) + step["unscoped_s"] == pytest.approx(step["op_s"])
     assert "attn" not in step["by_scope"]  # the chunk's op is not a step's
     ev = _one_round()
     ev["devices"]["/device:TPU:0"]["modules"][0] = ("jit__fused_step", -0.05, 0.45)  # cut by the slice's edge
     assert sc.step_by_scope(ev) is None
+
+
+def test_an_async_wait_goes_to_the_next_scoped_op_and_a_plain_copy_does_not():
+    ops = [("fusion", 0.10, 0.02, "jit(_fused_step)/jit(main)/attn_out/dot_general:"),
+           ("slice-done f32[320,5120]", 0.12, 0.03, ""),  # the prefetch of the mlp's weights
+           ("custom-call f32[1280,5120]", 0.15, 0.01, ""),  # joins the prefetched quarters
+           ("copy f32[720,16,20,64]", 0.16, 0.04, ""),  # a layout copy is work, not a wait
+           ("fusion", 0.20, 0.05, "jit(_fused_step)/jit(main)/mlp/dot_general:"),
+           ("custom-call f32[16,20,64]", 0.25, 0.02, "jit(_fused_step)/jit(main)/attn/paged_attention:"),
+           ("slice-done f32[256,1280]", 0.27, 0.03, "")]  # nothing scoped follows it in the dispatch
+    step = sc.step_by_scope(_events(ops, [("jit__fused_step", 0.10, 0.20)], []))
+    assert step["by_scope"] == pytest.approx({"attn_out": 0.02, "mlp": 0.05, "attn": 0.02})  # a named custom call keeps its name
+    assert step["waits_by_scope"] == pytest.approx({"mlp": 0.04})
+    assert step["unscoped_ops"] == pytest.approx({"copy f32[720,16,20,64]": 0.04, "slice-done f32[256,1280]": 0.03})
+    assert step["unscoped_by_next"] == pytest.approx({"mlp": 0.04, sc.NONE: 0.03})
+    assert sc.scoped_s(step) + step["unscoped_s"] == pytest.approx(step["op_s"]) and step["op_s"] == pytest.approx(0.20)
+    ms = sc.per_dispatch_ms(step)
+    assert ms["by_scope_ms"]["mlp"] == pytest.approx(90.0) and ms["unscoped_ms"] == pytest.approx(70.0)
+    # the chunk's twin: the same rule under the other mark
+    chunk = sc.step_by_scope(_events(ops, [("jit__fused_chunk", 0.10, 0.20)], []), sc.CHUNK_MARK)
+    assert chunk["waits_by_scope"] == pytest.approx({"mlp": 0.04})
 
 
 def test_what_a_program_lacks_reads_none_not_zero():
@@ -183,7 +207,9 @@ def test_the_recorded_chip_trace_splits_as_recorded():
     assert idle["idle_s"] == pytest.approx(first["idle_share"] * first["window_s"], rel=1e-6)
     # scoped + unscoped = the step dispatches' op time, which their module time bounds
     assert step["dispatches"] == want["step"]["dispatches"]
-    assert sum(step["by_scope"].values()) + step["unscoped_s"] == pytest.approx(step["op_s"], rel=1e-9)
+    assert sc.scoped_s(step) + step["unscoped_s"] == pytest.approx(step["op_s"], rel=1e-9)
+    assert 0 < sum(step["waits_by_scope"].values()) < want["step"]["unscoped_s"]  # the waits leave the unscoped time
+    assert step["unscoped_s"] + sum(step["waits_by_scope"].values()) == pytest.approx(want["step"]["unscoped_s"], rel=1e-6)
     assert step["op_s"] <= step["module_s"] * (1 + 1e-6)
     for scope, v in want["step"]["by_scope"].items():
         assert step["by_scope"][scope] == pytest.approx(v, rel=1e-6), scope
