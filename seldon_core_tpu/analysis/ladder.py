@@ -13,7 +13,9 @@ half: a program it does not report is invisible to the
   never exercised by it (warmup's own helper methods count — the closure
   over ``self.<method>()`` calls is followed).
 - LC002: a dispatched ``*_fn`` handle missing from
-  ``compile_counts()``/``compile_count()``.
+  ``compile_counts()``/``compile_count()`` (a helper method warmup calls
+  is still a dispatch site here: serving/decode_programs.py warms by the
+  live conventions).
 - LC003: a ``*buckets`` ladder read at a dispatch site but never walked
   by ``warmup()``.
 
@@ -101,12 +103,16 @@ class LadderCoveragePass:
             frontier.extend(_self_calls(methods[name]))
         counted = _attrs_used(counts) if counts is not None else None
 
-        # dispatch sites: first use of each handle/ladder outside warmup
+        # dispatch sites: first use of each handle outside warmup itself
+        # (a round-kind method warmup calls to compile by the live
+        # convention — serving/decode_programs.py — is still a dispatch
+        # site whose handle must be counted), of each ladder outside
+        # warmup's closure (the compile site, not a dispatch)
         handles: dict[str, ast.AST] = {}
         ladders: dict[str, ast.AST] = {}
         for mname, m in methods.items():
-            if mname in seen:
-                continue  # warmup closure is the compile site, not a dispatch
+            if mname == "warmup":
+                continue
             for node in ast.walk(m):
                 if isinstance(node, ast.Call):
                     attr = _is_self_attr(node.func)
@@ -114,7 +120,8 @@ class LadderCoveragePass:
                         handles.setdefault(attr, node)
                 attr = _is_self_attr(node)
                 if (
-                    attr
+                    mname not in seen
+                    and attr
                     and (attr == "buckets" or attr.endswith("_buckets"))
                     and isinstance(node.ctx, ast.Load)
                 ):

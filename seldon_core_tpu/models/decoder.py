@@ -23,6 +23,8 @@ parameter (static at trace time), the zoo entry is ``tiny_gpt``.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -865,6 +867,94 @@ def paged_chunk_prefill(params, pool, bt, tokens, positions, counts):
     with their writes junk-redirected, touching no live page). Returns
     (logits, hidden[n, c, d], pool)."""
     return _paged_forward(params, pool, bt, tokens, positions, counts)
+
+
+def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick, *, attn_kernel=""):
+    """One device program per scheduler step: paged decode_step + sampling
+    + key derivation fused into a single dispatch. Per-step host->device
+    traffic is the block tables plus four tiny vectors, and the readback
+    one [n_slots] int32 — the per-step floor is ONE dispatch, not three.
+    ``tick`` is a traced scalar, so the per-step RNG key needs
+    no host-side split and the program never recompiles. ``attn_kernel``
+    (bound by ``GPT2Decoder.fused_programs``, never traced) is the layers'
+    read side: serving/decode_programs.py ``_step_attn_kernel``'s answer."""
+    logits, _hidden, pool = paged_decode_step(params, pool, bt, tokens, positions, attn_kernel)
+    with jax.named_scope(SCOPE_SAMPLE):
+        key = jax.random.fold_in(jax.random.key(seed), tick)
+        return sample_tokens(logits, temps, topks, key), pool
+
+
+def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
+    """One device program per prefill chunk round: ``paged_chunk_prefill``
+    over every slot (counts-0 slots — generating, free — ride the static
+    shape with their writes junk-redirected) + next-token sampling from
+    each slot's last consumed position, one dispatch. ``ids`` is a
+    [n_slots, c] bucket from the chunk ladder; only the sampled token for
+    slots whose prompt COMPLETED this round is consumed by the host (it is
+    the first generated token). With the monolithic admit path gone, this
+    IS admission's prompt compute — a whole wave prefills in one dispatch
+    at the top bucket, or spread over rounds when chunking is on."""
+    logits, _hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
+    with jax.named_scope(SCOPE_SAMPLE):
+        c = ids.shape[1]
+        idx = jnp.clip(counts - 1, 0, c - 1)
+        last = logits[jnp.arange(ids.shape[0]), idx]  # [n, vocab]
+        key = jax.random.fold_in(jax.random.key(seed), tick)
+        return sample_tokens(last, temps, topks, key), pool
+
+
+_MECHANISMS = {
+    "speculation": "speculative decoding (draft, tree, feature head)",
+    "decode_mesh": "tensor-parallel decode (parallel/tp.py)",
+}
+
+
+def require_served(family, mechanism: str) -> None:
+    """Ask a decoder family whether it serves a decode mechanism (a key of
+    ``_MECHANISMS``): ``FamilyNotServed`` by name where it does not."""
+    if mechanism not in family.serves:
+        raise FamilyNotServed(
+            f"{_MECHANISMS[mechanism]} is not served for the {family.name!r} decoder family"
+        )
+
+
+class GPT2Decoder:
+    """The GPT-2 family as the object the decode scheduler asks — what a
+    decoder family answers, in one list (models/moe_decoder.py
+    ``MoEDecoder`` is the second): ``name``; ``decoder_dims(params)`` (raises
+    ``FamilyNotServed`` for another family's parameters);
+    ``paged_kv_init`` (the zeroed pool); ``frame_counters`` (FlightFrame
+    fields its programs' readback carries after the tokens, none here);
+    ``serves`` (of "speculation", "decode_mesh", "attn_kernel": what
+    beside the plain rounds it can be asked for — ``require_served``);
+    ``fused_programs(attn_kernel)`` (its step and chunk bodies, both named
+    ``_fused_step`` / ``_fused_chunk`` whatever the family)."""
+
+    name = "gpt2"
+    frame_counters = ()
+    serves = frozenset({"speculation", "decode_mesh", "attn_kernel"})
+    decoder_dims = staticmethod(decoder_dims)
+    paged_kv_init = staticmethod(paged_kv_init)
+
+    @functools.lru_cache(maxsize=None)
+    def fused_programs(self, attn_kernel: str = ""):
+        """(``_fused_step``, ``_fused_chunk``); with ``attn_kernel`` the
+        step has the layers' read side bound to it, under the same name.
+        Cached: every scheduler shares the compiled programs."""
+        if not attn_kernel:
+            return _fused_step, _fused_chunk
+        step = functools.partial(_fused_step, attn_kernel=attn_kernel)
+        step.__name__ = step.__qualname__ = _fused_step.__name__
+        return step, _fused_chunk
+
+
+gpt2_family = GPT2Decoder()
+
+
+def decoder_family(family=None):
+    """The family a model's spec names (``ModelSpec.generative["family"]``);
+    the GPT-2 family where it names none — THE default."""
+    return family if family is not None else gpt2_family
 
 
 def draft_propose(

@@ -26,11 +26,12 @@ table by position; the mask is by absolute key position. The pool keeps
 every position of every layer (one allocator, one page kind).
 
 A family is what ``serving/decode_scheduler.py`` takes from the model's
-spec (``ModelSpec.generative["family"]``): an object with ``decoder_dims``,
-``paged_kv_init``, ``paged_decode_step``, ``paged_chunk_prefill`` and
-``paged_verify_step``. ``MoEDecoder`` also counts its routing
-(``frame_counters``) through ``paged_forward``'s extra output, real rows
-only; the scheduler lands the counts in the round's FlightFrame.
+spec (``ModelSpec.generative["family"]``) and asks (the list is
+``decoder.GPT2Decoder``'s docstring): ``name``, ``decoder_dims``,
+``paged_kv_init``, ``frame_counters``, ``serves``, ``fused_programs``.
+``MoEDecoder`` counts its routing (``frame_counters``) through
+``paged_forward``'s extra output, real rows only; the scheduler lands the
+counts in the round's FlightFrame.
 
 Not served yet: speculation (a draft, a tree, a feature head) and
 tensor-parallel decode refuse this family at build (``FamilyNotServed``).
@@ -56,10 +57,12 @@ from seldon_core_tpu.models.decoder import (
     SCOPE_LM_HEAD,
     SCOPE_MLP,
     SCOPE_QKV,
+    SCOPE_SAMPLE,
     FamilyNotServed,
     _paged_gather,
     _paged_write,
     kv_pool_zeros,
+    sample_tokens,
 )
 from seldon_core_tpu.ops.moe import SCOPE_MOE_COMBINE, moe_topk_ffn
 
@@ -372,8 +375,8 @@ def _generate(cfg, params, ids, max_new_tokens: int):
 
 @dataclasses.dataclass(frozen=True)
 class MoEDecoder:
-    """The family object of one configuration: the five entry points the
-    decode scheduler uses, with the configuration's static sizes bound
+    """The family object of one configuration: what the decode scheduler
+    asks of a family, with the configuration's static sizes bound
     (window, period, frequencies and head counts are not readable from
     weight shapes). Hashable: equal configurations share compiled programs."""
 
@@ -382,6 +385,8 @@ class MoEDecoder:
     name = "moe"
     # what paged_forward's extra output counts, in order (FlightFrame fields)
     frame_counters = ("moe_rows", "moe_experts_hit", "moe_load_max")
+    # not served yet (decoder.require_served): speculation, a decode mesh, a step attention kernel
+    serves = frozenset()
 
     def decoder_dims(self, params: dict) -> dict:
         if "lm_head" not in params or "moe" not in params["layers"][0]:
@@ -398,6 +403,41 @@ class MoEDecoder:
 
     def paged_forward(self, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
         return _forward(self.cfg, params, pool, bt, tokens, positions, counts, rows, pick)
+
+    @functools.lru_cache(maxsize=None)
+    def fused_programs(self, attn_kernel: str = ""):
+        """This family's step and chunk bodies, under the GPT-2 family's
+        names — a device trace calls both families' programs
+        ``jit__fused_step`` — with two differences: the step takes ``rows``
+        (which slots generate, so junk rows stay out of the counts), and
+        the counts ride the token readback, appended to it: one
+        [n_slots + len(frame_counters)] int32 array, one transfer. The
+        chunk's head runs on each slot's last real row only. Cached: equal
+        configurations share compiled programs."""
+
+        def sample_and_count(logits, counted, temps, topks, seed, tick):
+            with jax.named_scope(SCOPE_SAMPLE):
+                key = jax.random.fold_in(jax.random.key(seed), tick)
+                toks = sample_tokens(logits[:, 0, :], temps, topks, key)
+                return jnp.concatenate([toks, counted])
+
+        def step(params, pool, bt, tokens, positions, temps, topks, seed, tick, rows):
+            logits, _hidden, pool, counted = self.paged_forward(
+                params, pool, bt, tokens[:, None], positions, rows=rows
+            )
+            return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+
+        def chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
+            idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
+            logits, _hidden, pool, counted = self.paged_forward(
+                params, pool, bt, ids, positions, counts=counts, pick=idx
+            )
+            return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+
+        # jit names a program after its function: the trace's name for both families
+        step.__name__ = step.__qualname__ = "_fused_step"
+        chunk.__name__ = chunk.__qualname__ = "_fused_chunk"
+        return step, chunk
 
     def paged_decode_step(self, params, pool, bt, tokens, positions):
         logits, hidden, pool, _ = _forward(self.cfg, params, pool, bt, tokens[:, None], positions)

@@ -120,13 +120,18 @@ Compile discipline: every device program is compiled once at ``warmup()``;
 ``compile_counts()`` exposes the jit cache sizes so serving can assert zero
 recompiles across changing batch composition (the same no-live-compile
 policy ModelRuntime enforces with shape buckets).
+
+The fused programs themselves, their jit handles, the device state only
+they touch and the convention each round kind is called by live in
+serving/decode_programs.py (``DecodePrograms``); a decoder family's step
+and chunk bodies are the family's own (``family.fused_programs``). This
+module is the loop: admission, the rounds (each written once), timing.
 """
 
 from __future__ import annotations
 
 import asyncio
 import collections
-import functools
 import logging
 import time
 from typing import Callable
@@ -170,27 +175,11 @@ from seldon_core_tpu.telemetry.flight import (
     sync_timing_enabled,
 )
 from seldon_core_tpu.telemetry.flight import register as flight_register
-from seldon_core_tpu.models import decoder as gpt2_family
 from seldon_core_tpu.models.decoder import (
-    SCOPE_SAMPLE,
-    FamilyNotServed,
     decoder_dims,
-    draft_propose,
-    draft_propose_features,
-    draft_propose_tree,
-    draft_tree_commit,
-    feature_chunk_prefill,
-    init_slot_cache,
+    decoder_family,
     is_feature_draft,
-    paged_chunk_prefill,
-    paged_decode_step,
-    paged_tree_commit,
-    paged_tree_verify,
-    paged_verify_step,
-    prefill,
-    sample_tokens,
-    speculative_accept,
-    speculative_accept_tree,
+    require_served,
 )
 from seldon_core_tpu.models.spec_tree import MAX_TREE_NODES, SpecTree, parse_spec_tree
 from seldon_core_tpu.parallel.tp import (
@@ -199,13 +188,13 @@ from seldon_core_tpu.parallel.tp import (
     decode_tp_mesh,
     decoder_param_shardings,
     kv_sharding,
-    tree_node_sharding,
 )
 from seldon_core_tpu.serving.affinity_router import (
     capture_prefix_len,
     usable_prefix_len,
 )
-from seldon_core_tpu.ops.paged_attention import mosaic_tiles, pages_read
+from seldon_core_tpu.ops.paged_attention import pages_read
+from seldon_core_tpu.serving.decode_programs import DecodePrograms
 from seldon_core_tpu.serving.kv_host_tier import KVHostTier
 from seldon_core_tpu.serving.kv_pool import PagedKVPool
 from seldon_core_tpu.persistence.state import make_state_store
@@ -213,294 +202,6 @@ from seldon_core_tpu.persistence.state import make_state_store
 log = logging.getLogger(__name__)
 
 OnToken = Callable[[int, int], None]  # (token_id, index-within-generation)
-
-
-def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick, *, attn_kernel=""):
-    """One device program per scheduler step: paged decode_step + sampling
-    + key derivation fused into a single dispatch. Per-step host->device
-    traffic is the block tables plus four tiny vectors, and the readback
-    one [n_slots] int32 — the per-step floor is ONE dispatch, not three.
-    ``tick`` is a traced scalar, so the per-step RNG key needs
-    no host-side split and the program never recompiles. ``attn_kernel``
-    (bound by ``_family_programs``, never traced) is the layers' read side:
-    ``_step_attn_kernel``'s answer."""
-    logits, _hidden, pool = paged_decode_step(params, pool, bt, tokens, positions, attn_kernel)
-    with jax.named_scope(SCOPE_SAMPLE):
-        key = jax.random.fold_in(jax.random.key(seed), tick)
-        return sample_tokens(logits, temps, topks, key), pool
-
-
-def _scatter_prefill_rows(cache_k, cache_v, k_new, v_new, row_for_slot, valid_slot):
-    """Write a prefill wave's K/V into each row's own slot as ONE masked
-    gather + slice update, vectorized over SLOTS (DRAFT cache only since
-    the paged pool took over the target side — the draft keeps the flat
-    slot layout because its whole point is to be small): slot j takes wave
-    row ``row_for_slot[j]`` iff ``valid_slot[j]`` and keeps its current
-    bytes otherwise. Pivoting the mapping to the slot axis makes the write
-    conflict-free by construction (each slot SELECTS its row — no scatter
-    with duplicate destination indices exists)."""
-    s = k_new.shape[3]
-    sel_k = jnp.take(k_new, row_for_slot, axis=1)  # [L, n_slots, h, s, hd]
-    sel_v = jnp.take(v_new, row_for_slot, axis=1)
-    mask = valid_slot[None, :, None, None, None]
-    cache_k = cache_k.at[:, :, :, :s, :].set(
-        jnp.where(mask, sel_k, cache_k[:, :, :, :s, :])
-    )
-    cache_v = cache_v.at[:, :, :, :s, :].set(
-        jnp.where(mask, sel_v, cache_v[:, :, :, :s, :])
-    )
-    return cache_k, cache_v
-
-
-def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
-    """One device program per prefill chunk round: ``paged_chunk_prefill``
-    over every slot (counts-0 slots — generating, free — ride the static
-    shape with their writes junk-redirected) + next-token sampling from
-    each slot's last consumed position, one dispatch. ``ids`` is a
-    [n_slots, c] bucket from the chunk ladder; only the sampled token for
-    slots whose prompt COMPLETED this round is consumed by the host (it is
-    the first generated token). With the monolithic admit path gone, this
-    IS admission's prompt compute — a whole wave prefills in one dispatch
-    at the top bucket, or spread over rounds when chunking is on."""
-    logits, _hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
-    with jax.named_scope(SCOPE_SAMPLE):
-        c = ids.shape[1]
-        idx = jnp.clip(counts - 1, 0, c - 1)
-        last = logits[jnp.arange(ids.shape[0]), idx]  # [n, vocab]
-        key = jax.random.fold_in(jax.random.key(seed), tick)
-        return sample_tokens(last, temps, topks, key), pool
-
-
-def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int) -> str:
-    """How the fused decode step's attention reads the pool — THE place the
-    choice is made, from what the scheduler can observe and nothing else (no
-    knob): "mosaic", the Pallas kernel that reads the pool's pages in place
-    and stops at each slot's length (ops/paged_attention.py), where the
-    family is the GPT-2 block, the pool is the two-component float pool,
-    there is no decode mesh, the pool lies on one device that is a TPU, and
-    Mosaic can tile the pool's rows and pages (``mosaic_tiles``: gpt2-xl's
-    rows of 1600 and pages of 4 rows are outside it);
-    "" — the page gather and the flat path's attention — everywhere else:
-    the int8 pool, a tensor-parallel mesh, the second family, a geometry
-    the kernel cannot tile, the CPU backend (where the gather is the oracle
-    that is bit-identical to the flat path). The dispatch's shape is the
-    program's own to see: only one query a slot takes the kernel
-    (models/decoder.py ``_layer_step_paged``), so chunk, verify and tree
-    programs gather whatever this says."""
-    if family is not gpt2_family or mesh is not None or len(pool_state) != 2:
-        return ""
-    devices = pool_state[0].sharding.device_set
-    if len(devices) != 1 or next(iter(devices)).platform != "tpu":
-        return ""
-    _layers, _pages, page_size, row_width = pool_state[0].shape
-    if not mosaic_tiles(row_width, heads, page_size, pool_state[0].dtype):
-        return ""
-    return "mosaic"
-
-
-@functools.lru_cache(maxsize=None)
-def _family_programs(family, attn_kernel=""):
-    """(``_fused_step``, ``_fused_chunk``) of a decoder family. The GPT-2
-    family (models/decoder.py, the module itself) has the two above; with
-    ``attn_kernel`` (static: ``_step_attn_kernel``'s answer) its step is
-    ``_fused_step`` with the layers' read side bound to it, under the same
-    name. A
-    family that counts what its forward does (``frame_counters``,
-    models/moe_decoder.py) gets its own pair under the SAME names — a
-    device trace calls both families' programs ``jit__fused_step`` — with
-    two differences: the step takes ``rows`` (which slots generate, so junk
-    rows stay out of the counts), and the counts ride the token readback,
-    appended to it: one [n_slots + len(frame_counters)] int32 array, one
-    transfer. The chunk's head runs on each slot's last real row only.
-    Cached per family: equal configurations share compiled programs."""
-    if family is gpt2_family:
-        if not attn_kernel:
-            return _fused_step, _fused_chunk
-        step = functools.partial(_fused_step, attn_kernel=attn_kernel)
-        step.__name__ = step.__qualname__ = _fused_step.__name__
-        return step, _fused_chunk
-
-    def sample_and_count(logits, counted, temps, topks, seed, tick):
-        with jax.named_scope(SCOPE_SAMPLE):
-            key = jax.random.fold_in(jax.random.key(seed), tick)
-            toks = sample_tokens(logits[:, 0, :], temps, topks, key)
-            return jnp.concatenate([toks, counted])
-
-    def step(params, pool, bt, tokens, positions, temps, topks, seed, tick, rows):
-        logits, _hidden, pool, counted = family.paged_forward(
-            params, pool, bt, tokens[:, None], positions, rows=rows
-        )
-        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
-
-    def chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
-        idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
-        logits, _hidden, pool, counted = family.paged_forward(
-            params, pool, bt, ids, positions, counts=counts, pick=idx
-        )
-        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
-
-    # jit names a program after its function: the trace's name for both families
-    step.__name__ = step.__qualname__ = _fused_step.__name__
-    chunk.__name__ = chunk.__qualname__ = _fused_chunk.__name__
-    return step, chunk
-
-
-def _fused_draft_admit(params, dcache_k, dcache_v, ids, row_for_slot, valid_slot):
-    """Draft-side prompt prefill for slots whose TARGET prefill completed:
-    the draft shares no K/V with the target's page pool, so its flat cache
-    takes the FULL prompt in one bucketed dispatch at transition time —
-    target-side prefix reuse never skews the draft's proposal distribution
-    (and greedy acceptance is bit-exact for ANY draft state regardless)."""
-    _, k_new, v_new = prefill(params, ids)
-    return _scatter_prefill_rows(
-        dcache_k, dcache_v, k_new, v_new, row_for_slot, valid_slot
-    )
-
-
-def _fused_draft(params, cache_k, cache_v, tokens, positions, temps, topks, seed, tick, k):
-    """One device program per speculation round, draft side: k
-    autoregressive draft steps (models/decoder.draft_propose) with the
-    per-tick RNG stream forked from the step programs' (fold_in 1)."""
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 1)
-    return draft_propose(
-        params, cache_k, cache_v, tokens, positions, temps, topks, key, k
-    )
-
-
-def _fused_verify(
-    params, pool, bt, tokens, drafts, draft_logits,
-    positions, limits, temps, topks, seed, tick,
-):
-    """One device program per speculation round, target side: the widened
-    [n, k+1] paged verify step + the acceptance rule, reading back only
-    (out_tokens [n, k+1], n_accepted [n]). The draft's proposals and raw
-    logits stay on device between the two dispatches."""
-    queries = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [n, k+1]
-    logits, _hidden, pool = paged_verify_step(params, pool, bt, queries, positions)
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 2)
-    out, acc = speculative_accept(
-        logits, drafts, draft_logits, limits, temps, topks, key
-    )
-    return out, acc, pool
-
-
-def _fused_draft_tree(
-    params, cache_k, cache_v, tokens, positions, temps, topks, seed, tick, tree
-):
-    """One device program per TREE speculation round, draft side: a root
-    decode step + ``tree.depth`` unrolled widened expansions proposing the
-    whole candidate tree (models/decoder.draft_propose_tree). The
-    speculative node K/V comes back in-register — the draft cache gains
-    only the root's entry; the verify dispatch commits the accepted path."""
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 1)
-    return draft_propose_tree(
-        params, cache_k, cache_v, tokens, positions, temps, topks, key, tree
-    )
-
-
-def _fused_tree_verify(
-    params, pool, bt, tokens, node_tokens, block_logits, node_k, node_v,
-    dck, dcv, positions, width_limits, temps, topks, seed, tick, tree,
-):
-    """One device program per TREE speculation round, target side: the
-    whole flattened tree scored in ONE widened dispatch
-    (paged_tree_verify — the pool is NOT written by the forward), the
-    longest-accepted-path walk, then BOTH commits: the accepted path's
-    target K/V through the block tables (non-accepted columns
-    junk-redirected — the pool never holds speculative garbage) and its
-    draft K/V into the flat draft cache. Readback is (out_tokens
-    [n, depth+1], n_accepted [n]); everything else stays on device."""
-    queries = jnp.concatenate([tokens[:, None], node_tokens], axis=1)  # [n, width]
-    logits, _hidden, new_k, new_v = paged_tree_verify(
-        params, pool, bt, queries, positions, tree
-    )
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 2)
-    out, acc, path_idx = speculative_accept_tree(
-        logits, queries, block_logits, width_limits, temps, topks, key, tree
-    )
-    pool = paged_tree_commit(pool, bt, new_k, new_v, path_idx, positions, acc)
-    dck, dcv = draft_tree_commit(dck, dcv, node_k, node_v, path_idx, positions, acc)
-    return out, acc, pool, dck, dcv
-
-
-def _fused_step_feat(
-    params, pool, bt, tokens, positions, feats, fmask, temps, topks, seed, tick
-):
-    """``_fused_step`` for feature-draft deployments: the same fused
-    decode+sample dispatch, additionally round-tripping the per-slot
-    FEATURE buffer — the consumed position's final-layer hidden replaces
-    the slot's carried feature wherever ``fmask`` (generating,
-    non-prefilling slots) holds, so a degraded/mixed plain round keeps
-    the next speculative round's draft root correctly conditioned."""
-    logits, hidden, pool = paged_decode_step(params, pool, bt, tokens, positions)
-    key = jax.random.fold_in(jax.random.key(seed), tick)
-    new_feats = jnp.where(fmask[:, None], hidden, feats)
-    return sample_tokens(logits, temps, topks, key), new_feats, pool
-
-
-def _fused_chunk_feat(
-    params, fparams, pool, bt, dck, dcv, ids, positions, counts, feats,
-    starts, temps, topks, seed, tick,
-):
-    """``_fused_chunk`` for feature-draft deployments: the target chunk
-    prefill PLUS the head's teacher-forced prefill over the same chunk
-    (models/decoder.feature_chunk_prefill — the head's K/V is written
-    under the same counts mask, so the separate draft-admit program is
-    gone in feature mode), and the per-slot feature carry: slots that
-    consumed prompt tokens this round update their feature to the chunk's
-    last computed hidden; everyone else keeps theirs."""
-    logits, hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
-    c = ids.shape[1]
-    rows = jnp.arange(ids.shape[0])
-    idx = jnp.clip(counts - 1, 0, c - 1)
-    last = logits[rows, idx]  # [n, vocab]
-    dck, dcv = feature_chunk_prefill(
-        fparams, dck, dcv, ids, hidden, feats, positions, counts, starts
-    )
-    new_feats = jnp.where((counts > 0)[:, None], hidden[rows, idx], feats)
-    key = jax.random.fold_in(jax.random.key(seed), tick)
-    return sample_tokens(last, temps, topks, key), new_feats, pool, dck, dcv
-
-
-def _fused_draft_feat(
-    fparams, dck, dcv, feats, tokens, positions, starts, temps, topks, seed, tick, tree
-):
-    """One device program per FEATURE speculation round, draft side: the
-    head's root step (fusing the slot's carried target feature with the
-    last emitted token) + ``tree.depth`` unrolled feature-autoregressive
-    expansions (models/decoder.draft_propose_features). Same RNG stream
-    and return layout as the token tree draft."""
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 1)
-    return draft_propose_features(
-        fparams, dck, dcv, feats, tokens, positions, starts, temps, topks, key, tree
-    )
-
-
-def _fused_ftree_verify(
-    params, pool, bt, tokens, node_tokens, block_logits, node_k, node_v,
-    dck, dcv, feats, fmask, positions, width_limits, temps, topks, seed,
-    tick, tree,
-):
-    """``_fused_tree_verify`` for feature-draft deployments: identical
-    widened verify + longest-accepted-path walk + both commits, plus the
-    FEATURE carry the head needs for the next round's root — the target's
-    final-layer hidden at the accepted path's LAST block (root when
-    nothing accepted), selected on device so the readback stays
-    (out_tokens, n_accepted)."""
-    queries = jnp.concatenate([tokens[:, None], node_tokens], axis=1)  # [n, width]
-    logits, hidden, new_k, new_v = paged_tree_verify(
-        params, pool, bt, queries, positions, tree
-    )
-    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), tick), 2)
-    out, acc, path_idx = speculative_accept_tree(
-        logits, queries, block_logits, width_limits, temps, topks, key, tree
-    )
-    pool = paged_tree_commit(pool, bt, new_k, new_v, path_idx, positions, acc)
-    dck, dcv = draft_tree_commit(dck, dcv, node_k, node_v, path_idx, positions, acc)
-    rows = jnp.arange(tokens.shape[0])
-    last_blk = jnp.take_along_axis(path_idx, acc[:, None], axis=1)[:, 0]
-    new_feats = jnp.where(fmask[:, None], hidden[rows, last_blk], feats)
-    return out, acc, pool, dck, dcv, new_feats
 
 
 class _TreeAutoTuner:
@@ -705,31 +406,30 @@ class _EnqueueSpan:
 class _Dispatch:
     """One device dispatch as the round loop sees it — THE place a
     dispatch is timed into the round's flight frame and named for a
-    profiler session; ``_timed_call``, the pipelined step and the
-    speculative round pairs all go through ``with self._dispatch(F_X) as
-    d:`` (one preallocated handle per family; the loop awaits each
-    dispatch, so a family never nests in itself).
+    profiler session; ``_timed_call``, the step round and the speculative
+    round pair all go through ``with self._dispatch(F_X) as d:`` (one
+    preallocated handle per family; the loop awaits each dispatch, so a
+    family never nests in itself).
 
     On the loop the handle spans hand-off to readback return
     (``ANN_DISPATCH``): that wall is the family's ``busy_ns``, and the part
-    after the enqueue->readback mark its ``rdb_ns``. Inside it, on
-    whichever thread does the work: ``d.enqueue()`` round the program
-    call(s), ``await d.readback(fn)`` for the blocking host read, or
-    ``await d.run(fn)`` for a ``_do_*`` closure that makes both and calls
-    ``_mark_enqueued()`` between them."""
+    after the enqueue->readback mark its ``rdb_ns``. Inside it, a program
+    set call (``DecodePrograms``: enqueues, returns ``(out, read)``) goes
+    one of two ways: ``with d.enqueue():`` round the call on the loop, the
+    overlap window, then ``await d.readback(read)`` (the pipelined round);
+    or ``await d.run(fn)``, which makes call, mark and read in one piece
+    off the loop (the serial round, the chunk round, the copy ladder)."""
 
-    __slots__ = ("s", "family", "t0", "carved", "ann", "exec_ann")
+    __slots__ = ("s", "family", "t0", "carved", "ann")
 
     def __init__(self, sched: "DecodeScheduler", family: int):
         self.s = sched
         self.family = family
         self.carved = 0
-        self.exec_ann = None
 
     def __enter__(self):
         s = self.s
         s._rb_mark_ns = 0
-        s._dispatch_open = self
         self.carved = 0
         self.ann = flight_mod.annotate(ANN_DISPATCH[self.family])
         self.t0 = time.perf_counter_ns()
@@ -747,30 +447,42 @@ class _Dispatch:
     def enqueue(self, family: int | None = None) -> _EnqueueSpan:
         return _EnqueueSpan(self, self.family if family is None else family)
 
-    def mark(self) -> None:
-        """The enqueue->readback boundary (``_mark_enqueued``): the
-        family's wall splits here, and inside ``run`` the thread's
-        annotation turns from enqueue to readback."""
-        self.s._rb_mark_ns = time.perf_counter_ns()
-        if self.exec_ann is not None:
-            self.exec_ann.__exit__(None, None, None)
-            self.exec_ann = flight_mod.annotate(ANN_READBACK[self.family])
-
     async def run(self, fn):
-        """A ``_do_*`` closure through ``_device_call``: program call,
-        ``_mark_enqueued()``, blocking read — annotated on the thread that
-        runs it. No mark = the whole call is enqueue (the copy ladder
-        reads nothing back)."""
+        """``fn`` enqueues and returns a program set call's ``(out,
+        read)``: run it through ``_device_call`` under ``ANN_ENQUEUE``,
+        mark the enqueue->readback boundary — the family's wall splits
+        there and the thread's annotation turns to ``ANN_READBACK`` — and
+        make the blocking read. Under ENGINE_FLIGHT_SYNC_TIMING the
+        dispatch is blocked on before the mark, so the enqueue column is
+        ground-truth device wall. ``fn`` returning None reads nothing back:
+        the whole call counts as enqueue (the copy ladder)."""
+        s = self.s
 
         def call():
-            self.exec_ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
+            ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
             try:
-                return fn()
+                queued = fn()
+                if queued is None:
+                    return None
+                out, read = queued
+                if s._sync_timing:
+                    jax.block_until_ready(out)
+                s._rb_mark_ns = time.perf_counter_ns()
+                ann.__exit__(None, None, None)
+                ann = flight_mod.annotate(ANN_READBACK[self.family])
+                return read()
             finally:
-                self.exec_ann.__exit__(None, None, None)
-                self.exec_ann = None
+                ann.__exit__(None, None, None)
 
-        return await self.s._device_call(call)
+        out = await s._device_call(call)
+        if s._faults is not None:
+            # chaos readback stall: the dispatch completed but the
+            # host-transfer wait drags — attributed to the family's
+            # readback column like a real slow transfer would be
+            stall = s._faults.readback_stall_s()
+            if stall > 0:
+                await asyncio.sleep(stall)
+        return out
 
     async def readback(self, fn):
         """The blocking host read of a dispatch the loop enqueued itself
@@ -1037,22 +749,14 @@ class DecodeScheduler:
                 f"spec_k={spec_k} needs a draft model (decode_draft_model)"
             )
         # the decoder family: the model's spec names it
-        # (ModelSpec.generative["family"]); the GPT-2 family of
-        # models/decoder.py where it names none
-        self.family = family if family is not None else gpt2_family
-        self._frame_counters = tuple(getattr(self.family, "frame_counters", ()))
-        if self.family is not gpt2_family and (
-            draft_params is not None or spec_k > 0 or str(spec_tree or "").strip()
-        ):
-            raise FamilyNotServed(
-                f"speculative decoding (draft, tree, feature head) is not served "
-                f"for the {self.family.name!r} decoder family"
-            )
-        if self.family is not gpt2_family and tp_width(mesh_axes) > 1:
-            raise FamilyNotServed(
-                f"tensor-parallel decode (parallel/tp.py) is not served for the "
-                f"{self.family.name!r} decoder family"
-            )
+        # (ModelSpec.generative["family"]); what it does not serve it
+        # refuses by name (FamilyNotServed)
+        self.family = decoder_family(family)
+        self._frame_counters = tuple(self.family.frame_counters)
+        if draft_params is not None or spec_k > 0 or str(spec_tree or "").strip():
+            require_served(self.family, "speculation")
+        if tp_width(mesh_axes) > 1:
+            require_served(self.family, "decode_mesh")
         dims = self.family.decoder_dims(params)
         self.max_ctx = seq_len + max_new_tokens
         if self.max_ctx > dims["max_len"]:
@@ -1078,7 +782,6 @@ class DecodeScheduler:
         # /decode/health so the affinity router can address it
         self.replica_id = int(replica_id)
         self._dtype = dtype
-        self._seed = np.int32(seed)
         # monotonically increasing RNG tick, folded into the seed key
         # inside the compiled programs (a traced scalar — never a recompile)
         self._tick = 0
@@ -1301,148 +1004,15 @@ class DecodeScheduler:
                 deployment=deployment_name or "decode",
                 metrics=metrics,
             )
-        if self.spec_enabled:
-            self._dck, self._dcv = self._commit_kv(
-                draft_params, init_slot_cache(draft_params, n_slots, self._draft_ctx, dtype)
-            )
-        if self.feature_draft:
-            # per-slot carried target feature f_{pos-1} (device-resident,
-            # round-tripped through every fused program that can move a
-            # slot's position — step/chunk/verify — so the next round's
-            # draft root is always conditioned on the LAST consumed
-            # position's hidden) and the per-slot draft attention window
-            # start (host data: the computed suffix boundary on warm
-            # prefix-reuse admissions)
-            self._feat = self._commit_kv(
-                params, (jnp.zeros((n_slots, dims["hidden"]), dtype),)
-            )[0]
-            self._draft_start = np.zeros(n_slots, np.int32)
-        # compiled programs — the pool state tuple is donated so page
-        # updates are in-place in HBM. The step program is ONE executable;
-        # the chunk ladder compiles one per bucket; the pool's CoW copy
-        # ladder one per copy bucket — all at warmup(). With speculation
-        # on, three more join: the k-step draft loop, the widened paged
-        # verify, and the draft's transition-time flat prompt prefill. The
-        # plain step program stays warm either way — it serves rounds
-        # where every active slot's effective spec_k is 0. On a decode
-        # mesh, OUTPUT shardings are pinned to the mesh layout so the
-        # donated pool/draft state round-trips every program with one
-        # stable signature (warmup == live traffic — zero recompiles,
-        # same as single-device).
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            rep = NamedSharding(self.mesh, P())
-            pool_sh = self.pool.state_shardings
-            step_kw = {"out_shardings": (rep, pool_sh)}
-            verify_kw = {"out_shardings": (rep, rep, pool_sh)}
-            dc_sh = (
-                tuple(
-                    kv_sharding(self.mesh, self._tp_axis, a)
-                    for a in (self._dck, self._dcv)
-                )
-                if self.spec_enabled
-                else None
-            )
-            draft_kw = {"out_shardings": (rep, rep) + dc_sh} if dc_sh else {}
-            draft_admit_kw = {"out_shardings": dc_sh} if dc_sh else {}
-            # tree round pair: the in-register node K/V rides head-sharded
-            # like every 5-D KV buffer; the TREE axis is replicated (heads
-            # stay sharded — parallel/tp.py), so the widened dispatch
-            # needs no new collective beyond the fused all-reduces
-            kvp = tree_node_sharding(self.mesh, self._tp_axis)
-            draft_tree_kw = (
-                {"out_shardings": (rep, rep, kvp, kvp) + dc_sh} if dc_sh else {}
-            )
-            tree_verify_kw = (
-                {"out_shardings": (rep, rep, pool_sh) + dc_sh} if dc_sh else {}
-            )
-            # feature-draft twins: the feat buffer [n_slots, hidden] is
-            # replicated (it feeds the fc fuse on every device)
-            step_f_kw = {"out_shardings": (rep, rep, pool_sh)}
-            chunk_f_kw = (
-                {"out_shardings": (rep, rep, pool_sh) + dc_sh} if dc_sh else {}
-            )
-            ftree_verify_kw = (
-                {"out_shardings": (rep, rep, pool_sh) + dc_sh + (rep,)}
-                if dc_sh
-                else {}
-            )
-        else:
-            step_kw = verify_kw = draft_kw = draft_admit_kw = {}
-            draft_tree_kw = tree_verify_kw = {}
-            step_f_kw = chunk_f_kw = ftree_verify_kw = {}
-        # the plain step's read side (the feature twins keep the gather)
-        self._attn_kernel = (
-            "" if self.feature_draft
-            else _step_attn_kernel(self.family, self.pool.state, self.mesh, dims["heads"])
+        # the compiled programs, the device state only they touch (draft
+        # cache pair, feature carry) and the one convention each round kind
+        # is called by: serving/decode_programs.py
+        self.programs = DecodePrograms(
+            self.family, params, self.draft_params, self.pool, dims=dims,
+            n_slots=n_slots, seq_len=seq_len, seed=seed, mesh=self.mesh,
+            tp_axis=self._tp_axis, spec_k=self.spec_k, spec_tree=self.spec_tree,
+            draft_ctx=self._draft_ctx, dtype=dtype, place=self._commit_kv,
         )
-        if self.feature_draft:
-            # feature mode swaps the step/chunk pair for feature-carrying
-            # twins (the chunk one also teacher-forces the head's prompt
-            # K/V, so the separate draft-admit ladder is gone)
-            self._step_f_fn = jax.jit(
-                _fused_step_feat, donate_argnums=(1, 5), **step_f_kw
-            )
-            self._chunk_f_fn = jax.jit(
-                _fused_chunk_feat, donate_argnums=(2, 4, 5, 9), **chunk_f_kw
-            )
-        else:
-            step, chunk = _family_programs(self.family, self._attn_kernel)
-            self._step_fn = jax.jit(step, donate_argnums=(1,), **step_kw)
-            self._chunk_fn = jax.jit(chunk, donate_argnums=(1,), **step_kw)
-        if self.spec_enabled:
-            if self.feature_draft:
-                self._draft_feat_fn = jax.jit(
-                    _fused_draft_feat,
-                    donate_argnums=(1, 2),
-                    static_argnums=(11,),
-                    **draft_tree_kw,
-                )
-                self._ftree_verify_fn = jax.jit(
-                    _fused_ftree_verify,
-                    donate_argnums=(1, 8, 9, 10),
-                    static_argnums=(18,),
-                    **ftree_verify_kw,
-                )
-            elif self.spec_tree is not None:
-                # tree mode subsumes the chain (a branching-1 tree IS the
-                # chain), so the chain draft/verify pair is not compiled —
-                # per-request chain/plain tightening rides the SAME tree
-                # programs through data-only width masks
-                self._draft_tree_fn = jax.jit(
-                    _fused_draft_tree,
-                    donate_argnums=(1, 2),
-                    static_argnums=(9,),
-                    **draft_tree_kw,
-                )
-                self._tree_verify_fn = jax.jit(
-                    _fused_tree_verify,
-                    donate_argnums=(1, 8, 9),
-                    static_argnums=(16,),
-                    **tree_verify_kw,
-                )
-            else:
-                self._draft_fn = jax.jit(
-                    _fused_draft, donate_argnums=(1, 2), static_argnums=(9,), **draft_kw
-                )
-                self._verify_fn = jax.jit(
-                    _fused_verify, donate_argnums=(1,), **verify_kw
-                )
-            if not self.feature_draft:
-                self._draft_admit_fn = jax.jit(
-                    _fused_draft_admit, donate_argnums=(1, 2), **draft_admit_kw
-                )
-                # wave buckets for the draft's transition-time flat prefill
-                # — the only surviving consumer of the admit ladder now
-                # that the target side admits through the chunk programs
-                # (the feature head's prompt K/V rides the chunk ladder)
-                buckets = []
-                b = 1
-                while b < n_slots:
-                    buckets.append(b)
-                    b *= 2
-                self.admit_buckets = tuple(buckets) + (n_slots,)
         # on an accelerator, device dispatch + token readback block the
         # calling thread for the device-step latency — run them on the
         # shared compute pool so the serving event loop (ingress, batcher
@@ -1556,7 +1126,6 @@ class DecodeScheduler:
         # flight kill switch (disabled timer = shared no-op handles).
         self._phases = PhaseTimer(enabled=self.flight.enabled)
         self._dispatches = tuple(_Dispatch(self, f) for f in range(len(ANN_DISPATCH)))
-        self._dispatch_open: _Dispatch | None = None
         self._round_ann = None  # the open ANN_ROUND trace annotation
         # ENGINE_FLIGHT_SYNC_TIMING=on: block on every dispatch so the
         # per-family flight columns are ground-truth device wall
@@ -1645,106 +1214,12 @@ class DecodeScheduler:
     # ---------------------------------------------------------------- warmup
     def warmup(self) -> None:
         """Compile every device program ahead of traffic (the chunk ladder,
-        the step program, the pool's CoW copy ladder, and the speculation
-        trio). Serving must never pay an XLA compile on a live request —
-        compile_counts() after this is the zero-recompile baseline.
-        Warmup dispatches write only into junk page 0 (all-zero block
-        tables, counts 0), so they touch no live bytes."""
+        the step program, the pool's CoW copy ladder, the draft-admit
+        ladder and the speculation pair: ``DecodePrograms.warmup``).
+        Serving must never pay an XLA compile on a live request —
+        compile_counts() after this is the zero-recompile baseline."""
         t0 = time.perf_counter()
-        zslot = np.zeros(self.n_slots, np.int32)
-        vslot = np.zeros(self.n_slots, bool)
-        bt0 = self.pool.block_tables()  # all-zero: every write junk-sinks
-        for c in self.chunk_buckets:
-            if self.feature_draft:
-                # counts 0: the head's teacher-forced writes mask off and
-                # the feat carry keeps its zeros — no live bytes touched
-                toks, self._feat, self.pool.state, self._dck, self._dcv = (
-                    self._chunk_f_fn(
-                        self.params, self.draft_params, self.pool.state, bt0,
-                        self._dck, self._dcv,
-                        np.zeros((self.n_slots, c), np.int32),
-                        zslot, zslot, self._feat, zslot,
-                        np.zeros(self.n_slots, np.float32), zslot,
-                        self._seed, np.int32(0),
-                    )
-                )
-            else:
-                toks, self.pool.state = self._chunk_fn(
-                    self.params, self.pool.state, bt0,
-                    np.zeros((self.n_slots, c), np.int32),
-                    zslot, zslot,
-                    np.zeros(self.n_slots, np.float32), zslot,
-                    self._seed, np.int32(0),
-                )
-        self.pool.warmup()  # the CoW copy ladder (page0 self-copies)
-        if self.spec_enabled and not self.feature_draft:
-            for b in self.admit_buckets:
-                self._dck, self._dcv = self._draft_admit_fn(
-                    self.draft_params, self._dck, self._dcv,
-                    np.zeros((b, self.seq_len), np.int32), zslot, vslot,
-                )
-        if self.feature_draft:
-            many, self._feat, self.pool.state = self._step_f_fn(
-                self.params, self.pool.state, bt0,
-                np.zeros(self.n_slots, np.int32), np.zeros(self.n_slots, np.int32),
-                self._feat, vslot,
-                np.zeros(self.n_slots, np.float32), np.zeros(self.n_slots, np.int32),
-                self._seed, np.int32(0),
-            )
-        else:
-            many, self.pool.state = self._step_fn(
-                self.params, self.pool.state, bt0,
-                np.zeros(self.n_slots, np.int32), np.zeros(self.n_slots, np.int32),
-                np.zeros(self.n_slots, np.float32), np.zeros(self.n_slots, np.int32),
-                self._seed, np.int32(0), *((vslot,) if self._frame_counters else ()),
-            )
-        if self.spec_enabled:
-            # the speculative round pair: junk writes land in page 0
-            zi = np.zeros(self.n_slots, np.int32)
-            zf = np.zeros(self.n_slots, np.float32)
-            if self.feature_draft:
-                node_toks, blogits, nk, nv, self._dck, self._dcv = (
-                    self._draft_feat_fn(
-                        self.draft_params, self._dck, self._dcv, self._feat,
-                        zi, zi, zi, zf, zi, self._seed, np.int32(0),
-                        self.spec_tree,
-                    )
-                )
-                wl0 = np.zeros((self.n_slots, self.spec_tree.depth), np.int32)
-                out_t, acc, self.pool.state, self._dck, self._dcv, self._feat = (
-                    self._ftree_verify_fn(
-                        self.params, self.pool.state, bt0, zi, node_toks,
-                        blogits, nk, nv, self._dck, self._dcv, self._feat,
-                        vslot, zi, wl0, zf, zi, self._seed, np.int32(0),
-                        self.spec_tree,
-                    )
-                )
-            elif self.spec_tree is not None:
-                node_toks, blogits, nk, nv, self._dck, self._dcv = (
-                    self._draft_tree_fn(
-                        self.draft_params, self._dck, self._dcv,
-                        zi, zi, zf, zi, self._seed, np.int32(0), self.spec_tree,
-                    )
-                )
-                wl0 = np.zeros((self.n_slots, self.spec_tree.depth), np.int32)
-                out_t, acc, self.pool.state, self._dck, self._dcv = (
-                    self._tree_verify_fn(
-                        self.params, self.pool.state, bt0, zi, node_toks,
-                        blogits, nk, nv, self._dck, self._dcv,
-                        zi, wl0, zf, zi, self._seed, np.int32(0), self.spec_tree,
-                    )
-                )
-            else:
-                drafts, dlogits, self._dck, self._dcv = self._draft_fn(
-                    self.draft_params, self._dck, self._dcv,
-                    zi, zi, zf, zi, self._seed, np.int32(0), self.spec_k,
-                )
-                out_t, acc, self.pool.state = self._verify_fn(
-                    self.params, self.pool.state, bt0,
-                    zi, drafts, dlogits, zi, zi, zf, zi, self._seed, np.int32(0),
-                )
-            jax.block_until_ready(out_t)
-        jax.block_until_ready(many)
+        self.programs.warmup(self.chunk_buckets)
         # record the compile cost on the existing compile metric (bucket
         # label = slot count)
         self._metrics.compile(self._deployment, self.n_slots, time.perf_counter() - t0)
@@ -1753,50 +1228,17 @@ class DecodeScheduler:
     def _step_attn_pages(self, pos: np.ndarray) -> tuple[int, int]:
         """(pages a plain step's attention reads for one layer's K, pages
         its block tables name) from the positions the round built — no
-        readback. The kernel path (``_attn_kernel``) fetches each slot's
+        readback. The kernel path (``programs.attn_kernel``) fetches each slot's
         ``ceil((pos + 1) / page_size)`` pages: a free slot's one junk page, a
         prefilling slot's up to its cursor; the gather path all of them."""
         table = self.n_slots * self.pool.pages_per_slot
-        if not self._attn_kernel:
+        if not self.programs.attn_kernel:
             return table, table
         return int(pages_read(pos, self.pool.page_size, self.pool.pages_per_slot).sum()), table
 
-    def _split_counts(self, toks: np.ndarray) -> np.ndarray:
-        """A counting family's step or chunk readback (``frame_counters``):
-        the trailing counts are added to the round's (``_commit_round``
-        lands them in the frame), the per-slot tokens returned. A family
-        that counts nothing never comes here."""
-        self._rb_counts += toks[self.n_slots:]
-        return toks[: self.n_slots]
-
     def compile_counts(self) -> dict[str, int]:
-        """jit cache sizes per program. The pjit cache is keyed on the
-        UNDERLYING function, so counts accumulate across scheduler
-        instances in one process (multi-tenant) — the zero-recompile
-        assertion is therefore relative: recompiles_since_warmup()."""
-        if self.feature_draft:
-            counts = {
-                "step_f": self._step_f_fn._cache_size(),
-                "chunk_f": self._chunk_f_fn._cache_size(),
-                "copy": self.pool.compile_count(),
-                "draft_feat": self._draft_feat_fn._cache_size(),
-                "ftree_verify": self._ftree_verify_fn._cache_size(),
-            }
-            return counts
-        counts = {
-            "step": self._step_fn._cache_size(),
-            "chunk": self._chunk_fn._cache_size(),
-            "copy": self.pool.compile_count(),
-        }
-        if self.spec_enabled:
-            if self.spec_tree is not None:
-                counts["draft_tree"] = self._draft_tree_fn._cache_size()
-                counts["tree_verify"] = self._tree_verify_fn._cache_size()
-            else:
-                counts["draft"] = self._draft_fn._cache_size()
-                counts["verify"] = self._verify_fn._cache_size()
-            counts["draft_admit"] = self._draft_admit_fn._cache_size()
-        return counts
+        """jit cache sizes per program (``DecodePrograms.compile_counts``)."""
+        return self.programs.compile_counts()
 
     @property
     def stat_prefix_evictions(self) -> int:
@@ -2406,8 +1848,8 @@ class DecodeScheduler:
         for i, a in enumerate(self.pool.state):
             _check(f"pool[{i}]", a)
         if self.spec_enabled:
-            _check("draft_k", self._dck)
-            _check("draft_v", self._dcv)
+            _check("draft_k", self.programs.dck)
+            _check("draft_v", self.programs.dcv)
         return {
             "tp": self.tp,
             "mesh_devices": len(mesh_devices),
@@ -2581,38 +2023,18 @@ class DecodeScheduler:
         phase across a device dispatch: busy time is _dispatch's."""
         return self._phases.phase(p)
 
-    def _mark_enqueued(self) -> None:
-        """Called by the _do_* dispatch closures at the enqueue->readback
-        boundary: after the fused program call returned its (async)
-        arrays, before the blocking np.asarray / host-transfer wait.
-        _timed_call splits the family's wall around this mark, so the
-        draft stops masquerading as free and the verify column stops
-        silently absorbing the whole round pair's wait. No mark = the
-        whole call counts as enqueue (the copy ladder reads nothing
-        back). Under ENGINE_FLIGHT_SYNC_TIMING the closures block on the
-        dispatch first, making the enqueue column ground-truth device
-        wall."""
-        self._dispatch_open.mark()
-
     def _dispatch(self, family: int) -> _Dispatch:
         """The ``with``-handle for one dispatch of a flight F_* family:
         THE timing-and-naming point of every dispatch (``_Dispatch``)."""
         return self._dispatches[family]
 
     async def _timed_call(self, family: int, fn):
-        """_device_call with the dispatch's wall time attributed to one
-        fused program family in the current round's flight frame, split
-        enqueue vs blocked readback at the closure's _mark_enqueued()."""
+        """``fn`` (a program set call, or the copy ladder) through
+        ``_Dispatch.run``: its wall time attributed to one fused program
+        family in the current round's flight frame, split enqueue vs
+        blocked readback at the mark."""
         with self._dispatches[family] as d:  # = self._dispatch(family)
-            out = await d.run(fn)
-            if self._faults is not None:
-                # chaos readback stall: the dispatch completed but the
-                # host-transfer wait drags — attributed to the family's
-                # readback column like a real slow transfer would be
-                stall = self._faults.readback_stall_s()
-                if stall > 0:
-                    await asyncio.sleep(stall)
-            return out
+            return await d.run(fn)
 
     def _commit_round(self, mode: str, *, step: bool) -> None:
         """THE single per-round commit point: round stats, prometheus round
@@ -2783,11 +2205,10 @@ class DecodeScheduler:
         self._rb_admitted += 1
         seq.t_admitted = time.perf_counter()
         self._rb_admit_wait += int((seq.t_admitted - seq.t_enqueued) * 1e9)
-        if self.feature_draft:
-            # the head's attention window opens at the computed suffix: the
-            # prefix-reused span has no draft-side K/V (the chunk rounds
-            # teacher-force only what they compute)
-            self._draft_start[slot] = reuse
+        # a feature head's attention window opens at the computed suffix:
+        # the prefix-reused span has no draft-side K/V (the chunk rounds
+        # teacher-force only what they compute)
+        self.programs.draft_start[slot] = reuse
         shared_pages = self.pool.alloc.pages_for(reuse) if reuse else 0
         if self.prefix_enabled:
             if entry is not None:
@@ -3124,21 +2545,6 @@ class DecodeScheduler:
             self.stat_pipeline_admits += 1
         self._kv_gauges()
 
-    def _draft_admit(self, slot_ids: list[int]) -> None:
-        """Draft-cache prompt prefill for slots finishing incremental
-        prefill this round, one bucketed dispatch (no readback)."""
-        bucket = next(b for b in self.admit_buckets if b >= len(slot_ids))
-        ids = np.zeros((bucket, self.seq_len), np.int32)
-        row_for_slot = np.zeros(self.n_slots, np.int32)
-        valid_slot = np.zeros(self.n_slots, bool)
-        for r, i in enumerate(slot_ids):
-            ids[r] = self._slots[i].prompt
-            row_for_slot[i] = r
-            valid_slot[i] = True
-        self._dck, self._dcv = self._draft_admit_fn(
-            self.draft_params, self._dck, self._dcv, ids, row_for_slot, valid_slot
-        )
-
     async def _chunk_round(self) -> None:
         """One prefill chunk round: every PREFILLING slot consumes up to
         its per-round chunk cap of prompt tokens in one fused dispatch
@@ -3191,40 +2597,13 @@ class DecodeScheduler:
         with self._phase(P_ALLOC):
             bt = self.pool.block_tables()
         tick = self._next_tick()
-
-        if self.feature_draft:
-
-            def _do_chunk():
-                toks, feat, state, dck, dcv = self._chunk_f_fn(
-                    self.params, self.draft_params, self.pool.state, bt,
-                    self._dck, self._dcv, ids, pos, counts, self._feat,
-                    self._draft_start, temps, topks, self._seed, tick,
-                )
-                if self._sync_timing:
-                    jax.block_until_ready((toks, state))
-                self._mark_enqueued()
-                return np.asarray(toks), (feat, state, dck, dcv)
-
-            t0 = telemetry.now_ns()
-            toks, (self._feat, self.pool.state, self._dck, self._dcv) = (
-                await self._timed_call(F_CHUNK, _do_chunk)
-            )
-        else:
-
-            def _do_chunk():
-                toks, state = self._chunk_fn(
-                    self.params, self.pool.state, bt, ids, pos, counts, temps,
-                    topks, self._seed, tick,
-                )
-                if self._sync_timing:
-                    jax.block_until_ready((toks, state))
-                self._mark_enqueued()
-                return np.asarray(toks), state
-
-            t0 = telemetry.now_ns()
-            toks, self.pool.state = await self._timed_call(F_CHUNK, _do_chunk)
-            if self._frame_counters:
-                toks = self._split_counts(toks)
+        t0 = telemetry.now_ns()
+        toks, counted = await self._timed_call(
+            F_CHUNK,
+            lambda: self.programs.chunk(bt, ids, pos, counts, temps, topks, tick),
+        )
+        if counted is not None:
+            self._rb_counts += counted
         t1 = telemetry.now_ns()
         self.stat_chunk_dispatches += 1
         finishing: list[tuple[_Seq, int]] = []
@@ -3248,13 +2627,13 @@ class DecodeScheduler:
                 seq.chunk_idx += 1
                 if seq.prefill_pos >= self.seq_len:
                     finishing.append((seq, i))
-        if finishing and self.spec_enabled and not self.feature_draft:
-            # (feature mode needs no transition-time draft prefill — the
-            # head's prompt K/V was teacher-forced by the chunk dispatches)
+        if finishing and self.programs.admit_buckets:
+            # (a feature head needs no transition-time draft prefill — its
+            # prompt K/V was teacher-forced by the chunk dispatches)
             # async dispatch: this is enqueue cost; the device time lands
             # in the next dispatch's blocked readback
             with self._dispatch(F_DRAFT) as d, d.enqueue():
-                self._draft_admit([i for _, i in finishing])
+                self.programs.draft_admit([(i, seq.prompt) for seq, i in finishing])
         t2 = telemetry.now_ns()
         with self._phase(P_SCATTER):
             for seq, i in finishing:
@@ -3279,7 +2658,7 @@ class DecodeScheduler:
                     self._retire(i)
 
     async def _spec_round(
-        self, bt, toks, pos, temps, topks, limits, wlimits, fmask, tick
+        self, bt, toks, pos, temps, topks, limits, wlimits, rows, tick
     ) -> None:
         """One speculative round: ONE draft dispatch proposes spec_k
         tokens per slot (or the whole candidate TREE on tree deployments),
@@ -3291,143 +2670,54 @@ class DecodeScheduler:
         exactly as on the plain path, so mid-burst retirement and SSE keep
         working. Tree rounds roll the caches forward by PATH positions:
         ``out_t``'s row layout ([n, depth+1], accepted-path tokens + bonus)
-        is identical to the chain's, so the host-side emission walk below
-        is shared between the modes."""
-        tree = self.spec_tree
+        is identical to the chain's, so the host-side emission walk
+        (``_consume_spec``) is shared between the modes.
 
-        def _do_spec():
-            # the draft/verify wall split feeds the flight frame's per-
-            # family attribution (d.enqueue(F_DRAFT) books the draft's
-            # segment to its own column), the verify side split again into
-            # enqueue vs blocked readback at _mark_enqueued(): with async
-            # dispatch the draft and verify-enqueue segments are host-side
-            # dispatch cost and the verify readback carries the blocked
-            # wait of the whole round pair. ENGINE_FLIGHT_SYNC_TIMING
-            # blocks after each program so both columns become
-            # ground-truth per-dispatch device wall.
-            feat = None  # the feature carry (feature-draft deployments only)
-            if self.feature_draft:
-                with d.enqueue(F_DRAFT):
-                    node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
-                        self.draft_params, self._dck, self._dcv, self._feat, toks,
-                        pos, self._draft_start, temps, topks, self._seed, tick, tree,
-                    )
-                    if self._sync_timing:
-                        jax.block_until_ready(node_toks)
-                out_t, acc, state, dck, dcv, feat = self._ftree_verify_fn(
-                    self.params, self.pool.state, bt, toks, node_toks, blogits,
-                    nk, nv, dck, dcv, self._feat, fmask, pos, wlimits, temps,
-                    topks, self._seed, tick, tree,
-                )
-            elif tree is not None:
-                with d.enqueue(F_DRAFT):
-                    node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
-                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                        topks, self._seed, tick, tree,
-                    )
-                    if self._sync_timing:
-                        jax.block_until_ready(node_toks)
-                out_t, acc, state, dck, dcv = self._tree_verify_fn(
-                    self.params, self.pool.state, bt, toks, node_toks, blogits,
-                    nk, nv, dck, dcv, pos, wlimits, temps, topks,
-                    self._seed, tick, tree,
-                )
-            else:
-                with d.enqueue(F_DRAFT):
-                    drafts, dlogits, dck, dcv = self._draft_fn(
-                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                        topks, self._seed, tick, self.spec_k,
-                    )
-                    if self._sync_timing:
-                        jax.block_until_ready(drafts)
-                out_t, acc, state = self._verify_fn(
-                    self.params, self.pool.state, bt, toks, drafts, dlogits, pos,
-                    limits, temps, topks, self._seed, tick,
-                )
-            if self._sync_timing:
-                jax.block_until_ready(out_t)
-            self._mark_enqueued()
-            return np.asarray(out_t), np.asarray(acc), state, dck, dcv, feat
-
+        The draft/verify wall split feeds the flight frame's per-family
+        attribution: ``d.enqueue(F_DRAFT)`` books the draft's segment to
+        its own column, the verify side splits again into enqueue vs
+        blocked readback at the mark — with async dispatch the draft and
+        verify-enqueue segments are host-side dispatch cost and the verify
+        readback carries the blocked wait of the whole round pair.
+        Pipelined, the pair enqueues back-to-back on the loop, round N+1's
+        host phases run under it (``_overlap_window``: inside the verify
+        family's busy wall, recorded apart as the frame's overlap_ns), and
+        only then does the host block on the verify readback. Serial, the
+        pair and its read run in one piece (``d.run``);
+        ENGINE_FLIGHT_SYNC_TIMING blocks after each program so both
+        columns become ground-truth per-dispatch device wall."""
+        programs = self.programs
         t0 = telemetry.now_ns()
         with self._dispatch(F_VERIFY) as d:
-            out_t, acc, self.pool.state, self._dck, self._dcv, feat = await d.run(
-                _do_spec
-            )
-        if feat is not None:
-            self._feat = feat
-        t1 = telemetry.now_ns()
-        # dispatch-time occupancy, committed (with steps/metrics) at the
-        # round's single _commit_round point
-        self._rb_active = self.active
-        self._consume_spec(out_t, acc, limits, wlimits, t0, t1)
 
-    async def _spec_round_pipelined(
-        self, bt, toks, pos, temps, topks, limits, wlimits, fmask, tick
-    ) -> None:
-        """The double-buffered twin of ``_spec_round``: the round pair's
-        draft + widened-verify dispatches enqueue back-to-back, round
-        N+1's host phases run under the in-flight pair
-        (``_overlap_window``), and only then does the host block on the
-        verify readback. The verify family's busy column spans the whole
-        enqueue->readback window (the overlap work sits INSIDE the
-        device-busy wall — recorded apart as the frame's overlap_ns), and
-        rdb is the true post-overlap block. Sync-timing runs never come
-        here (_pipeline_on forces the serial twin)."""
-        tree = self.spec_tree
-        t0 = telemetry.now_ns()
-        with self._dispatch(F_VERIFY) as d:
-            if self.feature_draft:
+            def draft():
                 with d.enqueue(F_DRAFT):
-                    node_toks, blogits, nk, nv, dck, dcv = self._draft_feat_fn(
-                        self.draft_params, self._dck, self._dcv, self._feat, toks,
-                        pos, self._draft_start, temps, topks, self._seed, tick, tree,
-                    )
-                with d.enqueue():
-                    out_dev, acc_dev, state, dck, dcv, self._feat = self._ftree_verify_fn(
-                        self.params, self.pool.state, bt, toks, node_toks, blogits,
-                        nk, nv, dck, dcv, self._feat, fmask, pos, wlimits, temps,
-                        topks, self._seed, tick, tree,
-                    )
-            elif tree is not None:
-                with d.enqueue(F_DRAFT):
-                    node_toks, blogits, nk, nv, dck, dcv = self._draft_tree_fn(
-                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                        topks, self._seed, tick, tree,
-                    )
-                with d.enqueue():
-                    out_dev, acc_dev, state, dck, dcv = self._tree_verify_fn(
-                        self.params, self.pool.state, bt, toks, node_toks, blogits,
-                        nk, nv, dck, dcv, pos, wlimits, temps, topks,
-                        self._seed, tick, tree,
-                    )
-            else:
-                with d.enqueue(F_DRAFT):
-                    drafts, dlogits, dck, dcv = self._draft_fn(
-                        self.draft_params, self._dck, self._dcv, toks, pos, temps,
-                        topks, self._seed, tick, self.spec_k,
-                    )
-                with d.enqueue():
-                    out_dev, acc_dev, state = self._verify_fn(
-                        self.params, self.pool.state, bt, toks, drafts, dlogits, pos,
-                        limits, temps, topks, self._seed, tick,
-                    )
-            self.pool.state = state
-            self._dck = dck
-            self._dcv = dcv
+                    proposal = programs.draft(toks, pos, temps, topks, tick)
+                    if self._sync_timing:
+                        jax.block_until_ready(proposal)
+                return proposal
+
+            def verify(proposal):
+                return programs.verify(
+                    bt, toks, proposal, pos, temps, topks, limits, wlimits, rows, tick
+                )
+
             self._rb_active = self.active  # dispatch-time occupancy
-            self._overlap_window()
-            out_t, acc = await d.readback(
-                lambda: (np.asarray(out_dev), np.asarray(acc_dev))
-            )
+            if self._pipeline_on():
+                proposal = draft()
+                with d.enqueue():
+                    _, read = verify(proposal)
+                self._overlap_window()
+                out_t, acc = await d.readback(read)
+            else:
+                out_t, acc = await d.run(lambda: verify(draft()))
         t1 = telemetry.now_ns()
         self._consume_spec(out_t, acc, limits, wlimits, t0, t1)
 
     def _consume_spec(self, out_t, acc, limits, wlimits, t0: int, t1: int) -> None:
-        """The readback-dependent half of a speculative round, shared by
-        the serial and pipelined dispatch twins: the accept/emission walk
-        over the verify readback, retirements, speculation attribution,
-        and the adaptive controller's update."""
+        """The readback-dependent half of a speculative round: the
+        accept/emission walk over the verify readback, retirements,
+        speculation attribution, and the adaptive controller's update."""
         tree = self.spec_tree
         self.stat_spec_dispatches += 1
         # ``proposed`` is the round's ACCEPTANCE OPPORTUNITY — depth
@@ -3502,38 +2792,32 @@ class DecodeScheduler:
             self._deployment, proposed, accepted, emitted, mode=mode
         )
 
-    async def _step_round_pipelined(self, bt, toks, pos, temps, topks, fmask, tick):
-        """The double-buffered plain round: enqueue the fused step, run
-        round N+1's host phases under the in-flight dispatch
-        (``_overlap_window``), then block on the token readback. The step
-        family's busy column spans the whole enqueue->readback window
-        (the overlap work sits INSIDE the device-busy wall — recorded
-        apart as the frame's overlap_ns); rdb is the true post-overlap
-        block. Sync-timing runs never come here (_pipeline_on forces the
-        serial path)."""
+    async def _step_round(self, bt, toks, pos, temps, topks, rows, tick) -> np.ndarray:
+        """The plain round's dispatch: the fused step, its token readback.
+        Pipelined, the step is enqueued on the loop, round N+1's host
+        phases run under the in-flight dispatch (``_overlap_window``), then
+        the host blocks on the readback: the step family's busy column
+        spans the whole enqueue->readback window (the overlap work sits
+        INSIDE the device-busy wall — recorded apart as the frame's
+        overlap_ns) and rdb is the true post-overlap block. Serial
+        (ENGINE_DECODE_PIPELINE=off, sync timing), call and read run in one
+        piece (``d.run``)."""
+
+        def step():
+            return self.programs.step(bt, toks, pos, temps, topks, tick, rows)
+
         with self._dispatch(F_STEP) as d:
-            with d.enqueue():
-                if self.feature_draft:
-                    nxt_dev, self._feat, state = self._step_f_fn(
-                        self.params, self.pool.state, bt, toks, pos, self._feat,
-                        fmask, temps, topks, self._seed, tick,
-                    )
-                elif self._frame_counters:
-                    # a counting family's step also takes the rows that generate
-                    nxt_dev, state = self._step_fn(
-                        self.params, self.pool.state, bt, toks, pos, temps, topks,
-                        self._seed, tick, fmask,
-                    )
-                else:
-                    nxt_dev, state = self._step_fn(
-                        self.params, self.pool.state, bt, toks, pos, temps, topks,
-                        self._seed, tick,
-                    )
-            self.pool.state = state
             self._rb_active = self.active  # dispatch-time occupancy
-            self._overlap_window()
-            nxt = await d.readback(lambda: np.asarray(nxt_dev))
-            return self._split_counts(nxt) if self._frame_counters else nxt
+            if self._pipeline_on():
+                with d.enqueue():
+                    _, read = step()
+                self._overlap_window()
+                nxt, counted = await d.readback(read)
+            else:
+                nxt, counted = await d.run(step)
+        if counted is not None:
+            self._rb_counts += counted
+        return nxt
 
     async def _run(self) -> None:
         try:
@@ -3707,10 +2991,9 @@ class DecodeScheduler:
                             continue
                         copies += self.pool.alloc.prepare_write(i, seq.pos, width)
                 await self._run_copies(copies)
-                pipelined = self._pipeline_on()
                 with self._phase(P_ALLOC):
                     bt = self.pool.block_tables()
-                    if not pipelined:
+                    if not self._pipeline_on():
                         # per-round pool gauges: this round's prepare_write
                         # may have allocated/CoW'd pages with no admission
                         # between. The pipelined loop refreshes them inside
@@ -3719,16 +3002,9 @@ class DecodeScheduler:
                         self._kv_gauges()
 
                 if spec_round:
-                    if pipelined:
-                        await self._spec_round_pipelined(
-                            bt, toks, pos, temps, topks, limits, wlimits,
-                            fmask, tick
-                        )
-                    else:
-                        await self._spec_round(
-                            bt, toks, pos, temps, topks, limits, wlimits,
-                            fmask, tick
-                        )
+                    await self._spec_round(
+                        bt, toks, pos, temps, topks, limits, wlimits, fmask, tick
+                    )
                     # reconcile the shadow admissions decided under the
                     # round pair's flight BEFORE the frame commits (they
                     # belong to this round, like the serial walk's)
@@ -3742,45 +3018,7 @@ class DecodeScheduler:
                     continue
 
                 self._rb_attn_pages = self._step_attn_pages(pos)
-                if pipelined:
-                    nxt = await self._step_round_pipelined(
-                        bt, toks, pos, temps, topks, fmask, tick
-                    )
-                elif self.feature_draft:
-
-                    def _do_step_f():
-                        nxt, feat, state = self._step_f_fn(
-                            self.params, self.pool.state, bt, toks, pos,
-                            self._feat, fmask, temps, topks, self._seed, tick,
-                        )
-                        if self._sync_timing:
-                            jax.block_until_ready((nxt, state))
-                        self._mark_enqueued()
-                        return np.asarray(nxt), (feat, state)
-
-                    nxt, (self._feat, self.pool.state) = await self._timed_call(
-                        F_STEP, _do_step_f
-                    )
-                    self._rb_active = self.active  # dispatch-time occupancy
-                else:
-
-                    def _do_step():
-                        nxt, state = self._step_fn(
-                            self.params, self.pool.state, bt, toks, pos, temps,
-                            topks, self._seed, tick,
-                            *((fmask,) if self._frame_counters else ()),
-                        )
-                        if self._sync_timing:
-                            jax.block_until_ready((nxt, state))
-                        self._mark_enqueued()
-                        return np.asarray(nxt), state
-
-                    nxt, self.pool.state = await self._timed_call(
-                        F_STEP, _do_step
-                    )
-                    if self._frame_counters:
-                        nxt = self._split_counts(nxt)
-                    self._rb_active = self.active  # dispatch-time occupancy
+                nxt = await self._step_round(bt, toks, pos, temps, topks, fmask, tick)
                 with self._phase(P_SAMPLING):
                     # sampled-token consumption: the readback array walked
                     # into per-slot emissions/retirements
@@ -3841,20 +3079,7 @@ class DecodeScheduler:
         page mapping drops with the bytes) and clear the index entries
         that pointed into it."""
         self.pool.reset()
-        if self.spec_enabled:
-            self._dck, self._dcv = self._commit_kv(
-                self.draft_params,
-                init_slot_cache(
-                    self.draft_params, self.n_slots, self._draft_ctx, self._dtype
-                ),
-            )
-        if self.feature_draft:
-            dims = decoder_dims(self.params)
-            self._feat = self._commit_kv(
-                self.params,
-                (jnp.zeros((self.n_slots, dims["hidden"]), self._dtype),),
-            )[0]
-            self._draft_start[:] = 0
+        self.programs.reset()
         if self.prefix_enabled:
             self._prefix_index.clear()
 
@@ -4125,9 +3350,11 @@ def scheduler_for_executor(executor, tpu_spec, *, metrics=None, deployment_name=
         # does ffn, because the distill recipe sizes the head's FFN to
         # the target's by default (the documented distill-then-serve flow
         # must line up without pinning ffn in the URI).
-        # the GPT-2 family's dims: a target of another family is refused
-        # here by name (FamilyNotServed), before a draft is built for it
-        dims = decoder_dims(runtime.params)
+        # a target whose family serves no speculation is refused here by
+        # name (FamilyNotServed), before a draft is built for it
+        family = decoder_family(gen.get("family"))
+        require_served(family, "speculation")
+        dims = family.decoder_dims(runtime.params)
         dkw = {"vocab": dims["vocab"], "max_len": dims["max_len"], **dkw}
         if dkw.get("features"):
             dkw = {"hidden": dims["hidden"], "ffn": dims["ffn"], **dkw}

@@ -23,7 +23,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 from harness import cells  # noqa: E402
 from harness.correct import judge_generated  # noqa: E402
 
-from seldon_core_tpu.models import decoder as gpt2_family  # noqa: E402
+from seldon_core_tpu.models.decoder import _fused_chunk, _fused_step, gpt2_family  # noqa: E402
 from seldon_core_tpu.models import moe_decoder as md  # noqa: E402
 from seldon_core_tpu.models.decoder import FamilyNotServed, init_decoder  # noqa: E402
 from seldon_core_tpu.ops import moe  # noqa: E402
@@ -380,11 +380,11 @@ async def test_scheduler_serves_the_family_counts_real_rows_and_never_recompiles
 
 
 def test_gpt2_family_keeps_its_own_fused_programs():
-    step, chunk = ds._family_programs(gpt2_family)
-    assert step is ds._fused_step and chunk is ds._fused_chunk
-    mstep, mchunk = ds._family_programs(FAM)
+    step, chunk = gpt2_family.fused_programs()
+    assert step is _fused_step and chunk is _fused_chunk
+    mstep, mchunk = FAM.fused_programs()
     assert (mstep.__name__, mchunk.__name__) == ("_fused_step", "_fused_chunk")  # one name in a trace
-    assert ds._family_programs(md.moe_family(md.MoEDecoderConfig(**vars(CFG)))) == (mstep, mchunk)
+    assert md.moe_family(md.MoEDecoderConfig(**vars(CFG))).fused_programs() == (mstep, mchunk)
 
 
 async def test_tiny_gpt_serves_bit_identical_tokens_through_the_family_seam():

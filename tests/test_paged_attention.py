@@ -189,7 +189,7 @@ async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens(mon
     frames count the pages the step's attention read."""
     import asyncio
 
-    from seldon_core_tpu.serving import decode_scheduler as ds
+    from seldon_core_tpu.serving import decode_programs as ds
 
     params = init_decoder(seed=3, vocab=128, hidden=64, layers=2, ffn=128, max_len=64)
     rng = np.random.default_rng(21)
@@ -202,7 +202,7 @@ async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens(mon
         return outs
 
     gather = _scheduler(params)
-    assert gather._attn_kernel == ""  # the CPU backend: the oracle path
+    assert gather.programs.attn_kernel == ""  # the CPU backend: the oracle path
     want = await serve(gather)
     table = 2 * gather.pool.pages_per_slot
     frames = [f for f in gather.flight.snapshot() if f.attn_pages_table]
@@ -211,7 +211,7 @@ async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens(mon
 
     monkeypatch.setattr(ds, "_step_attn_kernel", lambda family, pool_state, mesh, heads: "interpret")
     kernel = _scheduler(params)
-    assert kernel._attn_kernel == "interpret"
+    assert kernel.programs.attn_kernel == "interpret"
     got = await serve(kernel)
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)

@@ -420,7 +420,7 @@ async def test_warmup_compiles_every_bucket_and_mixed_traffic_recompiles_nothing
     # every program the mixed workload can touch exists before traffic;
     # ladders are warmed bucket-by-bucket (jit caches count executables)
     assert base["chunk"] >= len(sched.chunk_buckets)
-    assert base["draft_admit"] >= len(sched.admit_buckets)
+    assert base["draft_admit"] >= len(sched.programs.admit_buckets)
     assert base["copy"] >= len(sched.pool.copy_buckets)
     for prog in ("step", "draft", "verify"):
         assert base.get(prog, 0) >= 1, (prog, base)

@@ -69,7 +69,7 @@ def _smoke_decoder():
 
 
 def _step_args(params, geo, kv_dtype, param_sh, pool_sh_for, small_sh):
-    """ShapeDtypeStructs for decode_scheduler._fused_step — shapes only:
+    """ShapeDtypeStructs for models/decoder.py ``_fused_step`` — shapes only:
     there is no device to hold an array."""
     from seldon_core_tpu.models.decoder import paged_kv_init
 
@@ -128,7 +128,7 @@ def test_flash_attention_compiles_at_bert_base_long_context(topo, causal):
 
 
 def test_fused_paged_decode_step_compiles_on_the_int8_pool(topo):
-    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+    from seldon_core_tpu.models.decoder import _fused_step
 
     one = SingleDeviceSharding(topo.devices[0])
     params, geo = _smoke_decoder()
@@ -153,7 +153,7 @@ def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo
     0.11 GiB) is the floor of the temporaries whatever the depth, and only
     from eight layers up is a quarter of the pool above it."""
     from seldon_core_tpu.models.decoder import init_decoder
-    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+    from seldon_core_tpu.models.decoder import _fused_step
 
     one = SingleDeviceSharding(topo.devices[0])
     params = jax.eval_shape(
@@ -177,12 +177,12 @@ def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo
 def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo):
     """The same cell geometry with the step's attention in the Pallas decode
     kernel (ops/paged_attention.py; ``attn_kernel`` is the static argument the
-    scheduler's ``_step_attn_kernel`` answers on a TPU): Mosaic takes the
+    program set's ``_step_attn_kernel`` answers on a TPU): Mosaic takes the
     kernel at the real widths, the kernel takes the WHOLE donated pool — which
     still aliases, with no copy of it or of one layer of it — and the gathered
     float32 cache (0.11 GiB) is gone from the temporaries."""
     from seldon_core_tpu.models.decoder import init_decoder
-    from seldon_core_tpu.serving.decode_scheduler import _family_programs, gpt2_family
+    from seldon_core_tpu.models.decoder import gpt2_family
 
     one = SingleDeviceSharding(topo.devices[0])
     params = jax.eval_shape(
@@ -192,7 +192,7 @@ def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo)
     p, pool, rest = _step_args(
         params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
     )
-    step, _chunk = _family_programs(gpt2_family, "mosaic")
+    step, _chunk = gpt2_family.fused_programs("mosaic")
     compiled = jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 8  # a kernel call a layer: Mosaic, not the interpreter
@@ -225,9 +225,8 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, hidden, pag
     the step it chose compiles either way. Asking for the kernel at a
     refused geometry is a named error before Mosaic sees it."""
     from seldon_core_tpu.models.decoder import decoder_dims, init_decoder
-    from seldon_core_tpu.serving.decode_scheduler import (
-        _family_programs, _step_attn_kernel, gpt2_family,
-    )
+    from seldon_core_tpu.models.decoder import gpt2_family
+    from seldon_core_tpu.serving.decode_programs import _step_attn_kernel
 
     one = SingleDeviceSharding(topo.devices[0])
     params = jax.eval_shape(
@@ -239,18 +238,18 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, hidden, pag
     )
     got = _step_attn_kernel(gpt2_family, pool, None, decoder_dims(params)["heads"])
     assert got == want
-    step, _chunk = _family_programs(gpt2_family, got)
+    step, _chunk = gpt2_family.fused_programs(got)
     text = jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).compile().as_text()
     assert ("tpu_custom_call" in text) == bool(want)
     if not want:
-        forced, _chunk = _family_programs(gpt2_family, "mosaic")
+        forced, _chunk = gpt2_family.fused_programs("mosaic")
         with pytest.raises(ValueError, match="mosaic_tiles"):
             jax.jit(forced, donate_argnums=(1,)).lower(p, pool, *rest)
 
 
 def test_tp4_sharded_decode_step_compiles(topo):
     from seldon_core_tpu.parallel.tp import decoder_param_shardings, kv_sharding
-    from seldon_core_tpu.serving.decode_scheduler import _fused_step
+    from seldon_core_tpu.models.decoder import _fused_step
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("tp",))
     rep = NamedSharding(mesh, P())

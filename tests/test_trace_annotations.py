@@ -29,7 +29,6 @@ import pytest
 
 from seldon_core_tpu.models import decoder
 from seldon_core_tpu.models.decoder import init_decoder, paged_kv_init
-from seldon_core_tpu.serving import decode_scheduler as ds
 from seldon_core_tpu.serving.decode_scheduler import DecodeScheduler
 from seldon_core_tpu.telemetry import flight as flight_mod
 from seldon_core_tpu.telemetry.flight import FAMILIES, PHASES, PhaseTimer
@@ -196,8 +195,8 @@ def test_every_registered_phase_and_family_is_emitted(recorded):
     assert flight_mod.ANN_IDLE_WAIT in seen  # the loop waited for its first request
 
 
-def test_one_helper_times_the_pipelined_step_and_timed_call():
-    """The pipelined step has no timing of its own any more: it and
+def test_one_helper_times_the_step_round_and_timed_call():
+    """The step round has no timing of its own: it and
     ``_timed_call`` both go through ``_dispatch`` (busy = the handle's
     wall, rdb = the part after the mark), so the frame's columns and the
     annotations cannot drift apart."""
@@ -210,7 +209,7 @@ def test_one_helper_times_the_pipelined_step_and_timed_call():
     assert all(0 < f.rdb_ns[step] <= f.busy_ns[step] for f in frames)
     import inspect
 
-    src = inspect.getsource(DecodeScheduler._step_round_pipelined)
+    src = inspect.getsource(DecodeScheduler._step_round)
     assert "self._dispatch(F_STEP)" in src and "perf_counter_ns" not in src
     assert "self._dispatches[family]" in inspect.getsource(DecodeScheduler._timed_call)
 
@@ -254,10 +253,10 @@ def test_compiled_fused_programs_carry_every_scope(program):
     temps = jnp.zeros((n,), jnp.float32)
     if program == "step":
         args = (params, pool, bt, vec, vec, temps, vec, 0, jnp.int32(1))
-        fn = ds._fused_step
+        fn = decoder._fused_step
     else:
         args = (params, pool, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1))
-        fn = ds._fused_chunk
+        fn = decoder._fused_chunk
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert len(set(decoder.PAGED_SCOPES)) == len(decoder.PAGED_SCOPES) == 9
     for scope in decoder.PAGED_SCOPES:
