@@ -636,7 +636,7 @@ def _assert_mechanisms(sched, tpu: dict) -> dict:
         check(s.prefix_slots == tpu["decode_prefix_slots"], "prefix cache is off")
         check(
             s.prefill_chunk == tpu["decode_prefill_chunk"]
-            and max(s.chunk_buckets) == s.prefill_chunk,
+            and max(c for _rows, c in s.chunk_buckets) == s.prefill_chunk,
             f"chunked prefill is off: ladder {s.chunk_buckets}",
         )
     return {"tp": want_tp, "replicas": want_replicas}

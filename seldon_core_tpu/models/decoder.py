@@ -862,10 +862,11 @@ def paged_verify_step(params, pool, bt, tokens, positions):
 
 
 def paged_chunk_prefill(params, pool, bt, tokens, positions, counts):
-    """chunk_prefill over the page pool: persist only the first counts[i]
-    K/V entries per slot (counts-0 slots ride the static-shape dispatch
-    with their writes junk-redirected, touching no live page). Returns
-    (logits, hidden[n, c, d], pool)."""
+    """chunk_prefill over the page pool: row i is one slot's chunk, ``bt[i]``
+    that slot's block-table row; persist only the first counts[i] K/V
+    entries per row (a counts-0 row — padding — has its writes
+    junk-redirected, touching no live page). Returns (logits,
+    hidden[rows, c, d], pool)."""
     return _paged_forward(params, pool, bt, tokens, positions, counts)
 
 
@@ -886,14 +887,16 @@ def _fused_step(params, pool, bt, tokens, positions, temps, topks, seed, tick, *
 
 def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
     """One device program per prefill chunk round: ``paged_chunk_prefill``
-    over every slot (counts-0 slots — generating, free — ride the static
-    shape with their writes junk-redirected) + next-token sampling from
-    each slot's last consumed position, one dispatch. ``ids`` is a
-    [n_slots, c] bucket from the chunk ladder; only the sampled token for
-    slots whose prompt COMPLETED this round is consumed by the host (it is
-    the first generated token). With the monolithic admit path gone, this
-    IS admission's prompt compute — a whole wave prefills in one dispatch
-    at the top bucket, or spread over rounds when chunking is on."""
+    over the slots that prefill + next-token sampling from each row's last
+    consumed position, one dispatch. ``ids`` is a [rows, c] entry of the
+    scheduler's chunk ladder and ``bt`` [rows, n_log] the block-table rows
+    of those slots, whichever they are (a padding row has counts 0 and
+    writes junk page 0); a generating or free slot is not in the batch.
+    Only the sampled token of a row whose prompt COMPLETED this round is
+    consumed by the host (it is the first generated token). With the
+    monolithic admit path gone, this IS admission's prompt compute — a
+    whole wave prefills in one dispatch at the full-width top entry, or
+    spread over rounds when chunking is on."""
     logits, _hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
     with jax.named_scope(SCOPE_SAMPLE):
         c = ids.shape[1]

@@ -411,9 +411,11 @@ class MoEDecoder:
         ``jit__fused_step`` — with two differences: the step takes ``rows``
         (which slots generate, so junk rows stay out of the counts), and
         the counts ride the token readback, appended to it: one
-        [n_slots + len(frame_counters)] int32 array, one transfer. The
-        chunk's head runs on each slot's last real row only. Cached: equal
-        configurations share compiled programs."""
+        [rows + len(frame_counters)] int32 array, one transfer (the step's
+        rows are the slots, the chunk's the slots that prefill:
+        models/decoder.py ``_fused_chunk``). The chunk's head runs on each
+        row's last real position only. Cached: equal configurations share
+        compiled programs."""
 
         def sample_and_count(logits, counted, temps, topks, seed, tick):
             with jax.named_scope(SCOPE_SAMPLE):

@@ -296,7 +296,7 @@ class DecodePrograms:
         feature = self.mode == "feature"
         # a counting family's step also takes the rows that generate, and its
         # readback carries the counts after the tokens (``_tokens``)
-        self._counted = bool(family.frame_counters)
+        self._counted = len(family.frame_counters)
         self._place, self._draft_ctx, self._dtype = place, draft_ctx, dtype
         self._hidden = dims["hidden"]
         # per-slot draft attention window start (host data: the computed
@@ -405,12 +405,12 @@ class DecodePrograms:
 
     # ------------------------------------------------------- the round kinds
     def _tokens(self, out) -> tuple:
-        """The blocking read of a step or chunk: (per-slot tokens, a
-        counting family's trailing counts | None)."""
+        """The blocking read of a step or chunk: (one token a row of the
+        dispatch, a counting family's trailing counts | None)."""
         toks = np.asarray(out)
         if not self._counted:
             return toks, None
-        return toks[: self.n_slots], toks[self.n_slots :]
+        return toks[: -self._counted], toks[-self._counted :]
 
     def step(self, bt, toks, pos, temps, topks, tick, rows):
         """Enqueue one plain decode step. ``rows`` marks the generating
@@ -430,8 +430,12 @@ class DecodePrograms:
         return out, lambda: self._tokens(out)
 
     def chunk(self, bt, ids, pos, counts, temps, topks, tick):
-        """Enqueue one prefill chunk round over an ``ids`` bucket of the
-        chunk ladder (counts 0: the slot rides, its writes junk-sink)."""
+        """Enqueue one prefill chunk round at a ``[rows, c]`` entry of the
+        chunk ladder: ``ids`` and the block-table rows ``bt`` of the slots
+        that prefill, one row each (counts 0: a padding row, its writes
+        junk-sink), whichever slots they are; the read gives a token a
+        row. The feature twin carries its buffers by slot: all ``n_slots``
+        rows, row r slot r."""
         pool = self.pool
         if self.mode == "feature":
             out, self.feat, pool.state, self.dck, self.dcv = self._chunk_f_fn(
@@ -506,16 +510,20 @@ class DecodePrograms:
     # ------------------------------------------------------ compile discipline
     def warmup(self, chunk_buckets) -> None:
         """Compile every program ahead of traffic by the conventions live
-        rounds use (so a warmed signature IS a live one): the chunk ladder,
-        the pool's CoW copy ladder, the draft-admit ladder, the step and the
-        speculative round pair. All-zero block tables and counts: every
-        write lands in junk page 0, no live bytes touched."""
+        rounds use (so a warmed signature IS a live one): the chunk ladder's
+        ``(rows, c)`` entries, the pool's CoW copy ladder, the draft-admit
+        ladder, the step and the speculative round pair. All-zero block
+        tables and counts: every write lands in junk page 0, no live bytes
+        touched."""
         n = self.n_slots
         zi, zf, none = np.zeros(n, np.int32), np.zeros(n, np.float32), np.zeros(n, bool)
         bt0 = self.pool.block_tables()
         tick = np.int32(0)
-        for c in chunk_buckets:
-            self.chunk(bt0, np.zeros((n, c), np.int32), zi, zi, zf, zi, tick)
+        for rows, c in chunk_buckets:
+            self.chunk(
+                self.pool.block_tables(np.full(rows, -1)), np.zeros((rows, c), np.int32),
+                zi[:rows], zi[:rows], zf[:rows], zi[:rows], tick,
+            )
         self.pool.warmup()  # the CoW copy ladder (page0 self-copies)
         for b in self.admit_buckets:
             self.dck, self.dcv = self._draft_admit_fn(

@@ -706,6 +706,9 @@ class DecodeScheduler:
 
     # a round that takes this long is logged with what held it (_commit_round)
     SLOW_ROUND_NS = 1_000_000_000
+    # the rows ladder of the compact chunk programs, under ``n_slots``
+    # (``chunk_buckets``): the first takes every c, the rest the top c only
+    CHUNK_ROWS = (2, 4)
 
     def __init__(
         self,
@@ -879,16 +882,33 @@ class DecodeScheduler:
         # bench/test introspection.
         self.incremental = True
         top = self.prefill_chunk or seq_len
-        # power-of-FOUR ladder: each chunk bucket is a full-transformer
-        # program, and with chunking now the only admission path the
-        # ladder dominates warmup — a coarser ladder halves the compile
-        # count while round COUNTS stay set by the chunk cap, not the
-        # bucket (a 5-token suffix rides bucket 16 with junk-masked slack)
-        cb, b = [], 1
+        # the chunk ladder: the (rows, c) batches a chunk round dispatches
+        # at, cheapest first — a round takes the first entry that holds
+        # its prefilling slots and their longest chunk, so the program
+        # computes the slots that prefill, not all ``n_slots`` (at steady
+        # traffic one or two slots prefill a round; every term of a chunk
+        # program but the weights' read grows with its rows). c climbs by
+        # powers of FOUR from 16: each entry is a full-transformer program
+        # and the ladder dominates warmup, round COUNTS are set by the
+        # chunk cap, not the bucket (a 5-token suffix rides c = 16 with
+        # junk-masked slack), and under 16 positions a few rows cost what
+        # the weights' read costs, like a step. Rows climb CHUNK_ROWS to
+        # ``n_slots``, every entry past the first at the top c only: three
+        # or four slots prefill together in a round in twenty (a closed
+        # loop of 16 streams and 3 chunks a prompt), more in a wave (ramp,
+        # a burst). The feature head's twin carries its feature buffer and
+        # the draft's flat cache by slot, so it keeps every c at full
+        # width (row r is slot r).
+        cs, b = [], 16
         while b < top:
-            cb.append(b)
+            cs.append(b)
             b *= 4
-        self.chunk_buckets = tuple(cb) + (top,)
+        cs.append(top)
+        rows = [] if self.feature_draft else [r for r in self.CHUNK_ROWS if r < n_slots]
+        rows.append(n_slots)
+        self.chunk_buckets = tuple(
+            [(rows[0], c) for c in cs] + [(r, top) for r in rows[1:]]
+        )
         # paged pool geometry: the write mask junk-redirects out-of-range
         # entries, so the pool needs NO verify/chunk headroom columns —
         # virtual context is exactly seq + max_new (rounded up to pages).
@@ -1984,6 +2004,9 @@ class DecodeScheduler:
         self._rb_first_tokens = 0
         # pages the plain step's attention read, of the pages its tables name
         self._rb_attn_pages = (0, 0)
+        # rows the round's chunk dispatches computed, and the prefilling
+        # slots among them
+        self._rb_chunk_rows = self._rb_chunk_rows_live = 0
         # a counting family's per-dispatch counts, summed over the round
         # (nothing to build each round for a family that counts nothing)
         if self._frame_counters:
@@ -2096,6 +2119,7 @@ class DecodeScheduler:
                         self._rb_promotions, self._rb_admit_wait,
                         self._rb_prefill, self._rb_first_tokens,
                         *self._rb_attn_pages,
+                        self._rb_chunk_rows, self._rb_chunk_rows_live,
                         **(
                             dict(zip(self._frame_counters, self._rb_counts.tolist()))
                             if self._frame_counters
@@ -2420,7 +2444,7 @@ class DecodeScheduler:
     def _pipeline_plan_chunk(self) -> None:
         """Round N+1's chunk-round INPUT BUILD against the shadow state:
         the prefilling slots' next chunk plus the pending admissions'
-        first, as the same bucketed arrays ``_chunk_round`` would build.
+        first, as the same compact arrays ``_chunk_round`` would build.
         Pure array construction — page residency (prepare_write / CoW)
         stays in the serial chunk round, because a CoW copy is not
         rollback-safe while a numpy build is. The plan carries a snapshot
@@ -2451,26 +2475,35 @@ class DecodeScheduler:
         self._pending_chunk_plan = (key,) + self._chunk_input_arrays(rows)
 
     def _chunk_input_arrays(self, rows: list) -> tuple:
-        """The chunk round's bucketed input arrays from
-        ``(slot, uid, prefill_pos, count, seq)`` rows — ONE builder shared
-        by the serial chunk round and the overlap-window plan, so the
-        array layout cannot drift between the two paths (the plan's
-        snapshot key covers the rows, not the layout). Returns
-        ``(bucket, ids, pos, counts, temps, topks)``."""
+        """The chunk round's input arrays from ``(slot, uid, prefill_pos,
+        count, seq)`` rows in slot order — ONE builder shared by the
+        serial chunk round and the overlap-window plan, so the array
+        layout cannot drift between the two paths (the plan's snapshot key
+        covers the rows, not the layout). The batch is the first
+        ``chunk_buckets`` entry that holds the rows and their longest
+        chunk: the slots that prefill, one row each in slot order, then
+        padding rows (slot -1, count 0: their writes junk-sink). At full
+        width row r is slot r. Returns ``(slots, ids, pos, counts, temps,
+        topks)``, each ``[rows]`` (``ids`` ``[rows, c]``)."""
         need = max(r[3] for r in rows)
-        bucket = next(b for b in self.chunk_buckets if b >= need)
-        ids = np.zeros((self.n_slots, bucket), np.int32)
-        pos = np.zeros(self.n_slots, np.int32)
-        counts = np.zeros(self.n_slots, np.int32)
-        temps = np.zeros(self.n_slots, np.float32)
-        topks = np.zeros(self.n_slots, np.int32)
-        for slot, _uid, pp, c, seq in rows:
-            ids[slot, :c] = seq.prompt[pp : pp + c]
-            pos[slot] = pp
-            counts[slot] = c
-            temps[slot] = seq.temperature
-            topks[slot] = seq.top_k
-        return bucket, ids, pos, counts, temps, topks
+        n, bucket = next(
+            (n, c) for n, c in self.chunk_buckets if n >= len(rows) and c >= need
+        )
+        slots = np.full(n, -1, np.int32)
+        ids = np.zeros((n, bucket), np.int32)
+        pos = np.zeros(n, np.int32)
+        counts = np.zeros(n, np.int32)
+        temps = np.zeros(n, np.float32)
+        topks = np.zeros(n, np.int32)
+        at = [r[0] for r in rows] if n == self.n_slots else range(len(rows))
+        for r, (slot, _uid, pp, c, seq) in zip(at, rows):
+            slots[r] = slot
+            ids[r, :c] = seq.prompt[pp : pp + c]
+            pos[r] = pp
+            counts[r] = c
+            temps[r] = seq.temperature
+            topks[r] = seq.top_k
+        return slots, ids, pos, counts, temps, topks
 
     def _pipeline_take_chunk_plan(self, key: tuple):
         """Hand the overlap-built chunk plan to the chunk round iff the
@@ -2548,14 +2581,14 @@ class DecodeScheduler:
     async def _chunk_round(self) -> None:
         """One prefill chunk round: every PREFILLING slot consumes up to
         its per-round chunk cap of prompt tokens in one fused dispatch
-        (bucketed to the warmed chunk ladder; counts-0 slots ride without
-        cache writes). Slots whose prompt completes emit their first token
-        and transition to generating — decode steps for running slots
-        interleave between rounds instead of stalling behind a monolithic
-        wave prefill."""
+        whose batch is those slots (``_chunk_input_arrays``: the first
+        entry of the warmed chunk ladder that holds them; a generating or
+        free slot is not in it). Slots whose prompt completes emit their
+        first token and transition to generating — decode steps for
+        running slots interleave between rounds instead of stalling behind
+        a monolithic wave prefill."""
         with self._phase(P_ALLOC):
-            counts = np.zeros(self.n_slots, np.int32)
-            need = 0
+            rows: list[tuple[int, int, int, int, _Seq]] = []
             for i, seq in enumerate(self._slots):
                 if seq is None or not seq.prefilling:
                     continue
@@ -2563,39 +2596,30 @@ class DecodeScheduler:
                     self._retire(i)
                     continue
                 rem = self.seq_len - seq.prefill_pos
-                counts[i] = min(rem, seq.chunk_cap or rem)
-                need = max(need, int(counts[i]))
-            if need == 0:
+                c = min(rem, seq.chunk_cap or rem)
+                if c > 0:
+                    rows.append((i, seq.uid, seq.prefill_pos, c, seq))
+            if not rows:
                 return
             # the pipelined loop may have prebuilt this round's input
             # arrays under the previous round's dispatch — valid only if
             # the live state still matches the plan's snapshot key
-            rows = [
-                (i, seq.uid, seq.prefill_pos, int(counts[i]), seq)
-                for i, seq in enumerate(self._slots)
-                if seq is not None and counts[i] > 0
-            ]
             key = tuple(r[:4] for r in rows)
             plan = self._pipeline_take_chunk_plan(key)
-            if plan is not None:
-                _, bucket, ids, pos, counts, temps, topks = plan
-            else:
-                bucket, ids, pos, counts, temps, topks = (
-                    self._chunk_input_arrays(rows)
-                )
+            slots, ids, pos, counts, temps, topks = (
+                plan[1:] if plan is not None else self._chunk_input_arrays(rows)
+            )
             copies: list[tuple[int, int]] = []
-            for i, seq in enumerate(self._slots):
-                if counts[i] == 0 or seq is None:
-                    continue
+            for i, _uid, pp, c, _seq in rows:
                 # page residency for this slot's write range: allocate fresh
                 # pages, copy-on-write the shared boundary page (the reader's
                 # first divergent write into a prefix-mapped page) — always
                 # serial: a CoW copy is not rollback-safe, so residency is
                 # never decided under an in-flight dispatch
-                copies += self.pool.alloc.prepare_write(i, int(pos[i]), int(counts[i]))
+                copies += self.pool.alloc.prepare_write(i, pp, c)
         await self._run_copies(copies)
         with self._phase(P_ALLOC):
-            bt = self.pool.block_tables()
+            bt = self.pool.block_tables(slots)
         tick = self._next_tick()
         t0 = telemetry.now_ns()
         toks, counted = await self._timed_call(
@@ -2606,19 +2630,23 @@ class DecodeScheduler:
             self._rb_counts += counted
         t1 = telemetry.now_ns()
         self.stat_chunk_dispatches += 1
-        finishing: list[tuple[_Seq, int]] = []
+        self._rb_chunk_rows += len(slots)
+        self._rb_chunk_rows_live += len(rows)
+        bucket = ids.shape[1]
+        finishing: list[tuple[_Seq, int, int]] = []  # (seq, slot, its row's token)
         with self._phase(P_SCATTER):
-            for i, seq in enumerate(list(self._slots)):
-                if seq is None or counts[i] == 0:
+            for r, i in enumerate(slots.tolist()):
+                seq = self._slots[i] if i >= 0 else None
+                if seq is None:
                     continue
-                seq.prefill_pos += int(counts[i])
+                seq.prefill_pos += int(counts[r])
                 for c in seq.trace_ctxs:
                     cs = c.buf.begin(
                         "decode.prefill_chunk",
                         c.span.span_id,
                         {
                             "slot": i, "chunk": seq.chunk_idx,
-                            "tokens": int(counts[i]), "bucket": bucket,
+                            "tokens": int(counts[r]), "bucket": bucket,
                             "reused": seq.prefix_len,
                         },
                         start_ns=t0,
@@ -2626,17 +2654,17 @@ class DecodeScheduler:
                     cs.end(t1)
                 seq.chunk_idx += 1
                 if seq.prefill_pos >= self.seq_len:
-                    finishing.append((seq, i))
+                    finishing.append((seq, i, int(toks[r])))
         if finishing and self.programs.admit_buckets:
             # (a feature head needs no transition-time draft prefill — its
             # prompt K/V was teacher-forced by the chunk dispatches)
             # async dispatch: this is enqueue cost; the device time lands
             # in the next dispatch's blocked readback
             with self._dispatch(F_DRAFT) as d, d.enqueue():
-                self.programs.draft_admit([(i, seq.prompt) for seq, i in finishing])
+                self.programs.draft_admit([(i, seq.prompt) for seq, i, _ in finishing])
         t2 = telemetry.now_ns()
         with self._phase(P_SCATTER):
-            for seq, i in finishing:
+            for seq, i, first in finishing:
                 seq.prefilling = False
                 seq.pos = self.seq_len
                 if self.prefix_enabled and seq.cache_prefix > 0:
@@ -2653,7 +2681,7 @@ class DecodeScheduler:
                             start_ns=t2,
                         )
                     )
-                tok = self._emit(seq, int(toks[i]))
+                tok = self._emit(seq, first)
                 if self._finished(seq, tok):
                     self._retire(i)
 

@@ -454,10 +454,15 @@ class PagedKVPool:
     def virtual_ctx(self) -> int:
         return self.pages_per_slot * self.page_size
 
-    def block_tables(self) -> np.ndarray:
+    def block_tables(self, slots: np.ndarray | None = None) -> np.ndarray:
         """Fresh host copy of the block tables for one dispatch (the jit
-        argument must not alias the live allocator state)."""
-        return self.alloc.block_tables.copy()
+        argument must not alias the live allocator state): every slot's
+        row, or the rows of ``slots`` in their order, a row of junk page 0
+        for each -1 among them (a compact dispatch's padding)."""
+        bt = self.alloc.block_tables
+        if slots is None:
+            return bt.copy()
+        return np.where(slots[:, None] >= 0, bt[slots], bt.dtype.type(0))
 
     def run_copies(self, copies: list[tuple[int, int]]) -> None:
         """Dispatch the round's CoW page copies through the warmed ladder

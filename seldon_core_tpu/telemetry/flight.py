@@ -370,6 +370,9 @@ class FlightFrame:
     pages_per_slot``), counted on the host from the round's positions:
     equal on the gather path, read < table where the paged-attention kernel
     stops at each slot's length, 0 / 0 in a round without a plain step;
+    ``chunk_rows`` / ``chunk_rows_live`` the rows the round's prefill chunk
+    dispatch computed (its ``chunk_buckets`` entry's) and the slots that
+    prefilled in it, 0 / 0 in a round without one;
     ``moe_rows`` / ``moe_experts_hit`` /
     ``moe_load_max`` what a sparse-expert family's programs counted in the
     round's chunk and step dispatches (models/moe_decoder.py, real rows
@@ -385,6 +388,7 @@ class FlightFrame:
         "probe", "spec_widths", "promotions",
         "admit_wait_ns", "prefill_ns", "first_tokens",
         "attn_pages_read", "attn_pages_table",
+        "chunk_rows", "chunk_rows_live",
         "moe_rows", "moe_experts_hit", "moe_load_max",
     )
 
@@ -396,6 +400,7 @@ class FlightFrame:
         probe=False, spec_widths=(), promotions=0,
         admit_wait_ns=0, prefill_ns=0, first_tokens=0,
         attn_pages_read=0, attn_pages_table=0,
+        chunk_rows=0, chunk_rows_live=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
     ):
         self.seq = seq
@@ -428,6 +433,8 @@ class FlightFrame:
         self.first_tokens = first_tokens
         self.attn_pages_read = attn_pages_read
         self.attn_pages_table = attn_pages_table
+        self.chunk_rows = chunk_rows
+        self.chunk_rows_live = chunk_rows_live
         self.moe_rows = moe_rows
         self.moe_experts_hit = moe_experts_hit
         self.moe_load_max = moe_load_max
@@ -494,6 +501,8 @@ class FlightFrame:
             d["promotions"] = self.promotions
         if self.attn_pages_table:
             d["attn_pages"] = [self.attn_pages_read, self.attn_pages_table]
+        if self.chunk_rows:
+            d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
         if self.moe_rows:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
         return d

@@ -7,6 +7,7 @@ The rounds that call the set are covered where they always were
 test_spec_tree, test_moe_decoder); here the set itself is driven with the
 scheduler's argument shapes, one deployment of each convention."""
 
+import asyncio
 import functools
 
 import jax
@@ -18,12 +19,13 @@ from seldon_core_tpu.models import moe_decoder as md
 from seldon_core_tpu.models.decoder import (
     FamilyNotServed,
     decoder_family,
+    generate,
     gpt2_family,
     init_decoder,
     init_feature_draft,
     require_served,
 )
-from seldon_core_tpu.serving.decode_scheduler import DecodeScheduler
+from seldon_core_tpu.serving.decode_scheduler import DecodeScheduler, _PendingAdmit, _Seq
 
 SEQ, MAX_NEW, N = 8, 6, 2
 MOE = md.moe_family(
@@ -74,7 +76,7 @@ def _inputs(s: DecodeScheduler):
 @pytest.mark.parametrize("convention", list(CONVENTIONS))
 def test_step_and_chunk_keep_one_contract_under_every_convention(convention, kind):
     """``step`` and ``chunk`` take the same arguments and return ``(out,
-    read)`` with ``read() -> (tokens[n_slots], counts | None)`` whether the
+    read)`` with ``read() -> (tokens[rows], counts | None)`` whether the
     program also takes ``rows``, round-trips a feature buffer, or appends a
     counting family's counts to its readback — and compile nothing that
     ``warmup`` had not."""
@@ -88,7 +90,8 @@ def test_step_and_chunk_keep_one_contract_under_every_convention(convention, kin
     if kind == "step":
         out, read = p.step(bt, zi, zi, zf, zi, tick, rows)
     else:
-        bucket = s.chunk_buckets[0]
+        rows, bucket = s.chunk_buckets[0]
+        assert rows == N  # two slots: the compact width is the full width
         out, read = p.chunk(bt, np.zeros((N, bucket), np.int32), zi, zi, zf, zi, tick)
     jax.block_until_ready(out)  # what a sync-timing run blocks on
     toks, counts = read()
@@ -125,6 +128,162 @@ def test_draft_and_verify_keep_one_contract_under_every_convention(convention):
     assert (p.feat is not None) == (convention == "feature")
     assert p.compile_counts() == base
     assert bool(p.admit_buckets) == (convention != "feature")  # the head's prompt K/V rides the chunk ladder
+
+
+# ---- the chunk dispatch's batch is the slots that prefill (ISSUE 33) ----
+# 16 slots, 40-token prompts in chunks of at most 20: c climbs from 16, two
+# rows at every c, four rows and the full width at the top c only
+WIDE, WSEQ, CAP, WNEW = 16, 40, 20, 3
+LADDER = ((2, 16), (2, 20), (4, 20), (WIDE, 20))
+
+
+def _wide(family: str, vocab: int):
+    """(scheduler over 16 slots, not warmed; greedy oracle). A test that
+    counts compiles passes a ``vocab`` no other scheduler of the process has."""
+    kw = dict(seq_len=WSEQ, max_new_tokens=WNEW, n_slots=WIDE, kv_page_size=4, prefill_chunk=CAP)
+    if family == "counting":
+        fam = md.moe_family(md.MoEDecoderConfig(**{**MOE.cfg.__dict__, "vocab": vocab}))
+        params = md.init_moe_decoder(fam.cfg, seed=0, dtype=jnp.float32)
+        return DecodeScheduler(params, family=fam, **kw), lambda ids: fam.generate(params, ids, WNEW)
+    params = init_decoder(seed=3, vocab=vocab, hidden=64, layers=2, ffn=128, max_len=64)
+    return DecodeScheduler(params, **kw), lambda ids: generate(params, ids, WNEW)
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_warm(family: str) -> DecodeScheduler:
+    s, _ = _wide(family, vocab=96)
+    s.warmup()
+    return s
+
+
+def _chunk_dispatch(s, pool0, bt, slots, ids, pos, counts):
+    """One greedy chunk dispatch from the pool bytes ``pool0`` whose rows
+    are ``slots`` of the per-slot inputs (-1: a padding row, count 0, block
+    table row of junk page 0): (a token a slot, counts, the pool after)."""
+    slots = np.asarray(slots)
+
+    def take(x):
+        return np.where((slots >= 0).reshape((-1,) + (1,) * (x.ndim - 1)), x[slots], 0).astype(x.dtype)
+
+    s.pool.state = tuple(jnp.asarray(a) for a in pool0)
+    z = np.zeros(len(slots))
+    _out, read = s.programs.chunk(
+        take(bt), take(ids), take(pos), take(counts), z.astype(np.float32), z.astype(np.int32), np.int32(5)
+    )
+    toks, counted = read()
+    return {int(i): int(toks[r]) for r, i in enumerate(slots) if i >= 0}, counted, [np.asarray(a) for a in s.pool.state]
+
+
+@pytest.mark.parametrize("live", [(3, 11), (11,)], ids=["two_rows", "one_row_and_padding"])
+@pytest.mark.parametrize("family", ["gpt2", "counting"])
+def test_a_compact_chunk_dispatch_is_the_full_width_one_for_its_slots(family, live):
+    """Slots 3 and 11 prefill (a cold 16-token chunk; 13 tokens from position
+    8), slot 5 generates: the 2-row dispatch over ``bt[[3, 11]]`` gives those
+    slots the tokens and the pool pages the 16-row dispatch gives them, and
+    neither touches another live page; a padding row writes junk page 0 only."""
+    s = _wide_warm(family)
+    n_log, ps = s.pool.pages_per_slot, s.pool.page_size
+    rng = np.random.default_rng(0)
+    pool0 = [rng.normal(size=a.shape).astype(a.dtype) for a in s.pool.state]
+    bt = np.zeros((WIDE, n_log), np.int32)
+    for j, slot in enumerate((3, 11, 5)):
+        bt[slot] = 1 + j * n_log + np.arange(n_log)
+    ids = rng.integers(0, 96, (WIDE, 16)).astype(np.int32)
+    pos, counts = np.zeros(WIDE, np.int32), np.zeros(WIDE, np.int32)
+    pos[11] = 8
+    counts[list(live)] = [16, 13][2 - len(live):]
+    written = {int(bt[i, p // ps]) for i in live for p in range(pos[i], pos[i] + counts[i])}
+    toks_c, counted_c, pool_c = _chunk_dispatch(s, pool0, bt, list(live) + [-1] * (2 - len(live)), ids, pos, counts)
+    toks_w, counted_w, pool_w = _chunk_dispatch(s, pool0, bt, np.arange(WIDE), ids, pos, counts)
+    assert toks_c == {i: toks_w[i] for i in live}
+    if family == "counting":
+        assert int(counted_c[0]) == int(counted_w[0]) == int(counts.sum())  # moe_rows: real rows only
+        np.testing.assert_array_equal(counted_c, counted_w)
+    else:
+        assert counted_c is None
+    untouched = [p for p in range(1, pool0[0].shape[1]) if p not in written]
+    for a0, ac, aw in zip(pool0, pool_c, pool_w):
+        np.testing.assert_array_equal(ac[:, untouched], a0[:, untouched])  # slot 5's pages among them
+        np.testing.assert_array_equal(aw[:, untouched], a0[:, untouched])
+        np.testing.assert_allclose(ac[:, sorted(written)], aw[:, sorted(written)], rtol=1e-5, atol=1e-6)
+        assert not np.array_equal(ac[:, sorted(written)], a0[:, sorted(written)])
+
+
+@pytest.mark.parametrize("family", ["gpt2", "counting"])
+async def test_a_chunk_round_dispatches_at_the_first_entry_that_holds_its_slots(family):
+    """k prefilling slots dispatch at the smallest rows entry >= k, a wave of
+    ``n_slots`` at the full-width program; a mixed sequence of both compiles
+    nothing after ``warmup``, which compiled the ladder's entries and no
+    more; the frames count the rows dispatched and the slots live in them;
+    every request reads the greedy oracle's tokens whichever row it rode."""
+    s, oracle = _wide(family, vocab={"gpt2": 160, "counting": 112}[family])
+    assert s.chunk_buckets == LADDER
+    base = s.compile_counts()["chunk"]
+    s.warmup()
+    assert s.compile_counts()["chunk"] == base + len(LADDER)
+    prompts = np.random.default_rng(1).integers(0, 96, (23, WSEQ)).astype(np.int32)
+    want = np.asarray(oracle(jnp.asarray(prompts)))
+    got = [await s.submit(prompts[0])]
+    for lo, hi in ((1, 3), (3, 19), (19, 22), (22, 23)):  # two together, a wave of 16, three, one
+        got += await asyncio.gather(*(s.submit(p) for p in prompts[lo:hi]))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert s.recompiles_since_warmup() == 0
+    assert s.compile_counts()["chunk"] == base + len(LADDER)
+    frames = [f for f in s.flight.snapshot() if f.chunk_rows]
+    assert len(frames) == s.stat_chunk_dispatches
+    for f in frames:
+        assert f.busy_ns[0] > 0 and f.chunk_rows == next(r for r in (2, 4, WIDE) if r >= f.chunk_rows_live)
+        assert f.to_dict()["chunk_rows"] == [f.chunk_rows_live, f.chunk_rows]
+    assert sum(f.chunk_rows_live for f in frames) == 23 * 2  # every prompt is two chunks of 20
+    assert {1, 2, 3, WIDE} <= {f.chunk_rows_live for f in frames}
+    assert not any(f.chunk_rows for f in s.flight.snapshot() if f.busy_ns[0] == 0)
+    await s.close()
+
+
+@pytest.mark.parametrize("pending", [0, 1, 3], ids=["two_slots", "three_slots_in_four_rows", "five_slots_full_width"])
+async def test_the_overlap_built_chunk_plan_is_the_serial_build(pending):
+    """``_pipeline_plan_chunk`` (under the previous dispatch) and the serial
+    chunk round go through one builder: the same rows give the same arrays,
+    compact in slot order with padding after them, row r = slot r at full
+    width."""
+    s = _wide_warm("gpt2")
+    loop = asyncio.get_running_loop()
+    rng = np.random.default_rng(2)
+    rows = []
+    for uid, (slot, pp, temp) in enumerate([(3, 0, 0.0), (11, 20, 0.7)] + [(7, 8, 0.0), (0, 8, 0.0), (15, 8, 0.0)][:pending]):
+        seq = _Seq(rng.integers(0, 96, WSEQ).astype(np.int32), WNEW, temp, uid, 0, None, loop.create_future())
+        seq.uid, seq.chunk_cap, seq.prefilling, seq.prefill_pos = 100 + uid, CAP, True, pp
+        rows.append((slot, seq.uid, pp, min(CAP, WSEQ - pp), seq))
+    try:
+        for slot, _uid, _pp, _c, seq in rows[:2]:
+            s._slots[slot] = seq
+        for slot, _uid, pp, _c, seq in rows[2:]:  # admissions decided under the flight, not installed yet
+            s._pending_admits.append(_PendingAdmit(seq, slot, None, pp, 0))
+        s._pipeline_plan_chunk()
+        rows.sort(key=lambda r: r[0])
+        plan = s._pipeline_take_chunk_plan(tuple(r[:4] for r in rows))
+        assert plan is not None and s._pending_chunk_plan is None
+        serial = s._chunk_input_arrays(rows)
+        for a, b in zip(plan[1:], serial):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        slots, ids, pos, counts, temps, _topks = serial
+        assert (len(slots), ids.shape[1]) in LADDER
+        want = {0: [3, 11], 1: [3, 7, 11, -1], 3: [i if i in (0, 3, 7, 11, 15) else -1 for i in range(WIDE)]}
+        assert slots.tolist() == want[pending]
+        r11 = slots.tolist().index(11)
+        assert (pos[r11], counts[r11], temps[r11]) == (20, 20, np.float32(0.7))
+        np.testing.assert_array_equal(ids[r11, :20], next(r[4] for r in rows if r[0] == 11).prompt[20:40])
+        np.testing.assert_array_equal(
+            s.pool.block_tables(slots)[slots >= 0], s.pool.block_tables()[slots[slots >= 0]]
+        )
+        assert not s.pool.block_tables(slots)[slots < 0].any()
+    finally:
+        s._slots[3] = s._slots[11] = None
+        s._pending_admits.clear()
+        for r in rows:
+            r[4].future.cancel()
 
 
 @pytest.mark.parametrize("mechanism, message", [

@@ -174,6 +174,36 @@ def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo
     assert not [op for op in sized if op.startswith("copy")], sized
 
 
+def test_compact_chunk_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo):
+    """The same cell's 256-token prefill chunk at the compact width (two
+    rows of the scheduler's chunk ladder, block-table rows of two slots;
+    8 of 36 layers, shapes only): the per-layer scatter lands in the donated
+    pool, and the temporaries are the two rows' own — the 16-row program's
+    gathered caches and scores come to 1.5 GiB here, these to 0.11."""
+    from seldon_core_tpu.models.decoder import _fused_chunk, init_decoder
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(
+        lambda: init_decoder(0, vocab=50257, hidden=1280, layers=8, ffn=5120, max_len=1024)
+    )
+    geo = {"n_slots": 2, "n_pages": 720, "page_size": 16, "pages_per_slot": 44}
+    p, pool, (bt, _tokens, pos, temps, topks, seed, tick) = _step_args(
+        params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
+    )
+    ids = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one)
+    compiled = (
+        jax.jit(_fused_chunk, donate_argnums=(1,))
+        .lower(p, pool, bt, ids, pos, pos, temps, topks, seed, tick)
+        .compile()
+    )
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in pool)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 4
+    sized = _pool_sized_entry_ops(compiled.as_text(), pool)
+    assert not [op for op in sized if op.startswith("copy")], sized
+
+
 def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo):
     """The same cell geometry with the step's attention in the Pallas decode
     kernel (ops/paged_attention.py; ``attn_kernel`` is the static argument the
