@@ -305,6 +305,7 @@ def decoder_dims(params: dict) -> dict:
     heads = _heads(params)
     return {
         "layers": len(params["layers"]),
+        "kv_layers": len(params["layers"]),  # the layers that hold K/V pages: all of them here
         "heads": heads,
         "kv_heads": heads,
         "hidden": hidden,
@@ -652,10 +653,11 @@ def kv_pool_zeros(
     d: dict, n_pages: int, page_size: int, dtype=jnp.float32, kv_dtype: str = ""
 ) -> tuple:
     """The pool of ANY family from its ``decoder_dims``: a token row holds
-    one token's K (or V) for all ``kv_heads``."""
-    shape = (d["layers"], n_pages, page_size, d["kv_heads"] * d["head_dim"])
+    one token's K (or V) for all ``kv_heads``, in each of the ``kv_layers``
+    layers that attend (a hybrid family's recurrent layers hold no pages)."""
+    shape = (d["kv_layers"], n_pages, page_size, d["kv_heads"] * d["head_dim"])
     if kv_dtype == "int8":
-        sshape = (d["layers"], n_pages, page_size)
+        sshape = (d["kv_layers"], n_pages, page_size)
         # scale 1 / zp 0: dequantized junk pages read back as exact zeros,
         # matching the fp pool's init
         return (
@@ -909,6 +911,9 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
 _MECHANISMS = {
     "speculation": "speculative decoding (draft, tree, feature head)",
     "decode_mesh": "tensor-parallel decode (parallel/tp.py)",
+    "kv_int8": "the int8 KV pool (decode_kv_dtype)",
+    "host_tier": "the host and store KV tiers (decode_kv_host_bytes, decode_kv_store_tier)",
+    "prefix_export": "prefix-cache export and pre-seeding (export_prefix_state, preseed_prefix_state)",
 }
 
 
@@ -926,16 +931,29 @@ class GPT2Decoder:
     decoder family answers, in one list (models/moe_decoder.py
     ``MoEDecoder`` is the second): ``name``; ``decoder_dims(params)`` (raises
     ``FamilyNotServed`` for another family's parameters);
-    ``paged_kv_init`` (the zeroed pool); ``frame_counters`` (FlightFrame
-    fields its programs' readback carries after the tokens, none here);
-    ``serves`` (of "speculation", "decode_mesh", "attn_kernel": what
-    beside the plain rounds it can be asked for — ``require_served``);
+    ``paged_kv_init`` (the zeroed pool, of ``decoder_dims``' ``kv_layers``
+    layers); ``frame_counters`` (FlightFrame fields its programs' readback
+    carries after the tokens, none here);
+    ``serves`` (of "speculation", "decode_mesh", "attn_kernel", "kv_int8",
+    "host_tier", "prefix_export": what beside the plain rounds it can be
+    asked for — ``require_served``);
     ``fused_programs(attn_kernel)`` (its step and chunk bodies, both named
-    ``_fused_step`` / ``_fused_chunk`` whatever the family)."""
+    ``_fused_step`` / ``_fused_chunk`` whatever the family);
+    ``state_init`` — None, or for a family whose layers carry a recurrent
+    state (models/hybrid_decoder.py) ``state_init(params, rows)``: the
+    zeroed state cache, a tuple of arrays with the ROW at axis 0, which the
+    pool holds beside the pages (serving/kv_pool.py ``recurrent``). Such a
+    family's programs take that tuple after the pool, donated with it, and
+    give it back after it; its step takes ``rows`` and advances no other
+    slot's state; its chunk takes ``state_rows`` [3, rows] last (the row
+    each batch row reads, writes and snapshots)."""
 
     name = "gpt2"
     frame_counters = ()
-    serves = frozenset({"speculation", "decode_mesh", "attn_kernel"})
+    serves = frozenset(
+        {"speculation", "decode_mesh", "attn_kernel", "kv_int8", "host_tier", "prefix_export"}
+    )
+    state_init = None
     decoder_dims = staticmethod(decoder_dims)
     paged_kv_init = staticmethod(paged_kv_init)
 

@@ -247,16 +247,17 @@ def _window_table(bt, positions, m: int, page_size: int, window: int):
     return jnp.take_along_axis(bt, cols, axis=1), p0 * page_size
 
 
-def _attend(q, ck, cv, visible):
+def _attend(q, ck, cv, visible, scale=None):
     """q[n, m, h, d] against the gathered float32 cache ck/cv[n, g, K, d]
     (g key/value heads; query head j reads head j // (h / g)) under
     visible[n, m, K] -> context [n, m, h * d] in q's dtype. Same masking
-    and float32 softmax as the GPT-2 family's paged attention."""
+    and float32 softmax as the GPT-2 family's paged attention. ``scale``
+    multiplies the scores: 1 / sqrt(d) unless the family states another."""
     n, m, h, d = q.shape
     g = ck.shape[1]
     r = h // g
     qg = q.reshape(n, m, g, r, d).transpose(0, 2, 3, 1, 4).reshape(n, g, r * m, d)
-    s = jnp.einsum("ngqd,ngkd->ngqk", qg.astype(jnp.float32), ck) * (1.0 / d**0.5)
+    s = jnp.einsum("ngqd,ngkd->ngqk", qg.astype(jnp.float32), ck) * (1.0 / d**0.5 if scale is None else scale)
     s = jnp.where(visible[:, None, None, :, :], s.reshape(n, g, r, m, -1), -1e30)
     p = jax.nn.softmax(s, axis=-1).reshape(n, g, r * m, -1)
     ctx = jnp.einsum("ngqk,ngkd->ngqd", p, cv)
@@ -352,7 +353,7 @@ def _generate(cfg, params, ids, max_new_tokens: int):
     b, s = ids.shape
     ps = 16
     pages = -(-(s + max_new_tokens) // ps)
-    dims = {"layers": cfg.layers, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim}
+    dims = {"kv_layers": cfg.layers, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim}
     pool = kv_pool_zeros(dims, 1 + b * pages, ps, params["tok_emb"].dtype)
     bt = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
     zero = jnp.zeros((b,), jnp.int32)
@@ -386,15 +387,16 @@ class MoEDecoder:
     # what paged_forward's extra output counts, in order (FlightFrame fields)
     frame_counters = ("moe_rows", "moe_experts_hit", "moe_load_max")
     # not served yet (decoder.require_served): speculation, a decode mesh, a step attention kernel
-    serves = frozenset()
+    serves = frozenset({"kv_int8", "host_tier", "prefix_export"})
+    state_init = None  # no recurrent state: pages only
 
     def decoder_dims(self, params: dict) -> dict:
         if "lm_head" not in params or "moe" not in params["layers"][0]:
             raise FamilyNotServed("not a sparse-expert decoder's parameters (models/moe_decoder.py layout)")
         c = self.cfg
         return {
-            "layers": len(params["layers"]), "heads": c.heads, "kv_heads": c.kv_heads,
-            "hidden": c.hidden, "head_dim": c.head_dim, "q_width": c.q_width,
+            "layers": len(params["layers"]), "kv_layers": len(params["layers"]), "heads": c.heads,
+            "kv_heads": c.kv_heads, "hidden": c.hidden, "head_dim": c.head_dim, "q_width": c.q_width,
             "vocab": params["tok_emb"].shape[0], "max_len": c.max_len,
         }
 

@@ -498,6 +498,79 @@ def build_moe_decoder(
     )
 
 
+@register_model("hybrid_decoder")
+def build_hybrid_decoder(
+    seed: int = 0,
+    vocab: int = 512,
+    hidden: int = 64,
+    layers: int = 4,
+    attn_layers: str = "1",
+    ffn: int = 128,
+    heads: int = 4,
+    kv_heads: int = 2,
+    head_dim: int = 16,
+    ssm_heads: int = 8,
+    ssm_head_dim: int = 16,
+    ssm_state: int = 16,
+    ssm_conv: int = 4,
+    embedding_multiplier: float = 12.0,
+    residual_multiplier: float = 0.22,
+    attention_multiplier: float = 0.0625,
+    logits_scaling: float = 8.0,
+    rms_eps: float = 1e-5,
+    max_len: int = 131072,
+    seq: int = 32,
+    max_new_tokens: int = 16,
+    param_dtype: str = "bfloat16",
+    **_,
+) -> ModelSpec:
+    """The generative tier's third decoder family (models/hybrid_decoder.py,
+    Granite 4.0-H): Mamba-2 layers with a recurrent state, grouped-query
+    attention without positions in the layers ``attn_layers`` names
+    (comma-separated indices), a dense gated-SiLU MLP of width ``ffn`` in
+    every layer, Granite's four multipliers, a tied head. The parameters are
+    a published config's keys. Weights are drawn on the device from ``seed``
+    in ``param_dtype``. It serves through ``tpu.decode_slots``: the
+    recurrent state lives in state rows beside the KV pages, sized from
+    ``decode_slots`` and ``decode_prefix_slots``; without it the fused
+    fallback decodes whole batches greedily through the same forward.
+    Speculation, tensor-parallel decode, the int8 pool, the KV tiers and
+    prefix export are not served for it."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.hybrid_decoder import (
+        HybridDecoderConfig,
+        hybrid_family,
+        init_hybrid_decoder,
+    )
+
+    if seq + max_new_tokens > max_len:
+        raise ValueError(
+            f"seq={seq} + max_new_tokens={max_new_tokens} exceeds max_len={max_len}"
+        )
+    cfg = HybridDecoderConfig(
+        vocab=int(vocab), hidden=int(hidden), layers=int(layers),
+        attn_layers=tuple(int(i) for i in str(attn_layers).split(",") if i.strip()),
+        heads=int(heads), kv_heads=int(kv_heads), head_dim=int(head_dim), ffn=int(ffn),
+        ssm_heads=int(ssm_heads), ssm_head_dim=int(ssm_head_dim), ssm_state=int(ssm_state),
+        ssm_conv=int(ssm_conv), embedding_multiplier=float(embedding_multiplier),
+        residual_multiplier=float(residual_multiplier),
+        attention_multiplier=float(attention_multiplier),
+        logits_scaling=float(logits_scaling), rms_eps=float(rms_eps), max_len=int(max_len),
+    )
+    family = hybrid_family(cfg)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[str(param_dtype)]
+    max_new = int(max_new_tokens)
+    return ModelSpec(
+        lambda p, x: family.generate(p, x, max_new),
+        init_hybrid_decoder(cfg, int(seed), dtype),
+        (int(seq),),
+        (),
+        int_inputs="ids",
+        generative={"seq": int(seq), "max_new_tokens": max_new, "family": family},
+    )
+
+
 @register_model("draft")
 def build_draft(
     seed: int = 0,

@@ -16,8 +16,10 @@ handle, and the one convention each round kind is called by.
 The caller's convention is one per round kind — ``step``, ``chunk``,
 ``draft`` + ``verify``, ``draft_admit`` — whatever the deployment: whether
 ``rows`` reaches the program, whether a feature buffer rides along, whether
-a counting family's counts are split off the readback, chain or tree or
-feature tree, is decided in here, once. A call ENQUEUES: it hands the
+a counting family's counts are split off the readback, whether a recurrent
+family's state rows ride beside the pool (``pool.recurrent``: donated and
+put back with ``pool.state``), chain or tree or feature tree, is decided in
+here, once. A call ENQUEUES: it hands the
 donated ``pool.state`` in, puts the program's back, and returns ``(out,
 read)`` — the device handle(s) a timing run may block on and the blocking
 host read. Timing and naming a dispatch stay the scheduler's (``_Dispatch``).
@@ -297,6 +299,9 @@ class DecodePrograms:
         # a counting family's step also takes the rows that generate, and its
         # readback carries the counts after the tokens (``_tokens``)
         self._counted = len(family.frame_counters)
+        # a recurrent family's programs take the state rows after the pool,
+        # donated with it, and the chunk the rows each batch row reads and writes
+        self._stateful = bool(pool.recurrent)
         self._place, self._draft_ctx, self._dtype = place, draft_ctx, dtype
         self._hidden = dims["hidden"]
         # per-slot draft attention window start (host data: the computed
@@ -348,8 +353,9 @@ class DecodePrograms:
             )
         else:
             step, chunk = family.fused_programs(self.attn_kernel)
-            self._step_fn = jax.jit(step, donate_argnums=(1,), **out(rep, pool_sh))
-            self._chunk_fn = jax.jit(chunk, donate_argnums=(1,), **out(rep, pool_sh))
+            donated = (1, 2) if self._stateful else (1,)
+            self._step_fn = jax.jit(step, donate_argnums=donated, **out(rep, pool_sh))
+            self._chunk_fn = jax.jit(chunk, donate_argnums=donated, **out(rep, pool_sh))
         if self.mode == "tree":
             # tree mode subsumes the chain (a branching-1 tree IS the
             # chain), so the chain draft/verify pair is not compiled —
@@ -422,6 +428,11 @@ class DecodePrograms:
                 self.params, pool.state, bt, toks, pos, self.feat, rows, temps, topks,
                 self.seed, tick,
             )
+        elif self._stateful:
+            out, pool.state, pool.recurrent = self._step_fn(
+                self.params, pool.state, pool.recurrent, bt, toks, pos, temps, topks,
+                self.seed, tick, rows,
+            )
         else:
             out, pool.state = self._step_fn(
                 self.params, pool.state, bt, toks, pos, temps, topks, self.seed, tick,
@@ -429,18 +440,25 @@ class DecodePrograms:
             )
         return out, lambda: self._tokens(out)
 
-    def chunk(self, bt, ids, pos, counts, temps, topks, tick):
+    def chunk(self, bt, ids, pos, counts, temps, topks, tick, state_rows=None):
         """Enqueue one prefill chunk round at a ``[rows, c]`` entry of the
         chunk ladder: ``ids`` and the block-table rows ``bt`` of the slots
         that prefill, one row each (counts 0: a padding row, its writes
         junk-sink), whichever slots they are; the read gives a token a
-        row. The feature twin carries its buffers by slot: all ``n_slots``
-        rows, row r slot r."""
+        row. ``state_rows`` (a recurrent family: ``pool.state_rows``) names
+        the state row each batch row reads, writes and snapshots. The
+        feature twin carries its buffers by slot: all ``n_slots`` rows, row
+        r slot r."""
         pool = self.pool
         if self.mode == "feature":
             out, self.feat, pool.state, self.dck, self.dcv = self._chunk_f_fn(
                 self.params, self.draft_params, pool.state, bt, self.dck, self.dcv, ids,
                 pos, counts, self.feat, self.draft_start, temps, topks, self.seed, tick,
+            )
+        elif self._stateful:
+            out, pool.state, pool.recurrent = self._chunk_fn(
+                self.params, pool.state, pool.recurrent, bt, ids, pos, counts, temps, topks,
+                self.seed, tick, state_rows,
             )
         else:
             out, pool.state = self._chunk_fn(
@@ -520,9 +538,11 @@ class DecodePrograms:
         bt0 = self.pool.block_tables()
         tick = np.int32(0)
         for rows, c in chunk_buckets:
+            pad = np.full(rows, -1)
             self.chunk(
-                self.pool.block_tables(np.full(rows, -1)), np.zeros((rows, c), np.int32),
+                self.pool.block_tables(pad), np.zeros((rows, c), np.int32),
                 zi[:rows], zi[:rows], zf[:rows], zi[:rows], tick,
+                self.pool.state_rows(pad) if self._stateful else None,
             )
         self.pool.warmup()  # the CoW copy ladder (page0 self-copies)
         for b in self.admit_buckets:

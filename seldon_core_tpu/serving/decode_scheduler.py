@@ -39,6 +39,15 @@ scheduling over a vLLM-style PAGED KV pool into the stack:
   traffic. Entries are captured from retiring slots (full prompt) and
   explicit ``meta.tags.cache_prefix`` hints (at prefill completion) by
   pinning the pages in place.
+- Recurrent state beside pages (a family with ``state_init``:
+  models/hybrid_decoder.py): the pool also holds STATE ROWS, a row a slot, a
+  row a cached prefix and a row of zeros (serving/kv_pool.py). A chunk
+  dispatch names the row each batch row reads and writes; the step advances
+  the generating slots' own rows and leaves every other as it was. A state
+  is reusable only where a snapshot of it was kept, so a prefix hit reuses
+  an entry's WHOLE length or nothing, a ``cache_prefix`` hint makes the
+  chunk plan end a chunk at the hint's boundary (that dispatch writes the
+  snapshot row), and a request without a hint captures nothing.
 - Chunked prefill (``tpu.decode_prefill_chunk``): prompt suffixes are
   computed in fixed-size chunk buckets interleaved with decode steps
   (Sarathi-style), so a long admission wave no longer stalls every
@@ -522,13 +531,16 @@ class _PrefixEntry:
     the pool pages carrying its K/V (a kv_pool pin id) — no private pool
     row, no copy anywhere in its lifecycle."""
 
-    __slots__ = ("tokens", "length", "pages", "pin_id", "last_use", "hits")
+    __slots__ = ("tokens", "length", "pages", "pin_id", "last_use", "hits", "state_row")
 
-    def __init__(self, tokens: np.ndarray, pages: list[int], pin_id: int):
+    def __init__(self, tokens: np.ndarray, pages: list[int], pin_id: int, state_row: int = -1):
         self.tokens = np.asarray(tokens, np.int32)
         self.length = int(self.tokens.shape[0])
         self.pages = list(pages)
         self.pin_id = pin_id
+        # a recurrent family: the pool row that holds the state after
+        # ``length`` tokens (bound to the pin: kv_pool ``PoolPin.state_row``)
+        self.state_row = state_row
         self.last_use = 0
         self.hits = 0
 
@@ -538,16 +550,20 @@ class PrefixIndex:
     (serving/kv_pool.py): a hit maps the entry's pages into the reader's
     block table (refcount bump) instead of copying anything.
 
-    Matching is longest-COMMON-prefix against ANY entry, not whole-entry
-    match: causal K/V at position i depends only on tokens 0..i, so a
-    partial overlap with a longer cached entry is exactly as reusable as a
-    full one (what makes shared system prompts hit without any client
-    hint: the first full-prompt capture seeds every later request's common
-    prefix). The entries are few (``max_entries``) and their tokens one
-    int32 array each, so a match is one vectorised compare an entry: a
-    3072-token prefix costs microseconds on the admission path, which
-    lies between two dispatches with the device idle, and insert and
-    evict build nothing.
+    The depth a hit may reuse, by cache kind. PAGES: matching is
+    longest-COMMON-prefix against ANY entry, not whole-entry match: causal
+    K/V at position i depends only on tokens 0..i, so a partial overlap
+    with a longer cached entry is exactly as reusable as a full one (what
+    makes shared system prompts hit without any client hint: the first
+    full-prompt capture seeds every later request's common prefix). STATE
+    ROWS (``match(whole=True)``): a recurrent state after n tokens says
+    nothing of the state after fewer, so an entry is reusable at its own
+    length only, where its snapshot was taken, and only by a prompt that
+    holds all of it: the longest entry the prompt begins with. The entries
+    are few (``max_entries``) and their tokens one int32 array each, so a
+    match is one vectorised compare an entry: a 3072-token prefix costs
+    microseconds on the admission path, which lies between two dispatches
+    with the device idle, and insert and evict build nothing.
 
     Capacity is bounded twice: ``max_entries`` caps the index itself
     (insert evicts the LRU entry and returns it so the caller can release
@@ -567,11 +583,15 @@ class PrefixIndex:
         self._clock += 1
         return self._clock
 
-    def match(self, prompt, touch: bool = True) -> tuple["_PrefixEntry | None", int]:
+    def match(
+        self, prompt, touch: bool = True, whole: bool = False
+    ) -> tuple["_PrefixEntry | None", int]:
         """Longest common prefix between ``prompt`` and any entry:
         (entry, depth); of entries equally deep the newest insert wins.
-        ``touch=False`` peeks without bumping LRU age (the capture-dedup
-        probe must not keep its own victim warm)."""
+        ``whole``: only an entry the prompt holds ALL of counts (the class
+        docstring's rule for state rows). ``touch=False`` peeks without
+        bumping LRU age (the capture-dedup probe must not keep its own
+        victim warm)."""
         prompt = np.asarray(prompt, np.int32)
         ent, depth = None, 0
         for e in self.entries.values():
@@ -580,6 +600,8 @@ class PrefixIndex:
                 continue
             same = e.tokens[:n] == prompt[:n]
             d = n if same.all() else int(same.argmin())
+            if whole and d < e.length:
+                continue
             if d >= max(depth, 1):
                 ent, depth = e, d
         if ent is None:
@@ -590,23 +612,29 @@ class PrefixIndex:
         return ent, depth
 
     def insert(
-        self, tokens, pages: list[int], pin_id: int
+        self, tokens, pages: list[int], pin_id: int, state_row: int = -1
     ) -> tuple["_PrefixEntry", "_PrefixEntry | None"]:
         """Index a captured prefix; returns (entry, evicted) where
         ``evicted`` is the LRU entry pushed out by the max_entries cap (the
         caller must release its pool pin) or None."""
-        evicted = None
-        if len(self.entries) >= self.max_entries:
-            evicted = min(self.entries.values(), key=lambda e: e.last_use)
-            self.remove(evicted)
-            self.evictions += 1
-        e = _PrefixEntry(tokens, pages, pin_id)
+        evicted = self.evict_lru() if len(self.entries) >= self.max_entries else None
+        e = _PrefixEntry(tokens, pages, pin_id, state_row)
         e.last_use = self._tick()
         self.entries[pin_id] = e
         return e, evicted
 
     def remove(self, e: "_PrefixEntry") -> None:
         del self.entries[e.pin_id]
+
+    def evict_lru(self) -> "_PrefixEntry | None":
+        """Drop and return the least recently used entry (the caller releases
+        its pool pin); None where the index is empty."""
+        if not self.entries:
+            return None
+        lru = min(self.entries.values(), key=lambda e: e.last_use)
+        self.remove(lru)
+        self.evictions += 1
+        return lru
 
     def remove_by_pins(self, pin_ids) -> int:
         """Pool-pressure reclaim callback: the allocator already dropped
@@ -633,7 +661,7 @@ class _Seq:
         "t_last_token",
         "deadline", "trace_ctxs", "gen_spans",
         "prefilling", "prefill_pos", "prefix_len", "chunk_cap",
-        "cache_prefix", "chunk_idx",
+        "cache_prefix", "chunk_idx", "state_src",
         "slo_deadline", "slo_ok", "slo_sink",
         "replay", "emit_base", "kv_tier",
     )
@@ -669,6 +697,9 @@ class _Seq:
         self.chunk_cap = 0  # per-round prefill token cap (0 = whole suffix)
         self.cache_prefix = 0  # meta.tags.cache_prefix capture hint
         self.chunk_idx = 0
+        # a recurrent family: the state row the NEXT chunk reads where it is
+        # not the slot's own (the zero row, an entry's snapshot row), else -1
+        self.state_src = -1
         # goodput/SLO attribution: the request's deadline budget (absolute
         # perf_counter; 0 = none) captured from the DEADLINE contextvar at
         # submit, whether every configured SLO held so far, and an optional
@@ -760,6 +791,13 @@ class DecodeScheduler:
             require_served(self.family, "speculation")
         if tp_width(mesh_axes) > 1:
             require_served(self.family, "decode_mesh")
+        if kv_dtype == "int8":
+            require_served(self.family, "kv_int8")
+        if int(kv_host_bytes) > 0 or kv_store_url:
+            require_served(self.family, "host_tier")
+        # a family whose layers carry a recurrent state keeps it in state
+        # rows beside the pages (module docstring)
+        self._stateful = self.family.state_init is not None
         dims = self.family.decoder_dims(params)
         self.max_ctx = seq_len + max_new_tokens
         if self.max_ctx > dims["max_len"]:
@@ -1004,6 +1042,8 @@ class DecodeScheduler:
                 else None
             ),
             kv_init=self.family.paged_kv_init,
+            state_init=self.family.state_init,
+            n_state_rows=self.prefix_slots,
         )
         if self.prefix_enabled:
             self.pool.alloc.on_pins_reclaimed = self._on_pins_reclaimed
@@ -1094,6 +1134,9 @@ class DecodeScheduler:
         self.stat_prefix_misses = 0
         self.stat_prefix_tokens_saved = 0
         self.stat_prefix_captures = 0
+        # captures that could not be made: a span without pages, and in a
+        # recurrent family a request without a hint (its prompt-boundary
+        # state no longer exists when it retires) or without a free row
         self.stat_prefix_capture_skips = 0
         # entries pre-seeded from another replica's spill at warm boot
         self.stat_prefix_preseeded = 0
@@ -1295,6 +1338,7 @@ class DecodeScheduler:
         refcounts: live sharers = heat), then index hits. ``top_n`` caps
         the entries (0 = all). Returns None when the prefix cache is
         off."""
+        require_served(self.family, "prefix_export")
         if not self.prefix_enabled:
             return None
         alloc = self.pool.alloc
@@ -1338,6 +1382,7 @@ class DecodeScheduler:
         sharding so the warmed program signatures stay exactly the live
         ones. Entries that don't fit this deployment's geometry are
         skipped; pool pressure stops the walk. Returns entries seeded."""
+        require_served(self.family, "prefix_export")
         if not self.prefix_enabled or not payload:
             return 0
         if (
@@ -1777,6 +1822,7 @@ class DecodeScheduler:
         rendezvous home answers a sibling pull with. A host/store hit
         reuses the demoted bytes directly; a device hit gathers that one
         entry's page columns. None when no tier covers the prompt."""
+        require_served(self.family, "prefix_export")
         if not self.prefix_enabled:
             return None
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -1878,26 +1924,42 @@ class DecodeScheduler:
             + self.pool.alloc.prefix_pages,
         }
 
-    def _maybe_capture(self, seq: _Seq, slot: int, length: int) -> None:
+    def _maybe_capture(self, seq: _Seq, slot: int, length: int, state_row: int = -1) -> None:
         """Pin ``slot``'s leading prompt pages as a prefix entry when the
         index doesn't already cover prompt[:length] — a refcount bump, NO
         device work (the capture-copy dispatch of the flat layout is
         gone). Called at prefill completion for hinted captures
         (meta.tags.cache_prefix — the prefix K/V exists from that moment)
-        and at retirement for the automatic full-prompt policy."""
-        length = capture_prefix_len(length, self.prefix_ctx, self.seq_len)
-        if length < 1:
+        and at retirement for the automatic full-prompt policy. A
+        recurrent family (``state_row`` >= 0: the snapshot row the chunk
+        dispatch that ended at ``length`` just wrote, ``_snapshot_row``)
+        also binds that row to the pin, so whatever drops the pin frees it."""
+        alloc = self.pool.alloc
+        if state_row < 0:
+            length = capture_prefix_len(length, self.prefix_ctx, self.seq_len)
+            if length < 1:
+                return
+            _, depth = self._prefix_index.match(seq.prompt, touch=False)
+            if depth >= length:
+                return  # already covered verbatim (or by a longer entry)
+        elif self._snapshot_held(seq, length):
+            # another row of the same dispatch captured the same span first
+            alloc.give_state_row(state_row)
             return
-        _, depth = self._prefix_index.match(seq.prompt, touch=False)
-        if depth >= length:
-            return  # already covered verbatim (or by a longer entry)
-        pin = self.pool.alloc.capture(slot, length)
+        pin = alloc.capture(slot, length)
         if pin is None:
             # the span's pages aren't materialized (shouldn't happen for
             # a completed prefill) — skip rather than stall the loop
             self.stat_prefix_capture_skips += 1
+            if state_row >= 0:
+                alloc.give_state_row(state_row)
             return
-        _, evicted = self._prefix_index.insert(seq.prompt[:length], pin.pages, pin.pin_id)
+        pin.state_row = state_row
+        if state_row >= 0:
+            self._rb_state_captures += 1
+        _, evicted = self._prefix_index.insert(
+            seq.prompt[:length], pin.pages, pin.pin_id, state_row
+        )
         if evicted is not None:
             # index-cap LRU eviction: demote the displaced entry to the
             # host tier while its pages are intact, then release the pin
@@ -1906,6 +1968,55 @@ class DecodeScheduler:
             self.pool.alloc.release(evicted.pin_id)
             self._metrics.decode_prefix_evicted(self._deployment)
         self.stat_prefix_captures += 1
+
+    def _snapshot_row(self, seq: _Seq, end: int) -> int:
+        """A recurrent family, while a chunk round is planned: the snapshot
+        row the dispatch must also write for ``seq``, whose chunk ends at
+        prompt position ``end``; -1 where it captures nothing there (``end``
+        is not its hint's boundary, or an entry of that length holds the
+        span already). With no free row the index's LRU entry goes first,
+        as at the index cap; its row may be written in the same dispatch a
+        warm admission still reads it in: the program reads before it
+        writes. Rows are only ever written by chunk dispatches, and a
+        decided admission's first chunk rides the very next one."""
+        if end != self._hint_boundary(seq) or self._snapshot_held(seq, end):
+            return -1
+        alloc = self.pool.alloc
+        row = alloc.take_state_row()
+        if row < 0:
+            lru = self._prefix_index.evict_lru()
+            if lru is not None:
+                alloc.release(lru.pin_id)
+                self._metrics.decode_prefix_evicted(self._deployment)
+                row = alloc.take_state_row()
+        if row < 0:
+            self.stat_prefix_capture_skips += 1
+        return row
+
+    def _snapshot_held(self, seq: _Seq, length: int) -> bool:
+        """Whether an entry of exactly ``length`` tokens already holds the
+        snapshot of ``seq``'s first ``length`` prompt tokens."""
+        return self._prefix_index.match(seq.prompt[:length], touch=False, whole=True)[1] == length
+
+    def _hint_boundary(self, seq: _Seq) -> int:
+        """A recurrent family: the prompt position a hinted request's state
+        is snapshotted at (its ``cache_prefix``, inside what a later request
+        can reuse: at least one suffix token stays); 0 = none."""
+        if not (self._stateful and self.prefix_enabled and seq.cache_prefix > 0):
+            return 0
+        return usable_prefix_len(
+            capture_prefix_len(seq.cache_prefix, self.prefix_ctx, self.seq_len), self.seq_len
+        )
+
+    def _next_chunk(self, seq: _Seq, pos: int) -> int:
+        """The prompt tokens ``seq``'s next chunk takes from position
+        ``pos``: the rest of the prompt, at most its per-round cap, and in a
+        recurrent family no further than its hint's boundary, so that one
+        chunk ENDS there and the state at the boundary can be kept."""
+        rem = self.seq_len - pos
+        c = min(rem, seq.chunk_cap or rem)
+        boundary = self._hint_boundary(seq)
+        return min(c, boundary - pos) if boundary > pos else c
 
     def _retire(self, slot: int) -> None:
         seq = self._slots[slot]
@@ -1942,7 +2053,12 @@ class DecodeScheduler:
                 # prompt K/V and must not be captured. Capture pins pages
                 # BEFORE retire returns them to the pool.
                 if not seq.prefilling and seq.cache_prefix == 0:
-                    self._maybe_capture(seq, slot, self.seq_len)
+                    if self._stateful:
+                        # the state at the prompt's end was advanced by every
+                        # token since: nothing left to capture
+                        self.stat_prefix_capture_skips += 1
+                    else:
+                        self._maybe_capture(seq, slot, self.seq_len)
             self.pool.alloc.retire(slot)
             self._kv_gauges()
             if seq.gen_spans:
@@ -2007,6 +2123,9 @@ class DecodeScheduler:
         # rows the round's chunk dispatches computed, and the prefilling
         # slots among them
         self._rb_chunk_rows = self._rb_chunk_rows_live = 0
+        # a recurrent family: admissions that began from a snapshot row,
+        # snapshots bound to a new entry
+        self._rb_state_restores = self._rb_state_captures = 0
         # a counting family's per-dispatch counts, summed over the round
         # (nothing to build each round for a family that counts nothing)
         if self._frame_counters:
@@ -2120,6 +2239,8 @@ class DecodeScheduler:
                         self._rb_prefill, self._rb_first_tokens,
                         *self._rb_attn_pages,
                         self._rb_chunk_rows, self._rb_chunk_rows_live,
+                        state_restores=self._rb_state_restores,
+                        state_captures=self._rb_state_captures,
                         **(
                             dict(zip(self._frame_counters, self._rb_counts.tolist()))
                             if self._frame_counters
@@ -2178,7 +2299,9 @@ class DecodeScheduler:
         entry, reuse = None, 0
         if self.prefix_enabled:
             with self._phase(P_PREFIX_MATCH):
-                entry, depth = self._prefix_index.match(seq.prompt)
+                # a recurrent family reuses an entry's whole length or
+                # nothing (PrefixIndex: the depth rule per cache kind)
+                entry, depth = self._prefix_index.match(seq.prompt, whole=self._stateful)
                 # device-pool miss (or shallow hit): consult the tiers
                 # below — a host/store entry deeper than the device match
                 # promotes into pinned free pages and the re-match rides
@@ -2198,8 +2321,8 @@ class DecodeScheduler:
             # replica router normalizes the SAME way, so a prompt it
             # judged warm is one admission judges warm too.
             reuse = usable_prefix_len(depth, self.seq_len)
-            if reuse <= 0:
-                entry = None
+            if reuse <= 0 or (self._stateful and reuse < depth):
+                entry, reuse = None, 0
         # a cache_prefix hint pins pages at prefill completion; if the
         # hinted span's last page extends past seq_len, this slot's own
         # GENERATION writes will copy-on-write it — reserve for exactly
@@ -2246,6 +2369,10 @@ class DecodeScheduler:
                 self._metrics.decode_prefix(self._deployment, False, 0)
         seq.prefill_pos = reuse
         seq.prefix_len = reuse
+        if self._stateful:
+            # the first chunk starts from the entry's snapshot, or from zeros
+            seq.state_src = entry.state_row if entry is not None else self.pool.zero_row
+            self._rb_state_restores += entry is not None
         for c in seq.trace_ctxs:
             ms = c.buf.begin(
                 "decode.prefix_match" if self.prefix_enabled else "decode.admit",
@@ -2262,6 +2389,8 @@ class DecodeScheduler:
                     "free_pages": self.pool.alloc.free_pages,
                 },
             )
+            if self._stateful:
+                ms.add_event("state_row", {"read": seq.state_src, "restored": entry is not None})
             ms.end()
         self.stat_peak_active = max(self.stat_peak_active, self.active)
 
@@ -2456,15 +2585,13 @@ class DecodeScheduler:
         for i, seq in enumerate(self._slots):
             if seq is None or not seq.prefilling or seq.future.cancelled():
                 continue
-            rem = self.seq_len - seq.prefill_pos
-            c = min(rem, seq.chunk_cap or rem)
+            c = self._next_chunk(seq, seq.prefill_pos)
             if c > 0:
                 rows.append((i, seq.uid, seq.prefill_pos, c, seq))
         for p in self._pending_admits:
             if p.seq.future.cancelled():
                 continue
-            rem = self.seq_len - p.reuse
-            c = min(rem, p.seq.chunk_cap or rem)
+            c = self._next_chunk(p.seq, p.reuse)
             if c > 0:
                 rows.append((p.slot, p.seq.uid, p.reuse, c, p.seq))
         if not rows:
@@ -2557,7 +2684,9 @@ class DecodeScheduler:
                 # and it keeps warm-hit behavior identical to the serial
                 # loop instead of silently paying a full prefill.
                 with self._phase(P_PREFIX_MATCH):
-                    _, depth = self._prefix_index.match(p.seq.prompt, touch=False)
+                    _, depth = self._prefix_index.match(
+                        p.seq.prompt, touch=False, whole=self._stateful
+                    )
                 if usable_prefix_len(depth, self.seq_len) > reuse:
                     self.pool.alloc.retire(p.slot)  # undo the shallow mapping
                     entry, reuse, ok = self._admit_decide(p.seq, p.slot)
@@ -2595,8 +2724,7 @@ class DecodeScheduler:
                 if seq.future.cancelled():
                     self._retire(i)
                     continue
-                rem = self.seq_len - seq.prefill_pos
-                c = min(rem, seq.chunk_cap or rem)
+                c = self._next_chunk(seq, seq.prefill_pos)
                 if c > 0:
                     rows.append((i, seq.uid, seq.prefill_pos, c, seq))
             if not rows:
@@ -2618,13 +2746,24 @@ class DecodeScheduler:
                 # never decided under an in-flight dispatch
                 copies += self.pool.alloc.prepare_write(i, pp, c)
         await self._run_copies(copies)
+        state_rows, snaps = None, {}
         with self._phase(P_ALLOC):
             bt = self.pool.block_tables(slots)
+            if self._stateful:
+                # which state row each batch row reads, writes and snapshots:
+                # serial like page residency (taking a row may evict an entry)
+                for i, _uid, pp, c, seq in rows:
+                    row = self._snapshot_row(seq, pp + c)
+                    if row >= 0:
+                        snaps[i] = row
+                state_rows = self.pool.state_rows(
+                    slots, {r[0]: r[4].state_src for r in rows if r[4].state_src >= 0}, snaps
+                )
         tick = self._next_tick()
         t0 = telemetry.now_ns()
         toks, counted = await self._timed_call(
             F_CHUNK,
-            lambda: self.programs.chunk(bt, ids, pos, counts, temps, topks, tick),
+            lambda: self.programs.chunk(bt, ids, pos, counts, temps, topks, tick, state_rows),
         )
         if counted is not None:
             self._rb_counts += counted
@@ -2640,6 +2779,12 @@ class DecodeScheduler:
                 if seq is None:
                     continue
                 seq.prefill_pos += int(counts[r])
+                seq.state_src = -1  # from here on the slot's own row
+                if i in snaps:
+                    # the dispatch kept the state at the hint's boundary: pin
+                    # the pages up to it and bind the row, so that the very
+                    # next admission can start from both
+                    self._maybe_capture(seq, i, seq.prefill_pos, snaps[i])
                 for c in seq.trace_ctxs:
                     cs = c.buf.begin(
                         "decode.prefill_chunk",
@@ -2667,7 +2812,7 @@ class DecodeScheduler:
             for seq, i, first in finishing:
                 seq.prefilling = False
                 seq.pos = self.seq_len
-                if self.prefix_enabled and seq.cache_prefix > 0:
+                if self.prefix_enabled and seq.cache_prefix > 0 and not self._stateful:
                     # hinted capture at prefill completion — the hinted
                     # span's pages are pinned from this moment, so the very
                     # next admission can already map them

@@ -34,6 +34,19 @@ pin are reclaimed LRU-first under pool pressure.
 Conventions: physical page 0 is a reserved junk sink — free slots' block
 tables are all-zero and masked-off writes land there, so no static-shape
 dispatch can corrupt a live page. Page 0 is never allocated.
+
+A second kind of cache, for a family whose layers carry a recurrent state
+(models/hybrid_decoder.py): STATE ROWS beside the pages, in the same
+manager (``PagedKVPool.recurrent``: allocated, placed and reset with the
+pool, donated to the same programs). Row r < n_slots is slot r's own; the
+``n_state_rows`` rows after them hold cached prefixes' snapshots, handed out
+by the allocator and bound to the prefix pin that owns the snapshot, so
+whatever drops the pin (index cap, pin reclaim) gives the row back;
+``zero_row`` is never written (a cold admission's first chunk reads it);
+``drop_row`` is one past the last row: a write addressed there is dropped
+(a padding row's, a dispatch without a snapshot). Rows are addressed by
+index inside the programs: nothing here copies one, and ``paged_copy`` and
+the copy ladder address pages only.
 """
 
 from __future__ import annotations
@@ -54,12 +67,13 @@ class PoolPin:
     The radix index entry that owns it stores the pin_id; eviction drops
     the refs and frees whatever nothing else references."""
 
-    __slots__ = ("pin_id", "pages", "last_use")
+    __slots__ = ("pin_id", "pages", "last_use", "state_row")
 
     def __init__(self, pin_id: int, pages: list[int]):
         self.pin_id = pin_id
         self.pages = list(pages)
         self.last_use = 0
+        self.state_row = -1  # the snapshot row bound to this prefix (a recurrent family), else -1
 
 
 class PageAllocator:
@@ -74,7 +88,9 @@ class PageAllocator:
     ``try_admit`` refuses any admission that would break it; ``_alloc``
     only spends reservation the slot holds."""
 
-    def __init__(self, n_pages: int, page_size: int, n_slots: int, pages_per_slot: int):
+    def __init__(
+        self, n_pages: int, page_size: int, n_slots: int, pages_per_slot: int, n_state_rows: int = 0
+    ):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         floor = max(pages_per_slot + 2, n_slots + 1)
@@ -100,6 +116,11 @@ class PageAllocator:
         self._pins: dict[int, PoolPin] = {}
         self._next_pin = 0
         self._clock = 0
+        # snapshot rows of a recurrent family's state cache (module
+        # docstring): the rows after the slots' own, free until taken for a
+        # capture and bound to its pin
+        self.n_state_rows = int(n_state_rows)
+        self._state_free: list[int] = list(range(n_slots + self.n_state_rows - 1, n_slots - 1, -1))
         # called ONCE per reclaim wave with the list of reclaimed pin ids
         # (batched so the owner — the prefix index — hears of a wave
         # once, not once per pin, on the hot decode path)
@@ -146,6 +167,7 @@ class PageAllocator:
             "shared_total": self.stat_pages_shared,
             "cow_total": self.stat_cow_copies,
             "pin_reclaims": self.stat_pin_reclaims,
+            "state_rows_free": len(self._state_free),
         }
 
     def pages_for(self, tokens: int) -> int:
@@ -193,6 +215,10 @@ class PageAllocator:
                 raise AssertionError(f"page {p} leaked (unreferenced, not free)")
         if self.free_pages + self._reclaimable() < self.reserved_total():
             raise AssertionError("reservation invariant broken")
+        bound = [pin.state_row for pin in self._pins.values() if pin.state_row >= 0]
+        rows = sorted(bound + self._state_free)
+        if rows != list(range(self.n_slots, self.n_slots + self.n_state_rows)):
+            raise AssertionError("snapshot rows diverged: each is free or bound to one pin")
 
     # ----------------------------------------------------------- admission
     def try_admit(self, slot: int, shared_pages, reuse: int, extra_reserve: int = 0) -> bool:
@@ -352,6 +378,16 @@ class PageAllocator:
         self._pins[pin.pin_id] = pin
         return pin
 
+    # -------------------------------------------------------- snapshot rows
+    def take_state_row(self) -> int:
+        """A free snapshot row for a capture about to be dispatched, -1
+        where none is free (the caller evicts an entry and asks again)."""
+        return self._state_free.pop() if self._state_free else -1
+
+    def give_state_row(self, row: int) -> None:
+        """Hand back a taken row that was bound to no pin after all."""
+        self._state_free.append(int(row))
+
     def touch(self, pin_id: int) -> None:
         pin = self._pins.get(pin_id)
         if pin is not None:
@@ -366,6 +402,8 @@ class PageAllocator:
 
     def _drop_pin(self, pin: PoolPin, reclaim: bool) -> None:
         del self._pins[pin.pin_id]
+        if pin.state_row >= 0:
+            self._state_free.append(pin.state_row)
         freed = 0
         for p in pin.pages:
             self.pin_count[p] -= 1
@@ -401,6 +439,8 @@ class PagedKVPool:
         place=None,
         shardings_fn=None,
         kv_init=paged_kv_init,
+        state_init=None,
+        n_state_rows: int = 0,
     ):
         import jax.numpy as jnp
 
@@ -419,12 +459,18 @@ class PagedKVPool:
         self._dtype = dtype if dtype is not None else jnp.float32
         self._place = place or (lambda arrs: tuple(arrs))
         self.n_slots = int(n_slots)
+        # a recurrent family's zeroed state rows (module docstring); none otherwise
+        self._state_init = state_init
+        self.n_state_rows = int(n_state_rows) if state_init is not None else 0
+        self.zero_row = self.n_slots + self.n_state_rows
+        self.drop_row = self.zero_row + 1
         self.alloc = PageAllocator(
-            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot
+            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot, self.n_state_rows
         )
         self.state = self._place(
             kv_init(params, self.n_pages, self.page_size, self._dtype, kv_dtype)
         )
+        self.recurrent = self._recurrent_zeros()
         # tensor-parallel decode (parallel/tp.py): the scheduler hands a
         # per-buffer sharding resolver so the pool state is committed to
         # the decode mesh (payloads head-sharded, int8 scale planes
@@ -450,9 +496,30 @@ class PagedKVPool:
         self.copy_buckets = tuple(buckets) + (self.n_slots,)
         self.stat_copy_dispatches = 0
 
+    def _recurrent_zeros(self) -> tuple:
+        if self._state_init is None:
+            return ()
+        return self._place(self._state_init(self._params, self.drop_row))
+
     @property
     def virtual_ctx(self) -> int:
         return self.pages_per_slot * self.page_size
+
+    def state_rows(self, slots: np.ndarray, read=None, snap=None) -> np.ndarray:
+        """The ``[3, rows]`` int32 row indices of one chunk dispatch over
+        ``slots`` (-1: a padding row): the row each batch row reads (``read``
+        {slot: row} where it is not the slot's own: ``zero_row`` for a cold
+        first chunk, an entry's snapshot row for a warm one), the row it
+        writes (its own) and the snapshot row it also writes (``snap`` {slot:
+        row}). A padding row reads ``zero_row`` and writes nothing."""
+        out = np.full((3, len(slots)), self.drop_row, np.int32)
+        out[0] = self.zero_row
+        for r, slot in enumerate(slots.tolist()):
+            if slot >= 0:
+                out[0, r] = (read or {}).get(slot, slot)
+                out[1, r] = slot
+                out[2, r] = (snap or {}).get(slot, self.drop_row)
+        return out
 
     def block_tables(self, slots: np.ndarray | None = None) -> np.ndarray:
         """Fresh host copy of the block tables for one dispatch (the jit
@@ -497,7 +564,7 @@ class PagedKVPool:
         drop every host mapping with it."""
         on_reclaimed = self.alloc.on_pins_reclaimed
         self.alloc = PageAllocator(
-            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot
+            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot, self.n_state_rows
         )
         self.alloc.on_pins_reclaimed = on_reclaimed
         self.state = self._place(
@@ -505,3 +572,4 @@ class PagedKVPool:
                 self._params, self.n_pages, self.page_size, self._dtype, self.kv_dtype
             )
         )
+        self.recurrent = self._recurrent_zeros()

@@ -378,7 +378,12 @@ class FlightFrame:
     round's chunk and step dispatches (models/moe_decoder.py, real rows
     only): token rows routed, distinct experts with a row and the fullest
     expert's rows, the last two summed over layers. 0 for a family without
-    experts."""
+    experts; ``ssm_rows`` the batch rows whose recurrent state the round's
+    chunk and step dispatches advanced, as a recurrent family's programs
+    counted them (models/hybrid_decoder.py), ``state_restores`` the round's
+    admissions that began from a cached prefix's snapshot row and
+    ``state_captures`` the snapshot rows the round bound to a new prefix
+    entry (serving/kv_pool.py); 0 for a family without a state cache."""
 
     __slots__ = (
         "seq", "t_ns", "mode", "active", "prefilling", "queued",
@@ -390,6 +395,7 @@ class FlightFrame:
         "attn_pages_read", "attn_pages_table",
         "chunk_rows", "chunk_rows_live",
         "moe_rows", "moe_experts_hit", "moe_load_max",
+        "ssm_rows", "state_restores", "state_captures",
     )
 
     def __init__(
@@ -402,6 +408,7 @@ class FlightFrame:
         attn_pages_read=0, attn_pages_table=0,
         chunk_rows=0, chunk_rows_live=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
+        ssm_rows=0, state_restores=0, state_captures=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -438,6 +445,9 @@ class FlightFrame:
         self.moe_rows = moe_rows
         self.moe_experts_hit = moe_experts_hit
         self.moe_load_max = moe_load_max
+        self.ssm_rows = ssm_rows
+        self.state_restores = state_restores
+        self.state_captures = state_captures
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -505,6 +515,8 @@ class FlightFrame:
             d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
         if self.moe_rows:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
+        if self.ssm_rows:
+            d["ssm"] = [self.ssm_rows, self.state_restores, self.state_captures]
         return d
 
 

@@ -302,7 +302,8 @@ def test_a_family_answers_what_it_serves(family, mechanism, message):
     with pytest.raises(FamilyNotServed) as e:
         require_served(MOE, mechanism)
     assert str(e.value) == message
-    assert decoder_family(MOE) is MOE and not MOE.serves
+    # what it keeps serving of the mechanisms PR 34 named for the third family to refuse
+    assert decoder_family(MOE) is MOE and MOE.serves == {"kv_int8", "host_tier", "prefix_export"}
     params = md.init_moe_decoder(MOE.cfg, seed=0, dtype=jnp.float32)
     kw = {"spec_tree": "2,1"} if mechanism == "speculation" else {"mesh_axes": {"model": 2}}
     with pytest.raises(FamilyNotServed) as e:

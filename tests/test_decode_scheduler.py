@@ -949,6 +949,27 @@ async def test_a_slow_round_names_what_held_it(caplog):
     await sched.close()
 
 
+@pytest.mark.parametrize("hint,cap,want", [
+    (0, 0, [24]), (0, 16, [16, 8]), (12, 16, [16, 8]),  # a family without a state cache ignores the hint
+])
+def test_chunk_plan_of_a_family_without_state_rows_is_the_cap_alone(hint, cap, want):
+    """``_next_chunk``: the rest of the prompt, at most the per-round cap; the
+    hint's boundary cuts a chunk only for a recurrent family
+    (tests/test_hybrid_decoder.py drives that side)."""
+    from seldon_core_tpu.serving.decode_scheduler import _Seq
+
+    params = init_decoder(seed=1, vocab=64, hidden=64, layers=1, ffn=64, max_len=64)
+    sched = DecodeScheduler(params, seq_len=24, max_new_tokens=4, n_slots=2, prefix_slots=2, prefill_chunk=cap)
+    assert not sched._stateful and sched.pool.recurrent == () and sched.pool.alloc.n_state_rows == 0
+    seq = _Seq(np.zeros(24, np.int32), 4, 0.0, 0, 0, None, None)
+    seq.chunk_cap, seq.cache_prefix = sched.prefill_chunk, hint
+    got, pos = [], 0
+    while pos < 24:
+        got.append(sched._next_chunk(seq, pos))
+        pos += got[-1]
+    assert got == want and sched._hint_boundary(seq) == 0
+
+
 @pytest.mark.slow
 async def test_staggered_arrival_soak():
     """Soak-adjacent: dozens of staggered arrivals with mixed budgets and

@@ -96,6 +96,28 @@ def test_prefix_index_lcp_match_insert_evict():
     assert d == 0
 
 
+@pytest.mark.parametrize("prompt,want", [
+    ([1, 2, 3, 4, 9, 9], ("long", 4)),  # holds all of both entries: the longer
+    ([1, 2, 9, 9], ("short", 2)),  # shares 2 of the long entry's 4: only the short one is whole
+    ([1, 9], (None, 0)),  # holds neither whole, though it shares a token with both
+    ([1, 2], ("short", 2)),
+])
+def test_prefix_index_whole_match_is_the_depth_rule_for_state_rows(prompt, want):
+    """``match(whole=True)``: a recurrent state is reusable at its entry's own
+    length only, so only an entry the prompt holds ALL of counts, the longest
+    such; the plain match still answers the common depth with any entry. An
+    entry carries the snapshot row it was inserted with."""
+    idx = PrefixIndex(4)
+    short, _ = idx.insert(np.array([1, 2], np.int32), [1], pin_id=0, state_row=7)
+    long_, _ = idx.insert(np.array([1, 2, 3, 4], np.int32), [1, 2], pin_id=1, state_row=8)
+    named = {"short": short, "long": long_, None: None}
+    e, d = idx.match(np.asarray(prompt, np.int32), touch=False, whole=True)
+    assert (e, d) == (named[want[0]], want[1])
+    assert (short.state_row, long_.state_row) == (7, 8) and idx.insert(np.array([5], np.int32), [3], 2)[0].state_row == -1
+    _, plain = idx.match(np.asarray(prompt, np.int32), touch=False)
+    assert plain >= d
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
 def test_prefix_index_matches_a_token_by_token_walk(seed):
     """The vectorised match against a plain walk over every entry: the

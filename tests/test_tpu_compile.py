@@ -331,3 +331,52 @@ def test_grouped_expert_layer_compiles_with_the_pallas_kernel_at_published_width
     )
     assert rows > moe.MASKED_MAX_ROWS  # the grouped form, not the masked one
     assert compiled.as_text().count("tpu_custom_call") >= 2  # gate_up and down
+
+
+@pytest.mark.parametrize("program", ["step", "chunk_2_64"])
+def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo, program):
+    """The third family's fused step (64 slots) and (2, 64) chunk at the
+    granite-4.0-h-micro cell's widths, 10 of its 40 layers (one period: 9
+    Mamba-2 layers + 1 attention layer): the donated pool AND the donated
+    state rows come back aliased, and no op of the program copies an array
+    the size of the state (PR 27's finding, for the second cache)."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = hd.HybridDecoderConfig(
+        vocab=100352, hidden=2048, layers=10, attn_layers=(5,), heads=32, kv_heads=8, head_dim=64, ffn=8192,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, attention_multiplier=0.015625,
+    )
+    fam = hd.hybrid_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: hd.init_hybrid_decoder(cfg, 0, jnp.bfloat16)))
+    n, rows_total = 64, 64 + 4 + 1
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 3400, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
+    assert pool[0].shape[0] == 1 and len(rec) == 18 and rec[0].shape == (rows_total, 64, 64, 128)
+    step, chunk = fam.fused_programs()
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "step":
+        args = (arr((n, 52), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+        fn = step
+    else:
+        r, c = 2, 64
+        args = (arr((r, 52), i32), arr((r, c), i32), arr((r,), i32), arr((r,), i32), arr((r,), f32),
+                arr((r,), i32), arr((), i32), arr((), i32), arr((3, r), i32))
+        fn = chunk
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, rec, *args).compile()
+    donated = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in (*pool, *rec))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.temp_size_in_bytes < donated // 4  # nothing the size of the state beside it
+    state = re.escape("f32[%d,64,64,128]" % rows_total)
+    copies = [ln for ln in compiled.as_text().splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
+    assert not copies, copies[:2]
+    assert re.search(r'op_name="jit\(_fused_%s\)/attn/ssm_scan/' % ("step" if program == "step" else "chunk"), compiled.as_text())
