@@ -454,22 +454,53 @@ def decode_step(
     return logits, jnp.stack(new_k), jnp.stack(new_v)
 
 
+def _kth_largest(logits: jax.Array, k: jax.Array) -> jax.Array:
+    """Each row's k-th largest logit, [..., 1] (``k`` [...] int32 in
+    1..vocab, data): bit for bit ``flip(sort(row))[k - 1]``, ties, ``-inf``
+    and NaN (largest, as ``lax.sort`` has it) included, without ordering the
+    row. A float32's bits, the magnitude flipped where the sign is set, are
+    an int32 whose order is the floats'; the answer is the largest ``t``
+    that ``k`` entries reach, found a bit at a time from the top: 32
+    compare-and-count passes over the row. Float32 or narrower (the
+    upcast is exact, and the answer is one of the row's entries)."""
+    x = logits.astype(jnp.float32)
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    image = jnp.where(jnp.isnan(x), jnp.int32(0x7FFFFFFF), bits ^ ((bits >> 31) & 0x7FFFFFFF))
+    k = k[..., None]
+
+    def narrow(i, t):
+        # bit 31 first: it takes t from the least int32 to 0, every later
+        # bit is clear in t so far; either way the candidate lies above t
+        cand = t ^ (jnp.int32(1) << (31 - i))
+        reach = jnp.sum(image >= cand, axis=-1, keepdims=True, dtype=jnp.int32)
+        return jnp.where(reach >= k, cand, t)
+
+    t = lax.fori_loop(0, 32, narrow, jnp.full(k.shape, -(2**31), jnp.int32))
+    return lax.bitcast_convert_type(t ^ ((t >> 31) & 0x7FFFFFFF), jnp.float32).astype(logits.dtype)
+
+
 def _transform_logits(logits: jax.Array, temperature, top_k) -> jax.Array:
     """The per-row sampling transform shared by ``sample_tokens`` and the
     speculative acceptance rule (both MUST agree, or the draft's proposal
     distribution q would differ from the one acceptance corrects against):
     top_k restriction (<= 0 = full vocabulary) then temperature scaling.
     ``temperature``/``top_k`` broadcast against logits' leading axes;
-    top_k is data, not shape — the cutoff is looked up in the sorted
-    logits, so one compiled program serves every per-request k."""
+    top_k is data, not shape — the cutoff is the row's k-th largest logit,
+    selected (``_kth_largest``), so one compiled program serves every
+    per-request k; a dispatch in which no row asks for top_k looks for no
+    cutoff (the predicate is computed on the device from ``top_k``)."""
     vocab = logits.shape[-1]
     temperature = jnp.broadcast_to(temperature, logits.shape[:-1])
     top_k = jnp.broadcast_to(top_k, logits.shape[:-1])
-    sorted_desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
-    k_idx = jnp.clip(top_k - 1, 0, vocab - 1)
-    thresh = jnp.take_along_axis(sorted_desc, k_idx[..., None], axis=-1)  # [..., 1]
-    restricted = jnp.where(logits < thresh, -jnp.inf, logits)
-    masked = jnp.where(top_k[..., None] > 0, restricted, logits)
+
+    def cutoffs():
+        kth = _kth_largest(logits, jnp.clip(top_k, 1, vocab))
+        return jnp.where(top_k[..., None] > 0, kth, -jnp.inf)  # nothing lies under -inf
+
+    thresh = lax.cond(
+        jnp.any(top_k > 0), cutoffs, lambda: jnp.full((*logits.shape[:-1], 1), -jnp.inf, logits.dtype)
+    )
+    masked = jnp.where(logits < thresh, -jnp.inf, logits)
     return masked / jnp.maximum(temperature, 1e-6)[..., None].astype(logits.dtype)
 
 
@@ -482,11 +513,20 @@ def sample_tokens(
     """Per-row sampling: greedy argmax where temperature <= 0 (the serving
     default — what the fused oracle computes), else temperature-scaled
     categorical restricted to the top_k logits (top_k <= 0 means the full
-    vocabulary)."""
+    vocabulary). The cost follows what the dispatch's rows ask for: all
+    rows greedy, the argmax and nothing else; a sampling row, the draw over
+    [rows, vocab]; a sampling row with top_k, its cutoff besides. Both
+    predicates are computed on the device; nothing is read back."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = _transform_logits(logits, temperature, top_k)
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature > 0, sampled, greedy)
+    sampling = temperature > 0
+
+    def draw():
+        # a greedy row's top_k buys nothing
+        scaled = _transform_logits(logits, temperature, jnp.where(sampling, top_k, 0))
+        sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+        return jnp.where(sampling, sampled, greedy)
+
+    return lax.cond(jnp.any(sampling), draw, lambda: greedy)
 
 
 # ----------------------------------------------------- speculative decoding

@@ -2123,6 +2123,9 @@ class DecodeScheduler:
         # rows the round's chunk dispatches computed, and the prefilling
         # slots among them
         self._rb_chunk_rows = self._rb_chunk_rows_live = 0
+        # rows of the round's dispatches that asked the sampler for a draw,
+        # and those among them that asked for top_k (_count_sampling)
+        self._rb_sample_rows = self._rb_sample_topk_rows = 0
         # a recurrent family: admissions that began from a snapshot row,
         # snapshots bound to a new entry
         self._rb_state_restores = self._rb_state_captures = 0
@@ -2239,6 +2242,7 @@ class DecodeScheduler:
                         self._rb_prefill, self._rb_first_tokens,
                         *self._rb_attn_pages,
                         self._rb_chunk_rows, self._rb_chunk_rows_live,
+                        self._rb_sample_rows, self._rb_sample_topk_rows,
                         state_restores=self._rb_state_restores,
                         state_captures=self._rb_state_captures,
                         **(
@@ -2632,6 +2636,16 @@ class DecodeScheduler:
             topks[r] = seq.top_k
         return slots, ids, pos, counts, temps, topks
 
+    def _count_sampling(self, temps: np.ndarray, topks: np.ndarray) -> None:
+        """Book what a dispatch's ``temps`` / ``topks`` ask of the programs'
+        sampler into the round's frame: the same two predicates
+        ``sample_tokens`` computes on the device to decide whether it draws
+        at all and whether it looks for a top_k cutoff."""
+        sampling = temps > 0
+        if sampling.any():
+            self._rb_sample_rows += int(np.count_nonzero(sampling))
+            self._rb_sample_topk_rows += int(np.count_nonzero(sampling & (topks > 0)))
+
     def _pipeline_take_chunk_plan(self, key: tuple):
         """Hand the overlap-built chunk plan to the chunk round iff the
         live state still matches its snapshot key — one-shot either way
@@ -2771,6 +2785,7 @@ class DecodeScheduler:
         self.stat_chunk_dispatches += 1
         self._rb_chunk_rows += len(slots)
         self._rb_chunk_rows_live += len(rows)
+        self._count_sampling(temps, topks)
         bucket = ids.shape[1]
         finishing: list[tuple[_Seq, int, int]] = []  # (seq, slot, its row's token)
         with self._phase(P_SCATTER):
@@ -3174,6 +3189,7 @@ class DecodeScheduler:
                         # most one round stale, hidden under the flight.
                         self._kv_gauges()
 
+                self._count_sampling(temps, topks)
                 if spec_round:
                     await self._spec_round(
                         bt, toks, pos, temps, topks, limits, wlimits, fmask, tick

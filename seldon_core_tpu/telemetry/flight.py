@@ -373,6 +373,11 @@ class FlightFrame:
     ``chunk_rows`` / ``chunk_rows_live`` the rows the round's prefill chunk
     dispatch computed (its ``chunk_buckets`` entry's) and the slots that
     prefilled in it, 0 / 0 in a round without one;
+    ``sample_rows`` / ``sample_topk_rows`` the rows of the round's chunk and
+    step dispatches that asked for a draw (temperature > 0) and, of those,
+    the rows that asked for ``top_k``, counted on the host from the vectors
+    the programs' sampler gates on (models/decoder.py ``sample_tokens``):
+    0 / 0 in a round whose dispatches computed the argmax and nothing else;
     ``moe_rows`` / ``moe_experts_hit`` /
     ``moe_load_max`` what a sparse-expert family's programs counted in the
     round's chunk and step dispatches (models/moe_decoder.py, real rows
@@ -394,6 +399,7 @@ class FlightFrame:
         "admit_wait_ns", "prefill_ns", "first_tokens",
         "attn_pages_read", "attn_pages_table",
         "chunk_rows", "chunk_rows_live",
+        "sample_rows", "sample_topk_rows",
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
     )
@@ -407,6 +413,7 @@ class FlightFrame:
         admit_wait_ns=0, prefill_ns=0, first_tokens=0,
         attn_pages_read=0, attn_pages_table=0,
         chunk_rows=0, chunk_rows_live=0,
+        sample_rows=0, sample_topk_rows=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
     ):
@@ -442,6 +449,8 @@ class FlightFrame:
         self.attn_pages_table = attn_pages_table
         self.chunk_rows = chunk_rows
         self.chunk_rows_live = chunk_rows_live
+        self.sample_rows = sample_rows
+        self.sample_topk_rows = sample_topk_rows
         self.moe_rows = moe_rows
         self.moe_experts_hit = moe_experts_hit
         self.moe_load_max = moe_load_max
@@ -513,6 +522,8 @@ class FlightFrame:
             d["attn_pages"] = [self.attn_pages_read, self.attn_pages_table]
         if self.chunk_rows:
             d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
+        if self.sample_rows:
+            d["sample_rows"] = [self.sample_rows, self.sample_topk_rows]
         if self.moe_rows:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
         if self.ssm_rows:

@@ -195,6 +195,47 @@ def test_scheduler_records_frames_zero_recompiles():
     assert agg["blocked_rounds"].get("slots", 0) > 0
 
 
+@pytest.mark.parametrize("kw, want", [
+    ({}, None),
+    ({"sample_rows": 3}, [3, 0]),
+    ({"sample_rows": 5, "sample_topk_rows": 2}, [5, 2]),
+], ids=["greedy_round", "draws", "draws_with_top_k"])
+def test_frame_sample_rows_round_trip(kw, want):
+    """The sampler's two counts default to 0 / 0, read back as given, and
+    show in the dump only for a round that drew."""
+    f = _frame(0, **kw)
+    assert (f.sample_rows, f.sample_topk_rows) == (kw.get("sample_rows", 0), kw.get("sample_topk_rows", 0))
+    assert f.to_dict().get("sample_rows") == want
+    rec = FlightRecorder(n_slots=4, name="t", capacity=4, enabled=True)
+    rec.record(f)
+    assert rec.snapshot()[0].to_dict().get("sample_rows") == want
+
+
+@pytest.mark.parametrize("submit_kw, rows, topk_rows", [
+    ({}, False, False),
+    ({"temperature": 0.0, "top_k": 5}, False, False),  # a greedy row's top_k asks for nothing
+    ({"temperature": 0.8}, True, False),
+    ({"temperature": 0.8, "top_k": 5}, True, True),
+], ids=["greedy", "greedy_with_top_k", "temperature", "temperature_and_top_k"])
+def test_scheduler_frames_count_the_rows_that_sample(submit_kw, rows, topk_rows):
+    """What the frames say of a round's dispatches is what their ``temps`` /
+    ``topks`` asked: a generating or prefilling slot that samples is a row,
+    in the chunk dispatch and in the step alike."""
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4)
+    s.warmup()
+    _run_requests(s, n=6, **submit_kw)
+    frames = s.flight.snapshot()
+    assert s.recompiles_since_warmup() == 0
+    assert any(f.sample_rows for f in frames) == rows
+    assert any(f.sample_topk_rows for f in frames) == topk_rows
+    for f in frames:
+        # at most one row a slot in the chunk dispatch and one in the step
+        assert 0 <= f.sample_topk_rows <= f.sample_rows <= 2 * s.n_slots
+        if rows and f.busy_ns[flight_mod.F_STEP]:
+            assert f.sample_rows > 0  # every generating slot of the step samples
+            assert f.sample_topk_rows == (f.sample_rows if topk_rows else 0)
+
+
 def test_commit_point_consolidates_occupancy():
     """Satellite: stat_occupancy_sum and the flight frames are written at
     ONE commit point — summing the frames' step-round occupancy reproduces

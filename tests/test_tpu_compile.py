@@ -380,3 +380,109 @@ def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo,
     copies = [ln for ln in compiled.as_text().splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
     assert not copies, copies[:2]
     assert re.search(r'op_name="jit\(_fused_%s\)/attn/ssm_scan/' % ("step" if program == "step" else "chunk"), compiled.as_text())
+
+
+def _ungated_lines(text: str) -> list[str]:
+    """The compiled module's instructions that run whatever a ``conditional``
+    decides: the entry computation's and those of every computation it
+    reaches other than as a ``conditional``'s branch (fusions, loop bodies,
+    reducers)."""
+    comps: dict[str, list[str]] = {}
+    entry = name = None
+    for ln in text.splitlines():
+        m = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif ln.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(ln)
+    seen, todo = set(), [entry]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            if " conditional(" in ln:
+                continue
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", ln)
+    return [ln for c in seen for ln in comps[c]]
+
+
+def _family_step(family: str, one):
+    """(step program, its arguments as shapes, donate_argnums, the donated
+    arguments) of one decoder family at its benchmark cell's rows, widths
+    and vocabulary, and a few of its layers."""
+    i32, f32 = jnp.int32, jnp.float32
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def small(n, m):  # block tables, tokens, positions, temperatures, top-k, seed, tick
+        return (arr((n, m), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32),
+                arr((), i32), arr((), i32))
+
+    if family == "gpt2":
+        from seldon_core_tpu.models.decoder import gpt2_family, init_decoder
+
+        params = jax.eval_shape(lambda: init_decoder(0, vocab=50257, hidden=1280, layers=2, ffn=5120, max_len=1024))
+        geo = {"n_slots": 16, "n_pages": 720, "page_size": 16, "pages_per_slot": 44}
+        p, pool, rest = _step_args(params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one)
+        return gpt2_family.fused_programs("mosaic")[0], (p, pool, *rest), (1,), pool
+    if family == "moe":
+        from seldon_core_tpu.models import moe_decoder as md
+
+        cfg = md.MoEDecoderConfig(vocab=98304, hidden=2304, layers=4, heads=32, kv_heads=4, head_dim=128, ffn=896,
+                                  experts=64, experts_per_tok=8, window=1024, yarn_factor=16.0, yarn_original=8192)
+        fam = md.moe_family(cfg)
+        params = on_chip(jax.eval_shape(lambda: md.init_moe_decoder(cfg, 0, jnp.bfloat16)))
+        pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 3520, 16, jnp.bfloat16)))
+        args = (params, pool, *small(16, 204), arr((16,), jnp.bool_))
+        return fam.fused_programs()[0], args, (1,), pool
+    from seldon_core_tpu.models import hybrid_decoder as hd
+
+    cfg = hd.HybridDecoderConfig(
+        vocab=100352, hidden=2048, layers=3, attn_layers=(1,), heads=32, kv_heads=8, head_dim=64, ffn=8192,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, attention_multiplier=0.015625,
+    )
+    fam = hd.hybrid_family(cfg)
+    params = on_chip(jax.eval_shape(lambda: hd.init_hybrid_decoder(cfg, 0, jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 3400, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, 69)))
+    args = (params, pool, rec, *small(64, 52), arr((64,), jnp.bool_))
+    return fam.fused_programs()[0], args, (1, 2), (*pool, *rec)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
+def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, family):
+    """Each family's fused step at its cell's rows and vocabulary: what runs
+    whatever the dispatch's ``temps`` say holds the argmax and the two
+    predicates; the ``[rows, vocab]`` draw and the top_k cutoff's loop are
+    in a ``conditional``'s branches, the compiler kept the ``conditional``,
+    nothing sorts, and the gate costs no donation: the pool (and the state
+    rows) still alias whole and the arguments are the arrays handed in."""
+    step, args, donate, donated = _family_step(family, SingleDeviceSharding(topo.devices[0]))
+    compiled = jax.jit(step, donate_argnums=donate).lower(*args).compile()
+    text = compiled.as_text()
+    sampler = [ln for ln in text.splitlines() if "/sample/" in ln]
+    drawn = [ln for ln in sampler if "_gumbel" in ln]
+    looped = [ln for ln in sampler if "/cond/branch_1_fun/cond/branch_1_fun/while" in ln]
+    assert drawn and looped  # the pattern reads the text
+    assert all("/sample/cond/branch_1_fun/" in ln for ln in drawn + looped)
+    assert not [ln for ln in sampler if re.search(r"\ssort\(", ln)]  # the router's top-k may sort 64 experts
+    ungated = _ungated_lines(text)
+    assert any(" conditional(" in ln and '/sample/cond"' in ln for ln in ungated)  # the outer gate, in the open
+    assert any("/sample/" in ln and "reduce" in ln for ln in ungated)  # the argmax
+    behind = [ln for ln in ungated if "/sample/cond/" in ln and " conditional(" not in ln]
+    assert not behind, behind[:3]
+    mem = compiled.memory_analysis()
+    nbytes = lambda tree: sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in jax.tree.leaves(tree))  # noqa: E731
+    assert mem.alias_size_in_bytes >= nbytes(donated)
+    # the arguments' bytes and their tile padding (the (n,) vectors round up), nothing else
+    assert nbytes(args) <= mem.argument_size_in_bytes < nbytes(args) * 1.001 + 2**16
