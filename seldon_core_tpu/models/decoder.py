@@ -694,8 +694,16 @@ def kv_pool_zeros(
 ) -> tuple:
     """The pool of ANY family from its ``decoder_dims``: a token row holds
     one token's K (or V) for all ``kv_heads``, in each of the ``kv_layers``
-    layers that attend (a hybrid family's recurrent layers hold no pages)."""
+    layers that attend (a hybrid family's recurrent layers hold no pages).
+    ``kv_planes`` 1 (a latent-attention family, models/mla_decoder.py) is the
+    LATENT page kind: ONE plane whose token row is the token's compressed
+    key/value of ``kv_heads * head_dim`` = 1 x (latent + rotated shared key),
+    no V plane, no per-head rows; a float plane only."""
     shape = (d["kv_layers"], n_pages, page_size, d["kv_heads"] * d["head_dim"])
+    if d.get("kv_planes", 2) == 1:
+        if kv_dtype:
+            raise ValueError(f"kv_dtype {kv_dtype!r} on a one-plane (latent) pool: a float plane only")
+        return (jnp.zeros(shape, dtype),)
     if kv_dtype == "int8":
         sshape = (d["kv_layers"], n_pages, page_size)
         # scale 1 / zp 0: dequantized junk pages read back as exact zeros,
@@ -732,6 +740,32 @@ def _quant_rows(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     return q, scale, zp
 
 
+def _write_index(ps: int, m: int, bt, positions, counts):
+    """(physical page, row in it), each [n * m], of a dispatch's new token
+    rows: slot i's entry j at positions[i] + j through its block-table row;
+    an invalid entry (beyond counts[i], past the virtual length) at junk
+    page 0."""
+    n_log = bt.shape[1]
+    gp = positions[:, None] + jnp.arange(m)[None, :]  # [n, m] global positions
+    lp = jnp.clip(gp // ps, 0, n_log - 1)
+    phys = jnp.take_along_axis(bt, lp, axis=1)  # [n, m] physical pages
+    ok = (gp >= 0) & (gp < n_log * ps)
+    if counts is not None:
+        ok = ok & (jnp.arange(m)[None, :] < counts[:, None])
+    phys = jnp.where(ok, phys, 0)
+    return phys.reshape(-1), (gp % ps).reshape(-1)
+
+
+@jax.named_scope(SCOPE_KV_WRITE)
+def _paged_write_latent(pool: tuple, li: int, rows, bt, positions, counts):
+    """``_paged_write`` for the one-plane latent pool: ONE in-place scatter
+    of the dispatch's new rows [n, m, w] into layer ``li`` of the plane."""
+    (plane,) = pool
+    n, m, w = rows.shape
+    pf, of = _write_index(plane.shape[2], m, bt, positions, counts)
+    return (plane.at[li, pf, of].set(rows.reshape(n * m, w).astype(plane.dtype)),)
+
+
 @jax.named_scope(SCOPE_KV_WRITE)
 def _paged_write(pool: tuple, li: int, k, v, bt, positions, counts):
     """Scatter the dispatch's new K/V rows (k, v: [n, m, h*hd], slot i's
@@ -742,17 +776,7 @@ def _paged_write(pool: tuple, li: int, k, v, bt, positions, counts):
     which is what lets free/prefilling slots ride static-shape dispatches
     without owning writable pages."""
     n, m, w = k.shape
-    ps = pool[0].shape[2]
-    n_log = bt.shape[1]
-    gp = positions[:, None] + jnp.arange(m)[None, :]  # [n, m] global positions
-    lp = jnp.clip(gp // ps, 0, n_log - 1)
-    phys = jnp.take_along_axis(bt, lp, axis=1)  # [n, m] physical pages
-    ok = (gp >= 0) & (gp < n_log * ps)
-    if counts is not None:
-        ok = ok & (jnp.arange(m)[None, :] < counts[:, None])
-    phys = jnp.where(ok, phys, 0)
-    pf = phys.reshape(-1)
-    of = (gp % ps).reshape(-1)
+    pf, of = _write_index(pool[0].shape[2], m, bt, positions, counts)
     kt = k.reshape(n * m, w)  # per-token rows
     vt = v.reshape(n * m, w)
     if len(pool) == 2:
@@ -948,6 +972,69 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
         return sample_tokens(last, temps, topks, key), pool
 
 
+def counted_programs(paged_forward):
+    """The step and chunk bodies of a family whose ``paged_forward(params,
+    pool, bt, tokens, positions, counts=, rows=, pick=)`` gives (logits,
+    hidden, pool, counted), under the GPT-2 family's names (a device trace
+    calls every family's programs ``jit__fused_step``), with two
+    differences: the step takes ``rows`` (which slots generate, so junk rows
+    stay out of the counts), and the counts ride the token readback,
+    appended to it: one [rows + len(frame_counters)] int32 array, one
+    transfer (the step's rows are the slots, the chunk's the slots that
+    prefill: ``_fused_chunk``). The chunk's head runs on each row's last
+    real position only."""
+
+    def sample_and_count(logits, counted, temps, topks, seed, tick):
+        with jax.named_scope(SCOPE_SAMPLE):
+            key = jax.random.fold_in(jax.random.key(seed), tick)
+            toks = sample_tokens(logits[:, 0, :], temps, topks, key)
+            return jnp.concatenate([toks, counted])
+
+    def step(params, pool, bt, tokens, positions, temps, topks, seed, tick, rows):
+        logits, _hidden, pool, counted = paged_forward(
+            params, pool, bt, tokens[:, None], positions, rows=rows
+        )
+        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+
+    def chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
+        idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
+        logits, _hidden, pool, counted = paged_forward(
+            params, pool, bt, ids, positions, counts=counts, pick=idx
+        )
+        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+
+    # jit names a program after its function: the trace's name for every family
+    step.__name__ = step.__qualname__ = "_fused_step"
+    chunk.__name__ = chunk.__qualname__ = "_fused_chunk"
+    return step, chunk
+
+
+def paged_greedy_generate(forward, make_pool, ids, max_new_tokens: int, page_size: int = 16):
+    """Greedy whole-batch decode ids[b, s] -> [b, s + max_new_tokens]: the
+    fused fallback apply of a deployment without ``tpu.decode_slots``, for a
+    family whose ``forward(pool, bt, tokens, positions, counts=, pick=)``
+    gives (logits, hidden, pool, counted). The SAME paged forward over a
+    private pool, ``make_pool(n_pages, page_size)`` of the junk page + every
+    sequence's own pages, with identity block tables: one prefill over the
+    whole prompt, then a scan of single-token steps."""
+    ids = ids.astype(jnp.int32)
+    b, s = ids.shape
+    pages = -(-(s + max_new_tokens) // page_size)
+    pool = make_pool(1 + b * pages, page_size)
+    bt = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
+    zero = jnp.zeros((b,), jnp.int32)
+    logits, _, pool, _ = forward(pool, bt, ids, zero, counts=zero + s, pick=zero + (s - 1))
+    first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+
+    def step(carry, _):
+        tok, pos, pool = carry
+        logits, _, pool, _ = forward(pool, bt, tok[:, None], pos)
+        return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), pos + 1, pool), tok
+
+    (last, _, _), toks = lax.scan(step, (first, zero + s, pool), None, length=max_new_tokens - 1)
+    return jnp.concatenate([ids, toks.T.reshape(b, -1), last[:, None]], axis=1)
+
+
 _MECHANISMS = {
     "speculation": "speculative decoding (draft, tree, feature head)",
     "decode_mesh": "tensor-parallel decode (parallel/tp.py)",
@@ -968,9 +1055,12 @@ def require_served(family, mechanism: str) -> None:
 
 class GPT2Decoder:
     """The GPT-2 family as the object the decode scheduler asks — what a
-    decoder family answers, in one list (models/moe_decoder.py
-    ``MoEDecoder`` is the second): ``name``; ``decoder_dims(params)`` (raises
-    ``FamilyNotServed`` for another family's parameters);
+    decoder family answers, in one list (the others: models/moe_decoder.py
+    ``MoEDecoder``, models/hybrid_decoder.py ``HybridDecoder``,
+    models/mla_decoder.py ``MLADecoder``): ``name``; ``decoder_dims(params)``
+    (raises ``FamilyNotServed`` for another family's parameters; with
+    ``kv_planes`` 1 the family's pages are the one-plane latent kind,
+    ``kv_pool_zeros``);
     ``paged_kv_init`` (the zeroed pool, of ``decoder_dims``' ``kv_layers``
     layers); ``frame_counters`` (FlightFrame fields its programs' readback
     carries after the tokens, none here);

@@ -57,12 +57,12 @@ from seldon_core_tpu.models.decoder import (
     SCOPE_LM_HEAD,
     SCOPE_MLP,
     SCOPE_QKV,
-    SCOPE_SAMPLE,
     FamilyNotServed,
     _paged_gather,
     _paged_write,
+    counted_programs,
     kv_pool_zeros,
-    sample_tokens,
+    paged_greedy_generate,
 )
 from seldon_core_tpu.ops.moe import SCOPE_MOE_COMBINE, moe_topk_ffn
 
@@ -345,30 +345,13 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
 
 
 def _generate(cfg, params, ids, max_new_tokens: int):
-    """Greedy whole-batch decode ids[b, s] -> [b, s + max_new_tokens]: the
-    fused fallback apply of a deployment without ``tpu.decode_slots``. The
-    SAME paged forward over a private pool with identity block tables: one
-    prefill over the whole prompt, then a scan of single-token steps."""
-    ids = ids.astype(jnp.int32)
-    b, s = ids.shape
-    ps = 16
-    pages = -(-(s + max_new_tokens) // ps)
+    """The fused fallback apply of a deployment without ``tpu.decode_slots``
+    (``decoder.paged_greedy_generate``) over a private two-plane pool."""
     dims = {"kv_layers": cfg.layers, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim}
-    pool = kv_pool_zeros(dims, 1 + b * pages, ps, params["tok_emb"].dtype)
-    bt = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
-    zero = jnp.zeros((b,), jnp.int32)
-    logits, _, pool, _ = _forward(
-        cfg, params, pool, bt, ids, zero, counts=zero + s, pick=zero + (s - 1)
+    return paged_greedy_generate(
+        functools.partial(_forward, cfg, params),
+        lambda n_pages, ps: kv_pool_zeros(dims, n_pages, ps, params["tok_emb"].dtype), ids, max_new_tokens,
     )
-    first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-
-    def step(carry, _):
-        tok, pos, pool = carry
-        logits, _, pool, _ = _forward(cfg, params, pool, bt, tok[:, None], pos)
-        return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), pos + 1, pool), tok
-
-    (last, _, _), toks = lax.scan(step, (first, zero + s, pool), None, length=max_new_tokens - 1)
-    return jnp.concatenate([ids, toks.T.reshape(b, -1), last[:, None]], axis=1)
 
 
 # ------------------------------------------------------------------ family
@@ -408,40 +391,10 @@ class MoEDecoder:
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
-        """This family's step and chunk bodies, under the GPT-2 family's
-        names — a device trace calls both families' programs
-        ``jit__fused_step`` — with two differences: the step takes ``rows``
-        (which slots generate, so junk rows stay out of the counts), and
-        the counts ride the token readback, appended to it: one
-        [rows + len(frame_counters)] int32 array, one transfer (the step's
-        rows are the slots, the chunk's the slots that prefill:
-        models/decoder.py ``_fused_chunk``). The chunk's head runs on each
-        row's last real position only. Cached: equal configurations share
-        compiled programs."""
-
-        def sample_and_count(logits, counted, temps, topks, seed, tick):
-            with jax.named_scope(SCOPE_SAMPLE):
-                key = jax.random.fold_in(jax.random.key(seed), tick)
-                toks = sample_tokens(logits[:, 0, :], temps, topks, key)
-                return jnp.concatenate([toks, counted])
-
-        def step(params, pool, bt, tokens, positions, temps, topks, seed, tick, rows):
-            logits, _hidden, pool, counted = self.paged_forward(
-                params, pool, bt, tokens[:, None], positions, rows=rows
-            )
-            return sample_and_count(logits, counted, temps, topks, seed, tick), pool
-
-        def chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
-            idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
-            logits, _hidden, pool, counted = self.paged_forward(
-                params, pool, bt, ids, positions, counts=counts, pick=idx
-            )
-            return sample_and_count(logits, counted, temps, topks, seed, tick), pool
-
-        # jit names a program after its function: the trace's name for both families
-        step.__name__ = step.__qualname__ = "_fused_step"
-        chunk.__name__ = chunk.__qualname__ = "_fused_chunk"
-        return step, chunk
+        """This family's step and chunk bodies (``decoder.counted_programs``:
+        the step takes ``rows``, the counts ride the token readback).
+        Cached: equal configurations share compiled programs."""
+        return counted_programs(self.paged_forward)
 
     def paged_decode_step(self, params, pool, bt, tokens, positions):
         logits, hidden, pool, _ = _forward(self.cfg, params, pool, bt, tokens[:, None], positions)

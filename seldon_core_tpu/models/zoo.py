@@ -571,6 +571,87 @@ def build_hybrid_decoder(
     )
 
 
+@register_model("mla_decoder")
+def build_mla_decoder(
+    seed: int = 0,
+    vocab: int = 512,
+    hidden: int = 64,
+    layers: int = 3,
+    heads: int = 4,
+    q_rank: int = 24,
+    kv_rank: int = 16,
+    nope_dim: int = 8,
+    rope_dim: int = 4,
+    v_dim: int = 8,
+    dense_layers: int = 1,
+    dense_ffn: int = 96,
+    ffn: int = 32,
+    experts: int = 16,
+    experts_held: int = 0,
+    first_expert: int = 0,
+    experts_per_tok: int = 4,
+    n_group: int = 4,
+    topk_group: int = 2,
+    routed_scale: float = 2.5,
+    rope_theta: float = 10000.0,
+    yarn_factor: float = 32.0,
+    yarn_original: int = 16,
+    yarn_beta_fast: float = 32.0,
+    yarn_beta_slow: float = 1.0,
+    mscale_all_dim: float = 1.0,
+    rms_eps: float = 1e-6,
+    max_len: int = 131072,
+    seq: int = 32,
+    max_new_tokens: int = 16,
+    param_dtype: str = "bfloat16",
+    **_,
+) -> ModelSpec:
+    """The generative tier's fourth decoder family (models/mla_decoder.py,
+    the DeepSeek-V3 block): multi-head latent attention whose cache row is
+    one ``kv_rank + rope_dim`` latent a token (the one-plane page kind),
+    ``dense_layers`` leading dense layers, then a shared expert plus
+    ``experts_per_tok`` of ``experts`` routed ones under the sigmoid,
+    group-limited gate. The parameters are a published config's keys; ``ffn``
+    is ONE expert's width, ``vocab`` the rows of the vocabulary held.
+    ``experts_held`` (0: all) from ``first_expert`` is one chip's share of an
+    expert-parallel deployment: the router keeps ``experts`` outputs and a
+    pick that lands on an absent expert adds nothing. It serves through
+    ``tpu.decode_slots``; without it the fused fallback decodes whole batches
+    greedily through the same paged forward. Speculation, tensor-parallel
+    decode, the int8 pool, the KV tiers and prefix export are not served
+    for it."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.mla_decoder import MLADecoderConfig, init_mla_decoder, mla_family
+
+    if seq + max_new_tokens > max_len:
+        raise ValueError(
+            f"seq={seq} + max_new_tokens={max_new_tokens} exceeds max_len={max_len}"
+        )
+    cfg = MLADecoderConfig(
+        vocab=int(vocab), hidden=int(hidden), layers=int(layers), heads=int(heads), q_rank=int(q_rank),
+        kv_rank=int(kv_rank), nope_dim=int(nope_dim), rope_dim=int(rope_dim), v_dim=int(v_dim),
+        dense_layers=int(dense_layers), dense_ffn=int(dense_ffn), ffn=int(ffn), experts=int(experts),
+        experts_held=int(experts_held) or int(experts), first_expert=int(first_expert),
+        experts_per_tok=int(experts_per_tok), n_group=int(n_group), topk_group=int(topk_group),
+        routed_scale=float(routed_scale), rope_theta=float(rope_theta), yarn_factor=float(yarn_factor),
+        yarn_original=int(yarn_original), yarn_beta_fast=float(yarn_beta_fast),
+        yarn_beta_slow=float(yarn_beta_slow), mscale_all_dim=float(mscale_all_dim), rms_eps=float(rms_eps),
+        max_len=int(max_len),
+    )
+    family = mla_family(cfg)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[str(param_dtype)]
+    max_new = int(max_new_tokens)
+    return ModelSpec(
+        lambda p, x: family.generate(p, x, max_new),
+        init_mla_decoder(cfg, int(seed), dtype),
+        (int(seq),),
+        (),
+        int_inputs="ids",
+        generative={"seq": int(seq), "max_new_tokens": max_new, "family": family},
+    )
+
+
 @register_model("draft")
 def build_draft(
     seed: int = 0,

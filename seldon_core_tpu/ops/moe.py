@@ -110,6 +110,31 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 #   sort, no index. At 16 rows x top 8 of 64 about 56 experts are hit
 #   anyway, so this reads 64/56 of the least bytes.
 #
+# One chip's share of an expert-parallel layer (models/mla_decoder.py, PR 37):
+# the router keeps its published width and routes over ALL experts
+# (``route_sigmoid_grouped``), the chip holds ``held`` of them from ``first``
+# (``p["gate_up"].shape[0]`` of the stored weights), and ``held_picks`` hands
+# the SAME two forms the picks renumbered to the experts held, an absent
+# pick as the junk group at gate zero: the masked form's one-hot matches no
+# expert for it, the grouped form sorts it past the last group. Neither form
+# changed for it. What holds at 12 experts of 2048 held of 192, hidden 7168
+# (88 MB an expert): 64 rows x top 8 land 32 picks here, 11.2 of the 12
+# experts get a row, so the masked form reads 12/11.2 of the least bytes and
+# no sort; its rows x held FLOPs reach the weights' read time at 240 rows
+# (the chip's 197 TFLOP/s over 819 GB/s), which is ``MASKED_MAX_ROWS`` again.
+# Above it only a sixteenth of the T*k assignments are held, but a static
+# shape must take them all: the grouped form goes in blocks of
+# ``GROUPED_BLOCK_ROWS`` rows there (``moe_held_ffn``), because its sorted
+# copy, [T*k, hidden], of a (64, 256) chunk round would be 1.9 GB and its
+# float32 result twice that. Measured on a v5e, one layer's routed part
+# alone on the host's clock (my chip run, PR 37; masked / grouped, ms): 64
+# rows 1.475 / 1.563, 128 rows 1.465 / 1.631, 256 rows 1.659 / 1.962, 512
+# rows 3.087 / 2.770, 1024 rows 6.128 / 4.415: the step's 64 rows and the
+# (2, 64) chunk's 128 take the masked form, the 256-token chunks' 512 rows
+# and more the grouped one, and the threshold does not differ from mellum's.
+# In the fused step the six expert layers read 8.56 ms (1.43 a layer,
+# 740 GB/s of held experts' weights): PERF.md section 5.
+#
 # The top-1 ``moe_ffn`` above stays what the classifier tier's ``moe_mlp``
 # serves.
 
@@ -131,6 +156,12 @@ SCOPE_MOE_COMBINE = "moe_combine"  # un-sort, gate-weighted sum, counters
 # matches, the masked form can go). From 1024 rows the masked form's
 # rows x experts FLOPs bind (4 ms a layer).
 MASKED_MAX_ROWS = 256
+# rows of one grouped call of a layer that holds a SHARE of its experts
+# (``moe_held_ffn``): every block reads the held experts' weights again, and
+# holds [rows * k, hidden] sorted rows and their float32 products
+GROUPED_BLOCK_ROWS = 4096
+SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token takes, beside the routed ones
+SCOPE_DENSE_MLP = "dense"  # a leading dense layer's gated MLP
 
 
 def _on_tpu() -> bool:
@@ -155,10 +186,11 @@ def _grouped_dot(xs: jax.Array, w: jax.Array, sizes: jax.Array, out_dtype) -> ja
         # a [k, tn] weight tile of at most 6 MB (double-buffered in VMEM), tn | n
         cap = max(128, min(1152, (6 << 20) // (2 * k) // 128 * 128))
         tn = max(t for t in range(128, cap + 1, 128) if n % t == 0)
-        return gmm(
-            xs, w, sizes, preferred_element_type=out_dtype,
-            tiling=(256 if a % 256 == 0 else 128, k, tn),
-        )
+        # 256 rows a tile where they divide the rows and a [256, k] tile of them
+        # stays under 2 MB beside the weight tile (hidden 7168: 128 rows; the
+        # kernel's tiles are double-buffered inside 16 MB of VMEM)
+        tm = 256 if a % 256 == 0 and 256 * k * xs.dtype.itemsize <= (2 << 20) else 128
+        return gmm(xs, w, sizes, preferred_element_type=out_dtype, tiling=(tm, k, tn))
     return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=out_dtype)
 
 
@@ -242,3 +274,78 @@ def moe_topk_ffn(p: dict, x: jax.Array, k: int, valid: jax.Array | None = None):
     gates, experts = route_topk(p["router"], x, k)
     form = moe_experts_masked if x.shape[0] <= MASKED_MAX_ROWS else moe_experts_grouped
     return form(p, x, gates, experts, valid)
+
+
+# --------------------------------- one chip's share of an expert-parallel layer
+
+
+def route_sigmoid_grouped(
+    router_w: jax.Array, x: jax.Array, k: int, n_group: int, topk_group: int, scale: float
+) -> tuple[jax.Array, jax.Array]:
+    """x[T, d] -> (gates[T, k] float32, experts[T, k] int32) of the
+    DeepSeek-V3 family's gate without a correction bias: sigmoid scores over
+    ALL experts in float32; the experts lie in ``n_group`` groups of
+    consecutive ones, a group's score is its largest; the ``topk_group``
+    best groups stay; the top k scores inside them; gates are those scores
+    over their sum, times ``scale`` (``norm_topk_prob``,
+    ``routed_scaling_factor``). Ties go to the lower index, as ``top_k``
+    breaks them."""
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        s = jax.nn.sigmoid(x.astype(jnp.float32) @ router_w.astype(jnp.float32))  # [T, E]
+        t, n_exp = s.shape
+        per = n_exp // n_group
+        _, best = jax.lax.top_k(jnp.max(s.reshape(t, n_group, per), axis=-1), topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group, dtype=best.dtype)[None, None, :], axis=1)
+        masked = jnp.where(jnp.repeat(kept, per, axis=1), s, -1.0)  # a sigmoid score is > 0
+        top_s, top_e = jax.lax.top_k(masked, k)
+        return top_s / jnp.sum(top_s, axis=-1, keepdims=True) * scale, top_e.astype(jnp.int32)
+
+
+def held_picks(gates: jax.Array, experts: jax.Array, first: int, held: int):
+    """The router's picks as the two expert forms take them on a chip that
+    holds experts ``[first, first + held)``: (gates with an absent pick's
+    at zero, experts renumbered from ``first`` with an absent pick as
+    ``held``, the junk group; here[T, k] bool: the picks that are held).
+    The gates are NOT renormalised: they were over all k picks."""
+    local = experts - first
+    here = (local >= 0) & (local < held)
+    return jnp.where(here, gates, 0.0), jnp.where(here, local, held), here
+
+
+def gated_mlp(gate_up: jax.Array, down: jax.Array, x: jax.Array) -> jax.Array:
+    """down(silu(gate x) * up x): a dense layer's MLP, a shared expert."""
+    return _gated_silu(x @ gate_up.astype(x.dtype)) @ down.astype(x.dtype)
+
+
+def moe_held_ffn(
+    p: dict, x: jax.Array, k: int, n_group: int, topk_group: int, scale: float, first: int,
+    valid: jax.Array | None = None,
+):
+    """The ROUTED part of the expert layer on a chip that holds the experts
+    ``[first, first + p["gate_up"].shape[0])`` of ``p["router"].shape[1]``:
+    route over all of them, compute the picks that land here, add nothing
+    for the others. Returns (y[T, d], counters[4] int32: real rows, held
+    experts with a row, the fullest held expert's rows, picks of real rows
+    that landed on a held expert)."""
+    t = x.shape[0]
+    if valid is None:
+        valid = jnp.ones((t,), bool)
+    held = p["gate_up"].shape[0]
+    gates, experts = route_sigmoid_grouped(p["router"], x, k, n_group, topk_group, scale)
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        gates, experts, here = held_picks(gates, experts, first, held)
+        local = jnp.sum(here & valid[:, None], dtype=jnp.int32)
+    if t <= MASKED_MAX_ROWS:
+        y, cnt = moe_experts_masked(p, x, gates, experts, valid)
+    elif t <= GROUPED_BLOCK_ROWS:
+        y, cnt = moe_experts_grouped(p, x, gates, experts, valid)
+    else:
+        if t % GROUPED_BLOCK_ROWS:
+            raise ValueError(f"{t} rows are not whole blocks of {GROUPED_BLOCK_ROWS}")
+        blocks = [a.reshape(t // GROUPED_BLOCK_ROWS, GROUPED_BLOCK_ROWS, *a.shape[1:]) for a in (x, gates, experts, valid)]
+        y = jax.lax.map(lambda a: moe_experts_grouped(p, *a)[0], tuple(blocks)).reshape(t, -1)
+        with jax.named_scope(SCOPE_MOE_COMBINE):  # the blocks' loads, counted over all of them
+            hot = (experts[:, :, None] == jnp.arange(held, dtype=jnp.int32)[None, None, :]) & valid[:, None, None]
+            cnt = _load_counters(jnp.sum(hot, axis=(0, 1), dtype=jnp.int32), jnp.sum(valid))
+    with jax.named_scope(SCOPE_MOE_COMBINE):
+        return y, jnp.concatenate([cnt, local[None]])

@@ -388,7 +388,14 @@ class FlightFrame:
     counted them (models/hybrid_decoder.py), ``state_restores`` the round's
     admissions that began from a cached prefix's snapshot row and
     ``state_captures`` the snapshot rows the round bound to a new prefix
-    entry (serving/kv_pool.py); 0 for a family without a state cache."""
+    entry (serving/kv_pool.py); 0 for a family without a state cache;
+    ``moe_local_picks`` / ``mla_ctx_rows`` what a latent-attention family's
+    programs counted beside the ``moe_*`` three, which there are over the
+    experts the chip HOLDS (models/mla_decoder.py): the picks of real rows
+    that landed on a held expert, summed over layers (of ``moe_rows`` x top
+    k x expert layers routed), and the latent cache rows the dispatches'
+    live rows attended over, each row's keys summed (one layer's: every
+    layer reads as many); 0 for another family."""
 
     __slots__ = (
         "seq", "t_ns", "mode", "active", "prefilling", "queued",
@@ -402,6 +409,7 @@ class FlightFrame:
         "sample_rows", "sample_topk_rows",
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
+        "moe_local_picks", "mla_ctx_rows",
     )
 
     def __init__(
@@ -416,6 +424,7 @@ class FlightFrame:
         sample_rows=0, sample_topk_rows=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
+        moe_local_picks=0, mla_ctx_rows=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -457,6 +466,8 @@ class FlightFrame:
         self.ssm_rows = ssm_rows
         self.state_restores = state_restores
         self.state_captures = state_captures
+        self.moe_local_picks = moe_local_picks
+        self.mla_ctx_rows = mla_ctx_rows
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -528,6 +539,8 @@ class FlightFrame:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
         if self.ssm_rows:
             d["ssm"] = [self.ssm_rows, self.state_restores, self.state_captures]
+        if self.mla_ctx_rows:
+            d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
         return d
 
 

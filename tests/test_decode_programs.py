@@ -309,3 +309,91 @@ def test_a_family_answers_what_it_serves(family, mechanism, message):
     with pytest.raises(FamilyNotServed) as e:
         DecodeScheduler(params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=N, family=MOE, **kw)
     assert str(e.value) == message
+
+
+# what the three families that were served before the fourth lowered to at the
+# commit before it (PR 37's parent, a7fd829): sha256 of ``jit(program).lower(
+# shapes).as_text()`` at the sizes of ``_old_family_program``. A change to a
+# helper the families share (models/decoder.py's pool helpers and program
+# wrappers, ops/moe.py's forms) that alters what one of them computes, or the
+# order it computes it in, changes its text.
+LOWERED_BEFORE_THE_FOURTH_FAMILY = {
+    "gpt2.step": "bb7a50487387849fd45d78852252e0ffa0ee36b76863d5227bbd13f0b4cf0ecb",
+    "gpt2.chunk": "eba71f7caffcd2299c3f1e10f22f13254c08d16b97639d0cabc026912b2d87d6",
+    "moe.step": "e07abc7278c088aeb34055a65b3d3d937df48aa979a928e8fce9bcace1f6d896",
+    "moe.chunk": "3334c7074843521995637b7fe6679f210f219ad113bad42bd8080caff973e7b8",
+    "hybrid.step": "3730a9606d68e4cab4152fb0e262ec6a290a4d2d629256750117cabfed85c0c3",
+    "hybrid.chunk": "21db822e9808518243eb7be78ad48da5606cc23a1f6753b908d4ebb1a37a600f",
+}
+
+
+def _old_family_program(family: str, kind: str):
+    """(program, argument shapes) of one family's step or (2, 8) chunk at a
+    tiny size: 4 slots, pages of 4, tables of 5 pages."""
+    from seldon_core_tpu.models import decoder as dec
+    from seldon_core_tpu.models import hybrid_decoder as hd
+
+    def sds(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    rec = ()
+    if family == "gpt2":
+        fam = gpt2_family
+        p = init_decoder(0, vocab=96, hidden=32, layers=2, ffn=64, max_len=64)
+    elif family == "moe":
+        fam = md.moe_family(md.MoEDecoderConfig(
+            vocab=96, hidden=64, layers=4, heads=4, kv_heads=2, head_dim=16, ffn=32, experts=8, experts_per_tok=2,
+            window=8, period=4, yarn_factor=4.0, yarn_original=16))
+        p = md.init_moe_decoder(fam.cfg, seed=1, dtype=jnp.float32)
+    else:
+        fam = hd.hybrid_family(hd.HybridDecoderConfig(
+            vocab=96, hidden=64, layers=3, attn_layers=(1,), heads=4, kv_heads=2, head_dim=16, ffn=64, ssm_heads=4,
+            ssm_head_dim=16, ssm_state=8, ssm_conv=4))
+        p = hd.init_hybrid_decoder(fam.cfg, seed=1, dtype=jnp.float32)
+        rec = (sds(fam.state_init(p, 7)),)
+    pool = fam.paged_kv_init(p, 24, 4)
+    step, chunk = fam.fused_programs("")
+    n = 4 if kind == "step" else 2
+    tail = (f32(n), i32(n), i32(), i32())  # temperatures, top-k, seed, tick
+    if kind == "step":
+        rows = () if family == "gpt2" else (jax.ShapeDtypeStruct((n,), bool),)
+        return step, (sds(p), sds(pool), *rec, i32(n, 5), i32(n), i32(n), *tail, *rows)
+    state_rows = (i32(3, n),) if rec else ()
+    return chunk, (sds(p), sds(pool), *rec, i32(n, 5), i32(n, 8), i32(n), i32(n), *tail, *state_rows)
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED_BEFORE_THE_FOURTH_FAMILY))
+def test_the_older_families_programs_lower_to_the_text_they_had(program):
+    import hashlib
+
+    fn, args = _old_family_program(*program.split("."))
+    text = jax.jit(fn).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_BEFORE_THE_FOURTH_FAMILY[program]
+
+
+def test_the_fourth_family_rides_the_counting_convention():
+    """The latent-attention family's programs take what the sparse-expert
+    family's take (pool, tables, tokens, positions, the sampler's four, rows)
+    and append their five counts to the token readback: the set needs no
+    fourth convention."""
+    from seldon_core_tpu.models import mla_decoder as mla
+
+    fam = mla.mla_family(mla.MLADecoderConfig(vocab=96, experts_held=8))
+    params = mla.init_mla_decoder(fam.cfg, seed=0, dtype=jnp.float32)
+    sched = DecodeScheduler(params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=N, kv_page_size=4, family=fam)
+    progs = sched.programs
+    assert (progs.mode, progs.attn_kernel, progs._counted, progs._stateful) == ("", "", 5, False)
+    sched.warmup()
+    assert set(progs.compile_counts()) == {"step", "chunk", "copy"}
+    zi, zf = np.zeros(N, np.int32), np.zeros(N, np.float32)
+    out, read = progs.step(sched.pool.block_tables(), zi, zi, zf, zi, np.int32(1), np.ones(N, bool))
+    toks, counted = read()
+    assert toks.shape == (N,) and counted.shape == (5,) and counted[0] == N  # both rows counted as real
+    assert counted[4] == N  # each attended over one latent row (position 0)
+    assert sched.recompiles_since_warmup() == 0

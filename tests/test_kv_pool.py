@@ -327,6 +327,75 @@ def test_paged_copy_moves_a_pages_rows_and_planes_together(kv_dtype):
         np.testing.assert_array_equal(a[:, keep], b[:, keep])
 
 
+# ------------------------------------------------ the latent page kind
+
+
+def _latent_pool(**kw):
+    """A PagedKVPool over the latent-attention family's one-plane pages
+    (models/mla_decoder.py): 3 layers, 2 slots of 4 pages of 4 rows."""
+    from seldon_core_tpu.models import mla_decoder as mla
+    from seldon_core_tpu.serving.kv_pool import PagedKVPool
+
+    fam = mla.mla_family(mla.MLADecoderConfig(vocab=32, experts_held=16))
+    params = mla.init_mla_decoder(fam.cfg, seed=0, dtype=jnp.float32)
+    return fam, PagedKVPool(params, n_slots=2, cache_ctx=16, page_size=4, kv_init=fam.paged_kv_init, **kw)
+
+
+def test_the_pool_sizes_warms_copies_and_resets_a_one_plane_latent_pool():
+    """Nothing in the pool manager knows the page kind: the state is the
+    family's tuple (ONE plane of latent rows, whole lane tiles wide), the
+    copy ladder walks every component, a reset gives the plane back zeroed
+    with every mapping dropped."""
+    from seldon_core_tpu.models.decoder import _paged_write_latent
+
+    fam, pool = _latent_pool(n_pages=12)
+    (plane,) = pool.state
+    assert plane.shape == (3, 12, 4, 128) and pool.pages_per_slot == 4 and fam.cfg.row_width == 128
+    pool.warmup()
+    warmed = pool.compile_count()  # the jit cache is keyed on ``paged_copy``: other pools of the process count in it
+    assert warmed >= len(pool.copy_buckets) and len(pool.state) == 1
+    assert pool.alloc.try_admit(0, [], 0)
+    assert pool.alloc.prepare_write(0, 0, 8) == []  # two fresh pages
+    rows = jnp.arange(8 * 128, dtype=jnp.float32).reshape(1, 8, 128) + 1.0
+    bt = jnp.asarray(pool.block_tables(np.array([0])))
+    pool.state = _paged_write_latent(pool.state, 1, rows, bt, jnp.zeros(1, jnp.int32), None)
+    # a second reader shares the first page, then writes into it: copy-on-write through the ladder
+    pin = pool.alloc.capture(0, 8)
+    assert pool.alloc.try_admit(1, pin.pages, 6)
+    copies = pool.alloc.prepare_write(1, 6, 1)
+    assert len(copies) == 1 and copies[0][0] == pin.pages[1]
+    before = np.asarray(pool.state[0])
+    pool.run_copies(copies)
+    after = np.asarray(pool.state[0])
+    src, dst = copies[0]
+    np.testing.assert_array_equal(after[:, dst], before[:, src])  # every layer's rows of the page
+    assert after[1, dst].any() and not after[0, dst].any()  # layer 1 was written, layer 0 never
+    keep = [p for p in range(12) if p != dst]
+    np.testing.assert_array_equal(after[:, keep], before[:, keep])
+    pool.alloc.check()
+    assert pool.compile_count() == warmed  # the warmed ladder served it
+    pool.reset()
+    assert len(pool.state) == 1 and not np.asarray(pool.state[0]).any() and pool.alloc.free_pages == 11
+
+
+def test_the_latent_write_lands_rows_by_position_and_sends_junk_to_page_0():
+    """One scatter of [rows, c, width] through the block tables: a row at
+    positions[i] + j lands in page bt[i, (pos + j) // ps], row (pos + j) % ps;
+    rows beyond ``counts`` and a padding row's land in junk page 0."""
+    from seldon_core_tpu.models.decoder import _paged_write_latent
+
+    _, pool = _latent_pool(n_pages=12)
+    bt = jnp.asarray([[3, 5, 7, 9], [0, 0, 0, 0]], jnp.int32)
+    rows = jnp.broadcast_to(jnp.arange(1.0, 7.0)[None, :, None], (2, 6, 128))
+    (plane,) = _paged_write_latent(pool.state, 2, rows, bt, jnp.asarray([2, 0], jnp.int32), jnp.asarray([5, 0], jnp.int32))
+    got = np.asarray(plane[2])
+    assert got[3, 2:, 0].tolist() == [1.0, 2.0] and got[5, :3, 0].tolist() == [3.0, 4.0, 5.0]
+    assert not got[5, 3:].any() and not got[7].any() and not got[9].any()  # the sixth row is past counts
+    assert not np.asarray(plane[:2]).any()  # the other layers stand
+    live = [p for p in range(1, 12) if p not in (3, 5)]
+    assert not got[live].any()
+
+
 # ------------------------------------------------ scheduler over the pool
 
 

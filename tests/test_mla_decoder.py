@@ -1,0 +1,560 @@
+"""The latent-attention decoder family (models/mla_decoder.py, ops/mla.py, the
+held-expert layer of ops/moe.py) held to its plain reference
+(benchmarks/reference/a.x-k1.py) at a small size on the CPU: hidden 64, 4
+heads of nope 8 / rope 4 / v 8 over a 16-wide latent (cache rows of 20 in one
+128-lane tile), a
+leading dense layer then two expert layers of a shared expert + top 4 of 16
+sigmoid-routed experts in 4 groups (2 kept), of which this chip holds 4 from
+the fifth; pages of 4; YaRN factor 32 over an original context of 16. Seeded
+random weights; every case counts on its own.
+"""
+
+import asyncio
+import dataclasses
+import functools
+import json
+import math
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+from harness import cells  # noqa: E402
+from harness.correct import judge_generated  # noqa: E402
+
+from seldon_core_tpu.models import decoder as dec  # noqa: E402
+from seldon_core_tpu.models import hybrid_decoder as hd  # noqa: E402
+from seldon_core_tpu.models import mla_decoder as mla  # noqa: E402
+from seldon_core_tpu.models import moe_decoder as md  # noqa: E402
+from seldon_core_tpu.models.decoder import FamilyNotServed, init_decoder  # noqa: E402
+from seldon_core_tpu.ops import mla as mla_ops  # noqa: E402
+from seldon_core_tpu.ops import moe  # noqa: E402
+from seldon_core_tpu.serving import decode_scheduler as ds  # noqa: E402
+from seldon_core_tpu.serving.decode_programs import _step_attn_kernel  # noqa: E402
+
+SIZES = dict(
+    vocab=96, hidden=64, layers=3, heads=4, q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+    dense_layers=1, dense_ffn=96, ffn=32, experts=16, experts_per_tok=4, n_group=4, topk_group=2,
+    yarn_factor=32.0, yarn_original=16,
+)
+CFG = mla.MLADecoderConfig(**SIZES, experts_held=4, first_expert=4)  # one chip's share: experts 4..7
+# the same sizes under the published config's keys, for the reference
+PUBLISHED = {
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8, "kv_lora_rank": 16, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000.0, "first_k_dense_replace": 1, "n_group": 4, "topk_group": 2, "num_experts_per_tok": 4,
+    "routed_scaling_factor": 2.5,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 32, "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 16, "type": "yarn"},
+    "share": {"first_expert": 4},
+}
+PS = 4  # page size
+CTX = 40
+FAM = mla.mla_family(CFG)
+ROUTE = dict(top_k=4, n_group=4, topk_group=2, scale=2.5)
+
+
+@pytest.fixture(scope="module")
+def ref():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return cells.load_module(ROOT, json.load(f), "reference", "a.x-k1")
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return {d: mla.init_mla_decoder(CFG, seed=5, dtype=d) for d in (jnp.float32, jnp.bfloat16)}
+
+
+def _ref_logits(ref, params, ids, precision):
+    return np.asarray(
+        ref.logits(params, np.asarray(ids)[None], 0, n_head=CFG.heads, precision=precision, config=PUBLISHED)
+    )[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _programs(cfg, tag=""):
+    """(chunk, step) jitted over ``_forward``; ``tag`` keys a trace made
+    under a planted fault apart from the clean one."""
+    chunk = jax.jit(lambda p, pool, bt, t, pos, cnt: mla._forward(cfg, p, pool, bt, t, pos, counts=cnt)[::2])
+    step = jax.jit(lambda p, pool, bt, t, pos, rows: mla._forward(cfg, p, pool, bt, t, pos, rows=rows)[::2])
+    return chunk, step
+
+
+def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32, cfg=CFG, tag="", greedy_after=None, width=0):
+    """Teacher-forced through the paged programs: chunked prefill of
+    ``sum(chunks)`` tokens, then single-token steps along ``ids``; returns
+    logits [len(ids), vocab]. The sequence sits in slot 1 of 3 (slots 0 and 2
+    ride as junk). ``prefix_from`` = (pool, pages, n): the first n tokens'
+    pages of an earlier run are MAPPED (a prefix hit), only the rest is
+    computed. ``greedy_after``: from that position on each next token is the
+    served argmax (written into ``ids``). ``width``: the chunk programs'
+    static chunk length where it is more than the chunk (the rest is padding)."""
+    fam = mla.mla_family(cfg)
+    chunk, step = _programs(cfg, tag)
+    n_slots, pages = 3, CTX // PS
+    if prefix_from is None:
+        pool = fam.paged_kv_init(params, 1 + 2 * pages, PS, dtype)
+        mine, done = 1 + np.arange(pages), 0
+    else:
+        pool, theirs, done = prefix_from
+        assert done % PS == 0
+        mine = np.concatenate([theirs[: done // PS], 1 + pages + np.arange(pages - done // PS)])
+    bt = np.zeros((n_slots, pages), np.int32)
+    bt[1] = mine
+    out = np.zeros((len(ids), cfg.vocab), np.float32)
+    pos = done
+    for c in chunks:
+        toks = np.zeros((n_slots, max(c, width)), np.int32)
+        toks[1, :c] = ids[pos : pos + c]
+        logits, pool = chunk(params, pool, jnp.asarray(bt), jnp.asarray(toks), jnp.array([0, pos, 0], jnp.int32),
+                             jnp.array([0, c, 0], jnp.int32))
+        out[pos : pos + c] = np.asarray(logits[1, :c])
+        pos += c
+    if greedy_after is not None and greedy_after < pos < len(ids):
+        ids[pos] = int(np.argmax(out[pos - 1]))  # the first generated token comes from the last chunk
+    while pos < len(ids):
+        logits, pool = step(params, pool, jnp.asarray(bt), jnp.array([[0], [ids[pos]], [0]], jnp.int32),
+                            jnp.array([0, pos, 0], jnp.int32), jnp.array([False, True, False]))
+        out[pos] = np.asarray(logits[1, 0])
+        if greedy_after is not None and greedy_after <= pos < len(ids) - 1:
+            ids[pos + 1] = int(np.argmax(out[pos]))
+        pos += 1
+    return out, pool, mine
+
+
+def _ids(seed=0, n=CTX):
+    return np.random.default_rng(seed).integers(0, CFG.vocab, n).astype(np.int32)
+
+
+# (a) chunked prefill then decode through the latent pages == the reference's full forward
+
+
+def test_the_static_chunk_length_picks_the_path():
+    """Expanded costs rank * (nope + v) a cached row and head whatever the
+    chunk, absorbed 2 * rank + rope a QUERY: 16 queries at this size, 171 at
+    the published one (a 64-token tail is absorbed, a 256-token chunk not)."""
+    small = dict(rank=16, nope=8, rope=4, v_dim=8)
+    assert [mla_ops.expand_cheaper(m, **small) for m in (1, 16, 17, 24)] == [False, False, True, True]
+    full = dict(rank=512, nope=128, rope=64, v_dim=128)
+    assert [mla_ops.expand_cheaper(m, **full) for m in (1, 64, 170, 171, 256)] == [False, False, False, True, True]
+    # a block of the walk: 1024 keys for the step's 64 slots, 128 for the (64, 256) chunk program's scores
+    assert mla_ops.block_pages(64, 64, 1, 16, 533) == 64 and mla_ops.block_pages(64, 64, 256, 16, 533) == 8
+
+
+@pytest.mark.parametrize(
+    "chunks", [(8, 8, 8), (24,), (5, 19), (16, 1, 7), (3,)], ids=["absorbed", "expanded", "both", "ladder", "short"]
+)
+def test_cold_prefill_then_decode_equals_reference_float32(ref, weights, chunks):
+    """Across page boundaries (pages of 4) and both prefill paths (a chunk of
+    17 or more expands the cached rows, a shorter one absorbs the queries),
+    then absorbed steps: every position's logits, to 1e-5."""
+    params, ids = weights[jnp.float32], _ids(1)
+    got, _, _ = _serve(params, ids, chunks=chunks)
+    np.testing.assert_allclose(got, _ref_logits(ref, params, ids, "highest"), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("chunks", [(8, 8, 8), (16, 3, 5), (20, 4)], ids=["few", "at_the_limit", "long_then_few"])
+def test_a_long_chunks_program_absorbs_a_dispatch_of_few_live_queries(ref, weights, chunks):
+    """The 24-token program (expanded by its static length) given rows of at
+    most 16 live queries absorbs those (``absorb_short``: 16 here, 128 at the
+    published sizes) and expands a dispatch with more: either way the live
+    positions' logits are the reference's."""
+    assert mla_ops.absorb_short(rank=16, nope=8, rope=4, v_dim=8) == 16
+    assert mla_ops.absorb_short(rank=512, nope=128, rope=64, v_dim=128) == 128
+    params, ids = weights[jnp.float32], _ids(1)
+    got, _, _ = _serve(params, ids, chunks=chunks, width=24)
+    np.testing.assert_allclose(got, _ref_logits(ref, params, ids, "highest"), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("shared,chunks", [(8, (3, 3)), (20, (2,)), (16, (20,))])
+def test_prefix_hit_equals_reference_float32(ref, weights, shared, chunks):
+    """A second sequence maps the first one's latent pages for its first
+    ``shared`` tokens (the rows hold the ROTATED shared key: a hit needs
+    nothing new) and computes only the rest, absorbed or expanded."""
+    params = weights[jnp.float32]
+    first, second = _ids(2), _ids(3)
+    second[:shared] = first[:shared]
+    _, pool, pages = _serve(params, first, chunks=(10, 10))
+    got, _, _ = _serve(params, second, chunks=chunks, prefix_from=(pool, pages, shared))
+    want = _ref_logits(ref, params, second, "highest")
+    np.testing.assert_allclose(got[shared:], want[shared:], atol=1e-5, rtol=0)
+
+
+def test_bfloat16_serving_within_the_harness_delta(ref, weights):
+    """The harness's rule (benchmarks/harness/correct.py) at the small size:
+    along greedy tokens served in bfloat16 from bfloat16 latent pages, the
+    reference's exact logit of each served token trails its best by at most
+    twice the rounding delta between the reference at the stated precision
+    and at "highest"."""
+    params, ids, first = weights[jnp.bfloat16], _ids(4), 23
+    _serve(params, ids, chunks=(12, 12), dtype=jnp.bfloat16, greedy_after=first)
+    exact, noisy = (_ref_logits(ref, params, ids, p)[None, first:] for p in ("highest", "default"))
+    verdict = judge_generated([ids.tolist()], exact, noisy, first)
+    assert verdict["ok"], verdict
+    assert verdict["rounding_delta"] > 1e-4  # bfloat16 activations do round
+
+
+# (b) what the comparison sees: four planted faults, each outside it
+
+
+def _unrotated_key(monkeypatch):
+    real = mla._rope
+    monkeypatch.setattr(mla, "_rope", lambda x, pos, f, a: x if x.shape[2] == 1 else real(x, pos, f, a))
+    return CFG
+
+
+def _no_mscale(monkeypatch):
+    return dataclasses.replace(CFG, mscale_all_dim=0.0)  # m = 1: the scores lose m^2 = 1.81
+
+
+def _no_shared_expert(monkeypatch):
+    real = mla.gated_mlp
+    monkeypatch.setattr(mla, "gated_mlp", lambda gu, down, x: 0.0 * real(gu, down, x) if gu.shape[1] == 2 * CFG.ffn else real(gu, down, x))
+    return CFG
+
+
+def _gates_over_the_held_picks(monkeypatch):
+    real = moe.held_picks
+
+    def renormalised(gates, experts, first, held):
+        g, e, here = real(gates, experts, first, held)
+        return g * jnp.sum(gates, -1, keepdims=True) / jnp.maximum(jnp.sum(g, -1, keepdims=True), 1e-9), e, here
+
+    monkeypatch.setattr(moe, "held_picks", renormalised)
+    return CFG
+
+
+def _sharp(params):
+    """The same weights with the query and key/value up-projections times 8:
+    at std 0.02 the scores are ~0.01 and every softmax is uniform, so nothing
+    done to a score would show; at 64 times that, attention picks keys."""
+    layers = [{**p, "q_b": p["q_b"] * 8, "kv_b": p["kv_b"] * 8, "kv_a": p["kv_a"] * 8} for p in params["layers"]]
+    return {**params, "layers": layers}
+
+
+@pytest.mark.parametrize(
+    "fault", [_unrotated_key, _no_mscale, _no_shared_expert, _gates_over_the_held_picks], ids=lambda f: f.__name__[1:]
+)
+def test_a_planted_fault_fails_the_comparison(ref, weights, monkeypatch, fault):
+    """The shared key left unrotated, m^2 left off the score scale, the
+    shared expert left out, the gates normalised over the picks that are held
+    instead of all 8: each moves logits (std 0.16) a hundred times past the
+    1e-5 of the cases above."""
+    params, ids = _sharp(weights[jnp.float32]), _ids(1)
+    want = _ref_logits(ref, params, ids, "highest")
+    np.testing.assert_allclose(_serve(params, ids, chunks=(8, 16))[0], want, atol=1e-5, rtol=0)
+    cfg = fault(monkeypatch)
+    got, _, _ = _serve(params, ids, chunks=(8, 16), cfg=cfg, tag=fault.__name__)
+    assert np.abs(got - want).max() > 1e-3
+
+
+# (c) the two attention paths, and the walk in blocks
+
+
+def _attention_case(seed, n=3, m=5, heads=4, nope=8, rope=4, v=8, rank=16, pages=12, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    plane = jax.random.normal(ks[0], (2, 1 + n * pages, PS, rank + rope + 12), dtype)  # 12 lanes of padding, never read as keys
+    bt = 1 + jnp.arange(n * pages, dtype=jnp.int32).reshape(n, pages)
+    q_nope = jax.random.normal(ks[1], (n, m, heads, nope), dtype)
+    q_rope = jax.random.normal(ks[2], (n, m, heads, rope), dtype)
+    kv_b = jax.random.normal(ks[3], (rank, heads, nope + v), dtype) * 0.3
+    return plane, bt, q_nope, q_rope, kv_b
+
+
+def _plain_attention(plane, li, bt, q_nope, q_rope, q_pos, kv_b, rank, scale):
+    """Every row's table gathered whole, every head's keys and values expanded."""
+    n, m, heads, nope = q_nope.shape
+    rows = np.asarray(plane[li][bt]).reshape(n, -1, plane.shape[-1])
+    kv = np.einsum("nkr,rhe->nkhe", rows[..., :rank], np.asarray(kv_b))
+    rope = q_rope.shape[-1]
+    s = np.einsum("nmhd,nkhd->nhmk", q_nope, kv[..., :nope]) + np.einsum("nmhd,nkd->nhmk", q_rope, rows[..., rank : rank + rope])
+    seen = np.arange(rows.shape[1])[None, None, :] <= np.asarray(q_pos)[:, :, None]
+    s = np.where(seen[:, None], s * scale, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("nhmk,nkhv->nmhv", p, kv[..., nope:]).reshape(n, m, -1)
+
+
+@pytest.mark.parametrize("block_keys", [8, 16, 1024], ids=["blocks_of_8", "blocks_of_16", "one_block"])
+@pytest.mark.parametrize("expand", [False, True], ids=["absorbed", "expanded"])
+def test_both_paths_equal_plain_attention_in_any_blocks(monkeypatch, expand, block_keys):
+    monkeypatch.setattr(mla_ops, "_MIN_BLOCK_KEYS", min(block_keys, 128))
+    monkeypatch.setattr(mla_ops, "_MAX_BLOCK_KEYS", block_keys)
+    plane, bt, q_nope, q_rope, kv_b = _attention_case(0)
+    pos = jnp.array([0, 17, 43], jnp.int32)  # tables of 48 keys: one, three and six blocks of 8
+    q_pos = pos[:, None] + jnp.arange(5)[None, :]
+    got = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 1, bt, q_pos, pos + 5, kv_b, scale=0.3, expand=expand)
+    want = _plain_attention(plane, 1, bt, q_nope, q_rope, q_pos, kv_b, 16, 0.3)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-6)
+
+
+def test_the_walk_stops_at_the_longest_live_row(monkeypatch):
+    """Pages past every live row's keys are never read: NaNs planted there
+    stay out, and a row nobody reads (n_keys 1) does not lengthen the walk."""
+    monkeypatch.setattr(mla_ops, "_MIN_BLOCK_KEYS", 8)
+    monkeypatch.setattr(mla_ops, "_MAX_BLOCK_KEYS", 8)
+    plane, bt, q_nope, q_rope, kv_b = _attention_case(1, m=1)
+    plane = plane.at[:, bt[:, 4:].reshape(-1)].set(jnp.nan)  # keys 16.. of every table
+    q_pos = jnp.array([[3], [15], [40]], jnp.int32)
+    n_keys = jnp.array([4, 16, 1], jnp.int32)  # the third row rides as junk
+    got = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 0, bt, q_pos, n_keys, kv_b, scale=0.3, expand=False)
+    assert np.isfinite(np.asarray(got[:2])).all()
+
+
+def test_the_step_gathers_blocks_in_the_pools_dtype_never_a_float32_table(weights):
+    """In the lowered step there is no float32 array as long as a slot's
+    table (10 pages x 4 rows x 20 wide) and no per-head key or value of it:
+    what is gathered is one block of bfloat16 rows at a time."""
+    params = weights[jnp.bfloat16]
+    pool = FAM.paged_kv_init(params, 21, PS, jnp.bfloat16)
+    step = _programs(CFG)[1]
+    text = step.lower(params, pool, jnp.zeros((3, 10), jnp.int32), jnp.zeros((3, 1), jnp.int32),
+                      jnp.zeros((3,), jnp.int32), jnp.ones((3,), bool)).as_text()
+    assert "tensor<3x10x4x128xbf16>" in text  # the gathered block (the whole table here: one block)
+    assert "3x10x4x128xf32" not in text and "3x40x128xf32" not in text
+    assert "3x40x4x16x" not in text  # [rows, keys, heads, nope + v]: no expansion in the step
+
+
+# (d) the router against a literal transcription; the held share of the expert layer
+
+
+def _literal_router(w, x, top_k, n_group, topk_group, scale):
+    """Token by token, as the family's public inference code has it (a gate
+    without a correction bias): returns ({expert: gate} a token)."""
+    out = []
+    for row in np.asarray(x, np.float64):
+        s = 1.0 / (1.0 + np.exp(-(row @ np.asarray(w, np.float64))))
+        per = len(s) // n_group
+        best = [max(s[g * per : (g + 1) * per]) for g in range(n_group)]
+        groups = sorted(range(n_group), key=lambda g: (-best[g], g))[:topk_group]
+        inside = [e for e in range(len(s)) if e // per in groups]
+        picks = sorted(inside, key=lambda e: (-s[e], e))[:top_k]
+        total = sum(s[e] for e in picks)
+        out.append({e: scale * s[e] / total for e in picks})
+    return out
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "one_group_dominates"])
+def test_router_equals_a_literal_transcription(ref, case):
+    w = np.asarray(jax.random.normal(jax.random.key(3), (CFG.hidden, CFG.experts))) * 0.5
+    x = np.asarray(jax.random.normal(jax.random.key(4), (40, CFG.hidden)))
+    if case == "ties":  # equal columns score equal: the lower index wins, inside a group and between groups
+        w[:, 5], w[:, 9], w[:, 13] = w[:, 4], w[:, 8], w[:, 12]
+        w[:, 8:12] = w[:, 0:4]
+    if case == "one_group_dominates":
+        w[:, 12:] += 2.0 * np.sign(x[0])[:, None] / np.sqrt(CFG.hidden)
+    want = _literal_router(w, x, **ROUTE)
+    gates, experts = moe.route_sigmoid_grouped(jnp.asarray(w), jnp.asarray(x), 4, 4, 2, 2.5)
+    dense = np.asarray(ref.router(jnp.asarray(w), jnp.asarray(x), **ROUTE))
+    for t, picks in enumerate(want):
+        assert sorted(int(e) for e in experts[t]) == sorted(picks)
+        for g, e in zip(np.asarray(gates[t]), np.asarray(experts[t])):
+            assert g == pytest.approx(picks[int(e)], rel=1e-5)
+        assert set(np.flatnonzero(dense[t])) == set(picks)
+        np.testing.assert_allclose(dense[t][sorted(picks)], [picks[e] for e in sorted(picks)], rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-6)  # normalised over the 4 picks, then scaled
+    assert gates.dtype == jnp.float32
+
+
+def _expert_layer(seed=0, held=16):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    d, f = CFG.hidden, CFG.ffn
+    return {
+        "router": jax.random.normal(ks[0], (d, CFG.experts)) * 0.5,
+        "gate_up": jax.random.normal(ks[1], (held, d, 2 * f)) * 0.1,
+        "down": jax.random.normal(ks[2], (held, f, d)) * 0.1,
+        "shared_gate_up": jax.random.normal(ks[3], (d, 2 * f)) * 0.1,
+        "shared_down": jax.random.normal(ks[4], (f, d)) * 0.1,
+    }
+
+
+def _share(p, first, held):
+    return {**p, "gate_up": p["gate_up"][first : first + held], "down": p["down"][first : first + held]}
+
+
+def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_uncut_layer(ref):
+    """Four chips of four experts each: their routed parts (a pick that lands
+    on an absent expert adds nothing, gates over all four picks) plus the
+    shared expert counted ONCE equal the uncut layer of the reference."""
+    p = _expert_layer()
+    n2 = jax.random.normal(jax.random.key(8), (24, CFG.hidden))
+    uncut = ref.expert_ffn(p, n2, first_expert=0, act="float32", **ROUTE)
+    parts = [moe.moe_held_ffn(_share(p, 4 * s, 4), n2, 4, 4, 2, 2.5, 4 * s) for s in range(4)]
+    total = sum(y for y, _ in parts) + moe.gated_mlp(p["shared_gate_up"], p["shared_down"], n2)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=5e-6)
+    assert sum(int(c[3]) for _, c in parts) == 24 * 4  # every pick landed on exactly one chip
+    # and share by share, against the reference given the same share
+    for s, (y, _) in enumerate(parts):
+        want = ref.expert_ffn(_share(p, 4 * s, 4), n2, first_expert=4 * s, act="float32", shared=False, **ROUTE)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-6)
+
+
+@pytest.mark.parametrize("rows,form", [(24, "masked"), (300, "grouped"), (512, "blocks")])
+def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch, rows, form):
+    """By static row count: masked to 256 rows, grouped above, in blocks of
+    rows above ``GROUPED_BLOCK_ROWS`` (128 here). Junk rows come back zero and
+    stay out of the counts, which are over the experts HELD."""
+    monkeypatch.setattr(moe, "GROUPED_BLOCK_ROWS", 128 if form == "blocks" else 4096)
+    p = _share(_expert_layer(1), 4, 4)
+    n2 = jax.random.normal(jax.random.key(rows), (rows, CFG.hidden))
+    valid = jnp.arange(rows) < rows - 5
+    y, counted = jax.jit(lambda p, x, v: moe.moe_held_ffn(p, x, 4, 4, 2, 2.5, 4, v))(p, n2, valid)
+    want = np.asarray(ref.expert_ffn(p, n2, first_expert=4, act="float32", shared=False, **ROUTE))
+    np.testing.assert_allclose(np.asarray(y[: rows - 5]), want[: rows - 5], atol=5e-6)
+    assert not np.asarray(y[rows - 5 :]).any()
+    _, experts = moe.route_sigmoid_grouped(p["router"], n2, 4, 4, 2, 2.5)
+    mine = np.asarray((experts >= 4) & (experts < 8))[: rows - 5]
+    loads = np.bincount(np.asarray(experts)[: rows - 5][mine] - 4, minlength=4)
+    assert [int(c) for c in counted] == [rows - 5, int((loads > 0).sum()), int(loads.max()), int(mine.sum())]
+
+
+# (e) frequencies and the score scale against the closed forms
+
+
+@pytest.mark.parametrize("sizes", ["small", "published"])
+def test_rope_frequencies_and_score_scale(ref, sizes):
+    pub = PUBLISHED if sizes == "small" else ref.published()
+    rs = pub["rope_scaling"]
+    cfg = mla.MLADecoderConfig(
+        rope_dim=pub["qk_rope_head_dim"], nope_dim=pub["qk_nope_head_dim"], rope_theta=pub["rope_theta"],
+        yarn_factor=rs["factor"], yarn_original=rs["original_max_position_embeddings"],
+        yarn_beta_fast=rs["beta_fast"], yarn_beta_slow=rs["beta_slow"], mscale_all_dim=rs["mscale_all_dim"],
+    )
+    np.testing.assert_allclose(cfg.inv_freq, ref.inv_freq(pub), rtol=1e-6)
+    assert cfg.inv_freq[0] == pytest.approx(1.0)  # the fastest dimension keeps its frequency
+    d = cfg.rope_dim
+    assert cfg.inv_freq[-1] == pytest.approx(cfg.rope_theta ** (-(d - 2) / d) / cfg.yarn_factor, rel=1e-6)
+    m = 0.1 * math.log(32) + 1
+    assert cfg.score_scale == pytest.approx((cfg.nope_dim + d) ** -0.5 * m * m, rel=1e-12)
+    assert cfg.score_scale == pytest.approx(ref.score_scale(pub), rel=1e-12)
+    if sizes == "published":
+        assert m == pytest.approx(1.34657, abs=1e-5) and cfg.score_scale == pytest.approx(192**-0.5 * 1.81326, rel=1e-5)
+
+
+# (f) served through DecodeScheduler
+
+SEQ, MAX_NEW = 24, 8
+
+
+def _zoo(**kw):
+    from seldon_core_tpu.models.zoo import get_model
+
+    return get_model(
+        "mla_decoder", **SIZES, experts_held=4, first_expert=4, seq=SEQ, max_new_tokens=MAX_NEW,
+        param_dtype="float32", seed=11, **kw,
+    )
+
+
+async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles():
+    ms = _zoo()
+    fam = ms.generative["family"]
+    assert fam.name == "mla" and fam is mla.mla_family(CFG)
+    sched = ds.DecodeScheduler(
+        ms.params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4, prefix_slots=2, prefill_chunk=16,
+        kv_page_size=PS, family=fam,
+    )
+    assert len(sched.pool.state) == 1 and sched.pool.state[0].shape[-1] == CFG.row_width  # latent pages
+    assert sched.programs.attn_kernel == ""
+    sched.warmup()
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, 96, (5, SEQ)).astype(np.int32)
+    prompts[1:, :16] = prompts[0, :16]
+    oracle = np.asarray(jax.jit(ms.apply_fn)(ms.params, jnp.asarray(prompts)))
+    streamed: list = []
+    first = await sched.submit(prompts[0], cache_prefix=16, on_token=lambda t, i: streamed.append(int(t)))
+    np.testing.assert_array_equal(first, oracle[0])  # alone in 4 slots: 3 junk rows a step
+    assert streamed == oracle[0, SEQ:].tolist()  # token by token, as they were sampled
+    rest = await asyncio.gather(*(sched.submit(p) for p in prompts[1:]))
+    for got, want in zip(rest, oracle[1:]):
+        np.testing.assert_array_equal(got, want)  # prefix hits on the captured latent pages: the same greedy tokens
+    assert sched.stat_prefix_hits == 4
+    assert sched.recompiles_since_warmup() == 0
+    sched.pool.alloc.check()
+    frames = sched.flight.snapshot()
+    steps = [f for f in frames if f.busy_ns[0] == 0 and f.moe_rows]
+    assert steps
+    expert_layers, held, k = 2, 4, 4
+    for f in steps:
+        assert f.moe_rows == f.active  # junk rows (free slots) are not counted
+        assert 0 <= f.moe_local_picks <= f.moe_rows * k * expert_layers
+        assert f.moe_experts_hit <= expert_layers * held and f.moe_load_max <= f.moe_rows * expert_layers
+        assert f.moe_local_picks >= f.moe_load_max
+        # each generating slot attends over its prompt and what it has generated: SEQ + 1 .. SEQ + MAX_NEW keys
+        assert f.active * (SEQ + 1) <= f.mla_ctx_rows <= f.active * (SEQ + MAX_NEW)
+    chunked = [f for f in frames if f.busy_ns[0] > 0]
+    assert chunked and all(f.mla_ctx_rows > 0 for f in chunked)
+    assert steps[0].to_dict()["mla"] == [steps[0].mla_ctx_rows, steps[0].moe_local_picks]
+    await sched.close()
+
+
+# (g) the latent page kind; what the family does not serve is refused by name
+
+
+def test_the_pool_is_one_plane_of_latent_rows(weights):
+    d = FAM.decoder_dims(weights[jnp.float32])
+    assert (d["kv_planes"], d["kv_heads"], d["head_dim"], d["kv_layers"]) == (1, 1, CFG.row_width, 3)
+    (plane,) = FAM.paged_kv_init(weights[jnp.float32], 7, PS, jnp.bfloat16)
+    assert plane.shape == (3, 7, PS, 128) and plane.dtype == jnp.bfloat16  # 20 numbers a row, in one lane tile
+    assert mla.MLADecoderConfig(kv_rank=512, rope_dim=64).row_width == 640  # the published 576, in five
+    with pytest.raises(ValueError, match="latent"):
+        FAM.paged_kv_init(weights[jnp.float32], 7, PS, jnp.bfloat16, "int8")
+    # a copied page carries its rows in every layer (copy-on-write's primitive walks the one plane)
+    pool = (plane.at[:, 2].set(1.0),)
+    (copied,) = dec.paged_copy(pool, jnp.array([2, 0]), jnp.array([5, 0]))
+    assert bool(jnp.all(copied[:, 5] == 1.0)) and not bool(jnp.any(copied[:, 4]))
+
+
+@pytest.mark.parametrize(
+    "what", ["draft", "spec_tree", "tp", "kv_int8", "host_tier", "prefix_export", "gpt2_dims", "moe_dims", "hybrid_dims", "mla_dims"]
+)
+def test_what_the_family_does_not_serve_is_refused_by_name(what, weights):
+    params = weights[jnp.float32]
+    kw = dict(seq_len=8, max_new_tokens=4, n_slots=2, family=FAM)
+    with pytest.raises(FamilyNotServed) as e:
+        if what == "draft":
+            draft = init_decoder(seed=0, vocab=96, hidden=64, layers=1, ffn=64, max_len=64)
+            ds.DecodeScheduler(params, draft_params=draft, spec_k=2, **kw)
+        elif what == "spec_tree":
+            ds.DecodeScheduler(params, spec_tree="2,1", **kw)
+        elif what == "tp":
+            ds.DecodeScheduler(params, mesh_axes={"model": 2}, **kw)
+        elif what == "kv_int8":
+            ds.DecodeScheduler(params, kv_dtype="int8", **kw)
+        elif what == "host_tier":
+            ds.DecodeScheduler(params, kv_host_bytes=1 << 20, prefix_slots=2, **kw)
+        elif what == "prefix_export":
+            ds.DecodeScheduler(params, prefix_slots=2, **kw).export_prefix_state()
+        elif what == "gpt2_dims":
+            dec.gpt2_family.decoder_dims(params)  # not a KeyError
+        elif what == "moe_dims":
+            md.moe_family(md.MoEDecoderConfig()).decoder_dims(params)
+        elif what == "hybrid_dims":
+            hd.hybrid_family(hd.HybridDecoderConfig()).decoder_dims(params)
+        else:
+            FAM.decoder_dims(md.init_moe_decoder(md.MoEDecoderConfig(), seed=0, dtype=jnp.float32))
+    if what in ("draft", "spec_tree", "tp", "kv_int8", "host_tier", "prefix_export"):
+        assert "'mla' decoder family" in str(e.value)
+
+
+def test_the_step_attention_kernel_is_not_chosen_for_the_family(weights):
+    pool = FAM.paged_kv_init(weights[jnp.float32], 7, 16, jnp.bfloat16)
+    assert "attn_kernel" not in FAM.serves and _step_attn_kernel(FAM, pool, None, CFG.heads) == ""
+
+
+def test_the_fused_fallback_generates_through_the_same_forward(ref, weights):
+    """Without ``tpu.decode_slots`` the zoo model's apply decodes whole
+    batches greedily (``decoder.paged_greedy_generate``): the reference's
+    argmax along them."""
+    params = weights[jnp.float32]
+    ids = np.stack([_ids(6, 12), _ids(7, 12)])
+    out = np.asarray(jax.jit(lambda p, x: FAM.generate(p, x, 5))(params, jnp.asarray(ids)))
+    assert out.shape == (2, 17) and (out[:, :12] == ids).all()
+    for row in out:
+        want = _ref_logits(ref, params, row, "highest")
+        np.testing.assert_array_equal(row[12:], np.argmax(want[11:16], axis=-1))

@@ -459,6 +459,84 @@ def _family_step(family: str, one):
     return fam.fused_programs()[0], args, (1, 2), (*pool, *rec)
 
 
+def _mla_cell(one, layers: int):
+    """(family, parameters, pool) as shapes on the described chip: the
+    a.x-k1 cell's widths, experts held and vocabulary slice, the leading dense
+    layer + ``layers - 1`` expert layers, its 8192 latent pages of 16 rows."""
+    from seldon_core_tpu.models import mla_decoder as mla
+
+    cfg = mla.MLADecoderConfig(
+        vocab=20480, hidden=7168, layers=layers, heads=64, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+        v_dim=128, dense_layers=1, dense_ffn=18432, ffn=2048, experts=192, experts_held=12, first_expert=0,
+        experts_per_tok=8, n_group=8, topk_group=4, yarn_factor=32.0, yarn_original=4096,
+    )
+    fam = mla.mla_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    params = on_chip(jax.eval_shape(lambda: mla.init_mla_decoder(cfg, 0, jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 8192, 16, jnp.bfloat16)))
+    return fam, params, pool
+
+
+@pytest.mark.parametrize("program", ["step", "chunk_2_64", "chunk_64_256"])
+def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program, monkeypatch):
+    """The fourth family's fused step (64 slots) and the chunk ladder's
+    (2, 64) and (64, 256) entries ((64, 64) is no entry of the ladder: rows
+    above the first climb at the top c only; (4, 256) is the (64, 256)
+    program at fewer rows and is left to a scratch script) at the a.x-k1
+    cell's widths, the dense layer + one expert layer: the donated latent
+    plane comes back aliased and no op copies it (a 576-wide row made the
+    chip's compiler lay the plane out pages-minor and copy it twice a step:
+    ``MLADecoderConfig.row_width``); nothing the size of every slot's
+    gathered table exists in float32; the step and the 64-token chunk
+    absorb, the 256-token chunks expand block by block (or absorb a dispatch
+    of short live chunks); the grouped expert
+    products of the 256-token chunks are the Pallas kernel."""
+    from seldon_core_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    fam, params, pool = _mla_cell(one, layers=2)
+    assert len(pool) == 1 and pool[0].shape == (2, 8192, 16, 640)
+    i32, f32 = jnp.int32, jnp.float32
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    step, chunk = fam.fused_programs()
+    if program == "step":
+        n, c, fn = 64, 1, step
+        args = (arr((n, 532), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+    else:
+        n, c = (int(v) for v in program.split("_")[1:])
+        fn = chunk
+        args = (arr((n, 532), i32), arr((n, c), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32),
+                arr((n,), i32), arr((), i32), arr((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, pool, *args).compile()
+    text = compiled.as_text()
+    plane_bytes = int(np.prod(pool[0].shape)) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= plane_bytes
+    plane = re.escape("bf16[2,8192,16,640]")
+    assert not [ln for ln in text.splitlines() if re.search(r"= " + plane + r"\S* copy\(", ln)]
+    # a row's whole table is 532 pages = 8512 keys: nothing is gathered at that length, in any dtype
+    # (the walk takes 8 to 64 pages at a time), and nothing 640 lanes wide is float32
+    assert not re.findall(r"\[%d,(?:532,16|8512),[0-9,]*\]" % n, text)
+    from seldon_core_tpu.ops.mla import block_pages
+
+    bp = block_pages(n, 64, c, 16, 532)  # a block is gathered in the pool's dtype, never float32
+    assert re.findall(r"bf16\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
+    assert not re.findall(r"f32\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
+    where = "step" if program == "step" else "chunk"
+    assert re.search(r'op_name="jit\(_fused_%s\)/attn/(?:cond/branch_\d_fun/)?mla_core/' % where, text)
+    # a 256-token program absorbs too, in the branch for a dispatch whose rows' live queries are few
+    assert re.search(r"/attn/(?:cond/branch_\d_fun/)?mla_absorb/", text)
+    assert ("/mla_core/while/body/mla_expand/" in text) == (c == 256)
+    assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
+
+
 @pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
 def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, family):
     """Each family's fused step at its cell's rows and vocabulary: what runs
