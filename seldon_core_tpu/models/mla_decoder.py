@@ -28,9 +28,11 @@ What the block has, beside the three families before it:
 RMSNorm, the rotary helper, the expert forms, the sampler and the step /
 chunk wrappers are the other families' (imported, not re-typed).
 
-Not served: speculation, a decode mesh, the step attention kernel (the GPT-2
-family's), the int8 pool, the KV tiers and prefix export (``serves`` is
-empty; each refuses by name, ``decoder.require_served``).
+Served beside the plain rounds: a step that reads the latent plane in place
+(ops/mla.py ``mla_decode_attention``, where ``decode_programs.
+_step_attn_kernel`` chooses it). Not served: speculation, a decode mesh, the
+int8 pool, the KV tiers and prefix export (each refuses by name,
+``decoder.require_served``).
 """
 
 from __future__ import annotations
@@ -57,7 +59,14 @@ from seldon_core_tpu.models.decoder import (
     paged_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import SCOPE_ROPE, _rms, _rope, rope_inv_freq
-from seldon_core_tpu.ops.mla import absorb_short, expand_cheaper, mla_paged_attention
+from seldon_core_tpu.ops.mla import (
+    SCOPE_MLA_CORE,
+    absorb_short,
+    expand_cheaper,
+    kernel_runs,
+    mla_paged_attention,
+    pages_fetched,
+)
 from seldon_core_tpu.ops.moe import (
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
@@ -211,12 +220,13 @@ def init_mla_decoder(cfg: MLADecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
     return params
 
 
-def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, n_keys):
+def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, n_keys, runs, interpret):
     """One layer over the latent plane: x[n, m, d] with row i's query j at
     positions[i] + j. The new rows ``[latent | rotated key]`` scatter through
     the block tables first, attention reads them back with the cached ones
-    (write-then-read, as in every family). Returns (x, pool, counters[4]:
-    zeros for a dense layer)."""
+    (write-then-read, as in every family): through the step's kernel where
+    ``_forward`` found the dispatch to be its kind (``runs``; ``interpret``:
+    under the Pallas interpreter), else the walk. Returns (x, pool, counters[4]: zeros for a dense layer)."""
     c = cfg
     n, m, _ = x.shape
     q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]  # [n, m]
@@ -238,7 +248,7 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
         ctx = mla_paged_attention(
             q[..., : c.nope_dim], q_rope, pool[0], li, bt, q_pos, n_keys, p["kv_b"], scale=c.score_scale,
             expand=expand_cheaper(m, **sizes), short=absorb_short(**sizes),
-            live=None if counts is None else jnp.max(counts),
+            live=None if counts is None else jnp.max(counts), runs=runs, interpret=interpret,
         )
     with jax.named_scope(SCOPE_ATTN_OUT):
         x = x + ctx @ p["attn_o"].astype(x.dtype)
@@ -258,12 +268,15 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
     return x, pool, cnt
 
 
-def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
+def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None, attn_kernel=""):
     """Shared body of the paged programs, with ``moe_decoder._forward``'s
     arguments: tokens[n, m], row i's query j at positions[i] + j; ``counts``
     (chunk rounds), ``rows`` (the step's generating slots), ``pick`` (the
-    head's one query a row). Returns (logits[n, m or 1, vocab] float32,
-    hidden[n, m, d], pool, counters[5] int32: ``MLADecoder.frame_counters``)."""
+    head's one query a row). ``attn_kernel`` (static; "" | "mosaic" |
+    "interpret": ``decode_programs._step_attn_kernel``'s answer) lets a
+    dispatch of ONE query a row read the plane through ops/mla.py's kernel;
+    every other shape walks. Returns (logits[n, m or 1, vocab] float32,
+    hidden[n, m, d], pool, counters[7] int32: ``MLADecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     last = jnp.full((n,), m, positions.dtype)  # queries a row really has
@@ -275,11 +288,18 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         last = jnp.where(rows, last, 0)
     # the keys a row's last real query sees; a row nobody reads walks one block
     n_keys = jnp.where(last > 0, positions + last, 1)
+    with jax.named_scope(SCOPE_ATTN), jax.named_scope(SCOPE_MLA_CORE):
+        ps = pool[0].shape[2]
+        runs = kernel_runs(attn_kernel, m, cfg.kv_rank, bt, n_keys, ps)  # every layer's kernel walks the same tables
+        if runs is None:
+            fetched = jnp.zeros((2,), jnp.int32)  # the walk fetches nothing through the kernel
+        else:
+            fetched = pages_fetched(n_keys, runs, last > 0, ps, bt.shape[1])
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
     cnt = jnp.zeros((4,), jnp.int32)
     for li, lp in enumerate(params["layers"]):
-        x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys)
+        x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, runs, attn_kernel == "interpret")
         with jax.named_scope(SCOPE_MLP), jax.named_scope(SCOPE_MOE_COMBINE):
             cnt = cnt + c
     with jax.named_scope(SCOPE_LM_HEAD):
@@ -292,7 +312,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         # latent rows attended over are one layer's (every layer reads as many)
         cnt = cnt.at[0].set(jnp.sum(valid, dtype=jnp.int32))
         ctx_rows = jnp.sum(jnp.where(last > 0, n_keys, 0), dtype=jnp.int32)
-    return logits, x, pool, jnp.concatenate([cnt, ctx_rows[None]])
+    return logits, x, pool, jnp.concatenate([cnt, ctx_rows[None], fetched])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,10 +328,14 @@ class MLADecoder:
     # what the programs' readback carries after the tokens (FlightFrame
     # fields): the routing over the experts HELD, the picks of real rows that
     # landed on one, and the latent rows the dispatch's live rows attended
-    # over (each row's keys, summed; one layer's)
-    frame_counters = ("moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "mla_ctx_rows")
-    # nothing beside the plain rounds yet (decoder.require_served)
-    serves = frozenset()
+    # over (each row's keys, summed; one layer's), and where the step's kernel
+    # ran the pages it fetched for them and those that came in run DMAs
+    frame_counters = (
+        "moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "mla_ctx_rows",
+        "mla_pages_read", "mla_run_pages",
+    )
+    # beside the plain rounds: a step that reads the plane in place (ops/mla.py's kernel)
+    serves = frozenset({"attn_kernel"})
     state_init = None  # no recurrent state: latent pages only
 
     def decoder_dims(self, params: dict) -> dict:
@@ -329,14 +353,16 @@ class MLADecoder:
     def paged_kv_init(self, params, n_pages, page_size, dtype=jnp.float32, kv_dtype=""):
         return kv_pool_zeros(self.decoder_dims(params), n_pages, page_size, dtype, kv_dtype)
 
-    def paged_forward(self, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
-        return _forward(self.cfg, params, pool, bt, tokens, positions, counts, rows, pick)
+    def paged_forward(self, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None, attn_kernel=""):
+        return _forward(self.cfg, params, pool, bt, tokens, positions, counts, rows, pick, attn_kernel)
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
-        """This family's step and chunk bodies (``decoder.counted_programs``).
-        Cached: equal configurations share compiled programs."""
-        return counted_programs(self.paged_forward)
+        """This family's step and chunk bodies (``decoder.counted_programs``);
+        with ``attn_kernel`` both may take the kernel, and the one whose
+        dispatch has one query a row, the step, does. Cached: equal
+        configurations share compiled programs."""
+        return counted_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def generate(self, params, ids, max_new_tokens: int):
         """The fused fallback apply (``decoder.paged_greedy_generate``) over a

@@ -29,12 +29,30 @@ products go:
   the expansion and the scores of 192 queries nobody reads were 120 to 260
   ms of such a round (my chip run, PR 37; PERF.md section 6).
 
-Both walk a row's block table in blocks of pages, up to the longest live
-row's length and no further (a ``while`` loop with a traced trip count), with
-a float32 running maximum, sum and context (the online softmax): what is
-gathered at a time is one block of rows in the POOL'S dtype, ``[rows, block,
-rank + rope]``, never the tables' whole length and never float32. A block's
-float32 scores stay under ``_SCORES_BLOCK_BYTES``.
+Both are ONE algorithm, an online softmax over blocks of a row's block
+table with a float32 running maximum, sum and context, and there are two ways
+to bring a block in, picked by the dispatch's shape, the pool and the
+platform (no knob; ``serving/decode_programs._step_attn_kernel`` and
+``kernel_runs``):
+
+- THE WALK (``_walk``; blocked ``jnp``): a ``while`` loop with a traced trip
+  count over blocks of pages, up to the longest live row's length and no
+  further; what is gathered at a time is one block of rows in the POOL'S
+  dtype, ``[rows, block, rank + rope]``, never the tables' whole length and
+  never float32, and a block's float32 scores stay under
+  ``_SCORES_BLOCK_BYTES``. Every chunk program (absorbed, expanded or
+  ``absorb_short``), the CPU backend (where it is the oracle), a geometry
+  Mosaic cannot tile (``kernel_tiles``), a mesh;
+- THE KERNEL (``mla_decode_attention``; Pallas): a dispatch of ONE query a
+  row on the absorbed path, the fused decode step, where the pool is one
+  two-byte float plane of whole lane tiles and 16-row pages on one TPU. The
+  plane stays in HBM; a row's pages are fetched into VMEM once, in runs of
+  consecutive pages with one DMA a run (``page_runs``), for all heads, and
+  stop at the row's own length. The walk moved every gathered block four
+  times (gather read, write, scores, context) and took 20.4 ms alone at the
+  a.x-k1 cell's geometry where the kernel takes 7.2 (PERF.md section 6, PR
+  38). ``interpret=True`` runs it under the Pallas interpreter, for the CPU
+  backend's tests.
 """
 
 from __future__ import annotations
@@ -44,6 +62,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # device scopes, nested under the decoder's ``attn`` scope
 SCOPE_MLA_ABSORB = "mla_absorb"  # the Wuk product on the queries, the Wuv product on the context
@@ -56,6 +76,20 @@ NEG_INF = -1e30  # the other families' mask value
 # the cap of 1024 is 16 MB
 _SCORES_BLOCK_BYTES = 256 << 20
 _MIN_BLOCK_KEYS, _MAX_BLOCK_KEYS = 128, 1024
+_LANES = 128
+# The step's kernel: table entries it fetches with ONE DMA where their pages
+# are consecutive, and entries of one work item (a block: fetched, scored and
+# summed together). Picked by the kernel alone on a v5e at the a.x-k1 cell's
+# geometry, 64 rows of 8.3-8.5k keys over 7 layers (PERF.md section 6, PR 38):
+# blocks of 64 pages with runs of 8 / 16 / 32 took 7.44 / 7.21 / 7.17 ms
+# (a DMA a page 16.6 / 15.0 / 14.4; the DMAs alone 6.56 in runs, 9.7-12.0 a
+# page), runs of 32 in blocks of 32 / 64 / 128 / 256 pages 8.19 / 7.17 / 7.45 /
+# 8.22 (a block's products and softmax are one serial chain of ~0.4 us before
+# any arithmetic: short blocks pay it often, long ones compute a row's
+# half-empty last block). 16 and not 32: within the timing's noise, and a run
+# that breaks sends half as many pages to the DMA-a-page path.
+RUN_PAGES = 16
+BLOCK_PAGES = 64
 
 
 def expand_cheaper(queries: int, *, rank: int, nope: int, rope: int, v_dim: int) -> bool:
@@ -86,7 +120,7 @@ def block_pages(rows: int, heads: int, queries: int, page_size: int, pages_per_r
 
 def mla_paged_attention(
     q_nope, q_rope, plane, li: int, bt, q_pos, n_keys, kv_b,
-    *, scale: float, expand: bool, short: int = 0, live=None,
+    *, scale: float, expand: bool, short: int = 0, live=None, runs=None, interpret: bool = False,
 ):
     """Causal attention of q_nope[n, m, H, nope] / q_rope[n, m, H, rope]
     (rotated), row i's query j at absolute position q_pos[i, j], over layer
@@ -98,9 +132,14 @@ def mla_paged_attention(
     (module docstring); with it, ``short`` (static) and ``live`` (a traced
     scalar: the most queries any row of THIS dispatch really has) let a
     dispatch whose rows all have at most ``short`` absorb those and return
-    zeros for the rest. Returns the heads' outputs [n, m, H * v] in the
-    queries' dtype."""
+    zeros for the rest. ``runs`` (``kernel_runs``: not None for a dispatch
+    of ONE query a row where the program set chose the kernel) sends the
+    dispatch to the Pallas kernel instead of the walk, ``interpret`` (static)
+    under the Pallas interpreter. Returns the heads' outputs [n, m, H * v]
+    in the queries' dtype."""
     m = q_nope.shape[1]
+    if runs is not None:
+        return _absorbed_step(q_nope, q_rope, plane, li, bt, n_keys, runs, kv_b, scale, interpret)
     walk = functools.partial(_walk, plane=plane, li=li, bt=bt, n_keys=n_keys, kv_b=kv_b, scale=scale)
     if not (expand and live is not None and 0 < short < m):
         return walk(q_nope, q_rope, q_pos, expand=expand)
@@ -110,6 +149,17 @@ def mla_paged_attention(
         return jnp.pad(out, ((0, 0), (0, m - short), (0, 0)))
 
     return lax.cond(live <= short, few, lambda: walk(q_nope, q_rope, q_pos, expand=True))
+
+
+def _folded_queries(q_nope, q_rope, w, row_width: int):
+    """The absorbed path's queries against a cache row as it lies, its
+    padding lanes included: ``[Wuk_h^T q_nope_h | q_rope_h | 0]``,
+    [..., H, row width] for q_nope[..., H, nope] / q_rope[..., H, rope]."""
+    nope = q_nope.shape[-1]
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        qt = jnp.einsum("...hd,rhd->...hr", q_nope, w[:, :, :nope])
+        pad = jnp.zeros((*q_nope.shape[:-1], row_width - w.shape[0] - q_rope.shape[-1]), q_nope.dtype)
+        return jnp.concatenate([qt, q_rope, pad], axis=-1)
 
 
 def _walk(q_nope, q_rope, q_pos, *, plane, li, bt, n_keys, kv_b, scale, expand):
@@ -126,11 +176,7 @@ def _walk(q_nope, q_rope, q_pos, *, plane, li, bt, n_keys, kv_b, scale, expand):
     n_blocks = -(-bt.shape[1] // bp)
     bt = jnp.pad(bt, ((0, 0), (0, n_blocks * bp - bt.shape[1])))  # junk page 0: past every query
     if not expand:
-        with jax.named_scope(SCOPE_MLA_ABSORB):
-            qt = jnp.einsum("nmhd,rhd->nmhr", q_nope, w[:, :, :nope])
-            # against the row as it lies, its padding lanes included: [n, m, H, row width]
-            pad = jnp.zeros((n, m, heads, plane.shape[3] - rank - rope), dtype)
-            qc = jnp.concatenate([qt, q_rope, pad], axis=-1)
+        qc = _folded_queries(q_nope, q_rope, w, plane.shape[3])
 
     def block(j, carry):
         top, total, acc = carry  # [n, H, m] float32 twice, [n, H, m, rank | v] float32
@@ -170,3 +216,251 @@ def _walk(q_nope, q_rope, q_pos, *, plane, li, bt, n_keys, kv_b, scale, expand):
         return ctx.transpose(0, 2, 1, 3).reshape(n, m, heads * v_dim)
     with jax.named_scope(SCOPE_MLA_ABSORB):
         return jnp.einsum("nhmr,rhv->nmhv", ctx, w[:, :, nope:]).reshape(n, m, heads * v_dim)
+
+
+# ------------------------------------------------------------------------------
+# The step's kernel: one query a row, the latent rows read where they lie
+
+
+def kernel_tiles(row_width: int, page_size: int, dtype) -> bool:
+    """Whether Mosaic can tile ``mla_decode_attention`` over a latent plane
+    of this geometry: what ``decode_programs._step_attn_kernel`` asks before
+    it answers "mosaic" for a one-plane pool. A two-byte float (both
+    products go into the MXU as they are stored), rows of whole 128-lane
+    tiles, pages of whole sublane tiles (16 rows of a two-byte float: a page
+    is the destination of one DMA and a slice of the block the MXU takes)."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize != 2:
+        return False
+    return row_width % _LANES == 0 and page_size % 16 == 0
+
+
+def _table_blocks(pages: int) -> tuple[int, int, int]:
+    """How the kernel walks a table of ``pages`` entries: (entries a run
+    DMA takes, runs a block, blocks a table). A block is what one work item
+    fetches and computes on; small tables (the tests') shrink both."""
+    run = min(RUN_PAGES, pages)
+    block_runs = min(BLOCK_PAGES // RUN_PAGES, -(-pages // run))
+    return run, block_runs, -(-pages // (run * block_runs))
+
+
+def _pages_held(n_keys, page_size: int, pages: int):
+    """Pages of its table each row attends over: ``ceil(n_keys / page_size)``
+    (a length past the table is the table, one under a key is a key)."""
+    return -(-jnp.clip(n_keys, 1, pages * page_size) // page_size)
+
+
+def kernel_runs(kernel: str, queries: int, rank: int, bt, n_keys, page_size: int):
+    """``page_runs`` where a dispatch of ``queries`` a row takes the kernel
+    (``kernel``: ``decode_programs._step_attn_kernel``'s answer, "" |
+    "mosaic" | "interpret"): one query a row, and for Mosaic a latent of
+    whole lane tiles (the context product takes the rows' first ``rank``
+    lanes); None where it walks."""
+    if not kernel or queries != 1 or (kernel != "interpret" and rank % _LANES):
+        return None
+    return page_runs(bt, n_keys, page_size)
+
+
+def page_runs(bt, n_keys, page_size: int):
+    """Which groups of ``RUN_PAGES`` consecutive table entries the kernel
+    fetches with ONE DMA: runs[n, groups] int32 (the table's groups, padded
+    to whole blocks), 1 where the row has all ``RUN_PAGES`` pages of the
+    group and their ids are consecutive (``plane[li, first : first +
+    RUN_PAGES]`` is then contiguous in HBM and inside the plane, its last
+    page being a table entry), else 0: a DMA a page, for the pages the row
+    has. The kernel's own view of the table: bt[n, pages], n_keys[n] as
+    ``mla_paged_attention`` takes them."""
+    n, pages = bt.shape
+    run, block_runs, blocks = _table_blocks(pages)
+    groups = blocks * block_runs
+    ids = jnp.pad(bt, ((0, 0), (0, groups * run - pages))).reshape(n, groups, run)
+    consecutive = jnp.all(ids == ids[..., :1] + jnp.arange(run, dtype=bt.dtype), axis=-1)
+    held = _pages_held(n_keys, page_size, pages)
+    whole = (jnp.arange(groups, dtype=held.dtype)[None, :] + 1) * run <= held[:, None]
+    return (consecutive & whole).astype(jnp.int32)
+
+
+def pages_fetched(n_keys, runs, live, page_size: int, pages: int):
+    """int32[2]: the pages the kernel fetches for the ``live`` rows ([n]
+    bool), and those among them that come in run DMAs; one layer's. What the
+    program counts into FlightFrame ``mla_pages_read`` / ``mla_run_pages``."""
+    held = _pages_held(n_keys, page_size, pages)
+    in_runs = jnp.sum(runs, axis=1) * _table_blocks(pages)[0]
+    return jnp.sum(jnp.where(live[:, None], jnp.stack([held, in_runs], axis=1), 0), axis=0, dtype=jnp.int32)
+
+
+def _decode_kernel(
+    layer_ref, bt_ref, len_ref, run_ref,  # scalar prefetch
+    q_ref, plane_hbm,  # row i's folded queries [1, H, w]; the whole plane, left in HBM
+    o_ref,  # row i's normalised context [1, H, rank]
+    buf, top_ref, sum_ref, acc_ref, sem, cur,  # scratch
+    *, page_size: int, run: int, block_runs: int, rank: int, scale: float,
+):
+    """Grid step i is row i: its blocks of pages in turn, always with the
+    next block's rows in flight (the next ROW's first block after the last),
+    the online softmax's state in scratch. ``cur`` carries which of the two
+    buffers the row's first block was fetched into."""
+    i, n = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    block = run * block_runs
+    keys = block * page_size
+
+    def n_pages(row):
+        return (len_ref[row] + page_size - 1) // page_size
+
+    def n_blocks(row):
+        return (n_pages(row) + block - 1) // block
+
+    def copies(row, blk, b, fn):
+        """``fn`` (start or wait) on a block's DMAs into buffer b, group by
+        group: one for a run, else one a page the row has."""
+        for j in range(block_runs):
+            g = blk * block_runs + j
+            first = g * run
+
+            @pl.when(run_ref[row, g] == 1)
+            def _():
+                src = plane_hbm.at[layer, pl.ds(bt_ref[row, first], run)]
+                fn(pltpu.make_async_copy(src, buf.at[b, pl.ds(j * run, run)], sem.at[b]))
+
+            @pl.when(run_ref[row, g] == 0)
+            def _():
+                def page(k, _):
+                    fn(pltpu.make_async_copy(plane_hbm.at[layer, bt_ref[row, first + k]], buf.at[b, j * run + k], sem.at[b]))
+                    return 0
+
+                lax.fori_loop(0, jnp.clip(n_pages(row) - first, 0, run), page, 0)
+
+    def start(c):
+        c.start()
+
+    def wait(c):
+        c.wait()
+
+    @pl.when(i == 0)
+    def _():
+        # pages a block does not fetch hold what the buffer held: keep it finite
+        buf[...] = jnp.zeros_like(buf)
+        cur[0] = 0
+        copies(0, 0, 0, start)
+
+    nb, b0 = n_blocks(i), cur[0]
+    top_ref[...] = jnp.full_like(top_ref, NEG_INF)
+    sum_ref[...] = jnp.zeros_like(sum_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def one_block(blk, _):
+        b = (b0 + blk) % 2
+
+        @pl.when(blk + 1 < nb)
+        def _():
+            copies(i, blk + 1, 1 - b, start)
+
+        @pl.when((blk + 1 >= nb) & (i + 1 < n))
+        def _():
+            copies(i + 1, 0, 1 - b, start)
+
+        copies(i, blk, b, wait)
+        rows = buf[b].reshape(keys, buf.shape[-1])  # this block's rows, fetched once, used twice
+        s = lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        k_pos = blk * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        s = jnp.where(k_pos < len_ref[i], s * scale, NEG_INF)  # [H, keys]
+        top = top_ref[...]
+        new_top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
+        shrink = jnp.exp(top - new_top)
+        p = jnp.exp(s - new_top)
+        ctx = jnp.dot(p.astype(rows.dtype), rows[:, :rank], preferred_element_type=jnp.float32)
+        top_ref[...] = new_top
+        sum_ref[...] = sum_ref[...] * shrink + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * shrink + ctx
+        return 0
+
+    lax.fori_loop(0, nb, one_block, 0)
+    cur[0] = (b0 + nb) % 2
+    o_ref[0] = (acc_ref[...] / sum_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def mla_decode_attention(qc, plane, layer, bt, n_keys, runs, *, rank: int, scale: float, interpret: bool = False):
+    """The absorbed attention of ``n`` rows of ONE query each over the
+    latent plane, read in place.
+
+    qc ``[n, H, w]`` (the folded queries, ``_folded_queries``), plane
+    ``[L, P, ps, w]`` (the whole plane, left in HBM), ``layer`` the layer to
+    read, bt ``[n, pages]`` int32, n_keys ``[n]`` int32 (the leading keys of
+    its table a row attends over), runs ``page_runs(bt, n_keys, ps)``.
+    Returns the normalised context ``[n, H, rank]`` in qc's dtype: for each
+    row and head softmax(scale * qc . rows[:n_keys]) . rows[:n_keys, :rank].
+
+    Row i's table is walked in blocks of ``BLOCK_PAGES`` entries; a block's
+    rows are fetched into VMEM ONCE, each group of ``RUN_PAGES`` entries
+    with one DMA where ``runs`` says it is a run of consecutive pages, else
+    a DMA a page; they are scored against all heads on the MXU, and the
+    probabilities, cast to the plane's dtype as ``_walk`` casts them,
+    multiplied into the same rows' first ``rank`` lanes; maximum, sum and
+    context stay float32. Keys past ``n_keys`` get probability exactly 0
+    and pages past ``ceil(n_keys / ps)`` are never fetched. Nothing is
+    shared between rows.
+
+    Jitted with ``layer`` traced: a step's calls lower to Mosaic once."""
+    n, heads, w = qc.shape
+    _, _, ps, pw = plane.shape
+    pages = bt.shape[1]
+    if pw != w or qc.dtype != plane.dtype:
+        raise ValueError(f"queries {qc.dtype}{list(qc.shape)} against plane rows {plane.dtype}{list(plane.shape)}")
+    if interpret and jax.default_backend() != "cpu":
+        raise ValueError("mla_decode_attention(interpret=True) is for the CPU backend")
+    if not interpret and not (kernel_tiles(w, ps, plane.dtype) and rank % _LANES == 0):
+        raise ValueError(
+            f"mla_decode_attention cannot tile {plane.dtype} rows of {w} (latent {rank}) in pages of {ps} "
+            "for Mosaic (kernel_tiles): this geometry keeps the walk"
+        )
+    run, block_runs, blocks = _table_blocks(pages)
+    block = run * block_runs
+    kernel = functools.partial(_decode_kernel, page_size=ps, run=run, block_runs=block_runs, rank=rank, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n,),
+            in_specs=[
+                pl.BlockSpec((1, heads, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, heads, rank), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, ps, w), plane.dtype),  # the block in use and the one in flight
+                pltpu.VMEM((heads, 1), jnp.float32),  # running maximum
+                pltpu.VMEM((heads, 1), jnp.float32),  # running sum
+                pltpu.VMEM((heads, rank), jnp.float32),  # running context
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, heads, rank), qc.dtype),
+        # a row's first block is started by the row before it
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mla_decode_attention",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.pad(bt.astype(jnp.int32), ((0, 0), (0, blocks * block - pages))),
+        jnp.clip(n_keys.astype(jnp.int32), 1, pages * ps), runs.astype(jnp.int32),
+        qc, plane,
+    )
+
+
+def _absorbed_step(q_nope, q_rope, plane, li, bt, n_keys, runs, kv_b, scale, interpret):
+    """``mla_paged_attention`` for one query a row through the kernel: the
+    queries folded through ``Wuk`` and ``Wuv`` on the context outside it, as
+    ``_walk`` has them."""
+    n, nope = q_nope.shape[0], q_nope.shape[-1]
+    w = kv_b.astype(q_nope.dtype)
+    rank = w.shape[0]
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]  # the one query a row
+    qc = _folded_queries(q_nope, q_rope, w, plane.shape[3])  # [n, H, row width]
+    with jax.named_scope(SCOPE_MLA_CORE):
+        ctx = mla_decode_attention(qc, plane, li, bt, n_keys, runs, rank=rank, scale=scale, interpret=interpret)
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        return jnp.einsum("nhr,rhv->nhv", ctx, w[:, :, nope:]).reshape(n, 1, -1)

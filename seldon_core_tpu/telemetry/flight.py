@@ -395,7 +395,11 @@ class FlightFrame:
     that landed on a held expert, summed over layers (of ``moe_rows`` x top
     k x expert layers routed), and the latent cache rows the dispatches'
     live rows attended over, each row's keys summed (one layer's: every
-    layer reads as many); 0 for another family."""
+    layer reads as many); 0 for another family; ``mla_pages_read`` /
+    ``mla_run_pages`` where that family's step ran its kernel (ops/mla.py
+    ``mla_decode_attention``): the pages it fetched for the live rows, and
+    those among them that lay in runs of consecutive pages and came in ONE
+    DMA a run (one layer's); 0 where the walk ran."""
 
     __slots__ = (
         "seq", "t_ns", "mode", "active", "prefilling", "queued",
@@ -409,7 +413,7 @@ class FlightFrame:
         "sample_rows", "sample_topk_rows",
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
-        "moe_local_picks", "mla_ctx_rows",
+        "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
     )
 
     def __init__(
@@ -424,7 +428,7 @@ class FlightFrame:
         sample_rows=0, sample_topk_rows=0,
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
-        moe_local_picks=0, mla_ctx_rows=0,
+        moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -468,6 +472,8 @@ class FlightFrame:
         self.state_captures = state_captures
         self.moe_local_picks = moe_local_picks
         self.mla_ctx_rows = mla_ctx_rows
+        self.mla_pages_read = mla_pages_read
+        self.mla_run_pages = mla_run_pages
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -541,6 +547,8 @@ class FlightFrame:
             d["ssm"] = [self.ssm_rows, self.state_restores, self.state_captures]
         if self.mla_ctx_rows:
             d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
+        if self.mla_pages_read:
+            d["mla_pages"] = [self.mla_run_pages, self.mla_pages_read]
         return d
 
 

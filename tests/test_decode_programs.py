@@ -380,7 +380,7 @@ def test_the_older_families_programs_lower_to_the_text_they_had(program):
 def test_the_fourth_family_rides_the_counting_convention():
     """The latent-attention family's programs take what the sparse-expert
     family's take (pool, tables, tokens, positions, the sampler's four, rows)
-    and append their five counts to the token readback: the set needs no
+    and append their seven counts to the token readback: the set needs no
     fourth convention."""
     from seldon_core_tpu.models import mla_decoder as mla
 
@@ -388,12 +388,13 @@ def test_the_fourth_family_rides_the_counting_convention():
     params = mla.init_mla_decoder(fam.cfg, seed=0, dtype=jnp.float32)
     sched = DecodeScheduler(params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=N, kv_page_size=4, family=fam)
     progs = sched.programs
-    assert (progs.mode, progs.attn_kernel, progs._counted, progs._stateful) == ("", "", 5, False)
+    assert (progs.mode, progs.attn_kernel, progs._counted, progs._stateful) == ("", "", 7, False)
     sched.warmup()
     assert set(progs.compile_counts()) == {"step", "chunk", "copy"}
     zi, zf = np.zeros(N, np.int32), np.zeros(N, np.float32)
     out, read = progs.step(sched.pool.block_tables(), zi, zi, zf, zi, np.int32(1), np.ones(N, bool))
     toks, counted = read()
-    assert toks.shape == (N,) and counted.shape == (5,) and counted[0] == N  # both rows counted as real
+    assert toks.shape == (N,) and counted.shape == (7,) and counted[0] == N  # both rows counted as real
     assert counted[4] == N  # each attended over one latent row (position 0)
+    assert counted[5:].tolist() == [0, 0]  # the CPU backend's step walks: no page fetched by the kernel
     assert sched.recompiles_since_warmup() == 0

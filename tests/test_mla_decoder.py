@@ -16,6 +16,7 @@ import json
 import math
 import os
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -321,6 +322,126 @@ def test_the_step_gathers_blocks_in_the_pools_dtype_never_a_float32_table(weight
     assert "3x40x4x16x" not in text  # [rows, keys, heads, nope + v]: no expansion in the step
 
 
+# (c2) the step's kernel (ops/mla.py mla_decode_attention) under the Pallas interpreter
+
+
+def _kernel_tables(kind):
+    """Three rows' tables of 12 pages (runs of 2 entries, blocks of 4: six
+    groups in three blocks a row) over a plane of 40 pages."""
+    if kind == "one_run":
+        return 1 + np.arange(36, dtype=np.int32).reshape(3, 12)
+    if kind == "scattered":
+        return 1 + np.random.default_rng(3).permutation(36).astype(np.int32).reshape(3, 12)
+    return np.array(
+        [
+            [1, 2, 3, 5, 6, 7, 8, 9, 20, 21, 22, 23],  # a run that breaks inside a group; one that starts mid-group
+            [30, 10, 11, 13, 14, 16, 17, 5, 6, 33, 34, 4],  # consecutive pages only ACROSS the groups' edges: no run
+            [24, 25, 26, 27, 39, 38, 37, 36, 28, 29, 31, 32],  # runs, a descending stretch, runs
+        ],
+        np.int32,
+    )
+
+
+# lengths that end inside a page, inside a group (page 3 of 4 and 2 of 2), on a group's and on a block's edge,
+# a whole table, and a row nobody reads
+_KERNEL_LENGTHS = {"inside_a_page": (6, 21, 45), "inside_a_group": (9, 24, 40), "on_the_edges": (8, 16, 48),
+                   "a_row_nobody_reads": (1, 47, 13)}
+
+
+def _kernel_case(tables, lengths, dtype):
+    ks = jax.random.split(jax.random.key(7), 4)
+    plane = jax.random.normal(ks[0], (2, 40, PS, 128), jnp.float32)
+    plane = plane.at[..., 20:].set(0.0)  # the row's padding lanes, as the program writes them
+    bt = np.asarray(_kernel_tables(tables))
+    n_keys = np.asarray(lengths, np.int32)
+    junk, unread = np.asarray(plane).copy(), np.asarray(plane).copy()
+    for i, length in enumerate(n_keys):
+        held = -(-int(length) // PS)
+        last = bt[i, held - 1]
+        junk[:, last, length - (held - 1) * PS :] = 1e4  # past the length, inside the last page: finite junk
+        unread[:, last, length - (held - 1) * PS :] = 1e4
+        own = np.setdiff1d(bt[i, held:], np.concatenate([bt[j, : -(-int(n_keys[j]) // PS)] for j in range(3)]))
+        unread[:, own] = np.nan  # pages past the row's own: never fetched
+    q_nope = jax.random.normal(ks[1], (3, 1, 4, 8), jnp.float32).astype(dtype)
+    q_rope = jax.random.normal(ks[2], (3, 1, 4, 4), jnp.float32).astype(dtype)
+    kv_b = (jax.random.normal(ks[3], (16, 4, 16), jnp.float32) * 0.3).astype(dtype)
+    return jnp.asarray(junk, dtype), jnp.asarray(unread, dtype), jnp.asarray(bt), jnp.asarray(n_keys), q_nope, q_rope, kv_b
+
+
+@pytest.fixture()
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
+    monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
+
+
+@pytest.mark.parametrize("lengths", sorted(_KERNEL_LENGTHS))
+@pytest.mark.parametrize("tables", ["one_run", "scattered", "mixed"])
+def test_the_step_kernel_equals_the_walk_and_plain_attention_float32(small_blocks, tables, lengths):
+    """One query a row through the kernel == ``_walk(expand=False)`` ==
+    every head's keys and values expanded over the whole gathered table,
+    whatever the table's runs and wherever the lengths end; junk past a
+    length inside its page and NaNs in pages past it never reach the output."""
+    plane, unread, bt, n_keys, q_nope, q_rope, kv_b = _kernel_case(tables, _KERNEL_LENGTHS[lengths], jnp.float32)
+    q_pos = (n_keys - 1)[:, None]
+    runs = mla_ops.page_runs(bt, n_keys, PS)
+    args = dict(scale=0.3, expand=False)
+    got = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 1, bt, q_pos, n_keys, kv_b, runs=runs, interpret=True, **args)
+    walked = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 1, bt, q_pos, n_keys, kv_b, **args)
+    plain = _plain_attention(plane, 1, bt, q_nope, q_rope, q_pos, kv_b, 16, 0.3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(walked), atol=2e-6)
+    np.testing.assert_allclose(np.asarray(got), plain, atol=2e-6)
+    again = mla_ops.mla_paged_attention(q_nope, q_rope, unread, 1, bt, q_pos, n_keys, kv_b, runs=runs, interpret=True, **args)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+@pytest.mark.parametrize("tables", ["one_run", "scattered", "mixed"])
+def test_the_step_kernel_equals_the_walk_bfloat16(small_blocks, tables):
+    """At the pool's bfloat16 the kernel and the walk round alike (bfloat16
+    operands, float32 sums, the probabilities cast before the context
+    product): they differ by the order of the blocks' sums alone, and both
+    lie within bfloat16's tolerance of float32 attention."""
+    plane, _unread, bt, n_keys, q_nope, q_rope, kv_b = _kernel_case(tables, (6, 24, 45), jnp.bfloat16)
+    q_pos = (n_keys - 1)[:, None]
+    runs = mla_ops.page_runs(bt, n_keys, PS)
+    args = dict(scale=0.3, expand=False)
+    got = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 0, bt, q_pos, n_keys, kv_b, runs=runs, interpret=True, **args)
+    walked = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 0, bt, q_pos, n_keys, kv_b, **args)
+    assert got.dtype == walked.dtype == jnp.bfloat16
+    f32 = [a.astype(jnp.float32) for a in (q_nope, q_rope, plane, kv_b)]
+    plain = _plain_attention(f32[2], 0, bt, f32[0], f32[1], q_pos, f32[3], 16, 0.3)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(walked, np.float32), atol=2e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), plain, atol=4e-2)
+
+
+def test_page_runs_flags_whole_groups_of_consecutive_pages_a_row_has(small_blocks):
+    bt = jnp.asarray(_kernel_tables("mixed"))
+    n_keys = jnp.array([48, 45, 17], jnp.int32)  # 12, 12 and 5 pages
+    runs = np.asarray(mla_ops.page_runs(bt, n_keys, PS))
+    assert runs.tolist() == [[1, 0, 1, 1, 1, 1], [0, 0, 0, 0, 0, 0], [1, 1, 0, 0, 0, 0]]
+    every, last_two = jnp.array([True, True, True]), jnp.array([False, True, True])
+    assert np.asarray(mla_ops.pages_fetched(n_keys, jnp.asarray(runs), every, PS, 12)).tolist() == [12 + 12 + 5, 10 + 0 + 4]
+    assert np.asarray(mla_ops.pages_fetched(n_keys, jnp.asarray(runs), last_two, PS, 12)).tolist() == [12 + 5, 4]
+    # a length past the table is the table; one under a page is a page
+    none = jnp.zeros((3, 6), jnp.int32)
+    assert np.asarray(mla_ops.pages_fetched(jnp.array([500, 0, 1]), none, every, PS, 12)).tolist() == [12 + 1 + 1, 0]
+
+
+def test_only_one_query_a_row_takes_the_kernel(small_blocks):
+    """Inside a program the dispatch's shape decides: ``kernel_runs`` gives
+    the kernel its runs for one query a row, and None (the walk) for a chunk
+    of several, for no kernel chosen, and under Mosaic for a latent that is
+    not whole lane tiles."""
+    _plane, bt, *_ = _attention_case(2)
+    n_keys = jnp.array([5, 22, 45], jnp.int32)
+    want = np.asarray(mla_ops.page_runs(bt, n_keys, PS))
+    for kernel, queries, rank, taken in [("interpret", 1, 16, True), ("mosaic", 1, 512, True), ("mosaic", 1, 16, False),
+                                         ("interpret", 5, 16, False), ("mosaic", 64, 512, False), ("", 1, 512, False)]:
+        got = mla_ops.kernel_runs(kernel, queries, rank, bt, n_keys, PS)
+        assert (got is not None) == taken
+        if taken:
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # (d) the router against a literal transcription; the held share of the expert layer
 
 
@@ -460,7 +581,7 @@ async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles()
         kv_page_size=PS, family=fam,
     )
     assert len(sched.pool.state) == 1 and sched.pool.state[0].shape[-1] == CFG.row_width  # latent pages
-    assert sched.programs.attn_kernel == ""
+    assert sched.programs.attn_kernel == ""  # the CPU backend: the walk, which fetches nothing through the kernel
     sched.warmup()
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, 96, (5, SEQ)).astype(np.int32)
@@ -491,6 +612,98 @@ async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles()
     assert chunked and all(f.mla_ctx_rows > 0 for f in chunked)
     assert steps[0].to_dict()["mla"] == [steps[0].mla_ctx_rows, steps[0].moe_local_picks]
     await sched.close()
+
+
+async def test_scheduler_with_the_step_kernel_serves_the_same_tokens_and_counts_its_pages(monkeypatch):
+    """With the ONE place of choice answering "interpret" (the chip's answer
+    is "mosaic"; the interpreter is the CPU's way to run the same kernel) the
+    scheduler serves the oracle's greedy tokens, and each step round's frame
+    carries the pages the kernel fetched for the generating slots: ceil(keys
+    / page size) each, and of those the ones in whole runs of consecutive
+    pages."""
+    from seldon_core_tpu.serving import decode_programs as dp
+
+    monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
+    monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
+    monkeypatch.setattr(dp, "_step_attn_kernel", lambda family, pool_state, mesh, heads: "interpret")
+    ms = _zoo()
+    fam = ms.generative["family"]
+    sched = ds.DecodeScheduler(
+        ms.params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4, prefix_slots=2, prefill_chunk=16,
+        kv_page_size=PS, family=fam,
+    )
+    assert sched.programs.attn_kernel == "interpret"
+    sched.warmup()
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, 96, (3, SEQ)).astype(np.int32)
+    oracle = np.asarray(jax.jit(ms.apply_fn)(ms.params, jnp.asarray(prompts)))
+    first = await sched.submit(prompts[0])  # alone: pages 1.. in the order the fresh pool hands them out
+    np.testing.assert_array_equal(first, oracle[0])
+    rest = await asyncio.gather(*(sched.submit(p) for p in prompts[1:]))
+    for got, want in zip(rest, oracle[1:]):
+        np.testing.assert_array_equal(got, want)
+    assert sched.recompiles_since_warmup() == 0
+    frames = sched.flight.snapshot()
+    steps = [f for f in frames if f.busy_ns[0] == 0 and f.moe_rows]
+    assert steps and all(f.mla_pages_read for f in steps)
+    for f in steps:
+        # each generating slot has SEQ + 1 .. SEQ + MAX_NEW keys: 7 or 8 pages of 4, at most the 6 or 8 of them in runs of 2
+        assert 7 * f.active <= f.mla_pages_read <= 8 * f.active
+        if f.active == 1:
+            assert f.mla_pages_read == -(-f.mla_ctx_rows // PS)
+        assert 0 <= f.mla_run_pages <= f.mla_pages_read and f.mla_run_pages % 2 == 0
+    alone = [f for f in steps if f.active == 1][:MAX_NEW - 1]
+    # the first request had the fresh pool to itself: pages 1, 2, 3 .. in order, every whole group a run
+    assert [f.mla_run_pages for f in alone] == [2 * (-(-f.mla_ctx_rows // PS) // 2) for f in alone]
+    assert alone[0].to_dict()["mla_pages"] == [alone[0].mla_run_pages, alone[0].mla_pages_read]
+    chunked = [f for f in frames if f.busy_ns[0] > 0 and f.busy_ns[1] == 0]
+    assert chunked and not any(f.mla_pages_read for f in chunked)  # a chunk walks: nothing fetched by the kernel
+    await sched.close()
+
+
+def test_the_step_program_counts_the_pages_of_a_table_built_by_hand(weights, monkeypatch):
+    """The two counts ride the token readback after ``mla_ctx_rows``: the
+    pages of the LIVE rows (a row nobody reads fetches its junk page and is
+    not counted), and those of them in groups that are whole runs."""
+    monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
+    monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
+    params = weights[jnp.float32]
+    pool = FAM.paged_kv_init(params, 21, PS, jnp.float32)
+    step, _chunk = FAM.fused_programs("interpret")
+    bt = jnp.array([[1, 2, 3, 4, 5, 6, 7, 8, 9, 10], [11, 12, 14, 13, 15, 16, 17, 19, 0, 0], [20, 0, 0, 0, 0, 0, 0, 0, 0, 0]], jnp.int32)
+    positions = jnp.array([30, 22, 9], jnp.int32)  # 31, 23 and 10 keys: 8, 6 and 3 pages
+    zi, zf = jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.float32)
+    out, _pool = jax.jit(step)(params, pool, bt, zi, positions, zf, zi, jnp.int32(0), jnp.int32(0), jnp.array([True, True, False]))
+    counted = np.asarray(out)[3:]
+    assert len(counted) == len(FAM.frame_counters) == 7
+    named = dict(zip(FAM.frame_counters, counted.tolist()))
+    assert named["mla_ctx_rows"] == 31 + 23
+    assert named["mla_pages_read"] == 8 + 6
+    assert named["mla_run_pages"] == 8 + 4  # row 0: four whole runs; row 1: (11, 12) and (15, 16), not (14, 13)
+    walked, _pool = jax.jit(FAM.fused_programs()[0])(
+        params, FAM.paged_kv_init(params, 21, PS, jnp.float32), bt, zi, positions, zf, zi, jnp.int32(0), jnp.int32(0),
+        jnp.array([True, True, False]))
+    assert np.asarray(walked)[3:].tolist()[-2:] == [0, 0]  # the walk fetched nothing through the kernel
+    np.testing.assert_array_equal(np.asarray(walked)[:8], np.asarray(out)[:8])  # the same tokens and the same other counts
+
+
+def test_the_run_pages_reader_reads_the_frames_and_gives_none_without_the_fields():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == "mla_run_pages_pct")
+    assert entry == {"name": "mla_run_pages_pct", "unit": "%", "better": "higher", "source": "program_counter",
+                     "layer": "kernels", "moves": "itl_p95_ms", "workloads": ["a.x-k1.doc-qa-closed-64"]}
+    reader = cells.load_module(ROOT, bench, "layer_metrics", "mla_run_pages_pct")
+
+    def frame(**kw):
+        return types.SimpleNamespace(mode="plain", busy_ns=(0, 5), **kw)
+
+    step = dict(mla_ctx_rows=500, moe_rows=2)
+    assert reader.read({"frames": [frame(**step, mla_pages_read=40, mla_run_pages=32), frame(**step, mla_pages_read=60, mla_run_pages=64 - 8)]}) == 88.0
+    assert reader.read({"frames": [frame(**step)]}) is None  # the parent's frames: no such field
+    assert reader.read({"frames": [frame(**step, mla_pages_read=0, mla_run_pages=0)]}) is None  # the walk ran
+    assert reader.read({"frames": [frame(moe_rows=3)]}) is None  # another family's frames
+    assert reader.read({"frames": []}) is None and reader.read({}) is None
 
 
 # (g) the latent page kind; what the family does not serve is refused by name
@@ -542,9 +755,32 @@ def test_what_the_family_does_not_serve_is_refused_by_name(what, weights):
         assert "'mla' decoder family" in str(e.value)
 
 
-def test_the_step_attention_kernel_is_not_chosen_for_the_family(weights):
+@pytest.mark.parametrize(
+    "platform, lanes, page, dtype, mesh, want",
+    [
+        ("tpu", 640, 16, jnp.bfloat16, None, "mosaic"),  # the a.x-k1 cell's pool
+        ("tpu", 128, 32, jnp.bfloat16, None, "mosaic"),
+        ("cpu", 640, 16, jnp.bfloat16, None, ""),  # the CPU backend: the walk is the oracle
+        ("tpu", 640, 16, jnp.bfloat16, "a mesh", ""),
+        ("tpu", 576, 16, jnp.bfloat16, None, ""),  # the published row, not whole lane tiles
+        ("tpu", 640, 8, jnp.bfloat16, None, ""),  # a page under a two-byte float's sublane tile
+        ("tpu", 640, 16, jnp.float32, None, ""),  # not a two-byte float
+        ("tpu", 640, 16, jnp.int8, None, ""),
+    ],
+)
+def test_the_step_attention_kernel_is_chosen_where_the_latent_plane_tiles(platform, lanes, page, dtype, mesh, want):
+    """``_step_attn_kernel`` for this family: its kernel for ONE plane of a
+    two-byte float in whole lane tiles and pages of 16 rows or more, on one
+    TPU device and no mesh; the walk everywhere else."""
+    device = types.SimpleNamespace(platform=platform)
+    plane = types.SimpleNamespace(shape=(3, 7, page, lanes), dtype=jnp.dtype(dtype), sharding=types.SimpleNamespace(device_set=[device]))
+    assert "attn_kernel" in FAM.serves
+    assert _step_attn_kernel(FAM, (plane,), mesh, CFG.heads) == want
+
+
+def test_the_cpu_backends_pool_keeps_the_walk(weights):
     pool = FAM.paged_kv_init(weights[jnp.float32], 7, 16, jnp.bfloat16)
-    assert "attn_kernel" not in FAM.serves and _step_attn_kernel(FAM, pool, None, CFG.heads) == ""
+    assert mla_ops.kernel_tiles(128, 16, jnp.bfloat16) and _step_attn_kernel(FAM, pool, None, CFG.heads) == ""
 
 
 def test_the_fused_fallback_generates_through_the_same_forward(ref, weights):

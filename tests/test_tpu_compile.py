@@ -241,24 +241,54 @@ def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo)
 
 
 @pytest.mark.parametrize(
-    "hidden, page_size, want",
+    "pool_kind, width, page_size, want",
     [
-        (1280, 16, "mosaic"),  # the gpt2-large cells
-        (256, 8, "mosaic"),  # chip_smoke's width, the smallest page Mosaic takes
-        (1600, 16, ""),  # gpt2-xl: 25 heads of 64, a row that is not whole 128-lane tiles
-        (1280, 4, ""),  # a page under one sublane tile
+        ("kv", 1280, 16, "mosaic"),  # the gpt2-large cells
+        ("kv", 256, 8, "mosaic"),  # chip_smoke's width, the smallest page Mosaic takes
+        ("kv", 1600, 16, ""),  # gpt2-xl: 25 heads of 64, a row that is not whole 128-lane tiles
+        ("kv", 1280, 4, ""),  # a page under one sublane tile
+        ("latent", 640, 16, "mosaic"),  # the a.x-k1 cell: 512 + 64 in five lane tiles
+        ("latent", 256, 32, "mosaic"),  # a latent of one lane tile, two sublane tiles a page
+        ("latent", 576, 16, ""),  # the published row as it is: not whole lane tiles
+        ("latent", 640, 8, ""),  # a page under a two-byte float's sublane tile
     ],
 )
-def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, hidden, page_size, want):
+def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, width, page_size, want):
     """``_step_attn_kernel`` on a described TPU: the kernel where Mosaic can
-    tile the pool's rows and pages, the gather path where it cannot — and
-    the step it chose compiles either way. Asking for the kernel at a
-    refused geometry is a named error before Mosaic sees it."""
+    tile the pool's rows and pages, the gather path (the latent family: the
+    walk) where it cannot — and what it chose compiles either way. Asking
+    for the kernel at a refused geometry is a named error before Mosaic sees
+    it. ``kv``: the GPT-2 family's two-component pool of ``width`` = hidden
+    and the whole step; ``latent``: the latent family's one bfloat16 plane of
+    ``width`` lanes and its kernel alone (the whole step at the cell's widths
+    is ``test_latent_family_programs_compile_in_place_at_a_x_k1_widths``)."""
     from seldon_core_tpu.models.decoder import decoder_dims, init_decoder
     from seldon_core_tpu.models.decoder import gpt2_family
     from seldon_core_tpu.serving.decode_programs import _step_attn_kernel
 
     one = SingleDeviceSharding(topo.devices[0])
+    if pool_kind == "latent":
+        from seldon_core_tpu.models import mla_decoder as mla
+        from seldon_core_tpu.ops.mla import mla_decode_attention, page_runs
+
+        def arr(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        plane = arr((2, 256, page_size, width), jnp.bfloat16)
+        assert _step_attn_kernel(mla.mla_family(mla.MLADecoderConfig()), (plane,), None, 16) == want
+
+        def attend(qc, plane, bt, n_keys):
+            runs = page_runs(bt, n_keys, page_size)
+            return mla_decode_attention(qc, plane, 1, bt, n_keys, runs, rank=width - 128, scale=0.1)
+
+        shapes = (arr((4, 16, width), jnp.bfloat16), plane, arr((4, 40), jnp.int32), arr((4,), jnp.int32))
+        if want:
+            assert "tpu_custom_call" in jax.jit(attend).lower(*shapes).compile().as_text()
+        else:
+            with pytest.raises(ValueError, match="kernel_tiles"):
+                jax.jit(attend).lower(*shapes)
+        return
+    hidden = width
     params = jax.eval_shape(
         lambda: init_decoder(0, vocab=1024, hidden=hidden, layers=2, ffn=4 * hidden, max_len=256)
     )
@@ -486,15 +516,21 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     (2, 64) and (64, 256) entries ((64, 64) is no entry of the ladder: rows
     above the first climb at the top c only; (4, 256) is the (64, 256)
     program at fewer rows and is left to a scratch script) at the a.x-k1
-    cell's widths, the dense layer + one expert layer: the donated latent
-    plane comes back aliased and no op copies it (a 576-wide row made the
-    chip's compiler lay the plane out pages-minor and copy it twice a step:
-    ``MLADecoderConfig.row_width``); nothing the size of every slot's
-    gathered table exists in float32; the step and the 64-token chunk
-    absorb, the 256-token chunks expand block by block (or absorb a dispatch
-    of short live chunks); the grouped expert
-    products of the 256-token chunks are the Pallas kernel."""
+    cell's widths, the dense layer + one expert layer, as the program set
+    builds them on a TPU (``_step_attn_kernel`` answers "mosaic" for the
+    cell's plane): the donated latent plane comes back aliased and no op
+    copies it (a 576-wide row made the chip's compiler lay the plane out
+    pages-minor and copy it twice a step: ``MLADecoderConfig.row_width``);
+    nothing the size of every slot's gathered table exists in float32.
+    The STEP reads the plane through ops/mla.py's kernel: one Mosaic call a
+    layer under ``attn/mla_core``, no gathered block and no ``while`` there.
+    The chunks walk whatever the choice: the 64-token chunk absorbs, the
+    256-token chunks expand block by block (or absorb a dispatch of short
+    live chunks); the grouped expert products of the 256-token chunks are
+    the Pallas kernel."""
     from seldon_core_tpu.ops import moe
+    from seldon_core_tpu.ops.mla import block_pages
+    from seldon_core_tpu.serving.decode_programs import _step_attn_kernel
 
     monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     one = SingleDeviceSharding(topo.devices[0])
@@ -505,7 +541,9 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    step, chunk = fam.fused_programs()
+    chosen = _step_attn_kernel(fam, pool, None, 64)
+    assert chosen == "mosaic"
+    step, chunk = fam.fused_programs(chosen)
     if program == "step":
         n, c, fn = 64, 1, step
         args = (arr((n, 532), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
@@ -524,15 +562,22 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     # a row's whole table is 532 pages = 8512 keys: nothing is gathered at that length, in any dtype
     # (the walk takes 8 to 64 pages at a time), and nothing 640 lanes wide is float32
     assert not re.findall(r"\[%d,(?:532,16|8512),[0-9,]*\]" % n, text)
-    from seldon_core_tpu.ops.mla import block_pages
-
-    bp = block_pages(n, 64, c, 16, 532)  # a block is gathered in the pool's dtype, never float32
-    assert re.findall(r"bf16\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
-    assert not re.findall(r"f32\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
     where = "step" if program == "step" else "chunk"
     assert re.search(r'op_name="jit\(_fused_%s\)/attn/(?:cond/branch_\d_fun/)?mla_core/' % where, text)
     # a 256-token program absorbs too, in the branch for a dispatch whose rows' live queries are few
     assert re.search(r"/attn/(?:cond/branch_\d_fun/)?mla_absorb/", text)
+    kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/attn/mla_core/" in ln]
+    bp = block_pages(n, 64, c, 16, 532)  # the walk's block: gathered in the pool's dtype, never float32
+    gathered = re.findall(r"bf16\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
+    if program == "step":
+        assert len(kernels) == 2  # a kernel call a layer: Mosaic, not the interpreter
+        # no block of pages is gathered, whatever its length, and nothing walks
+        assert not gathered and not re.findall(r"bf16\[%d,\d+,16,640\]" % n, text) and "/mla_core/while" not in text
+        assert text.count("tpu_custom_call") == 2  # 64 rows: the masked expert form, no grouped kernel
+        return
+    assert not kernels  # a chunk's queries are many a row: the walk
+    assert gathered
+    assert not re.findall(r"f32\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
     assert ("/mla_core/while/body/mla_expand/" in text) == (c == 256)
     assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
 
