@@ -398,7 +398,7 @@ class _EnqueueSpan:
         self.family = family
 
     def __enter__(self):
-        self.ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
+        self.ann = flight_mod.annotate(ANN_ENQUEUE[self.family], **self.d.stats)
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -427,20 +427,33 @@ class _Dispatch:
     one of two ways: ``with d.enqueue():`` round the call on the loop, the
     overlap window, then ``await d.readback(read)`` (the pipelined round);
     or ``await d.run(fn)``, which makes call, mark and read in one piece
-    off the loop (the serial round, the chunk round, the copy ladder)."""
+    off the loop (the serial round, the chunk round, the copy ladder).
 
-    __slots__ = ("s", "family", "t0", "carved", "ann")
+    Its ``ANN_DISPATCH`` annotation and the ``ANN_ENQUEUE`` /
+    ``ANN_READBACK`` under it say WHICH dispatch they are (``stats``):
+    ``seq``, the scheduler's dispatch serial (monotonic over all families),
+    ``round``, the index the round's frame will commit under, and what the
+    call site noted through ``DecodeScheduler._dispatch(family, **stats)``
+    (a chunk's ``rows`` / ``c`` / ``live``, a step's ``rows`` / ``live``).
+    With no profiler session ``flight.annotate`` drops them."""
+
+    __slots__ = ("s", "family", "t0", "carved", "ann", "noted", "stats")
 
     def __init__(self, sched: "DecodeScheduler", family: int):
         self.s = sched
         self.family = family
         self.carved = 0
+        self.noted: dict = {}  # the call site's stats for the NEXT entry
+        self.stats: dict = {}  # the open dispatch's
 
     def __enter__(self):
         s = self.s
         s._rb_mark_ns = 0
         self.carved = 0
-        self.ann = flight_mod.annotate(ANN_DISPATCH[self.family])
+        s._dispatch_seq += 1
+        self.stats = {"seq": s._dispatch_seq, "round": s.flight.rounds, **self.noted}
+        self.noted = {}
+        self.ann = flight_mod.annotate(ANN_DISPATCH[self.family], **self.stats)
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -466,9 +479,10 @@ class _Dispatch:
         ground-truth device wall. ``fn`` returning None reads nothing back:
         the whole call counts as enqueue (the copy ladder)."""
         s = self.s
+        stats = self.stats
 
         def call():
-            ann = flight_mod.annotate(ANN_ENQUEUE[self.family])
+            ann = flight_mod.annotate(ANN_ENQUEUE[self.family], **stats)
             try:
                 queued = fn()
                 if queued is None:
@@ -478,7 +492,7 @@ class _Dispatch:
                     jax.block_until_ready(out)
                 s._rb_mark_ns = time.perf_counter_ns()
                 ann.__exit__(None, None, None)
-                ann = flight_mod.annotate(ANN_READBACK[self.family])
+                ann = flight_mod.annotate(ANN_READBACK[self.family], **stats)
                 return read()
             finally:
                 ann.__exit__(None, None, None)
@@ -498,9 +512,10 @@ class _Dispatch:
         (the pipelined rounds): marks, then runs ``fn`` through
         ``_device_call`` under ``ANN_READBACK``."""
         self.s._rb_mark_ns = time.perf_counter_ns()
+        stats = self.stats
 
         def call():
-            ann = flight_mod.annotate(ANN_READBACK[self.family])
+            ann = flight_mod.annotate(ANN_READBACK[self.family], **stats)
             try:
                 return fn()
             finally:
@@ -1189,6 +1204,15 @@ class DecodeScheduler:
         # flight kill switch (disabled timer = shared no-op handles).
         self._phases = PhaseTimer(enabled=self.flight.enabled)
         self._dispatches = tuple(_Dispatch(self, f) for f in range(len(ANN_DISPATCH)))
+        self._dispatch_seq = 0  # dispatches entered, all families: the annotations' ``seq``
+        # marked submits that reached the queue and their time on the loop
+        # since the request's bytes (flight.Ingress): running totals, written
+        # by submit alone; a frame carries what they rose by since the last
+        # commit (the marks below, written by _commit_round alone). Not among
+        # the _rb_* set: a submit lands between rounds too, and an idle wait's
+        # _round_reset must not drop the one that woke it
+        self.stat_ingress_ns = self.stat_ingress_requests = 0
+        self._ingress_committed = (0, 0)
         self._round_ann = None  # the open ANN_ROUND trace annotation
         # ENGINE_FLIGHT_SYNC_TIMING=on: block on every dispatch so the
         # per-family flight columns are ground-truth device wall
@@ -1480,6 +1504,7 @@ class DecodeScheduler:
         prefill_chunk: int | None = None,
         kv_tier: str | None = None,
         on_token: OnToken | None = None,
+        ingress: "flight_mod.Ingress | None" = None,
         _slo_sink=None,
         _replay_tokens=None,
     ) -> np.ndarray:
@@ -1496,7 +1521,10 @@ class DecodeScheduler:
         tier is disabled. ``_replay_tokens`` (fleet migration only) is the
         token prefix a dead replica already emitted: those positions are
         teacher-forced and not re-streamed, so the resumed request is
-        bit-identical to an uninterrupted greedy run."""
+        bit-identical to an uninterrupted greedy run. ``ingress`` is the
+        mark the serving layer made when it had the request's bytes in hand
+        (``flight.Ingress``): its time to this request's place in the queue
+        goes into the frame of the round it lands in."""
         if self._closed:
             raise APIException(
                 ErrorCode.ENGINE_MICROSERVICE_ERROR, "decode scheduler closed"
@@ -1576,6 +1604,10 @@ class DecodeScheduler:
         if self.queue_timeout_s > 0:
             seq.deadline = seq.t_enqueued + self.queue_timeout_s
         self._waiting.append(seq)
+        ns = ingress.done() if ingress is not None else None
+        if ns is not None:
+            self.stat_ingress_ns += ns
+            self.stat_ingress_requests += 1
         self._ensure_loop()
         self._wake.set()
         return await seq.future
@@ -2122,7 +2154,7 @@ class DecodeScheduler:
         self._rb_attn_pages = (0, 0)
         # rows the round's chunk dispatches computed, and the prefilling
         # slots among them
-        self._rb_chunk_rows = self._rb_chunk_rows_live = 0
+        self._rb_chunk_rows = self._rb_chunk_rows_live = self._rb_chunk_c = 0
         # rows of the round's dispatches that asked the sampler for a draw,
         # and those among them that asked for top_k (_count_sampling)
         self._rb_sample_rows = self._rb_sample_topk_rows = 0
@@ -2168,10 +2200,16 @@ class DecodeScheduler:
         phase across a device dispatch: busy time is _dispatch's."""
         return self._phases.phase(p)
 
-    def _dispatch(self, family: int) -> _Dispatch:
+    def _dispatch(self, family: int, **stats) -> _Dispatch:
         """The ``with``-handle for one dispatch of a flight F_* family:
-        THE timing-and-naming point of every dispatch (``_Dispatch``)."""
-        return self._dispatches[family]
+        THE timing-and-naming point of every dispatch (``_Dispatch``).
+        ``stats`` (integers the call site holds) ride the family's next
+        dispatch's trace annotations; ``_timed_call`` enters the handle
+        itself, so its callers note theirs here first."""
+        d = self._dispatches[family]
+        if stats:
+            d.noted = stats
+        return d
 
     async def _timed_call(self, family: int, fn):
         """``fn`` (a program set call, or the copy ladder) through
@@ -2226,6 +2264,7 @@ class DecodeScheduler:
                 # the kill switch removes the whole frame cost (pool snapshot,
                 # slot scan, frame object), not just the ring store
                 snap = self.pool.alloc.snapshot()
+                ingress = (self.stat_ingress_ns, self.stat_ingress_requests)
                 prefilling = sum(
                     1 for s in self._slots if s is not None and s.prefilling
                 )
@@ -2245,6 +2284,9 @@ class DecodeScheduler:
                         self._rb_sample_rows, self._rb_sample_topk_rows,
                         state_restores=self._rb_state_restores,
                         state_captures=self._rb_state_captures,
+                        chunk_c=self._rb_chunk_c,
+                        ingress_ns=ingress[0] - self._ingress_committed[0],
+                        ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
                             dict(zip(self._frame_counters, self._rb_counts.tolist()))
                             if self._frame_counters
@@ -2252,6 +2294,7 @@ class DecodeScheduler:
                         ),
                     )
                 )
+                self._ingress_committed = ingress
                 if self.spec_enabled:
                     # adaptive-speculation state for /decode/health: the tuned
                     # shape, the controller's EWMA, and the effective depth
@@ -2775,6 +2818,9 @@ class DecodeScheduler:
                 )
         tick = self._next_tick()
         t0 = telemetry.now_ns()
+        # which chunk_buckets entry the dispatch is, for a trace and the frame
+        self._rb_chunk_c = ids.shape[1]
+        self._dispatch(F_CHUNK, rows=len(slots), c=ids.shape[1], live=len(rows))
         toks, counted = await self._timed_call(
             F_CHUNK,
             lambda: self.programs.chunk(bt, ids, pos, counts, temps, topks, tick, state_rows),
@@ -2994,7 +3040,7 @@ class DecodeScheduler:
         def step():
             return self.programs.step(bt, toks, pos, temps, topks, tick, rows)
 
-        with self._dispatch(F_STEP) as d:
+        with self._dispatch(F_STEP, rows=self.n_slots, live=int(np.count_nonzero(rows))) as d:
             self._rb_active = self.active  # dispatch-time occupancy
             if self._pipeline_on():
                 with d.enqueue():

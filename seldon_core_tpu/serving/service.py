@@ -317,6 +317,7 @@ class PredictionService:
         *,
         wire_npy: bool = False,
         traceparent: str | None = None,
+        ingress=None,
     ):
         """Per-token streaming predict for generative deployments: an async
         generator of JSON-able events —
@@ -325,12 +326,19 @@ class PredictionService:
         as the terminal event. Without a decode scheduler the terminal
         event carries the buffered predict()'s ids (the endpoint stays
         functional for whole-batch generative deployments; gen_lens is
-        present only when the response pipeline computed it)."""
+        present only when the response pipeline computed it). ``ingress``
+        is the wire layer's mark from before it parsed the body
+        (``telemetry.flight.Ingress``); the scheduler books its time to the
+        queue into the round's flight frame."""
         import asyncio
 
         import numpy as np
 
+        from seldon_core_tpu.telemetry.flight import Ingress
+
         start = time.perf_counter()
+        if ingress is None:
+            ingress = Ingress()
         # same binary-wire gate as predict(): an EXPLICIT application/x-npy
         # declaration (wire_npy) is honored even when sniffing is off
         npy_requested = wire_npy or (self.decode_npy and is_npy(msg.bin_data))
@@ -348,6 +356,7 @@ class PredictionService:
         puid = msg.meta.puid
         sched = self.decode_scheduler
         if sched is None:
+            ingress.done()  # no scheduler, no round to book it to
             out = await self.predict(msg, traceparent=traceparent)
             arr = out.array
             ev = {
@@ -397,7 +406,7 @@ class PredictionService:
                 # sibling rows decoding detached with unretrieved errors)
                 outs = await asyncio.gather(
                     *(
-                        sched.submit(row, **overrides, on_token=on_token(i))
+                        sched.submit(row, **overrides, on_token=on_token(i), ingress=ingress)
                         for i, row in enumerate(rows)
                     ),
                     return_exceptions=True,
@@ -431,6 +440,7 @@ class PredictionService:
             raise
         finally:
             runner.cancel()
+            ingress.done()  # a request that never reached a submit
             self.tracer.finish_request(buf, troot, ttoken, error=trace_err)
             status = 200
             if isinstance(trace_err, APIException):

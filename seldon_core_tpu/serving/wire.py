@@ -27,6 +27,7 @@ from seldon_core_tpu.core.codec_json import (
 from seldon_core_tpu.core.codec_npy import is_npy
 from seldon_core_tpu.core.errors import APIException, ErrorCode
 from seldon_core_tpu.core.message import SeldonMessage
+from seldon_core_tpu.telemetry.flight import Ingress
 
 log = logging.getLogger(__name__)
 
@@ -239,6 +240,7 @@ async def engine_predictions_stream(service, req: WireRequest):
     The FIRST event is awaited before the response head is committed, so
     validation errors still come back as ordinary status-JSON failures;
     errors after streaming began are sent as a terminal error event."""
+    ingress = Ingress()  # the body is still bytes: its parse is ingress
     try:
         ctype = req.content_type
         kind = classify_binary_bytes(
@@ -251,12 +253,14 @@ async def engine_predictions_stream(service, req: WireRequest):
         else:
             msg = message_from_dict(payload_obj(req, ErrorCode.ENGINE_INVALID_JSON))
         gen = service.predict_stream(
-            msg, wire_npy=kind == "npy", traceparent=req.headers.get("traceparent")
+            msg, wire_npy=kind == "npy", traceparent=req.headers.get("traceparent"),
+            ingress=ingress,
         )
         first = await gen.__anext__()
     except StopAsyncIteration:
         return WireResponse(status=500, body=b'{"status":"FAILURE"}')
     except Exception as e:  # noqa: BLE001 - wire boundary
+        ingress.done()  # refused before a submit
         return failure_response(
             e,
             fallback_code=ErrorCode.ENGINE_MICROSERVICE_ERROR,

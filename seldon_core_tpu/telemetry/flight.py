@@ -119,6 +119,7 @@ ANN_PREFIX = "decode."
 ANN_ROUND = ANN_PREFIX + "round"  # loop: _round_reset -> _commit_round, awaits included
 ANN_IDLE_WAIT = ANN_PREFIX + "idle_wait"  # loop: no work (not a bubble)
 ANN_SSE_WRITE = ANN_PREFIX + "sse_write"  # loop: one stream flush (serving/fast_http.py)
+ANN_INGRESS = ANN_PREFIX + "ingress"  # loop: a request's bytes in hand -> its submit (Ingress)
 ANN_PHASE = tuple(f"{ANN_PREFIX}phase.{p}" for p in PHASES)  # loop: _PhaseCtx
 ANN_DISPATCH = tuple(f"{ANN_PREFIX}dispatch.{f}" for f in FAMILIES)  # loop: hand-off -> readback return
 ANN_ENQUEUE = tuple(f"{ANN_PREFIX}enqueue.{f}" for f in FAMILIES)  # the calling thread: program call -> enqueued
@@ -150,7 +151,10 @@ def annotate(name: str, **kw):
 
 _trace_annotation = _session_on = None
 
-_DEFAULT_CAPACITY = 2048
+# a 51-second benchmark window down to 6.3 ms rounds; 1.2-1.8 KB a frame,
+# 10-15 MB a full ring (measured: docs/observability.md "Decode-loop flight
+# recorder")
+_DEFAULT_CAPACITY = 8192
 # frames carried per auto-dump (span events are capped at
 # MAX_EVENTS_PER_SPAN=128 per span; stay under it with headroom)
 DUMP_FRAMES = 64
@@ -198,6 +202,31 @@ def _env_capacity(env: dict | None = None) -> int:
     except (TypeError, ValueError):
         n = _DEFAULT_CAPACITY
     return max(n, 16)
+
+
+class Ingress:
+    """One request's way over the event loop from its bytes in hand to the
+    scheduler's queue: the ``ANN_INGRESS`` trace annotation and the clock
+    that ``DecodeScheduler.submit`` books into the round's frame
+    (``ingress_ns`` / ``ingress_requests``). Made where the body is still
+    unparsed (serving/wire.py ``engine_predictions_stream``), else at
+    ``predict_stream``'s entry. ``done`` ends the annotation and returns the
+    nanoseconds since the mark, once: None at every later call (a request
+    of several rows submits several times, a migrated one again)."""
+
+    __slots__ = ("t_ns", "_ann")
+
+    def __init__(self):
+        self._ann = annotate(ANN_INGRESS)
+        self.t_ns = time.perf_counter_ns()
+
+    def done(self) -> int | None:
+        ann = self._ann
+        if ann is None:
+            return None
+        self._ann = None
+        ann.__exit__(None, None, None)
+        return time.perf_counter_ns() - self.t_ns
 
 
 class _PhaseCtx:
@@ -319,7 +348,8 @@ class PhaseTimer:
         trace annotations with no profiler session: ``phases_per_round``
         enter/exit pairs incl. one nested pair (each writes its ANN_PHASE
         annotation), one ANN_ROUND with its two stats, and per dispatch
-        the ANN_DISPATCH / ANN_ENQUEUE / ANN_READBACK triple — what
+        the ANN_DISPATCH / ANN_ENQUEUE / ANN_READBACK triple with a
+        dispatch's stats — what
         PARITY.md documents beside the frame-append cost and the tier-1
         guard budgets. A served round of 16 generating slots enters
         ``emit_slo`` once per token: ``phases_per_round=40`` is its size."""
@@ -331,9 +361,10 @@ class PhaseTimer:
                 with t.phase(p % N_PHASES):
                     pass
             for f in range(dispatches_per_round):
-                d = annotate(ANN_DISPATCH[f])
-                annotate(ANN_ENQUEUE[f]).__exit__(None, None, None)
-                annotate(ANN_READBACK[f]).__exit__(None, None, None)
+                stats = {"seq": i + f, "round": i, "rows": 16, "live": 16}
+                d = annotate(ANN_DISPATCH[f], **stats)
+                annotate(ANN_ENQUEUE[f], **stats).__exit__(None, None, None)
+                annotate(ANN_READBACK[f], **stats).__exit__(None, None, None)
                 d.__exit__(None, None, None)
             with t.phase(P_ACCEPT_WALK):
                 with t.phase(P_EMIT_SLO):
@@ -399,7 +430,13 @@ class FlightFrame:
     ``mla_run_pages`` where that family's step ran its kernel (ops/mla.py
     ``mla_decode_attention``): the pages it fetched for the live rows, and
     those among them that lay in runs of consecutive pages and came in ONE
-    DMA a run (one layer's); 0 where the walk ran."""
+    DMA a run (one layer's); 0 where the walk ran; ``chunk_c`` the chunk
+    length of the round's chunk dispatch (with ``chunk_rows`` its
+    ``chunk_buckets`` entry, whose wall is ``busy_ns[F_CHUNK]``), 0 where
+    none ran; ``ingress_ns`` / ``ingress_requests`` the submits that reached
+    the queue during the round and their summed time on the event loop from
+    the request's bytes in hand (``Ingress``: body parse, message build, the
+    hops to ``submit``), 0 / 0 for callers that hand ``submit`` no mark."""
 
     __slots__ = (
         "seq", "t_ns", "mode", "active", "prefilling", "queued",
@@ -414,6 +451,7 @@ class FlightFrame:
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
+        "chunk_c", "ingress_ns", "ingress_requests",
     )
 
     def __init__(
@@ -429,6 +467,7 @@ class FlightFrame:
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
+        chunk_c=0, ingress_ns=0, ingress_requests=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -474,6 +513,9 @@ class FlightFrame:
         self.mla_ctx_rows = mla_ctx_rows
         self.mla_pages_read = mla_pages_read
         self.mla_run_pages = mla_run_pages
+        self.chunk_c = chunk_c
+        self.ingress_ns = ingress_ns
+        self.ingress_requests = ingress_requests
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -539,6 +581,9 @@ class FlightFrame:
             d["attn_pages"] = [self.attn_pages_read, self.attn_pages_table]
         if self.chunk_rows:
             d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
+            d["chunk_c"] = self.chunk_c
+        if self.ingress_requests:
+            d["ingress"] = [self.ingress_requests, round(self.ingress_ns / 1e3, 1)]
         if self.sample_rows:
             d["sample_rows"] = [self.sample_rows, self.sample_topk_rows]
         if self.moe_rows:

@@ -82,6 +82,26 @@ def test_ring_is_bounded_and_ordered():
     assert [f.seq for f in rec.snapshot(4)] == [36, 37, 38, 39]
 
 
+def test_the_default_ring_holds_a_benchmark_window(monkeypatch):
+    """ISSUE 39: a 51-second window of 6.3 ms rounds fits the default ring
+    (5,000 recorded rounds are all in ``snapshot()``), ``record`` stays O(1)
+    (no walk of the ring: the running totals are the same adds at any
+    capacity) and ENGINE_FLIGHT_FRAMES keeps its meaning."""
+    from seldon_core_tpu.telemetry import flight as flight_mod
+
+    monkeypatch.delenv("ENGINE_FLIGHT_FRAMES", raising=False)
+    rec = FlightRecorder(n_slots=4, name="t", enabled=True)
+    assert rec.capacity == flight_mod._DEFAULT_CAPACITY == 8192 >= 51.0 / 0.0063
+    for i in range(5000):
+        rec.record(_frame(i, chunk_rows=2, chunk_c=64, ingress_ns=7, ingress_requests=1))
+    frames = rec.snapshot()
+    assert [f.seq for f in frames] == list(range(5000)) and rec.rounds == 5000
+    assert frames[-1].chunk_c == 64 and frames[-1].to_dict()["ingress"] == [1, 0.0]
+    assert rec.tokens_total == 2 * 5000 and len(rec._frames) == 8192
+    monkeypatch.setenv("ENGINE_FLIGHT_FRAMES", "64")
+    assert FlightRecorder(n_slots=4, name="small", enabled=True).capacity == 64
+
+
 def test_aggregate_math_on_synthetic_frames():
     rec = FlightRecorder(n_slots=4, name="t", capacity=64, enabled=True)
     rec.record(_frame(0, busy_ns=(2000, 1000, 0, 0, 0), gap_ns=1000,

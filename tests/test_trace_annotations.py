@@ -14,7 +14,12 @@ What this file pins:
    per-request stamps;
 4. the compiled ``_fused_step`` / ``_fused_chunk`` carry every scope name;
 5. a real CPU profiler session records the annotations with their stats;
-6. the per-round cost with no session stays inside the overhead budget.
+6. with no session a round's annotations construct nothing: a check and
+   the shared no-op;
+7. (ISSUE 39) a dispatch's annotations say which dispatch they are: a serial
+   over all families, the frame's index, a chunk's ``chunk_buckets`` entry
+   and a step's rows; the frame carries the entry (``chunk_c``) and the
+   loop's ingress (``ingress_ns`` / ``ingress_requests``).
 """
 
 import asyncio
@@ -142,7 +147,7 @@ def test_a_round_names_its_host_states(recorded, config):
     assert all(not e["open"] and e["exits"] == 1 for e in rec.events)
     # only registered names, all under the one prefix
     registered = (
-        {flight_mod.ANN_ROUND, flight_mod.ANN_IDLE_WAIT, flight_mod.ANN_SSE_WRITE}
+        {flight_mod.ANN_ROUND, flight_mod.ANN_IDLE_WAIT, flight_mod.ANN_SSE_WRITE, flight_mod.ANN_INGRESS}
         | set(flight_mod.ANN_PHASE) | set(flight_mod.ANN_DISPATCH)
         | set(flight_mod.ANN_ENQUEUE) | set(flight_mod.ANN_READBACK)
     )
@@ -210,7 +215,7 @@ def test_one_helper_times_the_step_round_and_timed_call():
     import inspect
 
     src = inspect.getsource(DecodeScheduler._step_round)
-    assert "self._dispatch(F_STEP)" in src and "perf_counter_ns" not in src
+    assert "self._dispatch(F_STEP, " in src and "perf_counter_ns" not in src
     assert "self._dispatches[family]" in inspect.getsource(DecodeScheduler._timed_call)
 
 
@@ -298,12 +303,35 @@ def test_a_cpu_profiler_session_records_the_annotations(tmp_path):
     assert flight_mod.annotate("decode.round", round=0, t_ns=0) is flight_mod._NOOP_CTX
 
 
-def test_annotations_stay_inside_the_overhead_budget():
-    """No session: the synthetic round (8 phases, a round annotation, two
-    dispatch triples) stays inside the recorder's CI budget, and a served
-    round's size (16 slots: ~40 phase entries) inside four times it."""
-    assert PhaseTimer.measure_overhead(2000) < 50.0
-    assert PhaseTimer.measure_overhead(1000, phases_per_round=40) < 200.0
+def test_annotations_cost_a_check_and_a_shared_noop_without_a_session(monkeypatch):
+    """What the overhead budget guarded, by a measure that a loaded machine
+    does not move: with no profiler session a synthetic round and a served
+    batch construct NO ``TraceAnnotation`` (every emit is the helper's one
+    check and the shared no-op), stats or none; the same rounds inside a
+    session construct one an annotation."""
+    made = []
+    real = jax.profiler.TraceAnnotation
+
+    class Counted(real):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(flight_mod, "_trace_annotation", Counted)
+    monkeypatch.setattr(flight_mod, "_session_on", real.is_enabled)
+    assert not real.is_enabled()
+    assert PhaseTimer.measure_overhead(50) > 0 and PhaseTimer.measure_overhead(20, phases_per_round=40) > 0
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2, prefill_chunk=4)
+    s.warmup()
+    _drive(s, _shared_prompts(3, shared=0, seed=6))
+    assert s.flight.rounds > 0 and s._dispatch_seq > 0 and made == []
+    for ann in (flight_mod.annotate(flight_mod.ANN_DISPATCH[0], seq=1, round=0, rows=2, c=4, live=1),
+                flight_mod.annotate(flight_mod.ANN_ROUND, round=0, t_ns=0), flight_mod.Ingress()._ann):
+        assert ann is flight_mod._NOOP_CTX
+    # the counter does count: inside a session every emit constructs one
+    monkeypatch.setattr(flight_mod, "_session_on", lambda: True)
+    PhaseTimer.measure_overhead(1, phases_per_round=8, dispatches_per_round=2)
+    assert len(made) == 1 + 6 + 2 * 3 + 2  # the round, six flat phases, two triples, the nested pair
 
 
 def test_each_sse_flush_is_named(monkeypatch):
@@ -332,3 +360,98 @@ def test_each_sse_flush_is_named(monkeypatch):
     assert [e["name"] for e in rec.events] == [flight_mod.ANN_SSE_WRITE] * 3
     assert all(not e["open"] for e in rec.events)
     assert sum(b"data: " in w for w in proto._transport.wrote) == 3
+
+
+# ------------------------------------------------- ISSUE 39: which dispatch
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_a_dispatch_says_which_it_was(recorded, config):
+    """Every ``decode.dispatch.*`` carries ``seq`` (one serial over all
+    families, in the order the loop entered them) and ``round`` (the frame
+    the round committed under); its enqueue and readback events carry the
+    same stats; a chunk adds its ladder entry and its live rows, a step its
+    rows and the generating slots."""
+    rec, s = recorded[config]
+    pre = flight_mod.ANN_PREFIX
+    dispatches = [e for e in rec.events if e["name"].startswith(pre + "dispatch.")]
+    assert [e["kw"]["seq"] for e in dispatches] == list(range(1, len(dispatches) + 1)) == list(
+        range(1, s._dispatch_seq + 1))
+    assert len({e["name"] for e in dispatches}) >= 2  # monotonic ACROSS families
+    by_seq = {e["kw"]["seq"]: e for e in dispatches}
+    frames = {f.seq: f for f in s.flight.snapshot()}
+    for e in dispatches:
+        fam, kw = e["name"].rsplit(".", 1)[1], e["kw"]
+        assert all(isinstance(v, int) for v in kw.values())
+        frame = frames[kw["round"]]
+        assert frame.busy_ns[FAMILIES.index(fam)] > 0  # the frame that round committed holds the dispatch
+        if fam == "chunk":
+            assert set(kw) == {"seq", "round", "rows", "c", "live"}
+            assert (kw["rows"], kw["c"]) in s.chunk_buckets and 1 <= kw["live"] <= kw["rows"]
+            assert (frame.chunk_rows, frame.chunk_c, frame.chunk_rows_live) == (kw["rows"], kw["c"], kw["live"])
+            assert frame.to_dict()["chunk_c"] == kw["c"]
+        elif fam == "step":
+            assert set(kw) == {"seq", "round", "rows", "live"}
+            assert kw["rows"] == s.n_slots and 1 <= kw["live"] <= s.n_slots and frame.active >= kw["live"]
+        else:
+            assert set(kw) == {"seq", "round"}
+    for e in rec.events:
+        kind = e["name"][len(pre):].split(".")[0]
+        if kind in ("enqueue", "readback"):
+            d = by_seq[e["kw"]["seq"]]
+            # a draft's enqueue inside a verify dispatch carries the verify's stats
+            assert e["kw"] == d["kw"]
+    # a round without a chunk dispatch says so
+    assert all(f.chunk_c == 0 for f in frames.values() if not f.chunk_rows)
+    assert any(f.chunk_c for f in frames.values())
+
+
+def test_a_submit_books_the_loops_ingress_into_its_round(monkeypatch):
+    """``submit(ingress=...)``: the mark's time goes into the frame of the
+    round the submit lands in, once a request (a second row of the same
+    request adds nothing); the one that wakes an idle loop is not dropped
+    by the wait's round reset; the annotation ends at the submit."""
+    rec = _Recorder()
+    monkeypatch.setattr(flight_mod, "annotate", rec)
+    s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2)
+    s.warmup()
+    ids = _shared_prompts(4, shared=0, seed=7)
+    marks = []
+
+    async def go():
+        first = flight_mod.Ingress()
+        marks.append(first)
+        await asyncio.sleep(0.002)  # the body's parse
+        outs = [await s.submit(ids[0], ingress=first)]  # wakes the idle loop
+        shared = flight_mod.Ingress()  # one request of two rows
+        marks.append(shared)
+        outs += await asyncio.gather(s.submit(ids[1], ingress=shared), s.submit(ids[2], ingress=shared),
+                                     s.submit(ids[3]))
+        await s.close()
+        return outs
+
+    assert len(asyncio.run(go())) == 4
+    frames = s.flight.snapshot()
+    assert sum(f.ingress_requests for f in frames) == 2
+    assert sum(f.ingress_ns for f in frames) >= 2_000_000
+    first = next(f for f in frames if f.ingress_requests)
+    assert first.seq == 0 and first.ingress_ns >= 2_000_000  # survived the idle wait's reset
+    assert first.to_dict()["ingress"][0] == 1
+    assert all(f.ingress_ns == 0 for f in frames if not f.ingress_requests)
+    ing = [e for e in rec.events if e["name"] == flight_mod.ANN_INGRESS]
+    assert len(ing) == 2 and all(not e["open"] and e["exits"] == 1 for e in ing)
+    assert all(m.done() is None for m in marks)
+
+
+def test_predict_stream_hands_the_wire_layers_mark_to_submit():
+    """The stream endpoint marks before it parses the body and the service
+    hands that mark (or, for another caller, one of its own) to ``submit``."""
+    import inspect
+
+    from seldon_core_tpu.serving import service, wire
+
+    src = inspect.getsource(wire.engine_predictions_stream)
+    assert src.index("Ingress()") < src.index("message_from_json_fast(req.body)") < src.index("ingress=ingress")
+    src = inspect.getsource(service.PredictionService.predict_stream)
+    assert "ingress = Ingress()" in src and "ingress=ingress" in src
+
