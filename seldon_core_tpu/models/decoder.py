@@ -756,46 +756,128 @@ def _write_index(ps: int, m: int, bt, positions, counts):
     return phys.reshape(-1), (gp % ps).reshape(-1)
 
 
+def _write_rows(pool: tuple, li: int, parts: tuple, bt, positions, counts) -> tuple:
+    """The ROW form of the pool write: one scatter index a token row,
+    n * m slices of one row each at (li, page, row)."""
+    n, m = parts[0].shape[:2]
+    pf, of = _write_index(pool[0].shape[2], m, bt, positions, counts)
+    flat = [x.reshape((n * m,) + x.shape[2:]) for x in parts]  # per-token rows
+    return tuple(plane.at[li, pf, of].set(x) for plane, x in zip(pool, flat))
+
+
+@functools.partial(jax.jit, inline=True)
+def _write_pages(pool: tuple, li, parts: tuple, bt, positions, counts) -> tuple:
+    """The PAGE form of the pool write: slot i's m consecutive positions
+    from positions[i] cover at most ``pw`` logical pages of its table row,
+    so the new rows are laid out by page (shifted by positions[i] % ps) and
+    n * pw slices of one whole page each go to (li, page). A page's rows
+    that the dispatch does not write (before the start, at or past
+    counts[i]) keep what the pool holds: the rows written are consecutive,
+    so only the first and the last page written can hold such rows, and
+    those two are read, merged by row mask and written back. A page with
+    no row to write (past counts[i], past the virtual length, a padding
+    row's) goes to junk page 0, where an invalid row goes in the row form;
+    everywhere else the pool ends bit for bit as ``_write_rows`` leaves it
+    (tests/test_kv_pool.py holds the two forms equal).
+
+    Why pages: a scatter costs the chip its indices, not its bytes. The 72
+    writes of a (2, 256) dispatch into the gpt2-large pool (float32 rows of
+    1280), timed alone on a v5e (PERF.md section 6, PR 40): 6.49 ms by
+    rows (0.18 us an index of 5 KB), 1.54 this way (the scatter 0.89,
+    0.36 us a page of 80 KB; the shift 0.28; the ends' read 0.09), 2.05
+    with every page read back, 1.50 with the ends in a scatter of their own
+    (but 0.67 against 0.57 at (2, 64)), 5.98 as a ``fori_loop`` of
+    ``dynamic_update_slice`` a page; 0.74 where the positions are known to
+    be page-aligned and whole (no shift, no merge). (layer, page) is ONE
+    index: the compiler's own flattening of a scatter of 1,024 indices or
+    more leaves its fusion without an op name, so without a scope.
+
+    Jitted on its own and inlined: a program's writes (72 in gpt2-large)
+    are then traced once and not a layer and a plane; traced in line, the
+    five chunk programs took 4.6 s more to trace and lower than the
+    parent's 7.2, at every boot, compile cache or not (setup_s +5 s)."""
+    n, m = parts[0].shape[:2]
+    n_log, ps = bt.shape[1], pool[0].shape[2]
+    pw = (m + 2 * ps - 2) // ps  # pages that m rows from any row of a page can touch
+    first = positions // ps  # [n] logical page of the first new row
+    off = positions - first * ps  # its row in that page
+    t = jnp.arange(pw * ps)[None, :]
+    j = t - off[:, None]  # [n, pw * ps] the new row that lands in each row of the tile
+    gp = first[:, None] * ps + t
+    ok = (j >= 0) & (j < m) & (gp >= 0) & (gp < n_log * ps)
+    if counts is not None:
+        ok = ok & (j < counts[:, None])
+    ok = ok.reshape(n, pw, ps)
+    some = ok.any(axis=2)  # [n, pw] pages with a row to write
+    lp = jnp.clip(first[:, None] + jnp.arange(pw)[None, :], 0, n_log - 1)
+    phys = jnp.where(some, jnp.take_along_axis(bt, lp, axis=1), 0)
+    last = pw - 1 - jnp.argmax(some[:, ::-1], axis=1)  # [n] the last page written, the first beside it
+    ends = jnp.take_along_axis(phys, jnp.stack([jnp.argmax(some, axis=1), last], axis=1), axis=1).reshape(-1)
+    is_last = jnp.arange(pw)[None, :] == last[:, None]
+
+    def tiles(x):
+        """x[n, m, ...] -> [n, pw, ps, ...], row j of slot i at tile row off[i] + j."""
+        pad = [(0, 0), (ps, pw * ps - m)] + [(0, 0)] * (x.ndim - 2)
+        cut = jax.vmap(lambda a, s: jax.lax.dynamic_slice_in_dim(a, s, pw * ps, axis=0))
+        return cut(jnp.pad(x, pad), ps - off).reshape((n, pw, ps) + x.shape[2:])
+
+    out = []
+    for plane, x in zip(pool, parts):
+        wide = (1,) * (x.ndim - 2)  # a row's own dims: (w,) of a payload plane, () of a scale plane
+        flat = plane.reshape((-1,) + plane.shape[2:])  # (layer, page) as one index
+        base = li * plane.shape[1]
+        held = flat[base + ends].reshape((n, 2, 1, ps) + x.shape[2:])
+        kept = jnp.where(is_last.reshape((n, pw, 1) + wide), held[:, 1], held[:, 0])
+        merged = jnp.where(ok.reshape((n, pw, ps) + wide), tiles(x), kept)
+        flat = flat.at[base + phys.reshape(-1)].set(merged.reshape((n * pw, ps) + x.shape[2:]))
+        out.append(flat.reshape(plane.shape))
+    return tuple(out)
+
+
+def write_form(m: int, page_size: int) -> str:
+    """The granule of a dispatch's pool write, "page" | "row", from its
+    static shape: ``m`` consecutive positions a slot against the page size.
+    A prefill chunk (a page's worth of rows or more) writes whole pages; a
+    step, a verify or a tree commit (fewer rows than a page holds) its
+    rows, which for one row a slot is already the least bytes."""
+    return "page" if m >= page_size else "row"
+
+
+def _write_parts(pool: tuple, li: int, parts: tuple, bt, positions, counts) -> tuple:
+    """Write a dispatch's new rows (``parts[c]``: [n, m, ...] in component
+    c's dtype, slot i's entry j at positions[i] + j) into layer ``li`` of
+    every pool component in place, through the block tables, in the form
+    ``write_form`` names."""
+    write = _write_pages if write_form(parts[0].shape[1], pool[0].shape[2]) == "page" else _write_rows
+    return write(pool, li, parts, bt, positions, counts)
+
+
 @jax.named_scope(SCOPE_KV_WRITE)
 def _paged_write_latent(pool: tuple, li: int, rows, bt, positions, counts):
-    """``_paged_write`` for the one-plane latent pool: ONE in-place scatter
-    of the dispatch's new rows [n, m, w] into layer ``li`` of the plane."""
-    (plane,) = pool
-    n, m, w = rows.shape
-    pf, of = _write_index(plane.shape[2], m, bt, positions, counts)
-    return (plane.at[li, pf, of].set(rows.reshape(n * m, w).astype(plane.dtype)),)
+    """``_paged_write`` for the one-plane latent pool: the dispatch's new
+    rows [n, m, w] into layer ``li`` of the plane, in place."""
+    return _write_parts(pool, li, (rows.astype(pool[0].dtype),), bt, positions, counts)
 
 
 @jax.named_scope(SCOPE_KV_WRITE)
 def _paged_write(pool: tuple, li: int, k, v, bt, positions, counts):
-    """Scatter the dispatch's new K/V rows (k, v: [n, m, h*hd], slot i's
+    """Write the dispatch's new K/V rows (k, v: [n, m, h*hd], slot i's
     entry j at positions[i] + j) into layer ``li`` of the WHOLE pool
-    through the block tables — one in-place update at (li, page, row) per
-    component. Invalid entries — beyond counts[i], or past the virtual
-    length — are redirected to junk page 0 instead of masked in place,
-    which is what lets free/prefilling slots ride static-shape dispatches
-    without owning writable pages."""
-    n, m, w = k.shape
-    pf, of = _write_index(pool[0].shape[2], m, bt, positions, counts)
-    kt = k.reshape(n * m, w)  # per-token rows
-    vt = v.reshape(n * m, w)
+    through the block tables — one in-place update per component, by rows
+    or by pages (``_write_parts``). Invalid entries — beyond counts[i], or
+    past the virtual length — are redirected to junk page 0 instead of
+    masked in place, which is what lets free/prefilling slots ride
+    static-shape dispatches without owning writable pages."""
     if len(pool) == 2:
-        pk, pv = pool
-        return (
-            pk.at[li, pf, of].set(kt.astype(pk.dtype)),
-            pv.at[li, pf, of].set(vt.astype(pv.dtype)),
+        parts = (k.astype(pool[0].dtype), v.astype(pool[1].dtype))
+    else:
+        n, m, w = k.shape
+        parts = tuple(
+            a.reshape((n, m) + a.shape[1:])
+            for x in (k, v)
+            for a in _quant_rows(x.reshape(n * m, w).astype(jnp.float32))
         )
-    kq, sk, zk, vq, sv, zv = pool
-    qk, sck, zpk = _quant_rows(kt.astype(jnp.float32))
-    qv, scv, zpv = _quant_rows(vt.astype(jnp.float32))
-    return (
-        kq.at[li, pf, of].set(qk),
-        sk.at[li, pf, of].set(sck),
-        zk.at[li, pf, of].set(zpk),
-        vq.at[li, pf, of].set(qv),
-        sv.at[li, pf, of].set(scv),
-        zv.at[li, pf, of].set(zpv),
-    )
+    return _write_parts(pool, li, parts, bt, positions, counts)
 
 
 @jax.named_scope(SCOPE_KV_GATHER)

@@ -189,6 +189,7 @@ from seldon_core_tpu.models.decoder import (
     decoder_family,
     is_feature_draft,
     require_served,
+    write_form,
 )
 from seldon_core_tpu.models.spec_tree import MAX_TREE_NODES, SpecTree, parse_spec_tree
 from seldon_core_tpu.parallel.tp import (
@@ -434,7 +435,8 @@ class _Dispatch:
     ``seq``, the scheduler's dispatch serial (monotonic over all families),
     ``round``, the index the round's frame will commit under, and what the
     call site noted through ``DecodeScheduler._dispatch(family, **stats)``
-    (a chunk's ``rows`` / ``c`` / ``live``, a step's ``rows`` / ``live``).
+    (a chunk's ``rows`` / ``c`` / ``live`` and the form its program's pool
+    write took, ``write`` "page" | "row"; a step's ``rows`` / ``live``).
     With no profiler session ``flight.annotate`` drops them."""
 
     __slots__ = ("s", "family", "t0", "carved", "ann", "noted", "stats")
@@ -2203,7 +2205,8 @@ class DecodeScheduler:
     def _dispatch(self, family: int, **stats) -> _Dispatch:
         """The ``with``-handle for one dispatch of a flight F_* family:
         THE timing-and-naming point of every dispatch (``_Dispatch``).
-        ``stats`` (integers the call site holds) ride the family's next
+        ``stats`` (what the call site holds: integers, and a chunk's
+        ``write`` form) ride the family's next
         dispatch's trace annotations; ``_timed_call`` enters the handle
         itself, so its callers note theirs here first."""
         d = self._dispatches[family]
@@ -2820,7 +2823,10 @@ class DecodeScheduler:
         t0 = telemetry.now_ns()
         # which chunk_buckets entry the dispatch is, for a trace and the frame
         self._rb_chunk_c = ids.shape[1]
-        self._dispatch(F_CHUNK, rows=len(slots), c=ids.shape[1], live=len(rows))
+        self._dispatch(
+            F_CHUNK, rows=len(slots), c=ids.shape[1], live=len(rows),
+            write=write_form(ids.shape[1], self.pool.page_size),
+        )
         toks, counted = await self._timed_call(
             F_CHUNK,
             lambda: self.programs.chunk(bt, ids, pos, counts, temps, topks, tick, state_rows),

@@ -24,6 +24,7 @@ import asyncio
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from seldon_core_tpu.models.decoder import generate, init_decoder
@@ -394,6 +395,118 @@ def test_the_latent_write_lands_rows_by_position_and_sends_junk_to_page_0():
     assert not np.asarray(plane[:2]).any()  # the other layers stand
     live = [p for p in range(1, 12) if p not in (3, 5)]
     assert not got[live].any()
+
+
+# ------------------------------------------------ the write's two granules
+
+
+def _random_pool(kind: str, layers: int, n_pages: int, ps: int, w: int, rng) -> tuple:
+    """A pool of ``kind`` that already HOLDS something in every row, so a
+    write that loses a row it should have kept shows."""
+    from seldon_core_tpu.models.decoder import kv_pool_zeros
+
+    d = {"kv_layers": layers, "kv_heads": 1, "head_dim": w, "kv_planes": 1 if kind == "latent" else 2}
+    dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+    blank = kv_pool_zeros(d, n_pages, ps, dtype, "int8" if kind == "int8" else "")
+    return tuple(
+        jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype) if a.dtype == jnp.int8
+        else jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in blank
+    )
+
+
+def _write(pool, li, k, v, *rest):
+    from seldon_core_tpu.models.decoder import _paged_write, _paged_write_latent
+
+    return _paged_write_latent(pool, li, k, *rest) if len(pool) == 1 else _paged_write(pool, li, k, v, *rest)
+
+
+def _spy_on_the_forms(monkeypatch) -> list:
+    """The names of the write forms the dispatcher takes from here on, in order."""
+    from seldon_core_tpu.models import decoder as dec
+
+    took = []
+    for form in ("_write_rows", "_write_pages"):
+        real = getattr(dec, form)
+        monkeypatch.setattr(dec, form, lambda *a, real=real, form=form: took.append(form) or real(*a))
+    return took
+
+
+@pytest.mark.parametrize("start_row", [0, 1, 15])
+@pytest.mark.parametrize("m", [16, 64, 256, 48])
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8", "latent"])
+def test_the_page_form_leaves_the_pool_as_the_row_form_does(kind, m, start_row, monkeypatch):
+    """A chunk's write goes by whole pages (``_write_pages``: read, merge by
+    row mask, write); the pool after it is, outside junk page 0, bit for bit
+    the pool after one scatter index a row (``_write_rows``), in every
+    component. One dispatch holds every edge: a full row, a row whose count
+    ends mid-page (its table shares the prefix pages with the first row's;
+    neither writes them), a padding row (count 0, an all-junk table), a row
+    whose positions run past the virtual length, and a row with a single
+    valid entry; all start ``start_row`` rows into a page."""
+    from seldon_core_tpu.models import decoder as dec
+
+    ps, w, layers, li, lead = 16, 8, 3, 1, 3
+    pw = m // ps + 1
+    n_log = lead + pw + 2
+    rng = np.random.default_rng(1000 * m + start_row)
+    n = 5
+    own = 1 + lead + np.arange(n * (n_log - lead), dtype=np.int32).reshape(n, n_log - lead)
+    bt = np.concatenate([np.tile(np.arange(1, lead + 1, dtype=np.int32), (n, 1)), own], axis=1)
+    bt[2] = 0  # the padding row of a ladder entry
+    n_pages = int(bt.max()) + 3  # and two pages no table names
+    start = lead * ps + start_row
+    positions = np.array([start, start, 0, n_log * ps - m // 2 + start_row, start], np.int32)
+    counts = np.array([m, m - ps // 2 - 3, 0, m, 1], np.int32)
+    pool = _random_pool(kind, layers, n_pages, ps, w, rng)
+    k = jnp.asarray(rng.standard_normal((n, m, w)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((n, m, w)), jnp.float32)
+    args = (li, k, v, jnp.asarray(bt), jnp.asarray(positions))
+
+    took = _spy_on_the_forms(monkeypatch)
+    for cnt in (jnp.asarray(counts), None):
+        took.clear()
+        by_page = _write(pool, *args, cnt)
+        assert took == ["_write_pages"]  # m >= ps: the dispatcher's own choice
+        with monkeypatch.context() as mp:
+            mp.setattr(dec, "_write_pages", dec._write_rows)
+            by_row = _write(pool, *args, cnt)
+        assert len(by_page) == len(by_row) == {"int8": 6, "latent": 1}.get(kind, 2)
+        # the pages a row form writes: row i's entries below its count, inside the virtual length
+        c = counts if cnt is not None else np.full(n, m)
+        owned = {int(bt[i, (positions[i] + j) // ps]) for i in range(n) for j in range(c[i])
+                 if positions[i] + j < n_log * ps} - {0}
+        assert len(owned) > pw and not owned & set(range(1, lead + 1))
+        others = [p for p in range(1, n_pages) if p not in owned]
+        for held, a, b in zip(pool, by_page, by_row):
+            assert a.dtype == b.dtype == held.dtype and a.shape == held.shape
+            raw = lambda x: np.asarray(x).view(np.uint16 if x.dtype == jnp.bfloat16 else None)  # noqa: E731
+            np.testing.assert_array_equal(raw(a)[:, 1:], raw(b)[:, 1:])
+            # pages the dispatch does not own, the shared prefix among them, and every other layer
+            np.testing.assert_array_equal(raw(a)[:, others], raw(held)[:, others])
+            np.testing.assert_array_equal(np.delete(raw(a), li, 0), np.delete(raw(held), li, 0))
+            assert np.any(raw(a)[li, sorted(owned)] != raw(held)[li, sorted(owned)])
+
+
+@pytest.mark.parametrize("kind", ["float32", "int8", "latent"])
+def test_fewer_rows_than_a_page_take_the_row_form(kind, monkeypatch):
+    """A step's one row a slot, a verify's few columns and the tree commit's
+    path keep one scatter index a row: for them it is already the least
+    bytes. The choice is the dispatch's static shape against the page size."""
+    ps, w = 16, 8
+    pool = _random_pool(kind, 2, 6, ps, w, np.random.default_rng(3))
+    bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    took = _spy_on_the_forms(monkeypatch)
+    for m in (1, 5, ps - 1, ps):
+        rows = jnp.ones((2, m, w), jnp.float32)
+        _write(pool, 0, rows, -rows, bt, jnp.asarray([14, 3], jnp.int32), None)
+    assert took == ["_write_rows"] * 3 + ["_write_pages"]
+    step = jax.make_jaxpr(lambda p, r: _write(p, 0, r, -r, bt, jnp.asarray([14, 3], jnp.int32), None))(
+        pool, jnp.ones((2, 1, w), jnp.float32))
+    shapes = {a.shape for a in pool}
+    ops = [(e.primitive.name, e.invars[0].aval.shape) for e in step.jaxpr.eqns if e.invars]
+    assert sum(name == "scatter" and shape in shapes for name, shape in ops) == len(pool)
+    assert not [shape for name, shape in ops if name == "gather" and shape in shapes]  # a step reads no page back
 
 
 # ------------------------------------------------ scheduler over the pool

@@ -110,6 +110,31 @@ def _pool_sized_entry_ops(text: str, pool) -> list[str]:
     return [op for op, n in ops if n in (pool_elems, pool_elems // pool[0].shape[0])]
 
 
+def _pool_scatters(text: str, pool) -> list[tuple[int, str]]:
+    """(indices, granule) of every scatter into an array shaped like a float
+    pool component, anywhere in the program's text: the granule is "row"
+    where the updates' window is one token row ``[w]``, "page" where it is a
+    whole page ``[ps, w]``. Each must carry the ``kv_write`` scope."""
+    hlo = {"float32": "f32", "bfloat16": "bf16"}
+    # as the pool is, without the layer axis of a one-layer pool (the compiler drops it), and with
+    # (layer, page) as one axis (the page form's own view of a plane)
+    shapes = {"%s[%s]" % (hlo[a.dtype.name], ",".join(map(str, dims)))
+              for a in pool for dims in (a.shape, a.shape[1:] if a.shape[0] == 1 else a.shape, (a.shape[0] * a.shape[1],) + a.shape[2:])}
+    defs = dict(re.findall(r"%([\w.-]+) = s32\[(\d+)(?:,\d+)?\]", text))
+    out = []
+    for shape, idx, dims, name in re.findall(
+        r"= (\w+\[[\d,]+\])\S* scatter\(%[\w.-]+, %([\w.-]+), [^)]*\), update_window_dims=\{([\d,]*)\}.*?op_name=\"([^\"]*)\"", text
+    ):
+        if shape in shapes:
+            assert "/kv_write/" in name, name
+            out.append((int(defs[idx]), {1: "row", 2: "page"}[len(dims.split(","))]))
+    # and so must the fusion that holds it: a trace names an op by its fusion's metadata (the compiler's
+    # own rewrite of a scatter of 1024 indices or more at (layer, page) left its fusion without any)
+    for shape, rest in re.findall(r"= (\w+\[[\d,]+\])\S* fusion\(([^\n]*)", text):
+        assert shape not in shapes or re.search(r'op_name="[^"]*/(kv_write|kv_gather|attn)/', rest), rest[:200]
+    return out
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_compiles_at_bert_base_long_context(topo, causal):
     from seldon_core_tpu.ops.attention import PALLAS_MIN_SEQ
@@ -172,14 +197,19 @@ def test_fused_step_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo
     sized = _pool_sized_entry_ops(compiled.as_text(), pool)
     assert "fusion" in sized  # the scatters' own fusions: the pattern reads the text
     assert not [op for op in sized if op.startswith("copy")], sized
+    # what the step writes is what it wrote before the chunks took pages (PR 40): one row a slot at
+    # (layer, page, row), K and V of each layer, and no page read back to merge
+    assert _pool_scatters(compiled.as_text(), pool) == [(16, "row")] * 16
+    assert "/kv_write/gather" not in compiled.as_text()
 
 
 def test_compact_chunk_writes_the_donated_pool_in_place_at_gpt2_large_geometry(topo):
     """The same cell's 256-token prefill chunk at the compact width (two
     rows of the scheduler's chunk ladder, block-table rows of two slots;
     8 of 36 layers, shapes only): the per-layer scatter lands in the donated
-    pool, and the temporaries are the two rows' own — the 16-row program's
-    gathered caches and scores come to 1.5 GiB here, these to 0.11."""
+    pool, a page an index, and the temporaries are the two rows' own — the
+    16-row program's gathered caches and scores come to 1.5 GiB here, these
+    to 0.11."""
     from seldon_core_tpu.models.decoder import _fused_chunk, init_decoder
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -202,6 +232,11 @@ def test_compact_chunk_writes_the_donated_pool_in_place_at_gpt2_large_geometry(t
     assert mem.temp_size_in_bytes < pool_bytes / 4
     sized = _pool_sized_entry_ops(compiled.as_text(), pool)
     assert not [op for op in sized if op.startswith("copy")], sized
+    # the write goes by pages (PR 40): 2 rows x the 17 pages that 256 positions from any row of a page
+    # can touch, whole [16, 1280] windows at (layer, page); no scatter is left with an index a token row
+    assert _pool_scatters(compiled.as_text(), pool) == [(2 * 17, "page")] * 16  # none of 2 * 256 indices
+    # the pages read back to merge are each row's first and last, not the seventeen
+    assert re.findall(r"= f32\[(\d+),16,1280\]\S* gather\([^\n]*/kv_write/", compiled.as_text()) == ["4"] * 16
 
 
 def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo):
@@ -410,6 +445,9 @@ def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo,
     copies = [ln for ln in compiled.as_text().splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
     assert not copies, copies[:2]
     assert re.search(r'op_name="jit\(_fused_%s\)/attn/ssm_scan/' % ("step" if program == "step" else "chunk"), compiled.as_text())
+    # the attention layer's K and V: a row a slot in the step, 2 x 5 whole pages in the (2, 64) chunk (PR 40)
+    want = (64, "row") if program == "step" else (2 * 5, "page")
+    assert _pool_scatters(compiled.as_text(), pool) == [want] * 2
 
 
 def _ungated_lines(text: str) -> list[str]:
@@ -562,6 +600,9 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     # a row's whole table is 532 pages = 8512 keys: nothing is gathered at that length, in any dtype
     # (the walk takes 8 to 64 pages at a time), and nothing 640 lanes wide is float32
     assert not re.findall(r"\[%d,(?:532,16|8512),[0-9,]*\]" % n, text)
+    # the one plane's write, a layer: a row a slot in the step, whole pages in a chunk (PR 40)
+    want = (64, "row") if program == "step" else (n * (c // 16 + 1), "page")
+    assert _pool_scatters(text, pool) == [want] * 2
     where = "step" if program == "step" else "chunk"
     assert re.search(r'op_name="jit\(_fused_%s\)/attn/(?:cond/branch_\d_fun/)?mla_core/' % where, text)
     # a 256-token program absorbs too, in the branch for a dispatch whose rows' live queries are few

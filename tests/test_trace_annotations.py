@@ -382,11 +382,13 @@ def test_a_dispatch_says_which_it_was(recorded, config):
     frames = {f.seq: f for f in s.flight.snapshot()}
     for e in dispatches:
         fam, kw = e["name"].rsplit(".", 1)[1], e["kw"]
-        assert all(isinstance(v, int) for v in kw.values())
+        assert all(isinstance(v, int) for k, v in kw.items() if k != "write")
         frame = frames[kw["round"]]
         assert frame.busy_ns[FAMILIES.index(fam)] > 0  # the frame that round committed holds the dispatch
         if fam == "chunk":
-            assert set(kw) == {"seq", "round", "rows", "c", "live"}
+            assert set(kw) == {"seq", "round", "rows", "c", "live", "write"}
+            # the form its program's pool write took, by the comparison the program makes
+            assert kw["write"] == ("page" if kw["c"] >= s.pool.page_size else "row")
             assert (kw["rows"], kw["c"]) in s.chunk_buckets and 1 <= kw["live"] <= kw["rows"]
             assert (frame.chunk_rows, frame.chunk_c, frame.chunk_rows_live) == (kw["rows"], kw["c"], kw["live"])
             assert frame.to_dict()["chunk_c"] == kw["c"]
@@ -455,3 +457,15 @@ def test_predict_stream_hands_the_wire_layers_mark_to_submit():
     src = inspect.getsource(service.PredictionService.predict_stream)
     assert "ingress = Ingress()" in src and "ingress=ingress" in src
 
+
+def test_both_forms_of_the_pool_write_are_named(recorded):
+    """ISSUE 40: a chunk whose ``c`` is a page's worth of rows or more says
+    ``write`` "page", a shorter one "row": the recorded configurations hold
+    both sides of the comparison."""
+    seen = {}
+    for rec, s in recorded.values():
+        for e in rec.events:
+            if e["name"] == flight_mod.ANN_PREFIX + "dispatch.chunk":
+                seen.setdefault(e["kw"]["write"], set()).add((e["kw"]["c"], s.pool.page_size))
+    assert set(seen) == {"page", "row"}
+    assert all(c >= ps for c, ps in seen["page"]) and all(c < ps for c, ps in seen["row"])
