@@ -1054,6 +1054,14 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
         return sample_tokens(last, temps, topks, key), pool
 
 
+def _sample_and_count(logits, counted, temps, topks, seed, tick):
+    """A counting family's readback: the dispatch's sampled tokens, then its counts."""
+    with jax.named_scope(SCOPE_SAMPLE):
+        key = jax.random.fold_in(jax.random.key(seed), tick)
+        toks = sample_tokens(logits[:, 0, :], temps, topks, key)
+        return jnp.concatenate([toks, counted])
+
+
 def counted_programs(paged_forward):
     """The step and chunk bodies of a family whose ``paged_forward(params,
     pool, bt, tokens, positions, counts=, rows=, pick=)`` gives (logits,
@@ -1066,24 +1074,18 @@ def counted_programs(paged_forward):
     prefill: ``_fused_chunk``). The chunk's head runs on each row's last
     real position only."""
 
-    def sample_and_count(logits, counted, temps, topks, seed, tick):
-        with jax.named_scope(SCOPE_SAMPLE):
-            key = jax.random.fold_in(jax.random.key(seed), tick)
-            toks = sample_tokens(logits[:, 0, :], temps, topks, key)
-            return jnp.concatenate([toks, counted])
-
     def step(params, pool, bt, tokens, positions, temps, topks, seed, tick, rows):
         logits, _hidden, pool, counted = paged_forward(
             params, pool, bt, tokens[:, None], positions, rows=rows
         )
-        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+        return _sample_and_count(logits, counted, temps, topks, seed, tick), pool
 
     def chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, tick):
         idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
         logits, _hidden, pool, counted = paged_forward(
             params, pool, bt, ids, positions, counts=counts, pick=idx
         )
-        return sample_and_count(logits, counted, temps, topks, seed, tick), pool
+        return _sample_and_count(logits, counted, temps, topks, seed, tick), pool
 
     # jit names a program after its function: the trace's name for every family
     step.__name__ = step.__qualname__ = "_fused_step"
@@ -1114,6 +1116,63 @@ def paged_greedy_generate(forward, make_pool, ids, max_new_tokens: int, page_siz
         return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), pos + 1, pool), tok
 
     (last, _, _), toks = lax.scan(step, (first, zero + s, pool), None, length=max_new_tokens - 1)
+    return jnp.concatenate([ids, toks.T.reshape(b, -1), last[:, None]], axis=1)
+
+
+def counted_state_programs(paged_forward):
+    """``counted_programs`` for a family with a state cache beside the pages
+    (``state_init``): ``paged_forward(params, pool, rec, bt, tokens,
+    positions, counts=, rows=, pick=, state_rows=)`` gives (logits, pool,
+    rec, counted). Both bodies take the state cache ``rec`` after the pool
+    (donated with it) and give it back after it; the step takes ``rows``
+    (the slots that generate: the others' state stands), the chunk
+    ``state_rows`` [3, rows]; the counts ride the token readback."""
+
+    def step(params, pool, rec, bt, tokens, positions, temps, topks, seed, tick, rows):
+        logits, pool, rec, counted = paged_forward(params, pool, rec, bt, tokens[:, None], positions, rows=rows)
+        return _sample_and_count(logits, counted, temps, topks, seed, tick), pool, rec
+
+    def chunk(params, pool, rec, bt, ids, positions, counts, temps, topks, seed, tick, state_rows):
+        idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
+        logits, pool, rec, counted = paged_forward(
+            params, pool, rec, bt, ids, positions, counts=counts, pick=idx, state_rows=state_rows
+        )
+        return _sample_and_count(logits, counted, temps, topks, seed, tick), pool, rec
+
+    step.__name__ = step.__qualname__ = "_fused_step"
+    chunk.__name__ = chunk.__qualname__ = "_fused_chunk"
+    return step, chunk
+
+
+def paged_state_greedy_generate(forward, make_pool, make_state, ids, max_new_tokens: int, chunk: int = 256):
+    """``paged_greedy_generate`` for a family with a state cache:
+    ``forward(pool, rec, bt, tokens, positions, counts=, pick=,
+    state_rows=)`` gives (logits, pool, rec, counted), over a private pool
+    and private state rows (``make_state(rows)``): the prompt in chunks of
+    ``chunk``, then a scan of single-token steps."""
+    ids = ids.astype(jnp.int32)
+    b, s = ids.shape
+    ps = 16
+    pages = -(-(s + max_new_tokens) // ps)
+    pool = make_pool(1 + b * pages, ps)
+    rec = make_state(b)
+    bt = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
+    zero = jnp.zeros((b,), jnp.int32)
+    own = jnp.arange(b, dtype=jnp.int32)
+    rows3 = jnp.stack([own, own, own + b])  # read and write the row's own; no snapshot
+    for at in range(0, s, chunk):
+        c = min(chunk, s - at)
+        logits, pool, rec, _ = forward(
+            pool, rec, bt, ids[:, at : at + c], zero + at, counts=zero + c, pick=zero + (c - 1), state_rows=rows3
+        )
+    first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+
+    def step(carry, _):
+        tok, pos, pool, rec = carry
+        logits, pool, rec, _ = forward(pool, rec, bt, tok[:, None], pos)
+        return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), pos + 1, pool, rec), tok
+
+    (last, _, _, _), toks = lax.scan(step, (first, zero + s, pool, rec), None, length=max_new_tokens - 1)
     return jnp.concatenate([ids, toks.T.reshape(b, -1), last[:, None]], axis=1)
 
 

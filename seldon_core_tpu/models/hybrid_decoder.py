@@ -57,12 +57,12 @@ from seldon_core_tpu.models.decoder import (
     SCOPE_LM_HEAD,
     SCOPE_MLP,
     SCOPE_QKV,
-    SCOPE_SAMPLE,
     FamilyNotServed,
     _paged_gather,
     _paged_write,
+    counted_state_programs,
     kv_pool_zeros,
-    sample_tokens,
+    paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, _attend, _rms
 
@@ -411,37 +411,16 @@ def _forward(
     return logits, pool, rec, advanced
 
 
-def _generate(cfg, params, ids, max_new_tokens: int, chunk: int = 256):
-    """Greedy whole-batch decode ids[b, s] -> [b, s + max_new_tokens]: the
-    fused fallback apply of a deployment without ``tpu.decode_slots``. The
-    SAME paged forward over a private pool and private state rows: the
-    prompt in chunks of ``chunk``, then a scan of single-token steps."""
-    ids = ids.astype(jnp.int32)
-    b, s = ids.shape
-    ps = 16
-    pages = -(-(s + max_new_tokens) // ps)
+def _generate(cfg, params, ids, max_new_tokens: int):
+    """The fused fallback apply of a deployment without ``tpu.decode_slots``
+    (``decoder.paged_state_greedy_generate``) over a private pool and
+    private state rows."""
     dims = {"kv_layers": len(cfg.attn_layers), "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim}
-    pool = kv_pool_zeros(dims, 1 + b * pages, ps, params["tok_emb"].dtype)
-    rec = state_zeros(cfg, b)
-    bt = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
-    zero = jnp.zeros((b,), jnp.int32)
-    own = jnp.arange(b, dtype=jnp.int32)
-    rows3 = jnp.stack([own, own, own + b])  # read and write the row's own; no snapshot
-    for at in range(0, s, chunk):
-        c = min(chunk, s - at)
-        logits, pool, rec, _ = _forward(
-            cfg, params, pool, rec, bt, ids[:, at : at + c], zero + at, counts=zero + c,
-            pick=zero + (c - 1), state_rows=rows3,
-        )
-    first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-
-    def step(carry, _):
-        tok, pos, pool, rec = carry
-        logits, pool, rec, _ = _forward(cfg, params, pool, rec, bt, tok[:, None], pos)
-        return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), pos + 1, pool, rec), tok
-
-    (last, _, _, _), toks = lax.scan(step, (first, zero + s, pool, rec), None, length=max_new_tokens - 1)
-    return jnp.concatenate([ids, toks.T.reshape(b, -1), last[:, None]], axis=1)
+    return paged_state_greedy_generate(
+        functools.partial(_forward, cfg, params),
+        lambda n_pages, ps: kv_pool_zeros(dims, n_pages, ps, params["tok_emb"].dtype),
+        functools.partial(state_zeros, cfg), ids, max_new_tokens,
+    )
 
 
 # ------------------------------------------------------------------ family
@@ -487,34 +466,11 @@ class HybridDecoder:
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
-        """This family's step and chunk bodies under the families' common
-        names. Both take the state cache ``rec`` after the pool (donated
-        with it) and give it back after it; the step takes ``rows`` (the
-        slots that generate: the others' state stands), the chunk
-        ``state_rows`` [3, rows]; the count rides the token readback."""
-
-        def sample_and_count(logits, counted, temps, topks, seed, tick):
-            with jax.named_scope(SCOPE_SAMPLE):
-                key = jax.random.fold_in(jax.random.key(seed), tick)
-                toks = sample_tokens(logits[:, 0, :], temps, topks, key)
-                return jnp.concatenate([toks, counted])
-
-        def step(params, pool, rec, bt, tokens, positions, temps, topks, seed, tick, rows):
-            logits, pool, rec, counted = self.paged_forward(
-                params, pool, rec, bt, tokens[:, None], positions, rows=rows
-            )
-            return sample_and_count(logits, counted, temps, topks, seed, tick), pool, rec
-
-        def chunk(params, pool, rec, bt, ids, positions, counts, temps, topks, seed, tick, state_rows):
-            idx = jnp.clip(counts - 1, 0, ids.shape[1] - 1)
-            logits, pool, rec, counted = self.paged_forward(
-                params, pool, rec, bt, ids, positions, counts=counts, pick=idx, state_rows=state_rows
-            )
-            return sample_and_count(logits, counted, temps, topks, seed, tick), pool, rec
-
-        step.__name__ = step.__qualname__ = "_fused_step"
-        chunk.__name__ = chunk.__qualname__ = "_fused_chunk"
-        return step, chunk
+        """This family's step and chunk bodies (``decoder.
+        counted_state_programs``: both carry the state cache beside the
+        pool, the step takes ``rows``, the chunk ``state_rows``). Cached:
+        equal configurations share compiled programs."""
+        return counted_state_programs(self.paged_forward)
 
     def generate(self, params, ids, max_new_tokens: int):
         return _generate(self.cfg, params, ids, max_new_tokens)

@@ -19,7 +19,8 @@ What the block has, beside the three families before it:
   the family's score scale ``(nope + rope)^-0.5 * mscale^2``;
 - ``dense_layers`` leading layers with a dense gated MLP, then layers of one
   SHARED expert every token takes plus routed experts under the sigmoid,
-  group-limited, scaled gate (ops/moe.py ``route_sigmoid_grouped``);
+  group-limited, scaled gate (ops/moe.py ``route_sigmoid_grouped``: the
+  family routes, ``moe_held_ffn`` takes the picks);
 - an expert layer that holds ONE CHIP'S SHARE: the router keeps its
   ``experts`` outputs, the parameters hold ``experts_held`` of them from
   ``first_expert``, and a pick that lands elsewhere adds nothing
@@ -73,6 +74,7 @@ from seldon_core_tpu.ops.moe import (
     SCOPE_SHARED_EXPERT,
     gated_mlp,
     moe_held_ffn,
+    route_sigmoid_grouped,
 )
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
@@ -258,10 +260,11 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
             with jax.named_scope(SCOPE_DENSE_MLP):
                 y, cnt = gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((4,), jnp.int32)
         else:
-            y, cnt = moe_held_ffn(
-                p["moe"], h, c.experts_per_tok, c.n_group, c.topk_group, c.routed_scale, c.first_expert,
-                valid.reshape(-1),
+            real = valid.reshape(-1)
+            gates, experts = route_sigmoid_grouped(
+                p["moe"]["router"], h, c.experts_per_tok, c.n_group, c.topk_group, c.routed_scale
             )
+            y, cnt = moe_held_ffn(p["moe"], h, gates, experts, c.first_expert, real)
             with jax.named_scope(SCOPE_SHARED_EXPERT):
                 y = y + gated_mlp(p["moe"]["shared_gate_up"], p["moe"]["shared_down"], h)
         x = x + y.reshape(x.shape)

@@ -652,6 +652,79 @@ def build_mla_decoder(
     )
 
 
+@register_model("conv_decoder")
+def build_conv_decoder(
+    seed: int = 0,
+    vocab: int = 512,
+    hidden: int = 64,
+    layers: int = 8,
+    attn_layers: str = "2,6",
+    heads: int = 4,
+    kv_heads: int = 2,
+    head_dim: int = 16,
+    conv_taps: int = 3,
+    dense_layers: int = 2,
+    dense_ffn: int = 128,
+    ffn: int = 32,
+    experts: int = 16,
+    experts_held: int = 0,
+    first_expert: int = 0,
+    experts_per_tok: int = 4,
+    routed_scale: float = 1.0,
+    rope_theta: float = 1000000.0,
+    rms_eps: float = 1e-5,
+    max_len: int = 128000,
+    seq: int = 32,
+    max_new_tokens: int = 16,
+    param_dtype: str = "bfloat16",
+    **_,
+) -> ModelSpec:
+    """The generative tier's fifth decoder family (models/conv_decoder.py,
+    the LFM2 block): gated short-convolution layers whose cache is
+    ``conv_taps - 1`` rows a layer, rotary grouped-query attention with an
+    RMS norm on each head's q and k in the layers ``attn_layers`` names
+    (comma-separated indices), ``dense_layers`` leading dense MLPs, then
+    ``experts_per_tok`` of ``experts`` routed experts under a sigmoid gate
+    whose bias selects and does not weigh; a tied head. The parameters are
+    a published config's keys; ``ffn`` is ONE expert's width.
+    ``experts_held`` (0: all) from ``first_expert`` is one chip's share of an
+    expert-parallel deployment: the router keeps ``experts`` outputs and a
+    pick that lands on an absent expert adds nothing. It serves through
+    ``tpu.decode_slots``: the conv cache lives in state rows beside the KV
+    pages, sized from ``decode_slots`` and ``decode_prefix_slots``; without
+    it the fused fallback decodes whole batches greedily through the same
+    forward. Speculation, tensor-parallel decode, the step attention kernel,
+    the int8 pool, the KV tiers and prefix export are not served for it."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.conv_decoder import ConvDecoderConfig, conv_family, init_conv_decoder
+
+    if seq + max_new_tokens > max_len:
+        raise ValueError(
+            f"seq={seq} + max_new_tokens={max_new_tokens} exceeds max_len={max_len}"
+        )
+    cfg = ConvDecoderConfig(
+        vocab=int(vocab), hidden=int(hidden), layers=int(layers),
+        attn_layers=tuple(int(i) for i in str(attn_layers).split(",") if i.strip()),
+        heads=int(heads), kv_heads=int(kv_heads), head_dim=int(head_dim), conv_taps=int(conv_taps),
+        dense_layers=int(dense_layers), dense_ffn=int(dense_ffn), ffn=int(ffn), experts=int(experts),
+        experts_held=int(experts_held) or int(experts), first_expert=int(first_expert),
+        experts_per_tok=int(experts_per_tok), routed_scale=float(routed_scale), rope_theta=float(rope_theta),
+        rms_eps=float(rms_eps), max_len=int(max_len),
+    )
+    family = conv_family(cfg)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[str(param_dtype)]
+    max_new = int(max_new_tokens)
+    return ModelSpec(
+        lambda p, x: family.generate(p, x, max_new),
+        init_conv_decoder(cfg, int(seed), dtype),
+        (int(seq),),
+        (),
+        int_inputs="ids",
+        generative={"seq": int(seq), "max_new_tokens": max_new, "family": family},
+    )
+
+
 @register_model("draft")
 def build_draft(
     seed: int = 0,

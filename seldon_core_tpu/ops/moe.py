@@ -111,8 +111,9 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 #   anyway, so this reads 64/56 of the least bytes.
 #
 # One chip's share of an expert-parallel layer (models/mla_decoder.py, PR 37):
-# the router keeps its published width and routes over ALL experts
-# (``route_sigmoid_grouped``), the chip holds ``held`` of them from ``first``
+# the family's router keeps its published width and routes over ALL experts
+# (``route_sigmoid_grouped``, ``route_sigmoid_biased``; ``moe_held_ffn`` takes
+# the picks, whichever gate made them), the chip holds ``held`` of them from ``first``
 # (``p["gate_up"].shape[0]`` of the stored weights), and ``held_picks`` hands
 # the SAME two forms the picks renumbered to the experts held, an absent
 # pick as the junk group at gate zero: the masked form's one-hot matches no
@@ -317,21 +318,36 @@ def gated_mlp(gate_up: jax.Array, down: jax.Array, x: jax.Array) -> jax.Array:
     return _gated_silu(x @ gate_up.astype(x.dtype)) @ down.astype(x.dtype)
 
 
+def route_sigmoid_biased(
+    router_w: jax.Array, bias: jax.Array, x: jax.Array, k: int, scale: float
+) -> tuple[jax.Array, jax.Array]:
+    """x[T, d] -> (gates[T, k] float32, experts[T, k] int32) of a gate whose
+    per-expert bias SELECTS and does not weigh (``use_expert_bias``; the
+    bias is what load balancing trains): sigmoid scores over ALL experts in
+    float32, no groups; the top k of ``scores + bias`` (ties to the lower
+    index); gates are the picks' UNBIASED scores over their sum + 1e-6,
+    times ``scale``."""
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        s = jax.nn.sigmoid(x.astype(jnp.float32) @ router_w.astype(jnp.float32))  # [T, E]
+        _, top_e = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        top_s = jnp.take_along_axis(s, top_e, axis=1)
+        return top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6) * scale, top_e.astype(jnp.int32)
+
+
 def moe_held_ffn(
-    p: dict, x: jax.Array, k: int, n_group: int, topk_group: int, scale: float, first: int,
-    valid: jax.Array | None = None,
+    p: dict, x: jax.Array, gates: jax.Array, experts: jax.Array, first: int, valid: jax.Array | None = None
 ):
     """The ROUTED part of the expert layer on a chip that holds the experts
-    ``[first, first + p["gate_up"].shape[0])`` of ``p["router"].shape[1]``:
-    route over all of them, compute the picks that land here, add nothing
-    for the others. Returns (y[T, d], counters[4] int32: real rows, held
-    experts with a row, the fullest held expert's rows, picks of real rows
-    that landed on a held expert)."""
+    ``[first, first + p["gate_up"].shape[0])`` of those the family's router
+    chose among (``gates`` / ``experts`` [T, k] over ALL of them:
+    ``route_sigmoid_grouped``, ``route_sigmoid_biased``): compute the picks
+    that land here, add nothing for the others. Returns (y[T, d],
+    counters[4] int32: real rows, held experts with a row, the fullest held
+    expert's rows, picks of real rows that landed on a held expert)."""
     t = x.shape[0]
     if valid is None:
         valid = jnp.ones((t,), bool)
     held = p["gate_up"].shape[0]
-    gates, experts = route_sigmoid_grouped(p["router"], x, k, n_group, topk_group, scale)
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         gates, experts, here = held_picks(gates, experts, first, held)
         local = jnp.sum(here & valid[:, None], dtype=jnp.int32)

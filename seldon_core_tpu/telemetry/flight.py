@@ -426,7 +426,11 @@ class FlightFrame:
     that landed on a held expert, summed over layers (of ``moe_rows`` x top
     k x expert layers routed), and the latent cache rows the dispatches'
     live rows attended over, each row's keys summed (one layer's: every
-    layer reads as many); 0 for another family; ``mla_pages_read`` /
+    layer reads as many); 0 for another family;
+    ``conv_rows`` the batch rows whose short-convolution cache the round's
+    chunk and step dispatches advanced (models/conv_decoder.py: the second
+    family with state rows, whose ``state_restores`` / ``state_captures``
+    are the same two counts); ``mla_pages_read`` /
     ``mla_run_pages`` where that family's step ran its kernel (ops/mla.py
     ``mla_decode_attention``): the pages it fetched for the live rows, and
     those among them that lay in runs of consecutive pages and came in ONE
@@ -451,7 +455,7 @@ class FlightFrame:
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
-        "chunk_c", "ingress_ns", "ingress_requests",
+        "chunk_c", "ingress_ns", "ingress_requests", "conv_rows",
     )
 
     def __init__(
@@ -467,7 +471,7 @@ class FlightFrame:
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
-        chunk_c=0, ingress_ns=0, ingress_requests=0,
+        chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -516,6 +520,7 @@ class FlightFrame:
         self.chunk_c = chunk_c
         self.ingress_ns = ingress_ns
         self.ingress_requests = ingress_requests
+        self.conv_rows = conv_rows
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -590,6 +595,8 @@ class FlightFrame:
             d["moe"] = [self.moe_rows, self.moe_experts_hit, self.moe_load_max]
         if self.ssm_rows:
             d["ssm"] = [self.ssm_rows, self.state_restores, self.state_captures]
+        if self.conv_rows:
+            d["conv"] = [self.conv_rows, self.state_restores, self.state_captures]
         if self.mla_ctx_rows:
             d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
         if self.mla_pages_read:

@@ -499,6 +499,13 @@ def _share(p, first, held):
     return {**p, "gate_up": p["gate_up"][first : first + held], "down": p["down"][first : first + held]}
 
 
+def _held_ffn(p, x, first, valid=None):
+    """The latent family's gate, then the held-expert layer over its picks
+    (two functions since PR 41: the family routes)."""
+    gates, experts = moe.route_sigmoid_grouped(p["router"], x, 4, 4, 2, 2.5)
+    return moe.moe_held_ffn(p, x, gates, experts, first, valid)
+
+
 def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_uncut_layer(ref):
     """Four chips of four experts each: their routed parts (a pick that lands
     on an absent expert adds nothing, gates over all four picks) plus the
@@ -506,7 +513,7 @@ def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_u
     p = _expert_layer()
     n2 = jax.random.normal(jax.random.key(8), (24, CFG.hidden))
     uncut = ref.expert_ffn(p, n2, first_expert=0, act="float32", **ROUTE)
-    parts = [moe.moe_held_ffn(_share(p, 4 * s, 4), n2, 4, 4, 2, 2.5, 4 * s) for s in range(4)]
+    parts = [_held_ffn(_share(p, 4 * s, 4), n2, 4 * s) for s in range(4)]
     total = sum(y for y, _ in parts) + moe.gated_mlp(p["shared_gate_up"], p["shared_down"], n2)
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=5e-6)
     assert sum(int(c[3]) for _, c in parts) == 24 * 4  # every pick landed on exactly one chip
@@ -525,7 +532,7 @@ def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch
     p = _share(_expert_layer(1), 4, 4)
     n2 = jax.random.normal(jax.random.key(rows), (rows, CFG.hidden))
     valid = jnp.arange(rows) < rows - 5
-    y, counted = jax.jit(lambda p, x, v: moe.moe_held_ffn(p, x, 4, 4, 2, 2.5, 4, v))(p, n2, valid)
+    y, counted = jax.jit(lambda p, x, v: _held_ffn(p, x, 4, v))(p, n2, valid)
     want = np.asarray(ref.expert_ffn(p, n2, first_expert=4, act="float32", shared=False, **ROUTE))
     np.testing.assert_allclose(np.asarray(y[: rows - 5]), want[: rows - 5], atol=5e-6)
     assert not np.asarray(y[rows - 5 :]).any()

@@ -623,6 +623,61 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
 
 
+@pytest.mark.parametrize("program", ["step", "chunk_2_64"])
+def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, program):
+    """The fifth family's fused step (64 slots) and (2, 64) chunk at the
+    lfm2-24b-a2b cell's widths, 8 of its 40 layers (two periods: 6 conv + 2
+    attention layers, the 2 dense MLPs and 6 expert layers of 8 held of 64):
+    the donated pool AND the donated conv state rows come back aliased, no op
+    copies an array the size of a state array, and the family's scopes name
+    the conv operator, the QK norm and the routed experts."""
+    from seldon_core_tpu.models import conv_decoder as cd
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = cd.ConvDecoderConfig(
+        vocab=65536, hidden=2048, layers=8, attn_layers=(2, 6), heads=32, kv_heads=8, head_dim=64, dense_layers=2,
+        dense_ffn=11776, ffn=1536, experts=64, experts_held=8, first_expert=0, experts_per_tok=4,
+    )
+    fam = cd.conv_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: cd.init_conv_decoder(cfg, 0, jnp.bfloat16)))
+    n, rows_total = 64, 64 + 4 + 1
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 6144, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
+    assert pool[0].shape == (2, 6144, 16, 512) and len(rec) == 6 and rec[0].shape == (rows_total, 4096)
+    step, chunk = fam.fused_programs()
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "step":
+        args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+        fn = step
+    else:
+        r, c = 2, 64
+        args = (arr((r, 144), i32), arr((r, c), i32), arr((r,), i32), arr((r,), i32), arr((r,), f32),
+                arr((r,), i32), arr((), i32), arr((), i32), arr((3, r), i32))
+        fn = chunk
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, rec, *args).compile()
+    donated = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in (*pool, *rec))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    state = re.escape("f32[%d,4096]" % rows_total)
+    assert not [ln for ln in text.splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
+    where = "step" if program == "step" else "chunk"
+    for scope in ("qkv/conv_in", "attn/conv_mix", "attn_out/conv_out", "qkv/qk_norm", "qkv/rope", "mlp/dense",
+                  "mlp/moe_router", "mlp/moe_experts"):
+        assert re.search(r'op_name="jit\(_fused_%s\)/%s/' % (where, scope), text), scope
+    # the attention layers' K and V: a row a slot in the step, 2 x 5 whole pages in the (2, 64) chunk (PR 40)
+    want = (64, "row") if program == "step" else (2 * 5, "page")
+    assert _pool_scatters(text, pool) == [want] * 4
+
+
 @pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
 def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, family):
     """Each family's fused step at its cell's rows and vocabulary: what runs
