@@ -39,9 +39,11 @@ an index past the last row drops the write), and positions past
 ``counts[r]`` leave the cache as it was: the hybrid family's contract, with
 nothing of Mamba's in it.
 
-Not served: speculation, a decode mesh, the step attention kernel, the int8
-pool, the host tier, prefix export (``serves`` is empty; each refuses by
-name, ``decoder.require_served``).
+On one TPU the STEP's attention layers read the pool's pages where they lie
+(ops/gqa_decode.py ``gqa_decode_attention``, where ``decode_programs.
+_step_attn_kernel`` chooses it; chunks and the CPU keep the gather). Not
+served: speculation, a decode mesh, the int8 pool, the host tier, prefix
+export (each refuses by name, ``decoder.require_served``).
 """
 
 from __future__ import annotations
@@ -64,12 +66,14 @@ from seldon_core_tpu.models.decoder import (
     SCOPE_QKV,
     FamilyNotServed,
     _paged_gather,
+    _paged_step_reads,
     _paged_write,
     counted_state_programs,
     kv_pool_zeros,
     paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, SCOPE_ROPE, _attend, _rms, _rope
+from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
 from seldon_core_tpu.ops.moe import (
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
@@ -270,11 +274,15 @@ def _conv(cfg: ConvDecoderConfig, si: int, p, x, rec, valid, state_rows):
     return out, tuple(state if i == si else a for i, a in enumerate(rec))
 
 
-def _attention(cfg: ConvDecoderConfig, ki: int, p, x, pool, bt, positions, counts):
+def _attention(cfg: ConvDecoderConfig, ki: int, p, x, pool, bt, positions, counts, reads=None, interpret=False):
     """Grouped-query attention over pool layer ``ki``: q and k normed a
     head, then rotated, at the row's own position; K and V scatter through
-    the block tables and attention reads them back through the gather (the
-    families' write-then-read). Returns (the mixer's output [n, m, d], pool)."""
+    the block tables and attention reads them back (the families'
+    write-then-read): through the gather, or, where the step was given
+    ``reads`` (``decoder._paged_step_reads``: one query a slot and the
+    program set chose the kernel), through ops/gqa_decode.py's kernel, which
+    reads the pages where they lie. Returns (the mixer's output [n, m, d],
+    pool)."""
     n, m, _ = x.shape
     q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]  # [n, m]
     with jax.named_scope(SCOPE_QKV):
@@ -287,13 +295,19 @@ def _attention(cfg: ConvDecoderConfig, ki: int, p, x, pool, bt, positions, count
             q = _rope(q, q_pos, cfg.inv_freq, 1.0)
             k = _rope(k, q_pos, cfg.inv_freq, 1.0).reshape(n, m, cfg.kv_width)  # token rows, normed and rotated
     pool = _paged_write(pool, ki, k, v, bt, positions, counts)
-    ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
-    with jax.named_scope(SCOPE_ATTN):
-        visible = jnp.arange(ck.shape[2], dtype=positions.dtype)[None, None, :] <= q_pos[:, :, None]
-        if 4 * n * cfg.heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
-            ctx = lax.map(lambda a: _attend(*(t[None] for t in a))[0], (q, ck, cv, visible))
-        else:
-            ctx = _attend(q, ck, cv, visible)
+    if reads is not None:
+        with jax.named_scope(SCOPE_ATTN):
+            ctx = gqa_decode_attention(
+                q[:, 0], pool[0], pool[1], ki, bt, *reads, scale=cfg.head_dim**-0.5, interpret=interpret
+            )[:, None]
+    else:
+        ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
+        with jax.named_scope(SCOPE_ATTN):
+            visible = jnp.arange(ck.shape[2], dtype=positions.dtype)[None, None, :] <= q_pos[:, :, None]
+            if 4 * n * cfg.heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
+                ctx = lax.map(lambda a: _attend(*(t[None] for t in a))[0], (q, ck, cv, visible))
+            else:
+                ctx = _attend(q, ck, cv, visible)
     with jax.named_scope(SCOPE_ATTN_OUT):
         return ctx @ p["attn_o"].astype(x.dtype), pool
 
@@ -311,26 +325,32 @@ def _feed_forward(cfg: ConvDecoderConfig, p, h, valid):
     return moe_held_ffn(p["moe"], h, gates, experts, cfg.first_expert, valid)
 
 
-def _forward(cfg, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None):
+def _forward(
+    cfg, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None, attn_kernel=""
+):
     """Shared body of the paged programs, with ``hybrid_decoder._forward``'s
     arguments: tokens[n, m], slot i's query j at positions[i] + j;
     ``counts`` [n] (chunk rounds), ``rows`` [n] bool (the step's generating
     slots), ``pick`` [n] (the head's one query a row), ``state_rows`` [3, n]
-    (``_conv``). Returns (logits [n, m or 1, vocab] float32, pool, rec,
-    counters[5] int32: ``ConvDecoder.frame_counters``)."""
+    (``_conv``); ``attn_kernel`` (static; "" | "mosaic" | "interpret":
+    ``decode_programs._step_attn_kernel``'s answer) lets a dispatch of ONE
+    query a slot read the pool through ops/gqa_decode.py's kernel, every
+    other shape gathers. Returns (logits [n, m or 1, vocab] float32, pool,
+    rec, counters[6] int32: ``ConvDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     if counts is not None:
         valid &= jnp.arange(m)[None, :] < counts[:, None]
     if rows is not None:
         valid &= rows[:, None]
+    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
     cnt = jnp.zeros((4,), jnp.int32)
     for li, p in enumerate(params["layers"]):
         ci = cfg.cache_index(li)
         if li in cfg.attn_layers:
-            mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts)
+            mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts, reads, attn_kernel == "interpret")
         else:
             mix, rec = _conv(cfg, ci, p, x, rec, valid, state_rows)
         with jax.named_scope(SCOPE_ATTN_OUT):
@@ -350,7 +370,7 @@ def _forward(cfg, params, pool, rec, bt, tokens, positions, counts=None, rows=No
         # rows whose conv state the dispatch advanced are one layer's too
         cnt = cnt.at[0].set(jnp.sum(valid, dtype=jnp.int32))
         advanced = jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32)
-    return logits, pool, rec, jnp.concatenate([cnt, advanced[None]])
+    return logits, pool, rec, jnp.concatenate([cnt, advanced[None], run_pages])
 
 
 # ------------------------------------------------------------------ family
@@ -369,10 +389,13 @@ class ConvDecoder:
     # what the programs' readback carries after the tokens (FlightFrame
     # fields): the routing over the experts HELD and the picks of real rows
     # that landed on one (the latent family's four), and the batch rows
-    # whose conv state the dispatch advanced
-    frame_counters = ("moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "conv_rows")
-    # nothing beside the plain rounds yet (decoder.require_served)
-    serves = frozenset()
+    # whose conv state the dispatch advanced, and where the step's kernel ran
+    # the pages it fetched in run DMAs (one layer's K)
+    frame_counters = (
+        "moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "conv_rows", "attn_run_pages",
+    )
+    # beside the plain rounds: a step that reads the pool in place (ops/gqa_decode.py's kernel)
+    serves = frozenset({"attn_kernel"})
 
     def decoder_dims(self, params: dict) -> dict:
         if "lm_head" in params or not any("conv_in" in p for p in params["layers"]):
@@ -393,16 +416,20 @@ class ConvDecoder:
         return state_zeros(self.cfg, rows)
 
     def paged_forward(
-        self, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None
+        self, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None,
+        attn_kernel="",
     ):
-        return _forward(self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows)
+        return _forward(
+            self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows, attn_kernel
+        )
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.
-        counted_state_programs``). Cached: equal configurations share
-        compiled programs."""
-        return counted_state_programs(self.paged_forward)
+        counted_state_programs``); with ``attn_kernel`` the one whose
+        dispatch is one query a slot, the step, reads the pool through the
+        kernel. Cached: equal configurations share compiled programs."""
+        return counted_state_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def generate(self, params, ids, max_new_tokens: int):
         """The fused fallback apply (``decoder.paged_state_greedy_generate``)

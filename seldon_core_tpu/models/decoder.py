@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from seldon_core_tpu.ops.gqa_decode import pages_fetched, step_reads
 from seldon_core_tpu.ops.paged_attention import paged_attention_decode, slot_lengths
 
 
@@ -898,6 +899,23 @@ def _paged_gather(pool: tuple, li: int, bt, h: int) -> tuple[jax.Array, jax.Arra
         v = vq[li, bt].astype(jnp.float32) * sv[li, bt][..., None] + zv[li, bt][..., None]
     n, p, ps, w = k.shape
     return _split_heads(k.reshape(n, p * ps, w), h), _split_heads(v.reshape(n, p * ps, w), h)
+
+
+def _paged_step_reads(attn_kernel: str, queries: int, pool: tuple, bt, positions, rows):
+    """What a grouped-query family's program hands ops/gqa_decode.py's
+    kernel, once for all its attention layers (they walk the same tables):
+    (``gqa_decode.step_reads``' lengths and run flags, the pages of one
+    layer's K that come in run DMAs as int32[1]) where the program set chose
+    a kernel (``attn_kernel``) AND the dispatch has one query a slot against
+    the two-plane float pool, else (None, zero): the gather. What is left of
+    the gather there, a few integers a slot, stays under the ``kv_gather``
+    scope."""
+    if not attn_kernel or queries != 1 or len(pool) != 2:
+        return None, jnp.zeros((1,), jnp.int32)
+    with jax.named_scope(SCOPE_KV_GATHER):
+        page_size = pool[0].shape[2]
+        reads = step_reads(bt, positions, rows, page_size)
+        return reads, pages_fetched(*reads, page_size, bt.shape[1])[1:]
 
 
 def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None, attn_kernel=""):

@@ -35,9 +35,11 @@ rides the step as junk and must keep its state. A chunk's batch row names
 the row it reads, the row it writes and the snapshot row it also writes
 (``state_rows`` [3, n]; an index past the last row drops the write).
 
-Not served: speculation, a decode mesh, the step attention kernel, the int8
-pool, the host tier, prefix export (``serves`` is empty; each refuses by
-name, ``decoder.require_served``).
+On one TPU the STEP's attention layers read the pool's pages where they lie
+(ops/gqa_decode.py ``gqa_decode_attention``, where ``decode_programs.
+_step_attn_kernel`` chooses it; chunks and the CPU keep the gather). Not
+served: speculation, a decode mesh, the int8 pool, the host tier, prefix
+export (each refuses by name, ``decoder.require_served``).
 """
 
 from __future__ import annotations
@@ -59,12 +61,14 @@ from seldon_core_tpu.models.decoder import (
     SCOPE_QKV,
     FamilyNotServed,
     _paged_gather,
+    _paged_step_reads,
     _paged_write,
     counted_state_programs,
     kv_pool_zeros,
     paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, _attend, _rms
+from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
 # name so readers of those still see whole steps: ``qkv/ssm_in``,
@@ -347,49 +351,60 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
     return out, rec
 
 
-def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, counts):
+def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, counts, reads=None, interpret=False):
     """Grouped-query attention without positions over pool layer ``ki``:
-    K and V scatter through the block tables, attention reads them back
-    through the gather, like the other families' write-then-read. Returns
-    (the mixer's output [n, m, d], pool)."""
+    K and V scatter through the block tables and attention reads them back,
+    like the other families' write-then-read: through the gather, or, where
+    the step was given ``reads`` (``decoder._paged_step_reads``), through
+    ops/gqa_decode.py's kernel, which reads the pages where they lie.
+    Returns (the mixer's output [n, m, d], pool)."""
     n, m, _ = x.shape
     with jax.named_scope(SCOPE_QKV):
         qkv = _rms(p["ln1"], x, cfg.rms_eps) @ p["attn_qkv"].astype(x.dtype)
         q, k, v = jnp.split(qkv, [cfg.q_width, cfg.q_width + cfg.kv_width], axis=-1)
         q = q.reshape(n, m, cfg.heads, cfg.head_dim)
     pool = _paged_write(pool, ki, k, v, bt, positions, counts)
-    ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
-    with jax.named_scope(SCOPE_ATTN):
-        q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]
-        visible = jnp.arange(ck.shape[2], dtype=positions.dtype)[None, None, :] <= q_pos[:, :, None]
-        scale = cfg.attention_multiplier
-        if 4 * n * cfg.heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
-            ctx = lax.map(
-                lambda a: _attend(*(t[None] for t in a), scale=scale)[0], (q, ck, cv, visible)
-            )
-        else:
-            ctx = _attend(q, ck, cv, visible, scale=scale)
+    scale = cfg.attention_multiplier
+    if reads is not None:
+        with jax.named_scope(SCOPE_ATTN):
+            ctx = gqa_decode_attention(q[:, 0], pool[0], pool[1], ki, bt, *reads, scale=scale, interpret=interpret)[:, None]
+    else:
+        ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
+        with jax.named_scope(SCOPE_ATTN):
+            q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]
+            visible = jnp.arange(ck.shape[2], dtype=positions.dtype)[None, None, :] <= q_pos[:, :, None]
+            if 4 * n * cfg.heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
+                ctx = lax.map(
+                    lambda a: _attend(*(t[None] for t in a), scale=scale)[0], (q, ck, cv, visible)
+                )
+            else:
+                ctx = _attend(q, ck, cv, visible, scale=scale)
     with jax.named_scope(SCOPE_ATTN_OUT):
         return ctx @ p["attn_o"].astype(x.dtype), pool
 
 
 def _forward(
-    cfg, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None
+    cfg, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None, attn_kernel=""
 ):
     """Shared body of the paged programs: tokens[n, m], slot i's query j at
     positions[i] + j. ``counts`` [n] (chunk rounds): the first counts[i]
     rows of slot i are real. ``rows`` [n] bool (the step): the slots that
     generate. ``pick`` [n]: the head runs on that one query of each row.
-    ``state_rows`` [3, n] int32: ``_mamba``. Returns (logits [n, m or 1,
-    vocab] float32, pool, rec, ssm_rows: the rows whose state advanced)."""
+    ``state_rows`` [3, n] int32: ``_mamba``. ``attn_kernel`` (static; "" |
+    "mosaic" | "interpret": ``decode_programs._step_attn_kernel``'s answer)
+    lets a dispatch of ONE query a slot read the pool through
+    ops/gqa_decode.py's kernel; every other shape gathers. Returns (logits
+    [n, m or 1, vocab] float32, pool, rec, counters[2] int32:
+    ``HybridDecoder.frame_counters``)."""
     n, m = tokens.shape
     res = cfg.residual_multiplier
+    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens] * jnp.asarray(cfg.embedding_multiplier, params["tok_emb"].dtype)
     for li, p in enumerate(params["layers"]):
         ci = cfg.cache_index(li)
         if li in cfg.attn_layers:
-            mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts)
+            mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts, reads, attn_kernel == "interpret")
         else:
             mix, rec = _mamba(cfg, ci, p, x, rec, counts, rows, state_rows)
         with jax.named_scope(SCOPE_ATTN_OUT):
@@ -408,7 +423,7 @@ def _forward(
         if rows is not None:
             live &= rows
         advanced = jnp.sum(live, dtype=jnp.int32)[None]
-    return logits, pool, rec, advanced
+    return logits, pool, rec, jnp.concatenate([advanced, run_pages])
 
 
 def _generate(cfg, params, ids, max_new_tokens: int):
@@ -436,10 +451,12 @@ class HybridDecoder:
     cfg: HybridDecoderConfig
 
     name = "hybrid"
-    # what the programs' readback carries after the tokens (FlightFrame fields)
-    frame_counters = ("ssm_rows",)
-    # nothing beside the plain rounds yet (decoder.require_served)
-    serves = frozenset()
+    # what the programs' readback carries after the tokens (FlightFrame
+    # fields): the rows whose state advanced, and where the step's kernel ran
+    # the pages it fetched in run DMAs (one layer's K)
+    frame_counters = ("ssm_rows", "attn_run_pages")
+    # beside the plain rounds: a step that reads the pool in place (ops/gqa_decode.py's kernel)
+    serves = frozenset({"attn_kernel"})
 
     def decoder_dims(self, params: dict) -> dict:
         if "lm_head" in params or not any("ssm_in" in p for p in params["layers"]):
@@ -460,17 +477,22 @@ class HybridDecoder:
         return state_zeros(self.cfg, rows)
 
     def paged_forward(
-        self, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None
+        self, params, pool, rec, bt, tokens, positions, counts=None, rows=None, pick=None, state_rows=None,
+        attn_kernel="",
     ):
-        return _forward(self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows)
+        return _forward(
+            self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows, attn_kernel
+        )
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.
         counted_state_programs``: both carry the state cache beside the
-        pool, the step takes ``rows``, the chunk ``state_rows``). Cached:
-        equal configurations share compiled programs."""
-        return counted_state_programs(self.paged_forward)
+        pool, the step takes ``rows``, the chunk ``state_rows``); with
+        ``attn_kernel`` the one whose dispatch is one query a slot, the step,
+        reads the pool through the kernel. Cached: equal configurations
+        share compiled programs."""
+        return counted_state_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def generate(self, params, ids, max_new_tokens: int):
         return _generate(self.cfg, params, ids, max_new_tokens)

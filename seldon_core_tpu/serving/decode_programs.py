@@ -50,6 +50,7 @@ from seldon_core_tpu.models.decoder import (
     speculative_accept,
     speculative_accept_tree,
 )
+from seldon_core_tpu.ops.gqa_decode import gqa_tiles
 from seldon_core_tpu.ops.mla import kernel_tiles
 from seldon_core_tpu.ops.paged_attention import mosaic_tiles
 from seldon_core_tpu.parallel.tp import kv_sharding, tree_node_sharding
@@ -77,16 +78,22 @@ def _scatter_prefill_rows(cache_k, cache_v, k_new, v_new, row_for_slot, valid_sl
     return cache_k, cache_v
 
 
-def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int) -> str:
+def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int) -> str:
     """How the fused decode step's attention reads the pool — THE place the
     choice is made, from what the set can observe and nothing else (no
     knob): "mosaic", a Pallas kernel that reads the pool's pages in place
     and stops at each slot's length, where the family has a kernel to choose
     at all (``serves``), there is no decode mesh, the pool lies on one
-    device that is a TPU, and Mosaic can tile the pool's rows and pages:
-    the two-component float pool's is ops/paged_attention.py (the GPT-2
-    block; ``mosaic_tiles``: gpt2-xl's rows of 1600 and pages of 4 rows are
-    outside it), the ONE-plane latent pool's is ops/mla.py
+    device that is a TPU, and Mosaic can tile the pool's rows and pages.
+    WHICH kernel follows from the pool and the family's heads
+    (``decoder_dims``), and each has its own predicate: the two-plane float
+    pool's is ops/paged_attention.py where every query head has its own
+    K/V head (the GPT-2 block; ``mosaic_tiles``: gpt2-xl's rows of 1600 and
+    pages of 4 rows are outside it) and ops/gqa_decode.py
+    ``gqa_decode_attention`` where ``kv_heads`` are fewer than ``heads``
+    (the short-convolution and hybrid families; ``gqa_tiles``: a two-byte
+    float, rows of whole lane tiles, pages of 16 rows or more, a head that
+    divides a tile); the ONE-plane latent pool's is ops/mla.py
     ``mla_decode_attention`` (``kernel_tiles``: a two-byte float, rows of
     whole lane tiles, pages of 16 rows or more);
     "" — the page gather and the flat path's attention, or the latent
@@ -94,19 +101,22 @@ def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int) -> str:
     tensor-parallel mesh, a family without a kernel, a geometry the kernel
     cannot tile, the CPU backend (where the gather and the walk are the
     oracles). The dispatch's shape is the program's own to see: only one
-    query a slot takes the kernel (models/decoder.py ``_layer_step_paged``,
-    ops/mla.py ``mla_paged_attention``), so chunk, verify and tree programs
-    gather or walk whatever this says."""
+    query a slot takes the kernel (models/decoder.py ``_layer_step_paged``
+    and ``_paged_step_reads``, ops/mla.py ``mla_paged_attention``), so
+    chunk, verify and tree programs gather or walk whatever this says."""
     if "attn_kernel" not in family.serves or mesh is not None or len(pool_state) > 2:
         return ""
     devices = pool_state[0].sharding.device_set
     if len(devices) != 1 or next(iter(devices)).platform != "tpu":
         return ""
     _layers, _pages, page_size, row_width = pool_state[0].shape
+    dtype = pool_state[0].dtype
     if len(pool_state) == 1:
-        tiles = kernel_tiles(row_width, page_size, pool_state[0].dtype)
+        tiles = kernel_tiles(row_width, page_size, dtype)
+    elif kv_heads < heads:
+        tiles = gqa_tiles(row_width, heads, kv_heads, page_size, dtype)
     else:
-        tiles = mosaic_tiles(row_width, heads, page_size, pool_state[0].dtype)
+        tiles = mosaic_tiles(row_width, heads, page_size, dtype)
     return "mosaic" if tiles else ""
 
 
@@ -335,7 +345,8 @@ class DecodePrograms:
 
         # the plain step's read side (the feature twins keep the gather)
         self.attn_kernel = (
-            "" if feature else _step_attn_kernel(family, pool.state, mesh, dims["heads"])
+            "" if feature
+            else _step_attn_kernel(family, pool.state, mesh, dims["heads"], dims["kv_heads"])
         )
         if feature:
             # feature mode swaps the step/chunk pair for feature-carrying

@@ -1314,15 +1314,20 @@ class DecodeScheduler:
         self._metrics.compile(self._deployment, self.n_slots, time.perf_counter() - t0)
         self._warmup_compile_counts = self.compile_counts()
 
-    def _step_attn_pages(self, pos: np.ndarray) -> tuple[int, int]:
+    def _step_attn_pages(self, pos: np.ndarray, rows: np.ndarray) -> tuple[int, int]:
         """(pages a plain step's attention reads for one layer's K, pages
-        its block tables name) from the positions the round built — no
-        readback. The kernel path (``programs.attn_kernel``) fetches each slot's
-        ``ceil((pos + 1) / page_size)`` pages: a free slot's one junk page, a
-        prefilling slot's up to its cursor; the gather path all of them."""
+        its block tables name) from the positions the round built and the
+        slots that generate (``rows``) — no readback. The kernel path
+        (``programs.attn_kernel``) fetches each slot's ``ceil((pos + 1) /
+        page_size)`` pages: a free slot's one junk page, a prefilling slot's
+        up to its cursor, or, where the step is told its rows (a family with
+        state rows: ops/gqa_decode.py ``step_reads``), one page for every
+        slot that does not generate; the gather path all of them."""
         table = self.n_slots * self.pool.pages_per_slot
         if not self.programs.attn_kernel:
             return table, table
+        if self._stateful:
+            pos = np.where(rows, pos, 0)
         return int(pages_read(pos, self.pool.page_size, self.pool.pages_per_slot).sum()), table
 
     def compile_counts(self) -> dict[str, int]:
@@ -3258,7 +3263,7 @@ class DecodeScheduler:
                     await asyncio.sleep(0)
                     continue
 
-                self._rb_attn_pages = self._step_attn_pages(pos)
+                self._rb_attn_pages = self._step_attn_pages(pos, fmask)
                 nxt = await self._step_round(bt, toks, pos, temps, topks, fmask, tick)
                 with self._phase(P_SAMPLING):
                     # sampled-token consumption: the readback array walked

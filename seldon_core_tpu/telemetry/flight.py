@@ -434,7 +434,10 @@ class FlightFrame:
     ``mla_run_pages`` where that family's step ran its kernel (ops/mla.py
     ``mla_decode_attention``): the pages it fetched for the live rows, and
     those among them that lay in runs of consecutive pages and came in ONE
-    DMA a run (one layer's); 0 where the walk ran; ``chunk_c`` the chunk
+    DMA a run (one layer's); 0 where the walk ran; ``attn_run_pages`` of
+    the ``attn_pages_read`` of a round whose step ran the grouped-query
+    kernel (ops/gqa_decode.py), those that came in ONE DMA a run, as the
+    program counted them (one layer's K); 0 elsewhere; ``chunk_c`` the chunk
     length of the round's chunk dispatch (with ``chunk_rows`` its
     ``chunk_buckets`` entry, whose wall is ``busy_ns[F_CHUNK]``), 0 where
     none ran; ``ingress_ns`` / ``ingress_requests`` the submits that reached
@@ -455,7 +458,7 @@ class FlightFrame:
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
-        "chunk_c", "ingress_ns", "ingress_requests", "conv_rows",
+        "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages",
     )
 
     def __init__(
@@ -471,7 +474,7 @@ class FlightFrame:
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
-        chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0,
+        chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -521,6 +524,7 @@ class FlightFrame:
         self.ingress_ns = ingress_ns
         self.ingress_requests = ingress_requests
         self.conv_rows = conv_rows
+        self.attn_run_pages = attn_run_pages
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -584,6 +588,8 @@ class FlightFrame:
             d["promotions"] = self.promotions
         if self.attn_pages_table:
             d["attn_pages"] = [self.attn_pages_read, self.attn_pages_table]
+        if self.attn_run_pages:
+            d["attn_run_pages"] = self.attn_run_pages
         if self.chunk_rows:
             d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
             d["chunk_c"] = self.chunk_c

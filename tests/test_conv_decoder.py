@@ -409,7 +409,7 @@ def _sched(ms, **kw):
 async def test_scheduler_serves_the_family_restores_snapshots_and_never_recompiles():
     ms = _zoo()
     sched = _sched(ms)
-    assert (sched.programs._counted, sched.programs._stateful, sched.programs.attn_kernel) == (5, True, "")
+    assert (sched.programs._counted, sched.programs._stateful, sched.programs.attn_kernel) == (6, True, "")
     assert len(sched.pool.state) == 2 and sched.pool.state[0].shape[0] == 2  # planes for the attention layers only
     assert len(sched.pool.recurrent) == 6 and sched.pool.recurrent[0].shape == (4 + 2 + 1, 2 * 64)
     sched.warmup()
@@ -460,7 +460,7 @@ async def test_interleaved_admissions_over_four_slots_generate_the_fallbacks_tok
     ("kv_int8", {"kv_dtype": "int8"}),
 ])
 def test_what_the_family_does_not_serve_is_refused_by_name(what, kw, weights):
-    assert FAM.serves == frozenset() and FAM.name == "conv"
+    assert FAM.serves == frozenset({"attn_kernel"}) and FAM.name == "conv"
     with pytest.raises(FamilyNotServed, match="'conv' decoder family"):
         require_served(FAM, what)
     with pytest.raises(FamilyNotServed, match="'conv' decoder family"):

@@ -425,7 +425,7 @@ def test_the_other_families_keep_their_own_fused_programs(weights):
     hstep, hchunk = FAM.fused_programs()
     assert (hstep.__name__, hchunk.__name__) == ("_fused_step", "_fused_chunk")  # one name in a trace
     assert hd.hybrid_family(hd.HybridDecoderConfig(**vars(CFG))).fused_programs() == (hstep, hchunk)
-    assert FAM.serves == frozenset() and FAM.frame_counters == ("ssm_rows",)
+    assert FAM.serves == frozenset({"attn_kernel"}) and FAM.frame_counters == ("ssm_rows", "attn_run_pages")
 
 
 @pytest.mark.parametrize("dims_of", ["gpt2", "moe", "hybrid"])
@@ -482,8 +482,8 @@ def test_what_the_family_does_not_serve_is_refused_by_name(what, weights):
                 sched.export_prefix_entry(np.zeros(8, np.int32))
 
 
-def test_the_step_attention_kernel_is_not_chosen_for_the_family(weights):
+def test_the_step_attention_kernel_is_not_chosen_on_the_cpu_backend(weights):
     from seldon_core_tpu.serving import decode_programs as dp
 
-    pool = FAM.paged_kv_init(weights[jnp.float32], 3, 8)
-    assert dp._step_attn_kernel(FAM, pool, None, CFG.heads) == ""
+    pool = FAM.paged_kv_init(weights[jnp.float32], 3, 16, jnp.bfloat16)
+    assert dp._step_attn_kernel(FAM, pool, None, CFG.heads, CFG.kv_heads) == ""

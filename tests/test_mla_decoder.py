@@ -632,7 +632,7 @@ async def test_scheduler_with_the_step_kernel_serves_the_same_tokens_and_counts_
 
     monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
     monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
-    monkeypatch.setattr(dp, "_step_attn_kernel", lambda family, pool_state, mesh, heads: "interpret")
+    monkeypatch.setattr(dp, "_step_attn_kernel", lambda family, pool_state, mesh, heads, kv_heads: "interpret")
     ms = _zoo()
     fam = ms.generative["family"]
     sched = ds.DecodeScheduler(
@@ -782,12 +782,12 @@ def test_the_step_attention_kernel_is_chosen_where_the_latent_plane_tiles(platfo
     device = types.SimpleNamespace(platform=platform)
     plane = types.SimpleNamespace(shape=(3, 7, page, lanes), dtype=jnp.dtype(dtype), sharding=types.SimpleNamespace(device_set=[device]))
     assert "attn_kernel" in FAM.serves
-    assert _step_attn_kernel(FAM, (plane,), mesh, CFG.heads) == want
+    assert _step_attn_kernel(FAM, (plane,), mesh, CFG.heads, 1) == want
 
 
 def test_the_cpu_backends_pool_keeps_the_walk(weights):
     pool = FAM.paged_kv_init(weights[jnp.float32], 7, 16, jnp.bfloat16)
-    assert mla_ops.kernel_tiles(128, 16, jnp.bfloat16) and _step_attn_kernel(FAM, pool, None, CFG.heads) == ""
+    assert mla_ops.kernel_tiles(128, 16, jnp.bfloat16) and _step_attn_kernel(FAM, pool, None, CFG.heads, 1) == ""
 
 
 def test_the_fused_fallback_generates_through_the_same_forward(ref, weights):

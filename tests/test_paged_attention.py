@@ -209,7 +209,7 @@ async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens(mon
     assert frames and all(f.attn_pages_read == f.attn_pages_table == table for f in frames)
     await gather.close()
 
-    monkeypatch.setattr(ds, "_step_attn_kernel", lambda family, pool_state, mesh, heads: "interpret")
+    monkeypatch.setattr(ds, "_step_attn_kernel", lambda family, pool_state, mesh, heads, kv_heads: "interpret")
     kernel = _scheduler(params)
     assert kernel.programs.attn_kernel == "interpret"
     got = await serve(kernel)

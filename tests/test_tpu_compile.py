@@ -275,6 +275,47 @@ def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo)
     assert re.search(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_step\)/attn/', text)
 
 
+def _without_locations(text: str) -> str:
+    """Lowered text with each Mosaic kernel's serialized module (which
+    carries source paths and line numbers) replaced by the hash of its
+    location-free form."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.lib.mlir import ir
+
+    def kernel(m):
+        body = json.loads(m.group(1).replace("\\22", '"'))["custom_call_config"]["body"]
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+        return 'backend_config = "mosaic:%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r'backend_config = "(\{[^\n]*?\})"', kernel, text)
+
+
+def test_gpt2_large_kernel_step_lowers_to_the_text_it_had(topo):
+    """The GPT-2 family's step with ITS kernel (ops/paged_attention.py) at
+    gpt2-large's widths, two layers: what it lowers to for the chip is what
+    it lowered to before the grouped-query kernel came (PR 42's parent,
+    f11f215, hashed there with this helper): the program, and the Mosaic
+    module inside it, source locations aside."""
+    import hashlib
+
+    from seldon_core_tpu.models.decoder import gpt2_family, init_decoder
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: init_decoder(0, vocab=50257, hidden=1280, layers=2, ffn=5120, max_len=1024))
+    geo = {"n_slots": 16, "n_pages": 720, "page_size": 16, "pages_per_slot": 44}
+    p, pool, rest = _step_args(params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one)
+    step, _chunk = gpt2_family.fused_programs("mosaic")
+    text = _without_locations(jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).as_text())
+    assert text.count('"mosaic:') == 1  # the layers' calls lower the kernel once
+    assert hashlib.sha256(text.encode()).hexdigest() == "7118908d312842cc732bc217c04879ef2ece1c3926277df2f2f3699d2995fea9"
+
+
 @pytest.mark.parametrize(
     "pool_kind, width, page_size, want",
     [
@@ -286,6 +327,12 @@ def test_fused_step_with_the_paged_attention_kernel_at_gpt2_large_geometry(topo)
         ("latent", 256, 32, "mosaic"),  # a latent of one lane tile, two sublane tiles a page
         ("latent", 576, 16, ""),  # the published row as it is: not whole lane tiles
         ("latent", 640, 8, ""),  # a page under a two-byte float's sublane tile
+        ("gqa", 512, 16, "mosaic"),  # the lfm2-24b-a2b and granite-4.0-h-micro cells: 32 / 8 heads of 64, bfloat16
+        ("gqa", 1024, 32, "mosaic"),  # 8 K/V heads of 128
+        ("gqa", 512, 4, ""),  # a page under a two-byte float's sublane tile
+        ("gqa_float32", 512, 16, ""),  # grouped heads over a four-byte pool: neither kernel's
+        ("gqa_int8", 512, 16, ""),  # the int8 pool's six components
+        ("gqa_mesh", 512, 16, ""),  # a decode mesh
     ],
 )
 def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, width, page_size, want):
@@ -310,7 +357,7 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, 
             return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
         plane = arr((2, 256, page_size, width), jnp.bfloat16)
-        assert _step_attn_kernel(mla.mla_family(mla.MLADecoderConfig()), (plane,), None, 16) == want
+        assert _step_attn_kernel(mla.mla_family(mla.MLADecoderConfig()), (plane,), None, 16, 1) == want
 
         def attend(qc, plane, bt, n_keys):
             runs = page_runs(bt, n_keys, page_size)
@@ -323,6 +370,36 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, 
             with pytest.raises(ValueError, match="kernel_tiles"):
                 jax.jit(attend).lower(*shapes)
         return
+    if pool_kind.startswith("gqa"):
+        # a two-plane pool whose 8 K/V heads serve 32 query heads: ops/gqa_decode.py's kernel alone (the whole steps
+        # at the cells' widths are the two families' own cases below)
+        from seldon_core_tpu.models import conv_decoder as cd
+        from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention, step_reads
+
+        def arr(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        fam = cd.conv_family(cd.ConvDecoderConfig())
+        dt = jnp.float32 if pool_kind == "gqa_float32" else jnp.bfloat16
+        pool = (arr((2, 256, page_size, width), dt),) * 2
+        if pool_kind == "gqa_int8":
+            pool = (arr((2, 256, page_size, width), jnp.int8), *(arr((2, 256, page_size), jnp.float32),) * 2) * 2
+        mesh = Mesh(np.asarray(topo.devices[:2]), ("model",)) if pool_kind == "gqa_mesh" else None
+        assert _step_attn_kernel(fam, pool, mesh, 32, 8) == want
+        if pool_kind != "gqa":
+            return
+
+        def attend(q, pk, pv, bt, positions, rows):
+            return gqa_decode_attention(q, pk, pv, 1, bt, *step_reads(bt, positions, rows, page_size), scale=0.125)
+
+        shapes = (arr((4, 32, width // 8), jnp.bfloat16), *pool, arr((4, 40), jnp.int32), arr((4,), jnp.int32),
+                  arr((4,), jnp.bool_))
+        if want:
+            assert "tpu_custom_call" in jax.jit(attend).lower(*shapes).compile().as_text()
+        else:
+            with pytest.raises(ValueError, match="gqa_tiles"):
+                jax.jit(attend).lower(*shapes)
+        return
     hidden = width
     params = jax.eval_shape(
         lambda: init_decoder(0, vocab=1024, hidden=hidden, layers=2, ffn=4 * hidden, max_len=256)
@@ -331,7 +408,8 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, 
     p, pool, rest = _step_args(
         params, geo, "", jax.tree.map(lambda _: one, params), lambda s: one, one
     )
-    got = _step_attn_kernel(gpt2_family, pool, None, decoder_dims(params)["heads"])
+    dims = decoder_dims(params)
+    got = _step_attn_kernel(gpt2_family, pool, None, dims["heads"], dims["kv_heads"])
     assert got == want
     step, _chunk = gpt2_family.fused_programs(got)
     text = jax.jit(step, donate_argnums=(1,)).lower(p, pool, *rest).compile().as_text()
@@ -398,9 +476,21 @@ def test_grouped_expert_layer_compiles_with_the_pallas_kernel_at_published_width
     assert compiled.as_text().count("tpu_custom_call") >= 2  # gate_up and down
 
 
-@pytest.mark.parametrize("program", ["step", "chunk_2_64"])
+def _assert_step_reads_the_pool_through_the_kernel(text: str, layers: int):
+    """A grouped-query family's step with ops/gqa_decode.py's kernel: a
+    Mosaic call an attention layer under ``attn``, what is left of the
+    gather (lengths, run flags) under ``kv_gather``, and no gathered
+    float32 cache."""
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_step\)/attn/', text)
+    assert len(calls) == layers
+    assert re.search(r'op_name="jit\(_fused_step\)/kv_gather/', text)
+    assert not re.search(r"f32\[64,\d+,16,512\]", text)  # [slots, table, page, row] upcast
+
+
+@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_2_64"])
 def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo, program):
-    """The third family's fused step (64 slots) and (2, 64) chunk at the
+    """The third family's fused step (64 slots; through the gather, and with
+    the grouped-query kernel as on a TPU) and (2, 64) chunk at the
     granite-4.0-h-micro cell's widths, 10 of its 40 layers (one period: 9
     Mamba-2 layers + 1 attention layer): the donated pool AND the donated
     state rows come back aliased, and no op of the program copies an array
@@ -425,9 +515,9 @@ def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo,
     pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 3400, 16, jnp.bfloat16)))
     rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
     assert pool[0].shape[0] == 1 and len(rec) == 18 and rec[0].shape == (rows_total, 64, 64, 128)
-    step, chunk = fam.fused_programs()
+    step, chunk = fam.fused_programs("mosaic" if program == "step_kernel" else "")
     i32, f32 = jnp.int32, jnp.float32
-    if program == "step":
+    if program.startswith("step"):
         args = (arr((n, 52), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
                 arr((), i32), arr((n,), jnp.bool_))
         fn = step
@@ -444,10 +534,12 @@ def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo,
     state = re.escape("f32[%d,64,64,128]" % rows_total)
     copies = [ln for ln in compiled.as_text().splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
     assert not copies, copies[:2]
-    assert re.search(r'op_name="jit\(_fused_%s\)/attn/ssm_scan/' % ("step" if program == "step" else "chunk"), compiled.as_text())
+    assert re.search(r'op_name="jit\(_fused_%s\)/attn/ssm_scan/' % ("chunk" if program == "chunk_2_64" else "step"), compiled.as_text())
     # the attention layer's K and V: a row a slot in the step, 2 x 5 whole pages in the (2, 64) chunk (PR 40)
-    want = (64, "row") if program == "step" else (2 * 5, "page")
+    want = (2 * 5, "page") if program == "chunk_2_64" else (64, "row")
     assert _pool_scatters(compiled.as_text(), pool) == [want] * 2
+    if program == "step_kernel":
+        _assert_step_reads_the_pool_through_the_kernel(compiled.as_text(), 1)
 
 
 def _ungated_lines(text: str) -> list[str]:
@@ -579,7 +671,7 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    chosen = _step_attn_kernel(fam, pool, None, 64)
+    chosen = _step_attn_kernel(fam, pool, None, 64, 1)
     assert chosen == "mosaic"
     step, chunk = fam.fused_programs(chosen)
     if program == "step":
@@ -623,9 +715,10 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
 
 
-@pytest.mark.parametrize("program", ["step", "chunk_2_64"])
+@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_2_64"])
 def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, program):
-    """The fifth family's fused step (64 slots) and (2, 64) chunk at the
+    """The fifth family's fused step (64 slots; through the gather, and with
+    the grouped-query kernel as on a TPU) and (2, 64) chunk at the
     lfm2-24b-a2b cell's widths, 8 of its 40 layers (two periods: 6 conv + 2
     attention layers, the 2 dense MLPs and 6 expert layers of 8 held of 64):
     the donated pool AND the donated conv state rows come back aliased, no op
@@ -651,9 +744,9 @@ def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, p
     pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 6144, 16, jnp.bfloat16)))
     rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
     assert pool[0].shape == (2, 6144, 16, 512) and len(rec) == 6 and rec[0].shape == (rows_total, 4096)
-    step, chunk = fam.fused_programs()
+    step, chunk = fam.fused_programs("mosaic" if program == "step_kernel" else "")
     i32, f32 = jnp.int32, jnp.float32
-    if program == "step":
+    if program.startswith("step"):
         args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
                 arr((), i32), arr((n,), jnp.bool_))
         fn = step
@@ -669,13 +762,16 @@ def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, p
     text = compiled.as_text()
     state = re.escape("f32[%d,4096]" % rows_total)
     assert not [ln for ln in text.splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
-    where = "step" if program == "step" else "chunk"
+    where = "chunk" if program == "chunk_2_64" else "step"
     for scope in ("qkv/conv_in", "attn/conv_mix", "attn_out/conv_out", "qkv/qk_norm", "qkv/rope", "mlp/dense",
                   "mlp/moe_router", "mlp/moe_experts"):
         assert re.search(r'op_name="jit\(_fused_%s\)/%s/' % (where, scope), text), scope
     # the attention layers' K and V: a row a slot in the step, 2 x 5 whole pages in the (2, 64) chunk (PR 40)
-    want = (64, "row") if program == "step" else (2 * 5, "page")
+    want = (2 * 5, "page") if program == "chunk_2_64" else (64, "row")
     assert _pool_scatters(text, pool) == [want] * 4
+    if program == "step_kernel":
+        _assert_step_reads_the_pool_through_the_kernel(text, 2)
+        assert mem.temp_size_in_bytes < 64 * 144 * 16 * 512 * 4  # under ONE gathered float32 cache
 
 
 @pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
