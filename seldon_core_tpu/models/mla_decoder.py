@@ -26,6 +26,23 @@ What the block has, beside the three families before it:
   ``first_expert``, and a pick that lands elsewhere adds nothing
   (``moe_held_ffn``): the layer runs without its exchange.
 
+Two more keys of the same block, as ``model_type: xing4_0`` publishes it:
+
+- ``hc_mult`` > 1: the residual path is that many STREAMS wide
+  (manifold-constrained hyper-connections, ops/mhc.py). The state between
+  blocks is ``[hc_mult, n, m, hidden]`` (streams-major) from the embedding
+  (replicated) to the final norm (summed); around each attention and feed-forward block a
+  per-token map decides which mixture of the streams the block reads, how
+  its output is written back to each and how the streams mix (a
+  Sinkhorn-normalised ``hc_mult x hc_mult``). The block itself, its pre-norm
+  and the cache row are unchanged: the streams are activations, not state.
+  With ``hc_mult`` 1 there is no stream axis, no map and no sum: the
+  programs are ``x + F(norm(x))``'s, text for text;
+- ``gate_bias``: the router's per-expert bias selects and does not weigh
+  (``topk_method: noaux_tc`` without groups; ops/moe.py
+  ``route_sigmoid_biased``) where no groups are declared; the grouped gate
+  where they are.
+
 RMSNorm, the rotary helper, the expert forms, the sampler and the step /
 chunk wrappers are the other families' (imported, not re-typed).
 
@@ -60,6 +77,7 @@ from seldon_core_tpu.models.decoder import (
     paged_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import SCOPE_ROPE, _rms, _rope, rope_inv_freq
+from seldon_core_tpu.ops import mhc
 from seldon_core_tpu.ops.mla import (
     SCOPE_MLA_CORE,
     absorb_short,
@@ -74,6 +92,7 @@ from seldon_core_tpu.ops.moe import (
     SCOPE_SHARED_EXPERT,
     gated_mlp,
     moe_held_ffn,
+    route_sigmoid_biased,
     route_sigmoid_grouped,
 )
 
@@ -83,6 +102,25 @@ from seldon_core_tpu.ops.moe import (
 # ``mlp/moe_*``, ``mlp/shared_expert``, ``mlp/dense`` (ops/moe.py)
 SCOPE_MLA_Q = "mla_q"  # q_a, its norm, q_b
 SCOPE_MLA_KV = "mla_kv"  # kv_a and the latent's norm
+# with ``hc_mult`` > 1 also ``qkv/mhc_map``, ``qkv/mhc_pre`` and
+# ``attn_out/mhc_post`` round the attention block, ``mlp/mhc_map``,
+# ``mlp/mhc_pre`` and ``mlp/mhc_post`` round the feed-forward one (ops/mhc.py)
+
+# the stream maps' random parameters (``init_mla_decoder``; ops/mhc.py
+# ``init_maps`` sets ``alpha`` from them): the std of the DYNAMIC part of each
+# map's logits, whatever the width, and the diagonal of ``b_res``. H_pre and
+# H_post move over most of their range from token to token; H_res's logits
+# move by 0.24 round a diagonal of 1.5 (mean H_res[i, i] 0.59). Sinkhorn's
+# rate is the square of its limit's second singular value: over 4 M seeded
+# maps 20 iterations leave 2 ppm at the worst with these two numbers, 53 ppm
+# with a diagonal of 2.0 and 1,100 with 2.0 and a std of 0.4 (numpy,
+# float32; PERF.md section 6, PR 43)
+HC_LOGIT_STD = (1.2, 1.2, 0.24)  # pre, post, res
+HC_RES_DIAG = 1.5
+# the selection bias's std, as the short-convolution family draws its gate's
+# (models/conv_decoder.py EXPERT_BIAS_STD): about three gaps between
+# neighbouring sorted scores of 64
+GATE_BIAS_STD = 0.05
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,18 +154,29 @@ class MLADecoderConfig:
     mscale_all_dim: float = 1.0
     rms_eps: float = 1e-6
     max_len: int = 131072
+    gate_bias: bool = False  # topk_method noaux_tc: a per-expert bias selects; no groups then (n_group 0)
+    hc_mult: int = 1  # residual streams; 1: x + F(norm(x))
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0  # mhc_h_res_clamp_max = -mhc_h_res_clamp_min
 
     def __post_init__(self):
         if self.rope_dim % 2:
             raise ValueError(f"rope_dim={self.rope_dim} must be even (rotary pairs)")
-        if self.experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
+        if self.gate_bias:  # the biased gate has no group step: no groups are declared
+            if self.n_group or self.topk_group or not 1 <= self.experts_per_tok <= self.experts:
+                raise ValueError(f"gate_bias with n_group={self.n_group}, topk_group={self.topk_group} (0 and 0: no "
+                                 f"groups), experts_per_tok={self.experts_per_tok} of {self.experts}")
+        elif self.experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"{self.experts} experts in n_group={self.n_group}, topk_group={self.topk_group}")
-        if not 1 <= self.experts_per_tok <= self.topk_group * (self.experts // self.n_group):
+        elif not 1 <= self.experts_per_tok <= self.topk_group * (self.experts // self.n_group):
             raise ValueError(f"experts_per_tok={self.experts_per_tok} of {self.topk_group} groups kept")
         if not 0 <= self.first_expert <= self.experts - self.experts_held or self.experts_held < 1:
             raise ValueError(f"experts [{self.first_expert}, +{self.experts_held}) of {self.experts}")
         if not 0 <= self.dense_layers <= self.layers:
             raise ValueError(f"dense_layers={self.dense_layers} of layers={self.layers}")
+        if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
+            raise ValueError(f"hc_mult={self.hc_mult}, hc_sinkhorn_iters={self.hc_sinkhorn_iters}")
 
     @property
     def row_width(self) -> int:
@@ -168,7 +217,12 @@ def init_mla_decoder(cfg: MLADecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
     fold_in(seed, i). The router is drawn like the rest: over a normalised
     input its logits have std 0.02 * sqrt(hidden) (1.7 at 7168), so the
     sigmoid scores spread over (0, 1) and are not all one half. The routed
-    experts drawn are the ``experts_held`` this chip holds."""
+    experts drawn are the ``experts_held`` this chip holds. With
+    ``gate_bias`` the selection bias is normal(0, ``GATE_BIAS_STD``), float32;
+    with ``hc_mult`` > 1 each layer gets its two blocks' stream maps
+    (``hc_attn``, ``hc_mlp``: ops/mhc.py ``init_maps`` with ``HC_LOGIT_STD`` and
+    ``HC_RES_DIAG``). Both from keys folded out of the layer's, so the other
+    weights are the draws they were."""
     root = jax.random.key(int(seed), impl="rbg")
     c = cfg
 
@@ -188,11 +242,20 @@ def init_mla_decoder(cfg: MLADecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
             "ln2": jnp.ones((c.hidden,), dtype),
         }
 
+    def streams(key):
+        if c.hc_mult == 1:
+            return {}
+        return {
+            name: mhc.init_maps(jax.random.fold_in(key, 1000 + b), c.hc_mult, c.hidden, dtype,
+                                logit_std=HC_LOGIT_STD, res_diag=HC_RES_DIAG)
+            for b, name in enumerate(("hc_attn", "hc_mlp"))
+        }
+
     @jax.jit
     def dense_layer(key):
         ks = jax.random.split(key, 7)
         mlp = {"gate_up": draw(ks[5], (c.hidden, 2 * c.dense_ffn)), "down": draw(ks[6], (c.dense_ffn, c.hidden))}
-        return {**attention(ks), "mlp": mlp}
+        return {**attention(ks), "mlp": mlp, **streams(key)}
 
     @jax.jit
     def expert_layer(key):
@@ -204,7 +267,9 @@ def init_mla_decoder(cfg: MLADecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
             "shared_gate_up": draw(ks[8], (c.hidden, 2 * c.ffn)),
             "shared_down": draw(ks[9], (c.ffn, c.hidden)),
         }
-        return {**attention(ks), "moe": moe}
+        if c.gate_bias:
+            moe["router_bias"] = jax.random.normal(jax.random.fold_in(key, 999), (c.experts,), jnp.float32) * GATE_BIAS_STD
+        return {**attention(ks), "moe": moe, **streams(key)}
 
     @jax.jit
     def ends(key):
@@ -222,18 +287,47 @@ def init_mla_decoder(cfg: MLADecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
     return params
 
 
+def _route(cfg: MLADecoderConfig, moe: dict, h):
+    """The configuration's gate: the bias-selected one where a bias is
+    declared, the group-limited one where groups are."""
+    if cfg.gate_bias:
+        return route_sigmoid_biased(moe["router"], moe["router_bias"], h, cfg.experts_per_tok, cfg.routed_scale)
+    return route_sigmoid_grouped(moe["router"], h, cfg.experts_per_tok, cfg.n_group, cfg.topk_group, cfg.routed_scale)
+
+
+def _streams_in(cfg: MLADecoderConfig, maps: dict, x, valid):
+    """What a block reads of the ``hc_mult``-stream state x[S, n, m, d]: (u[n,
+    m, d], (H_post, H_res) for ``mhc.post_mix``, the maps' ``mhc_resid_ppm``)."""
+    h_pre, h_post, h_res = mhc.stream_maps(
+        maps, x, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_res_clamp, rms_eps=cfg.rms_eps
+    )
+    with jax.named_scope(mhc.SCOPE_MHC_MAP):
+        resid = mhc.doubly_stochastic_residual(h_res, valid)
+    return mhc.pre_mix(x, h_pre), (h_post, h_res), resid
+
+
 def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, n_keys, runs, interpret):
     """One layer over the latent plane: x[n, m, d] with row i's query j at
-    positions[i] + j. The new rows ``[latent | rotated key]`` scatter through
-    the block tables first, attention reads them back with the cached ones
+    positions[i] + j (x[S, n, m, d] with ``hc_mult`` S > 1: each block then
+    reads ``u``, the maps' mixture of the streams, and writes its output
+    back through them, ops/mhc.py). The new rows ``[latent | rotated key]``
+    scatter through the block tables first, attention reads them back with
+    the cached ones
     (write-then-read, as in every family): through the step's kernel where
     ``_forward`` found the dispatch to be its kind (``runs``; ``interpret``:
-    under the Pallas interpreter), else the walk. Returns (x, pool, counters[4]: zeros for a dense layer)."""
+    under the Pallas interpreter), else the walk. Returns (x, pool,
+    counters[4]: zeros for a dense layer, the two blocks' larger
+    ``mhc_resid_ppm`` or None)."""
     c = cfg
-    n, m, _ = x.shape
+    hc = c.hc_mult > 1
+    n, m = x.shape[-3:-1]
     q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]  # [n, m]
+    resid = None
     with jax.named_scope(SCOPE_QKV):
-        h = _rms(p["ln1"], x, c.rms_eps)
+        u = x
+        if hc:
+            u, mix, resid = _streams_in(c, p["hc_attn"], x, valid)
+        h = _rms(p["ln1"], u, c.rms_eps)
         with jax.named_scope(SCOPE_MLA_Q):
             q = _rms(p["q_norm"], h @ p["q_a"].astype(x.dtype), c.rms_eps)
             q = jnp.einsum("nmq,qhd->nmhd", q, p["q_b"].astype(x.dtype))  # [n, m, H, nope + rope]
@@ -253,22 +347,27 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
             live=None if counts is None else jnp.max(counts), runs=runs, interpret=interpret,
         )
     with jax.named_scope(SCOPE_ATTN_OUT):
-        x = x + ctx @ p["attn_o"].astype(x.dtype)
+        o = ctx @ p["attn_o"].astype(x.dtype)
+        x = mhc.post_mix(x, o, *mix) if hc else x + o
     with jax.named_scope(SCOPE_MLP):
-        h = _rms(p["ln2"], x, c.rms_eps).reshape(n * m, -1)
+        u = x
+        if hc:
+            u, mix, r2 = _streams_in(c, p["hc_mlp"], x, valid)
+            with jax.named_scope(mhc.SCOPE_MHC_MAP):
+                resid = jnp.maximum(resid, r2)
+        h = _rms(p["ln2"], u, c.rms_eps).reshape(n * m, -1)
         if "mlp" in p:
             with jax.named_scope(SCOPE_DENSE_MLP):
                 y, cnt = gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((4,), jnp.int32)
         else:
             real = valid.reshape(-1)
-            gates, experts = route_sigmoid_grouped(
-                p["moe"]["router"], h, c.experts_per_tok, c.n_group, c.topk_group, c.routed_scale
-            )
+            gates, experts = _route(c, p["moe"], h)
             y, cnt = moe_held_ffn(p["moe"], h, gates, experts, c.first_expert, real)
             with jax.named_scope(SCOPE_SHARED_EXPERT):
                 y = y + gated_mlp(p["moe"]["shared_gate_up"], p["moe"]["shared_down"], h)
-        x = x + y.reshape(x.shape)
-    return x, pool, cnt
+        y = y.reshape(u.shape)
+        x = mhc.post_mix(x, y, *mix) if hc else x + y
+    return x, pool, cnt, resid
 
 
 def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None, attn_kernel=""):
@@ -278,8 +377,10 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     head's one query a row). ``attn_kernel`` (static; "" | "mosaic" |
     "interpret": ``decode_programs._step_attn_kernel``'s answer) lets a
     dispatch of ONE query a row read the plane through ops/mla.py's kernel;
-    every other shape walks. Returns (logits[n, m or 1, vocab] float32,
-    hidden[n, m, d], pool, counters[7] int32: ``MLADecoder.frame_counters``)."""
+    every other shape walks. With ``hc_mult`` > 1 the state between the
+    embedding and the final norm is [hc_mult, n, m, d]; ``hidden`` is the
+    streams' sum. Returns (logits[n, m or 1, vocab] float32, hidden[n, m, d],
+    pool, counters[7 or 8] int32: ``MLADecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     last = jnp.full((n,), m, positions.dtype)  # queries a row really has
@@ -300,12 +401,19 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
             fetched = pages_fetched(n_keys, runs, last > 0, ps, bt.shape[1])
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
+        if cfg.hc_mult > 1:
+            x = mhc.spread(x, cfg.hc_mult)
     cnt = jnp.zeros((4,), jnp.int32)
+    resid = []
     for li, lp in enumerate(params["layers"]):
-        x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, runs, attn_kernel == "interpret")
+        x, pool, c, r = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, runs, attn_kernel == "interpret")
         with jax.named_scope(SCOPE_MLP), jax.named_scope(SCOPE_MOE_COMBINE):
             cnt = cnt + c
+        if r is not None:
+            resid.append(r)
     with jax.named_scope(SCOPE_LM_HEAD):
+        if cfg.hc_mult > 1:  # the streams summed, then the final norm
+            x = mhc.merged(x)
         top = x if pick is None else jnp.take_along_axis(x, pick[:, None, None], axis=1)
         logits = jnp.matmul(
             _rms(params["ln_f"], top, cfg.rms_eps), params["lm_head"].astype(x.dtype),
@@ -315,7 +423,8 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         # latent rows attended over are one layer's (every layer reads as many)
         cnt = cnt.at[0].set(jnp.sum(valid, dtype=jnp.int32))
         ctx_rows = jnp.sum(jnp.where(last > 0, n_keys, 0), dtype=jnp.int32)
-    return logits, x, pool, jnp.concatenate([cnt, ctx_rows[None], fetched])
+        counted = [cnt, ctx_rows[None], fetched] + ([jnp.max(jnp.stack(resid))[None]] if resid else [])
+    return logits, x, pool, jnp.concatenate(counted)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -333,10 +442,16 @@ class MLADecoder:
     # landed on one, and the latent rows the dispatch's live rows attended
     # over (each row's keys, summed; one layer's), and where the step's kernel
     # ran the pages it fetched for them and those that came in run DMAs
-    frame_counters = (
-        "moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "mla_ctx_rows",
-        "mla_pages_read", "mla_run_pages",
-    )
+    # with ``hc_mult`` > 1 also the stream maps' canary, ``mhc_resid_ppm``: the
+    # largest |row or column sum - 1| of any H_res of the dispatch's real rows,
+    # x 1e6 (the scheduler SUMS a round's dispatches: a round of one dispatch,
+    # as a steady step round is, carries that dispatch's own)
+    @property
+    def frame_counters(self) -> tuple:
+        base = ("moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "mla_ctx_rows",
+                "mla_pages_read", "mla_run_pages")
+        return base + (("mhc_resid_ppm",) if self.cfg.hc_mult > 1 else ())
+
     # beside the plain rounds: a step that reads the plane in place (ops/mla.py's kernel)
     serves = frozenset({"attn_kernel"})
     state_init = None  # no recurrent state: latent pages only
@@ -345,6 +460,9 @@ class MLADecoder:
         if "lm_head" not in params or "kv_b" not in params["layers"][0]:
             raise FamilyNotServed("not a latent-attention decoder's parameters (models/mla_decoder.py layout)")
         c = self.cfg
+        moe = params["layers"][-1].get("moe")
+        if (c.hc_mult > 1) != ("hc_attn" in params["layers"][0]) or (moe and c.gate_bias != ("router_bias" in moe)):
+            raise FamilyNotServed(f"parameters and configuration disagree on hc_mult={c.hc_mult} / gate_bias={c.gate_bias}")
         return {
             "layers": len(params["layers"]), "kv_layers": len(params["layers"]), "heads": c.heads,
             # the latent page kind: ONE plane, one row of latent + rotated key a token, in whole lane tiles

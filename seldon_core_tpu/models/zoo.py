@@ -601,6 +601,11 @@ def build_mla_decoder(
     mscale_all_dim: float = 1.0,
     rms_eps: float = 1e-6,
     max_len: int = 131072,
+    gate_bias: bool = False,
+    hc_mult: int = 1,
+    hc_sinkhorn_iters: int = 20,
+    hc_eps: float = 1e-6,
+    hc_res_clamp: float = 30.0,
     seq: int = 32,
     max_new_tokens: int = 16,
     param_dtype: str = "bfloat16",
@@ -615,13 +620,20 @@ def build_mla_decoder(
     is ONE expert's width, ``vocab`` the rows of the vocabulary held.
     ``experts_held`` (0: all) from ``first_expert`` is one chip's share of an
     expert-parallel deployment: the router keeps ``experts`` outputs and a
-    pick that lands on an absent expert adds nothing. It serves through
-    ``tpu.decode_slots``; without it the fused fallback decodes whole batches
-    greedily through the same paged forward. Speculation, tensor-parallel
-    decode, the int8 pool, the KV tiers and prefix export are not served
-    for it."""
+    pick that lands on an absent expert adds nothing. ``gate_bias`` (with
+    ``n_group`` 0 and ``topk_group`` 0: no groups) is the gate whose
+    per-expert bias selects and does not weigh (``topk_method: noaux_tc``).
+    ``hc_mult`` > 1 makes the residual path that many streams wide
+    (manifold-constrained hyper-connections, ops/mhc.py: ``hc_sinkhorn_iters``
+    Sinkhorn-Knopp iterations with ``hc_eps`` in their denominators over
+    logits clamped to +-``hc_res_clamp``); 1 is ``x + F(norm(x))``. It serves
+    through ``tpu.decode_slots``; without it the fused fallback decodes whole
+    batches greedily through the same paged forward. Speculation,
+    tensor-parallel decode, the int8 pool, the KV tiers and prefix export are
+    not served for it."""
     import jax.numpy as jnp
 
+    from seldon_core_tpu.graph.spec import bool_param
     from seldon_core_tpu.models.mla_decoder import MLADecoderConfig, init_mla_decoder, mla_family
 
     if seq + max_new_tokens > max_len:
@@ -637,7 +649,8 @@ def build_mla_decoder(
         routed_scale=float(routed_scale), rope_theta=float(rope_theta), yarn_factor=float(yarn_factor),
         yarn_original=int(yarn_original), yarn_beta_fast=float(yarn_beta_fast),
         yarn_beta_slow=float(yarn_beta_slow), mscale_all_dim=float(mscale_all_dim), rms_eps=float(rms_eps),
-        max_len=int(max_len),
+        max_len=int(max_len), gate_bias=bool_param(gate_bias), hc_mult=int(hc_mult),
+        hc_sinkhorn_iters=int(hc_sinkhorn_iters), hc_eps=float(hc_eps), hc_res_clamp=float(hc_res_clamp),
     )
     family = mla_family(cfg)
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[str(param_dtype)]

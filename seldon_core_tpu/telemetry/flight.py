@@ -437,7 +437,12 @@ class FlightFrame:
     DMA a run (one layer's); 0 where the walk ran; ``attn_run_pages`` of
     the ``attn_pages_read`` of a round whose step ran the grouped-query
     kernel (ops/gqa_decode.py), those that came in ONE DMA a run, as the
-    program counted them (one layer's K); 0 elsewhere; ``chunk_c`` the chunk
+    program counted them (one layer's K); 0 elsewhere; ``mhc_resid_ppm``
+    where a latent-attention family carries a multi-stream residual
+    (``hc_mult`` > 1, ops/mhc.py): the largest |row or column sum - 1| of any
+    Sinkhorn-normalised stream mix of a dispatch's real rows, x 1e6, SUMMED
+    over the round's dispatches like every count (a steady step round has
+    one): a precision canary, single digits in float32; ``chunk_c`` the chunk
     length of the round's chunk dispatch (with ``chunk_rows`` its
     ``chunk_buckets`` entry, whose wall is ``busy_ns[F_CHUNK]``), 0 where
     none ran; ``ingress_ns`` / ``ingress_requests`` the submits that reached
@@ -458,7 +463,7 @@ class FlightFrame:
         "moe_rows", "moe_experts_hit", "moe_load_max",
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
-        "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages",
+        "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
     )
 
     def __init__(
@@ -474,7 +479,7 @@ class FlightFrame:
         moe_rows=0, moe_experts_hit=0, moe_load_max=0,
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
-        chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0,
+        chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -524,6 +529,7 @@ class FlightFrame:
         self.ingress_ns = ingress_ns
         self.ingress_requests = ingress_requests
         self.conv_rows = conv_rows
+        self.mhc_resid_ppm = mhc_resid_ppm
         self.attn_run_pages = attn_run_pages
 
     def to_dict(self) -> dict:
@@ -607,6 +613,8 @@ class FlightFrame:
             d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
         if self.mla_pages_read:
             d["mla_pages"] = [self.mla_run_pages, self.mla_pages_read]
+        if self.mhc_resid_ppm:
+            d["mhc_resid_ppm"] = self.mhc_resid_ppm
         return d
 
 

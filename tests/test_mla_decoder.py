@@ -698,8 +698,9 @@ def test_the_run_pages_reader_reads_the_frames_and_gives_none_without_the_fields
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == "mla_run_pages_pct")
+    cells_listed = entry.pop("workloads")  # the family's cells: the fifth, and whatever configuration joined it since
     assert entry == {"name": "mla_run_pages_pct", "unit": "%", "better": "higher", "source": "program_counter",
-                     "layer": "kernels", "moves": "itl_p95_ms", "workloads": ["a.x-k1.doc-qa-closed-64"]}
+                     "layer": "kernels", "moves": "itl_p95_ms"} and cells_listed[0] == "a.x-k1.doc-qa-closed-64"
     reader = cells.load_module(ROOT, bench, "layer_metrics", "mla_run_pages_pct")
 
     def frame(**kw):

@@ -715,6 +715,82 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
 
 
+@pytest.mark.parametrize("sinkhorn", ["kernel", "plain"])
+@pytest.mark.parametrize("program", ["step", "chunk_4_256"])
+def test_latent_family_with_four_streams_compiles_in_place_at_xing_widths(topo, program, sinkhorn, monkeypatch):
+    """The fourth family with ``hc_mult`` 4 and the bias-selected gate at the
+    xing4.0-29b-a4b cell's widths (hidden 3584 in four streams, 32 heads, a
+    768-wide query rank, 8 of 64 experts of 1024 held, 6144 latent pages),
+    the two dense layers + one expert layer: the step (its kernel at 32
+    heads, a call a layer) and the (4, 256) chunk entry. The donated plane
+    comes back aliased and uncopied; the stream maps' three scopes are in the
+    compiled text under ``qkv``, ``attn_out`` and ``mlp``, the Sinkhorn
+    iterations ONE Mosaic call a block as a TPU builds them (``mhc_sinkhorn``)
+    or, in the plain form, one ``while`` a block and not forty unrolled
+    stages; nothing
+    ``[rows, 4, 4, hidden]`` exists (the stream mix is written out over the
+    stream axis) and the streams-major state is never copied whole into
+    float32 (the maps' product reads the bfloat16 state as stored; every
+    mix converts inside its own fusion)."""
+    from seldon_core_tpu.models import mla_decoder as mla
+    from seldon_core_tpu.ops import mhc, moe
+    from seldon_core_tpu.serving.decode_programs import _step_attn_kernel
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mhc, "_sinkhorn_mode", lambda: "mosaic" if sinkhorn == "kernel" else "")
+    jax.clear_caches()  # the other form's trace of the same program is not this one's
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = mla.MLADecoderConfig(
+        vocab=16384, hidden=3584, layers=3, heads=32, q_rank=768, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        dense_layers=2, dense_ffn=9216, ffn=1024, experts=64, experts_held=8, first_expert=0, experts_per_tok=4,
+        n_group=0, topk_group=0, gate_bias=True, routed_scale=2.0, yarn_factor=64.0, yarn_original=4096, max_len=262144,
+        hc_mult=4,
+    )
+    fam = mla.mla_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: mla.init_mla_decoder(cfg, 0, jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 6144, 16, jnp.bfloat16)))
+    assert params["layers"][0]["hc_attn"]["phi"].shape == (4 * 3584, 24) and "router_bias" in params["layers"][2]["moe"]
+    i32, f32 = jnp.int32, jnp.float32
+    chosen = _step_attn_kernel(fam, pool, None, 32, 1)
+    assert chosen == "mosaic"
+    step, chunk = fam.fused_programs(chosen)
+    if program == "step":
+        n, c, fn, where = 64, 1, step, "step"
+        args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+    else:
+        n, c, fn, where = 4, 256, chunk, "chunk"
+        args = (arr((n, 144), i32), arr((n, c), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32),
+                arr((n,), i32), arr((), i32), arr((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, pool, *args).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= int(np.prod(pool[0].shape)) * 2
+    plane = re.escape("bf16[3,6144,16,640]")
+    assert not [ln for ln in text.splitlines() if re.search(r"= " + plane + r"\S* copy\(", ln)]
+    for parent, name in (("qkv", "mhc_map"), ("qkv", "mhc_pre"), ("attn_out", "mhc_post"), ("mlp", "mhc_map"),
+                         ("mlp", "mhc_pre"), ("mlp", "mhc_post")):
+        assert re.search(r'op_name="jit\(_fused_%s\)/%s/%s/' % (where, parent, name), text), (parent, name)
+    loops = {m for m in re.findall(r'op_name="jit\(_fused_\w+\)/(\w+)/mhc_map/while/body', text)}
+    calls = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/mhc_map/" in ln]
+    if sinkhorn == "kernel":
+        assert len(calls) == 6 and not loops  # a call a block: two blocks a layer
+    else:
+        assert loops == {"qkv", "mlp"} and not calls  # the Sinkhorn loop of both blocks' maps
+    assert not re.findall(r"\[4,4,(?:%d,%d|%d),3584\]|\[(?:%d,%d|%d),4,4,3584\]" % ((n, c, n * c) * 2), text)
+    # the state is streams-major and the compiler keeps each stream a [rows, queries, 3584] array of its own:
+    # never a float32 copy of all four, in either order of the axes
+    assert not re.findall(r"f32\[4,%d,%d,3584\]|f32\[%d,%d,4,3584\]" % (n, c, n, c), text)
+    kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/attn/mla_core/" in ln]
+    assert len(kernels) == (3 if program == "step" else 0)  # a kernel call a layer, at 32 heads; a chunk walks
+
+
 @pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_2_64"])
 def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, program):
     """The fifth family's fused step (64 slots; through the gather, and with
