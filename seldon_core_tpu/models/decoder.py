@@ -1061,8 +1061,8 @@ def _fused_chunk(params, pool, bt, ids, positions, counts, temps, topks, seed, t
     Only the sampled token of a row whose prompt COMPLETED this round is
     consumed by the host (it is the first generated token). With the
     monolithic admit path gone, this IS admission's prompt compute — a
-    whole wave prefills in one dispatch at the full-width top entry, or
-    spread over rounds when chunking is on."""
+    wave prefills four slots a round at the ladder's widest entry, and
+    each prompt over rounds when chunking is on."""
     logits, _hidden, pool = paged_chunk_prefill(params, pool, bt, ids, positions, counts)
     with jax.named_scope(SCOPE_SAMPLE):
         c = ids.shape[1]

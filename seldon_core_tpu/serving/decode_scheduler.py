@@ -643,14 +643,17 @@ class PrefixIndex:
     def remove(self, e: "_PrefixEntry") -> None:
         del self.entries[e.pin_id]
 
+    def lru(self) -> "_PrefixEntry | None":
+        """The least recently used entry; None where the index is empty."""
+        return min(self.entries.values(), key=lambda e: e.last_use, default=None)
+
     def evict_lru(self) -> "_PrefixEntry | None":
         """Drop and return the least recently used entry (the caller releases
         its pool pin); None where the index is empty."""
-        if not self.entries:
-            return None
-        lru = min(self.entries.values(), key=lambda e: e.last_use)
-        self.remove(lru)
-        self.evictions += 1
+        lru = self.lru()
+        if lru is not None:
+            self.remove(lru)
+            self.evictions += 1
         return lru
 
     def remove_by_pins(self, pin_ids) -> int:
@@ -754,8 +757,9 @@ class DecodeScheduler:
 
     # a round that takes this long is logged with what held it (_commit_round)
     SLOW_ROUND_NS = 1_000_000_000
-    # the rows ladder of the compact chunk programs, under ``n_slots``
-    # (``chunk_buckets``): the first takes every c, the rest the top c only
+    # the rows ladder of the compact chunk programs (``chunk_buckets``): the
+    # first takes every c, the rest the top c only; the last one a deployment
+    # has slots for is the most slots a chunk round takes (``chunk_rows_cap``)
     CHUNK_ROWS = (2, 4)
 
     def __init__(
@@ -947,20 +951,28 @@ class DecodeScheduler:
         # and the ladder dominates warmup, round COUNTS are set by the
         # chunk cap, not the bucket (a 5-token suffix rides c = 16 with
         # junk-masked slack), and under 16 positions a few rows cost what
-        # the weights' read costs, like a step. Rows climb CHUNK_ROWS to
-        # ``n_slots``, every entry past the first at the top c only: three
-        # or four slots prefill together in a round in twenty (a closed
-        # loop of 16 streams and 3 chunks a prompt), more in a wave (ramp,
-        # a burst). The feature head's twin carries its feature buffer and
-        # the draft's flat cache by slot, so it keeps every c at full
-        # width (row r is slot r).
+        # the weights' read costs, like a step. Rows climb CHUNK_ROWS, every
+        # entry past the first at the top c only, and END at
+        # ``chunk_rows_cap`` = min(n_slots, CHUNK_ROWS[-1]): a round takes
+        # at most that many slots, by arrival (``_chunk_rows_taken``), and
+        # the rest prefill in the next round. There is no ``(n_slots, top)``
+        # entry: past four rows a row costs the same or more (the weights'
+        # read is long paid for), a wave of five in 64 slots computed 64
+        # rows, and while it ran no slot emitted; between two compact rounds
+        # the generating slots step. The feature head's twin carries its
+        # feature buffer and the draft's flat cache by slot, so it keeps
+        # every c at full width (row r is slot r) and is not capped.
         cs, b = [], 16
         while b < top:
             cs.append(b)
             b *= 4
         cs.append(top)
-        rows = [] if self.feature_draft else [r for r in self.CHUNK_ROWS if r < n_slots]
-        rows.append(n_slots)
+        if self.feature_draft:
+            self.chunk_rows_cap, rows = n_slots, []
+        else:
+            self.chunk_rows_cap = min(n_slots, self.CHUNK_ROWS[-1])
+            rows = [r for r in self.CHUNK_ROWS if r < self.chunk_rows_cap]
+        rows.append(self.chunk_rows_cap)
         self.chunk_buckets = tuple(
             [(rows[0], c) for c in cs] + [(r, top) for r in rows[1:]]
         )
@@ -1165,6 +1177,9 @@ class DecodeScheduler:
         self.stat_tier_promotions = 0
         self.stat_tier_promote_overlap = 0
         self.stat_chunk_dispatches = 0
+        # slots that had a chunk to run in a round and were left for a later
+        # one (``_chunk_rows_taken``), summed over the chunk dispatches
+        self.stat_chunk_rows_held = 0
         # paged-pool attribution (the allocator owns the counters; these
         # track what the scheduler itself dispatched/declined)
         self.stat_kv_copy_rounds = 0
@@ -2008,7 +2023,7 @@ class DecodeScheduler:
             self._metrics.decode_prefix_evicted(self._deployment)
         self.stat_prefix_captures += 1
 
-    def _snapshot_row(self, seq: _Seq, end: int) -> int:
+    def _snapshot_row(self, seq: _Seq, end: int, unread: set) -> int:
         """A recurrent family, while a chunk round is planned: the snapshot
         row the dispatch must also write for ``seq``, whose chunk ends at
         prompt position ``end``; -1 where it captures nothing there (``end``
@@ -2017,17 +2032,23 @@ class DecodeScheduler:
         as at the index cap; its row may be written in the same dispatch a
         warm admission still reads it in: the program reads before it
         writes. Rows are only ever written by chunk dispatches, and a
-        decided admission's first chunk rides the very next one."""
+        decided admission's first chunk rides the next one unless the round
+        leaves it out (``_chunk_rows_taken``): ``unread`` holds the rows
+        such admissions have yet to read, and a dispatch they do not ride
+        must not write one, whoever dropped its entry meanwhile. A free row
+        among them is passed over, an LRU entry whose row is among them
+        stays, and with no other row the span is not captured."""
         if end != self._hint_boundary(seq) or self._snapshot_held(seq, end):
             return -1
         alloc = self.pool.alloc
-        row = alloc.take_state_row()
+        row = alloc.take_state_row(unread)
         if row < 0:
-            lru = self._prefix_index.evict_lru()
-            if lru is not None:
+            lru = self._prefix_index.lru()
+            if lru is not None and lru.state_row not in unread:
+                self._prefix_index.evict_lru()
                 alloc.release(lru.pin_id)
                 self._metrics.decode_prefix_evicted(self._deployment)
-                row = alloc.take_state_row()
+                row = alloc.take_state_row(unread)
         if row < 0:
             self.stat_prefix_capture_skips += 1
         return row
@@ -2159,9 +2180,10 @@ class DecodeScheduler:
         self._rb_first_tokens = 0
         # pages the plain step's attention read, of the pages its tables name
         self._rb_attn_pages = (0, 0)
-        # rows the round's chunk dispatches computed, and the prefilling
-        # slots among them
+        # rows the round's chunk dispatches computed, the prefilling slots
+        # among them, and the slots with a chunk to run that they left out
         self._rb_chunk_rows = self._rb_chunk_rows_live = self._rb_chunk_c = 0
+        self._rb_chunk_rows_held = 0
         # rows of the round's dispatches that asked the sampler for a draw,
         # and those among them that asked for top_k (_count_sampling)
         self._rb_sample_rows = self._rb_sample_topk_rows = 0
@@ -2293,6 +2315,7 @@ class DecodeScheduler:
                         state_restores=self._rb_state_restores,
                         state_captures=self._rb_state_captures,
                         chunk_c=self._rb_chunk_c,
+                        chunk_rows_held=self._rb_chunk_rows_held,
                         ingress_ns=ingress[0] - self._ingress_committed[0],
                         ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
@@ -2653,8 +2676,25 @@ class DecodeScheduler:
             self._pending_chunk_plan = None
             return
         rows.sort(key=lambda r: r[0])
+        rows = self._chunk_rows_taken(rows)
         key = tuple(r[:4] for r in rows)
         self._pending_chunk_plan = (key,) + self._chunk_input_arrays(rows)
+
+    def _chunk_rows_taken(self, rows: list) -> list:
+        """The rows of ``rows`` (every slot with a chunk to run, in slot
+        order) that ONE chunk round takes: all of them up to
+        ``chunk_rows_cap``, the ladder's widest entry; beyond it the
+        ``chunk_rows_cap`` that arrived first (lowest ``uid``: no slot
+        starves, and the oldest request reaches its first token first),
+        still in slot order. The rest stay ``prefilling`` at their
+        ``prefill_pos`` for a later round and are in nothing this round
+        builds: applied to the list before anything reads it, by the serial
+        round and the overlap-window plan alike, so the plan's snapshot key
+        and the round's agree."""
+        if len(rows) <= self.chunk_rows_cap:
+            return rows
+        first = sorted(r[1] for r in rows)[self.chunk_rows_cap - 1]
+        return [r for r in rows if r[1] <= first]
 
     def _chunk_input_arrays(self, rows: list) -> tuple:
         """The chunk round's input arrays from ``(slot, uid, prefill_pos,
@@ -2662,11 +2702,13 @@ class DecodeScheduler:
         serial chunk round and the overlap-window plan, so the array
         layout cannot drift between the two paths (the plan's snapshot key
         covers the rows, not the layout). The batch is the first
-        ``chunk_buckets`` entry that holds the rows and their longest
-        chunk: the slots that prefill, one row each in slot order, then
-        padding rows (slot -1, count 0: their writes junk-sink). At full
-        width row r is slot r. Returns ``(slots, ids, pos, counts, temps,
-        topks)``, each ``[rows]`` (``ids`` ``[rows, c]``)."""
+        ``chunk_buckets`` entry that holds the rows (what
+        ``_chunk_rows_taken`` left: no more than the widest entry) and
+        their longest chunk: the slots that prefill, one row each in slot
+        order, then padding rows (slot -1, count 0: their writes
+        junk-sink). At ``n_slots`` rows (the feature twin, a deployment of
+        four slots or fewer) row r is slot r. Returns ``(slots, ids, pos,
+        counts, temps, topks)``, each ``[rows]`` (``ids`` ``[rows, c]``)."""
         need = max(r[3] for r in rows)
         n, bucket = next(
             (n, c) for n, c in self.chunk_buckets if n >= len(rows) and c >= need
@@ -2773,14 +2815,18 @@ class DecodeScheduler:
         self._kv_gauges()
 
     async def _chunk_round(self) -> None:
-        """One prefill chunk round: every PREFILLING slot consumes up to
-        its per-round chunk cap of prompt tokens in one fused dispatch
-        whose batch is those slots (``_chunk_input_arrays``: the first
-        entry of the warmed chunk ladder that holds them; a generating or
-        free slot is not in it). Slots whose prompt completes emit their
-        first token and transition to generating — decode steps for
-        running slots interleave between rounds instead of stalling behind
-        a monolithic wave prefill."""
+        """One prefill chunk round: the PREFILLING slots, at most
+        ``chunk_rows_cap`` of them and then those that arrived first
+        (``_chunk_rows_taken``), each consume up to their per-round chunk
+        cap of prompt tokens in one fused dispatch whose batch is those
+        slots (``_chunk_input_arrays``: the first entry of the warmed chunk
+        ladder that holds them; a generating or free slot is not in it). A
+        slot left out keeps its ``prefill_pos`` and rides a later round: it
+        gets no page, no copy, no state or snapshot row and no span here,
+        and the frame counts it (``chunk_rows_held``). Slots whose prompt
+        completes emit their first token and transition to generating —
+        decode steps for running slots interleave between rounds instead
+        of stalling behind a monolithic wave prefill."""
         with self._phase(P_ALLOC):
             rows: list[tuple[int, int, int, int, _Seq]] = []
             for i, seq in enumerate(self._slots):
@@ -2794,6 +2840,9 @@ class DecodeScheduler:
                     rows.append((i, seq.uid, seq.prefill_pos, c, seq))
             if not rows:
                 return
+            waiting = len(rows)
+            rows = self._chunk_rows_taken(rows)
+            held = waiting - len(rows)
             # the pipelined loop may have prebuilt this round's input
             # arrays under the previous round's dispatch — valid only if
             # the live state still matches the plan's snapshot key
@@ -2817,8 +2866,14 @@ class DecodeScheduler:
             if self._stateful:
                 # which state row each batch row reads, writes and snapshots:
                 # serial like page residency (taking a row may evict an entry)
+                riding = {r[0] for r in rows}
+                unread = {
+                    s.state_src
+                    for i, s in enumerate(self._slots)
+                    if s is not None and s.state_src >= 0 and i not in riding
+                }
                 for i, _uid, pp, c, seq in rows:
-                    row = self._snapshot_row(seq, pp + c)
+                    row = self._snapshot_row(seq, pp + c, unread)
                     if row >= 0:
                         snaps[i] = row
                 state_rows = self.pool.state_rows(
@@ -2840,8 +2895,10 @@ class DecodeScheduler:
             self._rb_counts += counted
         t1 = telemetry.now_ns()
         self.stat_chunk_dispatches += 1
+        self.stat_chunk_rows_held += held
         self._rb_chunk_rows += len(slots)
         self._rb_chunk_rows_live += len(rows)
+        self._rb_chunk_rows_held += held
         self._count_sampling(temps, topks)
         bucket = ids.shape[1]
         finishing: list[tuple[_Seq, int, int]] = []  # (seq, slot, its row's token)
@@ -3102,8 +3159,9 @@ class DecodeScheduler:
                     await self._chaos_round()
                 # one prefill chunk per round, interleaved with the decode
                 # step below — running slots keep emitting while long
-                # prompts prefill chunk by chunk (with no chunk cap a whole
-                # admission wave prefills in one top-bucket dispatch)
+                # prompts prefill chunk by chunk, and an admission wave
+                # ``chunk_rows_cap`` slots a round (with no chunk cap a
+                # prompt prefills in one top-bucket dispatch)
                 await self._chunk_round()
 
                 with self._phase(P_SAMPLING):
@@ -3118,6 +3176,7 @@ class DecodeScheduler:
                     topks = np.zeros(self.n_slots, np.int32)
                     fmask = np.zeros(self.n_slots, bool)
                     n_gen = 0
+                    unridden: list[int] = []
                     for i, seq in enumerate(self._slots):
                         if seq is None:
                             continue
@@ -3132,8 +3191,15 @@ class DecodeScheduler:
                             # slot but park the junk write at the slot's
                             # own prefill cursor, where the next chunk
                             # overwrites it before any attention mask can
-                            # reach it
+                            # reach it. The page under the cursor is the
+                            # slot's own only once a chunk round has taken
+                            # the slot (``prepare_write``); one that the
+                            # rounds have left out so far (``_chunk_rows_taken``)
+                            # may still share it with the prefix entry it
+                            # hit, so its table row is blanked below
                             pos[i] = seq.prefill_pos
+                            if seq.chunk_idx == 0:
+                                unridden.append(i)
                             continue
                         toks[i] = seq.tokens[-1]
                         pos[i] = seq.pos
@@ -3226,8 +3292,10 @@ class DecodeScheduler:
                 # page residency for the round's writes: 1 token per
                 # generating slot on the plain step, the full [k+1]-wide
                 # block (accepted or junk) on a speculative round.
-                # Prefilling slots need nothing — their junk parks in
-                # already-owned pages or the junk sink.
+                # Prefilling slots need nothing — their junk parks in pages
+                # their chunk rounds made their own (allocated or copied on
+                # write) or, past those and for a slot no chunk round has
+                # taken yet, in the junk sink.
                 width = self.spec_k + 1 if spec_round else 1
                 copies: list[tuple[int, int]] = []
                 with self._phase(P_ALLOC):
@@ -3238,6 +3306,9 @@ class DecodeScheduler:
                 await self._run_copies(copies)
                 with self._phase(P_ALLOC):
                     bt = self.pool.block_tables()
+                    # a free slot's row: whatever this dispatch writes for
+                    # them lands in junk page 0, not in a prefix's pages
+                    bt[unridden] = 0
                     if not self._pipeline_on():
                         # per-round pool gauges: this round's prepare_write
                         # may have allocated/CoW'd pages with no admission

@@ -379,10 +379,15 @@ class PageAllocator:
         return pin
 
     # -------------------------------------------------------- snapshot rows
-    def take_state_row(self) -> int:
-        """A free snapshot row for a capture about to be dispatched, -1
-        where none is free (the caller evicts an entry and asks again)."""
-        return self._state_free.pop() if self._state_free else -1
+    def take_state_row(self, unread=()) -> int:
+        """A free snapshot row for a capture about to be dispatched, passing
+        over the rows in ``unread`` (free, but an admission that was decided
+        while an entry held them has yet to read them); -1 where no other is
+        free (the caller evicts an entry and asks again)."""
+        for k in range(len(self._state_free) - 1, -1, -1):
+            if self._state_free[k] not in unread:
+                return self._state_free.pop(k)
+        return -1
 
     def give_state_row(self, row: int) -> None:
         """Hand back a taken row that was bound to no pin after all."""

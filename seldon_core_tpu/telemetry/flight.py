@@ -445,7 +445,11 @@ class FlightFrame:
     one): a precision canary, single digits in float32; ``chunk_c`` the chunk
     length of the round's chunk dispatch (with ``chunk_rows`` its
     ``chunk_buckets`` entry, whose wall is ``busy_ns[F_CHUNK]``), 0 where
-    none ran; ``ingress_ns`` / ``ingress_requests`` the submits that reached
+    none ran; ``chunk_rows_held`` the slots that had a chunk to run in the
+    round and were left for a later one, because the ladder's widest entry
+    holds fewer (the scheduler's ``_chunk_rows_taken``: the first arrivals
+    ride), 0 in a round that took them all and in a round without a chunk
+    dispatch; ``ingress_ns`` / ``ingress_requests`` the submits that reached
     the queue during the round and their summed time on the event loop from
     the request's bytes in hand (``Ingress``: body parse, message build, the
     hops to ``submit``), 0 / 0 for callers that hand ``submit`` no mark."""
@@ -464,6 +468,7 @@ class FlightFrame:
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
+        "chunk_rows_held",
     )
 
     def __init__(
@@ -480,6 +485,7 @@ class FlightFrame:
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
+        chunk_rows_held=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -531,6 +537,7 @@ class FlightFrame:
         self.conv_rows = conv_rows
         self.mhc_resid_ppm = mhc_resid_ppm
         self.attn_run_pages = attn_run_pages
+        self.chunk_rows_held = chunk_rows_held
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -599,6 +606,8 @@ class FlightFrame:
         if self.chunk_rows:
             d["chunk_rows"] = [self.chunk_rows_live, self.chunk_rows]
             d["chunk_c"] = self.chunk_c
+            if self.chunk_rows_held:
+                d["chunk_rows_held"] = self.chunk_rows_held
         if self.ingress_requests:
             d["ingress"] = [self.ingress_requests, round(self.ingress_ns / 1e3, 1)]
         if self.sample_rows:

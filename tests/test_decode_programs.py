@@ -132,9 +132,11 @@ def test_draft_and_verify_keep_one_contract_under_every_convention(convention):
 
 # ---- the chunk dispatch's batch is the slots that prefill (ISSUE 33) ----
 # 16 slots, 40-token prompts in chunks of at most 20: c climbs from 16, two
-# rows at every c, four rows and the full width at the top c only
+# rows at every c, four rows at the top c only, and there the ladder ends: a
+# round takes at most four slots, the first arrivals (ISSUE 44)
 WIDE, WSEQ, CAP, WNEW = 16, 40, 20, 3
-LADDER = ((2, 16), (2, 20), (4, 20), (WIDE, 20))
+LADDER = ((2, 16), (2, 20), (4, 20))
+ROWS_CAP = 4
 
 
 def _wide(family: str, vocab: int):
@@ -209,22 +211,41 @@ def test_a_compact_chunk_dispatch_is_the_full_width_one_for_its_slots(family, li
         assert not np.array_equal(ac[:, sorted(written)], a0[:, sorted(written)])
 
 
+def _spy_on_the_selection(s):
+    """Record every call of ``_chunk_rows_taken`` as (uids with a chunk to
+    run in slot order, uids taken in slot order)."""
+    calls, taken = [], s._chunk_rows_taken
+
+    def spy(rows):
+        out = taken(rows)
+        calls.append(([r[1] for r in rows], [r[1] for r in out]))
+        return out
+
+    s._chunk_rows_taken = spy
+    return calls
+
+
 @pytest.mark.parametrize("family", ["gpt2", "counting"])
 async def test_a_chunk_round_dispatches_at_the_first_entry_that_holds_its_slots(family):
-    """k prefilling slots dispatch at the smallest rows entry >= k, a wave of
-    ``n_slots`` at the full-width program; a mixed sequence of both compiles
-    nothing after ``warmup``, which compiled the ladder's entries and no
-    more; the frames count the rows dispatched and the slots live in them;
-    every request reads the greedy oracle's tokens whichever row it rode."""
+    """k prefilling slots dispatch at the smallest rows entry >= k; a wave of
+    ``n_slots`` over a 4-row bound rides rounds of at most four live rows, the
+    oldest ``uid``s first, and the frames count the slots each round left for
+    a later one; a mixed sequence of both compiles nothing after ``warmup``,
+    which compiled the ladder's entries and no more (no full-width program);
+    the frames count the rows dispatched and the slots live in them; every
+    request reads the greedy oracle's tokens whichever round and row it rode."""
     s, oracle = _wide(family, vocab={"gpt2": 160, "counting": 112}[family])
-    assert s.chunk_buckets == LADDER
+    assert s.chunk_buckets == LADDER and s.chunk_rows_cap == ROWS_CAP
     base = s.compile_counts()["chunk"]
     s.warmup()
     assert s.compile_counts()["chunk"] == base + len(LADDER)
+    calls = _spy_on_the_selection(s)
     prompts = np.random.default_rng(1).integers(0, 96, (23, WSEQ)).astype(np.int32)
     want = np.asarray(oracle(jnp.asarray(prompts)))
     got = [await s.submit(prompts[0])]
+    rounds = {}
     for lo, hi in ((1, 3), (3, 19), (19, 22), (22, 23)):  # two together, a wave of 16, three, one
+        rounds[hi - lo] = s.flight.rounds
         got += await asyncio.gather(*(s.submit(p) for p in prompts[lo:hi]))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
@@ -233,28 +254,52 @@ async def test_a_chunk_round_dispatches_at_the_first_entry_that_holds_its_slots(
     frames = [f for f in s.flight.snapshot() if f.chunk_rows]
     assert len(frames) == s.stat_chunk_dispatches
     for f in frames:
-        assert f.busy_ns[0] > 0 and f.chunk_rows == next(r for r in (2, 4, WIDE) if r >= f.chunk_rows_live)
+        assert f.busy_ns[0] > 0 and f.chunk_rows == next(r for r in (2, ROWS_CAP) if r >= f.chunk_rows_live)
         assert f.to_dict()["chunk_rows"] == [f.chunk_rows_live, f.chunk_rows]
+        assert f.to_dict().get("chunk_rows_held", 0) == f.chunk_rows_held
     assert sum(f.chunk_rows_live for f in frames) == 23 * 2  # every prompt is two chunks of 20
-    assert {1, 2, 3, WIDE} <= {f.chunk_rows_live for f in frames}
-    assert not any(f.chunk_rows for f in s.flight.snapshot() if f.busy_ns[0] == 0)
+    assert {1, 2, 3, ROWS_CAP} == {f.chunk_rows_live for f in frames}
+    assert not any(f.chunk_rows or f.chunk_rows_held for f in s.flight.snapshot() if f.busy_ns[0] == 0)
+    # the wave: four slots a round by arrival, each four's first chunk and then its second
+    wave = [f for f in frames if rounds[16] <= f.seq < rounds[3]]
+    assert [f.chunk_rows_held for f in wave] == [12, 12, 8, 8, 4, 4, 0, 0]
+    assert [f.chunk_rows_held for f in wave[::2]] == [12, 8, 4, 0]  # over its first chunk's rounds
+    assert all(f.chunk_rows_live == f.chunk_rows == ROWS_CAP for f in wave)
+    assert sum(f.chunk_rows_held for f in frames) == s.stat_chunk_rows_held == 48
+    assert not any(f.chunk_rows_held for f in frames if f not in wave)
+    assert len(calls) >= len(frames)
+    for had, took in calls:
+        assert took == [u for u in had if u in sorted(had)[:ROWS_CAP]]  # the oldest, still in slot order
     await s.close()
 
 
-@pytest.mark.parametrize("pending", [0, 1, 3], ids=["two_slots", "three_slots_in_four_rows", "five_slots_full_width"])
-async def test_the_overlap_built_chunk_plan_is_the_serial_build(pending):
+PLAN_CASES = {
+    # slots waiting with their uid's rank by arrival -> the slots the round takes, in slot order
+    "two_slots": ({}, [3, 11]),
+    "three_slots_in_four_rows": ({7: 2}, [3, 7, 11, -1]),
+    "five_slots_and_the_newest_waits": ({7: 2, 0: 3, 15: 4}, [0, 3, 7, 11]),
+    "five_slots_and_a_middle_one_waits": ({7: 4, 0: 2, 15: 3}, [0, 3, 11, 15]),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+async def test_the_overlap_built_chunk_plan_is_the_serial_build(case):
     """``_pipeline_plan_chunk`` (under the previous dispatch) and the serial
-    chunk round go through one builder: the same rows give the same arrays,
-    compact in slot order with padding after them, row r = slot r at full
-    width."""
+    chunk round go through one selection and one builder: the same slots with
+    a chunk to run give the same key and the same arrays, compact in slot
+    order with padding after them; past the ladder's widest entry both leave
+    the same slot out, the plan is taken (no discard), and the slot left out
+    is in no array."""
+    pending, want = PLAN_CASES[case]
     s = _wide_warm("gpt2")
     loop = asyncio.get_running_loop()
     rng = np.random.default_rng(2)
     rows = []
-    for uid, (slot, pp, temp) in enumerate([(3, 0, 0.0), (11, 20, 0.7)] + [(7, 8, 0.0), (0, 8, 0.0), (15, 8, 0.0)][:pending]):
-        seq = _Seq(rng.integers(0, 96, WSEQ).astype(np.int32), WNEW, temp, uid, 0, None, loop.create_future())
-        seq.uid, seq.chunk_cap, seq.prefilling, seq.prefill_pos = 100 + uid, CAP, True, pp
+    for slot, pp, temp, rank in [(3, 0, 0.0, 0), (11, 20, 0.7, 1)] + [(i, 8, 0.0, r) for i, r in pending.items()]:
+        seq = _Seq(rng.integers(0, 96, WSEQ).astype(np.int32), WNEW, temp, 0, 0, None, loop.create_future())
+        seq.uid, seq.chunk_cap, seq.prefilling, seq.prefill_pos = 100 + rank, CAP, True, pp
         rows.append((slot, seq.uid, pp, min(CAP, WSEQ - pp), seq))
+    used = s.stat_pipeline_plans_used
     try:
         for slot, _uid, _pp, _c, seq in rows[:2]:
             s._slots[slot] = seq
@@ -262,16 +307,19 @@ async def test_the_overlap_built_chunk_plan_is_the_serial_build(pending):
             s._pending_admits.append(_PendingAdmit(seq, slot, None, pp, 0))
         s._pipeline_plan_chunk()
         rows.sort(key=lambda r: r[0])
-        plan = s._pipeline_take_chunk_plan(tuple(r[:4] for r in rows))
+        taken = s._chunk_rows_taken(rows)  # what the serial round does with its slots
+        assert [r[0] for r in taken] == [i for i in want if i >= 0]
+        assert len(rows) - len(taken) == max(0, len(rows) - ROWS_CAP)
+        plan = s._pipeline_take_chunk_plan(tuple(r[:4] for r in taken))
         assert plan is not None and s._pending_chunk_plan is None
-        serial = s._chunk_input_arrays(rows)
+        assert s.stat_pipeline_plans_used == used + 1
+        serial = s._chunk_input_arrays(taken)
         for a, b in zip(plan[1:], serial):
             assert a.dtype == b.dtype
             np.testing.assert_array_equal(a, b)
         slots, ids, pos, counts, temps, _topks = serial
         assert (len(slots), ids.shape[1]) in LADDER
-        want = {0: [3, 11], 1: [3, 7, 11, -1], 3: [i if i in (0, 3, 7, 11, 15) else -1 for i in range(WIDE)]}
-        assert slots.tolist() == want[pending]
+        assert slots.tolist() == want
         r11 = slots.tolist().index(11)
         assert (pos[r11], counts[r11], temps[r11]) == (20, 20, np.float32(0.7))
         np.testing.assert_array_equal(ids[r11, :20], next(r[4] for r in rows if r[0] == 11).prompt[20:40])
@@ -284,6 +332,175 @@ async def test_the_overlap_built_chunk_plan_is_the_serial_build(pending):
         s._pending_admits.clear()
         for r in rows:
             r[4].future.cancel()
+
+
+# ---- a slot a round leaves out keeps its state (ISSUE 44): the two families with state rows ----
+SSLOTS, HINT = 8, 12
+
+
+def _stateful(family: str, **kw):
+    """(model spec, scheduler over 8 slots, not warmed) of a recurrent or a
+    short-convolution family at its own tests' rehearse size: 24-token
+    prompts in chunks of at most 16, a 12-token hint's boundary ends a chunk."""
+    from tests import test_conv_decoder, test_hybrid_decoder
+
+    mod = {"hybrid": test_hybrid_decoder, "conv": test_conv_decoder}[family]
+    ms = mod._zoo()
+    return ms, mod._sched(ms, n_slots=SSLOTS, **kw)
+
+
+def _spy_on_the_state_rows(s, calls):
+    """Wrap ``programs.chunk``: for every chunk dispatch record the slots left
+    out of it (from the selection's last call) and, of every state array, the
+    rows those slots own and the rows they have yet to read, before and after."""
+    chunk = s.programs.chunk
+
+    def rows_of(idx):
+        return [np.asarray(a)[idx] for a in s.pool.recurrent]
+
+    seen = []
+
+    def spy(*args):
+        had, took = calls[-1]
+        left = [i for i, q in enumerate(s._slots) if q is not None and q.uid in had and q.uid not in took]
+        idx = left + [s._slots[i].state_src for i in left if s._slots[i].state_src >= 0]
+        before = rows_of(idx)
+        out = chunk(*args)
+        seen.append((left, before, rows_of(idx)))
+        return out
+
+    s.programs.chunk = spy
+    return seen
+
+
+@pytest.mark.parametrize("family", ["hybrid", "conv"])
+async def test_a_slot_left_out_of_a_round_keeps_its_state_rows(family):
+    """Eight admissions that hit one hinted prefix over a 4-row bound: the
+    round takes the four oldest, and the four it leaves out keep their own
+    state and conv rows and the snapshot row they have yet to read bit for bit
+    over the rounds they sit out; all eight restore from the snapshot and read
+    the oracle's tokens; nothing compiles after ``warmup``."""
+    ms, s = _stateful(family)
+    assert s.chunk_rows_cap == ROWS_CAP and max(r for r, _c in s.chunk_buckets) == ROWS_CAP
+    s.warmup()
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, 96, (9, s.seq_len)).astype(np.int32)
+    prompts[1:, :HINT] = prompts[0, :HINT]
+    oracle = np.asarray(jax.jit(ms.apply_fn)(ms.params, jnp.asarray(prompts)))
+    np.testing.assert_array_equal(await s.submit(prompts[0], cache_prefix=HINT), oracle[0])
+    calls = _spy_on_the_selection(s)
+    seen = _spy_on_the_state_rows(s, calls)
+    for got, want in zip(await asyncio.gather(*(s.submit(p) for p in prompts[1:])), oracle[1:]):
+        np.testing.assert_array_equal(got, want)
+    sat_out = [(left, before, after) for left, before, after in seen if left]
+    assert [len(left) for left, _b, _a in sat_out] == [4]  # one chunk each past the prefix: 12 tokens
+    for _left, before, after in sat_out:
+        assert len(before) == len(s.pool.recurrent) >= 6
+        for b, a in zip(before, after):
+            np.testing.assert_array_equal(a, b)
+    frames = s.flight.snapshot()
+    assert [f.chunk_rows_held for f in frames if f.chunk_rows][-2:] == [4, 0]
+    assert sum(f.state_restores for f in frames) == 8 and s.stat_prefix_hits == 8
+    assert s.recompiles_since_warmup() == 0
+    s.pool.alloc.check()
+    await s.close()
+
+
+async def test_no_round_writes_a_snapshot_row_that_a_slot_it_left_out_has_yet_to_read():
+    """One snapshot row, bound to prefix A's entry. A wave of four cold hinted
+    requests (prefix B, older) and four that hit A (newer): the first round
+    takes the four cold ones, each wants a row for its own boundary, the only
+    one is A's and evicting A's entry would free it, but four waiting slots
+    have yet to read it: the round captures nothing and evicts nothing, and
+    the four read A's state."""
+    ms, s = _stateful("hybrid", prefix_slots=1)
+    s.warmup()
+    rng = np.random.default_rng(3)
+    prompts = rng.integers(0, 96, (9, s.seq_len)).astype(np.int32)
+    prompts[5:, :HINT] = prompts[0, :HINT]  # A
+    prompts[2:5, :HINT] = prompts[1, :HINT]  # B
+    oracle = np.asarray(jax.jit(ms.apply_fn)(ms.params, jnp.asarray(prompts)))
+    np.testing.assert_array_equal(await s.submit(prompts[0], cache_prefix=HINT), oracle[0])
+    row = next(iter(s._prefix_index.entries.values())).state_row
+    kept = [np.asarray(a)[row] for a in s.pool.recurrent]
+    calls = _spy_on_the_selection(s)
+    seen = _spy_on_the_state_rows(s, calls)
+    skips = s.stat_prefix_capture_skips
+    entry, evictions = next(iter(s._prefix_index.entries.values())), s._prefix_index.evictions
+    first = s._chunk_round
+
+    async def one_round():
+        await first()
+        if len(seen) == 1:  # the round that left the four out: A's entry outlived it
+            assert s._prefix_index.entries.get(entry.pin_id) is entry and s._prefix_index.evictions == evictions
+
+    s._chunk_round = one_round
+    outs = await asyncio.gather(*(s.submit(p, cache_prefix=HINT if i < 4 else None) for i, p in enumerate(prompts[1:])))
+    for got, want in zip(outs, oracle[1:]):
+        np.testing.assert_array_equal(got, want)
+    left, before, after = seen[0]
+    assert len(left) == 4
+    for b, a, k in zip(before, after, kept):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a[-1], k)  # the last row recorded is the one they wait for
+    assert s.stat_prefix_hits == 4 and s.stat_prefix_capture_skips >= skips + 4
+    s.pool.alloc.check()
+    await s.close()
+
+
+# ---- a slot the rounds have left out so far writes nothing into the prefix it hit (ISSUE 44) ----
+PSLOTS, PNEW, PHINT = 8, 16, 14  # pages of 4 rows: position 14 is row 2 of the boundary page the readers share
+
+
+@pytest.mark.parametrize("round_kind", ["plain", "chain"])
+async def test_a_slot_left_out_of_a_round_writes_nothing_into_the_prefix_it_hit(round_kind):
+    """An entry of a whole 40-token prompt (a retired request's) and a live
+    donor's hinted 14 tokens, the donor still generating; six admissions hit
+    one or the other at depth 14, which is no page's edge, so each maps a
+    boundary page it shares. Two rounds take the four oldest and the two they
+    leave out ride the donor's step (or speculative round) at their cursor,
+    position 14:
+    what the dispatch writes for them goes to the junk page, not to row 2 of
+    the shared page, which is the longer entry's position 14 and the donor's
+    own. The entries' pages end bit for bit as they were, and the donor, the
+    six and a later reader of the whole long prompt read the oracle's tokens."""
+    params = init_decoder(seed=3, vocab=96, hidden=64, layers=2, ffn=128, max_len=64)
+    kw = dict(seq_len=WSEQ, max_new_tokens=PNEW, n_slots=PSLOTS, kv_page_size=4, prefill_chunk=CAP, prefix_slots=8)
+    if round_kind == "chain":
+        kw.update(draft_params=init_decoder(seed=5, vocab=96, hidden=64, layers=1, ffn=64, max_len=64), spec_k=2)
+    s = DecodeScheduler(params, **kw)
+    s.warmup()
+    rng = np.random.default_rng(4)
+    prompts = rng.integers(0, 96, (8, WSEQ)).astype(np.int32)  # 0: the long entry's, 1: the donor's, then the six
+    prompts[2::2, :PHINT], prompts[3::2, :PHINT] = prompts[0, :PHINT], prompts[1, :PHINT]
+    prompts[2:, PHINT] = (prompts[(0, 1) * 3, PHINT] + 1) % 96  # and not a token further
+    want = np.asarray(generate(params, jnp.asarray(prompts), PNEW))
+    np.testing.assert_array_equal(await s.submit(prompts[0]), want[0])
+    donor = asyncio.ensure_future(s.submit(prompts[1], cache_prefix=PHINT))
+    while s.stat_prefix_captures < 2:
+        await asyncio.sleep(0)
+    entries = list(s._prefix_index.entries.values())
+    assert sorted(e.length for e in entries) == [PHINT, WSEQ]
+    pages = sorted({p for e in entries for p in e.pages})
+    held = [np.asarray(a)[:, pages] for a in s.pool.state]
+    wave = s.flight.rounds
+    got = await asyncio.gather(*(s.submit(p) for p in prompts[2:]))
+    # the round that left two out went on to the donor's step, or its draft and verify
+    assert [f.mode for f in s.flight.snapshot() if f.chunk_rows_held] == [round_kind] * 2
+    for g, w in zip([await donor] + got, want[1:]):
+        np.testing.assert_array_equal(g, w)
+    assert s.stat_prefix_hits == 6 and s.stat_prefix_tokens_saved == 6 * PHINT
+    # 26 tokens past the hit are two chunks: the four oldest ride both before the last two ride theirs
+    assert [f.chunk_rows_held for f in s.flight.snapshot() if f.seq >= wave and f.chunk_rows] == [2, 2, 0, 0]
+    assert s.stat_chunk_rows_held == 4
+    assert all(e.pin_id in s._prefix_index.entries for e in entries)
+    for a, b in zip(s.pool.state, held):
+        np.testing.assert_array_equal(np.asarray(a)[:, pages], b)
+    np.testing.assert_array_equal(await s.submit(prompts[0]), want[0])
+    assert s.stat_prefix_hits == 7 and s.stat_prefix_tokens_saved == 6 * PHINT + WSEQ - 1
+    assert s.recompiles_since_warmup() == 0
+    s.pool.alloc.check()
+    await s.close()
 
 
 @pytest.mark.parametrize("mechanism, message", [

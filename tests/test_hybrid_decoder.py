@@ -411,6 +411,10 @@ def test_allocator_hands_out_binds_and_takes_back_snapshot_rows():
     a.release(pin.pin_id)
     a.check()
     assert a.snapshot()["state_rows_free"] == 2
+    # a free row that an admission has yet to read is passed over, whichever lies on top
+    top = a._state_free[-1]
+    assert a.take_state_row({top}) == 5 - top and a.take_state_row({top}) == -1
+    assert a.take_state_row({7}) == top and a.take_state_row() == -1
     assert PageAllocator(20, 4, 2, 4).snapshot()["state_rows_free"] == 0  # a family without a state cache
 
 

@@ -102,6 +102,8 @@ CONFIGS = {
     "plain-serial": (dict(n_slots=2), False, COMMON | STEP),
     "plain-pipelined": (dict(n_slots=2), True, COMMON | STEP),
     "chunk-pipelined": (dict(n_slots=2, prefill_chunk=4), True, COMMON | STEP),
+    # six slots over a 4-row bound: five prompts arrive together, a round takes four (ISSUE 44)
+    "chunk-held-pipelined": (dict(n_slots=6, prefill_chunk=4), True, COMMON | STEP),
     "spec-serial": (dict(n_slots=2, spec_k=3), False, COMMON | SPEC),
     "spec-pipelined": (dict(n_slots=2, spec_k=3), True, COMMON | SPEC),
     "prefix-cow": (dict(n_slots=2, prefix_slots=4, prefill_chunk=4, kv_page_size=4, kv_pages=14), True,
@@ -128,6 +130,8 @@ def recorded():
             try:
                 if name == "prefix-cow":
                     _drive(s, _shared_prompts(10, shared=5, seed=11), {"cache_prefix": 5})
+                elif name == "chunk-held-pipelined":
+                    _drive(s, _shared_prompts(6, shared=0, seed=1))
                 else:
                     _drive(s, _shared_prompts(5, shared=0, seed=1))
             finally:
@@ -389,9 +393,12 @@ def test_a_dispatch_says_which_it_was(recorded, config):
             assert set(kw) == {"seq", "round", "rows", "c", "live", "write"}
             # the form its program's pool write took, by the comparison the program makes
             assert kw["write"] == ("page" if kw["c"] >= s.pool.page_size else "row")
-            assert (kw["rows"], kw["c"]) in s.chunk_buckets and 1 <= kw["live"] <= kw["rows"]
+            assert (kw["rows"], kw["c"]) in s.chunk_buckets and 1 <= kw["live"] <= kw["rows"] <= s.chunk_rows_cap
             assert (frame.chunk_rows, frame.chunk_c, frame.chunk_rows_live) == (kw["rows"], kw["c"], kw["live"])
             assert frame.to_dict()["chunk_c"] == kw["c"]
+            # a round leaves slots for a later one only where it is full, and its frame says how many
+            assert frame.chunk_rows_held == 0 or kw["live"] == s.chunk_rows_cap
+            assert frame.to_dict().get("chunk_rows_held", 0) == frame.chunk_rows_held
         elif fam == "step":
             assert set(kw) == {"seq", "round", "rows", "live"}
             assert kw["rows"] == s.n_slots and 1 <= kw["live"] <= s.n_slots and frame.active >= kw["live"]
@@ -404,8 +411,10 @@ def test_a_dispatch_says_which_it_was(recorded, config):
             # a draft's enqueue inside a verify dispatch carries the verify's stats
             assert e["kw"] == d["kw"]
     # a round without a chunk dispatch says so
-    assert all(f.chunk_c == 0 for f in frames.values() if not f.chunk_rows)
+    assert all(f.chunk_c == 0 and f.chunk_rows_held == 0 for f in frames.values() if not f.chunk_rows)
     assert any(f.chunk_c for f in frames.values())
+    held = sum(f.chunk_rows_held for f in frames.values())
+    assert held == s.stat_chunk_rows_held and (held > 0) == (config == "chunk-held-pipelined")
 
 
 def test_a_submit_books_the_loops_ingress_into_its_round(monkeypatch):
