@@ -46,9 +46,10 @@ Two more keys of the same block, as ``model_type: xing4_0`` publishes it:
 RMSNorm, the rotary helper, the expert forms, the sampler and the step /
 chunk wrappers are the other families' (imported, not re-typed).
 
-Served beside the plain rounds: a step that reads the latent plane in place
-(ops/mla.py ``mla_decode_attention``, where ``decode_programs.
-_step_attn_kernel`` chooses it). Not served: speculation, a decode mesh, the
+Served beside the plain rounds: a step and prefill chunks that read the latent
+plane in place (ops/mla.py ``mla_decode_attention`` and
+``mla_chunk_attention``, where ``decode_programs._step_attn_kernel`` chooses
+them). Not served: speculation, a decode mesh, the
 int8 pool, the KV tiers and prefix export (each refuses by name,
 ``decoder.require_served``).
 """
@@ -83,6 +84,7 @@ from seldon_core_tpu.ops.mla import (
     absorb_short,
     expand_cheaper,
     kernel_runs,
+    kernel_takes,
     mla_paged_attention,
     pages_fetched,
 )
@@ -306,15 +308,16 @@ def _streams_in(cfg: MLADecoderConfig, maps: dict, x, valid):
     return mhc.pre_mix(x, h_pre), (h_post, h_res), resid
 
 
-def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, n_keys, runs, interpret):
+def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, n_keys, read, runs, interpret):
     """One layer over the latent plane: x[n, m, d] with row i's query j at
     positions[i] + j (x[S, n, m, d] with ``hc_mult`` S > 1: each block then
     reads ``u``, the maps' mixture of the streams, and writes its output
     back through them, ops/mhc.py). The new rows ``[latent | rotated key]``
     scatter through the block tables first, attention reads them back with
     the cached ones
-    (write-then-read, as in every family): through the step's kernel where
-    ``_forward`` found the dispatch to be its kind (``runs``; ``interpret``:
+    (write-then-read, as in every family): through ops/mla.py's kernels where
+    ``_forward`` found the program set to have chosen them (``runs``, with
+    ``read`` [n] the leading queries of a row somebody reads; ``interpret``:
     under the Pallas interpreter), else the walk. Returns (x, pool,
     counters[4]: zeros for a dense layer, the two blocks' larger
     ``mhc_resid_ppm`` or None)."""
@@ -344,7 +347,7 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
         ctx = mla_paged_attention(
             q[..., : c.nope_dim], q_rope, pool[0], li, bt, q_pos, n_keys, p["kv_b"], scale=c.score_scale,
             expand=expand_cheaper(m, **sizes), short=absorb_short(**sizes),
-            live=None if counts is None else jnp.max(counts), runs=runs, interpret=interpret,
+            live=None if counts is None else jnp.max(counts), runs=runs, counts=read, interpret=interpret,
         )
     with jax.named_scope(SCOPE_ATTN_OUT):
         o = ctx @ p["attn_o"].astype(x.dtype)
@@ -376,8 +379,8 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     (chunk rounds), ``rows`` (the step's generating slots), ``pick`` (the
     head's one query a row). ``attn_kernel`` (static; "" | "mosaic" |
     "interpret": ``decode_programs._step_attn_kernel``'s answer) lets a
-    dispatch of ONE query a row read the plane through ops/mla.py's kernel;
-    every other shape walks. With ``hc_mult`` > 1 the state between the
+    dispatch read the plane through ops/mla.py's kernels, the step's for ONE
+    query a row and the chunk's for more (``kernel_takes``); "" walks. With ``hc_mult`` > 1 the state between the
     embedding and the final norm is [hc_mult, n, m, d]; ``hidden`` is the
     streams' sum. Returns (logits[n, m or 1, vocab] float32, hidden[n, m, d],
     pool, counters[7 or 8] int32: ``MLADecoder.frame_counters``)."""
@@ -394,7 +397,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     n_keys = jnp.where(last > 0, positions + last, 1)
     with jax.named_scope(SCOPE_ATTN), jax.named_scope(SCOPE_MLA_CORE):
         ps = pool[0].shape[2]
-        runs = kernel_runs(attn_kernel, m, cfg.kv_rank, bt, n_keys, ps)  # every layer's kernel walks the same tables
+        runs = kernel_runs(attn_kernel, m, cfg.kv_rank, cfg.heads, bt, n_keys, ps)  # every layer's kernel walks the same tables
         if runs is None:
             fetched = jnp.zeros((2,), jnp.int32)  # the walk fetches nothing through the kernel
         else:
@@ -406,7 +409,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     cnt = jnp.zeros((4,), jnp.int32)
     resid = []
     for li, lp in enumerate(params["layers"]):
-        x, pool, c, r = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, runs, attn_kernel == "interpret")
+        x, pool, c, r = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, last, runs, attn_kernel == "interpret")
         with jax.named_scope(SCOPE_MLP), jax.named_scope(SCOPE_MOE_COMBINE):
             cnt = cnt + c
         if r is not None:
@@ -440,8 +443,9 @@ class MLADecoder:
     # what the programs' readback carries after the tokens (FlightFrame
     # fields): the routing over the experts HELD, the picks of real rows that
     # landed on one, and the latent rows the dispatch's live rows attended
-    # over (each row's keys, summed; one layer's), and where the step's kernel
-    # ran the pages it fetched for them and those that came in run DMAs
+    # over (each row's keys, summed; one layer's), and where a kernel ran (the
+    # step's or the chunk's) the pages it fetched for them and those that came
+    # in run DMAs;
     # with ``hc_mult`` > 1 also the stream maps' canary, ``mhc_resid_ppm``: the
     # largest |row or column sum - 1| of any H_res of the dispatch's real rows,
     # x 1e6 (the scheduler SUMS a round's dispatches: a round of one dispatch,
@@ -452,7 +456,7 @@ class MLADecoder:
                 "mla_pages_read", "mla_run_pages")
         return base + (("mhc_resid_ppm",) if self.cfg.hc_mult > 1 else ())
 
-    # beside the plain rounds: a step that reads the plane in place (ops/mla.py's kernel)
+    # beside the plain rounds: a step and chunks that read the plane in place (ops/mla.py's kernels)
     serves = frozenset({"attn_kernel"})
     state_init = None  # no recurrent state: latent pages only
 
@@ -480,10 +484,16 @@ class MLADecoder:
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.counted_programs``);
-        with ``attn_kernel`` both may take the kernel, and the one whose
-        dispatch has one query a row, the step, does. Cached: equal
-        configurations share compiled programs."""
+        with ``attn_kernel`` the step takes ops/mla.py's step kernel and a
+        chunk, whatever its length, the chunk's (``chunk_attn``). Cached:
+        equal configurations share compiled programs."""
         return counted_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
+
+    def chunk_attn(self, attn_kernel: str, c: int) -> str:
+        """How the chunk program of ``c`` tokens a row reads the plane under
+        ``attn_kernel``: "kernel" (``mla_chunk_attention``) or "walk". Static
+        a program: what its dispatches' annotation and frames say."""
+        return "kernel" if kernel_takes(attn_kernel, c, self.cfg.kv_rank, self.cfg.heads) else "walk"
 
     def generate(self, params, ids, max_new_tokens: int):
         """The fused fallback apply (``decoder.paged_greedy_generate``) over a

@@ -100,10 +100,13 @@ def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int
     family's blocked walk — everywhere else: the int8 pool, a
     tensor-parallel mesh, a family without a kernel, a geometry the kernel
     cannot tile, the CPU backend (where the gather and the walk are the
-    oracles). The dispatch's shape is the program's own to see: only one
-    query a slot takes the kernel (models/decoder.py ``_layer_step_paged``
-    and ``_paged_step_reads``, ops/mla.py ``mla_paged_attention``), so
-    chunk, verify and tree programs gather or walk whatever this says."""
+    oracles). The dispatch's shape is the program's own to see: in the
+    two-plane families only one query a slot takes the kernel (models/decoder.py
+    ``_layer_step_paged`` and ``_paged_step_reads``), so their chunk, verify
+    and tree programs gather whatever this says; the latent family's chunks
+    take its many-queries kernel under the same answer (ops/mla.py
+    ``kernel_takes``, ``mla_chunk_attention``; ``DecodePrograms.chunk_attn``
+    says which a chunk length took)."""
     if "attn_kernel" not in family.serves or mesh is not None or len(pool_state) > 2:
         return ""
     devices = pool_state[0].sharding.device_set
@@ -348,6 +351,8 @@ class DecodePrograms:
             "" if feature
             else _step_attn_kernel(family, pool.state, mesh, dims["heads"], dims["kv_heads"])
         )
+        # a family with a chunk kernel says which chunk lengths take it (the feature twins gather)
+        self._chunk_attn = None if feature else getattr(family, "chunk_attn", None)
         if feature:
             # feature mode swaps the step/chunk pair for feature-carrying
             # twins (the chunk one also teacher-forces the head's prompt
@@ -482,6 +487,14 @@ class DecodePrograms:
                 self.params, pool.state, bt, ids, pos, counts, temps, topks, self.seed, tick
             )
         return out, lambda: self._tokens(out)
+
+    def chunk_attn(self, c: int) -> str:
+        """How the chunk program of ``c`` tokens a row reads the pool:
+        "kernel" where the family has a chunk kernel and the set's choice
+        (``attn_kernel``) and the static shape send this program there, else
+        "walk" (the latent family's blocked walk, the other families' page
+        gather, the feature twin)."""
+        return "walk" if self._chunk_attn is None else self._chunk_attn(self.attn_kernel, c)
 
     def draft(self, toks, pos, temps, topks, tick) -> tuple:
         """Enqueue a speculative round's draft side. Returns the proposal

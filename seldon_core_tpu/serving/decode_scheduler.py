@@ -2183,7 +2183,7 @@ class DecodeScheduler:
         # rows the round's chunk dispatches computed, the prefilling slots
         # among them, and the slots with a chunk to run that they left out
         self._rb_chunk_rows = self._rb_chunk_rows_live = self._rb_chunk_c = 0
-        self._rb_chunk_rows_held = 0
+        self._rb_chunk_rows_held = self._rb_chunk_rows_kernel = 0
         # rows of the round's dispatches that asked the sampler for a draw,
         # and those among them that asked for top_k (_count_sampling)
         self._rb_sample_rows = self._rb_sample_topk_rows = 0
@@ -2233,7 +2233,7 @@ class DecodeScheduler:
         """The ``with``-handle for one dispatch of a flight F_* family:
         THE timing-and-naming point of every dispatch (``_Dispatch``).
         ``stats`` (what the call site holds: integers, and a chunk's
-        ``write`` form) ride the family's next
+        ``write`` and ``attn`` forms) ride the family's next
         dispatch's trace annotations; ``_timed_call`` enters the handle
         itself, so its callers note theirs here first."""
         d = self._dispatches[family]
@@ -2316,6 +2316,7 @@ class DecodeScheduler:
                         state_captures=self._rb_state_captures,
                         chunk_c=self._rb_chunk_c,
                         chunk_rows_held=self._rb_chunk_rows_held,
+                        chunk_rows_kernel=self._rb_chunk_rows_kernel,
                         ingress_ns=ingress[0] - self._ingress_committed[0],
                         ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
@@ -2883,9 +2884,10 @@ class DecodeScheduler:
         t0 = telemetry.now_ns()
         # which chunk_buckets entry the dispatch is, for a trace and the frame
         self._rb_chunk_c = ids.shape[1]
+        attn = self.programs.chunk_attn(ids.shape[1])
         self._dispatch(
             F_CHUNK, rows=len(slots), c=ids.shape[1], live=len(rows),
-            write=write_form(ids.shape[1], self.pool.page_size),
+            write=write_form(ids.shape[1], self.pool.page_size), attn=attn,
         )
         toks, counted = await self._timed_call(
             F_CHUNK,
@@ -2899,6 +2901,8 @@ class DecodeScheduler:
         self._rb_chunk_rows += len(slots)
         self._rb_chunk_rows_live += len(rows)
         self._rb_chunk_rows_held += held
+        if attn == "kernel":
+            self._rb_chunk_rows_kernel += len(rows)
         self._count_sampling(temps, topks)
         bucket = ids.shape[1]
         finishing: list[tuple[_Seq, int, int]] = []  # (seq, slot, its row's token)

@@ -431,10 +431,14 @@ class FlightFrame:
     chunk and step dispatches advanced (models/conv_decoder.py: the second
     family with state rows, whose ``state_restores`` / ``state_captures``
     are the same two counts); ``mla_pages_read`` /
-    ``mla_run_pages`` where that family's step ran its kernel (ops/mla.py
-    ``mla_decode_attention``): the pages it fetched for the live rows, and
-    those among them that lay in runs of consecutive pages and came in ONE
-    DMA a run (one layer's); 0 where the walk ran; ``attn_run_pages`` of
+    ``mla_run_pages`` where that family's dispatches ran a kernel (ops/mla.py:
+    the step's ``mla_decode_attention``, and since PR 45 a chunk's
+    ``mla_chunk_attention``, whose own pages a round with a chunk adds: the
+    readers of the step's figures take step-only rounds): the pages of the
+    live rows' tables it fetched (a chunk's kernel fetches them once a query
+    block; counted once), and those among them that lay in runs of
+    consecutive pages and came in ONE DMA a run (one layer's); 0 where the
+    walk ran; ``attn_run_pages`` of
     the ``attn_pages_read`` of a round whose step ran the grouped-query
     kernel (ops/gqa_decode.py), those that came in ONE DMA a run, as the
     program counted them (one layer's K); 0 elsewhere; ``mhc_resid_ppm``
@@ -449,7 +453,10 @@ class FlightFrame:
     round and were left for a later one, because the ladder's widest entry
     holds fewer (the scheduler's ``_chunk_rows_taken``: the first arrivals
     ride), 0 in a round that took them all and in a round without a chunk
-    dispatch; ``ingress_ns`` / ``ingress_requests`` the submits that reached
+    dispatch; ``chunk_rows_kernel`` of the round's ``chunk_rows_live``, those
+    whose attention ran in a chunk kernel (``DecodePrograms.chunk_attn``:
+    static a program, so all of a dispatch's or none), 0 where the chunk
+    walked or gathered; ``ingress_ns`` / ``ingress_requests`` the submits that reached
     the queue during the round and their summed time on the event loop from
     the request's bytes in hand (``Ingress``: body parse, message build, the
     hops to ``submit``), 0 / 0 for callers that hand ``submit`` no mark."""
@@ -468,7 +475,7 @@ class FlightFrame:
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
-        "chunk_rows_held",
+        "chunk_rows_held", "chunk_rows_kernel",
     )
 
     def __init__(
@@ -485,7 +492,7 @@ class FlightFrame:
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
-        chunk_rows_held=0,
+        chunk_rows_held=0, chunk_rows_kernel=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -538,6 +545,7 @@ class FlightFrame:
         self.mhc_resid_ppm = mhc_resid_ppm
         self.attn_run_pages = attn_run_pages
         self.chunk_rows_held = chunk_rows_held
+        self.chunk_rows_kernel = chunk_rows_kernel
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -608,6 +616,8 @@ class FlightFrame:
             d["chunk_c"] = self.chunk_c
             if self.chunk_rows_held:
                 d["chunk_rows_held"] = self.chunk_rows_held
+            if self.chunk_rows_kernel:
+                d["chunk_rows_kernel"] = self.chunk_rows_kernel
         if self.ingress_requests:
             d["ingress"] = [self.ingress_requests, round(self.ingress_ns / 1e3, 1)]
         if self.sample_rows:

@@ -552,7 +552,10 @@ LOWERED_BEFORE_THE_FOURTH_FAMILY = {
 # the fourth family's two at the commit before the fifth (PR 41's parent,
 # 934f8c5), hashed there: PR 41 took the router out of ``ops/moe.py``
 # ``moe_held_ffn`` (the family now hands it the picks), and the latent
-# family's programs had to lower to the text they lowered to before
+# family's programs had to lower to the text they lowered to before. They
+# still do after PR 45, which gave the family's CHUNKS a kernel to choose on a
+# TPU: on this, the CPU backend, both programs walk (``attn_kernel`` ""), and
+# the walk's text is what it was
 LOWERED_BEFORE_THE_FIFTH_FAMILY = {
     "mla.step": "dcb7e82625fdc8fd4a1fa472f4862fec343f6287a5e81be74a7c35de641480a9",
     "mla.chunk": "b2aaef81dce78101a011886d34302cedd9a425dbfa0bec0c3bd1e7631476901a",

@@ -93,7 +93,8 @@ def test_the_default_ring_holds_a_benchmark_window(monkeypatch):
     rec = FlightRecorder(n_slots=4, name="t", enabled=True)
     assert rec.capacity == flight_mod._DEFAULT_CAPACITY == 8192 >= 51.0 / 0.0063
     for i in range(5000):
-        rec.record(_frame(i, chunk_rows=2, chunk_c=64, ingress_ns=7, ingress_requests=1, chunk_rows_held=i % 2))
+        rec.record(_frame(i, chunk_rows=2, chunk_c=64, ingress_ns=7, ingress_requests=1, chunk_rows_held=i % 2,
+                          chunk_rows_live=2, chunk_rows_kernel=2 * (i % 2)))
     frames = rec.snapshot()
     assert [f.seq for f in frames] == list(range(5000)) and rec.rounds == 5000
     assert frames[-1].chunk_c == 64 and frames[-1].to_dict()["ingress"] == [1, 0.0]
@@ -102,6 +103,11 @@ def test_the_default_ring_holds_a_benchmark_window(monkeypatch):
     assert (frames[-1].chunk_rows_held, frames[-1].to_dict()["chunk_rows_held"]) == (1, 1)
     assert frames[-2].chunk_rows_held == 0 and "chunk_rows_held" not in frames[-2].to_dict()
     assert _frame(0).chunk_rows_held == 0  # a round without a chunk dispatch
+    # ISSUE 45: the prefilling rows whose attention ran in a chunk kernel, in the dict only where some did
+    assert "chunk_rows_kernel" in FlightFrame.__slots__ and "``chunk_rows_kernel``" in FlightFrame.__doc__
+    assert (frames[-1].chunk_rows_kernel, frames[-1].to_dict()["chunk_rows_kernel"]) == (2, 2)
+    assert frames[-2].chunk_rows_kernel == 0 and "chunk_rows_kernel" not in frames[-2].to_dict()
+    assert _frame(0).chunk_rows_kernel == 0 and "step" in FlightFrame.__doc__.split("``mla_run_pages``")[1][:200]
     assert rec.tokens_total == 2 * 5000 and len(rec._frames) == 8192
     monkeypatch.setenv("ENGINE_FLIGHT_FRAMES", "64")
     assert FlightRecorder(n_slots=4, name="small", enabled=True).capacity == 64

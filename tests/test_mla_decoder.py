@@ -79,15 +79,16 @@ def _ref_logits(ref, params, ids, precision):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(cfg, tag=""):
+def _programs(cfg, tag="", kernel=""):
     """(chunk, step) jitted over ``_forward``; ``tag`` keys a trace made
-    under a planted fault apart from the clean one."""
-    chunk = jax.jit(lambda p, pool, bt, t, pos, cnt: mla._forward(cfg, p, pool, bt, t, pos, counts=cnt)[::2])
-    step = jax.jit(lambda p, pool, bt, t, pos, rows: mla._forward(cfg, p, pool, bt, t, pos, rows=rows)[::2])
+    under a planted fault apart from the clean one; ``kernel``: the
+    programs' ``attn_kernel``."""
+    chunk = jax.jit(lambda p, pool, bt, t, pos, cnt: mla._forward(cfg, p, pool, bt, t, pos, counts=cnt, attn_kernel=kernel)[::2])
+    step = jax.jit(lambda p, pool, bt, t, pos, rows: mla._forward(cfg, p, pool, bt, t, pos, rows=rows, attn_kernel=kernel)[::2])
     return chunk, step
 
 
-def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32, cfg=CFG, tag="", greedy_after=None, width=0):
+def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32, cfg=CFG, tag="", greedy_after=None, width=0, kernel=""):
     """Teacher-forced through the paged programs: chunked prefill of
     ``sum(chunks)`` tokens, then single-token steps along ``ids``; returns
     logits [len(ids), vocab]. The sequence sits in slot 1 of 3 (slots 0 and 2
@@ -95,9 +96,11 @@ def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32, cfg=CFG,
     pages of an earlier run are MAPPED (a prefix hit), only the rest is
     computed. ``greedy_after``: from that position on each next token is the
     served argmax (written into ``ids``). ``width``: the chunk programs'
-    static chunk length where it is more than the chunk (the rest is padding)."""
+    static chunk length where it is more than the chunk (the rest is padding).
+    ``kernel``: the programs' ``attn_kernel`` ("interpret": chunks and steps
+    through ops/mla.py's kernels)."""
     fam = mla.mla_family(cfg)
-    chunk, step = _programs(cfg, tag)
+    chunk, step = _programs(cfg, tag, kernel)
     n_slots, pages = 3, CTX // PS
     if prefix_from is None:
         pool = fam.paged_kv_init(params, 1 + 2 * pages, PS, dtype)
@@ -183,6 +186,34 @@ def test_prefix_hit_equals_reference_float32(ref, weights, shared, chunks):
     second[:shared] = first[:shared]
     _, pool, pages = _serve(params, first, chunks=(10, 10))
     got, _, _ = _serve(params, second, chunks=chunks, prefix_from=(pool, pages, shared))
+    want = _ref_logits(ref, params, second, "highest")
+    np.testing.assert_allclose(got[shared:], want[shared:], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize(
+    "chunks, width", [((8, 8, 8), 0), ((24,), 0), ((5, 19), 0), ((16, 1, 7), 0), ((3, 5), 24)],
+    ids=["short", "long", "both", "ladder", "few_live_in_a_wide_entry"],
+)
+def test_cold_prefill_through_the_chunk_kernel_equals_reference_float32(ref, weights, small_chunk_blocks, chunks, width):
+    """The chunk PROGRAM with the kernels on (``attn_kernel`` "interpret":
+    every chunk length through ``mla_chunk_attention``, the steps after it
+    through the step's kernel; slots 0 and 2 ride as dead rows): every
+    position's logits equal the reference's, to the walk's 1e-5."""
+    params, ids = weights[jnp.float32], _ids(1)
+    got, _, _ = _serve(params, ids, chunks=chunks, width=width, kernel="interpret")
+    np.testing.assert_allclose(got, _ref_logits(ref, params, ids, "highest"), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("shared,chunks", [(8, (3, 3)), (20, (2,)), (16, (20,))])
+def test_prefix_hit_through_the_chunk_kernel_equals_reference_float32(ref, weights, small_chunk_blocks, shared, chunks):
+    """The second sequence's chunks start past the mapped pages (a query
+    block's first position is not 0, its keys begin in another request's
+    pages): through the kernel, the reference's logits."""
+    params = weights[jnp.float32]
+    first, second = _ids(2), _ids(3)
+    second[:shared] = first[:shared]
+    _, pool, pages = _serve(params, first, chunks=(10, 10), kernel="interpret")
+    got, _, _ = _serve(params, second, chunks=chunks, prefix_from=(pool, pages, shared), kernel="interpret")
     want = _ref_logits(ref, params, second, "highest")
     np.testing.assert_allclose(got[shared:], want[shared:], atol=1e-5, rtol=0)
 
@@ -426,20 +457,164 @@ def test_page_runs_flags_whole_groups_of_consecutive_pages_a_row_has(small_block
     assert np.asarray(mla_ops.pages_fetched(jnp.array([500, 0, 1]), none, every, PS, 12)).tolist() == [12 + 1 + 1, 0]
 
 
-def test_only_one_query_a_row_takes_the_kernel(small_blocks):
-    """Inside a program the dispatch's shape decides: ``kernel_runs`` gives
-    the kernel its runs for one query a row, and None (the walk) for a chunk
-    of several, for no kernel chosen, and under Mosaic for a latent that is
-    not whole lane tiles."""
+def test_which_dispatch_shapes_take_which_kernel(small_blocks):
+    """Inside a program the dispatch's static shape decides (``kernel_takes``;
+    ``kernel_runs`` hands whichever kernel its runs): one query a row the
+    step's kernel, more the chunk's, whatever their number; None (the walk)
+    where no kernel was chosen, and under Mosaic for a latent that is not
+    whole lane tiles or a query block that is not whole sublane tiles (257
+    queries of 4 heads: a prime past a block's 256, so blocks of one query,
+    4 rows). ``MLADecoder.chunk_attn`` is the same answer by
+    name, for the dispatch's annotation and the frames."""
     _plane, bt, *_ = _attention_case(2)
     n_keys = jnp.array([5, 22, 45], jnp.int32)
     want = np.asarray(mla_ops.page_runs(bt, n_keys, PS))
-    for kernel, queries, rank, taken in [("interpret", 1, 16, True), ("mosaic", 1, 512, True), ("mosaic", 1, 16, False),
-                                         ("interpret", 5, 16, False), ("mosaic", 64, 512, False), ("", 1, 512, False)]:
-        got = mla_ops.kernel_runs(kernel, queries, rank, bt, n_keys, PS)
+    for kernel, queries, rank, heads, taken in [
+        ("interpret", 1, 16, 4, True), ("mosaic", 1, 512, 64, True), ("mosaic", 1, 16, 4, False),
+        ("interpret", 5, 16, 4, True), ("mosaic", 16, 512, 32, True), ("mosaic", 64, 512, 64, True),
+        ("mosaic", 256, 512, 32, True), ("mosaic", 256, 512, 64, True), ("mosaic", 64, 16, 4, False),
+        ("mosaic", 257, 512, 4, False), ("mosaic", 264, 512, 4, True), ("", 1, 512, 64, False), ("", 64, 512, 64, False),
+    ]:
+        assert mla_ops.kernel_takes(kernel, queries, rank, heads) == taken
+        got = mla_ops.kernel_runs(kernel, queries, rank, heads, bt, n_keys, PS)
         assert (got is not None) == taken
         if taken:
             np.testing.assert_array_equal(np.asarray(got), want)
+    assert [FAM.chunk_attn(k, 16) for k in ("", "interpret", "mosaic")] == ["walk", "kernel", "walk"]  # a 16-wide latent
+    full = mla.mla_family(mla.MLADecoderConfig(heads=32, kv_rank=512, rope_dim=64))
+    assert [full.chunk_attn("mosaic", c) for c in (16, 64, 256)] == ["kernel"] * 3 and full.chunk_attn("", 256) == "walk"
+
+
+# (c3) the chunk's kernel (ops/mla.py mla_chunk_attention) under the Pallas interpreter
+
+
+@pytest.fixture()
+def small_chunk_blocks(small_blocks, monkeypatch):
+    monkeypatch.setattr(mla_ops, "CHUNK_BLOCK_PAGES", 8)  # key blocks of 32: 2 to 10 a row
+
+
+def _chunk_case(m, heads, tables, live, dtype=jnp.float32):
+    """Three rows of ``m`` queries by ``heads`` heads (query blocks of 1024
+    query-head rows, as on the chip: 32 or 16 queries), at positions 0 (cold),
+    37 (inside a page and a run) and 5. ``live``: "full" (every row all its
+    queries) or "ragged": row 1 has a third of its queries (its last live
+    query block ends inside the causal triangle, the blocks after it are
+    nobody's) and row 2 NONE (a padding row of the ladder entry); or the three
+    counts themselves. Returns the
+    plane, the same with NaN in every page no live query may see (past a
+    row's own keys, and the dead row's whole table), the tables and the
+    rest."""
+    n, first = 3, np.array([0, 37, 5], np.int32)
+    pages = (37 + m) // PS + 3
+    ks = jax.random.split(jax.random.key(m + heads), 4)
+    total = 1 + n * pages
+    plane = np.array(jax.random.normal(ks[0], (2, total, PS, 128), jnp.float32))
+    plane[..., 20:] = 0.0  # the row's padding lanes, as the program writes them
+    ids = np.arange(1, total, dtype=np.int32)
+    if tables == "scattered":
+        ids = np.random.default_rng(3).permutation(ids)
+    bt = ids.reshape(n, pages)
+    counts = np.array({"full": [m, m, m], "ragged": [m, m // 3 + 1, 0]}.get(live, live), np.int32)
+    unread = plane.copy()
+    for i in range(n):
+        held = -(-int(first[i] + counts[i]) // PS) if counts[i] else 0
+        unread[:, bt[i, held:]] = np.nan
+    q_nope = jax.random.normal(ks[1], (n, m, heads, 8), jnp.float32).astype(dtype)
+    q_rope = jax.random.normal(ks[2], (n, m, heads, 4), jnp.float32).astype(dtype)
+    kv_b = (jax.random.normal(ks[3], (16, heads, 16), jnp.float32) * 0.3).astype(dtype)
+    return (jnp.asarray(plane, dtype), jnp.asarray(unread, dtype), jnp.asarray(bt), jnp.asarray(first), jnp.asarray(counts),
+            q_nope, q_rope, kv_b)
+
+
+def _chunk_through_the_kernel(plane, bt, first, counts, q_nope, q_rope, kv_b, li=1):
+    m = q_nope.shape[1]
+    q_pos = first[:, None] + jnp.arange(m, dtype=jnp.int32)[None, :]
+    n_keys = jnp.where(counts > 0, first + counts, 1)  # as ``_forward`` hands them
+    runs = mla_ops.page_runs(bt, n_keys, PS)
+    return mla_ops.mla_paged_attention(q_nope, q_rope, plane, li, bt, q_pos, n_keys, kv_b, scale=0.3, expand=False,
+                                       runs=runs, counts=counts, interpret=True)
+
+
+def _assert_live_queries_equal(got, want, counts, heads, atol):
+    """The queries somebody reads equal ``want``; a query block wholly past a
+    row's count (and so a whole dead row) is ZEROS; whatever lies between is
+    finite."""
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    tq = mla_ops._query_block(got.shape[1], heads)
+    for i, c in enumerate(np.asarray(counts)):
+        np.testing.assert_allclose(got[i, :c], np.asarray(want, np.float32)[i, :c], atol=atol)
+        assert not got[i, -(-int(c) // tq) * tq :].any()
+
+
+@pytest.mark.parametrize("tables, live", [("one_run", "full"), ("scattered", "ragged")])
+@pytest.mark.parametrize("heads", [32, 64])
+@pytest.mark.parametrize("m", [16, 64, 256])
+def test_the_chunk_kernel_equals_the_walk_and_plain_attention_float32(small_chunk_blocks, m, heads, tables, live):
+    """Many queries a row through the kernel == ``_walk`` (absorbed AND
+    expanded) == every head's keys and values expanded over the whole
+    gathered table, for the queries somebody reads: causal by position, each
+    row to its own length, over tables of runs and of scattered pages. The
+    plane the kernel reads holds NaN in every page past a row's own keys and
+    in the dead row's whole table: none is fetched (a fetched NaN would
+    reach the output through 0 x NaN), and the dead row comes back zeros."""
+    plane, unread, bt, first, counts, q_nope, q_rope, kv_b = _chunk_case(m, heads, tables, live)
+    q_pos = first[:, None] + jnp.arange(m, dtype=jnp.int32)[None, :]
+    got = _chunk_through_the_kernel(unread, bt, first, counts, q_nope, q_rope, kv_b)
+    assert got.shape == (3, m, heads * 8)
+    all_keys = jnp.where(counts > 0, first + m, 1)
+    walked = [mla_ops.mla_paged_attention(q_nope, q_rope, plane, 1, bt, q_pos, all_keys, kv_b, scale=0.3, expand=e) for e in (False, True)]
+    plain = _plain_attention(plane, 1, bt, q_nope, q_rope, q_pos, kv_b, 16, 0.3)
+    for want in (*walked, plain):
+        _assert_live_queries_equal(got, want, counts, heads, atol=3e-6)
+
+
+@pytest.mark.parametrize("m, heads, tables", [(16, 32, "scattered"), (64, 64, "one_run"), (256, 32, "scattered")])
+def test_the_chunk_kernel_equals_the_walk_bfloat16(small_chunk_blocks, m, heads, tables):
+    """At the pool's bfloat16 the kernel and the absorbed walk round alike
+    (bfloat16 operands, float32 sums, the probabilities cast before the
+    context product): the step kernel's tolerances."""
+    plane, _unread, bt, first, counts, q_nope, q_rope, kv_b = _chunk_case(m, heads, tables, "ragged", jnp.bfloat16)
+    q_pos = first[:, None] + jnp.arange(m, dtype=jnp.int32)[None, :]
+    got = _chunk_through_the_kernel(plane, bt, first, counts, q_nope, q_rope, kv_b, li=0)
+    walked = mla_ops.mla_paged_attention(q_nope, q_rope, plane, 0, bt, q_pos, jnp.where(counts > 0, first + m, 1), kv_b,
+                                         scale=0.3, expand=False)
+    assert got.dtype == walked.dtype == jnp.bfloat16
+    f32 = [a.astype(jnp.float32) for a in (q_nope, q_rope, plane, kv_b)]
+    plain = _plain_attention(f32[2], 0, bt, f32[0], f32[1], q_pos, f32[3], 16, 0.3)
+    _assert_live_queries_equal(got, walked, counts, heads, atol=2e-2)
+    _assert_live_queries_equal(got, plain, counts, heads, atol=4e-2)
+
+
+def test_few_live_queries_in_a_256_wide_entry_skip_the_blocks_nobody_reads(small_chunk_blocks):
+    """A wave of short tails riding the 256-token entry: rows of 3, 17 and 0
+    live queries. Each row's first query block (32 queries) is computed and
+    equals the walk; the 7, 7 and 8 blocks after it are zeros without a fetch (NaN
+    everywhere past the rows' own keys), which is what the walk's
+    ``absorb_short`` branch saved with a second program body."""
+    plane, unread, bt, first, counts, q_nope, q_rope, kv_b = _chunk_case(256, 32, "scattered", (3, 17, 0))
+    got = _chunk_through_the_kernel(unread, bt, first, counts, q_nope, q_rope, kv_b)
+    q_pos = first[:, None] + jnp.arange(256, dtype=jnp.int32)[None, :]
+    want = _plain_attention(plane, 1, bt, q_nope, q_rope, q_pos, kv_b, 16, 0.3)
+    _assert_live_queries_equal(got, want, counts, 32, atol=3e-6)
+    assert not np.asarray(got)[:2, 32:].any() and not np.asarray(got)[2].any()
+
+
+def test_a_geometry_mosaic_cannot_tile_is_refused_by_name_before_the_compiler():
+    """Outside the interpreter the chunk kernel refuses what ``kernel_tiles``
+    / ``kernel_takes`` rule out, by name: float32 rows, pages of 4, a latent
+    that is not whole lane tiles, a query block of 4 rows (257 queries, a
+    prime, of 4 heads)."""
+    qc = jnp.zeros((2, 257 * 4, 128), jnp.bfloat16)
+    bt, z = jnp.zeros((2, 8), jnp.int32), jnp.zeros((2,), jnp.int32)
+    runs = jnp.zeros((2, 4), jnp.int32)
+    for plane, rank, heads in [
+        (jnp.zeros((1, 9, 16, 128), jnp.float32), 128, 4), (jnp.zeros((1, 9, 4, 128), jnp.bfloat16), 128, 4),
+        (jnp.zeros((1, 9, 16, 128), jnp.bfloat16), 16, 4), (jnp.zeros((1, 9, 16, 128), jnp.bfloat16), 128, 4),
+    ]:
+        q = qc.astype(plane.dtype)
+        with pytest.raises(ValueError, match="mla_chunk_attention cannot tile|against plane rows"):
+            mla_ops.mla_chunk_attention(q, plane, 0, bt, z + 1, z, z + 3, runs, heads=heads, rank=rank, scale=1.0)
 
 
 # (d) the router against a literal transcription; the held share of the expert layer
@@ -623,15 +798,18 @@ async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles()
 
 async def test_scheduler_with_the_step_kernel_serves_the_same_tokens_and_counts_its_pages(monkeypatch):
     """With the ONE place of choice answering "interpret" (the chip's answer
-    is "mosaic"; the interpreter is the CPU's way to run the same kernel) the
+    is "mosaic"; the interpreter is the CPU's way to run the same kernels) the
     scheduler serves the oracle's greedy tokens, and each step round's frame
     carries the pages the kernel fetched for the generating slots: ceil(keys
     / page size) each, and of those the ones in whole runs of consecutive
-    pages."""
+    pages. The prefill chunks ride the chunk's kernel under the same answer:
+    every chunk round's frame counts its prefilling rows into
+    ``chunk_rows_kernel`` and its rows' pages into ``mla_pages_read``."""
     from seldon_core_tpu.serving import decode_programs as dp
 
     monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
     monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
+    monkeypatch.setattr(mla_ops, "CHUNK_BLOCK_PAGES", 4)
     monkeypatch.setattr(dp, "_step_attn_kernel", lambda family, pool_state, mesh, heads, kv_heads: "interpret")
     ms = _zoo()
     fam = ms.generative["family"]
@@ -663,8 +841,17 @@ async def test_scheduler_with_the_step_kernel_serves_the_same_tokens_and_counts_
     # the first request had the fresh pool to itself: pages 1, 2, 3 .. in order, every whole group a run
     assert [f.mla_run_pages for f in alone] == [2 * (-(-f.mla_ctx_rows // PS) // 2) for f in alone]
     assert alone[0].to_dict()["mla_pages"] == [alone[0].mla_run_pages, alone[0].mla_pages_read]
-    chunked = [f for f in frames if f.busy_ns[0] > 0 and f.busy_ns[1] == 0]
-    assert chunked and not any(f.mla_pages_read for f in chunked)  # a chunk walks: nothing fetched by the kernel
+    assert not any(f.chunk_rows_kernel for f in steps if not f.chunk_rows)
+    chunked = [f for f in frames if f.chunk_rows]
+    assert chunked and [sched.programs.chunk_attn(c) for _rows, c in sched.chunk_buckets] == ["kernel"] * len(sched.chunk_buckets)
+    for f in chunked:
+        assert f.chunk_rows_kernel == f.chunk_rows_live > 0  # static a program: all of a dispatch's rows
+        assert f.to_dict()["chunk_rows_kernel"] == f.chunk_rows_kernel
+    assert all(f.mla_pages_read for f in chunked)  # the chunk's own fetches ride the same two counts
+    # a row's pages up to its last live query, counted once: the first request's chunk of 16 alone in its round
+    # sees 4 pages, all in runs; its chunk of 8 (6 pages) shares a round with the first step (25 keys: 7 pages)
+    assert (chunked[0].busy_ns[1], chunked[0].mla_pages_read, chunked[0].mla_run_pages) == (0, 4, 4)
+    assert (chunked[1].mla_pages_read, chunked[1].mla_ctx_rows) == (6 + 7, 24 + 25)
     await sched.close()
 
 

@@ -640,26 +640,119 @@ def _mla_cell(one, layers: int):
     return fam, params, pool
 
 
-@pytest.mark.parametrize("program", ["step", "chunk_2_64", "chunk_64_256"])
-def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program, monkeypatch):
-    """The fourth family's fused step (64 slots) and the chunk ladder's
-    (2, 64) and (64, 256) entries ((64, 64) is no entry of the ladder: rows
-    above the first climb at the top c only; (4, 256) is the (64, 256)
-    program at fewer rows and is left to a scratch script) at the a.x-k1
-    cell's widths, the dense layer + one expert layer, as the program set
-    builds them on a TPU (``_step_attn_kernel`` answers "mosaic" for the
-    cell's plane): the donated latent plane comes back aliased and no op
-    copies it (a 576-wide row made the chip's compiler lay the plane out
-    pages-minor and copy it twice a step: ``MLADecoderConfig.row_width``);
-    nothing the size of every slot's gathered table exists in float32.
-    The STEP reads the plane through ops/mla.py's kernel: one Mosaic call a
-    layer under ``attn/mla_core``, no gathered block and no ``while`` there.
-    The chunks walk whatever the choice: the 64-token chunk absorbs, the
-    256-token chunks expand block by block (or absorb a dispatch of short
-    live chunks); the grouped expert products of the 256-token chunks are
-    the Pallas kernel."""
+def _assert_chunk_reads_the_plane_through_the_kernel(text: str, n: int, c: int, heads: int, pages: int, layers: int):
+    """A latent chunk program as a TPU builds it since PR 45: ONE Mosaic call
+    a layer under ``attn/mla_core`` (``mla_chunk_attention``), the fold and
+    ``Wuv`` under ``attn/mla_absorb``; nothing walks and nothing branches on
+    the dispatch's live queries; no block of pages is gathered, no float32
+    score tensor ``[rows, heads, queries, keys]`` of any key block and no
+    expanded per-head ``kv`` block ``[rows, keys, heads, nope + v]`` exists."""
+    kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/attn/mla_core/" in ln]
+    assert len(kernels) == layers and all("mla_chunk_attention" in ln for ln in kernels)
+    assert re.search(r"/attn/mla_absorb/", text)
+    assert "/mla_core/while" not in text and "/attn/cond/" not in text and "/mla_expand/" not in text
+    from seldon_core_tpu.ops.mla import CHUNK_BLOCK_PAGES, block_pages
+
+    bp = block_pages(n, heads, c, 16, pages)  # what the walk of this entry gathered at a time
+    assert not re.findall(r"bf16\[%d,%d,16,640\]" % (n, bp), text)
+    keys = "(?:%d|%d)" % (bp * 16, CHUNK_BLOCK_PAGES * 16)  # of the walk's block, or of the kernel's
+    assert not re.findall(r"f32\[%d,%d,%d,%s\]" % (n, heads, c, keys), text)  # scores of a key block
+    assert not re.findall(r"bf16\[%d,%s,%d,256\]" % (n, keys, heads), text)  # a block's heads expanded
+
+
+@pytest.mark.parametrize("cell", ["a.x-k1", "xing4.0-29b-a4b"])
+@pytest.mark.parametrize("entry", [(2, 16), (2, 64), (2, 256), (4, 256)], ids=lambda e: "%d_%d" % e)
+def test_the_chunk_kernel_compiles_at_the_latent_cells_ladder_entries(topo, cell, entry):
+    """``mla_chunk_attention`` alone at the four chunk entries of the two
+    latent cells (64 heads over 532-entry tables of a 7-layer plane; 32 heads
+    over 144-entry tables of a 20-layer one): Mosaic takes the query blocks
+    (``CHUNK_Q_ROWS`` query-head rows, or the whole chunk where it is
+    shorter), the run DMAs and the scratch within its VMEM."""
+    from seldon_core_tpu.ops import mla as mla_ops
+
+    one = SingleDeviceSharding(topo.devices[0])
+    heads, pages, layers, n_pages = (64, 532, 7, 8192) if cell == "a.x-k1" else (32, 144, 20, 6144)
+    n, m = entry
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def attend(qc, plane, layer, bt, n_keys, q_first, counts):
+        runs = mla_ops.page_runs(bt, n_keys, 16)
+        return mla_ops.mla_chunk_attention(qc, plane, layer, bt, n_keys, q_first, counts, runs, heads=heads, rank=512, scale=0.1)
+
+    vec = arr((n,), jnp.int32)
+    compiled = jax.jit(attend).lower(
+        arr((n, m * heads, 640), jnp.bfloat16), arr((layers, n_pages, 16, 640), jnp.bfloat16), arr((), jnp.int32),
+        arr((n, pages), jnp.int32), vec, vec, vec,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "mla_chunk_attention" in text
+    assert not re.findall(r"bf16\[%d,%d,16,640\]" % (layers, n_pages) + r"\S* copy\(", text)  # the plane stays where it is
+    tq = mla_ops._query_block(m, heads)
+    assert tq * heads == min(mla_ops.CHUNK_Q_ROWS, m * heads) and mla_ops.kernel_takes("mosaic", m, 512, heads)
+
+
+def test_the_chunk_kernel_refuses_by_name_what_mosaic_cannot_tile():
+    """Before Mosaic sees it: a query block of 4 rows (257 queries, a prime,
+    of 4 heads) and a latent of 16 lanes raise ``kernel_tiles``' name, and
+    ``kernel_takes`` keeps such a program on the walk."""
+    from seldon_core_tpu.ops import mla as mla_ops
+
+    z = jnp.zeros((2,), jnp.int32)
+    plane = jnp.zeros((1, 9, 16, 128), jnp.bfloat16)
+    for m, rank in ((257, 128), (128, 16)):
+        assert not mla_ops.kernel_takes("mosaic", m, rank, 4)
+        with pytest.raises(ValueError, match="mla_chunk_attention cannot tile .* keeps the walk"):
+            mla_ops.mla_chunk_attention(jnp.zeros((2, m * 4, 128), jnp.bfloat16), plane, 0, jnp.zeros((2, 8), jnp.int32), z + 1, z,
+                                        z + 3, jnp.zeros((2, 4), jnp.int32), heads=4, rank=rank, scale=1.0)
+
+
+def test_the_latent_step_lowers_to_the_text_it_had_before_the_chunk_kernel(topo, monkeypatch):
+    """PR 45 gave the chunks a kernel and moved what the two kernels share
+    (a block's DMAs, a block of the online softmax) into functions of their
+    own: the STEP program at the a.x-k1 cell's widths still lowers to the
+    parent's text (193f7d8; the Mosaic body compared without its source
+    locations), so ``step_device_ms``, ``mla_decode_roofline`` and
+    ``step_roofline.mla`` read a program that did not change."""
+    import hashlib
+
     from seldon_core_tpu.ops import moe
-    from seldon_core_tpu.ops.mla import block_pages
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    fam, params, pool = _mla_cell(one, layers=2)
+    i32, f32, n = jnp.int32, jnp.float32, 64
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (arr((n, 532), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32), arr((), i32),
+            arr((n,), jnp.bool_))
+    step, _chunk = fam.fused_programs("mosaic")
+    text = _without_locations(jax.jit(step, donate_argnums=(1,)).lower(params, pool, *args).as_text())
+    text = re.sub(r"loc\(.*?\)\n|#loc.*\n", "", text)
+    assert text.count('"mosaic:') == 1 and 'kernel_name = "mla_decode_attention"' in text
+    assert hashlib.sha256(text.encode()).hexdigest() == "d9fb0f470fd2c1e233d86ef109c11335e2ccf0bbb8d03b3f2581202175fd02f9"
+
+
+@pytest.mark.parametrize("program", ["step", "chunk_2_16", "chunk_2_64", "chunk_2_256", "chunk_4_256"])
+def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program, monkeypatch):
+    """The fourth family's fused step (64 slots) and the chunk ladder's four
+    entries at the a.x-k1 cell's widths, the dense layer + one expert layer,
+    as the program set builds them on a TPU (``_step_attn_kernel`` answers
+    "mosaic" for the cell's plane): the donated latent plane comes back
+    aliased and no op copies it (a 576-wide row made the chip's compiler lay
+    the plane out pages-minor and copy it twice a step:
+    ``MLADecoderConfig.row_width``); nothing the size of every slot's
+    gathered table exists in float32. The STEP reads the plane through
+    ops/mla.py's step kernel: one Mosaic call a layer under
+    ``attn/mla_core``, no gathered block and no ``while`` there. Every CHUNK
+    reads it through the chunk's kernel (PR 45), absorbed whatever its
+    length: one Mosaic call a layer, no walk, no ``absorb_short`` branch, no
+    score tensor and no expanded block in HBM; the grouped expert products
+    of the 256-token chunks are the Pallas kernel."""
+    from seldon_core_tpu.ops import moe
     from seldon_core_tpu.serving.decode_programs import _step_attn_kernel
 
     monkeypatch.setattr(moe, "_on_tpu", lambda: True)
@@ -696,33 +789,32 @@ def test_latent_family_programs_compile_in_place_at_a_x_k1_widths(topo, program,
     want = (64, "row") if program == "step" else (n * (c // 16 + 1), "page")
     assert _pool_scatters(text, pool) == [want] * 2
     where = "step" if program == "step" else "chunk"
-    assert re.search(r'op_name="jit\(_fused_%s\)/attn/(?:cond/branch_\d_fun/)?mla_core/' % where, text)
-    # a 256-token program absorbs too, in the branch for a dispatch whose rows' live queries are few
-    assert re.search(r"/attn/(?:cond/branch_\d_fun/)?mla_absorb/", text)
+    assert re.search(r'op_name="jit\(_fused_%s\)/attn/mla_core/' % where, text)
+    assert re.search(r"/attn/mla_absorb/", text)
     kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/attn/mla_core/" in ln]
-    bp = block_pages(n, 64, c, 16, 532)  # the walk's block: gathered in the pool's dtype, never float32
-    gathered = re.findall(r"bf16\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
     if program == "step":
-        assert len(kernels) == 2  # a kernel call a layer: Mosaic, not the interpreter
+        assert len(kernels) == 2 and all("mla_decode_attention" in ln for ln in kernels)  # a call a layer: Mosaic
         # no block of pages is gathered, whatever its length, and nothing walks
-        assert not gathered and not re.findall(r"bf16\[%d,\d+,16,640\]" % n, text) and "/mla_core/while" not in text
+        assert not re.findall(r"bf16\[%d,\d+,16,640\]" % n, text) and "/mla_core/while" not in text
         assert text.count("tpu_custom_call") == 2  # 64 rows: the masked expert form, no grouped kernel
         return
-    assert not kernels  # a chunk's queries are many a row: the walk
-    assert gathered
-    assert not re.findall(r"f32\[%d,(?:%d,16|%d),640\]" % (n, bp, bp * 16), text)
-    assert ("/mla_core/while/body/mla_expand/" in text) == (c == 256)
-    assert (text.count("tpu_custom_call") >= 2) == (n * c > moe.MASKED_MAX_ROWS)  # gate_up and down, grouped
+    assert fam.chunk_attn(chosen, c) == "kernel"
+    _assert_chunk_reads_the_plane_through_the_kernel(text, n, c, 64, 532, layers=2)
+    assert (text.count("tpu_custom_call") >= 4) == (n * c > moe.MASKED_MAX_ROWS)  # + gate_up and down, grouped
 
 
-@pytest.mark.parametrize("sinkhorn", ["kernel", "plain"])
-@pytest.mark.parametrize("program", ["step", "chunk_4_256"])
+@pytest.mark.parametrize(
+    "program, sinkhorn",
+    [("step", "kernel"), ("step", "plain"), ("chunk_4_256", "kernel"), ("chunk_4_256", "plain"),
+     ("chunk_2_16", "kernel"), ("chunk_2_64", "kernel"), ("chunk_2_256", "kernel")],
+)
 def test_latent_family_with_four_streams_compiles_in_place_at_xing_widths(topo, program, sinkhorn, monkeypatch):
     """The fourth family with ``hc_mult`` 4 and the bias-selected gate at the
     xing4.0-29b-a4b cell's widths (hidden 3584 in four streams, 32 heads, a
     768-wide query rank, 8 of 64 experts of 1024 held, 6144 latent pages),
     the two dense layers + one expert layer: the step (its kernel at 32
-    heads, a call a layer) and the (4, 256) chunk entry. The donated plane
+    heads, a call a layer) and the chunk ladder's four entries (the chunk's
+    kernel at 32 heads, a call a layer: PR 45). The donated plane
     comes back aliased and uncopied; the stream maps' three scopes are in the
     compiled text under ``qkv``, ``attn_out`` and ``mlp``, the Sinkhorn
     iterations ONE Mosaic call a block as a TPU builds them (``mhc_sinkhorn``)
@@ -766,7 +858,8 @@ def test_latent_family_with_four_streams_compiles_in_place_at_xing_widths(topo, 
         args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
                 arr((), i32), arr((n,), jnp.bool_))
     else:
-        n, c, fn, where = 4, 256, chunk, "chunk"
+        n, c = (int(v) for v in program.split("_")[1:])
+        fn, where = chunk, "chunk"
         args = (arr((n, 144), i32), arr((n, c), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32),
                 arr((n,), i32), arr((), i32), arr((), i32))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, pool, *args).compile()
@@ -788,7 +881,12 @@ def test_latent_family_with_four_streams_compiles_in_place_at_xing_widths(topo, 
     # never a float32 copy of all four, in either order of the axes
     assert not re.findall(r"f32\[4,%d,%d,3584\]|f32\[%d,%d,4,3584\]" % (n, c, n, c), text)
     kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln and "/attn/mla_core/" in ln]
-    assert len(kernels) == (3 if program == "step" else 0)  # a kernel call a layer, at 32 heads; a chunk walks
+    assert len(kernels) == 3  # a kernel call a layer, at 32 heads: the step's, or the chunk's
+    if program == "step":
+        assert all("mla_decode_attention" in ln for ln in kernels)
+    else:
+        assert fam.chunk_attn(chosen, c) == "kernel"
+        _assert_chunk_reads_the_plane_through_the_kernel(text, n, c, 32, 144, layers=3)
 
 
 @pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_2_64"])

@@ -386,11 +386,13 @@ def test_a_dispatch_says_which_it_was(recorded, config):
     frames = {f.seq: f for f in s.flight.snapshot()}
     for e in dispatches:
         fam, kw = e["name"].rsplit(".", 1)[1], e["kw"]
-        assert all(isinstance(v, int) for k, v in kw.items() if k != "write")
+        assert all(isinstance(v, int) for k, v in kw.items() if k not in ("write", "attn"))
         frame = frames[kw["round"]]
         assert frame.busy_ns[FAMILIES.index(fam)] > 0  # the frame that round committed holds the dispatch
         if fam == "chunk":
-            assert set(kw) == {"seq", "round", "rows", "c", "live", "write"}
+            assert set(kw) == {"seq", "round", "rows", "c", "live", "write", "attn"}
+            # how its program's attention read the pool: this family has no chunk kernel, and the frame counts none
+            assert kw["attn"] == s.programs.chunk_attn(kw["c"]) == "walk" and frame.chunk_rows_kernel == 0
             # the form its program's pool write took, by the comparison the program makes
             assert kw["write"] == ("page" if kw["c"] >= s.pool.page_size else "row")
             assert (kw["rows"], kw["c"]) in s.chunk_buckets and 1 <= kw["live"] <= kw["rows"] <= s.chunk_rows_cap
