@@ -5,7 +5,7 @@ TEST_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 IMAGE ?= seldon-core-tpu/platform:latest
 
-.PHONY: lint test test-fast bench chip-smoke dryrun protos native install-bundle image release clean profile-smoke distill-smoke replica-smoke chaos-smoke kvtier-smoke
+.PHONY: lint test test-fast chip-smoke dryrun protos native install-bundle image release clean profile-smoke distill-smoke replica-smoke chaos-smoke kvtier-smoke
 
 lint:  ## invariant linter (trace-safety / commit-point / registry-drift / phase-registry / ladder)
 	$(PY) -m seldon_core_tpu.tools.lint
@@ -30,9 +30,6 @@ distill-smoke:  ## tiny feature-draft distillation through the CLI (the pytest s
 
 test-fast: lint  ## skip the slow model/parallel tests
 	$(PY) -m pytest tests/ -q -x --ignore=tests/test_models_heavy.py --ignore=tests/test_parallel.py
-
-bench:  ## one-line JSON benchmark on the attached accelerator (fails without one)
-	$(PY) bench.py
 
 chip-smoke:  ## both serving tiers over real HTTP on the attached chip, each checked against an independent forward (fails without one; --chips 4 for the cross-chip paths)
 	$(PY) chip_smoke.py

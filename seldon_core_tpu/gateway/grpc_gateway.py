@@ -8,25 +8,22 @@ per-deployment ManagedChannel cache (:114-132, 197-203); the in-process
 backend makes that a dict lookup, and the channel-cache behavior survives in
 RemoteBackend's pooled session.
 
-Two server modes (VERDICT r4 Next #2 — the gRPC ingress ran at 28% of the
-REST fast ingress; the full floor analysis with every number below lives in
-docs/reference/external-api.md §"gRPC ingress floor"):
+Two server modes (the floor analysis is in docs/reference/external-api.md
+§"gRPC ingress floor"; rates on the current host: not measured):
 
-- ``aio`` (default): pure grpc.aio — everything on the event loop.
-  Measured on the 1-core bench host: a zero-logic echo tops out at
-  ~3.4k RPC/s (~19 asyncio callback dispatches per unary call under
-  cProfile) — already BELOW the ~5.1k req/s the complete REST fast-ingress
-  path sustains on the same core. The gateway logic itself adds only
-  ~92 us CPU per RPC (auth 9 + proto decode 57 + encode 25).
+- ``aio`` (default): pure grpc.aio — everything on the event loop. A
+  zero-logic echo already costs ~19 asyncio callback dispatches per unary
+  call under cProfile, where the complete REST fast-ingress path turns a
+  request around in ~2: the Python gRPC stack is the floor, not the
+  gateway's auth + proto decode + encode.
 - ``sync``: the C-core ``grpc.server`` with a small thread pool; HTTP/2
   framing, flow control, and proto parse run in C threads, and each RPC
   bridges ONCE into the asyncio loop (run_coroutine_threadsafe) where
   auth -> codec -> backend -> audit stay loop-confined exactly as in the
-  REST path. Echo measures ~5.1k RPC/s (+48%) — but on a single shared
-  core the thread<->loop bridge hop erases the win for the loop-confined
-  batcher (full path measured 3.5k vs aio's 5.8k preds/s), so aio stays
-  the default there. On multi-core hosts the C threads run beside the
-  loop and ``mode='sync'`` is the right pick.
+  REST path. On a single shared core the thread<->loop bridge hop costs
+  more than C saves for the loop-confined batcher, so aio stays the
+  default there. On multi-core hosts the C threads run beside the loop
+  and ``mode='sync'`` is the right pick.
 """
 
 from __future__ import annotations

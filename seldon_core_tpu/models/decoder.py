@@ -274,14 +274,15 @@ def _embed_one(params, tok: jax.Array, pos) -> jax.Array:
 # program into the three pieces iteration-level scheduling needs:
 #   prefill()      one causal pass over a prompt -> per-sequence K/V + the
 #                  last-position logits (the first generated token's logits)
-#   init_slot_cache / write_prefill  a STATIC [L, n_slots, h, max_ctx, hd]
-#                  cache, sequences scattered into slots
+#   init_slot_cache  a STATIC [L, n_slots, h, max_ctx, hd] cache (the
+#                  draft's; the target's K/V lives in pages, below)
 #   decode_step()  one token for EVERY slot at per-slot positions — batch
 #                  composition changes between steps without shape changes
 #   sample_tokens  per-slot temperature/top-k sampling, greedy at temp<=0
-#   draft_propose / verify_step / speculative_accept
+#   draft_propose / speculative_accept
 #                  draft-model speculation: k proposed tokens per slot and
-#                  their one-dispatch verification against the same cache
+#                  the acceptance rule of their one-dispatch verification
+#                  (paged_verify_step)
 # All shapes are static in (n_slots, max_ctx), so one XLA program per
 # function serves every batch composition (zero recompiles after warmup).
 
@@ -344,17 +345,6 @@ def init_slot_cache(
     d = decoder_dims(params)
     shape = (d["layers"], n_slots, d["heads"], max_ctx, d["head_dim"])
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-
-
-def write_prefill(
-    cache_k: jax.Array, cache_v: jax.Array, k: jax.Array, v: jax.Array, slot
-) -> tuple[jax.Array, jax.Array]:
-    """Scatter one prefilled sequence's K/V (k[L, 1, h, s, hd]) into ``slot``
-    positions 0..s-1 via lax.dynamic_update_slice. Jitted by the scheduler
-    with cache donation, so the update is in-place in HBM."""
-    cache_k = lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0, 0))
-    cache_v = lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0, 0))
-    return cache_k, cache_v
 
 
 def _layer_step_slots(p, x, cache_k, cache_v, positions, h, counts=None, starts=None):
@@ -530,101 +520,13 @@ def sample_tokens(
     return lax.cond(jnp.any(sampling), draw, lambda: greedy)
 
 
-# ----------------------------------------------------- speculative decoding
-# Draft-model speculation (Leviathan et al.; Chen et al.): a cheap draft
-# decoder proposes k tokens per slot in ONE dispatch, the target model
-# scores all k+1 queries against the same slot cache in ONE widened
-# dispatch, and the longest valid prefix is accepted — amortizing the
-# per-dispatch cost over several emitted tokens. Speculative cache writes
-# need no rollback copy: positions only advance by the ACCEPTED length, so
-# rejected entries sit beyond every later attention mask until the next
-# consumed token overwrites them.
-
-
-def verify_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    positions: jax.Array,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """decode_step widened to m queries per slot: consume tokens[n, m]
-    (the last emitted token + the m-1 draft proposals) with slot i's query
-    j at positions[i] + j, return (logits[n, m, vocab], cache_k, cache_v)
-    with every query's K/V written at its own position.
-
-    logits[i, j] is the target's next-token distribution AFTER consuming
-    query j — exactly what j sequential decode_step calls would produce
-    for the same prefix, which is what makes greedy acceptance bit-exact.
-    Junk queries (beyond a slot's accept limit, or free slots) may index
-    the position table out of range; the lookup clips and their logits are
-    never used."""
-    heads = _heads(params)
-    m = tokens.shape[1]
-    max_len = params["pos_emb"].shape[0]
-    x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
-    x = x + jnp.asarray(params["pos_emb"])[pidx]
-    new_k, new_v = [], []
-    for li, lp in enumerate(params["layers"]):
-        x, ck, cv = _layer_step_slots(lp, x, cache_k[li], cache_v[li], positions, heads)
-        new_k.append(ck)
-        new_v.append(cv)
-    logits = _logits(params, x)  # [n, m, vocab]
-    return logits, jnp.stack(new_k), jnp.stack(new_v)
-
-
-def chunk_prefill(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    positions: jax.Array,
-    counts: jax.Array,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One prefill CHUNK for every slot: consume tokens[n, c] with slot
-    i's token j at positions[i] + j, persisting only the first counts[i]
-    K/V entries per slot (counts-0 slots — generating, free — ride the
-    static-shape dispatch without touching their cache). Returns
-    (logits[n, c, vocab], cache_k, cache_v); logits[i, counts[i] - 1] is
-    the next-token distribution after slot i's last consumed token — the
-    first generated token's logits when the chunk completes a prompt.
-
-    This is the incremental prefill building block behind both prefix
-    reuse (only the suffix a cached prefix doesn't cover is computed) and
-    Sarathi-style chunked prefill (a long prompt spreads over several
-    scheduler rounds interleaved with decode steps). Same per-position
-    K/V math as verify_step/_layer_step_slots: each query attends to
-    cache entries <= its own position through the in-block causal mask,
-    so a prompt prefilled in ANY chunk partition yields the same K/V as
-    one computed in a single pass over the same cache layout."""
-    heads = _heads(params)
-    m = tokens.shape[1]
-    max_len = params["pos_emb"].shape[0]
-    x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    # junk queries (beyond a slot's count) may index past the position
-    # table; clip like verify_step — their logits are never used and
-    # their K/V writes are masked off
-    pidx = jnp.clip(positions[:, None] + jnp.arange(m)[None, :], 0, max_len - 1)
-    x = x + jnp.asarray(params["pos_emb"])[pidx]
-    new_k, new_v = [], []
-    for li, lp in enumerate(params["layers"]):
-        x, ck, cv = _layer_step_slots(
-            lp, x, cache_k[li], cache_v[li], positions, heads, counts=counts
-        )
-        new_k.append(ck)
-        new_v.append(cv)
-    logits = _logits(params, x)  # [n, m, vocab]
-    return logits, jnp.stack(new_k), jnp.stack(new_v)
-
-
 # ------------------------------------------------------------- paged KV
 # Block-table KV memory (serving/kv_pool.py owns the allocator): instead of
 # one contiguous [L, n_slots, h, max_ctx, hd] row per slot, K/V lives in a
 # shared page pool of TOKEN ROWS [L, n_pages, page_size, h*hd] and each slot
 # carries a static-shape block table [max_pages] of physical page ids. The
-# attention building blocks below mirror decode_step / verify_step /
-# chunk_prefill exactly — same masks, same einsums, same f32 accumulation —
+# attention building blocks below mirror the flat decode_step exactly —
+# same masks, same einsums, same f32 accumulation, one query a slot or many —
 # but read the cache through a pool gather and write through a per-token
 # (layer, page, row) scatter, so two slots sharing a system prompt REFERENCE
 # the same pages (vLLM's PagedAttention memory model) instead of each
@@ -1021,18 +923,23 @@ def paged_decode_step(params, pool, bt, tokens, positions, attn_kernel=""):
 
 
 def paged_verify_step(params, pool, bt, tokens, positions):
-    """verify_step over the page pool: m queries per slot, logits[i, j]
-    scored AFTER consuming query j — the widened speculative verify.
+    """The widened speculative verify: consume tokens[n, m] (the last
+    emitted token + the m-1 draft proposals) with slot i's query j at
+    positions[i] + j; logits[i, j] is the target's next-token distribution
+    AFTER consuming query j — what j sequential paged_decode_step calls
+    give for the same prefix, which is what makes greedy acceptance exact.
     Returns (logits, hidden[n, m, d], pool)."""
     return _paged_forward(params, pool, bt, tokens, positions)
 
 
 def paged_chunk_prefill(params, pool, bt, tokens, positions, counts):
-    """chunk_prefill over the page pool: row i is one slot's chunk, ``bt[i]``
-    that slot's block-table row; persist only the first counts[i] K/V
-    entries per row (a counts-0 row — padding — has its writes
-    junk-redirected, touching no live page). Returns (logits,
-    hidden[rows, c, d], pool)."""
+    """One prefill CHUNK a row: row i is one slot's chunk (token j at
+    positions[i] + j), ``bt[i]`` that slot's block-table row; persist only
+    the first counts[i] K/V entries per row (a counts-0 row — padding — has
+    its writes junk-redirected, touching no live page). A prompt prefilled
+    in ANY chunk partition yields the same K/V as one pass. Returns (logits,
+    hidden[rows, c, d], pool); logits[i, counts[i] - 1] is the next-token
+    distribution after row i's last consumed token."""
     return _paged_forward(params, pool, bt, tokens, positions, counts)
 
 
@@ -1265,6 +1172,17 @@ def decoder_family(family=None):
     """The family a model's spec names (``ModelSpec.generative["family"]``);
     the GPT-2 family where it names none — THE default."""
     return family if family is not None else gpt2_family
+
+
+# ----------------------------------------------------- speculative decoding
+# Draft-model speculation (Leviathan et al.; Chen et al.): a cheap draft
+# decoder proposes k tokens per slot in ONE dispatch, the target model
+# scores all k+1 queries against its pages in ONE widened dispatch
+# (paged_verify_step), and the longest valid prefix is accepted — amortizing
+# the per-dispatch cost over several emitted tokens. Speculative cache
+# writes need no rollback copy: positions only advance by the ACCEPTED length, so
+# rejected entries sit beyond every later attention mask until the next
+# consumed token overwrites them.
 
 
 def draft_propose(

@@ -57,8 +57,8 @@ FLASH_MIN_SEQ = 1024
 # seq length from which an accelerator backend routes to the hand-tiled
 # Pallas kernel (ops/pallas_flash) instead of this pure-JAX blockwise path:
 # blockwise's per-step [.., sq, block] score tensors go through HBM while
-# the kernel keeps its working set in VMEM. The crossover on the current
-# chip is not measured (bench.py pallas_long_seq is the leg).
+# the kernel keeps its working set in VMEM. The crossover is not measured
+# on the chip; the kernel is in no cell of the benchmark (PERF.md section 3).
 PALLAS_MIN_SEQ = 4096
 
 

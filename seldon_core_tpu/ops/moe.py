@@ -124,13 +124,14 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 # no sort; its rows x held FLOPs reach the weights' read time at 240 rows
 # (the chip's 197 TFLOP/s over 819 GB/s), which is ``MASKED_MAX_ROWS`` again.
 # Above it only a sixteenth of the T*k assignments are held, but a static
-# shape must take them all: the grouped form goes in blocks of
-# ``GROUPED_BLOCK_ROWS`` rows there (``moe_held_ffn``), because its sorted
-# copy, [T*k, hidden], of a (64, 256) chunk round would be 1.9 GB and its
-# float32 result twice that. Measured on a v5e, one layer's routed part
-# alone on the host's clock (my chip run, PR 37; masked / grouped, ms): 64
-# rows 1.475 / 1.563, 128 rows 1.465 / 1.631, 256 rows 1.659 / 1.962, 512
-# rows 3.087 / 2.770, 1024 rows 6.128 / 4.415: the step's 64 rows and the
+# shape must take them all: the grouped form takes ``GROUPED_BLOCK_ROWS``
+# rows at most (``moe_held_ffn`` refuses more), because its sorted copy,
+# [T*k, hidden], of a (64, 256) chunk round would be 1.9 GB and its float32
+# result twice that; the chunk ladder's widest entry is (4, 256). Measured
+# on a v5e, one layer's routed part alone on the host's clock (my chip run,
+# PR 37; masked / grouped, ms): 64 rows 1.475 / 1.563, 128 rows 1.465 /
+# 1.631, 256 rows 1.659 / 1.962, 512 rows 3.087 / 2.770, 1024 rows
+# 6.128 / 4.415: the step's 64 rows and the
 # (2, 64) chunk's 128 take the masked form, the 256-token chunks' 512 rows
 # and more the grouped one, and the threshold does not differ from mellum's.
 # In the fused step the six expert layers read 8.56 ms (1.43 a layer,
@@ -157,9 +158,9 @@ SCOPE_MOE_COMBINE = "moe_combine"  # un-sort, gate-weighted sum, counters
 # matches, the masked form can go). From 1024 rows the masked form's
 # rows x experts FLOPs bind (4 ms a layer).
 MASKED_MAX_ROWS = 256
-# rows of one grouped call of a layer that holds a SHARE of its experts
-# (``moe_held_ffn``): every block reads the held experts' weights again, and
-# holds [rows * k, hidden] sorted rows and their float32 products
+# the most rows of one dispatch of a layer that holds a SHARE of its experts
+# (``moe_held_ffn``): the grouped call holds [rows * k, hidden] sorted rows
+# and their float32 products
 GROUPED_BLOCK_ROWS = 4096
 SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token takes, beside the routed ones
 SCOPE_DENSE_MLP = "dense"  # a leading dense layer's gated MLP
@@ -345,6 +346,11 @@ def moe_held_ffn(
     counters[4] int32: real rows, held experts with a row, the fullest held
     expert's rows, picks of real rows that landed on a held expert)."""
     t = x.shape[0]
+    if t > GROUPED_BLOCK_ROWS:
+        raise ValueError(
+            f"{t} rows in one dispatch of a held-expert layer, above {GROUPED_BLOCK_ROWS}: the chunk ladder ends at "
+            "four rows of tpu.decode_prefill_chunk tokens, so cap the chunk"
+        )
     if valid is None:
         valid = jnp.ones((t,), bool)
     held = p["gate_up"].shape[0]
@@ -353,15 +359,7 @@ def moe_held_ffn(
         local = jnp.sum(here & valid[:, None], dtype=jnp.int32)
     if t <= MASKED_MAX_ROWS:
         y, cnt = moe_experts_masked(p, x, gates, experts, valid)
-    elif t <= GROUPED_BLOCK_ROWS:
-        y, cnt = moe_experts_grouped(p, x, gates, experts, valid)
     else:
-        if t % GROUPED_BLOCK_ROWS:
-            raise ValueError(f"{t} rows are not whole blocks of {GROUPED_BLOCK_ROWS}")
-        blocks = [a.reshape(t // GROUPED_BLOCK_ROWS, GROUPED_BLOCK_ROWS, *a.shape[1:]) for a in (x, gates, experts, valid)]
-        y = jax.lax.map(lambda a: moe_experts_grouped(p, *a)[0], tuple(blocks)).reshape(t, -1)
-        with jax.named_scope(SCOPE_MOE_COMBINE):  # the blocks' loads, counted over all of them
-            hot = (experts[:, :, None] == jnp.arange(held, dtype=jnp.int32)[None, None, :]) & valid[:, None, None]
-            cnt = _load_counters(jnp.sum(hot, axis=(0, 1), dtype=jnp.int32), jnp.sum(valid))
+        y, cnt = moe_experts_grouped(p, x, gates, experts, valid)
     with jax.named_scope(SCOPE_MOE_COMBINE):
         return y, jnp.concatenate([cnt, local[None]])

@@ -19,11 +19,11 @@ the CPU backend only, and only where a caller passes ``interpret=True``
 (the tests, and the serving policies' explicit CPU branch): a kernel call
 that reaches an accelerator is compiled or it raises.
 
-Speed vs the pure-JAX blockwise path on the current chip: not measured
-(bench.py pallas_long_seq is the leg). The block defaults (block_q 512 /
-block_k 2048) and the PALLAS_MIN_SEQ routing threshold in ops/attention.py
-are carried-over design choices until that leg has run. models/bert.py
-routes long sequences here off the CPU backend.
+Speed vs the pure-JAX blockwise path: not measured on the chip, and the
+kernel is in no cell of the benchmark (PERF.md section 3). The block
+defaults (block_q 512 / block_k 2048) and the PALLAS_MIN_SEQ routing
+threshold in ops/attention.py are carried-over design choices with no
+measurement behind them. models/bert.py routes long sequences here off the CPU backend.
 """
 
 from __future__ import annotations

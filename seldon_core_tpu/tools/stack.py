@@ -1,7 +1,7 @@
 """The measured serving stack, built ONE way.
 
-bench.py's legs, the soak harness, and any future measurement tool must
-all boot the exact stack the product boots (warmed PredictorServer behind
+The soak harness and any other tool that measures the graph tier must
+boot the exact stack the product boots (warmed PredictorServer behind
 the OAuth gateway + in-process backend, serving GC policy applied) — a
 second hand-rolled copy is how a tool silently stops measuring what the
 platform runs. This is that single definition.

@@ -35,8 +35,7 @@ The report prints the greedy accept-rate proxy (draft/target argmax
 agreement along target-greedy trajectories — exactly the per-position
 acceptance probability of the chain/tree walk; the feature variant runs
 the head on the TRUE teacher features, the serving root's conditioning)
-before and after, plus the KL trajectory; the measured deltas for the
-stock bench pairs are recorded in PARITY.md.
+before and after, plus the KL trajectory.
 """
 
 from __future__ import annotations
@@ -372,8 +371,8 @@ def main(argv=None) -> None:
         "--self-cond", type=float, default=0.0,
         help="weight of a self-conditioned second pass (features mode) — "
         "scheduled sampling in feature space. Ships DISABLED: on the "
-        "bench pair it traded away depth-1 accuracy for less deep-drift "
-        "than the noise augmentation already buys (PARITY r16)",
+        "tiny test pair it traded away depth-1 accuracy for less "
+        "deep-drift than the noise augmentation already buys",
     )
     ap.add_argument(
         "--draft-ffn", type=int, default=0,

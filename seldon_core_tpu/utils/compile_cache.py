@@ -6,8 +6,8 @@ The cache directory is part of the cache key, so it must never move
 between runs: it is wherever ``JAX_COMPILATION_CACHE_DIR`` says, and
 otherwise ONE fixed directory inside the checkout — no temp name, pid or
 timestamp. Called by the process mains (serving/server.py, platform.py,
-serving/microservice.py, tools/soak.py, bench.py, chip_smoke.py); library
-code and tests never touch it.
+serving/microservice.py, tools/soak.py, benchmarks/run.py, chip_smoke.py);
+library code and tests never touch it.
 """
 
 from __future__ import annotations

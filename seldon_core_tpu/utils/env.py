@@ -63,10 +63,10 @@ ENGINE_TRACE_SAMPLE_RATE = "ENGINE_TRACE_SAMPLE_RATE"  # default 0.05
 ENGINE_OTLP_FILE = "ENGINE_OTLP_FILE"  # path; unset = no export
 ENGINE_ACCESS_LOG = "ENGINE_ACCESS_LOG"  # "json" enables; default off
 # decode-loop flight recorder (telemetry/flight.py reads these): per-round
-# ring buffer kill switch + capacity. On by default — the measured append
-# cost is single-digit µs/round (PARITY.md "Flight recorder overhead").
+# ring buffer kill switch + capacity. On by default — one O(1) append a
+# round (PARITY.md "Instrumentation overhead"; on the chip: PERF.md, PR 26).
 ENGINE_FLIGHT = "ENGINE_FLIGHT"  # "off" disables the recorder
-ENGINE_FLIGHT_FRAMES = "ENGINE_FLIGHT_FRAMES"  # ring capacity, default 2048
+ENGINE_FLIGHT_FRAMES = "ENGINE_FLIGHT_FRAMES"  # ring capacity, default 8192
 # "on" forces per-dispatch completion (block_until_ready after every fused
 # program) so each family's flight column is ground-truth device wall —
 # calibration runs only; default off (async dispatch stays pipelined)

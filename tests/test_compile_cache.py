@@ -87,7 +87,7 @@ def test_every_process_main_places_the_cache():
         "seldon_core_tpu/platform.py",
         "seldon_core_tpu/serving/microservice.py",
         "seldon_core_tpu/tools/soak.py",
-        "bench.py",
+        "benchmarks/run.py",
         "chip_smoke.py",
     ):
         with open(os.path.join(REPO, rel)) as f:

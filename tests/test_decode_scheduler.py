@@ -47,36 +47,50 @@ def _oracle(params, ids, max_new=MAX_NEW) -> np.ndarray:
 
 
 def test_decoder_slot_blocks_match_oracle():
-    """The raw building blocks (models/decoder.py): prefill -> write into
-    an arbitrary slot -> per-slot decode_step reproduces the fused oracle
-    for a sequence parked in slot 2 of a 4-slot cache, greedy-sampled via
-    sample_tokens."""
+    """The raw building blocks (models/decoder.py): a prompt chunk-prefilled
+    into the pages of slot 2 of 4, then per-slot paged_decode_step,
+    greedy-sampled via sample_tokens, reproduces the fused oracle; so does
+    the flat decode_step the draft's cache runs, fed the same sequence a
+    token at a time."""
     import jax
     from seldon_core_tpu.models.decoder import (
-        decode_step, init_slot_cache, prefill, sample_tokens, write_prefill,
+        decode_step, init_slot_cache, paged_chunk_prefill, paged_decode_step, paged_kv_init, sample_tokens,
     )
 
     params = _params()
     ids = _prompts(1, seed=6)
     oracle = _oracle(params, ids)[0]
-    slot, n_slots = 2, 4
-    ck, cv = init_slot_cache(params, n_slots, SEQ + MAX_NEW)
-    logits, k, v = prefill(params, jnp.asarray(ids))
-    ck, cv = write_prefill(ck, cv, k, v, slot)
+    slot, n_slots, ps = 2, 4, 4
+    pps = -(-(SEQ + MAX_NEW) // ps)
+    pool = paged_kv_init(params, 1 + n_slots * pps, ps)
+    bt = np.zeros((n_slots, pps), np.int32)
+    bt[slot] = np.arange(1 + slot * pps, 1 + (slot + 1) * pps)
+    bt = jnp.asarray(bt)
+    toks = np.zeros((n_slots, SEQ), np.int32)
+    toks[slot] = ids[0]
+    counts = np.zeros(n_slots, np.int32)
+    counts[slot] = SEQ
+    logits, _, pool = paged_chunk_prefill(
+        params, pool, bt, jnp.asarray(toks), jnp.zeros(n_slots, jnp.int32), jnp.asarray(counts)
+    )
     greedy_t = jnp.zeros(n_slots)
     greedy_k = jnp.zeros(n_slots, jnp.int32)
-    tok = int(
-        sample_tokens(logits, greedy_t[:1], greedy_k[:1], jax.random.key(0))[0]
-    )
-    got = [tok]
-    toks = np.zeros(n_slots, np.int32)
+    got = [int(sample_tokens(logits[:, SEQ - 1], greedy_t, greedy_k, jax.random.key(0))[slot])]
+    tok1 = np.zeros(n_slots, np.int32)
     pos = np.zeros(n_slots, np.int32)
     for i in range(MAX_NEW - 1):
-        toks[slot] = got[-1]
-        pos[slot] = SEQ + i
-        logits, ck, cv = decode_step(params, ck, cv, jnp.asarray(toks), jnp.asarray(pos))
+        tok1[slot], pos[slot] = got[-1], SEQ + i
+        logits, _, pool = paged_decode_step(params, pool, bt, jnp.asarray(tok1), jnp.asarray(pos))
         got.append(int(sample_tokens(logits, greedy_t, greedy_k, jax.random.key(i))[slot]))
     np.testing.assert_array_equal(got, oracle[SEQ:])
+    # the flat cache: every consumed token of the oracle's row through decode_step
+    ck, cv = init_slot_cache(params, n_slots, SEQ + MAX_NEW)
+    flat = []
+    for i, t in enumerate(oracle[:-1]):
+        tok1[slot], pos[slot] = t, i
+        logits, ck, cv = decode_step(params, ck, cv, jnp.asarray(tok1), jnp.asarray(pos))
+        flat.append(int(np.argmax(np.asarray(logits)[slot])))
+    np.testing.assert_array_equal(flat[SEQ - 1 :], oracle[SEQ:])
 
 
 async def test_matches_oracle_with_midstream_admission():
@@ -968,6 +982,48 @@ def test_chunk_plan_of_a_family_without_state_rows_is_the_cap_alone(hint, cap, w
         got.append(sched._next_chunk(seq, pos))
         pos += got[-1]
     assert got == want and sched._hint_boundary(seq) == 0
+
+
+async def test_chunked_admission_reuses_fewer_pages_than_monolithic_on_a_fixed_schedule():
+    """ROADMAP S9, as a count: a prefix is captured when its prefill
+    COMPLETES, so a sharer admitted while the first holder still prefills
+    misses it. One schedule through both admissions: a runner whose tokens
+    are the clock (one a round), and at each of its first six tokens one
+    request of six that share 24 of 32 prompt tokens (three pages of 8).
+    Monolithic admission prefills the first holder in the round after it
+    arrives: the five behind it reuse three pages each. Chunks of 8 take
+    four rounds over the same prompt: the three that arrive meanwhile miss,
+    two hit. Counts of the CPU backend, not a speed."""
+    n, seq, shared = 6, 32, 24
+    rng = np.random.default_rng(7)
+    ids = rng.integers(0, VOCAB, (n + 1, seq)).astype(np.int32)
+    ids[1:, :shared] = ids[1, :shared]  # row 0 is the runner's: it shares nothing
+
+    async def reused(chunk):
+        sched = DecodeScheduler(
+            _params(), seq_len=seq, max_new_tokens=MAX_NEW, n_slots=n + 1,
+            prefix_slots=4, prefill_chunk=chunk, kv_page_size=8,
+        )
+        sched.warmup()
+        sharers = []
+
+        def clock(tok, idx):
+            if 1 <= idx <= n:
+                sharers.append(asyncio.ensure_future(
+                    sched.submit(ids[idx], max_new_tokens=2, cache_prefix=shared)
+                ))
+
+        await sched.submit(ids[0], on_token=clock)
+        await asyncio.gather(*sharers)
+        counts = (sched.stat_prefix_hits, sched.pool.alloc.stat_pages_shared, sched.stat_prefix_tokens_saved)
+        assert sched.stat_prefix_hits + sched.stat_prefix_misses == n + 1
+        await sched.close()
+        return counts
+
+    mono, chunked = await reused(0), await reused(8)
+    assert mono == (5, 15, 5 * shared)
+    assert chunked == (2, 6, 2 * shared)
+    assert chunked[1] < mono[1]
 
 
 @pytest.mark.slow

@@ -123,49 +123,54 @@ def test_tiny_gpt_overflowing_config_rejected_at_build():
 # ----------------------------------------------------- speculative blocks
 
 
-def test_verify_step_matches_sequential_decode_steps():
-    """The widened verify program is the k+1-query generalization of
-    decode_step: given the same consumed tokens, its per-position logits
-    (and argmax chain) equal k+1 sequential single-token steps over the
-    same slot cache."""
+def test_paged_verify_matches_sequential_steps_and_the_plain_forward():
+    """The widened verify program is the k+1-query generalization of the
+    step: given the same consumed tokens, its per-position logits equal
+    k+1 sequential single-token steps over the same pages, both equal the
+    teacher-forced forward (``sequence_logits``), and its argmax chain is
+    ``generate``'s."""
     from seldon_core_tpu.models.decoder import (
-        decode_step, init_slot_cache, prefill, verify_step, write_prefill,
+        paged_chunk_prefill, paged_decode_step, paged_kv_init, paged_verify_step, sequence_logits,
     )
 
     params = init_decoder(seed=3, vocab=256, hidden=64, layers=2, ffn=128, max_len=64)
     ids = _prompt(b=1, s=8)
-    slot, n_slots, k = 1, 3, 3
-    ck, cv = init_slot_cache(params, n_slots, 32)
-    logits, kk, vv = prefill(params, jnp.asarray(ids))
-    ck, cv = write_prefill(ck, cv, kk, vv, slot)
-    first = int(np.argmax(np.asarray(logits)[0]))
-    # sequential chain: consume first + its greedy successors one at a time
-    toks = np.zeros(n_slots, np.int32)
+    slot, n_slots, k, ps, pps = 1, 3, 3, 4, 8
+    chain = [int(t) for t in np.asarray(generate(params, jnp.asarray(ids), k + 2))[0, 8:]]
+    oracle = np.asarray(sequence_logits(params, jnp.asarray([list(ids[0]) + chain[: k + 1]])))[0, 8:]
+    pool = paged_kv_init(params, 1 + n_slots * pps, ps)
+    bt = np.zeros((n_slots, pps), np.int32)  # the free slots write to the junk page
+    bt[slot] = own = np.arange(1 + slot * pps, 1 + (slot + 1) * pps)
+    bt = jnp.asarray(bt)
+    toks = np.zeros((n_slots, 8), np.int32)
+    toks[slot] = ids[0]
+    counts = np.zeros(n_slots, np.int32)
+    counts[slot] = 8
+    logits, _, pool = paged_chunk_prefill(
+        params, pool, bt, jnp.asarray(toks), jnp.zeros(n_slots, jnp.int32), jnp.asarray(counts)
+    )
+    assert int(np.argmax(np.asarray(logits)[slot, 7])) == chain[0]
+    # sequential chain: consume the first token + its greedy successors one at a time
+    tok1 = np.zeros(n_slots, np.int32)
     pos = np.zeros(n_slots, np.int32)
-    chain = [first]
     seq_logits = []
-    sck, scv = ck, cv
+    spool = pool
     for j in range(k + 1):
-        toks[slot] = chain[-1]
-        pos[slot] = 8 + j
-        lg, sck, scv = decode_step(params, sck, scv, jnp.asarray(toks), jnp.asarray(pos))
+        tok1[slot], pos[slot] = chain[j], 8 + j
+        lg, _, spool = paged_decode_step(params, spool, bt, jnp.asarray(tok1), jnp.asarray(pos))
         seq_logits.append(np.asarray(lg)[slot])
-        chain.append(int(np.argmax(np.asarray(lg)[slot])))
-    # widened: same k+1 consumed tokens in ONE call
+    # widened: the same k+1 consumed tokens in ONE call
     queries = np.zeros((n_slots, k + 1), np.int32)
     queries[slot] = chain[: k + 1]
-    positions = np.zeros(n_slots, np.int32)
-    positions[slot] = 8
-    wlg, wck, wcv = verify_step(params, ck, cv, jnp.asarray(queries), jnp.asarray(positions))
+    pos[slot] = 8
+    wlg, _, wpool = paged_verify_step(params, pool, bt, jnp.asarray(queries), jnp.asarray(pos))
     wlg = np.asarray(wlg)[slot]
     np.testing.assert_allclose(wlg, np.stack(seq_logits), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(wlg, oracle, rtol=2e-5, atol=2e-5)
     np.testing.assert_array_equal(np.argmax(wlg, axis=-1), chain[1:])
-    # the caches agree wherever the sequential path wrote (positions 0..8+k)
-    np.testing.assert_allclose(
-        np.asarray(wck)[:, slot, :, : 8 + k + 1],
-        np.asarray(sck)[:, slot, :, : 8 + k + 1],
-        rtol=1e-6, atol=1e-6,
-    )
+    # the slot's pages agree wherever the sequential path wrote (positions 0..8+k)
+    for plane, splane in zip(wpool, spool):
+        np.testing.assert_allclose(np.asarray(plane)[:, own], np.asarray(splane)[:, own], rtol=1e-6, atol=1e-6)
 
 
 def test_speculative_accept_greedy_unit():

@@ -11,22 +11,17 @@ The tier-1 guards this file pins:
 3. goodput / SLO attainment: TTFT breaches and deadline breaches are
    counted, auto-dump the ring into the span store as a force-retained
    trace, and tag the response;
-4. `bench.py --compare` exits nonzero on a synthetically regressed record
-   and zero on an identical one;
-5. GET /decode/flight and GET /decode/health serve live recorder data,
+4. GET /decode/flight and GET /decode/health serve live recorder data,
    and the profiler's ?duration_ms= auto-stop fires;
-6. host-phase attribution: frames carry a per-phase gap split with
+5. host-phase attribution: frames carry a per-phase gap split with
    sum(phase) <= gap and readback <= busy per family, phases + profiler
    ON still cost zero recompiles and stay within the overhead budget,
    and the sampling profiler is bounded-memory with valid folded output.
 """
 
 import asyncio
-import importlib.util
-import json
 import os
 import re
-import sys
 import threading
 import time
 
@@ -181,8 +176,8 @@ def test_env_kill_switch(monkeypatch):
 
 def test_recorder_overhead_within_budget():
     """Tier-1 guard (ii of the overhead contract): the measured per-round
-    append cost stays within the CI budget (local target <10 µs — the
-    measured figure is documented in PARITY.md)."""
+    append cost stays within the CI budget (local target <10 µs;
+    PARITY.md "Instrumentation overhead")."""
     us = FlightRecorder.measure_overhead(2000)
     assert us < OVERHEAD_BUDGET_US, f"flight append {us} µs/round"
 
@@ -389,157 +384,6 @@ def test_slo_metrics_and_exemplar_wiring():
         # exemplar only exists in the OpenMetrics exposition; older
         # clients fall back to classic text (no exemplar — tolerated)
         assert ("trace_id" in om) or (om == text)
-
-
-# ------------------------------------------------------- bench --compare
-
-
-_BENCH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py"
-)
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location("bench_cmp", _BENCH)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules.setdefault("bench_cmp", mod)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _record():
-    return {
-        "metric": "resnet50_predictions_per_sec",
-        "value": 12000.0,
-        "unit": "preds/s",
-        "vs_baseline": 9.6,
-        "s": {"iris": [2900.0, 85.0, 870.0, 0], "ceiling": [24000.0, 5.5, 10.8, 0]},
-        "gen": {
-            "tok_s": 1700.0, "ttft_p99": 1200.0, "itl_p99": 26.0,
-            "occ": 0.9, "recompiles": 0, "loop": [0.31, 0.89, 4.8],
-        },
-    }
-
-
-def test_compare_clean_on_identical_record(tmp_path):
-    """Tier-1 guard (ii): --compare exits 0 on an identical record..."""
-    bench = _load_bench()
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(_record()))
-    assert bench.run_compare(str(base), _record()) == 0
-
-
-def test_compare_fails_on_synthetic_regressions(tmp_path):
-    """...and nonzero on synthetically regressed ones, in every gated
-    direction: throughput down, latency up, recompiles appearing."""
-    bench = _load_bench()
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(_record()))
-    # throughput cliff (higher-is-better)
-    bad = _record()
-    bad["gen"]["tok_s"] = 900.0
-    assert bench.run_compare(str(base), bad) == 1
-    # latency cliff (lower-is-better)
-    bad = _record()
-    bad["gen"]["ttft_p99"] = 5000.0
-    assert bench.run_compare(str(base), bad) == 1
-    # a single recompile is a hard failure (count metric, no tolerance)
-    bad = _record()
-    bad["gen"]["recompiles"] = 1
-    assert bench.run_compare(str(base), bad) == 1
-    # bubble-fraction regression through the packed loop triple
-    bad = _record()
-    bad["gen"]["loop"][0] = 0.9
-    assert bench.run_compare(str(base), bad) == 1
-    # within tolerance: noise-sized wobble passes
-    ok = _record()
-    ok["gen"]["tok_s"] = 1700.0 * 0.9
-    ok["s"]["iris"][2] = 870.0 * 1.1
-    assert bench.run_compare(str(base), ok) == 0
-    # missing sections are skipped, not failed (different configurations)
-    partial = {"metric": "m", "value": 12000.0, "unit": "preds/s"}
-    assert bench.run_compare(str(base), partial) == 0
-
-
-def test_compare_gates_pipe_pack_and_prerename_baselines(tmp_path):
-    """The PR 13 compare surface: the packed gen.pipe A/B gates the
-    overlap share (the pipelined tokens/s + bubble gate through the
-    existing gen.tok_s / gen.loop keys), and a PRE-rename baseline
-    (spec_speedup / prefix_hit_rate spellings) still gates against a
-    post-rename record through the fallback reads — the renames must not
-    open a one-round gateless window."""
-    bench = _load_bench()
-    rec = _record()
-    rec["gen"]["pipe"] = [1550.0, 0.31, 0.23]
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(rec))
-    assert bench.run_compare(str(base), rec) == 0
-    # silently-serialized regression: the overlap collapses (the bubble
-    # rise shows through the existing gen.loop_bubble gate)
-    bad = _record()
-    bad["gen"]["pipe"] = [1550.0, 0.31, 0.0]
-    assert bench.run_compare(str(base), bad) == 1
-    bad = _record()
-    bad["gen"]["pipe"] = [1550.0, 0.31, 0.23]
-    bad["gen"]["loop"][0] = 0.9  # pipelined bubble gates via gen.loop
-    assert bench.run_compare(str(base), bad) == 1
-    # pre-rename baseline vs post-rename record: the old spellings map to
-    # the new gate keys, so a real regression still fails
-    old = _record()
-    old["gen"]["spec_speedup"] = 1.7
-    old["gen"]["prefix_hit_rate"] = 0.95
-    old_base = tmp_path / "old.json"
-    old_base.write_text(json.dumps(old))
-    new = _record()
-    new["gen"]["spec_spd"] = 1.7
-    new["gen"]["prefix_hit"] = 0.95
-    assert bench.run_compare(str(old_base), new) == 0
-    regressed = _record()
-    regressed["gen"]["spec_spd"] = 0.8
-    regressed["gen"]["prefix_hit"] = 0.95
-    assert bench.run_compare(str(old_base), regressed) == 1
-
-
-def test_compare_reads_driver_wrapper(tmp_path):
-    """load_record unwraps the driver's BENCH_rNN.json shape and rejects a
-    truncated (parsed: null) round instead of comparing garbage."""
-    bench = _load_bench()
-    wrapped = tmp_path / "BENCH_r99.json"
-    wrapped.write_text(
-        json.dumps({"n": 99, "cmd": "python bench.py", "rc": 0,
-                    "tail": "...", "parsed": _record()})
-    )
-    assert bench.run_compare(str(wrapped), _record()) == 0
-    truncated = tmp_path / "BENCH_trunc.json"
-    truncated.write_text(json.dumps({"n": 3, "tail": "x", "parsed": None}))
-    with pytest.raises(ValueError):
-        bench.load_record(str(truncated))
-
-
-def test_compare_cli_exit_codes(tmp_path):
-    """The CLI contract itself: `bench.py --compare BASE --record NEW`
-    exits 0/1 without running any bench leg."""
-    import subprocess
-
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(_record()))
-    bad = _record()
-    bad["gen"]["tok_s"] = 100.0
-    new = tmp_path / "new.json"
-    new.write_text(json.dumps(bad))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    same = subprocess.run(
-        [sys.executable, _BENCH, "--compare", str(base), "--record", str(base)],
-        capture_output=True, text=True, timeout=120, env=env,
-    )
-    assert same.returncode == 0, same.stderr[-500:]
-    assert "compare clean" in same.stderr
-    diff = subprocess.run(
-        [sys.executable, _BENCH, "--compare", str(base), "--record", str(new)],
-        capture_output=True, text=True, timeout=120, env=env,
-    )
-    assert diff.returncode == 1
-    assert "REGRESSED" in diff.stderr
 
 
 # ------------------------------------------------- operator API endpoints

@@ -698,16 +698,22 @@ def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_u
         np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-6)
 
 
-@pytest.mark.parametrize("rows,form", [(24, "masked"), (300, "grouped"), (512, "blocks")])
+@pytest.mark.parametrize("rows,form", [(24, "masked"), (300, "grouped"), (512, "refused")])
 def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch, rows, form):
-    """By static row count: masked to 256 rows, grouped above, in blocks of
-    rows above ``GROUPED_BLOCK_ROWS`` (128 here). Junk rows come back zero and
-    stay out of the counts, which are over the experts HELD."""
-    monkeypatch.setattr(moe, "GROUPED_BLOCK_ROWS", 128 if form == "blocks" else 4096)
+    """By static row count: masked to 256 rows, grouped above, and refused
+    above ``GROUPED_BLOCK_ROWS`` (128 in the last case; no served program is
+    that wide). Junk rows come back zero and stay out of the counts, which
+    are over the experts HELD."""
+    monkeypatch.setattr(moe, "GROUPED_BLOCK_ROWS", 128 if form == "refused" else 4096)
     p = _share(_expert_layer(1), 4, 4)
     n2 = jax.random.normal(jax.random.key(rows), (rows, CFG.hidden))
     valid = jnp.arange(rows) < rows - 5
-    y, counted = jax.jit(lambda p, x, v: _held_ffn(p, x, 4, v))(p, n2, valid)
+    held = jax.jit(lambda p, x, v: _held_ffn(p, x, 4, v))
+    if form == "refused":
+        with pytest.raises(ValueError, match="512 rows in one dispatch .* above 128"):
+            held(p, n2, valid)
+        return
+    y, counted = held(p, n2, valid)
     want = np.asarray(ref.expert_ffn(p, n2, first_expert=4, act="float32", shared=False, **ROUTE))
     np.testing.assert_allclose(np.asarray(y[: rows - 5]), want[: rows - 5], atol=5e-6)
     assert not np.asarray(y[rows - 5 :]).any()
