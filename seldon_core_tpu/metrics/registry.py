@@ -125,6 +125,13 @@ class NullMetrics:
         by prefix-cache pins (the reclaimable set)."""
         pass
 
+    def decode_kv_window_pool(self, deployment: str, free: int, live: int, released: int) -> None:
+        """A pool with a window page kind (a family with sliding-window
+        layers; ``decode_kv_pool`` then reads the full kind): the window
+        kind's ``free`` and ``live`` pages (gauges), and ``released`` more
+        pages that slots gave back as they moved past them (a counter)."""
+        pass
+
     def decode_kv_shared(self, deployment: str, pages: int) -> None:
         """One prefix-hit admission mapped ``pages`` pool pages copy-free."""
         pass
@@ -471,6 +478,24 @@ class Metrics(NullMetrics):
             ["deployment_name"],
             registry=registry,
         )
+        self._kv_win_free = Gauge(
+            "seldon_tpu_decode_kv_window_pages_free",
+            "Unallocated window-kind pages (sliding-window layers' pages) in the decode KV pool",
+            ["deployment_name"],
+            registry=registry,
+        )
+        self._kv_win_live = Gauge(
+            "seldon_tpu_decode_kv_window_pages_live",
+            "Window-kind KV pool pages mapped by at least one live decode slot",
+            ["deployment_name"],
+            registry=registry,
+        )
+        self._kv_win_released = Counter(
+            "seldon_tpu_decode_kv_window_pages_released_total",
+            "Window-kind pages slots gave back as they moved past them, before retiring",
+            ["deployment_name"],
+            registry=registry,
+        )
         self._kv_shared = Counter(
             "seldon_tpu_decode_kv_pages_shared_total",
             "Pool pages mapped copy-free into admitted slots off prefix hits",
@@ -762,6 +787,12 @@ class Metrics(NullMetrics):
         self._kv_pages_free.labels(deployment).set(free)
         self._kv_pages_live.labels(deployment).set(live)
         self._kv_pages_prefix.labels(deployment).set(prefix)
+
+    def decode_kv_window_pool(self, deployment, free, live, released):
+        self._kv_win_free.labels(deployment).set(free)
+        self._kv_win_live.labels(deployment).set(live)
+        if released > 0:
+            self._kv_win_released.labels(deployment).inc(released)
 
     def decode_kv_shared(self, deployment, pages):
         if pages > 0:

@@ -601,7 +601,20 @@ def kv_pool_zeros(
     ``kv_planes`` 1 (a latent-attention family, models/mla_decoder.py) is the
     LATENT page kind: ONE plane whose token row is the token's compressed
     key/value of ``kv_heads * head_dim`` = 1 x (latent + rotated shared key),
-    no V plane, no per-head rows; a float plane only."""
+    no V plane, no per-head rows; a float plane only.
+    ``kv_window_layers`` > 0 (models/moe_decoder.py: that many of the
+    ``kv_layers`` are sliding-window layers) gives TWO PAGE KINDS in one
+    state tuple: the full layers' planes, then the window layers' planes of
+    the same form, each kind with its own page axis, ``n_pages`` = (full,
+    window) or one count for both. A layer addresses its kind's planes by
+    its index among that kind's layers; serving/kv_pool.py holds a block
+    table and an allocator a kind."""
+    if d.get("kv_window_layers", 0):
+        n_full, n_win = n_pages if isinstance(n_pages, (tuple, list)) else (n_pages, n_pages)
+        one = {**d, "kv_window_layers": 0}
+        return kv_pool_zeros(
+            {**one, "kv_layers": d["kv_layers"] - d["kv_window_layers"]}, n_full, page_size, dtype, kv_dtype
+        ) + kv_pool_zeros({**one, "kv_layers": d["kv_window_layers"]}, n_win, page_size, dtype, kv_dtype)
     shape = (d["kv_layers"], n_pages, page_size, d["kv_heads"] * d["head_dim"])
     if d.get("kv_planes", 2) == 1:
         if kv_dtype:

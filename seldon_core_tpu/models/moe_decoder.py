@@ -10,20 +10,37 @@ the GPT-2 block of ``models/decoder.py``:
   kv_heads)``;
 - rotary positions, applied to q and k BEFORE the cache write at the row's
   own position, so pages hold rotated keys and a prefix hit needs nothing
-  new: plain frequencies on the sliding layers, YaRN on the full ones;
+  new: plain frequencies on the sliding layers, YaRN on the full ones (over
+  the first ``rotary_full`` share of a head's dimensions; the rest pass);
 - a layer pattern of period ``period``: ``period - 1`` sliding-window layers
-  (a query sees the ``window`` newest keys), then one full-attention layer;
+  (a query sees the ``window`` newest keys) and one full-attention layer,
+  last of its period or (``full_first``) first;
 - every feed-forward a routed expert layer: softmax over ``experts`` in
   float32, top ``experts_per_tok``, gates renormalised, gated-SiLU experts
   of width ``ffn`` (``ops/moe.py`` ``moe_topk_ffn``).
 
-It serves through the SAME paged machinery as the GPT-2 family: the token-row
-pool ``[L, pages, page_size, kv_heads * head_dim]`` written by
+What a configuration may add to that block (each off by default):
+``heads_window`` (another query head count on the sliding layers: weights of
+two shapes in one model), ``attn_gate`` (a per-head sigmoid gate on the
+attention output, from the layer's normed input: arXiv:2505.06708),
+``dense_layers`` leading layers with a dense gated MLP of width
+``dense_ffn``, ``shared_expert`` (one more expert every token takes,
+ungated), ``routed_scale`` on the renormalised gates, and ONE CHIP'S SHARE of
+an expert-parallel layer: ``experts_held`` experts from ``first_expert`` (the
+router keeps ``experts`` outputs, a pick that lands on an absent expert adds
+nothing: ``ops/moe.py`` ``moe_held_ffn``).
+
+It serves through the SAME paged machinery as the GPT-2 family: token-row
+planes ``[L, pages, page_size, kv_heads * head_dim]`` written by
 ``decoder._paged_write``, read by ``decoder._paged_gather``, copied by
-``decoder.paged_copy``. A sliding layer gathers through a WINDOWED block
-table: the pages that cover its queries' windows, taken from the slot's
-table by position; the mask is by absolute key position. The pool keeps
-every position of every layer (one allocator, one page kind).
+``decoder.paged_copy``. A configuration with sliding layers has TWO PAGE
+KINDS (``decoder.kv_pool_zeros``, serving/kv_pool.py): the full layers'
+planes hold every position, the sliding layers' planes have pages of their
+own under a block table of their own, and a page wholly older than the
+window is given back while the sequence runs. A sliding layer gathers
+through a WINDOWED block table: the pages that cover its queries' windows,
+taken from the slot's window-kind table by position (a page given back
+reads as junk page 0); the mask is by absolute key position.
 
 A family is what ``serving/decode_scheduler.py`` takes from the model's
 spec (``ModelSpec.generative["family"]``) and asks (the list is
@@ -64,12 +81,22 @@ from seldon_core_tpu.models.decoder import (
     kv_pool_zeros,
     paged_greedy_generate,
 )
-from seldon_core_tpu.ops.moe import SCOPE_MOE_COMBINE, moe_topk_ffn
+from seldon_core_tpu.ops.moe import (
+    SCOPE_DENSE_MLP,
+    SCOPE_MOE_COMBINE,
+    SCOPE_SHARED_EXPERT,
+    gated_mlp,
+    moe_held_ffn,
+    moe_topk_ffn,
+    route_topk,
+)
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
 # name so readers of those still see the time: ``qkv/rope``,
-# ``win|full/kv_gather``, ``win|full/attn``, ``mlp/moe_*`` (ops/moe.py)
+# ``win|full/kv_gather``, ``win|full/attn``, ``attn_out/gate``, ``mlp/moe_*``,
+# ``mlp/shared_expert``, ``mlp/dense`` (ops/moe.py)
 SCOPE_ROPE = "rope"
+SCOPE_ATTN_GATE = "gate"  # the per-head sigmoid gate on the attention output
 SCOPE_WIN = "win"  # a sliding-window layer's gather and attention
 SCOPE_FULL = "full"  # a full-attention layer's
 
@@ -93,7 +120,7 @@ class MoEDecoderConfig:
     experts: int = 8
     experts_per_tok: int = 2
     window: int = 8
-    period: int = 4  # layer i is full attention where i % period == period - 1
+    period: int = 4  # layer i is full attention where i % period == period - 1 (0 with full_first)
     rope_theta: float = 10000.0
     yarn_factor: float = 4.0
     yarn_original: int = 16
@@ -102,27 +129,80 @@ class MoEDecoderConfig:
     yarn_attention_factor: float = 0.0  # 0 = 0.1 * ln(yarn_factor) + 1
     rms_eps: float = 1e-6
     max_len: int = 131072
+    # what a configuration may add to the block (module docstring), each off by default
+    heads_window: int = 0  # query heads of a sliding layer; 0 = ``heads``
+    attn_gate: bool = False
+    rotary_full: float = 1.0  # the share of a head's dimensions a full layer rotates
+    rope_theta_window: float = 0.0  # a sliding layer's theta; 0 = ``rope_theta``
+    full_first: bool = False
+    dense_layers: int = 0
+    dense_ffn: int = 0
+    shared_expert: bool = False
+    experts_held: int = 0  # 0 = all of them
+    first_expert: int = 0
+    routed_scale: float = 1.0
 
     def __post_init__(self):
-        if self.heads % self.kv_heads:
-            raise ValueError(f"heads={self.heads} not a multiple of kv_heads={self.kv_heads}")
-        if self.head_dim % 2:
-            raise ValueError(f"head_dim={self.head_dim} must be even (rotary pairs)")
+        for h in (self.heads, self.heads_window or self.heads):
+            if h % self.kv_heads:
+                raise ValueError(f"heads={h} not a multiple of kv_heads={self.kv_heads}")
+        rot = self.head_dim * self.rotary_full
+        if self.head_dim % 2 or rot != int(rot) or int(rot) % 2 or not 0 < rot <= self.head_dim:
+            raise ValueError(f"head_dim={self.head_dim} x rotary_full={self.rotary_full} must be even (rotary pairs)")
         if not 1 <= self.experts_per_tok <= self.experts:
             raise ValueError(f"experts_per_tok={self.experts_per_tok} of experts={self.experts}")
         if self.layers < 1 or self.period < 1 or self.window < 1:
             raise ValueError("layers, period and window must be >= 1")
+        if not 0 <= self.dense_layers <= self.layers or (self.dense_layers and self.dense_ffn < 1):
+            raise ValueError(f"dense_layers={self.dense_layers} of layers={self.layers}, dense_ffn={self.dense_ffn}")
+        if not 0 <= self.first_expert <= self.experts - self.held:
+            raise ValueError(f"experts [{self.first_expert}, +{self.held}) of {self.experts}")
 
     @property
     def q_width(self) -> int:
+        """A full layer's query width (a sliding layer's: ``heads_of``)."""
         return self.heads * self.head_dim
 
     @property
     def kv_width(self) -> int:
         return self.kv_heads * self.head_dim
 
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.experts
+
+    @property
+    def n_counters(self) -> int:
+        """What an expert layer counts: rows, experts hit, the fullest expert's
+        rows, and over a share of the experts the picks that landed on it."""
+        return 4 if self.experts_held else 3
+
     def is_full(self, layer: int) -> bool:
-        return layer % self.period == self.period - 1
+        return layer % self.period == (0 if self.full_first else self.period - 1)
+
+    def heads_of(self, layer: int) -> int:
+        return self.heads if self.is_full(layer) else self.heads_window or self.heads
+
+    @property
+    def window_layers(self) -> int:
+        return sum(not self.is_full(i) for i in range(self.layers))
+
+    @property
+    def two_kinds(self) -> bool:
+        """Whether the pool holds two page kinds: there are layers of both
+        kinds (a model of sliding layers alone keeps one table)."""
+        return 0 < self.window_layers < self.layers
+
+    @property
+    def kind_layers(self) -> int:
+        """``decoder_dims``' ``kv_window_layers``: the layers whose pages are the window kind."""
+        return self.window_layers if self.two_kinds else 0
+
+    def plane_layer(self, layer: int) -> int:
+        """The layer's index inside its page kind's planes."""
+        if not self.two_kinds:
+            return layer
+        return sum(self.is_full(i) == self.is_full(layer) for i in range(layer))
 
     @property
     def attention_factor(self) -> float:
@@ -132,17 +212,20 @@ class MoEDecoderConfig:
 # ------------------------------------------------------------------ rotary
 
 
-def rope_inv_freq(cfg: MoEDecoderConfig, full: bool) -> np.ndarray:
-    """[head_dim / 2] float32 rotary frequencies of a layer kind. Sliding
-    layers: theta^(-2i/d). Full layers (YaRN): fast dimensions keep their
-    frequency, slow ones are divided by ``yarn_factor``, with a linear ramp
-    between the dimensions that turn ``beta_fast`` and ``beta_slow`` times
-    over the original context."""
-    d = cfg.head_dim
+def rope_inv_freq(cfg, full: bool) -> np.ndarray:
+    """[d / 2] float32 rotary frequencies of a layer kind over the ``d``
+    dimensions it rotates. Sliding layers: theta^(-2i/d), d the whole head.
+    Full layers (YaRN; d = ``rotary_full`` of the head): fast dimensions keep
+    their frequency, slow ones are divided by ``yarn_factor``, with a linear
+    ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times over the original context."""
+    if not full:
+        d = cfg.head_dim
+        theta = getattr(cfg, "rope_theta_window", 0.0) or cfg.rope_theta
+        return (theta ** (-2.0 * np.arange(d // 2, dtype=np.float64) / d)).astype(np.float32)
+    d = int(cfg.head_dim * getattr(cfg, "rotary_full", 1.0))
     i = np.arange(d // 2, dtype=np.float64)
     plain = cfg.rope_theta ** (-2.0 * i / d)
-    if not full:
-        return plain.astype(np.float32)
 
     def turns_dim(n: float) -> float:
         return d * math.log(cfg.yarn_original / (2.0 * math.pi * n)) / (2.0 * math.log(cfg.rope_theta))
@@ -154,13 +237,16 @@ def rope_inv_freq(cfg: MoEDecoderConfig, full: bool) -> np.ndarray:
 
 
 def _rope(x, pos, inv_freq: np.ndarray, factor: float):
-    """Rotate-half rotary embedding of x[n, m, h, d] at pos[n, m], cos and
+    """Rotate-half rotary embedding of x[n, m, h, d] at pos[n, m] over its
+    first ``2 * len(inv_freq)`` dimensions (the rest pass through), cos and
     sin scaled by ``factor`` (YaRN's attention factor; 1 on plain layers)."""
-    ang = pos[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)  # [n, m, d/2]
+    ang = pos[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)  # [n, m, rot/2]
     cos = (jnp.cos(ang) * factor)[:, :, None, :]
     sin = (jnp.sin(ang) * factor)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    rot = 2 * len(inv_freq)
+    x1, x2 = jnp.split(x[..., :rot].astype(jnp.float32), 2, axis=-1)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    return turned if rot == x.shape[-1] else jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
 # ----------------------------------------------------------------- weights
@@ -194,20 +280,36 @@ def init_moe_decoder(cfg: MoEDecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
     def draw(key, shape, std=0.02):
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
-    @jax.jit
-    def layer(key):
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def layer(heads: int, dense: bool, key):
         ks = jax.random.split(key, 5)
-        return {
+        q_width = heads * cfg.head_dim
+        p = {
             "ln1": jnp.ones((cfg.hidden,), dtype),
-            "attn_qkv": draw(ks[0], (cfg.hidden, cfg.q_width + 2 * cfg.kv_width)),
-            "attn_o": draw(ks[1], (cfg.q_width, cfg.hidden)),
+            "attn_qkv": draw(ks[0], (cfg.hidden, q_width + 2 * cfg.kv_width)),
+            "attn_o": draw(ks[1], (q_width, cfg.hidden)),
             "ln2": jnp.ones((cfg.hidden,), dtype),
-            "moe": {
-                "router": draw(ks[2], (cfg.hidden, cfg.experts)),
-                "gate_up": draw(ks[3], (cfg.experts, cfg.hidden, 2 * cfg.ffn)),
-                "down": draw(ks[4], (cfg.experts, cfg.ffn, cfg.hidden)),
-            },
         }
+        # what a configuration adds draws from keys of its own: the block
+        # above is the same draw with or without it
+        extra = [jax.random.fold_in(key, 100 + j) for j in range(3)]
+        if cfg.attn_gate:
+            p["attn_gate"] = draw(extra[0], (cfg.hidden, heads))
+        if dense:
+            p["mlp"] = {
+                "gate_up": draw(ks[3], (cfg.hidden, 2 * cfg.dense_ffn)),
+                "down": draw(ks[4], (cfg.dense_ffn, cfg.hidden)),
+            }
+            return p
+        p["moe"] = {
+            "router": draw(ks[2], (cfg.hidden, cfg.experts)),
+            "gate_up": draw(ks[3], (cfg.held, cfg.hidden, 2 * cfg.ffn)),
+            "down": draw(ks[4], (cfg.held, cfg.ffn, cfg.hidden)),
+        }
+        if cfg.shared_expert:
+            p["moe"]["shared_gate_up"] = draw(extra[1], (cfg.hidden, 2 * cfg.ffn))
+            p["moe"]["shared_down"] = draw(extra[2], (cfg.ffn, cfg.hidden))
+        return p
 
     @jax.jit
     def ends(key):
@@ -219,7 +321,9 @@ def init_moe_decoder(cfg: MoEDecoderConfig, seed: int = 0, dtype=jnp.bfloat16) -
         }
 
     params = ends(jax.random.fold_in(root, 1 << 20))
-    params["layers"] = [layer(jax.random.fold_in(root, i)) for i in range(cfg.layers)]
+    params["layers"] = [
+        layer(cfg.heads_of(i), i < cfg.dense_layers, jax.random.fold_in(root, i)) for i in range(cfg.layers)
+    ]
     return params
 
 
@@ -265,61 +369,106 @@ def _attend(q, ck, cv, visible, scale=None):
     return ctx.astype(q.dtype)
 
 
+def _kind_pool(cfg: MoEDecoderConfig, full: bool, pool: tuple, bt):
+    """(the planes, the block table) of a layer kind: the whole pool and its
+    one table where the configuration has one page kind, else the kind's half
+    of the state tuple (full planes first) and its table of ``bt``: ``[2, n,
+    pages]`` (full, window) as the pool hands it (``PagedKVPool.block_tables``)
+    or a pair; ONE ``[n, pages]`` table serves both kinds (a test, the fused
+    fallback's identity tables)."""
+    if not cfg.two_kinds:
+        return pool, bt
+    half = len(pool) // 2
+    tables = bt if isinstance(bt, (tuple, list)) or bt.ndim == 3 else (bt, bt)
+    return (pool[:half], tables[0]) if full else (pool[half:], tables[1])
+
+
+def _ffn(cfg: MoEDecoderConfig, p, h, valid):
+    """A layer's feed-forward over h[T, d] (normed): the dense MLP of a
+    leading layer, else the routed experts (all of them, or the share held)
+    and the shared one. Returns (y[T, d], counters[3 or 4])."""
+    if "mlp" in p:
+        with jax.named_scope(SCOPE_DENSE_MLP):
+            return gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((cfg.n_counters,), jnp.int32)
+    if cfg.experts_held:
+        gates, experts = route_topk(p["moe"]["router"], h, cfg.experts_per_tok)
+        y, cnt = moe_held_ffn(p["moe"], h, gates * cfg.routed_scale, experts, cfg.first_expert, valid)
+    else:
+        y, cnt = moe_topk_ffn(p["moe"], h, cfg.experts_per_tok, valid)
+        if cfg.routed_scale != 1.0:
+            y = y * cfg.routed_scale
+    if cfg.shared_expert:
+        with jax.named_scope(SCOPE_SHARED_EXPERT):
+            y = y + gated_mlp(p["moe"]["shared_gate_up"], p["moe"]["shared_down"], h)
+    return y, cnt
+
+
 def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, valid):
     """One layer over the page pool: x[n, m, d] with slot i's query j at
-    positions[i] + j. Rotated K and V scatter through the block tables
-    first, attention reads them back through the (windowed) gather, like
-    the GPT-2 family's write-then-read. Returns (x, pool, counters[3])."""
+    positions[i] + j. Rotated K and V scatter through the layer kind's block
+    table first, attention reads them back through the (windowed) gather,
+    like the GPT-2 family's write-then-read. Returns (x, pool, counters)."""
     n, m, _ = x.shape
     full = cfg.is_full(li)
+    heads, pl = cfg.heads_of(li), cfg.plane_layer(li)
+    q_width = heads * cfg.head_dim
     q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]  # [n, m]
     with jax.named_scope(SCOPE_QKV):
-        qkv = _rms(p["ln1"], x, cfg.rms_eps) @ p["attn_qkv"].astype(x.dtype)
-        q, k, v = jnp.split(qkv, [cfg.q_width, cfg.q_width + cfg.kv_width], axis=-1)
+        n1 = _rms(p["ln1"], x, cfg.rms_eps)
+        qkv = n1 @ p["attn_qkv"].astype(x.dtype)
+        q, k, v = jnp.split(qkv, [q_width, q_width + cfg.kv_width], axis=-1)
         with jax.named_scope(SCOPE_ROPE):
             inv_freq = rope_inv_freq(cfg, full)
             factor = cfg.attention_factor if full else 1.0
-            q = _rope(q.reshape(n, m, cfg.heads, cfg.head_dim), q_pos, inv_freq, factor)
+            q = _rope(q.reshape(n, m, heads, cfg.head_dim), q_pos, inv_freq, factor)
             k = _rope(k.reshape(n, m, cfg.kv_heads, cfg.head_dim), q_pos, inv_freq, factor)
             k = k.reshape(n, m, cfg.kv_width)  # token rows, rotated
-    pool = _paged_write(pool, li, k, v, bt, positions, counts)
+    kind, bt_k = _kind_pool(cfg, full, pool, bt)
+    kind = _paged_write(kind, pl, k, v, bt_k, positions, counts)
+    if cfg.two_kinds:
+        pool = kind + pool[len(kind):] if full else pool[: len(kind)] + kind
+    else:
+        pool = kind
     with jax.named_scope(SCOPE_FULL if full else SCOPE_WIN):
         if full:
-            bt_l, k0 = bt, jnp.zeros_like(positions)
+            bt_l, k0 = bt_k, jnp.zeros_like(positions)
         else:
             with jax.named_scope(SCOPE_KV_GATHER):
-                bt_l, k0 = _window_table(bt, positions, m, pool[0].shape[2], cfg.window)
-        ck, cv = _paged_gather(pool, li, bt_l, cfg.kv_heads)  # [n, g, K, d] float32
+                bt_l, k0 = _window_table(bt_k, positions, m, kind[0].shape[2], cfg.window)
+        ck, cv = _paged_gather(kind, pl, bt_l, cfg.kv_heads)  # [n, g, K, d] float32
         with jax.named_scope(SCOPE_ATTN):
             k_pos = k0[:, None] + jnp.arange(ck.shape[2], dtype=k0.dtype)[None, :]  # [n, K]
             visible = k_pos[:, None, :] <= q_pos[:, :, None]
             if not full:
                 visible &= q_pos[:, :, None] - k_pos[:, None, :] < cfg.window
-            if 4 * n * cfg.heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
+            if 4 * n * heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
                 ctx = lax.map(
                     lambda a: _attend(*(t[None] for t in a))[0], (q, ck, cv, visible)
                 )
             else:
                 ctx = _attend(q, ck, cv, visible)
     with jax.named_scope(SCOPE_ATTN_OUT):
+        if cfg.attn_gate:
+            with jax.named_scope(SCOPE_ATTN_GATE):
+                g = jax.nn.sigmoid((n1 @ p["attn_gate"].astype(x.dtype)).astype(jnp.float32))  # [n, m, heads]
+                ctx = (ctx.reshape(n, m, heads, cfg.head_dim) * g[..., None].astype(ctx.dtype)).reshape(n, m, q_width)
         x = x + ctx @ p["attn_o"].astype(x.dtype)
     with jax.named_scope(SCOPE_MLP):
-        y, cnt = moe_topk_ffn(
-            p["moe"], _rms(p["ln2"], x, cfg.rms_eps).reshape(n * m, -1),
-            cfg.experts_per_tok, valid.reshape(-1),
-        )
+        y, cnt = _ffn(cfg, p, _rms(p["ln2"], x, cfg.rms_eps).reshape(n * m, -1), valid.reshape(-1))
         x = x + y.reshape(x.shape)
     return x, pool, cnt
 
 
 def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
     """Shared body of the paged programs: tokens[n, m], slot i's query j
-    at positions[i] + j. ``counts`` [n] (chunk rounds): only the first
-    counts[i] rows of slot i are real. ``rows`` [n] bool (the step): the
-    slots that generate. ``pick`` [n]: the head runs on that one query of
-    each slot (a chunk round needs only the last real one; 256 positions
-    of a 98k vocabulary are 1.6 GB of logits). Returns (logits[n, m or 1,
-    vocab] float32, hidden[n, m, d], pool, counters[3] int32)."""
+    at positions[i] + j; ``bt`` the slots' block-table rows, ``[2, n, pages]``
+    (full kind, window kind) where the configuration has two page kinds. ``counts`` [n]
+    (chunk rounds): only the first counts[i] rows of slot i are real.
+    ``rows`` [n] bool (the step): the slots that generate. ``pick`` [n]:
+    the head runs on that one query of each slot (a chunk round needs only
+    the last real one; 256 positions of a 98k vocabulary are 1.6 GB of
+    logits). Returns (logits[n, m or 1, vocab] float32, hidden[n, m, d],
+    pool, counters int32: ``MoEDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     if counts is not None:
@@ -328,7 +477,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         valid &= rows[:, None]
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    cnt = jnp.zeros((3,), jnp.int32)
+    cnt = jnp.zeros((cfg.n_counters,), jnp.int32)
     for li, lp in enumerate(params["layers"]):
         x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid)
         with jax.named_scope(SCOPE_MLP), jax.named_scope(SCOPE_MOE_COMBINE):
@@ -346,8 +495,11 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
 
 def _generate(cfg, params, ids, max_new_tokens: int):
     """The fused fallback apply of a deployment without ``tpu.decode_slots``
-    (``decoder.paged_greedy_generate``) over a private two-plane pool."""
-    dims = {"kv_layers": cfg.layers, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim}
+    (``decoder.paged_greedy_generate``) over a private pool: every sequence
+    keeps all its pages in both kinds, under one identity table."""
+    dims = {
+        "kv_layers": cfg.layers, "kv_window_layers": cfg.kind_layers, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+    }
     return paged_greedy_generate(
         functools.partial(_forward, cfg, params),
         lambda n_pages, ps: kv_pool_zeros(dims, n_pages, ps, params["tok_emb"].dtype), ids, max_new_tokens,
@@ -367,20 +519,39 @@ class MoEDecoder:
     cfg: MoEDecoderConfig
 
     name = "moe"
-    # what paged_forward's extra output counts, in order (FlightFrame fields)
-    frame_counters = ("moe_rows", "moe_experts_hit", "moe_load_max")
-    # not served yet (decoder.require_served): speculation, a decode mesh, a step attention kernel
-    serves = frozenset({"kv_int8", "host_tier", "prefix_export"})
     state_init = None  # no recurrent state: pages only
 
+    @property
+    def frame_counters(self) -> tuple:
+        """What paged_forward's extra output counts, in order (FlightFrame
+        fields); over a share of the experts also the picks that landed on it."""
+        base = ("moe_rows", "moe_experts_hit", "moe_load_max")
+        return base + (("moe_local_picks",) if self.cfg.experts_held else ())
+
+    @property
+    def serves(self) -> frozenset:
+        """Beside the plain rounds (``decoder.require_served``): the int8 pool
+        on either layout; the KV tiers and prefix export only where the pool
+        has ONE page kind (they move a prefix as one list of pages). Not
+        served: speculation, a decode mesh, a step attention kernel."""
+        return frozenset({"kv_int8"} if self.cfg.two_kinds else {"kv_int8", "host_tier", "prefix_export"})
+
     def decoder_dims(self, params: dict) -> dict:
-        if "lm_head" not in params or "moe" not in params["layers"][0]:
+        layers = params.get("layers") or [{}]
+        if "lm_head" not in params or not ("moe" in layers[-1] and "attn_qkv" in layers[0]):
             raise FamilyNotServed("not a sparse-expert decoder's parameters (models/moe_decoder.py layout)")
         c = self.cfg
+        if len(layers) != c.layers or c.attn_gate != ("attn_gate" in layers[0]) or (
+            layers[0]["attn_qkv"].shape[1] != c.heads_of(0) * c.head_dim + 2 * c.kv_width
+        ):
+            raise FamilyNotServed("parameters and configuration disagree on layers, attn_gate or the head counts")
         return {
-            "layers": len(params["layers"]), "kv_layers": len(params["layers"]), "heads": c.heads,
+            "layers": c.layers, "kv_layers": c.layers, "heads": c.heads,
             "kv_heads": c.kv_heads, "hidden": c.hidden, "head_dim": c.head_dim, "q_width": c.q_width,
             "vocab": params["tok_emb"].shape[0], "max_len": c.max_len,
+            # the second page kind (decoder.kv_pool_zeros, serving/kv_pool.py):
+            # how many of kv_layers are sliding layers, and their window
+            "kv_window_layers": c.kind_layers, "kv_window": c.window if c.two_kinds else 0,
         }
 
     def paged_kv_init(self, params, n_pages, page_size, dtype=jnp.float32, kv_dtype=""):

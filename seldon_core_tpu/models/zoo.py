@@ -431,7 +431,7 @@ def build_moe_decoder(
     hidden: int = 64,
     layers: int = 4,
     ffn: int = 32,
-    heads: int = 4,
+    heads: int | str = 4,
     kv_heads: int = 2,
     head_dim: int = 16,
     experts: int = 8,
@@ -446,44 +446,79 @@ def build_moe_decoder(
     yarn_attention_factor: float = 0.0,
     rms_eps: float = 1e-6,
     max_len: int = 131072,
+    attn_gate: bool = False,
+    rotary_full: float = 1.0,
+    rope_theta_window: float = 0.0,
+    full_first: bool = False,
+    dense_layers: int = 0,
+    dense_ffn: int = 0,
+    shared_expert: bool = False,
+    experts_held: int = 0,
+    first_expert: int = 0,
+    routed_scale: float = 1.0,
     seq: int = 32,
     max_new_tokens: int = 16,
     param_dtype: str = "bfloat16",
-    **_,
+    **unknown,
 ) -> ModelSpec:
     """The generative tier's second decoder family (models/moe_decoder.py):
     RMSNorm, grouped-query attention with rotary positions (YaRN on the
     full layers), ``period - 1`` sliding-window layers to one full layer,
     and a top-``experts_per_tok``-of-``experts`` gated-SiLU expert layer in
     every block. The parameters are a published config's keys; ``ffn`` is
-    ONE expert's width. Weights are drawn on the device from ``seed`` in
+    ONE expert's width. What a configuration may add: ``heads`` as
+    ``"full,sliding"`` (two query head counts by layer kind), ``attn_gate``
+    (a per-head sigmoid gate on the attention output), ``rotary_full`` (the
+    share of a head's dimensions the full layers rotate),
+    ``rope_theta_window`` (the sliding layers' theta), ``full_first`` (the
+    full layer leads its period), ``dense_layers`` leading dense layers of
+    width ``dense_ffn``, ``shared_expert``, ``routed_scale``, and one chip's
+    share of an expert-parallel layer: ``experts_held`` (0: all) from
+    ``first_expert``, the router keeping ``experts`` outputs. Weights are
+    drawn on the device from ``seed`` in
     ``param_dtype`` (set it to the deployment's ``tpu.dtype``: the runtime
     casts what differs). It serves through ``tpu.decode_slots`` (the
     scheduler takes the family from ``generative["family"]``); without it
     the fused fallback decodes whole batches greedily through the same
     paged forward. Speculation and tensor-parallel decode are not served
-    for it yet."""
+    for it yet. A parameter it does not know is refused by name: a
+    configuration written for a later tree fails with a sentence here and
+    does not build another model."""
     import jax.numpy as jnp
 
+    from seldon_core_tpu.graph.spec import bool_param
     from seldon_core_tpu.models.moe_decoder import (
         MoEDecoderConfig,
         init_moe_decoder,
         moe_family,
     )
 
+    if unknown:
+        raise ValueError(
+            f"zoo://moe_decoder does not know the parameter(s) {sorted(unknown)}: "
+            "it builds what it is told, not a model without them"
+        )
     if seq + max_new_tokens > max_len:
         raise ValueError(
             f"seq={seq} + max_new_tokens={max_new_tokens} exceeds max_len={max_len}"
         )
+    by_kind = [int(h) for h in str(heads).split(",")]
+    if len(by_kind) not in (1, 2):
+        raise ValueError(f"heads={heads!r}: one count, or 'full,sliding'")
     cfg = MoEDecoderConfig(
-        vocab=int(vocab), hidden=int(hidden), layers=int(layers), heads=int(heads),
+        vocab=int(vocab), hidden=int(hidden), layers=int(layers), heads=by_kind[0],
         kv_heads=int(kv_heads), head_dim=int(head_dim), ffn=int(ffn),
         experts=int(experts), experts_per_tok=int(experts_per_tok), window=int(window),
         period=int(period), rope_theta=float(rope_theta), yarn_factor=float(yarn_factor),
         yarn_original=int(yarn_original), yarn_beta_fast=float(yarn_beta_fast),
         yarn_beta_slow=float(yarn_beta_slow),
         yarn_attention_factor=float(yarn_attention_factor), rms_eps=float(rms_eps),
-        max_len=int(max_len),
+        max_len=int(max_len), heads_window=by_kind[-1] if len(by_kind) == 2 else 0,
+        attn_gate=bool_param(attn_gate), rotary_full=float(rotary_full),
+        rope_theta_window=float(rope_theta_window), full_first=bool_param(full_first),
+        dense_layers=int(dense_layers), dense_ffn=int(dense_ffn),
+        shared_expert=bool_param(shared_expert), experts_held=int(experts_held),
+        first_expert=int(first_expert), routed_scale=float(routed_scale),
     )
     family = moe_family(cfg)
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[str(param_dtype)]

@@ -1073,7 +1073,16 @@ class DecodeScheduler:
             kv_init=self.family.paged_kv_init,
             state_init=self.family.state_init,
             n_state_rows=self.prefix_slots,
+            # a family with sliding-window layers: the second page kind, its
+            # count derived from the slots' rings and the entries' last windows
+            window=dims.get("kv_window", 0),
+            max_write=top,
+            n_prefix=self.prefix_slots,
         )
+        # a cache that is reusable only at the length it was kept at: state
+        # rows (a snapshot) or window-kind pages (the last window before it).
+        # Such a family captures at a hint's boundary and hits whole entries.
+        self._boundary_cache = self._stateful or self.pool.windowed
         if self.prefix_enabled:
             self.pool.alloc.on_pins_reclaimed = self._on_pins_reclaimed
         # demand-paged prefix-page tiers below the device pool
@@ -1183,6 +1192,13 @@ class DecodeScheduler:
         # paged-pool attribution (the allocator owns the counters; these
         # track what the scheduler itself dispatched/declined)
         self.stat_kv_copy_rounds = 0
+        # the window page kind (a family with sliding-window layers): pages
+        # slots allocated, those they gave back as they moved past them, the
+        # most live at a round's commit (the frames carry each round's)
+        self.stat_kv_win_written = 0
+        self.stat_kv_win_released = 0
+        self.stat_kv_win_live_peak = 0
+        self._kv_win_released_gauged = 0  # what /metrics' counter has been told of
         # scheduler rounds whose queue head could not reserve pages (one
         # waiting request blocked for N rounds counts N — a round counter,
         # not an admission counter)
@@ -1919,6 +1935,12 @@ class DecodeScheduler:
         self._metrics.decode_kv_pool(
             self._deployment, a.free_pages, a.live_pages, a.prefix_pages
         )
+        if a.win is not None:
+            released = a.win.stat_released - self._kv_win_released_gauged
+            self._kv_win_released_gauged = a.win.stat_released
+            self._metrics.decode_kv_window_pool(
+                self._deployment, a.win.free_pages, a.win.live_pages, released
+            )
         # pages resident per device: the page axis is NOT sharded (every
         # device holds all pages x its head shard), so the count matches
         # the pool-wide allocation while per-page BYTES scale 1/tp — the
@@ -2059,10 +2081,12 @@ class DecodeScheduler:
         return self._prefix_index.match(seq.prompt[:length], touch=False, whole=True)[1] == length
 
     def _hint_boundary(self, seq: _Seq) -> int:
-        """A recurrent family: the prompt position a hinted request's state
-        is snapshotted at (its ``cache_prefix``, inside what a later request
-        can reuse: at least one suffix token stays); 0 = none."""
-        if not (self._stateful and self.prefix_enabled and seq.cache_prefix > 0):
+        """A recurrent family, or a pool with window-kind pages: the prompt
+        position a hinted request's state is snapshotted at, or its pages
+        pinned while the last window before it is still mapped (its
+        ``cache_prefix``, inside what a later request can reuse: at least one
+        suffix token stays); 0 = none."""
+        if not (self._boundary_cache and self.prefix_enabled and seq.cache_prefix > 0):
             return 0
         return usable_prefix_len(
             capture_prefix_len(seq.cache_prefix, self.prefix_ctx, self.seq_len), self.seq_len
@@ -2118,6 +2142,8 @@ class DecodeScheduler:
                         # token since: nothing left to capture
                         self.stat_prefix_capture_skips += 1
                     else:
+                        # (window-kind pages: only while the prompt's last
+                        # window is still mapped, else a skip)
                         self._maybe_capture(seq, slot, self.seq_len)
             self.pool.alloc.retire(slot)
             self._kv_gauges()
@@ -2194,6 +2220,7 @@ class DecodeScheduler:
         # (nothing to build each round for a family that counts nothing)
         if self._frame_counters:
             self._rb_counts = np.zeros(len(self._frame_counters), np.int64)
+        self._rb_step_counts = ()
         # stale shadow admissions (a round error between the overlap
         # window and the reconcile): the normal flow drains the list at
         # _apply_pending before the round commits, so anything still here
@@ -2317,6 +2344,8 @@ class DecodeScheduler:
                         chunk_c=self._rb_chunk_c,
                         chunk_rows_held=self._rb_chunk_rows_held,
                         chunk_rows_kernel=self._rb_chunk_rows_kernel,
+                        **self._window_frame(snap),
+                        step_counts=self._rb_step_counts,
                         ingress_ns=ingress[0] - self._ingress_committed[0],
                         ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
@@ -2354,7 +2383,19 @@ class DecodeScheduler:
                 )
         self._round_reset(now_ns)
 
-    async def _run_copies(self, copies: list[tuple[int, int]]) -> None:
+    def _window_frame(self, snap: dict) -> dict:
+        """The round's window-kind frame fields from the allocator's snapshot
+        (cumulative counts: the round's are what was added since the last
+        frame), and the ``stat_*`` twins; nothing for a pool of one kind."""
+        if "win_live" not in snap:
+            return {}
+        written = snap["win_written"] - self.stat_kv_win_written
+        released = snap["win_released"] - self.stat_kv_win_released
+        self.stat_kv_win_written, self.stat_kv_win_released = snap["win_written"], snap["win_released"]
+        self.stat_kv_win_live_peak = max(self.stat_kv_win_live_peak, snap["win_live"])
+        return {"kv_win_live": snap["win_live"], "kv_win_released": released, "kv_win_written": written}
+
+    async def _run_copies(self, copies: list[tuple]) -> None:
         """Dispatch a round's copy-on-write page copies (batched through
         the pool's warmed ladder) BEFORE the round's write dispatch."""
         if not copies:
@@ -2380,7 +2421,7 @@ class DecodeScheduler:
             with self._phase(P_PREFIX_MATCH):
                 # a recurrent family reuses an entry's whole length or
                 # nothing (PrefixIndex: the depth rule per cache kind)
-                entry, depth = self._prefix_index.match(seq.prompt, whole=self._stateful)
+                entry, depth = self._prefix_index.match(seq.prompt, whole=self._boundary_cache)
                 # device-pool miss (or shallow hit): consult the tiers
                 # below — a host/store entry deeper than the device match
                 # promotes into pinned free pages and the re-match rides
@@ -2400,7 +2441,9 @@ class DecodeScheduler:
             # replica router normalizes the SAME way, so a prompt it
             # judged warm is one admission judges warm too.
             reuse = usable_prefix_len(depth, self.seq_len)
-            if reuse <= 0 or (self._stateful and reuse < depth):
+            if reuse <= 0 or (self._boundary_cache and reuse < depth) or not (
+                self.pool.alloc.pin_covers(entry.pin_id, reuse)
+            ):
                 entry, reuse = None, 0
         # a cache_prefix hint pins pages at prefill completion; if the
         # hinted span's last page extends past seq_len, this slot's own
@@ -2412,9 +2455,15 @@ class DecodeScheduler:
             alloc = self.pool.alloc
             hint_end = alloc.pages_for(seq.cache_prefix) * alloc.page_size
             extra = 1 if hint_end > self.seq_len else 0
+            # a capture at the hint's boundary, mid-prompt (state rows, window
+            # pages): the slot's next chunk writes into the boundary page it
+            # just pinned, unless the boundary is a page's end
+            if self._hint_boundary(seq) % alloc.page_size:
+                extra = 1
         with self._phase(P_ALLOC):
             admitted = self.pool.alloc.try_admit(
-                slot, entry.pages if entry is not None else (), reuse, extra
+                slot, entry.pages if entry is not None else (), reuse, extra,
+                pin_id=entry.pin_id if entry is not None else -1,
             )
         return entry, reuse, admitted
 
@@ -2793,7 +2842,7 @@ class DecodeScheduler:
                 # loop instead of silently paying a full prefill.
                 with self._phase(P_PREFIX_MATCH):
                     _, depth = self._prefix_index.match(
-                        p.seq.prompt, touch=False, whole=self._stateful
+                        p.seq.prompt, touch=False, whole=self._boundary_cache
                     )
                 if usable_prefix_len(depth, self.seq_len) > reuse:
                     self.pool.alloc.retire(p.slot)  # undo the shallow mapping
@@ -2918,6 +2967,10 @@ class DecodeScheduler:
                     # the pages up to it and bind the row, so that the very
                     # next admission can start from both
                     self._maybe_capture(seq, i, seq.prefill_pos, snaps[i])
+                elif self.pool.windowed and int(counts[r]) and seq.prefill_pos == self._hint_boundary(seq):
+                    # window-kind pages: pin the span while the slot still maps
+                    # its last window (the next write gives the oldest back)
+                    self._maybe_capture(seq, i, seq.prefill_pos)
                 for c in seq.trace_ctxs:
                     cs = c.buf.begin(
                         "decode.prefill_chunk",
@@ -2945,7 +2998,7 @@ class DecodeScheduler:
             for seq, i, first in finishing:
                 seq.prefilling = False
                 seq.pos = self.seq_len
-                if self.prefix_enabled and seq.cache_prefix > 0 and not self._stateful:
+                if self.prefix_enabled and seq.cache_prefix > 0 and not self._boundary_cache:
                     # hinted capture at prefill completion — the hinted
                     # span's pages are pinned from this moment, so the very
                     # next admission can already map them
@@ -3123,6 +3176,7 @@ class DecodeScheduler:
                 nxt, counted = await d.run(step)
         if counted is not None:
             self._rb_counts += counted
+            self._rb_step_counts = tuple(counted.tolist())  # the step's own, beside the round's sum
         return nxt
 
     async def _run(self) -> None:
@@ -3309,10 +3363,9 @@ class DecodeScheduler:
                         copies += self.pool.alloc.prepare_write(i, seq.pos, width)
                 await self._run_copies(copies)
                 with self._phase(P_ALLOC):
-                    bt = self.pool.block_tables()
                     # a free slot's row: whatever this dispatch writes for
                     # them lands in junk page 0, not in a prefix's pages
-                    bt[unridden] = 0
+                    bt = self.pool.block_tables(junk=unridden)
                     if not self._pipeline_on():
                         # per-round pool gauges: this round's prepare_write
                         # may have allocated/CoW'd pages with no admission
@@ -3401,6 +3454,8 @@ class DecodeScheduler:
         that pointed into it."""
         self.pool.reset()
         self.programs.reset()
+        # the fresh allocator counts from zero: so do the window kind's twins
+        self.stat_kv_win_written = self.stat_kv_win_released = self._kv_win_released_gauged = 0
         if self.prefix_enabled:
             self._prefix_index.clear()
 

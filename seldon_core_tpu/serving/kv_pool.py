@@ -47,6 +47,24 @@ whatever drops the pin (index cap, pin reclaim) gives the row back;
 (a padding row's, a dispatch without a snapshot). Rows are addressed by
 index inside the programs: nothing here copies one, and ``paged_copy`` and
 the copy ladder address pages only.
+
+A second kind of PAGE, for a family with sliding-window layers
+(models/moe_decoder.py; ``decoder_dims``' ``kv_window_layers`` / ``kv_window``):
+the window layers' planes follow the full layers' in the one state tuple, with
+a page axis, a block table ``[n_slots, max_pages]`` and an allocator of their
+own (``WindowPages``, ``PageAllocator.win``) under the same manager, pins and
+copy ladder (``block_tables()`` hands both kinds' tables as one ``[2, rows,
+max_pages]`` array). The table is indexed by logical page like the full kind's, but a
+slot maps only a RANGE of it: a page wholly older than every query still to
+come (``position - window + 1``) is given back when the slot next writes
+(``prepare_write``; the table entry reads junk page 0, the window mask hides
+it), so a slot never holds more than its ring, ``ring_pages``, whatever its
+context. Admission reserves the ring in this kind and the whole exclusive
+context in the full kind; a prefix pin holds every full-kind page of its span
+and the window-kind pages of its last ``window`` tokens, so a hit reuses an
+entry's whole length or nothing (as a state snapshot does) and a capture has
+to be taken when the sequence stands AT the span's end. ``decode_kv_pages``
+stays the full kind's count; the window kind's is derived (``window_pool_pages``).
 """
 
 from __future__ import annotations
@@ -67,13 +85,240 @@ class PoolPin:
     The radix index entry that owns it stores the pin_id; eviction drops
     the refs and frees whatever nothing else references."""
 
-    __slots__ = ("pin_id", "pages", "last_use", "state_row")
+    __slots__ = ("pin_id", "pages", "last_use", "state_row", "win_pages", "win_first")
 
     def __init__(self, pin_id: int, pages: list[int]):
         self.pin_id = pin_id
         self.pages = list(pages)
         self.last_use = 0
         self.state_row = -1  # the snapshot row bound to this prefix (a recurrent family), else -1
+        # the window kind's pages of the span's last window, and the logical page of the first
+        self.win_pages: list[int] = []
+        self.win_first = 0
+
+
+def ring_pages(window: int, max_write: int, page_size: int) -> int:
+    """The most window-kind pages a slot maps at once: those that cover the
+    window of the oldest query of a dispatch of ``max_write`` positions
+    through its newest position, from any row of a page."""
+    return -(-(int(window) + int(max_write)) // int(page_size)) + 1
+
+
+def span_pages(window: int, page_size: int) -> int:
+    """The window-kind pages a prefix pin holds: its span's last window, from any row of a page."""
+    return -(-int(window) // int(page_size)) + 1
+
+
+def window_pool_pages(n_slots: int, n_prefix: int, window: int, max_write: int, page_size: int) -> int:
+    """The window kind's page count of a deployment: every slot's ring, every
+    prefix entry's last window, the junk page and one page of slack."""
+    return n_slots * ring_pages(window, max_write, page_size) + n_prefix * span_pages(window, page_size) + 2
+
+
+class WindowPages:
+    """The window page kind's host accounting (module docstring), driven by
+    the ``PageAllocator`` that owns it: its own free list, refcounts, block
+    tables and reservations; the pins are the owner's. A slot maps the
+    logical pages ``[_lo, _hi)``.
+
+    Invariant, as for the full kind: ``free + reclaimable >= sum(reserved)``.
+    A slot's reservation is its ring of pages of its OWN (``_owner``):
+    allocating one spends it, giving one back refunds it (nobody else maps a
+    page a slot owns, so it goes straight to the free list). A page a pin
+    holds is nobody's own: a capture hands the slot's pages of the span to
+    the pin and refunds the slot, which is refused where the pool could not
+    guarantee the refund; a reader maps them without paying and gives them
+    back without a refund."""
+
+    def __init__(self, n_pages: int, page_size: int, n_slots: int, pages_per_slot: int, window: int, ring: int):
+        if n_pages < ring + 2:
+            raise ValueError(f"{n_pages} window-kind pages cannot hold one slot's ring of {ring} (+ junk + slack)")
+        self.n_pages, self.page_size, self.n_slots = int(n_pages), int(page_size), int(n_slots)
+        self.window, self.ring = int(window), int(ring)
+        self.refs = np.zeros(n_pages, np.int32)
+        self.refs[0] = 1  # page 0: the junk sink, as in the full kind
+        self.pin_count = np.zeros(n_pages, np.int32)
+        self._owner = np.full(n_pages, -1, np.int32)
+        self._free: list[int] = list(range(n_pages - 1, 0, -1))
+        self.block_tables = np.zeros((n_slots, pages_per_slot), np.int32)
+        self._lo = np.zeros(n_slots, np.int32)
+        self._hi = np.zeros(n_slots, np.int32)
+        self._reserved = np.zeros(n_slots, np.int64)
+        self.stat_written = 0  # pages a slot allocated (copy-on-write included)
+        self.stat_released = 0  # of those, given back before the slot retired
+        self.stat_cow_copies = 0
+        # called where an allocation finds the free list empty: the owner drops prefix pins until it is not
+        self.on_empty = lambda: None
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def live_pages(self) -> int:
+        """Pages at least one slot maps."""
+        return int(np.sum(self.refs[1:] > self.pin_count[1:]))
+
+    def reclaimable(self, exclude=()) -> int:
+        mask = (self.pin_count > 0) & (self.refs == self.pin_count)
+        return int(mask.sum()) - sum(1 for p in set(exclude) if mask[p])
+
+    def headroom(self, exclude=()) -> int:
+        """Pages the pool can still promise: free + reclaimable - reserved."""
+        return self.free_pages + self.reclaimable(exclude) - int(self._reserved.sum())
+
+    def slot_pages(self, slot: int) -> list[int]:
+        return [int(p) for p in self.block_tables[slot, int(self._lo[slot]) : int(self._hi[slot])]]
+
+    def first_needed(self, position: int) -> int:
+        """The logical page of the oldest key a query at ``position`` sees."""
+        return max(0, int(position) - self.window + 1) // self.page_size
+
+    # ------------------------------------------------------- the slot's range
+    def shared_for(self, pin: PoolPin | None, reuse: int) -> list[int] | None:
+        """The pin's pages a hit at ``reuse`` would map, those of ``[reuse -
+        window, reuse)``; None where the pin does not hold the whole of that
+        window."""
+        if pin is None or reuse <= 0:
+            return []
+        lo, hi = self.first_needed(reuse) - pin.win_first, -(-int(reuse) // self.page_size) - pin.win_first
+        if lo < 0 or hi > len(pin.win_pages):
+            return None
+        return pin.win_pages[lo:hi]
+
+    def admit(self, slot: int, shared: list[int], reuse: int) -> None:
+        """Map ``shared`` (``shared_for``'s answer: the pages that end at
+        ``reuse``) and reserve the ring (``PageAllocator.try_admit`` checked
+        both kinds first)."""
+        hi = -(-int(reuse) // self.page_size) if shared else 0
+        lo = hi - len(shared)
+        self.block_tables[slot, lo:hi] = shared
+        self.refs[shared] += 1  # a pin's pages are distinct
+        self._lo[slot], self._hi[slot] = lo, hi
+        self._reserved[slot] = self.ring
+
+    def _alloc(self, slot: int) -> int:
+        if self._reserved[slot] <= 0:
+            raise RuntimeError(f"slot {slot} allocating a window-kind page past its ring of {self.ring}")
+        if not self._free:
+            self.on_empty()
+        p = self._free.pop()
+        self.refs[p] = 1
+        self._owner[p] = slot
+        self._reserved[slot] -= 1
+        self.stat_written += 1
+        return p
+
+    def _drop(self, slot: int, p: int, released: bool) -> None:
+        """The slot's reference on page ``p`` goes; a page of its own goes
+        back to the free list and refunds the ring."""
+        if self._owner[p] == slot:
+            self._owner[p] = -1
+            self._reserved[slot] += 1
+            self.stat_released += released
+        self.refs[p] -= 1
+        if self.refs[p] == 0:
+            self._free.append(p)
+
+    def prepare_write(self, slot: int, start: int, end: int) -> list[tuple[int, int, int]]:
+        """Positions [start, end) of ``slot``: give back the pages no query
+        from ``start`` on can see, map the pages the write needs, copy-on-write
+        a shared one. Returns (src, dst, 1) page copies of this kind."""
+        ps, bt = self.page_size, self.block_tables
+        lo, hi, first, last = int(self._lo[slot]), int(self._hi[slot]), int(start) // ps, (end - 1) // ps
+        if first == last < hi and self.first_needed(start) <= lo and self.refs[bt[slot, last]] == 1:
+            return []  # a step inside a page of the slot's own, nothing to give back: the usual round
+        keep = min(self.first_needed(start), int(self._hi[slot]))
+        for lp in range(int(self._lo[slot]), keep):
+            self._drop(slot, int(bt[slot, lp]), released=True)
+            bt[slot, lp] = 0
+        self._lo[slot] = max(int(self._lo[slot]), keep)
+        copies = []
+        for lp in range(int(start) // ps, (end - 1) // ps + 1):
+            if lp >= self._hi[slot]:
+                if self._lo[slot] == self._hi[slot]:  # an empty range starts where the write does
+                    self._lo[slot] = self._hi[slot] = lp
+                for lpn in range(int(self._hi[slot]), lp + 1):
+                    bt[slot, lpn] = self._alloc(slot)
+                self._hi[slot] = lp + 1
+            elif self.refs[bt[slot, lp]] > 1:
+                shared = int(bt[slot, lp])
+                fresh = self._alloc(slot)
+                copies.append((shared, fresh, 1))
+                bt[slot, lp] = fresh
+                self.refs[shared] -= 1
+                self.stat_cow_copies += 1
+        return copies
+
+    def retire(self, slot: int) -> None:
+        for lp in range(int(self._lo[slot]), int(self._hi[slot])):
+            self._drop(slot, int(self.block_tables[slot, lp]), released=False)
+        self.block_tables[slot, :] = 0
+        self._lo[slot] = self._hi[slot] = 0
+        self._reserved[slot] = 0
+
+    # ------------------------------------------------------------------ pins
+    def capture(self, slot: int, length: int, pin: PoolPin) -> bool:
+        """Bind the slot's pages of ``[length - window, length)`` to ``pin``;
+        False (nothing bound) where the slot no longer maps all of them, or
+        the pool could not refund the slot the pages of its own among them."""
+        first, last = max(0, int(length) - self.window) // self.page_size, -(-int(length) // self.page_size)
+        if first < self._lo[slot] or last > self._hi[slot]:
+            return False
+        pages = [int(p) for p in self.block_tables[slot, first:last]]
+        own = [p for p in pages if self._owner[p] == slot]
+        if self.headroom() < len(own):
+            return False
+        for p in own:
+            self._owner[p] = -1
+        self._reserved[slot] += len(own)
+        for p in pages:
+            self.refs[p] += 1
+            self.pin_count[p] += 1
+        pin.win_pages, pin.win_first = pages, first
+        return True
+
+    def drop_pin(self, pin: PoolPin) -> None:
+        for p in pin.win_pages:
+            self.pin_count[p] -= 1
+            self.refs[p] -= 1
+            if self.refs[p] == 0:
+                self._free.append(p)
+
+    def check(self, pins) -> None:
+        """``PageAllocator.check`` for this kind."""
+        refs = np.zeros(self.n_pages, np.int64)
+        refs[0] = 1
+        for s in range(self.n_slots):
+            row = self.block_tables[s]
+            lo, hi = int(self._lo[s]), int(self._hi[s])
+            if row[:lo].any() or row[hi:].any() or not row[lo:hi].all():
+                raise AssertionError(f"window kind: slot {s} maps pages outside [{lo}, {hi}) or junk inside")
+            if hi - lo > self.ring:
+                raise AssertionError(f"window kind: slot {s} maps {hi - lo} pages, above its ring of {self.ring}")
+            for p in row[lo:hi]:
+                refs[p] += 1
+        held = np.zeros(self.n_pages, np.int64)
+        for pin in pins:
+            for p in pin.win_pages:
+                refs[p] += 1
+                held[p] += 1
+        if not np.array_equal(refs, self.refs) or not np.array_equal(held, self.pin_count):
+            raise AssertionError("window kind: refcounts diverged from block tables + pins")
+        free = set(self._free)
+        if len(free) != len(self._free) or 0 in free:
+            raise AssertionError("window kind: double-free, or junk page 0 in the free list")
+        for p in range(1, self.n_pages):
+            if (self.refs[p] == 0) != (p in free):
+                raise AssertionError(f"window kind: page {p} is free and referenced, or neither")
+            o = int(self._owner[p])
+            if o >= 0 and (self.refs[p] != 1 or p not in self.block_tables[o]):
+                raise AssertionError(f"window kind: page {p} is slot {o}'s own and shared or unmapped")
+        own = np.bincount(self._owner[self._owner >= 0], minlength=self.n_slots)
+        if (own + self._reserved > self.ring).any():
+            raise AssertionError("window kind: a slot's own pages + reservation exceed its ring")
+        if self.headroom() < 0:
+            raise AssertionError("window kind: reservation invariant broken")
 
 
 class PageAllocator:
@@ -89,8 +334,11 @@ class PageAllocator:
     only spends reservation the slot holds."""
 
     def __init__(
-        self, n_pages: int, page_size: int, n_slots: int, pages_per_slot: int, n_state_rows: int = 0
+        self, n_pages: int, page_size: int, n_slots: int, pages_per_slot: int, n_state_rows: int = 0,
+        window: tuple[int, int, int] | None = None,
     ):
+        """``window`` = (pages, window tokens, ring pages) gives the pool its
+        second page kind (``WindowPages``, ``self.win``)."""
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         floor = max(pages_per_slot + 2, n_slots + 1)
@@ -121,6 +369,10 @@ class PageAllocator:
         # capture and bound to its pin
         self.n_state_rows = int(n_state_rows)
         self._state_free: list[int] = list(range(n_slots + self.n_state_rows - 1, n_slots - 1, -1))
+        self.win = None
+        if window is not None:
+            self.win = WindowPages(window[0], page_size, n_slots, pages_per_slot, window[1], window[2])
+            self.win.on_empty = lambda: self._reclaim_until_free(self.win)
         # called ONCE per reclaim wave with the list of reclaimed pin ids
         # (batched so the owner — the prefix index — hears of a wave
         # once, not once per pin, on the hot decode path)
@@ -168,6 +420,13 @@ class PageAllocator:
             "cow_total": self.stat_cow_copies,
             "pin_reclaims": self.stat_pin_reclaims,
             "state_rows_free": len(self._state_free),
+            **(
+                {
+                    "win_free": self.win.free_pages, "win_live": self.win.live_pages,
+                    "win_written": self.win.stat_written, "win_released": self.win.stat_released,
+                }
+                if self.win is not None else {}
+            ),
         }
 
     def pages_for(self, tokens: int) -> int:
@@ -219,9 +478,11 @@ class PageAllocator:
         rows = sorted(bound + self._state_free)
         if rows != list(range(self.n_slots, self.n_slots + self.n_state_rows)):
             raise AssertionError("snapshot rows diverged: each is free or bound to one pin")
+        if self.win is not None:
+            self.win.check(self._pins.values())
 
     # ----------------------------------------------------------- admission
-    def try_admit(self, slot: int, shared_pages, reuse: int, extra_reserve: int = 0) -> bool:
+    def try_admit(self, slot: int, shared_pages, reuse: int, extra_reserve: int = 0, pin_id: int = -1) -> bool:
         """Admit a sequence into ``slot``: map its matched prefix pages
         (refcount bump — the copy-free share) and reserve its worst-case
         exclusive page need. Returns False — mapping nothing — when the
@@ -232,7 +493,10 @@ class PageAllocator:
         are exempt from the reservation (the partial boundary page will be
         copy-on-written at the first divergent write). ``extra_reserve``
         covers CoW the caller knows is coming (a cache_prefix capture hint
-        pinning pages mid-generation)."""
+        pinning pages mid-generation). With a window kind, ``pin_id`` names
+        the matched entry's pin, whose pages of the last window before
+        ``reuse`` are mapped beside the full kind's, and the slot's ring is
+        reserved there: both kinds admit, or neither maps anything."""
         if self._mapped[slot] or self._reserved[slot]:
             raise RuntimeError(f"slot {slot} admitted while still mapped")
         n_map = self.pages_for(reuse) if reuse > 0 else 0
@@ -243,6 +507,14 @@ class PageAllocator:
         avail = self.free_pages + self._reclaimable(exclude=shared)
         if avail - self.reserved_total() < need:
             return False
+        if self.win is not None:
+            pin = self._pins.get(pin_id) if reuse > 0 else None
+            win_shared = self.win.shared_for(pin, reuse)
+            if win_shared is None or (reuse > 0 and pin is None):
+                raise ValueError("the matched entry's pin does not hold the window before reuse (pin_covers)")
+            if self.win.headroom(exclude=win_shared) < self.win.ring:
+                return False
+            self.win.admit(slot, win_shared, reuse)
         for lp, p in enumerate(shared):
             self.block_tables[slot, lp] = p
             self.refs[p] += 1
@@ -250,6 +522,15 @@ class PageAllocator:
         self._reserved[slot] = need
         self.stat_pages_shared += n_map
         return True
+
+    def pin_covers(self, pin_id: int, reuse: int) -> bool:
+        """Whether a hit at ``reuse`` tokens can be served from this pin: always
+        with one page kind; with a window kind, where the pin holds the
+        window-kind pages of the whole last window before ``reuse``."""
+        if self.win is None:
+            return True
+        pin = self._pins.get(pin_id)
+        return pin is not None and self.win.shared_for(pin, reuse) is not None
 
     # ---------------------------------------------------------- allocation
     def _alloc(self, slot: int) -> int:
@@ -265,9 +546,12 @@ class PageAllocator:
         self._reserved[slot] -= 1
         return p
 
-    def _reclaim_until_free(self) -> None:
+    def _reclaim_until_free(self, kind=None) -> None:
+        """Drop prefix pins, LRU first, until ``kind`` (this allocator, or
+        its window kind) has a free page."""
+        kind = kind or self
         reclaimed: list[int] = []
-        while not self._free and self._pins:
+        while not kind._free and self._pins:
             # prefer the LRU pin that actually FREES a page (one whose
             # pages include a refs==1 page): dropping a pin whose pages
             # live readers still map would destroy a prefix entry without
@@ -276,14 +560,14 @@ class PageAllocator:
             # needs both dropped — still progress).
             freeing = [
                 p for p in self._pins.values()
-                if any(self.refs[pg] == 1 for pg in p.pages)
+                if any(kind.refs[pg] == 1 for pg in (p.pages if kind is self else p.win_pages))
             ]
             pin = min(freeing or self._pins.values(), key=lambda q: q.last_use)
             self._drop_pin(pin, reclaim=True)
             reclaimed.append(pin.pin_id)
         if reclaimed and self.on_pins_reclaimed is not None:
             self.on_pins_reclaimed(reclaimed)
-        if not self._free:
+        if not kind._free:
             raise RuntimeError(
                 "kv page pool exhausted with nothing reclaimable — "
                 "reservation invariant broken (bug)"
@@ -292,8 +576,10 @@ class PageAllocator:
     def prepare_write(self, slot: int, start: int, count: int) -> list[tuple[int, int]]:
         """Make positions [start, start + count) writable by ``slot``:
         allocate not-yet-mapped logical pages and copy-on-write shared
-        ones. Returns the (src, dst) page copies the caller MUST dispatch
-        (through the pool's copy ladder) before its write dispatch.
+        ones, in both page kinds where there are two (the window kind first
+        gives back the pages the write's queries no longer see). Returns the
+        (src, dst) page copies, (src, dst, 1) in the window kind, the caller
+        MUST dispatch (through the pool's copy ladder) before its write dispatch.
         Positions beyond the slot's virtual length are ignored — the
         device-side write mask junk-redirects them to page 0."""
         ps = self.page_size
@@ -307,7 +593,10 @@ class PageAllocator:
                 "chaos: induced allocator OOM (page budget exhausted by "
                 f"fault injection) preparing write for slot {slot}"
             )
-        copies: list[tuple[int, int]] = []
+        copies: list[tuple] = []
+        if self.win is not None:
+            # the window kind's pages of the same positions: (src, dst, 1) copies
+            copies += self.win.prepare_write(slot, int(start), end)
         bt = self.block_tables
         for lp in range(int(start) // ps, (end - 1) // ps + 1):
             if lp >= self._mapped[slot]:
@@ -337,16 +626,21 @@ class PageAllocator:
         self.block_tables[slot, :] = 0
         self._mapped[slot] = 0
         self._reserved[slot] = 0
+        if self.win is not None:
+            self.win.retire(slot)
 
     # -------------------------------------------------------- prefix pins
     def capture(self, slot: int, length: int) -> PoolPin | None:
         """Pin the pages covering the slot's leading ``length`` tokens as a
         prefix entry — a refcount bump, NO copy (the old capture dispatch
-        is gone). Returns None if the span isn't materialized yet."""
+        is gone). Returns None if the span isn't materialized yet, or, with
+        a window kind, if the slot has moved past the span's last window."""
         n = self.pages_for(length)
         if n < 1 or n > self._mapped[slot]:
             return None
         pin = PoolPin(self._next_pin, self.slot_pages(slot)[:n])
+        if self.win is not None and not self.win.capture(slot, length, pin):
+            return None
         self._next_pin += 1
         self._clock += 1
         pin.last_use = self._clock
@@ -365,7 +659,7 @@ class PageAllocator:
         the pin-only (reclaimable) set, so ``free + reclaimable`` is
         constant."""
         n = int(n)
-        if n < 1 or n > len(self._free):
+        if n < 1 or n > len(self._free) or self.win is not None:  # a pin of one kind's pages serves no hit here
             return None
         pages = [self._free.pop() for _ in range(n)]
         pin = PoolPin(self._next_pin, pages)
@@ -409,6 +703,8 @@ class PageAllocator:
         del self._pins[pin.pin_id]
         if pin.state_row >= 0:
             self._state_free.append(pin.state_row)
+        if self.win is not None:
+            self.win.drop_pin(pin)
         freed = 0
         for p in pin.pages:
             self.pin_count[p] -= 1
@@ -446,7 +742,14 @@ class PagedKVPool:
         kv_init=paged_kv_init,
         state_init=None,
         n_state_rows: int = 0,
+        window: int = 0,
+        max_write: int = 0,
+        n_prefix: int = 0,
     ):
+        """``window`` > 0 (the family's ``decoder_dims``' ``kv_window``) gives
+        the pool its second page kind, sized from the slots' rings
+        (``max_write``: the most positions one dispatch writes a slot) and
+        ``n_prefix`` prefix entries' last windows (``window_pool_pages``)."""
         import jax.numpy as jnp
 
         if kv_dtype not in ("", "int8"):
@@ -469,12 +772,16 @@ class PagedKVPool:
         self.n_state_rows = int(n_state_rows) if state_init is not None else 0
         self.zero_row = self.n_slots + self.n_state_rows
         self.drop_row = self.zero_row + 1
-        self.alloc = PageAllocator(
-            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot, self.n_state_rows
-        )
-        self.state = self._place(
-            kv_init(params, self.n_pages, self.page_size, self._dtype, kv_dtype)
-        )
+        # the window page kind (module docstring): (pages, window, ring) | None
+        self._window = None
+        if window > 0:
+            write = min(int(max_write) or int(cache_ctx), int(cache_ctx))
+            self._window = (
+                window_pool_pages(self.n_slots, int(n_prefix), window, write, self.page_size),
+                int(window), min(ring_pages(window, write, self.page_size), self.pages_per_slot),
+            )
+        self.alloc = self._new_alloc()
+        self.state = self._place(self._kv_zeros())
         self.recurrent = self._recurrent_zeros()
         # tensor-parallel decode (parallel/tp.py): the scheduler hands a
         # per-buffer sharding resolver so the pool state is committed to
@@ -493,13 +800,44 @@ class PagedKVPool:
             if self.state_shardings is not None
             else {}
         )
-        self._copy_fn = jax.jit(paged_copy, donate_argnums=(0,), **copy_kw)
+        if self._window is None:
+            self._copy_fns = (jax.jit(paged_copy, donate_argnums=(0,), **copy_kw),)
+        else:
+            # a copy addresses ONE kind's page axis: its half of the state tuple
+            # (full planes first), the other half passing through the donation
+            half = len(self.state) // 2
+
+            def copy_full(pool, src, dst):
+                return paged_copy(pool[:half], src, dst) + tuple(pool[half:])
+
+            def copy_window(pool, src, dst):
+                return tuple(pool[:half]) + paged_copy(pool[half:], src, dst)
+
+            self._copy_fns = tuple(jax.jit(f, donate_argnums=(0,), **copy_kw) for f in (copy_full, copy_window))
         buckets, b = [], 1
         while b < self.n_slots:
             buckets.append(b)
             b *= 2
         self.copy_buckets = tuple(buckets) + (self.n_slots,)
         self.stat_copy_dispatches = 0
+
+    @property
+    def windowed(self) -> bool:
+        """Whether the pool holds the window page kind beside the full one."""
+        return self._window is not None
+
+    @property
+    def n_window_pages(self) -> int:
+        return self._window[0] if self._window is not None else 0
+
+    def _new_alloc(self) -> PageAllocator:
+        return PageAllocator(
+            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot, self.n_state_rows, self._window
+        )
+
+    def _kv_zeros(self) -> tuple:
+        n_pages = self.n_pages if self._window is None else (self.n_pages, self._window[0])
+        return self._kv_init(self._params, n_pages, self.page_size, self._dtype, self.kv_dtype)
 
     def _recurrent_zeros(self) -> tuple:
         if self._state_init is None:
@@ -526,55 +864,58 @@ class PagedKVPool:
                 out[2, r] = (snap or {}).get(slot, self.drop_row)
         return out
 
-    def block_tables(self, slots: np.ndarray | None = None) -> np.ndarray:
+    def block_tables(self, slots: np.ndarray | None = None, junk=None):
         """Fresh host copy of the block tables for one dispatch (the jit
         argument must not alias the live allocator state): every slot's
-        row, or the rows of ``slots`` in their order, a row of junk page 0
-        for each -1 among them (a compact dispatch's padding)."""
-        bt = self.alloc.block_tables
+        row (those ``junk`` [n_slots] bool marks as rows of junk page 0), or
+        the rows of ``slots`` in their order, a row of junk page 0 for each
+        -1 among them (a compact dispatch's padding). With a window kind,
+        ONE array ``[2, rows, max_pages]``, the full kind's table then the
+        window kind's: one transfer a dispatch, as with one kind."""
+        win = self.alloc.win
+        bt = self.alloc.block_tables if win is None else np.stack([self.alloc.block_tables, win.block_tables])
         if slots is None:
-            return bt.copy()
-        return np.where(slots[:, None] >= 0, bt[slots], bt.dtype.type(0))
+            bt = bt.copy() if win is None else bt  # np.stack copied already
+            if junk is not None:
+                bt[..., junk, :] = 0
+            return bt
+        return np.where(slots[:, None] >= 0, bt[..., slots, :], bt.dtype.type(0))
 
-    def run_copies(self, copies: list[tuple[int, int]]) -> None:
+    def run_copies(self, copies: list[tuple]) -> None:
         """Dispatch the round's CoW page copies through the warmed ladder
-        (padding entries copy junk page 0 onto itself)."""
-        i = 0
-        while i < len(copies):
-            batch = copies[i : i + self.copy_buckets[-1]]
-            bucket = next(b for b in self.copy_buckets if b >= len(batch))
-            src = np.zeros(bucket, np.int32)
-            dst = np.zeros(bucket, np.int32)
-            for j, (s, d) in enumerate(batch):
-                src[j] = s
-                dst[j] = d
-            self.state = self._copy_fn(self.state, src, dst)
-            self.stat_copy_dispatches += 1
-            i += len(batch)
+        (padding entries copy junk page 0 onto itself), a kind at a time:
+        (src, dst) in the full kind, (src, dst, 1) in the window kind."""
+        for kind, copy_fn in enumerate(self._copy_fns):
+            mine = [c[:2] for c in copies if (len(c) > 2 and c[2]) == kind]
+            i = 0
+            while i < len(mine):
+                batch = mine[i : i + self.copy_buckets[-1]]
+                bucket = next(b for b in self.copy_buckets if b >= len(batch))
+                src = np.zeros(bucket, np.int32)
+                dst = np.zeros(bucket, np.int32)
+                for j, (s, d) in enumerate(batch):
+                    src[j] = s
+                    dst[j] = d
+                self.state = copy_fn(self.state, src, dst)
+                self.stat_copy_dispatches += 1
+                i += len(batch)
 
     def warmup(self) -> None:
         """Compile the copy ladder (page0 -> page0 self-copies touch no
         live bytes)."""
-        for b in self.copy_buckets:
-            self.state = self._copy_fn(
-                self.state, np.zeros(b, np.int32), np.zeros(b, np.int32)
-            )
+        for copy_fn in self._copy_fns:
+            for b in self.copy_buckets:
+                self.state = copy_fn(self.state, np.zeros(b, np.int32), np.zeros(b, np.int32))
 
     def compile_count(self) -> int:
-        return self._copy_fn._cache_size()
+        return sum(f._cache_size() for f in self._copy_fns)
 
     def reset(self) -> None:
         """Post-failure recovery: the state tuple was donated into a call
         that raised, so its buffers may be invalidated — reallocate, and
         drop every host mapping with it."""
         on_reclaimed = self.alloc.on_pins_reclaimed
-        self.alloc = PageAllocator(
-            self.n_pages, self.page_size, self.n_slots, self.pages_per_slot, self.n_state_rows
-        )
+        self.alloc = self._new_alloc()
         self.alloc.on_pins_reclaimed = on_reclaimed
-        self.state = self._place(
-            self._kv_init(
-                self._params, self.n_pages, self.page_size, self._dtype, self.kv_dtype
-            )
-        )
+        self.state = self._place(self._kv_zeros())
         self.recurrent = self._recurrent_zeros()

@@ -456,7 +456,19 @@ class FlightFrame:
     dispatch; ``chunk_rows_kernel`` of the round's ``chunk_rows_live``, those
     whose attention ran in a chunk kernel (``DecodePrograms.chunk_attn``:
     static a program, so all of a dispatch's or none), 0 where the chunk
-    walked or gathered; ``ingress_ns`` / ``ingress_requests`` the submits that reached
+    walked or gathered; ``kv_win_live`` where the pool has a window page
+    kind (serving/kv_pool.py ``WindowPages``: a family with sliding-window
+    layers) the window-kind pages some slot maps at the commit (``kv_live``
+    then reads the full kind alone), ``kv_win_written`` the window-kind pages
+    slots allocated in the round and ``kv_win_released`` those of their own
+    they gave back in it because they moved past them, a retirement's not
+    counted; 0 / 0 / 0 in a pool of one kind; ``step_counts`` a counting
+    family's counts (its ``frame_counters``, in their order) of the round's
+    STEP dispatch alone, where one ran: the named fields hold the sum of the
+    round's dispatches, and where every round rides a chunk (a saturated
+    closed loop) no round's sum is a step's; () where no step ran or the
+    family counts nothing, and in the dump only beside a chunk dispatch;
+    ``ingress_ns`` / ``ingress_requests`` the submits that reached
     the queue during the round and their summed time on the event loop from
     the request's bytes in hand (``Ingress``: body parse, message build, the
     hops to ``submit``), 0 / 0 for callers that hand ``submit`` no mark."""
@@ -476,6 +488,7 @@ class FlightFrame:
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
         "chunk_rows_held", "chunk_rows_kernel",
+        "kv_win_live", "kv_win_released", "kv_win_written", "step_counts",
     )
 
     def __init__(
@@ -493,6 +506,7 @@ class FlightFrame:
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
         chunk_rows_held=0, chunk_rows_kernel=0,
+        kv_win_live=0, kv_win_released=0, kv_win_written=0, step_counts=(),
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -546,6 +560,10 @@ class FlightFrame:
         self.attn_run_pages = attn_run_pages
         self.chunk_rows_held = chunk_rows_held
         self.chunk_rows_kernel = chunk_rows_kernel
+        self.kv_win_live = kv_win_live
+        self.kv_win_released = kv_win_released
+        self.kv_win_written = kv_win_written
+        self.step_counts = step_counts
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -634,6 +652,10 @@ class FlightFrame:
             d["mla_pages"] = [self.mla_run_pages, self.mla_pages_read]
         if self.mhc_resid_ppm:
             d["mhc_resid_ppm"] = self.mhc_resid_ppm
+        if self.kv_win_live or self.kv_win_written or self.kv_win_released:
+            d["kv_win"] = [self.kv_win_live, self.kv_win_released, self.kv_win_written]
+        if self.step_counts and self.chunk_rows:
+            d["step_counts"] = list(self.step_counts)
         return d
 
 

@@ -822,3 +822,21 @@ async def test_flight_and_profile_query_validation():
         assert (await r.json())["hz"] == 200.0
     finally:
         await client.close()
+
+
+@pytest.mark.parametrize("kw, want", [
+    ({}, None),
+    ({"kv_win_live": 12}, [12, 0, 0]),
+    ({"kv_win_live": 98, "kv_win_released": 3, "kv_win_written": 4}, [98, 3, 4]),
+    ({"kv_win_released": 2}, [0, 2, 0]),
+], ids=["one_page_kind", "live_only", "a_round_that_wrote_and_gave_back", "the_last_slot_retiring"])
+def test_frame_window_kind_counts_round_trip(kw, want):
+    """The window page kind's three counts default to 0, read back as given,
+    and show in the dump only where one is set (a pool of one kind never)."""
+    f = _frame(0, **kw)
+    assert (f.kv_win_live, f.kv_win_released, f.kv_win_written) == tuple(
+        kw.get(k, 0) for k in ("kv_win_live", "kv_win_released", "kv_win_written"))
+    assert f.to_dict().get("kv_win") == want and f.to_dict()["kv"] == [3, 2, 0]  # kv stays the full kind's
+    rec = FlightRecorder(n_slots=4, name="t", capacity=4, enabled=True)
+    rec.record(f)
+    assert rec.snapshot()[0].to_dict().get("kv_win") == want

@@ -425,7 +425,9 @@ def test_the_other_families_keep_their_own_fused_programs(weights):
     step, chunk = gpt2_family.fused_programs()
     assert step is _fused_step and chunk is _fused_chunk and gpt2_family.state_init is None
     moe = md.moe_family(md.MoEDecoderConfig())
-    assert moe.state_init is None and {"kv_int8", "host_tier", "prefix_export"} <= moe.serves
+    # two page kinds (sliding layers): the int8 pool; the tiers and prefix export where there is one kind
+    assert moe.state_init is None and moe.serves == {"kv_int8"} and moe.cfg.two_kinds
+    assert {"kv_int8", "host_tier", "prefix_export"} <= md.moe_family(md.MoEDecoderConfig(period=1)).serves
     hstep, hchunk = FAM.fused_programs()
     assert (hstep.__name__, hchunk.__name__) == ("_fused_step", "_fused_chunk")  # one name in a trace
     assert hd.hybrid_family(hd.HybridDecoderConfig(**vars(CFG))).fused_programs() == (hstep, hchunk)
@@ -444,8 +446,11 @@ def test_the_pool_has_the_layers_that_hold_kv(dims_of, weights):
     d = fam.decoder_dims(params)
     want = len(ATTN) if dims_of == "hybrid" else d["layers"]
     assert d["kv_layers"] == want
-    assert fam.paged_kv_init(params, 3, 4)[0].shape == (want, 3, 4, d["kv_heads"] * d["head_dim"])
-    assert fam.paged_kv_init(params, 3, 4, kv_dtype="int8")[1].shape == (want, 3, 4)
+    full = want - d.get("kv_window_layers", 0)  # sliding layers' pages are a kind of their own, after the full kind's planes
+    assert fam.paged_kv_init(params, 3, 4)[0].shape == (full, 3, 4, d["kv_heads"] * d["head_dim"])
+    assert fam.paged_kv_init(params, 3, 4, kv_dtype="int8")[1].shape == (full, 3, 4)
+    if dims_of == "moe":
+        assert (full, d["kv_window_layers"]) == (1, 3) and fam.paged_kv_init(params, (3, 5), 4)[2].shape[:2] == (3, 5)
     if dims_of == "hybrid":
         rec = fam.state_init(params, 7)  # an array a Mamba layer: states, then conv inputs
         assert [a.shape for a in rec] == [(7, 8, 16, 16)] * 4 + [(7, 3 * (8 * 16 + 2 * 16))] * 4

@@ -801,3 +801,169 @@ async def test_kv_pool_serving_wiring_metrics_and_spans():
         await sched.close()
         if server.batcher is not None:
             await server.batcher.close()
+
+
+# ------------------------------------------------- the window page kind (PR 47)
+
+
+def _two_kinds(n_slots=4, ps=4, pps=12, window=8, max_write=8, n_prefix=2, n_pages=0):
+    from seldon_core_tpu.serving.kv_pool import ring_pages, window_pool_pages
+
+    n_win = window_pool_pages(n_slots, n_prefix, window, max_write, ps)
+    return PageAllocator(
+        n_pages or n_slots * pps + 2, ps, n_slots, pps,
+        window=(n_win, window, min(ring_pages(window, max_write, ps), pps)),
+    )
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_window_kind_invariants_random_admit_write_capture_retire_sequences(seed):
+    """The soak of the one-kind allocator over both kinds: admissions (cold,
+    and hits at the entry's whole length), writes of 1 to a chunk's positions
+    (the window kind gives pages back inside ``prepare_write``), captures where
+    the slot stands, retirements, releases, with both kinds' audit throughout;
+    a slot never maps more window-kind pages than its ring, and both pools come
+    back whole."""
+    rng = np.random.default_rng(seed)
+    n_slots, ps, pps, window, max_write = 4, 4, 12, 8, 8
+    alloc = _two_kinds(n_slots, ps, pps, window, max_write)
+    cursor = [-1] * n_slots
+    forked = [False] * n_slots
+    pins: list = []  # (pin, its span's length)
+    for step in range(4000):
+        free_slots = [s for s in range(n_slots) if cursor[s] < 0]
+        busy = [s for s in range(n_slots) if 0 <= cursor[s]]
+        r = rng.random()
+        if r < 0.25 and free_slots:
+            slot = int(rng.choice(free_slots))
+            pin, length = pins[int(rng.integers(len(pins)))] if pins and rng.random() < 0.6 else (None, 0)
+            if pin is not None and not alloc.pin_covers(pin.pin_id, length):
+                pin, length = None, 0
+            ok = alloc.try_admit(slot, pin.pages if pin else (), length, extra_reserve=1,
+                                 pin_id=pin.pin_id if pin else -1)
+            if ok:
+                cursor[slot], forked[slot] = length, False
+        elif r < 0.70 and busy:
+            slot = int(rng.choice(busy))
+            count = min(int(rng.integers(1, max_write + 1)), pps * ps - cursor[slot])
+            if count > 0:
+                for src, dst, *_kind in alloc.prepare_write(slot, cursor[slot], count):
+                    assert src != dst and dst != 0
+                cursor[slot] += count
+                assert len(alloc.win.slot_pages(slot)) <= alloc.win.ring
+        elif r < 0.82 and busy:
+            slot = int(rng.choice(busy))
+            if cursor[slot] >= 1 and not forked[slot]:
+                pin = alloc.capture(slot, cursor[slot])  # where the slot stands: its last window is mapped
+                if pin is not None:
+                    pins.append((pin, cursor[slot]))
+                    forked[slot] = True
+                    assert len(pin.win_pages) <= -(-window // ps) + 1
+        elif r < 0.93 and busy:
+            slot = int(rng.choice(busy))
+            alloc.retire(slot)
+            cursor[slot] = -1
+        elif pins:
+            pin, _ = pins.pop(int(rng.integers(len(pins))))
+            alloc.release(pin.pin_id)
+        if step % 25 == 0:
+            pins = [(p, n) for p, n in pins if p.pin_id in alloc._pins]
+            alloc.check()
+    for slot in range(n_slots):
+        if cursor[slot] >= 0:
+            alloc.retire(slot)
+    for pin, _ in pins:
+        alloc.release(pin.pin_id)
+    alloc.check()
+    assert alloc.free_pages == alloc.n_pages - 1 and alloc.win.free_pages == alloc.win.n_pages - 1
+    assert alloc.win.stat_written > alloc.win.stat_released > 0
+
+
+@pytest.mark.parametrize("write", [1, 3, 8], ids=["steps", "by3", "chunks"])
+def test_window_kind_holds_a_ring_whatever_the_context(write):
+    """One slot through its whole context: the full kind maps every page, the
+    window kind the pages of [position - window + 1, position) and no more than
+    its ring; a page wholly older is back on the free list before the next is
+    taken, so another slot's write can have it in the same round."""
+    alloc = _two_kinds(n_slots=2, pps=12, window=8, max_write=8)
+    assert alloc.try_admit(0, (), 0) and alloc.try_admit(1, (), 0)
+    assert (alloc.win._reserved[0], alloc._reserved[0]) == (alloc.win.ring, 12)
+    pos = 0
+    while pos < 48:
+        n = min(write, 48 - pos)
+        assert alloc.prepare_write(0, pos, n) == []
+        pos += n
+        mapped = alloc.win.slot_pages(0)
+        assert alloc.win._lo[0] == max(0, pos - n - 8 + 1) // 4 and alloc.win._hi[0] == -(-pos // 4)
+        assert len(mapped) <= alloc.win.ring and 0 not in mapped
+        assert not alloc.win.block_tables[0, : alloc.win._lo[0]].any()  # given back: the table reads junk page 0
+        alloc.check()
+    assert len(alloc.slot_pages(0)) == 12 and alloc.win.stat_released == alloc.win.stat_written - len(alloc.win.slot_pages(0))
+    freed = set(range(1, alloc.win.n_pages)) - set(alloc.win.slot_pages(0))
+    alloc.prepare_write(1, 0, 8)
+    assert set(alloc.win.slot_pages(1)) <= freed  # handed to another slot
+    snap = alloc.snapshot()
+    assert (snap["win_live"], snap["win_written"], snap["win_released"]) == (
+        alloc.win.live_pages, alloc.win.stat_written, alloc.win.stat_released)
+
+
+@pytest.mark.parametrize("length", [16, 18], ids=["page_end", "mid_page"])
+def test_window_kind_pin_holds_the_last_window_and_cow_works_on_both_kinds(length):
+    """A capture pins every full-kind page of its span and the window-kind
+    pages of its last window; a hit maps exactly those; the writer and the
+    reader each copy the boundary page in BOTH kinds where the span ends inside
+    one; a hit at another length is not served; dropping the pin frees both."""
+    alloc = _two_kinds(n_slots=3, pps=12, window=8, max_write=8)
+    assert alloc.try_admit(0, (), 0, extra_reserve=1)
+    for pos in range(0, length, 6):
+        alloc.prepare_write(0, pos, min(6, length - pos))
+    pin = alloc.capture(0, length)
+    assert len(pin.pages) == -(-length // 4) and pin.win_first == (length - 8) // 4
+    assert len(pin.win_pages) == -(-length // 4) - pin.win_first
+    assert alloc.pin_covers(pin.pin_id, length) and not alloc.pin_covers(pin.pin_id, length - 8)
+    with pytest.raises(ValueError, match="pin_covers"):
+        alloc.try_admit(1, pin.pages, length - 8, pin_id=pin.pin_id)
+    assert alloc.try_admit(1, pin.pages, length, pin_id=pin.pin_id)
+    assert alloc.win.slot_pages(1) == pin.win_pages and alloc.slot_pages(1) == pin.pages
+    want = 2 * (length % 4 != 0)  # one copy a kind
+    for slot in (0, 1):
+        copies = alloc.prepare_write(slot, length, 3)
+        assert len(copies) == want and sorted(len(c) for c in copies) == [2, 3][: want]
+        alloc.check()
+    assert alloc.win.reclaimable() == (length % 4 != 0)  # both still map the pin's older pages; each has its own boundary page
+    alloc.retire(0)
+    alloc.retire(1)
+    assert alloc.win.reclaimable() == len(pin.win_pages) and alloc.capture(1, length) is None
+    alloc.release(pin.pin_id)
+    alloc.check()
+    assert alloc.win.free_pages == alloc.win.n_pages - 1 and alloc.free_pages == alloc.n_pages - 1
+
+
+def test_window_kind_admission_is_by_kind_and_pressure_reclaims_pins():
+    """Admission asks both kinds: with the window kind's pages promised away
+    the third slot waits though the full kind has room; a prefix's pin-only
+    pages count as reclaimable and go, LRU first, when a write finds the free
+    list empty (the owner hears of it once)."""
+    from seldon_core_tpu.serving.kv_pool import WindowPages
+
+    alloc = _two_kinds(n_slots=3, pps=12, window=8, max_write=8, n_prefix=0)
+    alloc.win = WindowPages(2 * alloc.win.ring + 2, 4, 3, 12, 8, alloc.win.ring)  # two rings + junk + slack
+    alloc.win.on_empty = lambda: alloc._reclaim_until_free(alloc.win)
+    heard = []
+    alloc.on_pins_reclaimed = heard.append
+    assert alloc.try_admit(0, (), 0) and alloc.try_admit(1, (), 0)
+    assert not alloc.try_admit(2, (), 0) and alloc.free_pages - alloc.reserved_total() >= 12
+    assert not alloc._mapped[2] and not alloc._reserved[2] and not alloc.win._reserved[2]  # neither kind mapped anything
+    alloc.retire(1)
+    alloc.prepare_write(0, 0, 8)
+    pin = alloc.capture(0, 8)
+    alloc.retire(0)  # the pin alone holds two window-kind pages now: reclaimable
+    assert alloc.win.reclaimable() == 2 and alloc.try_admit(1, (), 0) and alloc.try_admit(2, (), 0)
+    for start, n in ((0, 1), (1, 8), (9, 8)):  # from a page's second row: both slots reach their whole ring
+        for slot in (1, 2):
+            alloc.prepare_write(slot, start, n)
+        alloc.check()
+    assert len(alloc.win.slot_pages(1)) == len(alloc.win.slot_pages(2)) == alloc.win.ring
+    assert heard == [[pin.pin_id]] and alloc.stat_pin_reclaims == 1 and pin.pin_id not in alloc._pins
+    with pytest.raises(RuntimeError, match="past its ring"):
+        alloc.win.prepare_write(1, 17, 48)  # a write wider than the ring was sized for

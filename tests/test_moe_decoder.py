@@ -180,7 +180,9 @@ def test_rows_older_than_the_window(weights, layer, moves):
     before = run(pool)
     # positions 0..27 (pages 0..6 of the slot) are older than 39 - 8: overwrite them in this layer
     old = jnp.asarray(pages[:7])
-    junk = tuple(a.at[layer, old].set(7.0) for a in pool)
+    # the layer's planes are its page kind's half of the state tuple (full kind first), under its index there
+    kind = (0, 1) if CFG.is_full(layer) else (2, 3)
+    junk = tuple(a.at[CFG.plane_layer(layer), old].set(7.0) if i in kind else a for i, a in enumerate(pool))
     after = run(junk)
     assert bool(jnp.allclose(before, after, atol=1e-6)) is not moves
 
@@ -360,6 +362,11 @@ async def test_scheduler_serves_the_family_counts_real_rows_and_never_recompiles
     assert sched.stat_prefix_hits == 4
     assert sched.recompiles_since_warmup() == 0
     frames = sched.flight.snapshot()
+    # the three sliding layers' pages are a kind of their own (PR 47): given back past the window, all back at the end
+    win = sched.pool.alloc.win
+    assert sched.pool.windowed and win.live_pages == 0 and 0 < win.stat_released < win.stat_written
+    assert sum(f.kv_win_released for f in frames) == win.stat_released and max(f.kv_win_live for f in frames) > 0
+    sched.pool.alloc.check()
     layers, experts = 4, 8
     steps = [f for f in frames if f.busy_ns[0] == 0 and f.moe_rows]
     assert steps
@@ -412,7 +419,10 @@ def test_decoder_dims_say_the_pool_row_for_both_families(dims_of, weights):
         d = FAM.decoder_dims(weights[jnp.float32])
         assert (d["heads"], d["kv_heads"], d["head_dim"], d["q_width"]) == (4, 2, 16, 64)
         pool = FAM.paged_kv_init(weights[jnp.float32], 3, 4)
-    assert pool[0].shape == (d["layers"], 3, 4, d["kv_heads"] * d["head_dim"])
+    # the sliding layers' pages are a kind of their own: the full kind's planes hold the full layers
+    assert pool[0].shape == (d["layers"] - d.get("kv_window_layers", 0), 3, 4, d["kv_heads"] * d["head_dim"])
+    if dims_of == "moe":
+        assert (d["kv_window_layers"], d["kv_window"], len(pool)) == (6, 8, 4) and pool[2].shape[0] == 6
 
 
 @pytest.mark.parametrize("what", ["draft", "spec_tree", "tp", "tp_problems", "gpt2_dims", "moe_dims"])
@@ -440,3 +450,47 @@ def test_what_the_family_does_not_serve_is_refused_by_name(what, weights):
 def test_heads_of_64_are_named_as_gpt2s_convention():
     with pytest.raises(ValueError, match="GPT-2's head_dim-64 convention"):
         init_decoder(hidden=200)
+
+
+# (g) what PR 47 added to the block, piece by piece (the whole of it: tests/test_window_pages.py)
+
+
+@pytest.mark.parametrize("share", [0.25, 0.5, 1.0])
+def test_partial_rotary_turns_the_first_dimensions_and_passes_the_rest(share):
+    cfg = md.MoEDecoderConfig(head_dim=16, rotary_full=share, rope_theta=50000.0, rope_theta_window=10000.0)
+    rot = int(16 * share)
+    full, plain = md.rope_inv_freq(cfg, True), md.rope_inv_freq(cfg, False)
+    assert full.shape == (rot // 2,) and plain.shape == (8,)
+    want_plain, want_yarn = _closed_form(rot, 50000.0, cfg.yarn_factor, cfg.yarn_original, cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    np.testing.assert_allclose(full, want_yarn, rtol=1e-6)  # the ramp runs over the dimensions that rotate
+    np.testing.assert_allclose(plain, 10000.0 ** (-2.0 * np.arange(8) / 16), rtol=1e-6)  # the sliding layers' own theta
+    x = jax.random.normal(jax.random.key(0), (2, 3, 4, 16))
+    pos = jnp.array([[0, 1, 2], [7, 8, 9]], jnp.int32)
+    y = md._rope(x, pos, full, 1.25)
+    np.testing.assert_array_equal(np.asarray(y[..., rot:]), np.asarray(x[..., rot:]))  # untouched
+    np.testing.assert_allclose(np.asarray(y[0, 0, :, :rot]), 1.25 * np.asarray(x[0, 0, :, :rot]), rtol=1e-6)  # position 0: cos 1
+    assert not np.allclose(np.asarray(y[1, :, :, :rot]), 1.25 * np.asarray(x[1, :, :, :rot]))
+
+
+@pytest.mark.parametrize("key, value, message", [
+    ("heads_window", 5, "heads=5 not a multiple of kv_heads=2"),
+    ("rotary_full", 0.3, "must be even"),
+    ("dense_layers", 9, "dense_layers=9 of layers=4"),
+    ("dense_layers", 1, "dense_ffn=0"),
+    ("experts_held", 6, r"experts \[4, \+6\) of 8"),
+])
+def test_the_configuration_refuses_sizes_that_cannot_be(key, value, message):
+    with pytest.raises(ValueError, match=message):
+        md.MoEDecoderConfig(**{key: value, **({"first_expert": 4} if key == "experts_held" else {})})
+
+
+@pytest.mark.parametrize("pattern, want", [
+    (dict(layers=8, period=4), (False, [0, 1, 2, 0, 3, 4, 5, 1])),
+    (dict(layers=8, period=4, full_first=True), (True, [0, 0, 1, 2, 1, 3, 4, 5])),
+    (dict(layers=3, period=4), (False, [0, 1, 2])),  # sliding layers alone: one page kind, every layer its own index
+    (dict(layers=4, period=1), (True, [0, 1, 2, 3])),  # full layers alone
+], ids=["full_last", "full_first", "all_sliding", "all_full"])
+def test_a_layer_indexes_its_page_kinds_planes(pattern, want):
+    cfg = md.MoEDecoderConfig(**pattern)
+    assert (cfg.is_full(0), [cfg.plane_layer(i) for i in range(cfg.layers)]) == want
+    assert cfg.two_kinds == (0 < cfg.window_layers < cfg.layers)

@@ -975,3 +975,58 @@ def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, fam
     assert mem.alias_size_in_bytes >= nbytes(donated)
     # the arguments' bytes and their tile padding (the (n,) vectors round up), nothing else
     assert nbytes(args) <= mem.argument_size_in_bytes < nbytes(args) * 1.001 + 2**16
+
+
+@pytest.mark.parametrize("program", ["step", "chunk_4_256"])
+def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(topo, monkeypatch, program):
+    """The sparse-expert family as PR 47 left it, at the laguna-s-2.1 cell's
+    widths (48 / 72 query heads over 8 key/value heads of 128, the per-head
+    gate, half-rotary full layers, a dense layer, a shared expert and 32 of 256
+    routed ones) over its leading dense + full layer and two sliding layers:
+    the step of 64 slots and the (4, 256) chunk over 464-entry tables of BOTH
+    page kinds (14,000 full-kind pages, the window kind's derived count). Both
+    kinds' planes alias whole, and no float32 copy of a whole context exists
+    for a SLIDING layer: its gather is the window's pages."""
+    from seldon_core_tpu.models import moe_decoder as md
+    from seldon_core_tpu.ops import moe
+    from seldon_core_tpu.serving.kv_pool import window_pool_pages
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = md.MoEDecoderConfig(
+        vocab=12544, hidden=3072, layers=3, heads=48, heads_window=72, kv_heads=8, head_dim=128, ffn=1024, experts=256,
+        experts_per_tok=10, experts_held=32, window=512, period=4, full_first=True, rope_theta=500000.0,
+        rope_theta_window=10000.0, rotary_full=0.5, yarn_factor=128.0, yarn_original=8192, max_len=1048576,
+        attn_gate=True, dense_layers=1, dense_ffn=12288, shared_expert=True, routed_scale=2.5)
+    fam = md.moe_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: md.init_moe_decoder(cfg, 0, jnp.bfloat16)))
+    n_win = window_pool_pages(64, 2, 512, 256, 16)
+    assert n_win == 64 * 49 + 2 * 33 + 2
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, (14000, n_win), 16, jnp.bfloat16)))
+    assert [a.shape[:2] for a in pool] == [(1, 14000)] * 2 + [(2, n_win)] * 2
+    i32, f32 = jnp.int32, jnp.float32
+    step, chunk = fam.fused_programs()
+    n = 64 if program == "step" else 4
+    bt = (arr((n, 464), i32), arr((n, 464), i32))
+    tail = (arr((n,), f32), arr((n,), i32), arr((), i32), arr((), i32))
+    if program == "step":
+        fn, args = step, (params, pool, bt, arr((n,), i32), arr((n,), i32), *tail, arr((n,), jnp.bool_))
+    else:
+        fn, args = chunk, (params, pool, bt, arr((n, 256), i32), arr((n,), i32), arr((n,), i32), *tail)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    nbytes = lambda tree: sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in jax.tree.leaves(tree))  # noqa: E731
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    text = compiled.as_text()
+    for scope in ("attn_out/gate", "mlp/shared_expert", "mlp/dense", "win/kv_gather", "full/attn"):
+        assert f"/{scope}/" in text, scope
+    # a sliding layer's gathered cache is its window's pages (34 or 50 of them), never the table's 464
+    assert not re.findall(r"f32\[%d,8,7424,128\][^\n]*/win/" % n, text)
+    assert mem.temp_size_in_bytes < 3 << 30

@@ -480,3 +480,35 @@ def test_both_forms_of_the_pool_write_are_named(recorded):
                 seen.setdefault(e["kw"]["write"], set()).add((e["kw"]["c"], s.pool.page_size))
     assert set(seen) == {"page", "row"}
     assert all(c >= ps for c, ps in seen["page"]) and all(c < ps for c, ps in seen["row"])
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_the_sparse_expert_programs_nest_what_pr_47_added_under_the_old_scopes(program):
+    """The per-head gate under ``attn_out``, the shared expert, the held
+    experts and the dense layer under ``mlp`` by ``ops/moe.py``'s names,
+    ``win/*`` and ``full/*`` as they were: a reader of the nine old scopes
+    still sees all of the time, a reader of the finer ones can split it."""
+    from seldon_core_tpu.models import moe_decoder as md
+
+    cfg = md.MoEDecoderConfig(
+        vocab=96, hidden=64, layers=4, heads=4, heads_window=6, kv_heads=2, head_dim=16, ffn=32, experts=16,
+        experts_per_tok=3, experts_held=4, window=8, full_first=True, rotary_full=0.5, attn_gate=True, dense_layers=1,
+        dense_ffn=96, shared_expert=True, routed_scale=2.5)
+    fam = md.moe_family(cfg)
+    params = md.init_moe_decoder(cfg, 0, jnp.float32)
+    pool = fam.paged_kv_init(params, (8, 6), 4)
+    n = 2
+    bt = (jnp.zeros((n, 4), jnp.int32),) * 2
+    vec, temps = jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32)
+    step, chunk = fam.fused_programs()
+    if program == "step":
+        args = (params, pool, bt, vec, vec, temps, vec, 0, jnp.int32(1), jnp.ones((n,), bool))
+    else:
+        args = (params, pool, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1))
+    text = jax.jit(step if program == "step" else chunk).lower(*args).compile().as_text()
+    for scope in ("attn_out/gate", "mlp/shared_expert", "mlp/dense", "mlp/moe_router", "mlp/moe_experts", "mlp/moe_combine",
+                  "win/kv_gather", "win/attn", "full/attn", "qkv/rope"):
+        assert f"/{scope}/" in text, scope
+    for scope in decoder.PAGED_SCOPES:
+        assert f"/{scope}/" in text, scope
+    assert "/gate/" not in text.replace("/attn_out/gate/", "")  # the gate is nowhere but under attn_out
