@@ -32,7 +32,10 @@ nothing: ``ops/moe.py`` ``moe_held_ffn``).
 
 It serves through the SAME paged machinery as the GPT-2 family: token-row
 planes ``[L, pages, page_size, kv_heads * head_dim]`` written by
-``decoder._paged_write``, read by ``decoder._paged_gather``, copied by
+``decoder._paged_write``, read by ``decoder._paged_gather`` (the STEP, on a
+TPU, reads them where they lie instead: ops/gqa_decode.py
+``gqa_decode_attention``, where ``decode_programs._step_attn_kernel`` chooses
+it; chunks, a verify and the CPU keep the gather), copied by
 ``decoder.paged_copy``. A configuration with sliding layers has TWO PAGE
 KINDS (``decoder.kv_pool_zeros``, serving/kv_pool.py): the full layers'
 planes hold every position, the sliding layers' planes have pages of their
@@ -40,7 +43,9 @@ own under a block table of their own, and a page wholly older than the
 window is given back while the sequence runs. A sliding layer gathers
 through a WINDOWED block table: the pages that cover its queries' windows,
 taken from the slot's window-kind table by position (a page given back
-reads as junk page 0); the mask is by absolute key position.
+reads as junk page 0); the mask is by absolute key position. The step's
+kernel walks the same sub-table, with the in-table position of each slot's
+oldest visible key beside it (``gqa_decode.step_reads``' ``first``).
 
 A family is what ``serving/decode_scheduler.py`` takes from the model's
 spec (``ModelSpec.generative["family"]``) and asks (the list is
@@ -81,6 +86,7 @@ from seldon_core_tpu.models.decoder import (
     kv_pool_zeros,
     paged_greedy_generate,
 )
+from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention, pages_fetched, step_reads
 from seldon_core_tpu.ops.moe import (
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
@@ -90,6 +96,7 @@ from seldon_core_tpu.ops.moe import (
     moe_topk_ffn,
     route_topk,
 )
+from seldon_core_tpu.ops.paged_attention import window_first_page, window_pages
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
 # name so readers of those still see the time: ``qkv/rope``,
@@ -343,10 +350,10 @@ def _window_table(bt, positions, m: int, page_size: int, window: int):
     table by position, and the absolute position of their first key. A
     table no longer than that is returned whole."""
     n_log = bt.shape[1]
-    pw = -(-(window + m) // page_size) + 1
+    pw = window_pages(window, m, page_size)
     if pw >= n_log:
         return bt, jnp.zeros_like(positions)
-    p0 = jnp.clip((positions - (window - 1)) // page_size, 0, n_log - pw)
+    p0 = window_first_page(positions, window, page_size, n_log, pw)
     cols = p0[:, None] + jnp.arange(pw, dtype=p0.dtype)[None, :]
     return jnp.take_along_axis(bt, cols, axis=1), p0 * page_size
 
@@ -403,11 +410,61 @@ def _ffn(cfg: MoEDecoderConfig, p, h, valid):
     return y, cnt
 
 
-def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, valid):
+def _step_reads(cfg: MoEDecoderConfig, attn_kernel: str, queries: int, pool: tuple, bt, positions, rows):
+    """What this family's program hands ops/gqa_decode.py's kernel, ONCE a
+    layer kind for all the kind's layers (they walk the same table): ({full:
+    (table, lengths, runs[, first])}, the pages of one layer a kind that
+    come in run DMAs as int32[1]) where the program set chose a kernel
+    (``attn_kernel``) AND the dispatch has one query a slot against float
+    planes (two a kind), else (None, zero): the gather. A full layer's table
+    is its kind's whole; a sliding layer's the windowed sub-table, with the
+    first key a slot. What is left of the gather there, a few integers a
+    slot, stays under the kind's ``kv_gather`` scope."""
+    if not attn_kernel or queries != 1 or len(pool) != (4 if cfg.two_kinds else 2):
+        return None, jnp.zeros((1,), jnp.int32)
+    reads, in_runs = {}, jnp.zeros((), jnp.int32)
+    for full in sorted({cfg.is_full(i) for i in range(cfg.layers)}):
+        kind, table = _kind_pool(cfg, full, pool, bt)
+        ps = kind[0].shape[2]
+        with jax.named_scope(SCOPE_FULL if full else SCOPE_WIN), jax.named_scope(SCOPE_KV_GATHER):
+            if full:
+                r = step_reads(table, positions, rows, ps)
+            else:
+                table, k0 = _window_table(table, positions, 1, ps, cfg.window)
+                r = step_reads(table, positions, rows, ps, k0, cfg.window)
+            reads[full] = (table, *r)
+            in_runs = in_runs + pages_fetched(r[0], r[1], ps, table.shape[1])[1]
+    return reads, in_runs[None]
+
+
+def _gathered_attention(cfg: MoEDecoderConfig, full: bool, q, kind, pl: int, bt_k, positions, q_pos):
+    """A layer's attention through the gather: q[n, m, h, d] against layer
+    ``pl`` of its kind's planes, the whole table (a full layer) or the
+    windowed sub-table, masked by absolute key position."""
+    n, m, heads, _ = q.shape
+    if full:
+        bt_l, k0 = bt_k, jnp.zeros_like(positions)
+    else:
+        with jax.named_scope(SCOPE_KV_GATHER):
+            bt_l, k0 = _window_table(bt_k, positions, m, kind[0].shape[2], cfg.window)
+    ck, cv = _paged_gather(kind, pl, bt_l, cfg.kv_heads)  # [n, g, K, d] float32
+    with jax.named_scope(SCOPE_ATTN):
+        k_pos = k0[:, None] + jnp.arange(ck.shape[2], dtype=k0.dtype)[None, :]  # [n, K]
+        visible = k_pos[:, None, :] <= q_pos[:, :, None]
+        if not full:
+            visible &= q_pos[:, :, None] - k_pos[:, None, :] < cfg.window
+        if 4 * n * heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
+            return lax.map(lambda a: _attend(*(t[None] for t in a))[0], (q, ck, cv, visible))
+        return _attend(q, ck, cv, visible)
+
+
+def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, valid, reads=None, interpret=False):
     """One layer over the page pool: x[n, m, d] with slot i's query j at
     positions[i] + j. Rotated K and V scatter through the layer kind's block
     table first, attention reads them back through the (windowed) gather,
-    like the GPT-2 family's write-then-read. Returns (x, pool, counters)."""
+    like the GPT-2 family's write-then-read, or, where the step was given
+    ``reads`` (``_step_reads``), through ops/gqa_decode.py's kernel, which
+    reads the kind's pages where they lie. Returns (x, pool, counters)."""
     n, m, _ = x.shape
     full = cfg.is_full(li)
     heads, pl = cfg.heads_of(li), cfg.plane_layer(li)
@@ -430,23 +487,13 @@ def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, va
     else:
         pool = kind
     with jax.named_scope(SCOPE_FULL if full else SCOPE_WIN):
-        if full:
-            bt_l, k0 = bt_k, jnp.zeros_like(positions)
+        if reads is not None:
+            with jax.named_scope(SCOPE_ATTN):
+                ctx = gqa_decode_attention(
+                    q[:, 0], kind[0], kind[1], pl, *reads[full], scale=cfg.head_dim**-0.5, interpret=interpret
+                )[:, None]
         else:
-            with jax.named_scope(SCOPE_KV_GATHER):
-                bt_l, k0 = _window_table(bt_k, positions, m, kind[0].shape[2], cfg.window)
-        ck, cv = _paged_gather(kind, pl, bt_l, cfg.kv_heads)  # [n, g, K, d] float32
-        with jax.named_scope(SCOPE_ATTN):
-            k_pos = k0[:, None] + jnp.arange(ck.shape[2], dtype=k0.dtype)[None, :]  # [n, K]
-            visible = k_pos[:, None, :] <= q_pos[:, :, None]
-            if not full:
-                visible &= q_pos[:, :, None] - k_pos[:, None, :] < cfg.window
-            if 4 * n * heads * m * ck.shape[2] > _SCORES_BATCH_BYTES:
-                ctx = lax.map(
-                    lambda a: _attend(*(t[None] for t in a))[0], (q, ck, cv, visible)
-                )
-            else:
-                ctx = _attend(q, ck, cv, visible)
+            ctx = _gathered_attention(cfg, full, q, kind, pl, bt_k, positions, q_pos)
     with jax.named_scope(SCOPE_ATTN_OUT):
         if cfg.attn_gate:
             with jax.named_scope(SCOPE_ATTN_GATE):
@@ -459,7 +506,7 @@ def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, va
     return x, pool, cnt
 
 
-def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
+def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None, attn_kernel=""):
     """Shared body of the paged programs: tokens[n, m], slot i's query j
     at positions[i] + j; ``bt`` the slots' block-table rows, ``[2, n, pages]``
     (full kind, window kind) where the configuration has two page kinds. ``counts`` [n]
@@ -467,8 +514,11 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     ``rows`` [n] bool (the step): the slots that generate. ``pick`` [n]:
     the head runs on that one query of each slot (a chunk round needs only
     the last real one; 256 positions of a 98k vocabulary are 1.6 GB of
-    logits). Returns (logits[n, m or 1, vocab] float32, hidden[n, m, d],
-    pool, counters int32: ``MoEDecoder.frame_counters``)."""
+    logits). ``attn_kernel`` (static; "" | "mosaic" | "interpret":
+    ``decode_programs._step_attn_kernel``'s answer) lets a dispatch of ONE
+    query a slot read both page kinds through ops/gqa_decode.py's kernel;
+    every other shape gathers. Returns (logits[n, m or 1, vocab] float32,
+    hidden[n, m, d], pool, counters int32: ``MoEDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     if counts is not None:
@@ -477,9 +527,10 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         valid &= rows[:, None]
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
+    reads, run_pages = _step_reads(cfg, attn_kernel, m, pool, bt, positions, rows)
     cnt = jnp.zeros((cfg.n_counters,), jnp.int32)
     for li, lp in enumerate(params["layers"]):
-        x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid)
+        x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, reads, attn_kernel == "interpret")
         with jax.named_scope(SCOPE_MLP), jax.named_scope(SCOPE_MOE_COMBINE):
             cnt = cnt + c
     with jax.named_scope(SCOPE_LM_HEAD):
@@ -490,7 +541,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         )
         # rows is every layer's own count: report it once, not summed
         cnt = cnt.at[0].set(jnp.sum(valid, dtype=jnp.int32))
-    return logits, x, pool, cnt
+    return logits, x, pool, jnp.concatenate([cnt, run_pages])
 
 
 def _generate(cfg, params, ids, max_new_tokens: int):
@@ -524,17 +575,22 @@ class MoEDecoder:
     @property
     def frame_counters(self) -> tuple:
         """What paged_forward's extra output counts, in order (FlightFrame
-        fields); over a share of the experts also the picks that landed on it."""
+        fields); over a share of the experts also the picks that landed on
+        it; last, the pages of one layer a page kind that the step's kernel
+        fetched in run DMAs (0 where it gathers)."""
         base = ("moe_rows", "moe_experts_hit", "moe_load_max")
-        return base + (("moe_local_picks",) if self.cfg.experts_held else ())
+        return base + (("moe_local_picks",) if self.cfg.experts_held else ()) + ("attn_run_pages",)
 
     @property
     def serves(self) -> frozenset:
-        """Beside the plain rounds (``decoder.require_served``): the int8 pool
-        on either layout; the KV tiers and prefix export only where the pool
-        has ONE page kind (they move a prefix as one list of pages). Not
-        served: speculation, a decode mesh, a step attention kernel."""
-        return frozenset({"kv_int8"} if self.cfg.two_kinds else {"kv_int8", "host_tier", "prefix_export"})
+        """Beside the plain rounds (``decoder.require_served``): a step that
+        reads the pool in place (ops/gqa_decode.py's kernel, both page kinds);
+        the int8 pool on either layout (its step gathers); the KV tiers and
+        prefix export only where the pool has ONE page kind (they move a
+        prefix as one list of pages). Not served: speculation, a decode
+        mesh."""
+        one_kind = () if self.cfg.two_kinds else ("host_tier", "prefix_export")
+        return frozenset({"attn_kernel", "kv_int8", *one_kind})
 
     def decoder_dims(self, params: dict) -> dict:
         layers = params.get("layers") or [{}]
@@ -547,6 +603,7 @@ class MoEDecoder:
             raise FamilyNotServed("parameters and configuration disagree on layers, attn_gate or the head counts")
         return {
             "layers": c.layers, "kv_layers": c.layers, "heads": c.heads,
+            "heads_window": c.heads_window or c.heads,  # a sliding layer's query heads
             "kv_heads": c.kv_heads, "hidden": c.hidden, "head_dim": c.head_dim, "q_width": c.q_width,
             "vocab": params["tok_emb"].shape[0], "max_len": c.max_len,
             # the second page kind (decoder.kv_pool_zeros, serving/kv_pool.py):
@@ -557,15 +614,17 @@ class MoEDecoder:
     def paged_kv_init(self, params, n_pages, page_size, dtype=jnp.float32, kv_dtype=""):
         return kv_pool_zeros(self.decoder_dims(params), n_pages, page_size, dtype, kv_dtype)
 
-    def paged_forward(self, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None):
-        return _forward(self.cfg, params, pool, bt, tokens, positions, counts, rows, pick)
+    def paged_forward(self, params, pool, bt, tokens, positions, counts=None, rows=None, pick=None, attn_kernel=""):
+        return _forward(self.cfg, params, pool, bt, tokens, positions, counts, rows, pick, attn_kernel)
 
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.counted_programs``:
-        the step takes ``rows``, the counts ride the token readback).
-        Cached: equal configurations share compiled programs."""
-        return counted_programs(self.paged_forward)
+        the step takes ``rows``, the counts ride the token readback); with
+        ``attn_kernel`` the one whose dispatch is one query a slot, the step,
+        reads the pool through the kernel. Cached: equal configurations
+        share compiled programs."""
+        return counted_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def paged_decode_step(self, params, pool, bt, tokens, positions):
         logits, hidden, pool, _ = _forward(self.cfg, params, pool, bt, tokens[:, None], positions)

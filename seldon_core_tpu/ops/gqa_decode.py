@@ -3,14 +3,25 @@ group x kv_heads`` query heads over a two-plane float pool whose token row
 holds ``kv_heads x head_dim``, K and V pages read where they lie.
 
 The step of a family with grouped-query attention (models/conv_decoder.py,
-models/hybrid_decoder.py) used to gather every slot's WHOLE block table out
-of the pool, upcast it to float32, split its heads and score every virtual
+models/hybrid_decoder.py and, since PR 48, models/moe_decoder.py over both
+its page kinds) used to gather every slot's WHOLE block table out of the
+pool, upcast it to float32, split its heads and score every virtual
 position, whatever the slots held: 25.8 ms of a 45.6 ms step at the
-lfm2-24b-a2b cell's geometry (PERF.md section 5, PR 41). This kernel leaves
-both planes in HBM and, for each slot, fetches the ``ceil(length / ps)``
-pages its table names ONCE for all the query heads, in blocks, each group of
-``RUN_PAGES`` table entries with ONE DMA a plane where their pages are
-consecutive (ops/mla.py ``page_runs``), else a DMA a page.
+lfm2-24b-a2b cell's geometry (PERF.md section 5, PR 41), 29 of 44.2 at the
+laguna-s-2.1 cell's (section 6, PR 48). This kernel leaves both planes in
+HBM and, for each slot, fetches the ``ceil(length / ps)`` pages its table
+names ONCE for all the query heads, in blocks, each group of ``RUN_PAGES``
+table entries with ONE DMA a plane where their pages are consecutive
+(ops/mla.py ``page_runs``), else a DMA a page.
+
+A sliding-window layer hands it the windowed sub-table of its page kind
+(``moe_decoder._window_table``: the pages that cover the query's window, the
+older entries of the kind's table being junk page 0 once given back) with
+one more vector a slot, ``first``: the in-table position of the oldest key
+the query sees (``step_reads``). That form is a static variant: a call
+without ``first`` traces the kernel it traced before there was one. Query
+heads come in any whole number of groups (48 / 8, 72 / 8): the
+block-diagonal query's rows are padded to whole sublane tiles.
 
 Why not ops/paged_attention.py's kernel with a group loop: that one multiplies
 on the VPU, one multiply a K element a query, and a group of four would be
@@ -68,14 +79,16 @@ def gqa_tiles(row_width: int, heads: int, kv_heads: int, page_size: int, dtype) 
     into the MXU as they are stored), rows of whole 128-lane tiles, pages of
     whole sublane tiles (16 rows of a two-byte float: a page is the
     destination of one DMA and a slice of the block the MXU takes), a head
-    that divides a lane tile, and query heads in whole groups that fill
-    whole sublane tiles of the block-diagonal query (multiples of 16)."""
+    that divides a lane tile, and query heads in whole groups of two or more
+    (48 / 8, 72 / 8: the block-diagonal query's rows are padded to whole
+    sublane tiles, ``_block_diagonal``; a group of ONE is
+    ops/paged_attention.py's geometry, not this kernel's)."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize != 2:
         return False
     if row_width % _LANES or row_width % kv_heads or _LANES % (row_width // kv_heads):
         return False
-    return page_size % 16 == 0 and heads % kv_heads == 0 and heads % 16 == 0
+    return page_size % 16 == 0 and heads % kv_heads == 0 and heads > kv_heads
 
 
 def _table_blocks(pages: int) -> tuple[int, int, int]:
@@ -90,13 +103,23 @@ def _table_blocks(pages: int) -> tuple[int, int, int]:
     return run, -(-n_runs // blocks), blocks
 
 
-def step_reads(bt, positions, rows, page_size: int):
+def step_reads(bt, positions, rows, page_size: int, k0=None, window: int = 0):
     """What the kernel reads in a step, from what the step program is given:
     (lengths [n] int32, runs [n, groups] int32). A slot that generates
     (``rows``; None: every slot) attends over ``positions + 1`` keys; any
     other slot (prefilling, free) over ONE, so the kernel fetches one page
     for it whatever its table holds and nobody reads its output. ``runs``
-    is ops/mla.py ``page_runs`` over this kernel's groups."""
+    is ops/mla.py ``page_runs`` over this kernel's groups.
+
+    With ``k0`` [n] (``bt`` a sliding layer's windowed sub-table whose first
+    row is the key at absolute position ``k0``: models/moe_decoder.py
+    ``_window_table``) the lengths count from ``k0`` and there is a third
+    vector, ``first`` [n] int32: the in-table position of the oldest key the
+    query sees, ``positions - (window - 1) - k0`` (0 where the window
+    reaches back past the table's first row, and for a slot outside
+    ``rows``)."""
+    if k0 is not None:
+        positions = positions - k0
     lengths = slot_lengths(positions, page_size, bt.shape[1]).astype(jnp.int32)
     if rows is not None:
         lengths = jnp.where(rows, lengths, 1)
@@ -104,7 +127,9 @@ def step_reads(bt, positions, rows, page_size: int):
     groups = blocks * block_runs
     runs = mla.page_runs(bt, lengths, page_size)  # groups past the table's last are 0
     runs = jnp.pad(runs, ((0, 0), (0, max(groups - runs.shape[1], 0))))[:, :groups]
-    return lengths, runs
+    if k0 is None:
+        return lengths, runs
+    return lengths, runs, jnp.clip(lengths - window, 0, None)
 
 
 def pages_fetched(lengths, runs, page_size: int, pages: int):
@@ -116,17 +141,24 @@ def pages_fetched(lengths, runs, page_size: int, pages: int):
     return jnp.stack([jnp.sum(held), jnp.sum(in_runs)]).astype(jnp.int32)
 
 
-def _decode_kernel(
-    layer_ref, bt_ref, len_ref, run_ref,  # scalar prefetch
-    q_ref, k_hbm, v_hbm,  # slot i's block-diagonal query [1, H, w]; the two planes, left in HBM
-    o_ref,  # slot i's normalised context over every kv head's lanes [1, H, w]
-    kbuf, vbuf, top_ref, sum_ref, acc_ref, sem, cur,  # scratch
-    *, page_size: int, run: int, block_runs: int, scale: float, terms: int,
-):
+def _decode_kernel(*refs, page_size: int, run: int, block_runs: int, scale: float, terms: int, windowed: bool):
     """Grid step i is slot i: its blocks of pages in turn, always with the
     next block's K and V rows in flight (the next SLOT's first block after
     the last), the online softmax's state in scratch. ``cur`` carries which
-    of the two buffers the slot's first block was fetched into."""
+    of the two buffers the slot's first block was fetched into.
+
+    ``windowed`` (static): one more scalar-prefetch vector, ``first``: keys
+    at in-table positions under ``first[i]`` weigh exactly 0, as keys from
+    the length on do, and their V rows are zeroed in VMEM like the last
+    page's tail (a page given back reads as the junk page, whatever it
+    holds). Without it the kernel is what it was, argument for argument."""
+    (
+        layer_ref, bt_ref, len_ref, run_ref,  # scalar prefetch
+        *first_ref,  # [n] with ``windowed``
+        q_ref, k_hbm, v_hbm,  # slot i's block-diagonal query [1, H, w]; the two planes, left in HBM
+        o_ref,  # slot i's normalised context over every kv head's lanes [1, H, w]
+        kbuf, vbuf, top_ref, sum_ref, acc_ref, sem, cur,  # scratch
+    ) = refs
     i, n = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     block = run * block_runs
@@ -204,11 +236,26 @@ def _decode_kernel(
             at = last - blk * block
             vbuf[b, at] = jnp.where(tail, vbuf[b, at], jnp.zeros((), vbuf.dtype))
 
+        if windowed:
+            # V's rows before the slot's first key, in the pages of this block that hold any (the window
+            # starts inside the sub-table's first pages; they may be the junk page): zeroed like the tail
+            first = first_ref[0][i]
+
+            def head(j, _):
+                seen = lax.broadcasted_iota(jnp.int32, (page_size, 1), 0) + (blk * block + j) * page_size >= first
+                vbuf[b, j] = jnp.where(seen, vbuf[b, j], jnp.zeros((), vbuf.dtype))
+                return 0
+
+            lax.fori_loop(0, jnp.clip((first + page_size - 1) // page_size - blk * block, 0, block), head, 0)
+
         k = kbuf[b].reshape(keys, kbuf.shape[-1])
         v = vbuf[b].reshape(keys, vbuf.shape[-1])
         s = lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         k_pos = blk * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        s = jnp.where(k_pos < len_ref[i], s * scale, NEG_INF)  # [H, keys]
+        # (a block wholly before the first key scores NEG_INF throughout, weighs 1 a key against a maximum of
+        # NEG_INF, and is wiped by the rescaling at the first block with a key in it: every slot has one)
+        seen = (k_pos < len_ref[i]) & (k_pos >= first) if windowed else k_pos < len_ref[i]
+        s = jnp.where(seen, s * scale, NEG_INF)  # [H, keys]
         top = top_ref[...]
         new_top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
         shrink = jnp.exp(top - new_top)
@@ -229,17 +276,23 @@ def _decode_kernel(
 
 
 def _block_diagonal(q, kv_heads: int):
-    """q[n, H, d] -> [n, H, kv_heads * d]: head h's numbers in the lanes of
-    kv head ``h // (H / kv_heads)``, zero elsewhere."""
+    """q[n, H, d] -> [n, H', kv_heads * d]: head h's numbers in the lanes of
+    kv head ``h // (H / kv_heads)``, zero elsewhere; H' is H rounded up to
+    whole sublane tiles of a two-byte float (16 rows: 48 stays, 72 -> 80),
+    the rows past H all zero (``_own_lanes`` drops them)."""
     n, heads, d = q.shape
     own = jnp.arange(heads)[:, None] // (heads // kv_heads) == jnp.arange(kv_heads * d)[None, :] // d
-    return jnp.where(own[None], jnp.tile(q, (1, 1, kv_heads)), jnp.zeros((), q.dtype))
+    wide = jnp.where(own[None], jnp.tile(q, (1, 1, kv_heads)), jnp.zeros((), q.dtype))
+    return wide if heads % 16 == 0 else jnp.pad(wide, ((0, 0), (0, -heads % 16), (0, 0)))
 
 
-def _own_lanes(ctx, kv_heads: int):
-    """ctx[n, H, kv_heads * d], every head over every kv head's lanes ->
-    [n, H * d]: each head's own kv head's lanes, heads merged."""
-    n, heads, w = ctx.shape
+def _own_lanes(ctx, kv_heads: int, heads: int):
+    """ctx[n, H', kv_heads * d], every head over every kv head's lanes ->
+    [n, H * d]: each of the first ``heads`` rows' own kv head's lanes, heads
+    merged."""
+    n, padded, w = ctx.shape
+    if padded != heads:
+        ctx = ctx[:, :heads]
     d = w // kv_heads
     by_group = ctx.reshape(n, kv_heads, heads // kv_heads, kv_heads, d)
     own = jnp.eye(kv_heads, dtype=bool)[None, :, None, :, None]
@@ -248,7 +301,9 @@ def _own_lanes(ctx, kv_heads: int):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def gqa_decode_attention(q, pool_k, pool_v, layer, bt, lengths, runs, *, scale: float, interpret: bool = False):
+def gqa_decode_attention(
+    q, pool_k, pool_v, layer, bt, lengths, runs, first=None, *, scale: float, interpret: bool = False
+):
     """Decode attention of ``n`` slots of ONE query each over the page pool,
     read in place.
 
@@ -266,10 +321,14 @@ def gqa_decode_attention(q, pool_k, pool_v, layer, bt, lengths, runs, *, scale: 
     with one DMA a plane where ``runs`` says it is a run of consecutive
     pages, else a DMA a page; pages past ``ceil(length / ps)`` are never
     fetched, rows past the length weigh exactly 0. Nothing is shared between
-    slots. A slot's first key is its table's first row: a windowed table
-    with a first-key offset a slot would be one more scalar-prefetch vector.
+    slots. A slot's first key is its table's first row unless ``first``
+    ([n] int32, with ``lengths`` and ``runs`` what ``step_reads`` gives for a
+    windowed sub-table) says where in the table its window starts: rows
+    before it weigh exactly 0 too, whatever their pages hold. With ``first``
+    None the traced kernel is the one without it.
 
-    Jitted with ``layer`` traced: a step's calls lower to Mosaic once."""
+    Jitted with ``layer`` traced: a step's calls lower to Mosaic once a
+    table width and head count."""
     n, heads, d = q.shape
     _, _, ps, w = pool_k.shape
     pages = bt.shape[1]
@@ -289,31 +348,33 @@ def gqa_decode_attention(q, pool_k, pool_v, layer, bt, lengths, runs, *, scale: 
         raise ValueError(f"runs {list(runs.shape)} for {blocks} blocks of {block_runs} groups (step_reads)")
     # float32 rows (the interpreter's tests) take the probabilities whole
     terms = P_TERMS if jnp.dtype(pool_k.dtype).itemsize < 4 else 1
+    windowed = first is not None
     kernel = functools.partial(
-        _decode_kernel, page_size=ps, run=run, block_runs=block_runs, scale=scale, terms=terms
+        _decode_kernel, page_size=ps, run=run, block_runs=block_runs, scale=scale, terms=terms, windowed=windowed
     )
+    rows = heads + -heads % 16  # the block-diagonal query's rows: the heads in whole sublane tiles
     ctx = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5 if windowed else 4,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, heads, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, rows, w), lambda i, *_: (i, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, heads, w), lambda i, *_: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, rows, w), lambda i, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, block, ps, w), pool_k.dtype),  # K: the block in use and the one in flight
                 pltpu.VMEM((2, block, ps, w), pool_v.dtype),  # V
-                pltpu.VMEM((heads, 1), jnp.float32),  # running maximum
-                pltpu.VMEM((heads, 1), jnp.float32),  # running sum
-                pltpu.VMEM((heads, w), jnp.float32),  # running context, every kv head's lanes
+                pltpu.VMEM((rows, 1), jnp.float32),  # running maximum
+                pltpu.VMEM((rows, 1), jnp.float32),  # running sum
+                pltpu.VMEM((rows, w), jnp.float32),  # running context, every kv head's lanes
                 pltpu.SemaphoreType.DMA((2, 2)),  # plane, buffer
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, heads, w), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, rows, w), q.dtype),
         # a slot's first block is started by the slot before it
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -322,6 +383,7 @@ def gqa_decode_attention(q, pool_k, pool_v, layer, bt, lengths, runs, *, scale: 
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.pad(bt.astype(jnp.int32), ((0, 0), (0, blocks * block - pages))),
         jnp.clip(lengths.astype(jnp.int32), 1, pages * ps), runs.astype(jnp.int32),
+        *((first.astype(jnp.int32),) if windowed else ()),
         _block_diagonal(q.astype(pool_k.dtype), kv_heads), pool_k, pool_v,
     )
-    return _own_lanes(ctx, kv_heads)
+    return _own_lanes(ctx, kv_heads, heads)

@@ -78,6 +78,22 @@ def pages_read(positions: np.ndarray, page_size: int, pages_per_slot: int) -> np
     return -(-slot_lengths(np.asarray(positions), page_size, pages_per_slot) // page_size)
 
 
+def window_pages(window: int, queries: int, page_size: int) -> int:
+    """Entries of a sliding layer's windowed sub-table: the pages that cover
+    the windows of ``queries`` consecutive queries wherever the first falls
+    in its page (models/moe_decoder.py ``_window_table``)."""
+    return -(-(window + queries) // page_size) + 1
+
+
+def window_first_page(positions, window: int, page_size: int, pages_per_slot: int, pw: int):
+    """The table entry a windowed sub-table of ``pw`` entries starts at, for
+    queries from ``positions``: the page of the oldest key the first query
+    sees, kept inside the table. numpy or jax alike, as ``slot_lengths``:
+    the program takes the sub-table with it and the scheduler counts the
+    pages the step's kernel fetches from it."""
+    return ((positions - (window - 1)) // page_size).clip(0, pages_per_slot - pw)
+
+
 def mosaic_tiles(row_width: int, heads: int, page_size: int, dtype) -> bool:
     """Whether Mosaic can tile the kernel at this geometry — what the
     scheduler asks before it picks the kernel (``decode_scheduler.

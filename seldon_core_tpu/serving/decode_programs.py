@@ -78,7 +78,7 @@ def _scatter_prefill_rows(cache_k, cache_v, k_new, v_new, row_for_slot, valid_sl
     return cache_k, cache_v
 
 
-def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int) -> str:
+def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int, heads_window: int = 0) -> str:
     """How the fused decode step's attention reads the pool — THE place the
     choice is made, from what the set can observe and nothing else (no
     knob): "mosaic", a Pallas kernel that reads the pool's pages in place
@@ -91,36 +91,46 @@ def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int
     K/V head (the GPT-2 block; ``mosaic_tiles``: gpt2-xl's rows of 1600 and
     pages of 4 rows are outside it) and ops/gqa_decode.py
     ``gqa_decode_attention`` where ``kv_heads`` are fewer than ``heads``
-    (the short-convolution and hybrid families; ``gqa_tiles``: a two-byte
-    float, rows of whole lane tiles, pages of 16 rows or more, a head that
-    divides a tile); the ONE-plane latent pool's is ops/mla.py
+    (the short-convolution, hybrid and sparse-expert families;
+    ``gqa_tiles``: a two-byte float, rows of whole lane tiles, pages of 16
+    rows or more, a head that divides a tile, query heads in whole groups);
+    a pool of TWO page kinds (``heads_window``: the sliding layers' query
+    heads, from a family whose ``decoder_dims`` has ``kv_window_layers``;
+    the state is the full layers' planes then the sliding layers') is asked
+    once a kind, with that kind's planes and head count, and takes the
+    kernel only if both tile; the ONE-plane latent pool's is ops/mla.py
     ``mla_decode_attention`` (``kernel_tiles``: a two-byte float, rows of
     whole lane tiles, pages of 16 rows or more);
     "" — the page gather and the flat path's attention, or the latent
-    family's blocked walk — everywhere else: the int8 pool, a
-    tensor-parallel mesh, a family without a kernel, a geometry the kernel
+    family's blocked walk — everywhere else: the int8 pool (six planes a
+    kind), a tensor-parallel mesh, a family without a kernel, a geometry the kernel
     cannot tile, the CPU backend (where the gather and the walk are the
     oracles). The dispatch's shape is the program's own to see: in the
     two-plane families only one query a slot takes the kernel (models/decoder.py
-    ``_layer_step_paged`` and ``_paged_step_reads``), so their chunk, verify
-    and tree programs gather whatever this says; the latent family's chunks
+    ``_layer_step_paged`` and ``_paged_step_reads``, models/moe_decoder.py
+    ``_step_reads``), so their chunk, verify and tree programs gather
+    whatever this says; the latent family's chunks
     take its many-queries kernel under the same answer (ops/mla.py
     ``kernel_takes``, ``mla_chunk_attention``; ``DecodePrograms.chunk_attn``
     says which a chunk length took)."""
-    if "attn_kernel" not in family.serves or mesh is not None or len(pool_state) > 2:
+    half = len(pool_state) // 2
+    kinds = [(pool_state[:half], heads), (pool_state[half:], heads_window)] if heads_window else [(pool_state, heads)]
+    if "attn_kernel" not in family.serves or mesh is not None or any(len(planes) > 2 for planes, _ in kinds):
         return ""
     devices = pool_state[0].sharding.device_set
     if len(devices) != 1 or next(iter(devices)).platform != "tpu":
         return ""
-    _layers, _pages, page_size, row_width = pool_state[0].shape
-    dtype = pool_state[0].dtype
-    if len(pool_state) == 1:
-        tiles = kernel_tiles(row_width, page_size, dtype)
-    elif kv_heads < heads:
-        tiles = gqa_tiles(row_width, heads, kv_heads, page_size, dtype)
-    else:
-        tiles = mosaic_tiles(row_width, heads, page_size, dtype)
-    return "mosaic" if tiles else ""
+
+    def tiles(planes: tuple, heads: int) -> bool:
+        _layers, _pages, page_size, row_width = planes[0].shape
+        dtype = planes[0].dtype
+        if len(planes) == 1:
+            return kernel_tiles(row_width, page_size, dtype)
+        if kv_heads < heads:
+            return gqa_tiles(row_width, heads, kv_heads, page_size, dtype)
+        return mosaic_tiles(row_width, heads, page_size, dtype)
+
+    return "mosaic" if all(tiles(planes, h) for planes, h in kinds) else ""
 
 
 def _fused_draft_admit(params, dcache_k, dcache_v, ids, row_for_slot, valid_slot):
@@ -349,7 +359,11 @@ class DecodePrograms:
         # the plain step's read side (the feature twins keep the gather)
         self.attn_kernel = (
             "" if feature
-            else _step_attn_kernel(family, pool.state, mesh, dims["heads"], dims["kv_heads"])
+            # a pool of two page kinds names the second kind's head count too
+            else _step_attn_kernel(
+                family, pool.state, mesh, dims["heads"], dims["kv_heads"],
+                *((dims["heads_window"],) if dims.get("kv_window_layers") else ()),
+            )
         )
         # a family with a chunk kernel says which chunk lengths take it (the feature twins gather)
         self._chunk_attn = None if feature else getattr(family, "chunk_attn", None)

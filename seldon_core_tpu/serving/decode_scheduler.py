@@ -203,7 +203,7 @@ from seldon_core_tpu.serving.affinity_router import (
     capture_prefix_len,
     usable_prefix_len,
 )
-from seldon_core_tpu.ops.paged_attention import pages_read
+from seldon_core_tpu.ops.paged_attention import pages_read, window_first_page, window_pages
 from seldon_core_tpu.serving.decode_programs import DecodePrograms
 from seldon_core_tpu.serving.kv_host_tier import KVHostTier
 from seldon_core_tpu.serving.kv_pool import PagedKVPool
@@ -1351,15 +1351,27 @@ class DecodeScheduler:
         slots that generate (``rows``) — no readback. The kernel path
         (``programs.attn_kernel``) fetches each slot's ``ceil((pos + 1) /
         page_size)`` pages: a free slot's one junk page, a prefilling slot's
-        up to its cursor, or, where the step is told its rows (a family with
-        state rows: ops/gqa_decode.py ``step_reads``), one page for every
-        slot that does not generate; the gather path all of them."""
-        table = self.n_slots * self.pool.pages_per_slot
+        up to its cursor, or, where the step is told its rows (a counting
+        family: ops/gqa_decode.py ``step_reads``), one page for every slot
+        that does not generate; the gather path all of them. A pool of two
+        page kinds counts one layer of EACH: the full kind as above, the
+        window kind the sliding layers' sub-table (``window_pages`` entries a
+        slot through the gather; through the kernel from the sub-table's
+        first page to the position's, one for a slot that does not
+        generate)."""
+        pool = self.pool
+        ps, pages = pool.page_size, pool.pages_per_slot
+        window = pool.alloc.win.window if pool.windowed else 0
+        pw = min(window_pages(window, 1, ps), pages) if window else 0
+        table = self.n_slots * pages * (2 if window else 1)
         if not self.programs.attn_kernel:
-            return table, table
-        if self._stateful:
+            return self.n_slots * (pages + pw), table
+        if self._stateful or self._frame_counters:
             pos = np.where(rows, pos, 0)
-        return int(pages_read(pos, self.pool.page_size, self.pool.pages_per_slot).sum()), table
+        read = int(pages_read(pos, ps, pages).sum())
+        if window:
+            read += int(pages_read(pos - window_first_page(pos, window, ps, pages, pw) * ps, ps, pw).sum())
+        return read, table
 
     def compile_counts(self) -> dict[str, int]:
         """jit cache sizes per program (``DecodePrograms.compile_counts``)."""

@@ -398,9 +398,11 @@ class FlightFrame:
     token as the program sees it; ``attn_pages_read`` / ``attn_pages_table``
     the pages a plain round's fused step read for one layer's attention
     (summed over slots) and the pages its block tables name (``n_slots x
-    pages_per_slot``), counted on the host from the round's positions:
-    equal on the gather path, read < table where the paged-attention kernel
-    stops at each slot's length, 0 / 0 in a round without a plain step;
+    pages_per_slot``; a pool of two page kinds counts one layer of EACH
+    kind, the window kind's read being its sub-table's, and both tables),
+    counted on the host from the round's positions: equal on the gather path
+    of a pool of one kind, read < table where a step's kernel stops at each
+    slot's length, 0 / 0 in a round without a plain step;
     ``chunk_rows`` / ``chunk_rows_live`` the rows the round's prefill chunk
     dispatch computed (its ``chunk_buckets`` entry's) and the slots that
     prefilled in it, 0 / 0 in a round without one;
@@ -441,7 +443,8 @@ class FlightFrame:
     walk ran; ``attn_run_pages`` of
     the ``attn_pages_read`` of a round whose step ran the grouped-query
     kernel (ops/gqa_decode.py), those that came in ONE DMA a run, as the
-    program counted them (one layer's K); 0 elsewhere; ``mhc_resid_ppm``
+    program counted them (one layer's K, one layer a page kind); 0
+    elsewhere; ``mhc_resid_ppm``
     where a latent-attention family carries a multi-stream residual
     (``hc_mult`` > 1, ops/mhc.py): the largest |row or column sum - 1| of any
     Sinkhorn-normalised stream mix of a dispatch's real rows, x 1e6, SUMMED

@@ -523,8 +523,9 @@ def test_a_family_answers_what_it_serves(family, mechanism, message):
         require_served(MOE, mechanism)
     assert str(e.value) == message
     # what it keeps serving of the mechanisms PR 34 named for the third family to refuse
-    # (two page kinds since PR 47: the tiers and prefix export move a prefix as ONE list of pages, and are refused)
-    assert decoder_family(MOE) is MOE and MOE.serves == {"kv_int8"} and MOE.cfg.two_kinds
+    # (two page kinds since PR 47: the tiers and prefix export move a prefix as ONE list of pages, and are refused),
+    # and since PR 48 a step that reads the pool in place
+    assert decoder_family(MOE) is MOE and MOE.serves == {"attn_kernel", "kv_int8"} and MOE.cfg.two_kinds
     params = md.init_moe_decoder(MOE.cfg, seed=0, dtype=jnp.float32)
     kw = {"spec_tree": "2,1"} if mechanism == "speculation" else {"mesh_axes": {"model": 2}}
     with pytest.raises(FamilyNotServed) as e:
@@ -551,9 +552,14 @@ LOWERED_BEFORE_THE_FOURTH_FAMILY = {
     # the sparse-expert family's two were re-made at PR 47 (e07abc72... / fc28eed0... before): its pool
     # has two page kinds (the state tuple is the full layers' planes then the sliding layers', both kinds'
     # block tables in one [2, n, pages] array, a layer indexing its kind's planes), so the programs' arguments and every pool
-    # write and gather differ; its logits against its reference do not (tests/test_moe_decoder.py)
-    "moe.step": "cd6f1c49720b7b182d81f4dbd30a6067d6891195375e01ec08593f3f33492099",
-    "moe.chunk": "873438f22698b52cb035d32671ba64a92148cfcad99fe9a988e8c1e96807676b",
+    # write and gather differ; its logits against its reference do not (tests/test_moe_decoder.py). Re-made again at
+    # PR 48 (cd6f1c49... / 873438f2... before), which gave its step a kernel to choose on a TPU and its readback one
+    # more count (``attn_run_pages``: a constant 0 on this, the gather path), as PR 42 did to the hybrid family's two:
+    # one more element of the token readback and the constant's place in the text, and ``_window_table``'s clip
+    # written as the array's method (``paged_attention.window_first_page``, shared with the scheduler's page count):
+    # the same operations on the same values, in the same order
+    "moe.step": "42204fab3ec8f1bab50e4c5a8033fe8852a86791662e937e4feed3b3119d132d",
+    "moe.chunk": "81498d460f77aa4ccb1a22b569d1a1c92c31100c184919b620733a47bfb6c95e",
     "hybrid.step": "e17c5b7e1f238a00f5912f73a9d8ac916a23ef288193feb8f68c4e229296e2fd",
     "hybrid.chunk": "aba78efa101c5b7c9b363b2bc7ecda74de78a9b76aee6df7351237888a48a43c",
 }

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from seldon_core_tpu.models import conv_decoder as cd
 from seldon_core_tpu.models import hybrid_decoder as hd
 from seldon_core_tpu.models.decoder import _paged_gather
-from seldon_core_tpu.models.moe_decoder import _attend
+from seldon_core_tpu.models.moe_decoder import _attend, _window_table
 from seldon_core_tpu.ops import gqa_decode as gqa
 from seldon_core_tpu.ops import mla as mla_ops
 from seldon_core_tpu.serving import decode_programs as dp
@@ -196,6 +196,137 @@ def test_two_byte_pool_takes_the_probabilities_in_three_terms():
 )
 def test_gqa_tiles_names_what_mosaic_can_tile(width, heads, kv_heads, page_size, dtype, want):
     assert gqa.gqa_tiles(width, heads, kv_heads, page_size, dtype) is want
+
+
+@pytest.mark.parametrize(
+    "width, heads, kv_heads, want",
+    [
+        (1024, 48, 8, True),  # the laguna-s-2.1 cell's full layers: 6 to a group, three sublane tiles
+        (1024, 72, 8, True),  # its sliding layers: 9 to a group, padded to 80 rows
+        (512, 32, 4, True),  # the mellum2-12b-a2.5b cell: 8 to a group
+        (1024, 40, 8, True),  # 5 to a group
+        (1024, 44, 8, False),  # 5.5 to a group
+        (1024, 8, 8, False),  # one to a group: ops/paged_attention.py's geometry
+    ],
+)
+def test_gqa_tiles_takes_any_whole_number_of_groups(width, heads, kv_heads, want):
+    assert gqa.gqa_tiles(width, heads, kv_heads, 16, jnp.bfloat16) is want
+
+
+# --------------------------------- a windowed sub-table with a first key a slot
+
+WINDOW = 8  # two pages: a sub-table of ceil(9 / 4) + 1 = 4 entries, one block of two runs
+
+
+def _window_gather_attention(q, pool, li, bt, positions, window, scale):
+    """A sliding layer's gather path for one query a slot (``moe_decoder.
+    _layer``): the windowed sub-table's pages, masked by absolute position."""
+    bt_w, k0 = _window_table(bt, positions, 1, PS, window)
+    ck, cv = _paged_gather(pool, li, bt_w, pool[0].shape[-1] // q.shape[-1])
+    k_pos = k0[:, None] + jnp.arange(ck.shape[2])[None, :]
+    visible = (k_pos <= positions[:, None]) & (positions[:, None] - k_pos < window)
+    return np.asarray(_attend(q[:, None], ck, cv, visible[:, None, :], scale=scale)[:, 0])
+
+
+def _window_kernel(q, pool, li, bt, positions, rows, window, scale):
+    bt_w, k0 = _window_table(bt, positions, 1, PS, window)
+    reads = gqa.step_reads(bt_w, positions, rows, PS, k0, window)
+    out = gqa.gqa_decode_attention(q, pool[0], pool[1], li, bt_w, *reads, scale=scale, interpret=True)
+    return np.asarray(out), reads
+
+
+@pytest.mark.parametrize("window", [WINDOW, 20, 64], ids=["one_block", "two_blocks", "whole_table"])
+def test_windowed_table_with_a_first_key_matches_the_gather_and_never_reads_a_given_back_page(window):
+    """The window kind's table as the allocator leaves it: every entry wholly
+    older than a slot's window is junk page 0, and page 0 holds NaN in K and
+    V (so do the tails past each slot's position). The kernel walks the
+    sub-table from its first entry, weighs the rows before ``first`` exactly
+    0 and zeroes their V in VMEM: the clean pool's gather-path context. The
+    positions put the first key on a page's first row, inside a page, two
+    pages into the sub-table (the table's end clips the sub-table's start),
+    and before the table's first row (a context shorter than the window)."""
+    rng = np.random.default_rng(31)
+    clean = _pool(rng, 2)
+    bt_np = 1 + np.arange(4 * PAGES).reshape(4, PAGES)
+    bt_np[1] = bt_np[1, ::-1]  # one slot's pages descend: a DMA a page
+    full = PAGES * PS - 1
+    positions = np.minimum([window - 1 + PS, window + 5, full, min(window, PAGES * PS) - 3], full)
+    seen = np.zeros((N_PAGES, PS), bool)
+    for row, pos in zip(bt_np, positions):
+        for at in range(max(pos - window + 1, 0), pos + 1):
+            seen[row[at // PS], at % PS] = True
+        row[: max(pos - window + 1, 0) // PS] = 0  # given back
+    assert (bt_np == 0).any() or window >= PAGES * PS
+    poisoned = tuple(jnp.where(seen[None, :, :, None], a, jnp.nan) for a in clean)
+    assert np.isnan(np.asarray(poisoned[1][0, 0])).all()  # the junk page
+    bt, pos = jnp.asarray(bt_np, jnp.int32), jnp.asarray(positions, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((4, 8, HEAD_DIM)), jnp.float32)
+    rows = jnp.ones((4,), bool)
+    want = _window_gather_attention(q, clean, 1, bt, pos, window, 0.3)
+    got, (lengths, runs, first) = _window_kernel(q, poisoned, 1, bt, pos, rows, window, 0.3)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+    pw = min(-(-(window + 1) // PS) + 1, PAGES)
+    assert runs.shape[0] == 4 and np.asarray(lengths).max() <= pw * PS
+    if window < PAGES * PS:
+        # the oldest visible key's place in the sub-table: 0 on a page's first row, then inside a page, then
+        # two pages and three rows in (clipped at the table's end), then a context shorter than the window
+        p0 = np.clip((positions - (window - 1)) // PS, 0, PAGES - pw)
+        assert np.asarray(first).tolist() == np.maximum(positions - (window - 1) - p0 * PS, 0).tolist()
+        assert np.asarray(first)[2] >= 2 * PS and np.asarray(first)[3] == 0
+        assert np.asarray(lengths).tolist() == (positions + 1 - p0 * PS).tolist()
+    # a slot outside ``rows`` reads one key of its sub-table's first page, whatever lies after
+    some = jnp.asarray([True, False, True, False])
+    got_some, (lengths, _runs, first) = _window_kernel(q, poisoned, 1, bt, pos, some, window, 0.3)
+    assert np.asarray(lengths)[[1, 3]].tolist() == [1, 1] and np.asarray(first)[[1, 3]].tolist() == [0, 0]
+    np.testing.assert_array_equal(got_some[[0, 2]], got[[0, 2]])
+
+
+def test_a_block_wholly_before_the_first_key_adds_nothing():
+    """``first`` past the first block's last row (no table of ``_window_table``
+    puts it there; the kernel does not lean on that): the block's scores are
+    all masked, and what its keys weigh against a maximum of the mask value
+    is wiped by the online softmax's rescaling at the first block that holds
+    a visible key."""
+    rng = np.random.default_rng(37)
+    pool = _pool(rng, 2)
+    bt = jnp.asarray(1 + np.arange(2 * PAGES).reshape(2, PAGES), jnp.int32)
+    positions = jnp.asarray([PAGES * PS - 1, 7 * PS], jnp.int32)
+    first = jnp.asarray([4 * PS + 3, 5 * PS], jnp.int32)  # blocks of 4 pages = 16 rows
+    lengths, runs = gqa.step_reads(bt, positions, None, PS)
+    q = jnp.asarray(rng.standard_normal((2, 8, HEAD_DIM)), jnp.float32)
+    got = np.asarray(gqa.gqa_decode_attention(q, pool[0], pool[1], 0, bt, lengths, runs, first, scale=0.3, interpret=True))
+    ck, cv = _paged_gather(pool, 0, bt, 2)
+    k_pos = jnp.arange(ck.shape[2])[None, :]
+    visible = (k_pos <= positions[:, None]) & (k_pos >= first[:, None])
+    want = np.asarray(_attend(q[:, None], ck, cv, visible[:, None, :], scale=0.3)[:, 0])
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+
+
+@pytest.mark.parametrize("heads", [48, 72])
+def test_groups_of_six_and_nine_heads_of_128_match_the_gather_path(heads):
+    """The laguna-s-2.1 cell's two head counts over 8 K/V heads of 128 (rows
+    of 1024): 48 query heads are three sublane tiles of the block-diagonal
+    query, 72 are padded to 80 with zero rows that the caller never sees.
+    Full table and windowed sub-table alike."""
+    rng = np.random.default_rng(41)
+    d, kv = 128, 8
+    pool = tuple(jnp.asarray(rng.standard_normal((1, 12, PS, kv * d)), jnp.float32) for _ in range(2))
+    bt = jnp.asarray([[1, 2, 3, 4, 5], [11, 9, 10, 7, 8]], jnp.int32)
+    positions = jnp.asarray([5 * PS - 1, 2 * PS + 1], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((2, heads, d)), jnp.float32)
+    wide = np.asarray(gqa._block_diagonal(q, kv))
+    assert wide.shape == (2, 48 if heads == 48 else 80, kv * d) and not wide[:, heads:].any()
+    ck, cv = _paged_gather(pool, 0, bt, kv)
+    visible = jnp.arange(ck.shape[2])[None, None, :] <= positions[:, None, None]
+    want = np.asarray(_attend(q[:, None], ck, cv, visible, scale=d**-0.5)[:, 0])
+    reads = gqa.step_reads(bt, positions, None, PS)
+    got = np.asarray(gqa.gqa_decode_attention(q, pool[0], pool[1], 0, bt, *reads, scale=d**-0.5, interpret=True))
+    assert got.shape == (2, heads * d)
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+    want = _window_gather_attention(q, pool, 0, bt, positions, 6, d**-0.5)
+    got, _reads = _window_kernel(q, pool, 0, bt, positions, None, 6, d**-0.5)
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
 
 
 def test_the_kernel_refuses_what_it_cannot_read():

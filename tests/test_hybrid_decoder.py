@@ -425,8 +425,8 @@ def test_the_other_families_keep_their_own_fused_programs(weights):
     step, chunk = gpt2_family.fused_programs()
     assert step is _fused_step and chunk is _fused_chunk and gpt2_family.state_init is None
     moe = md.moe_family(md.MoEDecoderConfig())
-    # two page kinds (sliding layers): the int8 pool; the tiers and prefix export where there is one kind
-    assert moe.state_init is None and moe.serves == {"kv_int8"} and moe.cfg.two_kinds
+    # two page kinds (sliding layers): the int8 pool and (PR 48) the step's kernel; the tiers and prefix export where there is one kind
+    assert moe.state_init is None and moe.serves == {"attn_kernel", "kv_int8"} and moe.cfg.two_kinds
     assert {"kv_int8", "host_tier", "prefix_export"} <= md.moe_family(md.MoEDecoderConfig(period=1)).serves
     hstep, hchunk = FAM.fused_programs()
     assert (hstep.__name__, hchunk.__name__) == ("_fused_step", "_fused_chunk")  # one name in a trace

@@ -333,6 +333,13 @@ def test_gpt2_large_kernel_step_lowers_to_the_text_it_had(topo):
         ("gqa_float32", 512, 16, ""),  # grouped heads over a four-byte pool: neither kernel's
         ("gqa_int8", 512, 16, ""),  # the int8 pool's six components
         ("gqa_mesh", 512, 16, ""),  # a decode mesh
+        ("two_kinds", 1024, 16, "mosaic"),  # the laguna-s-2.1 cell: 48 / 72 query heads over 8 K/V heads of 128, two page kinds
+        ("two_kinds", 512, 16, "mosaic"),  # the mellum2-12b-a2.5b cell's rows (heads of 64 here)
+        ("two_kinds", 1024, 8, ""),  # a page under a two-byte float's sublane tile, in both kinds
+        ("two_kinds_one_float32", 1024, 16, ""),  # one kind's planes four bytes wide: the other alone cannot take the step
+        ("two_kinds_one_ragged", 1024, 16, ""),  # the sliding layers' 44 query heads are 5.5 to a K/V head
+        ("two_kinds_int8", 1024, 16, ""),  # the int8 pool: six planes a kind
+        ("two_kinds_mesh", 1024, 16, ""),  # a decode mesh
     ],
 )
 def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, width, page_size, want):
@@ -369,6 +376,38 @@ def test_step_attn_kernel_is_chosen_only_where_mosaic_tiles_it(topo, pool_kind, 
         else:
             with pytest.raises(ValueError, match="kernel_tiles"):
                 jax.jit(attend).lower(*shapes)
+        return
+    if pool_kind.startswith("two_kinds"):
+        # the sparse-expert family's pool of two page kinds: the full layers' planes, then the sliding layers' (fewer
+        # pages, more layers), asked once a kind with that kind's query heads; the sub-table's kernel alone compiles
+        # (the whole step at the cell's widths is ``test_the_two_page_kinds_programs_compile_...`` below)
+        from seldon_core_tpu.models import moe_decoder as md
+        from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention, step_reads
+
+        def arr(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        fam = md.moe_family(md.MoEDecoderConfig())
+        assert fam.cfg.two_kinds and "attn_kernel" in fam.serves
+        full_dt = jnp.float32 if pool_kind == "two_kinds_one_float32" else jnp.bfloat16
+        pool = (arr((1, 256, page_size, width), full_dt),) * 2 + (arr((3, 128, page_size, width), jnp.bfloat16),) * 2
+        if pool_kind == "two_kinds_int8":
+            kind = lambda layers, pages: (  # noqa: E731
+                arr((layers, pages, page_size, width), jnp.int8), *(arr((layers, pages, page_size), jnp.float32),) * 2) * 2
+            pool = kind(1, 256) + kind(3, 128)
+        mesh = Mesh(np.asarray(topo.devices[:2]), ("model",)) if pool_kind == "two_kinds_mesh" else None
+        heads_window = 44 if pool_kind == "two_kinds_one_ragged" else 72
+        assert _step_attn_kernel(fam, pool, mesh, 48, 8, heads_window) == want
+        if pool_kind != "two_kinds" or not want:
+            return
+
+        def attend(q, pk, pv, bt, positions, k0, rows):
+            reads = step_reads(bt, positions, rows, page_size, k0, 512)
+            return gqa_decode_attention(q, pk, pv, 2, bt, *reads, scale=0.1)
+
+        shapes = (arr((4, 72, width // 8), jnp.bfloat16), *pool[2:], arr((4, 34), jnp.int32), arr((4,), jnp.int32),
+                  arr((4,), jnp.int32), arr((4,), jnp.bool_))
+        assert "tpu_custom_call" in jax.jit(attend).lower(*shapes).compile().as_text()
         return
     if pool_kind.startswith("gqa"):
         # a two-plane pool whose 8 K/V heads serve 32 query heads: ops/gqa_decode.py's kernel alone (the whole steps
@@ -948,6 +987,43 @@ def test_conv_family_updates_pool_and_state_rows_in_place_at_lfm2_widths(topo, p
         assert mem.temp_size_in_bytes < 64 * 144 * 16 * 512 * 4  # under ONE gathered float32 cache
 
 
+def test_the_conv_step_with_the_kernel_lowers_to_the_text_it_had_before_the_windowed_form(topo):
+    """The short-convolution family's step with ops/gqa_decode.py's kernel at
+    the lfm2-24b-a2b cell's widths (32 / 8 heads of 64, no first key, no padded
+    head row), 8 layers: what it lowers to for the chip is what it lowered to
+    before the kernel took a windowed table and 6- and 9-head groups (PR 48's
+    parent, e75f1e9, hashed there with ``_without_locations``): the windowed
+    form is a static variant, and a call without ``first`` traces the kernel
+    it traced, argument for argument."""
+    import hashlib
+
+    from seldon_core_tpu.models import conv_decoder as cd
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = cd.ConvDecoderConfig(
+        vocab=65536, hidden=2048, layers=8, attn_layers=(2, 6), heads=32, kv_heads=8, head_dim=64, dense_layers=2,
+        dense_ffn=11776, ffn=1536, experts=64, experts_held=8, first_expert=0, experts_per_tok=4,
+    )
+    fam = cd.conv_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: cd.init_conv_decoder(cfg, 0, jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 6144, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, 69)))
+    n, i32, f32 = 64, jnp.int32, jnp.float32
+    args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+            arr((), i32), arr((n,), jnp.bool_))
+    step, _chunk = fam.fused_programs("mosaic")
+    text = _without_locations(jax.jit(step, donate_argnums=(1, 2)).lower(params, pool, rec, *args).as_text())
+    assert text.count('"mosaic:') == 1  # the two attention layers' calls lower the kernel once
+    assert hashlib.sha256(text.encode()).hexdigest() == "32aa74fc4a6f8982c535c233395873bb41bd59176623d0c7e99627aa396fb265"
+
+
 @pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
 def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, family):
     """Each family's fused step at its cell's rows and vocabulary: what runs
@@ -977,16 +1053,22 @@ def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, fam
     assert nbytes(args) <= mem.argument_size_in_bytes < nbytes(args) * 1.001 + 2**16
 
 
-@pytest.mark.parametrize("program", ["step", "chunk_4_256"])
+@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_4_256"])
 def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(topo, monkeypatch, program):
-    """The sparse-expert family as PR 47 left it, at the laguna-s-2.1 cell's
+    """The sparse-expert family, as PR 47 left it and (``step_kernel``) with
+    the step PR 48 gave it on a TPU, at the laguna-s-2.1 cell's
     widths (48 / 72 query heads over 8 key/value heads of 128, the per-head
     gate, half-rotary full layers, a dense layer, a shared expert and 32 of 256
     routed ones) over its leading dense + full layer and two sliding layers:
     the step of 64 slots and the (4, 256) chunk over 464-entry tables of BOTH
     page kinds (14,000 full-kind pages, the window kind's derived count). Both
     kinds' planes alias whole, and no float32 copy of a whole context exists
-    for a SLIDING layer: its gather is the window's pages."""
+    for a SLIDING layer: its gather is the window's pages. With the kernel
+    the step has a Mosaic call a layer under its kind's ``attn`` scope (two
+    lowerings: 48 heads over the 464-entry table, 72 padded to 80 over the
+    34-entry sub-table with a first key a slot; blocks of 64 pages of rows of
+    1024 are 8 MiB of VMEM scratch, which Mosaic takes), no gathered context
+    of either kind, and temporaries of megabytes where the gather's are 2 GB."""
     from seldon_core_tpu.models import moe_decoder as md
     from seldon_core_tpu.ops import moe
     from seldon_core_tpu.serving.kv_pool import window_pool_pages
@@ -1012,11 +1094,11 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, (14000, n_win), 16, jnp.bfloat16)))
     assert [a.shape[:2] for a in pool] == [(1, 14000)] * 2 + [(2, n_win)] * 2
     i32, f32 = jnp.int32, jnp.float32
-    step, chunk = fam.fused_programs()
-    n = 64 if program == "step" else 4
+    step, chunk = fam.fused_programs("mosaic" if program == "step_kernel" else "")
+    n = 4 if program == "chunk_4_256" else 64
     bt = (arr((n, 464), i32), arr((n, 464), i32))
     tail = (arr((n,), f32), arr((n,), i32), arr((), i32), arr((), i32))
-    if program == "step":
+    if program.startswith("step"):
         fn, args = step, (params, pool, bt, arr((n,), i32), arr((n,), i32), *tail, arr((n,), jnp.bool_))
     else:
         fn, args = chunk, (params, pool, bt, arr((n, 256), i32), arr((n,), i32), arr((n,), i32), *tail)
@@ -1030,3 +1112,9 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     # a sliding layer's gathered cache is its window's pages (34 or 50 of them), never the table's 464
     assert not re.findall(r"f32\[%d,8,7424,128\][^\n]*/win/" % n, text)
     assert mem.temp_size_in_bytes < 3 << 30
+    if program == "step_kernel":
+        calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_step\)/(full|win)/attn/', text)
+        assert sorted(calls) == ["full", "win", "win"]  # a call a layer, under its kind's scope
+        assert re.search(r'op_name="jit\(_fused_step\)/full/kv_gather/', text)  # the lengths and run flags
+        assert not re.search(r"\[64,(464|34),16,1024\]|\[64,8,(7424|544),128\]", text)  # no gathered context, either kind
+        assert mem.temp_size_in_bytes < 64 << 20

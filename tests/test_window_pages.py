@@ -30,6 +30,9 @@ from harness.correct import judge_generated  # noqa: E402
 
 from seldon_core_tpu.models import moe_decoder as md  # noqa: E402
 from seldon_core_tpu.models.decoder import FamilyNotServed  # noqa: E402
+from seldon_core_tpu.ops import gqa_decode as gqa  # noqa: E402
+from seldon_core_tpu.ops import mla as mla_ops  # noqa: E402
+from seldon_core_tpu.serving import decode_programs as dp  # noqa: E402
 from seldon_core_tpu.serving import decode_scheduler as ds  # noqa: E402
 from seldon_core_tpu.serving.kv_pool import PageAllocator, PagedKVPool, ring_pages, window_pool_pages  # noqa: E402
 
@@ -329,7 +332,7 @@ def _zoo(**kw):
 async def test_scheduler_serves_both_page_kinds_to_the_references_tokens(ref):
     ms = _zoo()
     fam = ms.generative["family"]
-    assert fam.cfg == CFG and fam.frame_counters[-1] == "moe_local_picks"
+    assert fam.cfg == CFG and fam.frame_counters[-2:] == ("moe_local_picks", "attn_run_pages")
     sched = ds.DecodeScheduler(
         ms.params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4, prefix_slots=2, prefill_chunk=16,
         kv_page_size=PS, family=fam,
@@ -361,13 +364,139 @@ async def test_scheduler_serves_both_page_kinds_to_the_references_tokens(ref):
     assert any("kv_win" in f.to_dict() for f in frames) and any(f.moe_local_picks for f in frames)
     # a round's named counts are the sum of its dispatches; the step's own ride beside them
     stepped = [f for f in frames if f.busy_ns[1] > 0]
-    assert stepped and all(len(f.step_counts) == 4 and f.step_counts[0] <= f.moe_rows for f in stepped)
+    # the four routing counts, then the run pages of the step's kernel (none on the CPU: the step gathers)
+    assert stepped and all(len(f.step_counts) == 5 and f.step_counts[0] <= f.moe_rows for f in stepped)
+    assert not any(f.step_counts[4] or f.attn_run_pages for f in stepped)
     assert all(f.step_counts == () for f in frames if f.busy_ns[1] == 0)
     for f in stepped:
-        own = (f.moe_rows, f.moe_experts_hit, f.moe_load_max, f.moe_local_picks)
+        own = (f.moe_rows, f.moe_experts_hit, f.moe_load_max, f.moe_local_picks, f.attn_run_pages)
         assert (f.step_counts == own) == (f.chunk_rows == 0) or f.step_counts == own  # alone in its round: the same numbers
         assert ("step_counts" in f.to_dict()) == bool(f.chunk_rows)
     await sched.close()
+
+
+# (h) the step's kernel (ops/gqa_decode.py) over both page kinds, under the interpreter
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Runs of 2 table entries, blocks of 4 (tests/test_gqa_decode.py): the
+    full kind's tables of 9-10 pages have block boundaries inside them."""
+    monkeypatch.setattr(mla_ops, "RUN_PAGES", 2)
+    monkeypatch.setattr(mla_ops, "BLOCK_PAGES", 4)
+
+
+def _kinds_fetched(bt, pos, rows):
+    """[pages read, pages in runs] of one layer a kind, by the kernel's own
+    arithmetic, for the tables, positions and rows a step was handed."""
+    total = np.zeros(2, np.int64)
+    pos, rows = jnp.asarray(pos), jnp.asarray(rows)
+    full, win = jnp.asarray(bt[0]), jnp.asarray(bt[1])
+    reads = gqa.step_reads(full, pos, rows, PS)
+    total += np.asarray(gqa.pages_fetched(*reads, PS, full.shape[1]))
+    sub, k0 = md._window_table(win, pos, 1, PS, WINDOW)
+    reads = gqa.step_reads(sub, pos, rows, PS, k0, WINDOW)
+    total += np.asarray(gqa.pages_fetched(*reads[:2], PS, sub.shape[1]))
+    return total.tolist()
+
+
+def test_the_kernel_step_reads_both_kinds_where_they_lie_and_never_a_page_given_back(weights, small_blocks):
+    """A context of four windows served through the gather (pages given back
+    on the way), then one more step twice from the same pool: through the
+    gather, and through the kernel over a pool whose junk page holds NaN in
+    both kinds (what the window kind's given-back entries and the other
+    slots' tables name). The generating slot's logits agree to float32
+    rounding, its new rows land in the same pages, a chunk asked for the
+    kernel gathers all the same, and the step counts its run pages."""
+    params = weights[jnp.float32]
+    ids = _ids(8)
+    pool = Pool(params)
+    assert pool.alloc.try_admit(1, (), 0)
+    at = 33
+    pool.serve(params, ids[:at], 1, chunks=(8, 8, 8))
+    assert pool.alloc.win.stat_released >= 4
+    pool.copy(pool.alloc.prepare_write(1, at, 1))
+    tables = jnp.stack(pool.tables(1))
+    assert (np.asarray(tables[1, 1, : (at - WINDOW) // PS]) == 0).all()  # given back: the junk page
+    toks, positions = np.zeros((3, 1), np.int32), np.zeros(3, np.int32)
+    toks[1], positions[1] = ids[at], at
+    rows = jnp.asarray([False, True, False])
+    args = (jnp.asarray(toks), jnp.asarray(positions))
+    want, _h, state_g, counted_g = FAM.paged_forward(params, pool.state, tables, *args, rows=rows)
+    poisoned = tuple(a.at[:, 0].set(jnp.nan) for a in pool.state)
+    got, _h, state_k, counted_k = FAM.paged_forward(params, poisoned, tables, *args, rows=rows, attn_kernel="interpret")
+    assert np.isfinite(np.asarray(got[1])).all()
+    np.testing.assert_allclose(np.asarray(got[1, 0]), np.asarray(want[1, 0]), rtol=0, atol=2e-5)
+    assert len(counted_k) == len(FAM.frame_counters) == 5 and int(counted_g[-1]) == 0
+    assert counted_k[:4].tolist() == counted_g[:4].tolist()  # the routing counts are the real rows' alone
+    assert int(counted_k[-1]) == _kinds_fetched(np.asarray(tables), positions, rows)[1] > 0
+    mine = [np.asarray(t)[1][np.asarray(t)[1] > 0] for t in tables]
+    for i, (a, b) in enumerate(zip(state_g, state_k)):
+        np.testing.assert_allclose(np.asarray(a[:, mine[i // 2]]), np.asarray(b[:, mine[i // 2]]), rtol=0, atol=2e-5)
+    chunked = FAM.paged_forward(
+        params, pool.state, tables, jnp.asarray(np.zeros((3, 2), np.int32)), jnp.asarray(positions),
+        counts=jnp.zeros((3,), jnp.int32), attn_kernel="interpret",
+    )
+    assert int(chunked[3][-1]) == 0
+
+
+async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens_past_a_window(monkeypatch, small_blocks):
+    """With the ONE place of choice answering "interpret" a scheduler over
+    both page kinds serves the gather scheduler's greedy tokens over contexts
+    of four windows (window-kind pages given back mid-run, a hinted prefix
+    hit), with no recompile, and every plain round's frame carries what the
+    kernel's own arithmetic fetches from BOTH kinds' tables for the
+    positions and rows the step was handed."""
+    ms = _zoo()
+    fam = ms.generative["family"]
+    kw = dict(seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4, prefix_slots=2, prefill_chunk=16, kv_page_size=PS, family=fam)
+    prompts = np.random.default_rng(3).integers(0, 96, (5, SEQ)).astype(np.int32)
+    prompts[1:, :16] = prompts[0, :16]
+
+    async def serve(sched):
+        first = await sched.submit(prompts[0], cache_prefix=16)
+        return [first, *await asyncio.gather(*(sched.submit(p) for p in prompts[1:]))]
+
+    gather = ds.DecodeScheduler(ms.params, **kw)
+    assert gather.programs.attn_kernel == "" and "attn_kernel" in fam.serves  # the CPU backend: the oracle path
+    gather.warmup()
+    want = await serve(gather)
+    pages = gather.pool.pages_per_slot
+    plain = [f for f in gather.flight.snapshot() if f.attn_pages_table]
+    # through the gather a slot's whole full-kind table and its sub-table of ceil((8 + 1) / 4) + 1 window-kind entries
+    assert plain and all((f.attn_pages_read, f.attn_pages_table, f.attn_run_pages) == (4 * (pages + 4), 8 * pages, 0) for f in plain)
+    await gather.close()
+
+    asked = []
+    monkeypatch.setattr(dp, "_step_attn_kernel", lambda *a: asked.append(a[3:]) or "interpret")
+    kernel = ds.DecodeScheduler(ms.params, **kw)
+    assert kernel.programs.attn_kernel == "interpret" and asked == [(4, 2, 6)]  # both kinds' query heads, the K/V heads
+    kernel.warmup()
+    handed = []
+    step = kernel.programs.step
+
+    def spy(bt, toks, pos, temps, topks, tick, rows):
+        handed.append((np.array(bt), np.array(pos), np.array(rows)))
+        return step(bt, toks, pos, temps, topks, tick, rows)
+
+    monkeypatch.setattr(kernel.programs, "step", spy)
+    got = await serve(kernel)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert kernel.recompiles_since_warmup() == 0 and kernel.stat_prefix_hits == 4
+    assert kernel.pool.alloc.win.stat_released > 0
+    kernel.pool.alloc.check()
+    plain = [f for f in kernel.flight.snapshot() if f.attn_pages_table]
+    assert len(plain) == len(handed) and {f.attn_pages_table for f in plain} == {8 * pages}
+    for f, (bt, pos, rows) in zip(plain, handed):
+        assert [f.attn_pages_read, f.attn_run_pages] == _kinds_fetched(bt, pos, rows)
+        assert f.step_counts[-1] == f.attn_run_pages
+        # the full kind up to each generating slot's position, the window kind at most its sub-table, one page each for the rest
+        held = -(-(pos[rows] + 1) // PS)
+        assert held.sum() + rows.sum() + 2 * (~rows).sum() <= f.attn_pages_read <= held.sum() + 4 * rows.sum() + 2 * (~rows).sum()
+    assert any(f.attn_run_pages for f in plain) and any(rows.any() and not rows.all() for _bt, _pos, rows in handed)
+    assert max(pos[rows].max() for _bt, pos, rows in handed if rows.any()) >= 4 * WINDOW
+    await kernel.close()
 
 
 async def test_admission_throttles_by_kind_and_never_deadlocks():
