@@ -75,6 +75,8 @@ from seldon_core_tpu.models.decoder import (
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, SCOPE_ROPE, _attend, _rms, _rope
 from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
 from seldon_core_tpu.ops.moe import (
+    HELD_COUNTERS,
+    N_HELD_COUNTERS,
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
     gated_mlp,
@@ -315,10 +317,10 @@ def _attention(cfg: ConvDecoderConfig, ki: int, p, x, pool, bt, positions, count
 def _feed_forward(cfg: ConvDecoderConfig, p, h, valid):
     """A layer's feed-forward over h[T, d]: the dense MLP, or the routed
     experts held here under this family's gate. Returns (y[T, d],
-    counters[4]: zeros for a dense layer)."""
+    counters[6]: zeros for a dense layer)."""
     if "mlp" in p:
         with jax.named_scope(SCOPE_DENSE_MLP):
-            return gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((4,), jnp.int32)
+            return gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
     gates, experts = route_sigmoid_biased(
         p["moe"]["router"], p["moe"]["router_bias"], h, cfg.experts_per_tok, cfg.routed_scale
     )
@@ -336,7 +338,7 @@ def _forward(
     ``decode_programs._step_attn_kernel``'s answer) lets a dispatch of ONE
     query a slot read the pool through ops/gqa_decode.py's kernel, every
     other shape gathers. Returns (logits [n, m or 1, vocab] float32, pool,
-    rec, counters[6] int32: ``ConvDecoder.frame_counters``)."""
+    rec, counters[8] int32: ``ConvDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     if counts is not None:
@@ -346,7 +348,7 @@ def _forward(
     reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    cnt = jnp.zeros((4,), jnp.int32)
+    cnt = jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
     for li, p in enumerate(params["layers"]):
         ci = cfg.cache_index(li)
         if li in cfg.attn_layers:
@@ -388,11 +390,12 @@ class ConvDecoder:
     name = "conv"
     # what the programs' readback carries after the tokens (FlightFrame
     # fields): the routing over the experts HELD and the picks of real rows
-    # that landed on one (the latent family's four), and the batch rows
+    # that landed on one and the layer calls that ran the grouped form and ran
+    # it compact (the latent family's six), and the batch rows
     # whose conv state the dispatch advanced, and where the step's kernel ran
     # the pages it fetched in run DMAs (one layer's K)
     frame_counters = (
-        "moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "conv_rows", "attn_run_pages",
+        "moe_rows", "moe_experts_hit", "moe_load_max", *HELD_COUNTERS, "conv_rows", "attn_run_pages",
     )
     # beside the plain rounds: a step that reads the pool in place (ops/gqa_decode.py's kernel)
     serves = frozenset({"attn_kernel"})

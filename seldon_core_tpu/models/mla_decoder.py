@@ -89,6 +89,8 @@ from seldon_core_tpu.ops.mla import (
     pages_fetched,
 )
 from seldon_core_tpu.ops.moe import (
+    HELD_COUNTERS,
+    N_HELD_COUNTERS,
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
     SCOPE_SHARED_EXPERT,
@@ -319,7 +321,7 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
     ``_forward`` found the program set to have chosen them (``runs``, with
     ``read`` [n] the leading queries of a row somebody reads; ``interpret``:
     under the Pallas interpreter), else the walk. Returns (x, pool,
-    counters[4]: zeros for a dense layer, the two blocks' larger
+    counters[6]: zeros for a dense layer, the two blocks' larger
     ``mhc_resid_ppm`` or None)."""
     c = cfg
     hc = c.hc_mult > 1
@@ -361,7 +363,7 @@ def _layer(cfg: MLADecoderConfig, li: int, p, x, pool, bt, positions, counts, va
         h = _rms(p["ln2"], u, c.rms_eps).reshape(n * m, -1)
         if "mlp" in p:
             with jax.named_scope(SCOPE_DENSE_MLP):
-                y, cnt = gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((4,), jnp.int32)
+                y, cnt = gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
         else:
             real = valid.reshape(-1)
             gates, experts = _route(c, p["moe"], h)
@@ -383,7 +385,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     query a row and the chunk's for more (``kernel_takes``); "" walks. With ``hc_mult`` > 1 the state between the
     embedding and the final norm is [hc_mult, n, m, d]; ``hidden`` is the
     streams' sum. Returns (logits[n, m or 1, vocab] float32, hidden[n, m, d],
-    pool, counters[7 or 8] int32: ``MLADecoder.frame_counters``)."""
+    pool, counters[9 or 10] int32: ``MLADecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
     last = jnp.full((n,), m, positions.dtype)  # queries a row really has
@@ -406,7 +408,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
         if cfg.hc_mult > 1:
             x = mhc.spread(x, cfg.hc_mult)
-    cnt = jnp.zeros((4,), jnp.int32)
+    cnt = jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
     resid = []
     for li, lp in enumerate(params["layers"]):
         x, pool, c, r = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, n_keys, last, runs, attn_kernel == "interpret")
@@ -442,7 +444,8 @@ class MLADecoder:
     name = "mla"
     # what the programs' readback carries after the tokens (FlightFrame
     # fields): the routing over the experts HELD, the picks of real rows that
-    # landed on one, and the latent rows the dispatch's live rows attended
+    # landed on one, the layer calls that ran the grouped form and ran it
+    # compact, and the latent rows the dispatch's live rows attended
     # over (each row's keys, summed; one layer's), and where a kernel ran (the
     # step's or the chunk's) the pages it fetched for them and those that came
     # in run DMAs;
@@ -452,7 +455,7 @@ class MLADecoder:
     # as a steady step round is, carries that dispatch's own)
     @property
     def frame_counters(self) -> tuple:
-        base = ("moe_rows", "moe_experts_hit", "moe_load_max", "moe_local_picks", "mla_ctx_rows",
+        base = ("moe_rows", "moe_experts_hit", "moe_load_max", *HELD_COUNTERS, "mla_ctx_rows",
                 "mla_pages_read", "mla_run_pages")
         return base + (("mhc_resid_ppm",) if self.cfg.hc_mult > 1 else ())
 

@@ -88,6 +88,8 @@ from seldon_core_tpu.models.decoder import (
 )
 from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention, pages_fetched, step_reads
 from seldon_core_tpu.ops.moe import (
+    HELD_COUNTERS,
+    N_HELD_COUNTERS,
     SCOPE_DENSE_MLP,
     SCOPE_MOE_COMBINE,
     SCOPE_SHARED_EXPERT,
@@ -181,8 +183,9 @@ class MoEDecoderConfig:
     @property
     def n_counters(self) -> int:
         """What an expert layer counts: rows, experts hit, the fullest expert's
-        rows, and over a share of the experts the picks that landed on it."""
-        return 4 if self.experts_held else 3
+        rows, and over a share of the experts the picks that landed on it and
+        the layer calls that ran the grouped form, and ran it compact."""
+        return N_HELD_COUNTERS if self.experts_held else 3
 
     def is_full(self, layer: int) -> bool:
         return layer % self.period == (0 if self.full_first else self.period - 1)
@@ -393,7 +396,7 @@ def _kind_pool(cfg: MoEDecoderConfig, full: bool, pool: tuple, bt):
 def _ffn(cfg: MoEDecoderConfig, p, h, valid):
     """A layer's feed-forward over h[T, d] (normed): the dense MLP of a
     leading layer, else the routed experts (all of them, or the share held)
-    and the shared one. Returns (y[T, d], counters[3 or 4])."""
+    and the shared one. Returns (y[T, d], counters[3 or 6])."""
     if "mlp" in p:
         with jax.named_scope(SCOPE_DENSE_MLP):
             return gated_mlp(p["mlp"]["gate_up"], p["mlp"]["down"], h), jnp.zeros((cfg.n_counters,), jnp.int32)
@@ -576,10 +579,11 @@ class MoEDecoder:
     def frame_counters(self) -> tuple:
         """What paged_forward's extra output counts, in order (FlightFrame
         fields); over a share of the experts also the picks that landed on
-        it; last, the pages of one layer a page kind that the step's kernel
-        fetched in run DMAs (0 where it gathers)."""
+        it and the layer calls that ran the grouped form, and ran it compact
+        (``moe_held_ffn``'s six); last, the pages of one layer a page kind
+        that the step's kernel fetched in run DMAs (0 where it gathers)."""
         base = ("moe_rows", "moe_experts_hit", "moe_load_max")
-        return base + (("moe_local_picks",) if self.cfg.experts_held else ()) + ("attn_run_pages",)
+        return base + (HELD_COUNTERS if self.cfg.experts_held else ()) + ("attn_run_pages",)
 
     @property
     def serves(self) -> frozenset:

@@ -123,11 +123,57 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 # experts get a row, so the masked form reads 12/11.2 of the least bytes and
 # no sort; its rows x held FLOPs reach the weights' read time at 240 rows
 # (the chip's 197 TFLOP/s over 819 GB/s), which is ``MASKED_MAX_ROWS`` again.
-# Above it only a sixteenth of the T*k assignments are held, but a static
-# shape must take them all: the grouped form takes ``GROUPED_BLOCK_ROWS``
-# rows at most (``moe_held_ffn`` refuses more), because its sorted copy,
-# [T*k, hidden], of a (64, 256) chunk round would be 1.9 GB and its float32
-# result twice that; the chunk ladder's widest entry is (4, 256). Measured
+# Above it only held/routed of the T*k assignments are held (a sixteenth
+# there, an eighth at 32 of 256 and 8 of 64), and since PR 49 the grouped
+# form carries those and not every assignment: blocks of ``held_capacity``
+# rows, ``COMPACT_SHARE`` times the even share + the junk row an expert, in
+# whole row tiles, a STATIC number read from shapes (2,816 of a (4, 256)
+# chunk's 10,240 at 32 of 256 top 10; 768 of 2,048 and 1,280 of 4,096 at 8
+# of 64 top 4; T*k itself where a chip holds all its experts, whose program
+# is what it was). A static shape must still take whatever the router
+# sends: the sorted assignments that have a product (every real held pick
+# and the junk row an expert; the absent picks sort behind them) go through
+# the products a block at a time, in a loop whose trip count the device
+# reads from the groups' sizes (``_compact_blocks``): ONE block for any
+# routing up to twice the even share, more for a routing that leans harder
+# on this chip, so no pick is dropped or capped whatever the routing, and
+# ``moe_held_ffn`` counts the calls that fit one block (``HELD_COUNTERS``).
+# A block's rows come in by ``take``, the products run over ``cap`` rows,
+# and the gate-scaled float32 results add to their tokens through a 0/1
+# [T, cap] product on the MXU, exact in float32 in three bfloat16 passes
+# (``_split_bf16``): no un-sort, which would rebuild the [T*k, d] array.
+# Measured on a v5e, seven layers chained in one program on the host's
+# clock, ms a layer (my chip runs, PR 49) at 1024 x 3072, 32 of 256, top 10,
+# f 1024 (604 MB of held weights: 0.74 ms at the chip's 819 GB/s) / 1024 x
+# 2048, 8 of 64, top 4, f 1536 / 1024 x 3584, 8 of 64, top 4, f 1024: full
+# width (every assignment a row, the parent's form) 3.565 / 0.741 / 0.885;
+# the two products alone over ``cap`` rows 1.207 / 0.381 / 0.423; one
+# compact block with this combine 1.457 / 0.518 / 0.594; with an XLA
+# scatter-add of the ``cap`` rows into [T, d] in its place 1.478 / 0.576 /
+# 0.735; with ``Precision.HIGHEST`` on a float32 0/1 product 1.521 / 0.492
+# / 0.584; with a ``take`` back to [T*k, d] 2.516 / 0.691 / 0.691 (the
+# careless version); with the rows' places from a cumulative count over
+# [T, held] and a 0/1 product in place of the T*k-key argsort and the
+# ``take`` 1.498 / 0.513 / 0.600: the sort is not what costs, and the
+# simplest dispatch stays. What takes the overflow: the block beside the
+# full-width form under a ``lax.cond`` on the count read 1.56 / 0.50-0.63 /
+# 0.66-0.68, and the blocks' loop 1.497 / 0.468 / 0.582 (at 512 rows of the
+# second shape 0.389 against 0.394); with EVERY pick sent to the held
+# experts, eight times H's share and four blocks, the loop reads 3.617 /
+# 1.297 / 1.631: what full width costs, and no routing of a cell comes near
+# it. In the cells (a whole chunk dispatch, traced): laguna's (4, 256) reads
+# 40.08 ms with the loop, 40.72 under the cond, 54.77 before; lfm2's (2,
+# 256), 38 layers of it, 31.65, 30.86 and 32.26: there a loop a layer costs
+# ~20 us more than a branch a layer. The loop stays: the cond traced,
+# lowered and loaded both forms in every layer (38 unrolled layers in two 256-token
+# chunk entries: 7.5 s more set-up with a warm compile cache where the
+# whole is 55, the loop 1.5; my chip runs, PR 49) and kept the full width's
+# [T*k, d] temporaries in the program. The full-width form itself
+# (a chip that holds all its experts) takes ``GROUPED_BLOCK_ROWS`` rows at
+# most (``moe_held_ffn`` refuses more, whatever the share): it holds [T*k,
+# hidden] sorted rows and their float32 products (1.9 GB and twice that for
+# a (64, 256) chunk round); the chunk ladder's widest entry is (4, 256).
+# Measured
 # on a v5e, one layer's routed part alone on the host's clock (my chip run,
 # PR 37; masked / grouped, ms): 64 rows 1.475 / 1.563, 128 rows 1.465 /
 # 1.631, 256 rows 1.659 / 1.962, 512 rows 3.087 / 2.770, 1024 rows
@@ -158,10 +204,22 @@ SCOPE_MOE_COMBINE = "moe_combine"  # un-sort, gate-weighted sum, counters
 # matches, the masked form can go). From 1024 rows the masked form's
 # rows x experts FLOPs bind (4 ms a layer).
 MASKED_MAX_ROWS = 256
-# the most rows of one dispatch of a layer that holds a SHARE of its experts
-# (``moe_held_ffn``): the grouped call holds [rows * k, hidden] sorted rows
-# and their float32 products
+# the most rows of one dispatch of ``moe_held_ffn``: at full width (every
+# expert held) the grouped call holds [rows * k, hidden] sorted rows and their
+# float32 products
 GROUPED_BLOCK_ROWS = 4096
+# the compact grouped form's block over the even share of a dispatch's
+# assignments that lands on the experts held (``held_capacity``). Even
+# routing of a (4, 256) chunk at 32 of 256 top 10 lands 1,280 picks here give
+# or take 33 (the 2,784 that fit a block are 45 deviations off); a prompt that
+# leans on this chip's experts has twice its share before its layer takes a
+# second block. At that capacity the layer reads 1.497 ms where full width
+# reads 3.565, and the two products alone over 2,816 rows 1.207 (my chip
+# runs, PR 49): what the room costs is inside the difference
+COMPACT_SHARE = 2
+# what ``moe_held_ffn`` counts after ``_load_counters``' three (FlightFrame fields)
+HELD_COUNTERS = ("moe_local_picks", "moe_grouped_calls", "moe_compact_calls")
+N_HELD_COUNTERS = 3 + len(HELD_COUNTERS)
 SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token takes, beside the routed ones
 SCOPE_DENSE_MLP = "dense"  # a leading dense layer's gated MLP
 
@@ -217,10 +275,27 @@ def _load_counters(sizes: jax.Array, rows: jax.Array) -> jax.Array:
     return jnp.stack([rows, jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
 
 
-def moe_experts_grouped(p: dict, x, gates, experts, valid) -> tuple[jax.Array, jax.Array]:
+def _split_bf16(a: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """float32 a = hi + mid + lo, each bfloat16 (3 x 8 bits of mantissa): a
+    0/1 matrix times each piece on the MXU, accumulated in float32, SELECTS
+    and sums float32 rows exactly, in three passes where
+    ``Precision.HIGHEST`` takes six. ``reduce_precision`` and not a cast
+    there and back, which the compiler is free to drop (excess precision)."""
+    hi = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+    mid = jax.lax.reduce_precision(a - hi, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16), (a - hi - mid).astype(jnp.bfloat16)
+
+
+def moe_experts_grouped(p: dict, x, gates, experts, valid, cap: int | None = None) -> tuple[jax.Array, jax.Array]:
     """The grouped form. p: {"gate_up": [E, d, 2f], "down": [E, f, d]};
     x[T, d]; gates/experts[T, k]; valid[T] bool (junk rows False).
-    Returns (y[T, d] in x's dtype with zeros on junk rows, counters[3])."""
+    Returns (y[T, d] in x's dtype with zeros on junk rows, counters[3]).
+    With ``cap`` (static; ``held_capacity``) the COMPACT form: the sorted
+    assignments that have a product (every real one and the junk row an
+    expert; the rest are absent picks, the junk group) go through the
+    products in blocks of ``cap`` rows, as many blocks as they fill: one
+    where the routing is anywhere near even, more where it is not, so no
+    pick is dropped whatever the routing."""
     t, k = experts.shape
     n_exp = p["gate_up"].shape[0]
     with jax.named_scope(SCOPE_MOE_DISPATCH):
@@ -235,6 +310,10 @@ def moe_experts_grouped(p: dict, x, gates, experts, valid) -> tuple[jax.Array, j
         flat = jnp.where(junk & (nth < n_exp), nth, flat)
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.sum(flat[:, None] == ids[None, :], axis=0, dtype=jnp.int32)
+    if cap is not None:
+        y = _compact_blocks(p, x, jnp.where(valid[:, None], gates, 0.0), order, sizes, cap)
+        return y, _load_counters(real, jnp.sum(valid))
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
         xs = jnp.take(x, order // k, axis=0)  # [T*k, d], sorted by expert
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         h = _gated_silu(_grouped_dot(xs, p["gate_up"].astype(x.dtype), sizes, x.dtype))
@@ -246,6 +325,40 @@ def moe_experts_grouped(p: dict, x, gates, experts, valid) -> tuple[jax.Array, j
         y = jnp.take(ys, back, axis=0).reshape(t, k, -1)
         y = jnp.sum(y * jnp.where(valid[:, None], gates, 0.0)[..., None], axis=1)
         return y.astype(x.dtype), _load_counters(real, jnp.sum(valid))
+
+
+def _compact_blocks(p: dict, x, gates, order, sizes, cap: int) -> jax.Array:
+    """y[T, d] of the compact grouped form: ``order[T*k]`` the assignments
+    sorted by expert, ``sizes[E]`` the groups' rows (junk rows an expert
+    counted in), ``gates[T, k]`` float32 with zeros on junk. Block b takes
+    the sorted assignments [b * cap, (b + 1) * cap), each group's rows among
+    them, and ADDS its gate-scaled float32 results to their tokens through a
+    0/1 [T, cap] product on the MXU, exact in float32 (``_split_bf16``): no
+    un-sort, which would rebuild the [T*k, d] array. The trip count is the
+    device's: ceil(sum(sizes) / cap)."""
+    t, k = gates.shape
+    w_in, w_out = p["gate_up"].astype(x.dtype), p["down"].astype(x.dtype)
+    ends = jnp.cumsum(sizes, dtype=jnp.int32)
+    total = ends[-1]
+    order = jnp.pad(order, (0, -order.shape[0] % cap))  # a whole last block; what is past ``total`` weighs nothing
+    flat_gates = jnp.pad(gates.reshape(-1), (0, order.shape[0] - t * k))
+
+    def block(b, y):
+        with jax.named_scope(SCOPE_MOE_DISPATCH):
+            at = b * cap
+            idx = jax.lax.dynamic_slice(order, (at,), (cap,))
+            here = jnp.clip(ends - at, 0, cap) - jnp.clip(ends - sizes - at, 0, cap)  # each group's rows in this block
+            xs = jnp.take(x, idx // k, axis=0)  # [cap, d], sorted by expert
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            ys = _grouped_dot(_gated_silu(_grouped_dot(xs, w_in, here, x.dtype)), w_out, here, jnp.float32)
+        with jax.named_scope(SCOPE_MOE_COMBINE):
+            # rows past the last group belong to no product: hold them to zero
+            ys = jnp.where((jnp.arange(cap) < total - at)[:, None], ys * jnp.take(flat_gates, idx)[:, None], 0.0)
+            back = (jnp.arange(t, dtype=idx.dtype)[:, None] == (idx // k)[None, :]).astype(jnp.bfloat16)
+            return y + sum(jnp.matmul(back, part, preferred_element_type=jnp.float32) for part in _split_bf16(ys))
+
+    y = jax.lax.fori_loop(0, -(-total // cap), block, jnp.zeros((t, x.shape[1]), jnp.float32))
+    return y.astype(x.dtype)
 
 
 def moe_experts_masked(p: dict, x, gates, experts, valid) -> tuple[jax.Array, jax.Array]:
@@ -335,17 +448,30 @@ def route_sigmoid_biased(
         return top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6) * scale, top_e.astype(jnp.int32)
 
 
+def held_capacity(assignments: int, held: int, routed: int) -> int:
+    """The rows of the compact grouped form (static): ``COMPACT_SHARE`` times
+    the even share of a dispatch's ``assignments`` (rows x top k) that lands
+    on the ``held`` of the ``routed`` experts, plus the one junk row an expert
+    that keeps every held expert's weights read, in whole ``gmm`` row tiles;
+    ``assignments`` itself where that is no less (a chip that holds all its
+    experts: nothing to compact)."""
+    even = -(-assignments * held // routed)
+    return min(assignments, -(-(COMPACT_SHARE * even + held) // 256) * 256)
+
+
 def moe_held_ffn(
     p: dict, x: jax.Array, gates: jax.Array, experts: jax.Array, first: int, valid: jax.Array | None = None
 ):
     """The ROUTED part of the expert layer on a chip that holds the experts
     ``[first, first + p["gate_up"].shape[0])`` of those the family's router
-    chose among (``gates`` / ``experts`` [T, k] over ALL of them:
-    ``route_sigmoid_grouped``, ``route_sigmoid_biased``): compute the picks
-    that land here, add nothing for the others. Returns (y[T, d],
-    counters[4] int32: real rows, held experts with a row, the fullest held
-    expert's rows, picks of real rows that landed on a held expert)."""
-    t = x.shape[0]
+    chose among (``gates`` / ``experts`` [T, k] over ALL of them, as wide as
+    ``p["router"]``: ``route_sigmoid_grouped``, ``route_sigmoid_biased``):
+    compute the picks that land here, add nothing for the others. Returns
+    (y[T, d], counters[6] int32: real rows, held experts with a row, the
+    fullest held expert's rows, then ``HELD_COUNTERS``: picks of real rows
+    that landed on a held expert, 1 where the layer ran the grouped form,
+    1 where it ran it compact)."""
+    t, k = experts.shape
     if t > GROUPED_BLOCK_ROWS:
         raise ValueError(
             f"{t} rows in one dispatch of a held-expert layer, above {GROUPED_BLOCK_ROWS}: the chunk ladder ends at "
@@ -357,9 +483,15 @@ def moe_held_ffn(
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         gates, experts, here = held_picks(gates, experts, first, held)
         local = jnp.sum(here & valid[:, None], dtype=jnp.int32)
+    cap = held_capacity(t * k, held, p["router"].shape[-1])
+    # static. A chip that holds all its experts has every assignment as its capacity: full width, the program it was
+    compacts = t > MASKED_MAX_ROWS and cap < t * k
     if t <= MASKED_MAX_ROWS:
         y, cnt = moe_experts_masked(p, x, gates, experts, valid)
     else:
-        y, cnt = moe_experts_grouped(p, x, gates, experts, valid)
+        y, cnt = moe_experts_grouped(p, x, gates, experts, valid, cap if compacts else None)
     with jax.named_scope(SCOPE_MOE_COMBINE):
-        return y, jnp.concatenate([cnt, local[None]])
+        grouped = jnp.full((), int(t > MASKED_MAX_ROWS), jnp.int32)
+        # one block took them all: the real held picks and the junk row an expert fit the capacity
+        compact = (local + held <= cap).astype(jnp.int32) if compacts else jnp.zeros((), jnp.int32)
+        return y, jnp.concatenate([cnt, jnp.stack([local, grouped, compact])])

@@ -429,6 +429,14 @@ class FlightFrame:
     k x expert layers routed), and the latent cache rows the dispatches'
     live rows attended over, each row's keys summed (one layer's: every
     layer reads as many); 0 for another family;
+    ``moe_grouped_calls`` / ``moe_compact_calls`` the layer calls of the
+    round's dispatches in which a layer that holds a SHARE of its experts
+    ran the grouped form (ops/moe.py ``moe_held_ffn`` above
+    ``MASKED_MAX_ROWS`` rows: the wide prefill chunks), and those among them
+    whose picks that land here fit ONE block of the shape-derived capacity
+    (``held_capacity``; the compact form's rows, where the parent carried
+    every assignment); the rest overflowed it and ran more blocks; 0 / 0 in a
+    round without a wide chunk and for a family that holds all its experts;
     ``conv_rows`` the batch rows whose short-convolution cache the round's
     chunk and step dispatches advanced (models/conv_decoder.py: the second
     family with state rows, whose ``state_restores`` / ``state_captures``
@@ -490,7 +498,7 @@ class FlightFrame:
         "ssm_rows", "state_restores", "state_captures",
         "moe_local_picks", "mla_ctx_rows", "mla_pages_read", "mla_run_pages",
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
-        "chunk_rows_held", "chunk_rows_kernel",
+        "chunk_rows_held", "chunk_rows_kernel", "moe_grouped_calls", "moe_compact_calls",
         "kv_win_live", "kv_win_released", "kv_win_written", "step_counts",
     )
 
@@ -508,7 +516,7 @@ class FlightFrame:
         ssm_rows=0, state_restores=0, state_captures=0,
         moe_local_picks=0, mla_ctx_rows=0, mla_pages_read=0, mla_run_pages=0,
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
-        chunk_rows_held=0, chunk_rows_kernel=0,
+        chunk_rows_held=0, chunk_rows_kernel=0, moe_grouped_calls=0, moe_compact_calls=0,
         kv_win_live=0, kv_win_released=0, kv_win_written=0, step_counts=(),
     ):
         self.seq = seq
@@ -563,6 +571,8 @@ class FlightFrame:
         self.attn_run_pages = attn_run_pages
         self.chunk_rows_held = chunk_rows_held
         self.chunk_rows_kernel = chunk_rows_kernel
+        self.moe_grouped_calls = moe_grouped_calls
+        self.moe_compact_calls = moe_compact_calls
         self.kv_win_live = kv_win_live
         self.kv_win_released = kv_win_released
         self.kv_win_written = kv_win_written
@@ -651,6 +661,8 @@ class FlightFrame:
             d["conv"] = [self.conv_rows, self.state_restores, self.state_captures]
         if self.mla_ctx_rows:
             d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
+        if self.moe_grouped_calls:
+            d["moe_compact"] = [self.moe_compact_calls, self.moe_grouped_calls]
         if self.mla_pages_read:
             d["mla_pages"] = [self.mla_run_pages, self.mla_pages_read]
         if self.mhc_resid_ppm:

@@ -222,14 +222,14 @@ def test_step_advances_the_rows_that_generate_and_no_other(weights):
         )
 
     _, pool, rec, counted = chunk(0, 9, ZERO)
-    assert [int(counted[0]), int(counted[4])] == [9, 1]  # nine real rows, one row's state advanced
+    assert [int(counted[0]), int(counted[6])] == [9, 1]  # nine real rows, one row's state advanced
     mid = [np.asarray(r[1]) for r in rec]
     for t in range(3):
         _, pool, rec, counted = FAM.paged_forward(
             params, pool, rec, jnp.asarray(bt), jnp.array([[5], [0], [6]], jnp.int32),
             jnp.array([t, 9, t], jnp.int32), rows=jnp.array([True, False, True]),
         )
-        assert int(counted[4]) == 2
+        assert int(counted[6]) == 2
     for before, after in zip(mid, rec):
         np.testing.assert_array_equal(before, np.asarray(after[1]))
     assert np.asarray(rec[0][0]).any() and np.asarray(rec[0][2]).any()  # the others did advance
@@ -409,7 +409,7 @@ def _sched(ms, **kw):
 async def test_scheduler_serves_the_family_restores_snapshots_and_never_recompiles():
     ms = _zoo()
     sched = _sched(ms)
-    assert (sched.programs._counted, sched.programs._stateful, sched.programs.attn_kernel) == (6, True, "")
+    assert (sched.programs._counted, sched.programs._stateful, sched.programs.attn_kernel) == (8, True, "")
     assert len(sched.pool.state) == 2 and sched.pool.state[0].shape[0] == 2  # planes for the attention layers only
     assert len(sched.pool.recurrent) == 6 and sched.pool.recurrent[0].shape == (4 + 2 + 1, 2 * 64)
     sched.warmup()

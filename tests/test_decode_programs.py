@@ -569,10 +569,16 @@ LOWERED_BEFORE_THE_FOURTH_FAMILY = {
 # family's programs had to lower to the text they lowered to before. They
 # still do after PR 45, which gave the family's CHUNKS a kernel to choose on a
 # TPU: on this, the CPU backend, both programs walk (``attn_kernel`` ""), and
-# the walk's text is what it was
+# the walk's text is what it was. Re-made at PR 49 (dcb7e826... / b2aaef81...
+# before), which gave ``moe_held_ffn``'s counts two more entries (the layer
+# calls that ran the grouped form, and ran it compact: constants 0 at these
+# programs' 4 and 16 rows, the masked form): the counters' vector is six wide
+# where it was four, the readback two elements longer, and the helpers and
+# loop arguments after it are numbered on; the same operations on the same
+# values, in the same order
 LOWERED_BEFORE_THE_FIFTH_FAMILY = {
-    "mla.step": "dcb7e82625fdc8fd4a1fa472f4862fec343f6287a5e81be74a7c35de641480a9",
-    "mla.chunk": "b2aaef81dce78101a011886d34302cedd9a425dbfa0bec0c3bd1e7631476901a",
+    "mla.step": "48c7343865de7c891712c95124d96faeb7f0819105f41cf4db240dbc741e1c3a",
+    "mla.chunk": "3797537ce0a6e7cf046937d1aa202da8785488cf0d730440d67b7c315bf18cf8",
 }
 LOWERED = {**LOWERED_BEFORE_THE_FOURTH_FAMILY, **LOWERED_BEFORE_THE_FIFTH_FAMILY}
 
@@ -651,13 +657,14 @@ def test_the_fourth_family_rides_the_counting_convention():
     params = mla.init_mla_decoder(fam.cfg, seed=0, dtype=jnp.float32)
     sched = DecodeScheduler(params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=N, kv_page_size=4, family=fam)
     progs = sched.programs
-    assert (progs.mode, progs.attn_kernel, progs._counted, progs._stateful) == ("", "", 7, False)
+    assert (progs.mode, progs.attn_kernel, progs._counted, progs._stateful) == ("", "", 9, False)
     sched.warmup()
     assert set(progs.compile_counts()) == {"step", "chunk", "copy"}
     zi, zf = np.zeros(N, np.int32), np.zeros(N, np.float32)
     out, read = progs.step(sched.pool.block_tables(), zi, zi, zf, zi, np.int32(1), np.ones(N, bool))
     toks, counted = read()
-    assert toks.shape == (N,) and counted.shape == (7,) and counted[0] == N  # both rows counted as real
-    assert counted[4] == N  # each attended over one latent row (position 0)
-    assert counted[5:].tolist() == [0, 0]  # the CPU backend's step walks: no page fetched by the kernel
+    assert toks.shape == (N,) and counted.shape == (9,) and counted[0] == N  # both rows counted as real
+    assert counted[4:6].tolist() == [0, 0]  # a step's rows take the masked form: no grouped call, none compact
+    assert counted[6] == N  # each attended over one latent row (position 0)
+    assert counted[7:].tolist() == [0, 0]  # the CPU backend's step walks: no page fetched by the kernel
     assert sched.recompiles_since_warmup() == 0

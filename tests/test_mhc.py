@@ -379,7 +379,7 @@ def test_one_stream_and_the_grouped_gate_are_the_family_as_it_was():
     assert jax.tree.structure(a) == jax.tree.structure(b) and "hc_attn" not in a["layers"][0]
     assert all(np.array_equal(x, y) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
     assert mla.mla_family(named).frame_counters == mla.mla_family(K1).frame_counters
-    assert len(mla.mla_family(CFG).frame_counters) == 8 and mla.mla_family(CFG).frame_counters[-1] == "mhc_resid_ppm"
+    assert len(mla.mla_family(CFG).frame_counters) == 10 and mla.mla_family(CFG).frame_counters[-1] == "mhc_resid_ppm"
     ids = _ids(1)
     got_a, got_b = (_serve(a, ids, chunks=(8, 16), cfg=c)[0] for c in (K1, named))
     np.testing.assert_array_equal(got_a, got_b)
@@ -428,7 +428,7 @@ async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles(r
         kv_page_size=PS, family=fam,
     )
     assert len(sched.pool.state) == 1 and sched.pool.state[0].shape[-1] == CFG.row_width  # latent pages, nothing else
-    assert sched.programs._counted == 8 and not sched.programs._stateful  # the streams are no state
+    assert sched.programs._counted == 10 and not sched.programs._stateful  # the streams are no state
     sched.warmup()
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, 96, (5, SEQ)).astype(np.int32)

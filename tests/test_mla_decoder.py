@@ -698,29 +698,66 @@ def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_u
         np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-6)
 
 
-@pytest.mark.parametrize("rows,form", [(24, "masked"), (300, "grouped"), (512, "refused")])
+@pytest.mark.parametrize(
+    "rows,form",
+    [(24, "masked"), (300, "grouped"), (512, "refused"), (512, "compact"), (300, "overflow"), (300, "all_held")],
+)
 def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch, rows, form):
     """By static row count: masked to 256 rows, grouped above, and refused
-    above ``GROUPED_BLOCK_ROWS`` (128 in the last case; no served program is
+    above ``GROUPED_BLOCK_ROWS`` (128 in the third case; no served program is
     that wide). Junk rows come back zero and stay out of the counts, which
-    are over the experts HELD."""
+    are over the experts HELD. Since PR 49 the grouped form over a SHARE of
+    the experts runs COMPACT, in blocks of ``held_capacity`` sorted
+    assignments (768 of the 300 rows' 1,200, 1,280 of the 512 rows' 2,048):
+    one block where the held picks + a junk row an expert fit it; a routing
+    that sends every pick to the held experts ("overflow": 1,180 picks + 4
+    do not fit 768) takes two, drops nothing and says so in the last
+    counter; a layer that holds all sixteen gets its assignments as its
+    capacity and the full-width form, no loop."""
     monkeypatch.setattr(moe, "GROUPED_BLOCK_ROWS", 128 if form == "refused" else 4096)
-    p = _share(_expert_layer(1), 4, 4)
+    first, n_held = (0, 16) if form == "all_held" else (4, 4)
+    p = _share(_expert_layer(1), first, n_held)
     n2 = jax.random.normal(jax.random.key(rows), (rows, CFG.hidden))
+    if form == "overflow":  # a coordinate every row shares, and router weights that score the held experts on it
+        n2 = n2.at[:, 0].set(3.0)
+        p["router"] = p["router"].at[0].set(jnp.where((jnp.arange(CFG.experts) // 4) == 1, 5.0, -5.0))
     valid = jnp.arange(rows) < rows - 5
-    held = jax.jit(lambda p, x, v: _held_ffn(p, x, 4, v))
+    held = jax.jit(lambda p, x, v: _held_ffn(p, x, first, v))
     if form == "refused":
         with pytest.raises(ValueError, match="512 rows in one dispatch .* above 128"):
             held(p, n2, valid)
         return
     y, counted = held(p, n2, valid)
-    want = np.asarray(ref.expert_ffn(p, n2, first_expert=4, act="float32", shared=False, **ROUTE))
+    want = np.asarray(ref.expert_ffn(p, n2, first_expert=first, act="float32", shared=False, **ROUTE))
     np.testing.assert_allclose(np.asarray(y[: rows - 5]), want[: rows - 5], atol=5e-6)
     assert not np.asarray(y[rows - 5 :]).any()
     _, experts = moe.route_sigmoid_grouped(p["router"], n2, 4, 4, 2, 2.5)
-    mine = np.asarray((experts >= 4) & (experts < 8))[: rows - 5]
-    loads = np.bincount(np.asarray(experts)[: rows - 5][mine] - 4, minlength=4)
-    assert [int(c) for c in counted] == [rows - 5, int((loads > 0).sum()), int(loads.max()), int(mine.sum())]
+    mine = np.asarray((experts >= first) & (experts < first + n_held))[: rows - 5]
+    loads = np.bincount(np.asarray(experts)[: rows - 5][mine] - first, minlength=n_held)
+    assert [int(c) for c in counted[:4]] == [rows - 5, int((loads > 0).sum()), int(loads.max()), int(mine.sum())]
+    cap = moe.held_capacity(rows * 4, n_held, CFG.experts)
+    assert cap == {"masked": 96, "grouped": 768, "compact": 1280, "overflow": 768, "all_held": 1200}[form]
+    fits = int(mine.sum()) + n_held <= cap
+    assert fits == (form != "overflow") and (form != "overflow" or int(mine.sum()) == (rows - 5) * 4)
+    # [.., the layer ran the grouped form, and ran it compact]
+    compact = form in ("grouped", "compact")
+    assert [int(c) for c in counted[4:]] == [int(form != "masked"), int(compact)]
+    from tests.test_sampling import _primitives
+
+    _, prims = _primitives(jax.make_jaxpr(lambda p, x, v: _held_ffn(p, x, first, v))(p, n2, valid).jaxpr)
+    # the blocks' loop, and no branch beside it; masked, and all experts held: neither
+    assert ("while" in prims) == (form in ("grouped", "compact", "overflow")) and "cond" not in prims
+
+
+def test_the_compact_capacity_is_twice_the_even_share_plus_a_junk_row_an_expert_in_row_tiles():
+    """``held_capacity`` at the three cells' widest chunk entries (ISSUE 49):
+    32 of 256 at top 10 over (4, 256), 8 of 64 at top 4 over (2, 256) and
+    (4, 256); a chip that holds all its experts keeps every assignment."""
+    assert moe.held_capacity(10240, 32, 256) == 2816
+    assert moe.held_capacity(2048, 8, 64) == 768 and moe.held_capacity(4096, 8, 64) == 1280
+    assert moe.held_capacity(10240, 64, 64) == 10240 and moe.held_capacity(1200, 16, 16) == 1200
+    assert moe.held_capacity(300, 1, 256) == 256 and moe.held_capacity(200, 1, 2) == 200  # never above the assignments
+    assert all(moe.held_capacity(a, 12, 192) % 256 == 0 for a in (4096, 8192, 16384))
 
 
 # (e) frequencies and the score scale against the closed forms
@@ -802,6 +839,42 @@ async def test_scheduler_serves_the_family_streams_counts_and_never_recompiles()
     await sched.close()
 
 
+async def test_scheduler_counts_the_wide_chunks_layer_calls_that_ran_compact(monkeypatch):
+    """A chunk dispatch wide enough for the grouped form (the (4, 64) entry's
+    256 rows, with the masked form's reach cut to 128 here; the cells' are
+    the 256-token entries) counts its two expert layers' calls into
+    ``moe_grouped_calls`` and, their routing fitting ``held_capacity`` (768
+    of 1,024 assignments), into ``moe_compact_calls``: the frame says
+    ``"moe_compact": [2, 2]``. Narrower dispatches and steps count neither.
+    The tokens are the oracle's."""
+    from seldon_core_tpu.models.zoo import get_model
+
+    monkeypatch.setattr(moe, "MASKED_MAX_ROWS", 128)
+    seq, new = 72, 4
+    ms = get_model(
+        "mla_decoder", **SIZES, experts_held=4, first_expert=4, seq=seq, max_new_tokens=new, param_dtype="float32", seed=11,
+    )
+    sched = ds.DecodeScheduler(
+        ms.params, seq_len=seq, max_new_tokens=new, n_slots=4, prefill_chunk=64, kv_page_size=PS,
+        family=ms.generative["family"],
+    )
+    assert (4, 64) in sched.chunk_buckets and moe.held_capacity(4 * 64 * 4, 4, CFG.experts) == 768
+    sched.warmup()
+    prompts = np.random.default_rng(3).integers(0, 96, (4, seq)).astype(np.int32)
+    oracle = np.asarray(jax.jit(ms.apply_fn)(ms.params, jnp.asarray(prompts)))
+    got = await asyncio.gather(*(sched.submit(p) for p in prompts))
+    for mine, want in zip(got, oracle):
+        np.testing.assert_array_equal(mine, want)
+    assert sched.recompiles_since_warmup() == 0
+    frames = sched.flight.snapshot()
+    wide = [f for f in frames if f.chunk_rows * f.chunk_c > 128]
+    assert wide and all((f.moe_grouped_calls, f.moe_compact_calls) == (2, 2) for f in wide)  # a call an expert layer
+    assert wide[0].to_dict()["moe_compact"] == [2, 2]
+    rest = [f for f in frames if f not in wide]
+    assert rest and not any(f.moe_grouped_calls or f.moe_compact_calls or "moe_compact" in f.to_dict() for f in rest)
+    await sched.close()
+
+
 async def test_scheduler_with_the_step_kernel_serves_the_same_tokens_and_counts_its_pages(monkeypatch):
     """With the ONE place of choice answering "interpret" (the chip's answer
     is "mosaic"; the interpreter is the CPU's way to run the same kernels) the
@@ -875,7 +948,7 @@ def test_the_step_program_counts_the_pages_of_a_table_built_by_hand(weights, mon
     zi, zf = jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.float32)
     out, _pool = jax.jit(step)(params, pool, bt, zi, positions, zf, zi, jnp.int32(0), jnp.int32(0), jnp.array([True, True, False]))
     counted = np.asarray(out)[3:]
-    assert len(counted) == len(FAM.frame_counters) == 7
+    assert len(counted) == len(FAM.frame_counters) == 9
     named = dict(zip(FAM.frame_counters, counted.tolist()))
     assert named["mla_ctx_rows"] == 31 + 23
     assert named["mla_pages_read"] == 8 + 6

@@ -515,6 +515,48 @@ def test_grouped_expert_layer_compiles_with_the_pallas_kernel_at_published_width
     assert compiled.as_text().count("tpu_custom_call") >= 2  # gate_up and down
 
 
+def _grouped_product_rows(text: str) -> list[int]:
+    """The row counts of a compiled program's grouped expert products (the
+    megablox kernel's calls: ``%gmm.N = dtype[rows, n] custom-call(...)``),
+    in the text's order."""
+    return [int(m) for m in re.findall(r"%gmm[.\d]* = \w+\[(\d+),\d+\][^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+
+
+@pytest.mark.parametrize(
+    "cell, rows, hidden, ffn, held, routed, k, cap",
+    [("laguna-s-2.1", 1024, 3072, 1024, 32, 256, 10, 2816), ("lfm2-24b-a2b", 1024, 2048, 1536, 8, 64, 4, 1280),
+     ("lfm2-24b-a2b", 512, 2048, 1536, 8, 64, 4, 768), ("xing4.0-29b-a4b", 1024, 3584, 1024, 8, 64, 4, 1280)],
+    ids=["laguna_4_256", "lfm2_4_256", "lfm2_2_256", "xing_4_256"],
+)
+def test_held_expert_layer_compiles_compact_at_the_three_cells_widths(
+        topo, monkeypatch, cell, rows, hidden, ffn, held, routed, k, cap):
+    """``moe_held_ffn`` over a 256-token chunk entry's rows at the widths of the
+    three cells that hold a share of their experts (PR 49): the blocks' loop
+    compiles for the chip, its two grouped products over ``held_capacity``
+    rows (2,816 of laguna's 10,240 assignments), and nothing as long as the
+    assignments goes through a product or comes back in float32: no branch,
+    no full-width form beside it."""
+    from seldon_core_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    bf = jnp.bfloat16
+    p = {"router": sds((hidden, routed), bf), "gate_up": sds((held, hidden, 2 * ffn), bf), "down": sds((held, ffn, hidden), bf)}
+    compiled = (
+        jax.jit(lambda p, x, g, e, v: moe.moe_held_ffn(p, x, g, e, held, v))
+        .lower(p, sds((rows, hidden), bf), sds((rows, k), jnp.float32), sds((rows, k), jnp.int32), sds((rows,), jnp.bool_))
+        .compile()
+    )
+    assert moe.held_capacity(rows * k, held, routed) == cap < rows * k
+    text = compiled.as_text()
+    assert _grouped_product_rows(text) == [cap, cap]
+    assert " conditional(" not in text and "f32[%d,%d]" % (rows * k, hidden) not in text
+
+
 def _assert_step_reads_the_pool_through_the_kernel(text: str, layers: int):
     """A grouped-query family's step with ops/gqa_decode.py's kernel: a
     Mosaic call an attention layer under ``attn``, what is left of the
@@ -753,7 +795,12 @@ def test_the_latent_step_lowers_to_the_text_it_had_before_the_chunk_kernel(topo,
     own: the STEP program at the a.x-k1 cell's widths still lowers to the
     parent's text (193f7d8; the Mosaic body compared without its source
     locations), so ``step_device_ms``, ``mla_decode_roofline`` and
-    ``step_roofline.mla`` read a program that did not change."""
+    ``step_roofline.mla`` read a program that did not change. Re-made at
+    PR 49 (d9fb0f47... before), which gave ``moe_held_ffn``'s counts two more
+    entries, constants 0 at the step's 64 rows (the masked form): the
+    counters' vector is six wide where it was four and the readback two
+    elements longer; text for text nothing else differs from the parent's
+    but the numbering of the values after them."""
     import hashlib
 
     from seldon_core_tpu.ops import moe
@@ -772,7 +819,7 @@ def test_the_latent_step_lowers_to_the_text_it_had_before_the_chunk_kernel(topo,
     text = _without_locations(jax.jit(step, donate_argnums=(1,)).lower(params, pool, *args).as_text())
     text = re.sub(r"loc\(.*?\)\n|#loc.*\n", "", text)
     assert text.count('"mosaic:') == 1 and 'kernel_name = "mla_decode_attention"' in text
-    assert hashlib.sha256(text.encode()).hexdigest() == "d9fb0f470fd2c1e233d86ef109c11335e2ccf0bbb8d03b3f2581202175fd02f9"
+    assert hashlib.sha256(text.encode()).hexdigest() == "b3c77aa02c3e3683a60ef5da091cca95d61f3a6b02d15e3c69bfafaf4adc17a9"
 
 
 @pytest.mark.parametrize("program", ["step", "chunk_2_16", "chunk_2_64", "chunk_2_256", "chunk_4_256"])
@@ -994,7 +1041,9 @@ def test_the_conv_step_with_the_kernel_lowers_to_the_text_it_had_before_the_wind
     before the kernel took a windowed table and 6- and 9-head groups (PR 48's
     parent, e75f1e9, hashed there with ``_without_locations``): the windowed
     form is a static variant, and a call without ``first`` traces the kernel
-    it traced, argument for argument."""
+    it traced, argument for argument. Re-made at PR 49 (32aa74fc... before)
+    for the two constant counts ``moe_held_ffn`` gained, as the latent
+    step's above."""
     import hashlib
 
     from seldon_core_tpu.models import conv_decoder as cd
@@ -1021,7 +1070,7 @@ def test_the_conv_step_with_the_kernel_lowers_to_the_text_it_had_before_the_wind
     step, _chunk = fam.fused_programs("mosaic")
     text = _without_locations(jax.jit(step, donate_argnums=(1, 2)).lower(params, pool, rec, *args).as_text())
     assert text.count('"mosaic:') == 1  # the two attention layers' calls lower the kernel once
-    assert hashlib.sha256(text.encode()).hexdigest() == "32aa74fc4a6f8982c535c233395873bb41bd59176623d0c7e99627aa396fb265"
+    assert hashlib.sha256(text.encode()).hexdigest() == "9ee0656864cdc6b1a498fb80158d35982ef8b97c3c39cf52720ca1c7220c21f1"
 
 
 @pytest.mark.parametrize("family", ["gpt2", "moe", "hybrid"])
@@ -1112,6 +1161,10 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     # a sliding layer's gathered cache is its window's pages (34 or 50 of them), never the table's 464
     assert not re.findall(r"f32\[%d,8,7424,128\][^\n]*/win/" % n, text)
     assert mem.temp_size_in_bytes < 3 << 30
+    if program == "chunk_4_256":
+        # the two expert layers' grouped products (PR 49): a pair a layer over the 2,816 rows of ``held_capacity``,
+        # none over all 10,240 assignments
+        assert _grouped_product_rows(text) == [2816] * 4
     if program == "step_kernel":
         calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_step\)/(full|win)/attn/', text)
         assert sorted(calls) == ["full", "win", "win"]  # a call a layer, under its kind's scope

@@ -332,7 +332,7 @@ def _zoo(**kw):
 async def test_scheduler_serves_both_page_kinds_to_the_references_tokens(ref):
     ms = _zoo()
     fam = ms.generative["family"]
-    assert fam.cfg == CFG and fam.frame_counters[-2:] == ("moe_local_picks", "attn_run_pages")
+    assert fam.cfg == CFG and fam.frame_counters[3:] == (*md.HELD_COUNTERS, "attn_run_pages")
     sched = ds.DecodeScheduler(
         ms.params, seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=4, prefix_slots=2, prefill_chunk=16,
         kv_page_size=PS, family=fam,
@@ -364,12 +364,14 @@ async def test_scheduler_serves_both_page_kinds_to_the_references_tokens(ref):
     assert any("kv_win" in f.to_dict() for f in frames) and any(f.moe_local_picks for f in frames)
     # a round's named counts are the sum of its dispatches; the step's own ride beside them
     stepped = [f for f in frames if f.busy_ns[1] > 0]
-    # the four routing counts, then the run pages of the step's kernel (none on the CPU: the step gathers)
-    assert stepped and all(len(f.step_counts) == 5 and f.step_counts[0] <= f.moe_rows for f in stepped)
-    assert not any(f.step_counts[4] or f.attn_run_pages for f in stepped)
+    # the four routing counts, the grouped and compact layer calls (a step's rows take the masked form: none), then
+    # the run pages of the step's kernel (none on the CPU: the step gathers)
+    assert stepped and all(len(f.step_counts) == 7 and f.step_counts[0] <= f.moe_rows for f in stepped)
+    assert not any(any(f.step_counts[4:]) or f.attn_run_pages for f in stepped)
     assert all(f.step_counts == () for f in frames if f.busy_ns[1] == 0)
     for f in stepped:
-        own = (f.moe_rows, f.moe_experts_hit, f.moe_load_max, f.moe_local_picks, f.attn_run_pages)
+        own = (f.moe_rows, f.moe_experts_hit, f.moe_load_max, f.moe_local_picks, f.moe_grouped_calls,
+               f.moe_compact_calls, f.attn_run_pages)
         assert (f.step_counts == own) == (f.chunk_rows == 0) or f.step_counts == own  # alone in its round: the same numbers
         assert ("step_counts" in f.to_dict()) == bool(f.chunk_rows)
     await sched.close()
@@ -427,8 +429,8 @@ def test_the_kernel_step_reads_both_kinds_where_they_lie_and_never_a_page_given_
     got, _h, state_k, counted_k = FAM.paged_forward(params, poisoned, tables, *args, rows=rows, attn_kernel="interpret")
     assert np.isfinite(np.asarray(got[1])).all()
     np.testing.assert_allclose(np.asarray(got[1, 0]), np.asarray(want[1, 0]), rtol=0, atol=2e-5)
-    assert len(counted_k) == len(FAM.frame_counters) == 5 and int(counted_g[-1]) == 0
-    assert counted_k[:4].tolist() == counted_g[:4].tolist()  # the routing counts are the real rows' alone
+    assert len(counted_k) == len(FAM.frame_counters) == 7 and int(counted_g[-1]) == 0
+    assert counted_k[:6].tolist() == counted_g[:6].tolist()  # the routing counts are the real rows' alone
     assert int(counted_k[-1]) == _kinds_fetched(np.asarray(tables), positions, rows)[1] > 0
     mine = [np.asarray(t)[1][np.asarray(t)[1] > 0] for t in tables]
     for i, (a, b) in enumerate(zip(state_g, state_k)):
