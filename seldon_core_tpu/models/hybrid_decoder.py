@@ -1,23 +1,39 @@
 """Hybrid state-space / attention causal decoder — the generative tier's
-third family (Granite 4.0-H: ``model_type: granitemoehybrid``).
+third family (Granite 4.0-H: ``model_type: granitemoehybrid``; Nemotron-H:
+``model_type: nemotron_h``).
 
 What the block has, beside the two families before it:
 
-- most layers mix tokens with a Mamba-2 recurrence instead of attention: a
+- most mixers are a Mamba-2 recurrence instead of attention: a
   ``[ssm_heads, ssm_head_dim, ssm_state]`` float32 state per layer and
   sequence, advanced one token at a time in the step and as ONE chunked
-  scan in a prefill chunk (the published ``mamba_chunk_size`` is the tier's
-  chunk cap, so a chunk dispatch is at most one scan chunk), behind a
-  depthwise causal convolution whose cache is the last ``ssm_conv - 1``
-  inputs;
-- the layers named in ``attn_layers`` are grouped-query attention WITHOUT
-  positions (``position_embedding_type: nope``), scores times
+  scan in a prefill chunk whatever scan chunk the configuration publishes
+  (the result does not depend on it: granite's ``mamba_chunk_size`` 256 is
+  the tier's chunk cap; Nemotron-H publishes ``chunk_size`` 128 under the
+  same 256-token dispatch, see ``_scan_chunk``), behind a depthwise causal
+  convolution whose cache is the last ``ssm_conv - 1`` inputs. B and C come
+  in ``ssm_groups`` groups (``n_groups``): head h reads group
+  ``h // (ssm_heads // ssm_groups)``, the conv cache is ``d_inner + 2 *
+  ssm_groups * ssm_state`` wide and the gated norm runs over each group's
+  ``d_inner / ssm_groups`` channels; one group is granite's;
+- the attention layers are grouped-query attention WITHOUT positions
+  (``position_embedding_type: nope``), scores times
   ``attention_multiplier``; only they hold K/V pages (``decoder_dims``
   ``kv_layers``), written and gathered by the GPT-2 family's
   ``_paged_write`` / ``_paged_gather``;
+- a layer's kind is the configuration's. Without a ``pattern`` (granite)
+  the layers named in ``attn_layers`` attend, every other is Mamba-2, and a
+  dense gated-SiLU MLP pairs with EVERY mixer. With one
+  (``hybrid_override_pattern``: a character a layer, ``M`` Mamba-2, ``*``
+  attention, ``E`` an expert layer) a layer is ONE sublayer, ``x + Mix(
+  RMSNorm(x))`` and nothing else; an expert layer is a shared expert every
+  token takes plus one chip's share of the routed ones under the
+  bias-selected sigmoid gate (ops/moe.py ``route_sigmoid_biased``,
+  ``moe_held_ffn``), each ``down(relu(up x)^2)`` with no gate projection;
 - Granite's multipliers: the embedding times ``embedding_multiplier``, every
-  residual branch times ``residual_multiplier``, the tied head's logits
-  over ``logits_scaling``; a dense gated-SiLU MLP in every layer.
+  residual branch times ``residual_multiplier``, the head's logits over
+  ``logits_scaling`` (a configuration without them passes ones); the head is
+  the embedding (tied) unless ``untied`` (``lm_head`` [hidden, vocab]).
 
 The recurrent state is the family's second cache, beside the pages
 (``state_init``; serving/kv_pool.py holds it as ``pool.recurrent``): ROWS of
@@ -69,12 +85,23 @@ from seldon_core_tpu.models.decoder import (
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, _attend, _rms
 from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
+from seldon_core_tpu.ops.moe import (
+    HELD_COUNTERS,
+    N_HELD_COUNTERS,
+    SCOPE_MOE_COMBINE,
+    SCOPE_SHARED_EXPERT,
+    expert_mlp,
+    lane_tiles,
+    moe_held_ffn,
+    route_sigmoid_biased,
+)
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
 # name so readers of those still see whole steps: ``qkv/ssm_in``,
 # ``attn/ssm_conv``, ``attn/ssm_scan`` (the recurrence or the chunked scan,
 # with the state rows' read and write), ``attn_out/ssm_norm``,
-# ``attn_out/ssm_out``
+# ``attn_out/ssm_out``; an expert layer's are ops/moe.py's, under ``mlp``
+# (``mlp/moe_*``, ``mlp/shared_expert``)
 SCOPE_SSM_IN = "ssm_in"
 SCOPE_SSM_CONV = "ssm_conv"
 SCOPE_SSM_SCAN = "ssm_scan"
@@ -89,24 +116,41 @@ _SCAN_BLOCK_BYTES = 128 << 20
 # default precision they are rounded to bfloat16 first, and the state and the
 # decay are the float32 part of the model
 _SCAN_PRECISION = lax.Precision.HIGHEST
+# a layer's kind, as ``hybrid_override_pattern`` spells it
+KIND_SSM, KIND_ATTN, KIND_EXPERT = "M", "*", "E"
+# the gate's denominator adds this to the picks' scores (``nemotron_h``)
+_GATE_EPS = 1e-20
+# the selection bias's std (models/conv_decoder.py ``EXPERT_BIAS_STD``'s
+# reasoning at 128 scores: about six gaps between neighbouring sorted ones)
+EXPERT_BIAS_STD = 0.05
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridDecoderConfig:
-    """The published keys of a Granite-4.0-H decoder (zoo://hybrid_decoder)."""
+    """The published keys of a Granite-4.0-H or a Nemotron-H decoder
+    (zoo://hybrid_decoder)."""
 
     vocab: int = 512
     hidden: int = 64
     layers: int = 4
-    attn_layers: tuple = (1,)  # the layers that are attention; every other is Mamba-2
+    attn_layers: tuple = (1,)  # the layers that are attention (read from ``pattern`` where there is one)
     heads: int = 4
     kv_heads: int = 2
     head_dim: int = 16
-    ffn: int = 128  # shared_intermediate_size: the gated MLP's width
+    ffn: int = 128  # the paired MLP's width (shared_intermediate_size); with a pattern ONE routed expert's
     ssm_heads: int = 8
     ssm_head_dim: int = 16
     ssm_state: int = 16
     ssm_conv: int = 4
+    ssm_groups: int = 1  # n_groups: the B/C groups the heads share
+    pattern: str = ""  # hybrid_override_pattern: one sublayer a layer; "": a mixer and a dense MLP in every layer
+    untied: bool = False  # tie_word_embeddings false: the head is ``lm_head``
+    experts: int = 0  # n_routed_experts: the router's width
+    experts_held: int = 0  # the routed experts this chip holds, from ``first_expert``
+    first_expert: int = 0
+    experts_per_tok: int = 0
+    shared_ffn: int = 0  # moe_shared_expert_intermediate_size
+    routed_scale: float = 1.0
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
     attention_multiplier: float = 0.0625
@@ -117,10 +161,29 @@ class HybridDecoderConfig:
     def __post_init__(self):
         if self.heads % self.kv_heads:
             raise ValueError(f"heads={self.heads} not a multiple of kv_heads={self.kv_heads}")
+        if self.pattern:
+            if len(self.pattern) != self.layers or set(self.pattern) - {KIND_SSM, KIND_ATTN, KIND_EXPERT}:
+                raise ValueError(
+                    f"pattern={self.pattern!r}: {self.layers} characters of {KIND_SSM!r} (Mamba-2), "
+                    f"{KIND_ATTN!r} (attention) and {KIND_EXPERT!r} (an expert layer)"
+                )
+            object.__setattr__(self, "attn_layers", tuple(i for i, k in enumerate(self.pattern) if k == KIND_ATTN))
         if any(not 0 <= i < self.layers for i in self.attn_layers):
             raise ValueError(f"attn_layers={self.attn_layers} outside 0..{self.layers - 1}")
         if self.ssm_conv < 2:
             raise ValueError("ssm_conv must be >= 2")
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"ssm_heads={self.ssm_heads} not a multiple of ssm_groups={self.ssm_groups}")
+        if KIND_EXPERT in self.pattern and not (
+            0 < self.experts_per_tok <= self.experts
+            and 0 < self.experts_held <= self.experts - self.first_expert
+            and self.shared_ffn > 0
+        ):
+            raise ValueError(
+                "an expert layer needs experts, experts_per_tok, shared_ffn and the share held "
+                f"(experts_held from first_expert): {self.experts}, {self.experts_per_tok}, {self.shared_ffn}, "
+                f"{self.experts_held} from {self.first_expert}"
+            )
 
     @property
     def q_width(self) -> int:
@@ -135,18 +198,31 @@ class HybridDecoderConfig:
         return self.ssm_heads * self.ssm_head_dim
 
     @property
-    def conv_width(self) -> int:  # xs | B | C, one group
-        return self.d_inner + 2 * self.ssm_state
+    def conv_width(self) -> int:  # xs | B | C, a B and a C a group
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def kinds(self) -> str:
+        """A character a layer (``KIND_*``)."""
+        return self.pattern or "".join(KIND_ATTN if i in self.attn_layers else KIND_SSM for i in range(self.layers))
+
+    @property
+    def paired(self) -> bool:
+        """Whether a dense MLP follows every mixer (no pattern: granite)."""
+        return not self.pattern
 
     @property
     def ssm_layers(self) -> int:
-        return self.layers - len(set(self.attn_layers))
+        return self.kinds.count(KIND_SSM)
+
+    @property
+    def expert_layers(self) -> int:
+        return self.kinds.count(KIND_EXPERT)
 
     def cache_index(self, layer: int) -> int:
         """A layer's index in ITS cache: the attention layers count through
         the KV pool's layers, the Mamba layers through the state's."""
-        attn = layer in self.attn_layers
-        return sum((i in self.attn_layers) == attn for i in range(layer))
+        return self.kinds[:layer].count(self.kinds[layer])
 
 
 # ----------------------------------------------------------------- weights
@@ -171,7 +247,19 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
     state holds, and the comparison with the reference sees nothing; at
     0.01 two positions in five of a 20-layer build at the published widths
     still predict their own input, at 0.004 one in a hundred of the
-    40-layer one (float32 on the CPU, PR 34)."""
+    40-layer one (float32 on the CPU, PR 34). An UNTIED head has no such
+    coherent sum: the embedding at std 1 (as models/moe_decoder.py draws one)
+    and ``lm_head`` like a projection.
+
+    An expert layer as models/conv_decoder.py draws one: the router like the
+    rest, its selection bias normal(0, ``EXPERT_BIAS_STD``) in float32 (it is
+    trained by load balancing and published as a buffer; zeros would leave
+    the selection path untested), the routed experts drawn the
+    ``experts_held`` this chip holds, ``up`` and ``down`` and no gate. An
+    expert's hidden width is STORED in whole lane tiles (ops/moe.py
+    ``lane_tiles``: 1856 as 1920, zeros in ``up``'s last columns and
+    ``down``'s last rows, so the grouped products run on the megablox kernel;
+    the mathematics is the width's: ``relu(0)^2 = 0``)."""
     root = jax.random.key(int(seed), impl="rbg")
     h, n, w = cfg.ssm_heads, cfg.ssm_state, cfg.conv_width
 
@@ -179,6 +267,8 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
     def mlp(k1, k2):
+        if not cfg.paired:
+            return {}
         return {
             "ln2": jnp.ones((cfg.hidden,), dtype),
             "mlp_in": draw(k1, (cfg.hidden, 2 * cfg.ffn)),
@@ -202,7 +292,7 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
         bound = 1.0 / math.sqrt(cfg.ssm_conv)
         return {
             "ln1": jnp.ones((cfg.hidden,), dtype),
-            "ssm_in": draw(ks[0], (cfg.hidden, 2 * cfg.d_inner + 2 * n + h)),  # z | xBC | dt
+            "ssm_in": draw(ks[0], (cfg.hidden, cfg.d_inner + w + h)),  # z | xBC | dt
             "conv_w": jax.random.uniform(ks[1], (cfg.ssm_conv, w), jnp.float32, -bound, bound).astype(dtype),
             "conv_b": jax.random.uniform(ks[2], (w,), jnp.float32, -bound, bound).astype(dtype),
             "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
@@ -213,16 +303,35 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
             **mlp(*jax.random.split(ks[6])),
         }
 
+    def expert(k_up, k_down, lead, width):
+        pad = lane_tiles(width) - width
+        nil = ((0, 0),) * len(lead)
+        return {"up": jnp.pad(draw(k_up, (*lead, cfg.hidden, width)), (*nil, (0, 0), (0, pad))),
+                "down": jnp.pad(draw(k_down, (*lead, width, cfg.hidden)), (*nil, (0, pad), (0, 0)))}
+
+    @jax.jit
+    def expert_layer(key):
+        ks = jax.random.split(key, 6)
+        return {
+            "ln1": jnp.ones((cfg.hidden,), dtype),
+            "moe": {
+                "router": draw(ks[0], (cfg.hidden, cfg.experts)),
+                "router_bias": jax.random.normal(ks[1], (cfg.experts,), jnp.float32) * EXPERT_BIAS_STD,
+                **expert(ks[2], ks[3], (cfg.experts_held,), cfg.ffn),
+            },
+            "shared": expert(ks[4], ks[5], (), cfg.shared_ffn),
+        }
+
     params = {
-        "tok_emb": jax.jit(lambda k: draw(k, (cfg.vocab, cfg.hidden), 0.004))(
+        "tok_emb": jax.jit(lambda k: draw(k, (cfg.vocab, cfg.hidden), 1.0 if cfg.untied else 0.004))(
             jax.random.fold_in(root, 1 << 20)
         ),
         "ln_f": jnp.ones((cfg.hidden,), dtype),
     }
-    params["layers"] = [
-        (attn_layer if i in cfg.attn_layers else ssm_layer)(jax.random.fold_in(root, i))
-        for i in range(cfg.layers)
-    ]
+    if cfg.untied:
+        params["lm_head"] = jax.jit(lambda k: draw(k, (cfg.hidden, cfg.vocab)))(jax.random.fold_in(root, (1 << 20) + 1))
+    layer = {KIND_ATTN: attn_layer, KIND_SSM: ssm_layer, KIND_EXPERT: expert_layer}
+    params["layers"] = [layer[k](jax.random.fold_in(root, i)) for i, k in enumerate(cfg.kinds)]
     return params
 
 
@@ -244,20 +353,32 @@ def _scan_chunk(dt, a_neg, xs, b, c, s_in):
     """One chunk of the Mamba-2 recurrence in its chunked form. dt [n, m, h]
     (0 on a row past the slot's count: decay 1, no input, the state stands),
     a_neg [h] = -exp(A_log), xs [n, m, h, p], b / c [n, m, N], s_in
-    [n, h, p, N]; all float32. Returns (y [n, m, h, p], s_out)."""
+    [n, h, p, N]; all float32. Returns (y [n, m, h, p], s_out). With B/C
+    groups the head axis ``h`` is two, (group, head of the group), everywhere
+    it stands and b / c are [n, m, g, N]: a head reads its group's B and C
+    where they lie, none is repeated a head.
+
+    A dispatch is ONE scan chunk whatever ``chunk_size`` a configuration
+    publishes (the result does not depend on it). At Nemotron-H's widths (64
+    heads of 64 in 8 groups, state 128, ``chunk_size`` 128) the scans of a
+    (4, 256) dispatch, twelve layers chained, read 0.401 ms a layer as one
+    chunk of 256, 0.731 as two of 128 with the state carried and 0.645 as
+    four of 64 (my chip run, PR 51): the second chunk waits for the first
+    one's state, and the halved decay matrix does not pay for it."""
+    g = "g" if b.ndim == 4 else ""  # the group axis, where there is one; "h" then counts a group's heads
     m = dt.shape[1]
     cs = jnp.cumsum(dt * a_neg, axis=1)  # [n, m, h], <= 0 and falling
     dtx = dt[..., None] * xs
     causal = jnp.tril(jnp.ones((m, m), bool))
-    diff = cs.transpose(0, 2, 1)[:, :, :, None] - cs.transpose(0, 2, 1)[:, :, None, :]  # [n, h, t, s]
+    diff = jnp.moveaxis(cs, 1, -1)[..., :, None] - jnp.moveaxis(cs, 1, -1)[..., None, :]  # [n, h, t, s]
     decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
     dot = functools.partial(jnp.einsum, precision=_SCAN_PRECISION)
-    cb = dot("ntk,nsk->nts", c, b)
-    y = dot("nhts,nshp->nthp", decay * cb[:, None], dtx)
-    y = y + jnp.exp(cs)[..., None] * dot("ntk,nhpk->nthp", c, s_in)
-    tail = jnp.exp(cs[:, -1:, :] - cs)  # [n, m, h]: what is left of step s at the chunk's end
-    s_out = jnp.exp(cs[:, -1, :])[:, :, None, None] * s_in + dot(
-        "nshp,nsk->nhpk", tail[..., None] * dtx, b
+    cb = dot(f"nt{g}k,ns{g}k->n{g}ts", c, b)
+    y = dot(f"n{g}hts,ns{g}hp->nt{g}hp", decay * jnp.expand_dims(cb, -3), dtx)
+    y = y + jnp.exp(cs)[..., None] * dot(f"nt{g}k,n{g}hpk->nt{g}hp", c, s_in)
+    tail = jnp.exp(cs[:, -1:] - cs)  # [n, m, h]: what is left of step s at the chunk's end
+    s_out = jnp.exp(cs[:, -1])[..., None, None] * s_in + dot(
+        f"ns{g}hp,ns{g}k->n{g}hpk", tail[..., None] * dtx, b
     )
     return y, s_out
 
@@ -265,7 +386,8 @@ def _scan_chunk(dt, a_neg, xs, b, c, s_in):
 def _scan_blocked(dt, a_neg, xs, b, c, s_in):
     """``_scan_chunk`` with the rows in blocks where the decay matrix of all
     of them would pass ``_SCAN_BLOCK_BYTES``."""
-    n, m, h = dt.shape
+    n, m = dt.shape[:2]
+    h = math.prod(dt.shape[2:])
     blk = n
     while blk > 1 and 4 * blk * h * m * m > _SCAN_BLOCK_BYTES and blk % 2 == 0:
         blk //= 2
@@ -276,6 +398,17 @@ def _scan_blocked(dt, a_neg, xs, b, c, s_in):
         lambda a: _scan_chunk(a[0], a_neg, *a[1:]), tuple(split(t) for t in (dt, xs, b, c, s_in))
     )
     return y.reshape(n, *y.shape[2:]), s_out.reshape(n, *s_out.shape[2:])
+
+
+def _valid(n: int, m: int, counts, rows):
+    """[n, m] bool: the dispatch's real rows: the first ``counts[r]`` of a
+    chunk's row r, the step's rows that generate."""
+    valid = jnp.ones((n, m), bool)
+    if counts is not None:
+        valid &= jnp.arange(m)[None, :] < counts[:, None]
+    if rows is not None:
+        valid &= rows[:, None]
+    return valid
 
 
 def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_rows):
@@ -295,15 +428,15 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
         x, state, conv = lax.optimization_barrier((x, state, conv))
     n, m, _ = x.shape
     h, hd, ns, w, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.conv_width, cfg.ssm_conv
+    # the head axis: (heads,) under one B/C group, (groups, heads of a group) under more (head i reads group
+    # i // heads of a group), and B and C [.., groups, N] beside it; one group reshapes nothing
+    grp = (cfg.ssm_groups,) if cfg.ssm_groups > 1 else ()
+    hs = (*grp, h // cfg.ssm_groups)
     f32 = jnp.float32
     with jax.named_scope(SCOPE_QKV), jax.named_scope(SCOPE_SSM_IN):
         zxd = _rms(p["ln1"], x, cfg.rms_eps) @ p["ssm_in"].astype(x.dtype)
         z, xbc, dt = jnp.split(zxd, [cfg.d_inner, cfg.d_inner + w], axis=-1)
-    valid = jnp.ones((n, m), bool)
-    if counts is not None:
-        valid &= jnp.arange(m)[None, :] < counts[:, None]
-    if rows is not None:
-        valid &= rows[:, None]
+    valid = _valid(n, m, counts, rows)
     with jax.named_scope(SCOPE_ATTN):
         with jax.named_scope(SCOPE_SSM_CONV):
             conv_in = conv[:n] if state_rows is None else conv[state_rows[0]]
@@ -318,37 +451,57 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
             conv_out = jax.vmap(lambda s, at: lax.dynamic_slice_in_dim(s, at, k - 1))(seq, last)
             conv_out = conv_out.reshape(n, (k - 1) * w)
         with jax.named_scope(SCOPE_SSM_SCAN):
-            xs, b, c = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + ns], axis=-1)
-            xs = xs.reshape(n, m, h, hd)
+            xs, b, c = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + (w - cfg.d_inner) // 2], axis=-1)
+            xs = xs.reshape(n, m, *hs, hd)
+            b, c = b.reshape(n, m, *grp, ns), c.reshape(n, m, *grp, ns)
             dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
-            dt = jnp.where(valid[..., None], dt, 0.0)
-            a_neg = -jnp.exp(p["A_log"].astype(f32))
+            dt = jnp.where(valid[..., None], dt, 0.0).reshape(n, m, *hs)
+            a_neg = -jnp.exp(p["A_log"].astype(f32)).reshape(hs)
             if state_rows is None:
-                s_in = state[:n]
+                s_in = state[:n].reshape(n, *hs, hd, ns)
+                own = (slice(None), 0, *[slice(None)] * len(grp), None, None, slice(None))  # [n, (g,) 1, 1, N]: beside a head's [p, N]
                 decay = jnp.exp(dt[:, 0] * a_neg)  # [n, h]; 1 where the row stands
-                s_out = decay[:, :, None, None] * s_in + (
-                    (dt[:, 0, :, None] * xs[:, 0])[..., None] * b[:, 0, None, None, :]
-                )
+                s_out = decay[..., None, None] * s_in + (dt[:, 0, ..., None] * xs[:, 0])[..., None] * b[own]
                 # a product and a sum over the state as it is written, not a
                 # matrix product that would read it again rounded to bfloat16
-                y = jnp.sum(s_out * c[:, 0, None, None, :], axis=-1)[:, None]
-                state = state.at[:n].set(s_out)
+                y = jnp.sum(s_out * c[own], axis=-1)[:, None]
+                state = state.at[:n].set(s_out.reshape(n, h, hd, ns))
                 conv = conv.at[:n].set(conv_out)
             else:
-                y, s_out = _scan_blocked(dt, a_neg, xs, b, c, state[state_rows[0]])
+                y, s_out = _scan_blocked(dt, a_neg, xs, b, c, state[state_rows[0]].reshape(n, *hs, hd, ns))
                 for to in (state_rows[1], state_rows[2]):
-                    state = state.at[to].set(s_out, mode="drop")
+                    state = state.at[to].set(s_out.reshape(n, h, hd, ns), mode="drop")
                     conv = conv.at[to].set(conv_out, mode="drop")
-            y = y + p["D"].astype(f32)[:, None] * xs
+            y = y + p["D"].astype(f32).reshape(hs)[..., None] * xs
     with jax.named_scope(SCOPE_ATTN_OUT):
         with jax.named_scope(SCOPE_SSM_NORM):
-            g = y.reshape(n, m, cfg.d_inner) * jax.nn.silu(z.astype(f32))
+            # the gate first, then the norm over each B/C group's channels (all of them under one group)
+            g = (y.reshape(n, m, cfg.d_inner) * jax.nn.silu(z.astype(f32))).reshape(n, m, *grp, -1)
             g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.rms_eps)
-            g = g.astype(x.dtype) * p["ssm_norm"].astype(x.dtype)
+            g = g.reshape(n, m, cfg.d_inner).astype(x.dtype) * p["ssm_norm"].astype(x.dtype)
         with jax.named_scope(SCOPE_SSM_OUT):
             out = g @ p["ssm_out"].astype(x.dtype)
     rec = tuple(state if i == si else conv if i == cfg.ssm_layers + si else a for i, a in enumerate(rec))
     return out, rec
+
+
+def _experts(cfg: HybridDecoderConfig, p, x, valid):
+    """An expert layer over x[n, m, d]: the shared expert, which every token
+    takes ungated, plus the routed experts held here. The router scores ALL
+    ``experts`` (sigmoid, float32), its bias selects the top
+    ``experts_per_tok`` and does not weigh, the gates are the picks' scores
+    over their sum times ``routed_scale``; a pick on an expert another chip
+    holds adds nothing. Returns (the layer's output [n, m, d], counters[6]:
+    ops/moe.py ``moe_held_ffn``)."""
+    n, m, d = x.shape
+    h = _rms(p["ln1"], x, cfg.rms_eps).reshape(n * m, d)
+    with jax.named_scope(SCOPE_SHARED_EXPERT):
+        shared = expert_mlp(p["shared"], h)
+    gates, experts = route_sigmoid_biased(
+        p["moe"]["router"], p["moe"]["router_bias"], h, cfg.experts_per_tok, cfg.routed_scale, _GATE_EPS
+    )
+    y, cnt = moe_held_ffn(p["moe"], h, gates, experts, cfg.first_expert, valid.reshape(-1))
+    return (shared + y).reshape(x.shape), cnt
 
 
 def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, counts, reads=None, interpret=False):
@@ -394,36 +547,53 @@ def _forward(
     "mosaic" | "interpret": ``decode_programs._step_attn_kernel``'s answer)
     lets a dispatch of ONE query a slot read the pool through
     ops/gqa_decode.py's kernel; every other shape gathers. Returns (logits
-    [n, m or 1, vocab] float32, pool, rec, counters[2] int32:
-    ``HybridDecoder.frame_counters``)."""
+    [n, m or 1, vocab] float32, pool, rec, counters[2, or 8 with expert
+    layers] int32: ``HybridDecoder.frame_counters``)."""
     n, m = tokens.shape
     res = cfg.residual_multiplier
     reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens] * jnp.asarray(cfg.embedding_multiplier, params["tok_emb"].dtype)
-    for li, p in enumerate(params["layers"]):
+    counted = []  # a configuration with expert layers: their six counts, before the two every configuration has
+    if cfg.expert_layers:
+        real = _valid(n, m, counts, rows)  # the rows an expert layer routes and counts
+        cnt = jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
+    for li, (kind, p) in enumerate(zip(cfg.kinds, params["layers"])):
         ci = cfg.cache_index(li)
-        if li in cfg.attn_layers:
+        if kind == KIND_EXPERT:
+            with jax.named_scope(SCOPE_MLP):
+                y, c = _experts(cfg, p, x, real)
+                x = x + y * jnp.asarray(res, x.dtype)
+                with jax.named_scope(SCOPE_MOE_COMBINE):
+                    cnt = cnt + c
+            continue
+        if kind == KIND_ATTN:
             mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts, reads, attn_kernel == "interpret")
         else:
             mix, rec = _mamba(cfg, ci, p, x, rec, counts, rows, state_rows)
         with jax.named_scope(SCOPE_ATTN_OUT):
             x = x + mix * jnp.asarray(res, x.dtype)
-        with jax.named_scope(SCOPE_MLP):
-            gu = _rms(p["ln2"], x, cfg.rms_eps) @ p["mlp_in"].astype(x.dtype)
-            g, u = jnp.split(gu, 2, axis=-1)
-            x = x + ((jax.nn.silu(g) * u) @ p["mlp_out"].astype(x.dtype)) * jnp.asarray(res, x.dtype)
+        if cfg.paired:
+            with jax.named_scope(SCOPE_MLP):
+                gu = _rms(p["ln2"], x, cfg.rms_eps) @ p["mlp_in"].astype(x.dtype)
+                g, u = jnp.split(gu, 2, axis=-1)
+                x = x + ((jax.nn.silu(g) * u) @ p["mlp_out"].astype(x.dtype)) * jnp.asarray(res, x.dtype)
     with jax.named_scope(SCOPE_LM_HEAD):
-        last = x if pick is None else jnp.take_along_axis(x, pick[:, None, None], axis=1)
-        logits = jnp.einsum(  # the tied head: the embedding's rows again
-            "nmd,vd->nmv", _rms(params["ln_f"], last, cfg.rms_eps), jnp.asarray(params["tok_emb"]).astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        ) / cfg.logits_scaling
+        last = _rms(params["ln_f"], x if pick is None else jnp.take_along_axis(x, pick[:, None, None], axis=1), cfg.rms_eps)
+        if cfg.untied:
+            logits = jnp.matmul(last, params["lm_head"].astype(x.dtype), preferred_element_type=jnp.float32)
+        else:  # the tied head: the embedding's rows again
+            logits = jnp.einsum(
+                "nmd,vd->nmv", last, jnp.asarray(params["tok_emb"]).astype(x.dtype), preferred_element_type=jnp.float32
+            )
+        logits = logits / cfg.logits_scaling
         live = jnp.ones((n,), bool) if counts is None else counts > 0
         if rows is not None:
             live &= rows
         advanced = jnp.sum(live, dtype=jnp.int32)[None]
-    return logits, pool, rec, jnp.concatenate([advanced, run_pages])
+        if cfg.expert_layers:  # rows are every expert layer's own count: reported once, not summed
+            counted = [cnt.at[0].set(jnp.sum(real, dtype=jnp.int32))]
+    return logits, pool, rec, jnp.concatenate([*counted, advanced, run_pages])
 
 
 def _generate(cfg, params, ids, max_new_tokens: int):
@@ -451,15 +621,21 @@ class HybridDecoder:
     cfg: HybridDecoderConfig
 
     name = "hybrid"
-    # what the programs' readback carries after the tokens (FlightFrame
-    # fields): the rows whose state advanced, and where the step's kernel ran
-    # the pages it fetched in run DMAs (one layer's K)
-    frame_counters = ("ssm_rows", "attn_run_pages")
     # beside the plain rounds: a step that reads the pool in place (ops/gqa_decode.py's kernel)
     serves = frozenset({"attn_kernel"})
 
+    @property
+    def frame_counters(self) -> tuple:
+        """What the programs' readback carries after the tokens (FlightFrame
+        fields): the rows whose state advanced, and where the step's kernel
+        ran the pages it fetched in run DMAs (one layer's K); before them,
+        from a configuration with expert layers and no other, the routing
+        over the experts HELD (ops/moe.py ``moe_held_ffn``'s six)."""
+        held = ("moe_rows", "moe_experts_hit", "moe_load_max", *HELD_COUNTERS) if self.cfg.expert_layers else ()
+        return (*held, "ssm_rows", "attn_run_pages")
+
     def decoder_dims(self, params: dict) -> dict:
-        if "lm_head" in params or not any("ssm_in" in p for p in params["layers"]):
+        if ("lm_head" in params) != self.cfg.untied or not any("ssm_in" in p for p in params["layers"]):
             raise FamilyNotServed("not a hybrid decoder's parameters (models/hybrid_decoder.py layout)")
         c = self.cfg
         return {

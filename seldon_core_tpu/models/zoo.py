@@ -548,6 +548,14 @@ def build_hybrid_decoder(
     ssm_head_dim: int = 16,
     ssm_state: int = 16,
     ssm_conv: int = 4,
+    ssm_groups: int = 1,
+    experts: int = 0,
+    experts_held: int = 0,
+    first_expert: int = 0,
+    experts_per_tok: int = 0,
+    shared_ffn: int = 0,
+    routed_scale: float = 1.0,
+    untied: bool = False,
     embedding_multiplier: float = 12.0,
     residual_multiplier: float = 0.22,
     attention_multiplier: float = 0.0625,
@@ -557,38 +565,63 @@ def build_hybrid_decoder(
     seq: int = 32,
     max_new_tokens: int = 16,
     param_dtype: str = "bfloat16",
-    **_,
+    **unknown,
 ) -> ModelSpec:
     """The generative tier's third decoder family (models/hybrid_decoder.py,
-    Granite 4.0-H): Mamba-2 layers with a recurrent state, grouped-query
-    attention without positions in the layers ``attn_layers`` names
-    (comma-separated indices), a dense gated-SiLU MLP of width ``ffn`` in
-    every layer, Granite's four multipliers, a tied head. The parameters are
-    a published config's keys. Weights are drawn on the device from ``seed``
-    in ``param_dtype``. It serves through ``tpu.decode_slots``: the
-    recurrent state lives in state rows beside the KV pages, sized from
-    ``decode_slots`` and ``decode_prefix_slots``; without it the fused
-    fallback decodes whole batches greedily through the same forward.
-    Speculation, tensor-parallel decode, the int8 pool, the KV tiers and
-    prefix export are not served for it."""
+    Granite 4.0-H and Nemotron-H): Mamba-2 layers with a recurrent state
+    (``ssm_groups`` B/C groups), grouped-query attention without positions,
+    Granite's four multipliers (a model without them passes ones), a tied
+    head unless ``untied``. ``attn_layers`` says which layer is what, one of
+    two ways: comma-separated indices (Granite: those layers attend, every
+    other is Mamba-2, and a dense gated-SiLU MLP of width ``ffn`` pairs with
+    every mixer), or the published ``hybrid_override_pattern`` (Nemotron-H: a
+    character a layer, ``M`` Mamba-2, ``*`` attention, ``E`` an expert
+    layer; a layer is that ONE sublayer). An expert layer is a shared expert
+    of width ``shared_ffn`` plus the top ``experts_per_tok`` of ``experts``
+    routed ones of width ``ffn`` under the bias-selected sigmoid gate times
+    ``routed_scale``, squared-ReLU and ungated; ``experts_held`` (0: all)
+    from ``first_expert`` is one chip's share of an expert-parallel
+    deployment: the router keeps ``experts`` outputs and a pick that lands
+    on an absent expert adds nothing. The parameters are a published
+    config's keys; ``vocab`` the rows of the vocabulary held. Weights are
+    drawn on the device from ``seed`` in ``param_dtype``. It serves through
+    ``tpu.decode_slots``: the recurrent state lives in state rows beside the
+    KV pages, sized from ``decode_slots`` and ``decode_prefix_slots``;
+    without it the fused fallback decodes whole batches greedily through the
+    same forward. Speculation, tensor-parallel decode, the int8 pool, the KV
+    tiers and prefix export are not served for it. A parameter it does not
+    know is refused by name: a configuration written for a later tree fails
+    with a sentence here and does not build another model."""
     import jax.numpy as jnp
 
+    from seldon_core_tpu.graph.spec import bool_param
     from seldon_core_tpu.models.hybrid_decoder import (
         HybridDecoderConfig,
         hybrid_family,
         init_hybrid_decoder,
     )
 
+    if unknown:
+        raise ValueError(
+            f"zoo://hybrid_decoder does not know the parameter(s) {sorted(unknown)}: "
+            "it builds what it is told, not a model without them"
+        )
     if seq + max_new_tokens > max_len:
         raise ValueError(
             f"seq={seq} + max_new_tokens={max_new_tokens} exceeds max_len={max_len}"
         )
+    named = str(attn_layers).strip()
+    by_index = all(i.strip().isdigit() for i in named.split(",") if i.strip())
     cfg = HybridDecoderConfig(
         vocab=int(vocab), hidden=int(hidden), layers=int(layers),
-        attn_layers=tuple(int(i) for i in str(attn_layers).split(",") if i.strip()),
+        attn_layers=tuple(int(i) for i in named.split(",") if i.strip()) if by_index else (),
+        pattern="" if by_index else named,
         heads=int(heads), kv_heads=int(kv_heads), head_dim=int(head_dim), ffn=int(ffn),
         ssm_heads=int(ssm_heads), ssm_head_dim=int(ssm_head_dim), ssm_state=int(ssm_state),
-        ssm_conv=int(ssm_conv), embedding_multiplier=float(embedding_multiplier),
+        ssm_conv=int(ssm_conv), ssm_groups=int(ssm_groups), untied=bool_param(untied), experts=int(experts),
+        experts_held=int(experts_held) or int(experts), first_expert=int(first_expert),
+        experts_per_tok=int(experts_per_tok), shared_ffn=int(shared_ffn), routed_scale=float(routed_scale),
+        embedding_multiplier=float(embedding_multiplier),
         residual_multiplier=float(residual_multiplier),
         attention_multiplier=float(attention_multiplier),
         logits_scaling=float(logits_scaling), rms_eps=float(rms_eps), max_len=int(max_len),

@@ -86,10 +86,14 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 
 
 # ------------------------------------------------ the served expert layer
-# Top-k routed, gated-SiLU experts for the decode programs
-# (models/moe_decoder.py): softmax over ALL experts in float32, top k,
-# gates renormalised over the k; every token reaches all k of its experts
-# (no capacity, no dropped token). Two forms of the same mathematics,
+# Top-k routed experts for the decode programs (models/moe_decoder.py):
+# softmax over ALL experts in float32, top k, gates renormalised over the k;
+# every token reaches all k of its experts (no capacity, no dropped token).
+# An expert's activation follows its weights (``_w_in`` / ``_activate``):
+# ``gate_up`` + ``down`` is the gated-SiLU expert ``down(silu(gate x) * up
+# x)`` of every family before the hybrid one's expert layers; ``up`` +
+# ``down`` is ``down(relu(up x)^2)``, no gate projection (``mlp_hidden_act:
+# relu2``, models/hybrid_decoder.py). Two forms of the same mathematics,
 # chosen by the program's STATIC row count:
 #
 # - grouped (chunk rounds): the T*k assignments are sorted by expert and
@@ -114,7 +118,7 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 # the family's router keeps its published width and routes over ALL experts
 # (``route_sigmoid_grouped``, ``route_sigmoid_biased``; ``moe_held_ffn`` takes
 # the picks, whichever gate made them), the chip holds ``held`` of them from ``first``
-# (``p["gate_up"].shape[0]`` of the stored weights), and ``held_picks`` hands
+# (``p["down"].shape[0]`` of the stored weights), and ``held_picks`` hands
 # the SAME two forms the picks renumbered to the experts held, an absent
 # pick as the junk group at gate zero: the masked form's one-hot matches no
 # expert for it, the grouped form sorts it past the last group. Neither form
@@ -189,7 +193,7 @@ def moe_load_balance_loss(params: dict, x: jax.Array) -> jax.Array:
 # device scopes, nested under the decoder's ``mlp`` scope
 SCOPE_MOE_ROUTER = "moe_router"  # router product, softmax, top-k, renormalisation
 SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort by expert, group sizes, row gather
-SCOPE_MOE_EXPERTS = "moe_experts"  # the expert matrix products and the gated SiLU
+SCOPE_MOE_EXPERTS = "moe_experts"  # the expert matrix products and the activation between them
 SCOPE_MOE_COMBINE = "moe_combine"  # un-sort, gate-weighted sum, counters
 
 # rows (tokens of one dispatch) up to which the masked form is used; above
@@ -228,6 +232,14 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def lane_tiles(width: int) -> int:
+    """``width`` in whole 128-lane tiles: what an expert's hidden width is
+    STORED as where it is off the tile (``_grouped_dot``). Storage, never a
+    width: the padding is zeros in ``up``'s columns and ``down``'s rows, and
+    routers, references, rooflines and configurations say the width."""
+    return -(-width // 128) * 128
+
+
 def _grouped_dot(xs: jax.Array, w: jax.Array, sizes: jax.Array, out_dtype) -> jax.Array:
     """xs[A, k] (rows sorted by group) x w[G, k, n] -> [A, n]: row r times
     its group's matrix. Rows past ``sum(sizes)`` belong to no group and come
@@ -237,7 +249,18 @@ def _grouped_dot(xs: jax.Array, w: jax.Array, sizes: jax.Array, out_dtype) -> ja
     these shapes 3-4x ``lax.ragged_dot``'s lowering and bit-identical to it
     (8192 rows: 0.87 / 0.46 ms against 3.17 / 2.23 for the two products, my
     chip runs, PR 28). Elsewhere, and at sizes off its 128-tiles,
-    ``lax.ragged_dot``."""
+    ``lax.ragged_dot``. A width off the tile is the CALLER's to store in
+    whole tiles (``lane_tiles``): at the hybrid family's expert of 1856 =
+    14.5 tiles (1,792 compact rows of a (4, 256) dispatch, hidden 2688, 16
+    held experts, 784 rows in groups; both products and the squared ReLU,
+    eleven layers chained, ms a layer on the host's clock, my chip run,
+    PR 51) ``ragged_dot`` as it fell reads 5.74, this kernel over ``up`` and
+    ``down`` stored zero-padded to 1920 reads 1.76 (exact: ``relu(0)^2 = 0``
+    against zero rows of ``down``; +3.4% bytes), and this kernel over 1856
+    as it is (an n tile of 640, 384 or 128 that does not divide it, the last
+    one masked at the edge; a whole-k tile of 1856) 2.18 to 2.36 with the
+    same bits; with every row of the block in a group 6.39 / 1.84 / 2.29.
+    The padded store stays, the kernel's rule stays what it was."""
     a, k = xs.shape
     n = w.shape[2]
     if _on_tpu() and a % 128 == 0 and k % 128 == 0 and n % 128 == 0:
@@ -269,6 +292,19 @@ def _gated_silu(gate_up: jax.Array) -> jax.Array:
     return jax.nn.silu(g) * u
 
 
+def _w_in(p: dict) -> jax.Array:
+    """An expert's first projection as it is stored: gate and up side by side
+    (``gate_up``: the gated-SiLU expert) or up alone (``up``: the squared-ReLU
+    expert, which has no gate projection)."""
+    return p["gate_up"] if "gate_up" in p else p["up"]
+
+
+def _activate(p: dict, h: jax.Array) -> jax.Array:
+    """What lies between an expert's two products, by the weights it has:
+    ``silu(gate) * up`` of a fused ``gate_up``, ``relu(up)^2`` of ``up``."""
+    return _gated_silu(h) if "gate_up" in p else jnp.square(jax.nn.relu(h))
+
+
 def _load_counters(sizes: jax.Array, rows: jax.Array) -> jax.Array:
     """[3] int32 of one layer's routing, real rows only: rows, distinct
     experts with a row, the fullest expert's rows."""
@@ -287,8 +323,8 @@ def _split_bf16(a: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
 
 
 def moe_experts_grouped(p: dict, x, gates, experts, valid, cap: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """The grouped form. p: {"gate_up": [E, d, 2f], "down": [E, f, d]};
-    x[T, d]; gates/experts[T, k]; valid[T] bool (junk rows False).
+    """The grouped form. p: {"gate_up": [E, d, 2f] or "up": [E, d, f],
+    "down": [E, f, d]} (``_activate``); x[T, d]; gates/experts[T, k]; valid[T] bool (junk rows False).
     Returns (y[T, d] in x's dtype with zeros on junk rows, counters[3]).
     With ``cap`` (static; ``held_capacity``) the COMPACT form: the sorted
     assignments that have a product (every real one and the junk row an
@@ -297,7 +333,7 @@ def moe_experts_grouped(p: dict, x, gates, experts, valid, cap: int | None = Non
     where the routing is anywhere near even, more where it is not, so no
     pick is dropped whatever the routing."""
     t, k = experts.shape
-    n_exp = p["gate_up"].shape[0]
+    n_exp = p["down"].shape[0]
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         ids = jnp.arange(n_exp, dtype=jnp.int32)
         flat = jnp.where(valid[:, None], experts, n_exp).reshape(-1)  # junk -> group E
@@ -316,7 +352,7 @@ def moe_experts_grouped(p: dict, x, gates, experts, valid, cap: int | None = Non
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         xs = jnp.take(x, order // k, axis=0)  # [T*k, d], sorted by expert
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        h = _gated_silu(_grouped_dot(xs, p["gate_up"].astype(x.dtype), sizes, x.dtype))
+        h = _activate(p, _grouped_dot(xs, _w_in(p).astype(x.dtype), sizes, x.dtype))
         ys = _grouped_dot(h, p["down"].astype(x.dtype), sizes, jnp.float32)
     with jax.named_scope(SCOPE_MOE_COMBINE):
         # rows past the last group belong to no product: hold them to zero
@@ -337,7 +373,7 @@ def _compact_blocks(p: dict, x, gates, order, sizes, cap: int) -> jax.Array:
     un-sort, which would rebuild the [T*k, d] array. The trip count is the
     device's: ceil(sum(sizes) / cap)."""
     t, k = gates.shape
-    w_in, w_out = p["gate_up"].astype(x.dtype), p["down"].astype(x.dtype)
+    w_in, w_out = _w_in(p).astype(x.dtype), p["down"].astype(x.dtype)
     ends = jnp.cumsum(sizes, dtype=jnp.int32)
     total = ends[-1]
     order = jnp.pad(order, (0, -order.shape[0] % cap))  # a whole last block; what is past ``total`` weighs nothing
@@ -350,7 +386,7 @@ def _compact_blocks(p: dict, x, gates, order, sizes, cap: int) -> jax.Array:
             here = jnp.clip(ends - at, 0, cap) - jnp.clip(ends - sizes - at, 0, cap)  # each group's rows in this block
             xs = jnp.take(x, idx // k, axis=0)  # [cap, d], sorted by expert
         with jax.named_scope(SCOPE_MOE_EXPERTS):
-            ys = _grouped_dot(_gated_silu(_grouped_dot(xs, w_in, here, x.dtype)), w_out, here, jnp.float32)
+            ys = _grouped_dot(_activate(p, _grouped_dot(xs, w_in, here, x.dtype)), w_out, here, jnp.float32)
         with jax.named_scope(SCOPE_MOE_COMBINE):
             # rows past the last group belong to no product: hold them to zero
             ys = jnp.where((jnp.arange(cap) < total - at)[:, None], ys * jnp.take(flat_gates, idx)[:, None], 0.0)
@@ -364,14 +400,14 @@ def _compact_blocks(p: dict, x, gates, order, sizes, cap: int) -> jax.Array:
 def moe_experts_masked(p: dict, x, gates, experts, valid) -> tuple[jax.Array, jax.Array]:
     """The masked form: every expert computes every row, the gates select.
     Same arguments and results as ``moe_experts_grouped``."""
-    n_exp = p["gate_up"].shape[0]
+    n_exp = p["down"].shape[0]
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         hot = experts[:, :, None] == jnp.arange(n_exp, dtype=jnp.int32)[None, None, :]
         hot = hot & valid[:, None, None]  # [T, k, E]
         dense = jnp.sum(jnp.where(hot, gates[..., None], 0.0), axis=1)  # [T, E] float32
         sizes = jnp.sum(hot, axis=(0, 1), dtype=jnp.int32)
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        h = _gated_silu(jnp.einsum("td,edf->etf", x, p["gate_up"].astype(x.dtype)))
+        h = _activate(p, jnp.einsum("td,edf->etf", x, _w_in(p).astype(x.dtype)))
         ys = jnp.einsum(
             "etf,efd->etd", h, p["down"].astype(x.dtype), preferred_element_type=jnp.float32
         )
@@ -427,25 +463,32 @@ def held_picks(gates: jax.Array, experts: jax.Array, first: int, held: int):
     return jnp.where(here, gates, 0.0), jnp.where(here, local, held), here
 
 
+def expert_mlp(p: dict, x: jax.Array) -> jax.Array:
+    """ONE expert over every row of x[T, d], its activation by its weights
+    (``_activate``): a dense layer's MLP, a shared expert."""
+    return _activate(p, x @ _w_in(p).astype(x.dtype)) @ p["down"].astype(x.dtype)
+
+
 def gated_mlp(gate_up: jax.Array, down: jax.Array, x: jax.Array) -> jax.Array:
-    """down(silu(gate x) * up x): a dense layer's MLP, a shared expert."""
-    return _gated_silu(x @ gate_up.astype(x.dtype)) @ down.astype(x.dtype)
+    """down(silu(gate x) * up x): ``expert_mlp`` of a fused ``gate_up``."""
+    return expert_mlp({"gate_up": gate_up, "down": down}, x)
 
 
 def route_sigmoid_biased(
-    router_w: jax.Array, bias: jax.Array, x: jax.Array, k: int, scale: float
+    router_w: jax.Array, bias: jax.Array, x: jax.Array, k: int, scale: float, eps: float = 1e-6
 ) -> tuple[jax.Array, jax.Array]:
     """x[T, d] -> (gates[T, k] float32, experts[T, k] int32) of a gate whose
     per-expert bias SELECTS and does not weigh (``use_expert_bias``; the
     bias is what load balancing trains): sigmoid scores over ALL experts in
     float32, no groups; the top k of ``scores + bias`` (ties to the lower
-    index); gates are the picks' UNBIASED scores over their sum + 1e-6,
-    times ``scale``."""
+    index); gates are the picks' UNBIASED scores over their sum + ``eps``
+    (1e-6 as ``lfm2_moe`` publishes it, 1e-20 as ``nemotron_h`` does), times
+    ``scale``."""
     with jax.named_scope(SCOPE_MOE_ROUTER):
         s = jax.nn.sigmoid(x.astype(jnp.float32) @ router_w.astype(jnp.float32))  # [T, E]
         _, top_e = jax.lax.top_k(s + bias.astype(jnp.float32), k)
         top_s = jnp.take_along_axis(s, top_e, axis=1)
-        return top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6) * scale, top_e.astype(jnp.int32)
+        return top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + eps) * scale, top_e.astype(jnp.int32)
 
 
 def held_capacity(assignments: int, held: int, routed: int) -> int:
@@ -463,7 +506,7 @@ def moe_held_ffn(
     p: dict, x: jax.Array, gates: jax.Array, experts: jax.Array, first: int, valid: jax.Array | None = None
 ):
     """The ROUTED part of the expert layer on a chip that holds the experts
-    ``[first, first + p["gate_up"].shape[0])`` of those the family's router
+    ``[first, first + p["down"].shape[0])`` of those the family's router
     chose among (``gates`` / ``experts`` [T, k] over ALL of them, as wide as
     ``p["router"]``: ``route_sigmoid_grouped``, ``route_sigmoid_biased``):
     compute the picks that land here, add nothing for the others. Returns
@@ -479,7 +522,7 @@ def moe_held_ffn(
         )
     if valid is None:
         valid = jnp.ones((t,), bool)
-    held = p["gate_up"].shape[0]
+    held = p["down"].shape[0]
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         gates, experts, here = held_picks(gates, experts, first, held)
         local = jnp.sum(here & valid[:, None], dtype=jnp.int32)

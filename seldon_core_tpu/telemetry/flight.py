@@ -418,7 +418,9 @@ class FlightFrame:
     expert's rows, the last two summed over layers. 0 for a family without
     experts; ``ssm_rows`` the batch rows whose recurrent state the round's
     chunk and step dispatches advanced, as a recurrent family's programs
-    counted them (models/hybrid_decoder.py), ``state_restores`` the round's
+    counted them (models/hybrid_decoder.py, whose configurations with expert
+    layers count the ``moe_*`` six over the experts HELD beside it: both
+    groups in one frame), ``state_restores`` the round's
     admissions that began from a cached prefix's snapshot row and
     ``state_captures`` the snapshot rows the round bound to a new prefix
     entry (serving/kv_pool.py); 0 for a family without a state cache;
@@ -661,6 +663,8 @@ class FlightFrame:
             d["conv"] = [self.conv_rows, self.state_restores, self.state_captures]
         if self.mla_ctx_rows:
             d["mla"] = [self.mla_ctx_rows, self.moe_local_picks]
+        elif self.moe_local_picks:  # a share of the experts under another family's attention or state rows
+            d["moe_local_picks"] = self.moe_local_picks
         if self.moe_grouped_calls:
             d["moe_compact"] = [self.moe_compact_calls, self.moe_grouped_calls]
         if self.mla_pages_read:

@@ -347,8 +347,8 @@ def _stateful(family: str, **kw):
     prompts in chunks of at most 16, a 12-token hint's boundary ends a chunk."""
     from tests import test_conv_decoder, test_hybrid_decoder
 
-    mod = {"hybrid": test_hybrid_decoder, "conv": test_conv_decoder}[family]
-    ms = mod._zoo()
+    mod = {"hybrid": test_hybrid_decoder, "hybrid_experts": test_hybrid_decoder, "conv": test_conv_decoder}[family]
+    ms = mod._nzoo() if family == "hybrid_experts" else mod._zoo()  # the hybrid family's single-sublayer shape (PR 51)
     return ms, mod._sched(ms, n_slots=SSLOTS, **kw)
 
 
@@ -376,7 +376,7 @@ def _spy_on_the_state_rows(s, calls):
     return seen
 
 
-@pytest.mark.parametrize("family", ["hybrid", "conv"])
+@pytest.mark.parametrize("family", ["hybrid", "hybrid_experts", "conv"])
 async def test_a_slot_left_out_of_a_round_keeps_its_state_rows(family):
     """Eight admissions that hit one hinted prefix over a 4-row bound: the
     round takes the four oldest, and the four it leaves out keep their own

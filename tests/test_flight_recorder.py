@@ -840,3 +840,28 @@ def test_frame_window_kind_counts_round_trip(kw, want):
     rec = FlightRecorder(n_slots=4, name="t", capacity=4, enabled=True)
     rec.record(f)
     assert rec.snapshot()[0].to_dict().get("kv_win") == want
+
+
+@pytest.mark.parametrize("kw, want", [
+    ({"ssm_rows": 64}, {"ssm": [64, 0, 0]}),
+    ({"ssm_rows": 64, "moe_rows": 64, "moe_experts_hit": 170, "moe_load_max": 90, "moe_local_picks": 530},
+     {"ssm": [64, 0, 0], "moe": [64, 170, 90], "moe_local_picks": 530}),
+    ({"ssm_rows": 4, "state_restores": 2, "moe_rows": 1088, "moe_experts_hit": 176, "moe_load_max": 700,
+      "moe_local_picks": 9000, "moe_grouped_calls": 11, "moe_compact_calls": 11},
+     {"ssm": [4, 2, 0], "moe": [1088, 176, 700], "moe_local_picks": 9000, "moe_compact": [11, 11]}),
+    ({"mla_ctx_rows": 500, "moe_rows": 64, "moe_local_picks": 30}, {"mla": [500, 30], "moe": [64, 0, 0]}),
+], ids=["granite", "single_sublayers_step", "single_sublayers_wide_chunk", "latent_family"])
+def test_frame_carries_state_rows_and_held_expert_counts_together(kw, want):
+    """The hybrid family's frames with expert layers (PR 51) carry the held
+    experts' counts beside ``ssm_rows``: both groups read back as given and
+    show in one dump, the picks that landed here under their own key (the
+    latent family keeps them beside its context rows), and a configuration
+    without experts dumps what it dumped."""
+    f = _frame(0, **kw)
+    assert all(getattr(f, k) == v for k, v in kw.items())
+    keys = ("ssm", "moe", "moe_local_picks", "moe_compact", "mla")
+    assert {k: f.to_dict()[k] for k in keys if k in f.to_dict()} == want
+    rec = FlightRecorder(n_slots=4, name="t", capacity=4, enabled=True)
+    rec.record(f)
+    got = rec.snapshot()[0].to_dict()
+    assert {k: got[k] for k in keys if k in got} == want

@@ -698,12 +698,32 @@ def test_the_sixteen_experts_four_shares_and_the_shared_expert_once_sum_to_the_u
         np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-6)
 
 
+def _relu2(p):
+    """The same layer with squared-ReLU experts: ``up`` (the gated one's up
+    half) and ``down``, no gate projection (the hybrid family's, PR 51)."""
+    rest = {k: v for k, v in p.items() if k != "gate_up"}
+    return {**rest, "up": p["gate_up"][..., p["gate_up"].shape[-1] // 2 :]}
+
+
+def _plain_relu2_share(p, x, gates, experts, first):
+    """down_e(relu(up_e x)^2) of every held expert over every row, weighed by
+    the gates of the picks that name it: no sort, no capacity, no form."""
+    held = p["down"].shape[0]
+    dense = jnp.sum(jnp.where(experts[:, :, None] == first + jnp.arange(held)[None, None, :], gates[:, :, None], 0.0), axis=1)
+    h = jnp.square(jax.nn.relu(jnp.einsum("td,edf->etf", x, p["up"], precision="highest")))
+    return jnp.einsum("etf,efd,te->td", h, p["down"], dense, precision="highest")
+
+
+@pytest.mark.parametrize("act", ["gated_silu", "relu2"])
 @pytest.mark.parametrize(
     "rows,form",
     [(24, "masked"), (300, "grouped"), (512, "refused"), (512, "compact"), (300, "overflow"), (300, "all_held")],
 )
-def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch, rows, form):
-    """By static row count: masked to 256 rows, grouped above, and refused
+def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch, rows, form, act):
+    """The activation follows the expert's weights (``gate_up`` + ``down``:
+    gated SiLU, held to the latent family's reference; ``up`` + ``down``:
+    squared ReLU, held to the plain sum over experts), ONE body a form.
+    By static row count: masked to 256 rows, grouped above, and refused
     above ``GROUPED_BLOCK_ROWS`` (128 in the third case; no served program is
     that wide). Junk rows come back zero and stay out of the counts, which
     are over the experts HELD. Since PR 49 the grouped form over a SHARE of
@@ -717,6 +737,8 @@ def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch
     monkeypatch.setattr(moe, "GROUPED_BLOCK_ROWS", 128 if form == "refused" else 4096)
     first, n_held = (0, 16) if form == "all_held" else (4, 4)
     p = _share(_expert_layer(1), first, n_held)
+    if act == "relu2":
+        p = _relu2(p)
     n2 = jax.random.normal(jax.random.key(rows), (rows, CFG.hidden))
     if form == "overflow":  # a coordinate every row shares, and router weights that score the held experts on it
         n2 = n2.at[:, 0].set(3.0)
@@ -728,10 +750,13 @@ def test_the_held_layer_in_each_form_equals_the_reference_share(ref, monkeypatch
             held(p, n2, valid)
         return
     y, counted = held(p, n2, valid)
-    want = np.asarray(ref.expert_ffn(p, n2, first_expert=first, act="float32", shared=False, **ROUTE))
+    gates, experts = moe.route_sigmoid_grouped(p["router"], n2, 4, 4, 2, 2.5)
+    if act == "relu2":
+        want = np.asarray(_plain_relu2_share(p, n2, gates, experts, first))
+    else:
+        want = np.asarray(ref.expert_ffn(p, n2, first_expert=first, act="float32", shared=False, **ROUTE))
     np.testing.assert_allclose(np.asarray(y[: rows - 5]), want[: rows - 5], atol=5e-6)
     assert not np.asarray(y[rows - 5 :]).any()
-    _, experts = moe.route_sigmoid_grouped(p["router"], n2, 4, 4, 2, 2.5)
     mine = np.asarray((experts >= first) & (experts < first + n_held))[: rows - 5]
     loads = np.bincount(np.asarray(experts)[: rows - 5][mine] - first, minlength=n_held)
     assert [int(c) for c in counted[:4]] == [rows - 5, int((loads > 0).sum()), int(loads.max()), int(mine.sum())]

@@ -623,6 +623,79 @@ def test_hybrid_family_updates_state_rows_in_place_at_granite_micro_widths(topo,
         _assert_step_reads_the_pool_through_the_kernel(compiled.as_text(), 1)
 
 
+@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_4_256"])
+def test_hybrid_family_with_single_sublayers_compiles_in_place_at_nemotron_nano_widths(topo, program, monkeypatch):
+    """The hybrid family's other shape (PR 51) at the
+    nemotron-3-nano-30b-a3b cell's widths, the pattern's first seven layers
+    (3 Mamba-2 with eight B/C groups, 3 expert layers that hold 16 of 128
+    squared-ReLU experts of 1856 stored as 1920, 1 attention layer of 32 / 2
+    heads of 128): the fused step (64 slots; through the gather, and with the
+    grouped-query kernel at a group of SIXTEEN over a 256-lane row) and the
+    (4, 256) chunk compile for the chip; pool and state rows come back
+    aliased and nothing the size of the state is copied; the chunk's expert
+    layers run the megablox kernel over ``held_capacity`` rows and no
+    ``ragged-dot`` (the width off the tile is stored in whole tiles)."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+    from seldon_core_tpu.ops import moe
+    from seldon_core_tpu.ops.gqa_decode import gqa_tiles
+
+    assert gqa_tiles(256, 32, 2, 16, jnp.bfloat16) and not gqa_tiles(256, 32, 2, 16, jnp.float32)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = hd.HybridDecoderConfig(
+        vocab=16384, hidden=2688, layers=7, pattern="MEMEM*E", heads=32, kv_heads=2, head_dim=128, ffn=1856,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8, untied=True, experts=128, experts_held=16,
+        experts_per_tok=6, shared_ffn=3712, routed_scale=2.5, embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=128**-0.5, logits_scaling=1.0, max_len=262144,
+    )
+    fam = hd.hybrid_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: hd.init_hybrid_decoder(cfg, 0, jnp.bfloat16)))
+    assert params["layers"][1]["moe"]["up"].shape == (16, 2688, 1920) and params["layers"][1]["shared"]["up"].shape == (2688, 3712)
+    n, rows_total = 64, 64 + 4 + 1
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 9400, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
+    assert pool[0].shape == (1, 9400, 16, 256) and len(rec) == 6
+    assert rec[0].shape == (rows_total, 64, 64, 128) and rec[3].shape == (rows_total, 3 * 6144)
+    step, chunk = fam.fused_programs("mosaic" if program == "step_kernel" else "")
+    i32, f32 = jnp.int32, jnp.float32
+    if program.startswith("step"):
+        args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+        fn = step
+    else:
+        r, c = 4, 256
+        args = (arr((r, 144), i32), arr((r, c), i32), arr((r,), i32), arr((r,), i32), arr((r,), f32),
+                arr((r,), i32), arr((), i32), arr((), i32), arr((3, r), i32))
+        fn = chunk
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, rec, *args).compile()
+    donated = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in (*pool, *rec))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    state = re.escape("f32[%d,64,64,128]" % rows_total)
+    copies = [ln for ln in text.splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
+    assert not copies, copies[:2]
+    kind = "chunk" if program == "chunk_4_256" else "step"
+    for outer, inner in (("attn", "ssm_scan"), ("mlp", "shared_expert"), ("mlp", "moe_experts"), ("mlp", "moe_router")):
+        # the compact form's products lie in its blocks' loop: ``mlp/while/body/moe_experts``
+        assert re.search(r'op_name="jit\(_fused_%s\)/%s/([^"/]+/)*%s/' % (kind, outer, inner), text), inner
+    assert "ragged-dot" not in text
+    if program == "chunk_4_256":
+        cap = moe.held_capacity(4 * 256 * 6, 16, 128)
+        assert cap == 1792 and _grouped_product_rows(text) == [cap, cap] * 3  # up then down, an expert layer
+    else:
+        assert _grouped_product_rows(text) == []  # 64 rows: the masked form
+    if program == "step_kernel":
+        _assert_step_reads_the_pool_through_the_kernel(text, 1)
+
+
 def _ungated_lines(text: str) -> list[str]:
     """The compiled module's instructions that run whatever a ``conditional``
     decides: the entry computation's and those of every computation it

@@ -512,3 +512,33 @@ def test_the_sparse_expert_programs_nest_what_pr_47_added_under_the_old_scopes(p
     for scope in decoder.PAGED_SCOPES:
         assert f"/{scope}/" in text, scope
     assert "/gate/" not in text.replace("/attn_out/gate/", "")  # the gate is nowhere but under attn_out
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_the_hybrid_programs_nest_the_expert_layer_under_mlp_beside_the_state_scopes(program):
+    """A configuration of single sublayers (PR 51): the expert layer under
+    ``mlp`` by ``ops/moe.py``'s names, the shared expert among them, the
+    Mamba-2 mixer under the ``ssm_*`` names it had, attention as it was: a
+    reader of the nine old scopes still sees all of the time."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+    from tests.test_hybrid_decoder import NCFG
+
+    fam = hd.hybrid_family(NCFG)
+    params = hd.init_hybrid_decoder(NCFG, 0, jnp.float32)
+    pool, rec = fam.paged_kv_init(params, 8, 4), fam.state_init(params, 4)
+    n = 2
+    bt = jnp.zeros((n, 4), jnp.int32)
+    vec, temps = jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32)
+    step, chunk = fam.fused_programs()
+    if program == "step":
+        args = (params, pool, rec, bt, vec, vec, temps, vec, 0, jnp.int32(1), jnp.ones((n,), bool))
+    else:
+        args = (params, pool, rec, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1),
+                jnp.zeros((3, n), jnp.int32))
+    text = jax.jit(step if program == "step" else chunk).lower(*args).compile().as_text()
+    for scope in ("mlp/shared_expert", "mlp/moe_router", "mlp/moe_dispatch", "mlp/moe_experts", "mlp/moe_combine",
+                  "qkv/ssm_in", "attn/ssm_conv", "attn/ssm_scan", "attn_out/ssm_norm", "attn_out/ssm_out"):
+        assert f"/{scope}/" in text, scope
+    for scope in decoder.PAGED_SCOPES:
+        assert f"/{scope}/" in text, scope
+    assert "/shared_expert/" not in text.replace("/mlp/shared_expert/", "")  # nowhere but under mlp
