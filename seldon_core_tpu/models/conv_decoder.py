@@ -39,9 +39,11 @@ an index past the last row drops the write), and positions past
 ``counts[r]`` leave the cache as it was: the hybrid family's contract, with
 nothing of Mamba's in it.
 
-On one TPU the STEP's attention layers read the pool's pages where they lie
-(ops/gqa_decode.py ``gqa_decode_attention``, where ``decode_programs.
-_step_attn_kernel`` chooses it; chunks and the CPU keep the gather). Not
+On one TPU the attention layers read the pool's pages where they lie: the
+STEP through ops/gqa_decode.py ``gqa_decode_attention``, a prefill CHUNK
+through ``gqa_chunk_attention`` (where ``decode_programs._step_attn_kernel``
+chooses a kernel and ``gqa_chunk_tiles`` holds for the chunk: ``chunk_attn``;
+the CPU keeps the gather, the oracle of both). Not
 served: speculation, a decode mesh, the int8 pool, the host tier, prefix
 export (each refuses by name, ``decoder.require_served``).
 """
@@ -70,10 +72,11 @@ from seldon_core_tpu.models.decoder import (
     _paged_write,
     counted_state_programs,
     kv_pool_zeros,
+    paged_gqa_attention,
     paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, SCOPE_ROPE, _attend, _rms, _rope
-from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
+from seldon_core_tpu.ops.gqa_decode import gqa_chunk_tiles
 from seldon_core_tpu.ops.moe import (
     HELD_COUNTERS,
     N_HELD_COUNTERS,
@@ -299,9 +302,7 @@ def _attention(cfg: ConvDecoderConfig, ki: int, p, x, pool, bt, positions, count
     pool = _paged_write(pool, ki, k, v, bt, positions, counts)
     if reads is not None:
         with jax.named_scope(SCOPE_ATTN):
-            ctx = gqa_decode_attention(
-                q[:, 0], pool[0], pool[1], ki, bt, *reads, scale=cfg.head_dim**-0.5, interpret=interpret
-            )[:, None]
+            ctx = paged_gqa_attention(q, pool, ki, bt, reads, scale=cfg.head_dim**-0.5, interpret=interpret)
     else:
         ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
         with jax.named_scope(SCOPE_ATTN):
@@ -336,8 +337,9 @@ def _forward(
     slots), ``pick`` [n] (the head's one query a row), ``state_rows`` [3, n]
     (``_conv``); ``attn_kernel`` (static; "" | "mosaic" | "interpret":
     ``decode_programs._step_attn_kernel``'s answer) lets a dispatch of ONE
-    query a slot read the pool through ops/gqa_decode.py's kernel, every
-    other shape gathers. Returns (logits [n, m or 1, vocab] float32, pool,
+    query a slot, and a prefill chunk (``counts``; ``gqa_chunk_tiles``), read
+    the pool through ops/gqa_decode.py's kernels; every other shape gathers.
+    Returns (logits [n, m or 1, vocab] float32, pool,
     rec, counters[8] int32: ``ConvDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
@@ -345,7 +347,8 @@ def _forward(
         valid &= jnp.arange(m)[None, :] < counts[:, None]
     if rows is not None:
         valid &= rows[:, None]
-    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
+    chunk = gqa_chunk_tiles(attn_kernel, m, cfg.heads, cfg.kv_heads, cfg.head_dim)
+    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows, counts if chunk else None)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
     cnt = jnp.zeros((N_HELD_COUNTERS,), jnp.int32)
@@ -426,12 +429,20 @@ class ConvDecoder:
             self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows, attn_kernel
         )
 
+    def chunk_attn(self, attn_kernel: str, c: int) -> str:
+        """How the chunk program of ``c`` tokens a row reads the pool under
+        ``attn_kernel``: "kernel" (ops/gqa_decode.py ``gqa_chunk_attention``)
+        or "gather". Static (``gqa_chunk_tiles``: what ``_forward`` asks)."""
+        takes = gqa_chunk_tiles(attn_kernel, c, self.cfg.heads, self.cfg.kv_heads, self.cfg.head_dim)
+        return "kernel" if takes else "gather"
+
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.
-        counted_state_programs``); with ``attn_kernel`` the one whose
-        dispatch is one query a slot, the step, reads the pool through the
-        kernel. Cached: equal configurations share compiled programs."""
+        counted_state_programs``); with ``attn_kernel`` the step reads the
+        pool through ops/gqa_decode.py's step kernel and a chunk through its
+        chunk kernel (``chunk_attn``). Cached: equal configurations share
+        compiled programs."""
         return counted_state_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def generate(self, params, ids, max_new_tokens: int):

@@ -31,7 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from seldon_core_tpu.ops.gqa_decode import pages_fetched, step_reads
+from seldon_core_tpu.ops.gqa_decode import (
+    chunk_reads,
+    gqa_chunk_attention,
+    gqa_decode_attention,
+    pages_fetched,
+    step_reads,
+)
 from seldon_core_tpu.ops.paged_attention import paged_attention_decode, slot_lengths
 
 
@@ -816,21 +822,37 @@ def _paged_gather(pool: tuple, li: int, bt, h: int) -> tuple[jax.Array, jax.Arra
     return _split_heads(k.reshape(n, p * ps, w), h), _split_heads(v.reshape(n, p * ps, w), h)
 
 
-def _paged_step_reads(attn_kernel: str, queries: int, pool: tuple, bt, positions, rows):
+def _paged_step_reads(attn_kernel: str, queries: int, pool: tuple, bt, positions, rows, counts=None):
     """What a grouped-query family's program hands ops/gqa_decode.py's
-    kernel, once for all its attention layers (they walk the same tables):
-    (``gqa_decode.step_reads``' lengths and run flags, the pages of one
-    layer's K that come in run DMAs as int32[1]) where the program set chose
-    a kernel (``attn_kernel``) AND the dispatch has one query a slot against
-    the two-plane float pool, else (None, zero): the gather. What is left of
-    the gather there, a few integers a slot, stays under the ``kv_gather``
-    scope."""
-    if not attn_kernel or queries != 1 or len(pool) != 2:
+    kernels, once for all its attention layers (they walk the same tables):
+    (the kernel's vectors, the pages of one layer's K that a STEP's kernel
+    fetches in run DMAs as int32[1]) where the program set chose a kernel
+    (``attn_kernel``) AND the dispatch is one the kernels take against the
+    two-plane float pool: one query a slot (the step: ``gqa_decode.step_reads``'
+    lengths and run flags) or a prefill chunk whose ``counts`` the family
+    hands over where its ``chunk_attn`` says "kernel" (``chunk_reads``'
+    five); else (None, zero): the gather. What is left of the gather there, a
+    few integers a slot, stays under the ``kv_gather`` scope."""
+    if not attn_kernel or len(pool) != 2 or (queries != 1 and counts is None):
         return None, jnp.zeros((1,), jnp.int32)
     with jax.named_scope(SCOPE_KV_GATHER):
         page_size = pool[0].shape[2]
+        if queries != 1:
+            return chunk_reads(bt, positions, counts, page_size), jnp.zeros((1,), jnp.int32)
         reads = step_reads(bt, positions, rows, page_size)
         return reads, pages_fetched(*reads, page_size, bt.shape[1])[1:]
+
+
+def paged_gqa_attention(q, pool: tuple, li, bt, reads, *, scale: float, interpret: bool, window: int = 0):
+    """A grouped-query family's attention through ops/gqa_decode.py's
+    kernels, q[n, m, H, d] over layer ``li`` of a two-plane float pool read
+    where it lies, with the vectors ``_paged_step_reads`` made for ``bt``:
+    the step's kernel for one query a slot, the chunk's for more (``window``:
+    ``bt`` is a sliding layer's windowed sub-table; the step's vectors carry
+    it as ``first``). Returns [n, m, H * d]."""
+    if q.shape[1] == 1:
+        return gqa_decode_attention(q[:, 0], pool[0], pool[1], li, bt, *reads, scale=scale, interpret=interpret)[:, None]
+    return gqa_chunk_attention(q, pool[0], pool[1], li, bt, *reads, scale=scale, window=window, interpret=interpret)
 
 
 def _layer_step_paged(p, x, pool, li, bt, positions, h, counts=None, attn_kernel=""):

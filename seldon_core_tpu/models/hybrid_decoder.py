@@ -51,9 +51,11 @@ rides the step as junk and must keep its state. A chunk's batch row names
 the row it reads, the row it writes and the snapshot row it also writes
 (``state_rows`` [3, n]; an index past the last row drops the write).
 
-On one TPU the STEP's attention layers read the pool's pages where they lie
-(ops/gqa_decode.py ``gqa_decode_attention``, where ``decode_programs.
-_step_attn_kernel`` chooses it; chunks and the CPU keep the gather). Not
+On one TPU the attention layers read the pool's pages where they lie: the
+STEP through ops/gqa_decode.py ``gqa_decode_attention``, a prefill CHUNK
+through ``gqa_chunk_attention`` (where ``decode_programs._step_attn_kernel``
+chooses a kernel and ``gqa_chunk_tiles`` holds for the chunk: ``chunk_attn``;
+the CPU keeps the gather, the oracle of both). Not
 served: speculation, a decode mesh, the int8 pool, the host tier, prefix
 export (each refuses by name, ``decoder.require_served``).
 """
@@ -81,10 +83,11 @@ from seldon_core_tpu.models.decoder import (
     _paged_write,
     counted_state_programs,
     kv_pool_zeros,
+    paged_gqa_attention,
     paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, _attend, _rms
-from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention
+from seldon_core_tpu.ops.gqa_decode import gqa_chunk_tiles
 from seldon_core_tpu.ops.moe import (
     HELD_COUNTERS,
     N_HELD_COUNTERS,
@@ -520,7 +523,7 @@ def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, cou
     scale = cfg.attention_multiplier
     if reads is not None:
         with jax.named_scope(SCOPE_ATTN):
-            ctx = gqa_decode_attention(q[:, 0], pool[0], pool[1], ki, bt, *reads, scale=scale, interpret=interpret)[:, None]
+            ctx = paged_gqa_attention(q, pool, ki, bt, reads, scale=scale, interpret=interpret)
     else:
         ck, cv = _paged_gather(pool, ki, bt, cfg.kv_heads)  # [n, g, K, d] float32
         with jax.named_scope(SCOPE_ATTN):
@@ -545,13 +548,15 @@ def _forward(
     generate. ``pick`` [n]: the head runs on that one query of each row.
     ``state_rows`` [3, n] int32: ``_mamba``. ``attn_kernel`` (static; "" |
     "mosaic" | "interpret": ``decode_programs._step_attn_kernel``'s answer)
-    lets a dispatch of ONE query a slot read the pool through
-    ops/gqa_decode.py's kernel; every other shape gathers. Returns (logits
+    lets a dispatch of ONE query a slot, and a prefill chunk (``counts``;
+    ``gqa_chunk_tiles``), read the pool through ops/gqa_decode.py's kernels;
+    every other shape gathers. Returns (logits
     [n, m or 1, vocab] float32, pool, rec, counters[2, or 8 with expert
     layers] int32: ``HybridDecoder.frame_counters``)."""
     n, m = tokens.shape
     res = cfg.residual_multiplier
-    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows)
+    chunk = gqa_chunk_tiles(attn_kernel, m, cfg.heads, cfg.kv_heads, cfg.head_dim)
+    reads, run_pages = _paged_step_reads(attn_kernel, m, pool, bt, positions, rows, counts if chunk else None)
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens] * jnp.asarray(cfg.embedding_multiplier, params["tok_emb"].dtype)
     counted = []  # a configuration with expert layers: their six counts, before the two every configuration has
@@ -660,14 +665,21 @@ class HybridDecoder:
             self.cfg, params, pool, rec, bt, tokens, positions, counts, rows, pick, state_rows, attn_kernel
         )
 
+    def chunk_attn(self, attn_kernel: str, c: int) -> str:
+        """How the chunk program of ``c`` tokens a row reads the pool under
+        ``attn_kernel``: "kernel" (ops/gqa_decode.py ``gqa_chunk_attention``)
+        or "gather". Static (``gqa_chunk_tiles``: what ``_forward`` asks)."""
+        takes = gqa_chunk_tiles(attn_kernel, c, self.cfg.heads, self.cfg.kv_heads, self.cfg.head_dim)
+        return "kernel" if takes else "gather"
+
     @functools.lru_cache(maxsize=None)
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.
         counted_state_programs``: both carry the state cache beside the
         pool, the step takes ``rows``, the chunk ``state_rows``); with
-        ``attn_kernel`` the one whose dispatch is one query a slot, the step,
-        reads the pool through the kernel. Cached: equal configurations
-        share compiled programs."""
+        ``attn_kernel`` the step reads the pool through ops/gqa_decode.py's step
+        kernel and a chunk through its chunk kernel (``chunk_attn``). Cached:
+        equal configurations share compiled programs."""
         return counted_state_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
 
     def generate(self, params, ids, max_new_tokens: int):

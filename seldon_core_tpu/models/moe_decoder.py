@@ -32,10 +32,11 @@ nothing: ``ops/moe.py`` ``moe_held_ffn``).
 
 It serves through the SAME paged machinery as the GPT-2 family: token-row
 planes ``[L, pages, page_size, kv_heads * head_dim]`` written by
-``decoder._paged_write``, read by ``decoder._paged_gather`` (the STEP, on a
-TPU, reads them where they lie instead: ops/gqa_decode.py
-``gqa_decode_attention``, where ``decode_programs._step_attn_kernel`` chooses
-it; chunks, a verify and the CPU keep the gather), copied by
+``decoder._paged_write``, read by ``decoder._paged_gather`` (on a TPU the
+STEP and the prefill CHUNKS read them where they lie instead: ops/gqa_decode.py
+``gqa_decode_attention`` and ``gqa_chunk_attention``, where
+``decode_programs._step_attn_kernel`` chooses a kernel and, for a chunk,
+``_chunk_takes`` holds; a verify and the CPU keep the gather), copied by
 ``decoder.paged_copy``. A configuration with sliding layers has TWO PAGE
 KINDS (``decoder.kv_pool_zeros``, serving/kv_pool.py): the full layers'
 planes hold every position, the sliding layers' planes have pages of their
@@ -43,9 +44,10 @@ own under a block table of their own, and a page wholly older than the
 window is given back while the sequence runs. A sliding layer gathers
 through a WINDOWED block table: the pages that cover its queries' windows,
 taken from the slot's window-kind table by position (a page given back
-reads as junk page 0); the mask is by absolute key position. The step's
-kernel walks the same sub-table, with the in-table position of each slot's
-oldest visible key beside it (``gqa_decode.step_reads``' ``first``).
+reads as junk page 0); the mask is by absolute key position. The kernels
+walk the same sub-table: the step's with the in-table position of each
+slot's oldest visible key beside it (``gqa_decode.step_reads``' ``first``),
+the chunk's with the window itself, a lower bound a query.
 
 A family is what ``serving/decode_scheduler.py`` takes from the model's
 spec (``ModelSpec.generative["family"]``) and asks (the list is
@@ -84,9 +86,10 @@ from seldon_core_tpu.models.decoder import (
     _paged_write,
     counted_programs,
     kv_pool_zeros,
+    paged_gqa_attention,
     paged_greedy_generate,
 )
-from seldon_core_tpu.ops.gqa_decode import gqa_decode_attention, pages_fetched, step_reads
+from seldon_core_tpu.ops.gqa_decode import chunk_reads, gqa_chunk_tiles, pages_fetched, step_reads
 from seldon_core_tpu.ops.moe import (
     HELD_COUNTERS,
     N_HELD_COUNTERS,
@@ -413,30 +416,49 @@ def _ffn(cfg: MoEDecoderConfig, p, h, valid):
     return y, cnt
 
 
-def _step_reads(cfg: MoEDecoderConfig, attn_kernel: str, queries: int, pool: tuple, bt, positions, rows):
-    """What this family's program hands ops/gqa_decode.py's kernel, ONCE a
+def _chunk_takes(cfg: MoEDecoderConfig, attn_kernel: str, queries: int) -> bool:
+    """Whether a chunk of ``queries`` a row reads the pool through
+    ops/gqa_decode.py's chunk kernel under ``_step_attn_kernel``'s answer:
+    ``gqa_chunk_tiles`` for the head count of every layer kind. Static: what
+    the program (``_step_reads``) and the scheduler's count of its dispatches
+    (``MoEDecoder.chunk_attn``) both ask."""
+    return all(
+        gqa_chunk_tiles(attn_kernel, queries, heads, cfg.kv_heads, cfg.head_dim)
+        for heads in sorted({cfg.heads_of(i) for i in range(cfg.layers)})
+    )
+
+
+def _step_reads(cfg: MoEDecoderConfig, attn_kernel: str, queries: int, pool: tuple, bt, positions, rows, counts=None):
+    """What this family's program hands ops/gqa_decode.py's kernels, ONCE a
     layer kind for all the kind's layers (they walk the same table): ({full:
-    (table, lengths, runs[, first])}, the pages of one layer a kind that
-    come in run DMAs as int32[1]) where the program set chose a kernel
-    (``attn_kernel``) AND the dispatch has one query a slot against float
-    planes (two a kind), else (None, zero): the gather. A full layer's table
-    is its kind's whole; a sliding layer's the windowed sub-table, with the
-    first key a slot. What is left of the gather there, a few integers a
-    slot, stays under the kind's ``kv_gather`` scope."""
-    if not attn_kernel or queries != 1 or len(pool) != (4 if cfg.two_kinds else 2):
+    (table, *vectors)}, the pages of one layer a kind that a STEP's kernel
+    fetches in run DMAs as int32[1]) where the program set chose a kernel
+    (``attn_kernel``), the planes are float (two a kind) AND the dispatch is
+    one the kernels take: one query a slot (the step: ``step_reads``'
+    lengths, runs[, first]) or a prefill chunk (``counts`` and
+    ``_chunk_takes``: ``chunk_reads``' five); else (None, zero): the gather
+    (a verify or tree program, the int8 pool). A full layer's table is its
+    kind's whole; a sliding layer's the windowed sub-table, with the first
+    key a slot. What is left of the gather there, a few integers a slot,
+    stays under the kind's ``kv_gather`` scope."""
+    step = queries == 1
+    takes = step or (counts is not None and _chunk_takes(cfg, attn_kernel, queries))
+    if not attn_kernel or not takes or len(pool) != (4 if cfg.two_kinds else 2):
         return None, jnp.zeros((1,), jnp.int32)
     reads, in_runs = {}, jnp.zeros((), jnp.int32)
     for full in sorted({cfg.is_full(i) for i in range(cfg.layers)}):
         kind, table = _kind_pool(cfg, full, pool, bt)
         ps = kind[0].shape[2]
         with jax.named_scope(SCOPE_FULL if full else SCOPE_WIN), jax.named_scope(SCOPE_KV_GATHER):
-            if full:
-                r = step_reads(table, positions, rows, ps)
-            else:
-                table, k0 = _window_table(table, positions, 1, ps, cfg.window)
+            k0 = None
+            if not full:
+                table, k0 = _window_table(table, positions, queries, ps, cfg.window)
+            if step:
                 r = step_reads(table, positions, rows, ps, k0, cfg.window)
+                in_runs = in_runs + pages_fetched(r[0], r[1], ps, table.shape[1])[1]
+            else:
+                r = chunk_reads(table, positions, counts, ps, k0)
             reads[full] = (table, *r)
-            in_runs = in_runs + pages_fetched(r[0], r[1], ps, table.shape[1])[1]
     return reads, in_runs[None]
 
 
@@ -492,9 +514,10 @@ def _layer(cfg: MoEDecoderConfig, li: int, p, x, pool, bt, positions, counts, va
     with jax.named_scope(SCOPE_FULL if full else SCOPE_WIN):
         if reads is not None:
             with jax.named_scope(SCOPE_ATTN):
-                ctx = gqa_decode_attention(
-                    q[:, 0], kind[0], kind[1], pl, *reads[full], scale=cfg.head_dim**-0.5, interpret=interpret
-                )[:, None]
+                ctx = paged_gqa_attention(
+                    q, kind, pl, reads[full][0], reads[full][1:],
+                    scale=cfg.head_dim**-0.5, interpret=interpret, window=0 if full else cfg.window,
+                )
         else:
             ctx = _gathered_attention(cfg, full, q, kind, pl, bt_k, positions, q_pos)
     with jax.named_scope(SCOPE_ATTN_OUT):
@@ -519,8 +542,9 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
     the last real one; 256 positions of a 98k vocabulary are 1.6 GB of
     logits). ``attn_kernel`` (static; "" | "mosaic" | "interpret":
     ``decode_programs._step_attn_kernel``'s answer) lets a dispatch of ONE
-    query a slot read both page kinds through ops/gqa_decode.py's kernel;
-    every other shape gathers. Returns (logits[n, m or 1, vocab] float32,
+    query a slot, and a prefill chunk (``counts``; ``_chunk_takes``), read both
+    page kinds through ops/gqa_decode.py's kernels; every other shape gathers.
+    Returns (logits[n, m or 1, vocab] float32,
     hidden[n, m, d], pool, counters int32: ``MoEDecoder.frame_counters``)."""
     n, m = tokens.shape
     valid = jnp.ones((n, m), bool)
@@ -530,7 +554,7 @@ def _forward(cfg, params, pool, bt, tokens, positions, counts=None, rows=None, p
         valid &= rows[:, None]
     with jax.named_scope(SCOPE_EMBED):
         x = jnp.asarray(params["tok_emb"])[tokens]  # [n, m, d]
-    reads, run_pages = _step_reads(cfg, attn_kernel, m, pool, bt, positions, rows)
+    reads, run_pages = _step_reads(cfg, attn_kernel, m, pool, bt, positions, rows, counts)
     cnt = jnp.zeros((cfg.n_counters,), jnp.int32)
     for li, lp in enumerate(params["layers"]):
         x, pool, c = _layer(cfg, li, lp, x, pool, bt, positions, counts, valid, reads, attn_kernel == "interpret")
@@ -625,10 +649,16 @@ class MoEDecoder:
     def fused_programs(self, attn_kernel: str = ""):
         """This family's step and chunk bodies (``decoder.counted_programs``:
         the step takes ``rows``, the counts ride the token readback); with
-        ``attn_kernel`` the one whose dispatch is one query a slot, the step,
-        reads the pool through the kernel. Cached: equal configurations
-        share compiled programs."""
+        ``attn_kernel`` the step reads the pool through ops/gqa_decode.py's step
+        kernel and a chunk through its chunk kernel (``chunk_attn``). Cached:
+        equal configurations share compiled programs."""
         return counted_programs(functools.partial(self.paged_forward, attn_kernel=attn_kernel))
+
+    def chunk_attn(self, attn_kernel: str, c: int) -> str:
+        """How the chunk program of ``c`` tokens a row reads the pool under
+        ``attn_kernel``: "kernel" (``gqa_chunk_attention``, both page kinds)
+        or "gather". Static (``_chunk_takes``)."""
+        return "kernel" if _chunk_takes(self.cfg, attn_kernel, c) else "gather"
 
     def paged_decode_step(self, params, pool, bt, tokens, positions):
         logits, hidden, pool, _ = _forward(self.cfg, params, pool, bt, tokens[:, None], positions)

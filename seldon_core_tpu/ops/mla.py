@@ -362,20 +362,21 @@ def _wait(c):
     c.wait()
 
 
-def _softmax_block(q, rows, seen, top_ref, sum_ref, acc_ref, rank: int, scale: float):
+def _softmax_block(q, rows, seen, top_ref, sum_ref, acc_ref, rank: int, scale: float, values=None):
     """One block of the online softmax in a kernel: the queries q[r, w]
     scored against the block's rows[keys, w] on the MXU, keys outside
     ``seen()`` (broadcast to [r, keys]) at probability exactly 0, the
     probabilities, cast to the rows' dtype as ``_walk`` casts them, multiplied
-    into the same rows' first ``rank`` lanes; maximum, sum and context
-    (float32 scratch) updated in place."""
+    into the same rows' first ``rank`` lanes (the latent kernels) or into
+    ``values`` [keys, v] (ops/gqa_decode.py's chunk kernel); maximum, sum and
+    context (float32 scratch) updated in place."""
     s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     s = jnp.where(seen(), s * scale, NEG_INF)  # [r, keys]
     top = top_ref[...]
     new_top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
     shrink = jnp.exp(top - new_top)
     p = jnp.exp(s - new_top)
-    ctx = jnp.dot(p.astype(rows.dtype), rows[:, :rank], preferred_element_type=jnp.float32)
+    ctx = jnp.dot(p.astype(rows.dtype), rows[:, :rank] if values is None else values, preferred_element_type=jnp.float32)
     top_ref[...] = new_top
     sum_ref[...] = sum_ref[...] * shrink + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * shrink + ctx

@@ -105,14 +105,17 @@ def _step_attn_kernel(family, pool_state: tuple, mesh, heads: int, kv_heads: int
     family's blocked walk — everywhere else: the int8 pool (six planes a
     kind), a tensor-parallel mesh, a family without a kernel, a geometry the kernel
     cannot tile, the CPU backend (where the gather and the walk are the
-    oracles). The dispatch's shape is the program's own to see: in the
-    two-plane families only one query a slot takes the kernel (models/decoder.py
-    ``_layer_step_paged`` and ``_paged_step_reads``, models/moe_decoder.py
-    ``_step_reads``), so their chunk, verify and tree programs gather
-    whatever this says; the latent family's chunks
-    take its many-queries kernel under the same answer (ops/mla.py
-    ``kernel_takes``, ``mla_chunk_attention``; ``DecodePrograms.chunk_attn``
-    says which a chunk length took)."""
+    oracles). The dispatch's shape is the program's own to see: one query a
+    slot takes the step's kernel (models/decoder.py ``_layer_step_paged`` and
+    ``_paged_step_reads``, models/moe_decoder.py ``_step_reads``); a prefill
+    chunk takes a many-queries kernel under the same answer where the family
+    has one and its static test holds for the chunk's length: the latent
+    family's ops/mla.py ``mla_chunk_attention`` (``kernel_takes``), the
+    grouped-query families' ops/gqa_decode.py ``gqa_chunk_attention``
+    (``gqa_chunk_tiles``; the sparse-expert family's over both page kinds);
+    ``DecodePrograms.chunk_attn`` says which a chunk length took. The GPT-2
+    family's chunks, and every family's verify and tree programs (several
+    queries a slot without ``counts``), gather whatever this says."""
     half = len(pool_state) // 2
     kinds = [(pool_state[:half], heads), (pool_state[half:], heads_window)] if heads_window else [(pool_state, heads)]
     if "attn_kernel" not in family.serves or mesh is not None or any(len(planes) > 2 for planes, _ in kinds):
@@ -506,8 +509,9 @@ class DecodePrograms:
         """How the chunk program of ``c`` tokens a row reads the pool:
         "kernel" where the family has a chunk kernel and the set's choice
         (``attn_kernel``) and the static shape send this program there, else
-        "walk" (the latent family's blocked walk, the other families' page
-        gather, the feature twin)."""
+        the family's name for its fallback (the latent family's "walk", the
+        grouped-query families' "gather"); "walk" for a family without the
+        hook (the GPT-2 family's page gather) and for the feature twin."""
         return "walk" if self._chunk_attn is None else self._chunk_attn(self.attn_kernel, c)
 
     def draft(self, toks, pos, temps, topks, tick) -> tuple:

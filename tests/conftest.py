@@ -67,3 +67,17 @@ def pytest_pyfunc_call(pyfuncitem):
         asyncio.run(fn(**kwargs))
         return True
     return None
+
+
+@pytest.fixture()
+def small_chunk_kernel_blocks(monkeypatch):
+    """ops/gqa_decode.py's kernels at the tests' sizes: runs of 2 table
+    entries, key blocks of 4, query blocks of 16 score rows a lane tile (4
+    queries at the tiny families' 4 heads in one 32-lane tile), so that a
+    chunk of 8 or 16 is several work items a row over several key blocks."""
+    from seldon_core_tpu.ops import gqa_decode, mla
+
+    monkeypatch.setattr(mla, "RUN_PAGES", 2)
+    monkeypatch.setattr(mla, "BLOCK_PAGES", 4)
+    monkeypatch.setattr(mla, "CHUNK_Q_ROWS", 16)
+    monkeypatch.setattr(gqa_decode, "CHUNK_BLOCK_PAGES", 4)

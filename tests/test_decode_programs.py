@@ -633,17 +633,37 @@ def _old_family_program(family: str, kind: str):
     return chunk, (sds(p), sds(pool), *rec, bt, i32(n, 8), i32(n), i32(n), *tail, *state_rows)
 
 
+@functools.lru_cache(maxsize=None)
+def _lowered_in_a_fresh_process() -> dict:
+    """{program: sha256 of its lowered text}, every program of ``LOWERED``
+    lowered in ONE child process that has traced nothing else, as the hashes
+    were made: which inner jitted helpers (`_where`, `clip`, ...) two call
+    sites share in the text follows what the process has traced before, and
+    ``jax.clear_caches()`` does not undo all of it: under six workers
+    `moe.step` (PR 43) and `moe.chunk` (PR 52) each read another text once in
+    a worker that had run other files first, and the right one alone. The
+    child is pinned to the CPU backend: it never loads the TPU's library."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import hashlib, json, jax\n"
+        "from tests import test_decode_programs as t\n"
+        "print(json.dumps({p: hashlib.sha256(jax.jit(f).lower(*a).as_text().encode()).hexdigest()\n"
+        "    for p in sorted(t.LOWERED) for f, a in [t._old_family_program(*p.split('.'))]}))\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": os.pathsep.join([root, os.environ.get("PYTHONPATH", "")])}
+    done = subprocess.run([sys.executable, "-c", code], cwd=root, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
 @pytest.mark.parametrize("program", sorted(LOWERED))
 def test_the_older_families_programs_lower_to_the_text_they_had(program):
-    import hashlib
-
-    fn, args = _old_family_program(*program.split("."))
-    # from empty trace caches, as the hashes were made: which inner jitted helpers (`_where`, `clip`, ...) two
-    # call sites share in the text follows what the process has traced before, and once in three whole runs
-    # under six workers `moe.step` alone read another text in a worker that had run other files first (PR 43)
-    jax.clear_caches()
-    text = jax.jit(fn).lower(*args).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[program]
+    assert _lowered_in_a_fresh_process()[program] == LOWERED[program]
 
 
 def test_the_fourth_family_rides_the_counting_convention():
@@ -668,3 +688,54 @@ def test_the_fourth_family_rides_the_counting_convention():
     assert counted[6] == N  # each attended over one latent row (position 0)
     assert counted[7:].tolist() == [0, 0]  # the CPU backend's step walks: no page fetched by the kernel
     assert sched.recompiles_since_warmup() == 0
+
+
+# ---- the grouped-query families' chunks take ops/gqa_decode.py's chunk kernel where the step takes its own (ISSUE 52) ----
+
+
+@pytest.mark.parametrize("family", ["moe", "hybrid", "hybrid_experts", "conv"])
+async def test_a_grouped_query_familys_chunk_rounds_count_the_rows_the_kernel_took(
+    family, monkeypatch, small_chunk_kernel_blocks
+):
+    """With the ONE place of choice answering "interpret", ``chunk_attn``
+    names the kernel for every entry of the ladder, every chunk round's frame
+    counts its prefilling rows into ``chunk_rows_kernel``, and the scheduler
+    serves the tokens it serves through the gather (whose ``chunk_attn`` says
+    "gather" and whose frames count none) with no recompile. The
+    sparse-expert family's chunks read BOTH page kinds in place."""
+    from seldon_core_tpu.serving import decode_programs as dp
+    from tests import test_moe_decoder
+
+    def build():
+        if family != "moe":
+            return _stateful(family)
+        ms = test_moe_decoder._zoo()
+        sched = DecodeScheduler(
+            ms.params, seq_len=test_moe_decoder.SEQ, max_new_tokens=test_moe_decoder.MAX_NEW, n_slots=4,
+            prefix_slots=0, prefill_chunk=16, kv_page_size=4, family=ms.generative["family"],
+        )
+        return ms, sched
+
+    rng = np.random.default_rng(3)
+    served = {}
+    for kernel in ("", "interpret"):
+        if kernel:
+            monkeypatch.setattr(dp, "_step_attn_kernel", lambda *a: kernel)
+        ms, sched = build()
+        assert sched.programs.attn_kernel == kernel
+        sched.warmup()
+        if not served:
+            prompts = rng.integers(0, 96, (3, sched.seq_len)).astype(np.int32)
+        first = await sched.submit(prompts[0])
+        served[kernel] = [first, *await asyncio.gather(*(sched.submit(p) for p in prompts[1:]))]
+        assert sched.recompiles_since_warmup() == 0
+        frames = sched.flight.snapshot()
+        chunked = [f for f in frames if f.chunk_rows]
+        names = {sched.programs.chunk_attn(c) for _rows, c in sched.chunk_buckets}
+        assert chunked and names == ({"kernel"} if kernel else {"gather"})
+        for f in chunked:
+            assert f.chunk_rows_live > 0 and f.chunk_rows_kernel == (f.chunk_rows_live if kernel else 0)
+        assert not any(f.chunk_rows_kernel for f in frames if not f.chunk_rows)
+        await sched.close()
+    for got, want in zip(served["interpret"], served[""]):
+        np.testing.assert_array_equal(got, want)

@@ -1,10 +1,11 @@
-"""The grouped-query decode kernel (ops/gqa_decode.py) in the Pallas
-interpreter on the CPU, against ``_paged_gather`` + ``_attend`` (the gather
-path of models/conv_decoder.py and models/hybrid_decoder.py): float32 pool,
-float32 mathematics on both sides, read from the pool's pages in place and
-only as far as each slot's length; then a whole step of each of the two
-families with the kernel against the same step through the gather, and a
-scheduler whose frames count what the kernel fetched.
+"""The grouped-query kernels (ops/gqa_decode.py: the step's, one query a
+slot; the chunk's, many) in the Pallas interpreter on the CPU, against
+``_paged_gather`` + ``_attend`` (the gather path of models/conv_decoder.py,
+models/hybrid_decoder.py and models/moe_decoder.py): float32 pool, float32
+mathematics on both sides, read from the pool's pages in place and only as
+far as each slot's length; then a whole step of each of the two families
+with the kernel against the same step through the gather, and a scheduler
+whose frames count what the kernel fetched.
 
 Tolerance: both sides are float32 on the same products (a float32 pool's
 probabilities go into the context product whole, ``gqa_decode_attention``).
@@ -383,8 +384,7 @@ def _family(name):
 def test_a_family_step_with_the_kernel_equals_the_gather_step(name):
     """Three slots: one mid-generation over a prefilled context, one
     prefilling (outside ``rows``), one free. A chunk prefills through the
-    gather (no program of several queries a slot takes the kernel), then
-    three steps run twice from the same pool and state rows: the logits of
+    gather, then three steps run twice from the same pool and state rows: the logits of
     the generating slot agree to float32 rounding, the pool and the state
     rows come back the same where anyone reads them, and the step counts the
     run pages."""
@@ -404,7 +404,7 @@ def test_a_family_step_with_the_kernel_equals_the_gather_step(name):
         counts=jnp.asarray([ctx, 9, 0], jnp.int32), pick=jnp.asarray([ctx - 1, 8, 0], jnp.int32), state_rows=rows3,
     )
     assert gqa.step_reads(jnp.asarray(bt), jnp.zeros((n,), jnp.int32), None, PS)[1].shape == (n, 6)
-    chunked = fam.paged_forward(  # a chunk asked for the kernel gathers all the same
+    chunked = fam.paged_forward(  # a chunk through the chunk's kernel counts no run page: the count is the step's
         params, pool, rec, jnp.asarray(bt), jnp.asarray(ids[:, :2]), jnp.zeros((n,), jnp.int32),
         counts=jnp.zeros((n,), jnp.int32), pick=jnp.zeros((n,), jnp.int32), state_rows=rows3 * 0 + n + 2,
         attn_kernel="interpret",
@@ -517,3 +517,167 @@ async def test_scheduler_with_the_kernel_step_serves_the_gather_steps_tokens_and
     assert all((f.attn_pages_table > 0) == (f.busy_ns[1] > 0) for f in frames)
     assert not any(f.attn_run_pages for f in frames if not f.attn_pages_table)
     await kernel.close()
+
+
+# ------------------------------------------------ the chunk's kernel: many queries a row
+
+M = 8  # a chunk's queries a row; query blocks of 4 (``_small_query_blocks``)
+
+
+def _small_query_blocks(monkeypatch, heads, kv_heads, d, tq=4):
+    """Key blocks of 4 table entries and query blocks of ``tq`` queries: a
+    chunk of ``M`` is two work items a row."""
+    monkeypatch.setattr(gqa, "CHUNK_BLOCK_PAGES", 4)
+    monkeypatch.setattr(mla_ops, "CHUNK_Q_ROWS", tq * (min(128, kv_heads * d) // d) * heads // kv_heads)
+    assert gqa._chunk_query_block(M, heads, kv_heads, kv_heads * d) == tq
+
+
+def _chunk_gather(q, pool, li, bt, positions, scale, window=0):
+    """The families' gather path for a chunk (``moe_decoder.
+    _gathered_attention``): the whole table, or a sliding layer's windowed
+    sub-table, masked by absolute position. The oracle."""
+    m, d = q.shape[1], q.shape[3]
+    bt_l, k0 = _window_table(bt, positions, m, PS, window) if window else (bt, jnp.zeros_like(positions))
+    ck, cv = _paged_gather(pool, li, bt_l, pool[0].shape[-1] // d)
+    q_pos = positions[:, None] + jnp.arange(m)[None, :]
+    k_pos = k0[:, None] + jnp.arange(ck.shape[2])[None, :]
+    visible = k_pos[:, None, :] <= q_pos[:, :, None]
+    if window:
+        visible &= q_pos[:, :, None] - k_pos[:, None, :] < window
+    return np.asarray(_attend(q, ck, cv, visible, scale=scale))
+
+
+def _chunk_kernel(q, pool, li, bt, positions, counts, scale, window=0):
+    m = q.shape[1]
+    bt_l, k0 = _window_table(bt, positions, m, PS, window) if window else (bt, None)
+    reads = gqa.chunk_reads(bt_l, positions, counts, PS, k0)
+    out = gqa.gqa_chunk_attention(q, pool[0], pool[1], li, bt_l, *reads, scale=scale, window=window, interpret=True)
+    return np.asarray(out), reads
+
+
+def _agree_where_read(got, want, counts, tq=4):
+    """Row i's real queries agree; a query block wholly past its count (and a
+    row of count 0) is zeros; a query past the count inside a block with a
+    real one is something finite."""
+    assert np.isfinite(got).all()
+    for i, c in enumerate(np.asarray(counts).tolist()):
+        np.testing.assert_allclose(got[i, :c], want[i, :c], rtol=0, atol=ATOL)
+        assert not got[i, -(-c // tq) * tq :].any()
+
+
+# positions and counts of one dispatch: a context that ends mid-page (3 + 8 = 11 keys), one that ends on a key
+# block's edge (8 + 8 = 16 keys = 4 pages), a padding row, one live query, three (the second query block: zeros)
+CHUNK_POSITIONS, CHUNK_COUNTS = [3, 8, 0, 17, 29], [M, M, 0, 1, 3]
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [4, 6, 9, 16])
+@pytest.mark.parametrize("kind", ["runs", "scattered"])
+def test_chunk_kernel_matches_the_gather_path(kind, group, d, monkeypatch):
+    """Groups of 4, 6, 9 and 16 query heads a K/V head, heads of 64 (two K/V
+    heads a lane tile: a block-diagonal pair) and 128, tables in runs and
+    scattered; one dispatch holds counts of m, 0, 1 and 3, a context that
+    ends mid-page and one on a key block's edge, and a query block past a
+    row's live queries. Both layers."""
+    kv_heads = 2
+    heads = group * kv_heads
+    _small_query_blocks(monkeypatch, heads, kv_heads, d)
+    rng = np.random.default_rng(43)
+    pool = tuple(jnp.asarray(rng.standard_normal((L, 64, PS, kv_heads * d)), jnp.float32) for _ in range(2))
+    ids = 1 + np.arange(5 * PAGES).reshape(5, PAGES)
+    if kind == "scattered":
+        ids = 1 + rng.permutation(63)[: 5 * PAGES].reshape(5, PAGES)
+        ids = np.where(np.diff(ids, axis=1, append=-5) == 1, ids[:, ::-1], ids)
+    bt = jnp.asarray(ids, jnp.int32)
+    positions, counts = jnp.asarray(CHUNK_POSITIONS, jnp.int32), jnp.asarray(CHUNK_COUNTS, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((5, M, heads, d)), jnp.float32)
+    for li in range(L):
+        got, reads = _chunk_kernel(q, pool, li, bt, positions, counts, 0.3)
+        assert got.shape == (5, M, heads * d)
+        _agree_where_read(got, _chunk_gather(q, pool, li, bt, positions, 0.3), counts)
+    lengths, q_first, cnt, next_live, runs = (np.asarray(a) for a in reads)
+    assert lengths.tolist() == [11, 16, 1, 18, 32] and q_first.tolist() == CHUNK_POSITIONS and cnt.tolist() == CHUNK_COUNTS
+    assert next_live.tolist() == [0, 1, 3, 3, 4, 5]  # the padding row is nobody's next
+    assert runs.shape == (5, 6)  # three key blocks of two groups of two entries
+    whole = [[1, 0, 0, 0, 0, 0], [1, 1, 0, 0, 0, 0], [0] * 6, [1, 1, 0, 0, 0, 0], [1, 1, 1, 1, 0, 0]]
+    assert runs.tolist() == (whole if kind == "runs" else [[0] * 6] * 5)
+
+
+def test_chunk_kernel_reads_no_row_past_a_length_and_none_of_a_padding_rows_table(monkeypatch):
+    """NaN in K and V on the junk page, on every row past each row's length
+    (the tail of its last page, fetched with it; every later page, never
+    fetched) and over the whole table of the row of count 0: the clean pool's
+    context."""
+    heads, kv_heads, d = 8, 2, 64
+    _small_query_blocks(monkeypatch, heads, kv_heads, d)
+    rng = np.random.default_rng(47)
+    clean = tuple(jnp.asarray(rng.standard_normal((L, 64, PS, kv_heads * d)), jnp.float32) for _ in range(2))
+    bt_np = 1 + np.arange(5 * PAGES).reshape(5, PAGES)
+    seen = np.zeros((64, PS), bool)
+    for row, pos, c in zip(bt_np, CHUNK_POSITIONS, CHUNK_COUNTS):
+        for at in range(pos + c if c else 0):
+            seen[row[at // PS], at % PS] = True
+    poisoned = tuple(jnp.where(seen[None, :, :, None], a, jnp.nan) for a in clean)
+    assert np.isnan(np.asarray(poisoned[1][0, 0])).all() and np.isnan(np.asarray(poisoned[0][0, bt_np[2]])).all()
+    bt = jnp.asarray(bt_np, jnp.int32)
+    positions, counts = jnp.asarray(CHUNK_POSITIONS, jnp.int32), jnp.asarray(CHUNK_COUNTS, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((5, M, heads, d)), jnp.float32)
+    got, _reads = _chunk_kernel(q, poisoned, 1, bt, positions, counts, 0.3)
+    _agree_where_read(got, _chunk_gather(q, clean, 1, bt, positions, 0.3), counts)
+
+
+@pytest.mark.parametrize("heads", [8, 18])
+@pytest.mark.parametrize("window", [6, 13, 64], ids=["inside_a_page", "two_blocks", "whole_table"])
+def test_windowed_chunk_matches_the_gather_and_never_reads_a_given_back_page(window, heads, monkeypatch):
+    """A sliding layer's chunk over the window kind's table as the allocator
+    leaves it: every entry wholly older than the FIRST query's window is junk
+    page 0, which holds NaN in K and V (so do the rows past each length). The
+    kernel walks the sub-table from the key block that holds the oldest key a
+    query block's first query sees, weighs a key exactly 0 for a query a
+    window or more past it, and zeroes V's rows before that oldest key in
+    VMEM: the clean pool's gather-path context. Windows that start inside
+    the sub-table's first page, that span two key blocks, and that hold the
+    whole table; groups of 4 and 9 heads of 128."""
+    kv_heads, d = 2, 128
+    _small_query_blocks(monkeypatch, heads, kv_heads, d)
+    rng = np.random.default_rng(53)
+    clean = tuple(jnp.asarray(rng.standard_normal((L, 64, PS, kv_heads * d)), jnp.float32) for _ in range(2))
+    bt_np = 1 + np.arange(5 * PAGES).reshape(5, PAGES)
+    bt_np[1] = bt_np[1, ::-1]  # one row's pages descend: a DMA a page
+    positions = np.minimum([window + 1, 3, 0, 22, 30], PAGES * PS - M)
+    counts = np.array(CHUNK_COUNTS)
+    seen = np.zeros((64, PS), bool)
+    for row, pos, c in zip(bt_np, positions, counts):
+        for at in range(max(pos - window + 1, 0), pos + c if c else 0):
+            seen[row[at // PS], at % PS] = True
+        row[: max(pos - window + 1, 0) // PS] = 0  # given back
+    assert (bt_np == 0).any() or window >= PAGES * PS
+    poisoned = tuple(jnp.where(seen[None, :, :, None], a, jnp.nan) for a in clean)
+    assert np.isnan(np.asarray(poisoned[1][0, 0])).all()  # the junk page
+    bt, pos, cnt = jnp.asarray(bt_np, jnp.int32), jnp.asarray(positions, jnp.int32), jnp.asarray(counts, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((5, M, heads, d)), jnp.float32)
+    got, (lengths, q_first, *_rest) = _chunk_kernel(q, poisoned, 1, bt, pos, cnt, d**-0.5, window)
+    _agree_where_read(got, _chunk_gather(q, clean, 1, bt, pos, d**-0.5, window), counts)
+    pw = -(-(window + M) // PS) + 1
+    if pw < PAGES:
+        p0 = np.clip((positions - (window - 1)) // PS, 0, PAGES - pw)
+        assert np.asarray(q_first).tolist() == (positions - p0 * PS).tolist()
+        assert np.asarray(lengths).tolist() == np.where(counts > 0, positions + counts - p0 * PS, 1).tolist()
+        assert window != 6 or (np.asarray(q_first)[0] - (window - 1)) % PS  # the first key inside a page
+
+
+def test_gqa_chunk_tiles_names_what_the_chunk_kernel_takes():
+    """The five cells' geometries and every entry of their chunk ladders take
+    the kernel under "mosaic"; no kernel, one query, a head that does not
+    divide a lane tile and query heads outside whole groups do not; the
+    interpreter takes any whole groups."""
+    cells = {"H.full": (48, 8, 128), "H.win": (72, 8, 128), "C": (32, 4, 128), "I": (32, 2, 128), "D, F": (32, 8, 64)}
+    for heads, kv_heads, d in cells.values():
+        assert all(gqa.gqa_chunk_tiles("mosaic", c, heads, kv_heads, d) for c in (16, 64, 256))
+        assert gqa._chunk_query_block(256, heads, kv_heads, kv_heads * d) in (64, 128)
+    assert not gqa.gqa_chunk_tiles("", 256, 48, 8, 128)
+    assert not gqa.gqa_chunk_tiles("mosaic", 1, 48, 8, 128)
+    assert not gqa.gqa_chunk_tiles("mosaic", 256, 44, 8, 128)
+    assert not gqa.gqa_chunk_tiles("mosaic", 256, 32, 8, 96)
+    assert not gqa.gqa_chunk_tiles("mosaic", 2, 9, 1, 128)  # 18 score rows: not whole sublane tiles
+    assert gqa.gqa_chunk_tiles("interpret", 2, 9, 1, 128) and gqa.gqa_chunk_tiles("interpret", 8, 8, 2, 8)

@@ -6,6 +6,7 @@ Seeded random weights; every case counts on its own.
 """
 
 import asyncio
+import dataclasses
 import json
 import os
 import sys
@@ -79,7 +80,7 @@ def _ids(seed=0, n=CTX):
     return np.random.default_rng(seed).integers(0, CFG.vocab, n).astype(np.int32)
 
 
-def _serve(params, ids, *, chunks, dtype=jnp.float32, start=None, snap_at=None, others=False, fam=FAM):
+def _serve(params, ids, *, chunks, dtype=jnp.float32, start=None, snap_at=None, others=False, fam=FAM, attn_kernel=""):
     """Teacher-forced through the paged programs: chunked prefill of
     ``sum(chunks)`` tokens, then single-token steps along ``ids``; returns
     (logits [len(ids), vocab], pool, rec, pages). The sequence sits in slot 1
@@ -88,7 +89,8 @@ def _serve(params, ids, *, chunks, dtype=jnp.float32, start=None, snap_at=None, 
     rest is computed. ``snap_at``: the chunk that ends there also writes the
     snapshot row. ``others``: slots 0 and 2 generate junk tokens in every
     step instead of riding masked. ``fam``: the family object (granite's
-    shape, or the single-sublayer one further down)."""
+    shape, or the single-sublayer one further down). ``attn_kernel``: what
+    every dispatch is handed as ``decode_programs._step_attn_kernel``'s answer."""
     n_slots, pages = 3, CTX // PS
     if start is None:
         pool = fam.paged_kv_init(params, 1 + 2 * pages, PS, dtype)
@@ -111,18 +113,47 @@ def _serve(params, ids, *, chunks, dtype=jnp.float32, start=None, snap_at=None, 
         )
         logits, pool, rec, _ = fam.paged_forward(
             params, pool, rec, jnp.asarray(bt), jnp.asarray(toks), jnp.array([0, pos, 0], jnp.int32),
-            counts=jnp.array([0, c, 0], jnp.int32), state_rows=jnp.asarray(rows3),
+            counts=jnp.array([0, c, 0], jnp.int32), state_rows=jnp.asarray(rows3), attn_kernel=attn_kernel,
         )
         out[pos : pos + c] = np.asarray(logits[1, :c])
         pos, read = pos + c, 1
     while pos < len(ids):
         logits, pool, rec, _ = fam.paged_forward(
             params, pool, rec, jnp.asarray(bt), jnp.array([[7], [ids[pos]], [9]], jnp.int32),
-            jnp.array([0, pos, 0], jnp.int32), rows=jnp.array([others, True, others]),
+            jnp.array([0, pos, 0], jnp.int32), rows=jnp.array([others, True, others]), attn_kernel=attn_kernel,
         )
         out[pos] = np.asarray(logits[1, 0])
         pos += 1
     return out, pool, rec, mine
+
+
+@pytest.mark.parametrize("chunks", [(8, 8, 8, 8), (9, 2, 1, 6), (5, 5, 5, 5), (27,)], ids=["pages", "ragged", "by5", "one"])
+def test_a_chunk_program_with_the_kernel_equals_the_gather_chunk(weights, small_chunk_kernel_blocks, chunks):
+    """The prefill chunks through ops/gqa_decode.py's chunk kernel and the
+    steps after them through its step kernel (the Pallas interpreter) give
+    the gather path's logits to float32 rounding at every position and leave
+    the same pool and state rows, whatever the chunks' lengths and wherever
+    they start."""
+    ids, params = _ids(), weights[jnp.float32]
+    want, pool_g, rec_g, mine = _serve(params, ids, chunks=chunks)
+    got, pool_k, rec_k, _ = _serve(params, ids, chunks=chunks, attn_kernel="interpret")
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * max(np.abs(want).max(), 1.0))
+    for a, b in zip(pool_g, pool_k):
+        np.testing.assert_allclose(np.asarray(a[:, mine]), np.asarray(b[:, mine]), rtol=0, atol=2e-5)
+    for a, b in zip(rec_g, rec_k):
+        np.testing.assert_allclose(np.asarray(a[1]), np.asarray(b[1]), rtol=0, atol=2e-5)
+
+
+def test_chunk_attn_names_the_kernel_where_the_head_group_takes_it():
+    """``chunk_attn`` is the program's own static test by name: "kernel"
+    under a chosen kernel where the query block tiles (the published head
+    counts at every entry of the ladder), else "gather"."""
+    assert [FAM.chunk_attn(k, 8) for k in ("", "interpret", "mosaic")] == ["gather", "kernel", "kernel"]
+    assert FAM.chunk_attn("interpret", 1) == "gather"  # one query a slot is the step's kernel
+    for heads, kv_heads, d in ((32, 8, 64), (32, 2, 128)):  # granite-4.0-h-micro, nemotron-3-nano-30b-a3b
+        full = hd.hybrid_family(dataclasses.replace(CFG, heads=heads, kv_heads=kv_heads, head_dim=d))
+        assert [full.chunk_attn("mosaic", c) for c in (16, 64, 256)] == ["kernel"] * 3 and full.chunk_attn("", 256) == "gather"
+    assert FAM.chunk_attn("mosaic", 2) == "gather"  # 8 score rows: not whole sublane tiles of a two-byte float
 
 
 # (a) chunked prefill then decode through both caches == the reference's full forward

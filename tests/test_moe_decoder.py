@@ -7,6 +7,7 @@ to five windows. Seeded random weights; every case counts on its own.
 """
 
 import asyncio
+import dataclasses
 import math
 import os
 import sys
@@ -70,13 +71,14 @@ def _ref_logits(ref, params, ids, precision):
     )[0]
 
 
-def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32):
+def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32, attn_kernel=""):
     """Teacher-forced through the paged programs: chunked prefill of
     ``sum(chunks)`` tokens, then single-token steps along ``ids``; returns
     logits [len(ids), vocab]. The sequence sits in slot 1 of 3 (slots 0 and
     2 ride as junk). ``prefix_from`` = (pool, pages, n): the first n tokens'
     pages of an earlier run are MAPPED (a prefix hit), only the rest is
-    computed."""
+    computed. ``attn_kernel``: what the chunks are handed as
+    ``decode_programs._step_attn_kernel``'s answer (the steps gather)."""
     n_slots, pages = 3, CTX // PS
     if prefix_from is None:
         pool = FAM.paged_kv_init(params, 1 + 2 * pages, PS, dtype)
@@ -93,8 +95,9 @@ def _serve(params, ids, *, chunks, prefix_from=None, dtype=jnp.float32):
         toks = np.zeros((n_slots, max(chunks)), np.int32)
         toks[1, :c] = ids[pos : pos + c]
         counts = np.array([0, c, 0], np.int32)
-        logits, _h, pool = FAM.paged_chunk_prefill(
-            params, pool, jnp.asarray(bt), jnp.asarray(toks), jnp.array([0, pos, 0], jnp.int32), jnp.asarray(counts)
+        logits, _h, pool, _ = FAM.paged_forward(
+            params, pool, jnp.asarray(bt), jnp.asarray(toks), jnp.array([0, pos, 0], jnp.int32), jnp.asarray(counts),
+            attn_kernel=attn_kernel,
         )
         out[pos : pos + c] = np.asarray(logits[1, :c])
         pos += c
@@ -161,6 +164,34 @@ def test_bfloat16_serving_within_the_harness_delta(ref, weights, path):
 
 
 # (b) a sliding layer forgets what left its window; a full layer does not
+
+
+@pytest.mark.parametrize("chunks", [(8, 8, 8, 8), (9, 2, 1, 6), (5, 5, 5, 5), (27,)], ids=["pages", "ragged", "by5", "one"])
+def test_a_chunk_program_with_the_kernel_equals_the_gather_chunk(weights, small_chunk_kernel_blocks, chunks):
+    """The prefill chunks through ops/gqa_decode.py's chunk kernel (the
+    Pallas interpreter; both page kinds: the full layers' whole table, the
+    sliding layers' windowed sub-table) give the gather chunks' logits to
+    float32 rounding at every position and leave the same pool, whatever the
+    chunks' lengths and wherever they start; the steps after them agree too."""
+    ids, params = _ids(), weights[jnp.float32]
+    want, pool_g, mine = _serve(params, ids, chunks=chunks)
+    got, pool_k, _ = _serve(params, ids, chunks=chunks, attn_kernel="interpret")
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * np.abs(want).max())
+    for a, b in zip(pool_g, pool_k):
+        np.testing.assert_allclose(np.asarray(a[:, mine]), np.asarray(b[:, mine]), rtol=0, atol=2e-5)
+
+
+def test_chunk_attn_names_the_kernel_where_every_layer_kind_takes_it():
+    """``chunk_attn`` is the program's own static test by name: "kernel"
+    under a chosen kernel where BOTH head counts tile (the published 48 and 72
+    over 8 K/V heads of 128 at every entry of the ladder), else "gather"."""
+    assert [FAM.chunk_attn(k, 8) for k in ("", "interpret", "mosaic")] == ["gather", "kernel", "kernel"]
+    assert FAM.chunk_attn("interpret", 1) == "gather"  # one query a slot is the step's kernel
+    wide = dict(heads=48, kv_heads=8, head_dim=128, hidden=3072)
+    full = md.moe_family(dataclasses.replace(CFG, heads_window=72, **wide))
+    assert [full.chunk_attn("mosaic", c) for c in (16, 64, 256)] == ["kernel"] * 3 and full.chunk_attn("", 256) == "gather"
+    odd = md.moe_family(dataclasses.replace(CFG, heads_window=9, heads=48, kv_heads=1, head_dim=128))
+    assert odd.chunk_attn("mosaic", 2) == "gather" and odd.chunk_attn("mosaic", 16) == "kernel"  # 18 score rows
 
 
 @pytest.mark.parametrize("layer,moves", [(0, False), (2, False), (3, True), (7, True)])

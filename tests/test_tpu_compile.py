@@ -9,6 +9,7 @@ fails here at no chip time. A compile that passes is not a chip run.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
@@ -1175,7 +1176,49 @@ def test_step_programs_sampler_draws_and_selects_only_behind_its_gates(topo, fam
     assert nbytes(args) <= mem.argument_size_in_bytes < nbytes(args) * 1.001 + 2**16
 
 
-@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_4_256"])
+# the grouped-query chunk kernel (ops/gqa_decode.py gqa_chunk_attention) at the five cells' geometries:
+# (rows, chunk, query heads, K/V heads, head_dim, table entries, window)
+GQA_CHUNK_CELLS = {
+    "laguna_full_4_256": (4, 256, 48, 8, 128, 464, 0),
+    "laguna_window_4_256": (4, 256, 72, 8, 128, 49, 512),
+    "laguna_window_2_16": (2, 16, 72, 8, 128, 34, 512),
+    "mellum2_2_64": (2, 64, 32, 4, 128, 208, 0),
+    "nemotron_4_256": (4, 256, 32, 2, 128, 144, 0),
+    "lfm2_4_256": (4, 256, 32, 8, 64, 144, 0),
+    "lfm2_2_16": (2, 16, 32, 8, 64, 144, 0),
+    "granite_2_64": (2, 64, 32, 8, 64, 48, 0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GQA_CHUNK_CELLS))
+def test_the_gqa_chunk_kernel_compiles_at_the_five_cells_geometries(topo, cell):
+    """Mosaic takes the kernel alone for the described chip at each cell's
+    head group, head width, table and chunk entry (heads of 64 two a lane
+    tile; the sliding layers' windowed sub-table with the window as a static
+    variant), as ``gqa_chunk_tiles`` says it will: one Mosaic call, and
+    temporaries no larger than the re-laid queries and their output."""
+    from seldon_core_tpu.ops import gqa_decode as gqa
+
+    n, m, heads, kv_heads, d, pages, window = GQA_CHUNK_CELLS[cell]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    assert gqa.gqa_chunk_tiles("mosaic", m, heads, kv_heads, d)
+    groups = np.prod(gqa._table_blocks(pages, gqa.CHUNK_BLOCK_PAGES)[1:])
+    w, i32 = kv_heads * d, jnp.int32
+    plane = arr((2, 4096, 16, w), jnp.bfloat16)
+    args = (arr((n, m, heads, d), jnp.bfloat16), plane, plane, arr((), i32), arr((n, pages), i32), arr((n,), i32),
+            arr((n,), i32), arr((n,), i32), arr((n + 1,), i32), arr((n, int(groups)), i32))
+    fn = functools.partial(gqa.gqa_chunk_attention, scale=d**-0.5, window=window)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "gqa_chunk_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * n * m * heads * 128 * 2
+
+
+@pytest.mark.parametrize("program", ["step", "step_kernel", "chunk_4_256", "chunk_4_256_kernel"])
 def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(topo, monkeypatch, program):
     """The sparse-expert family, as PR 47 left it and (``step_kernel``) with
     the step PR 48 gave it on a TPU, at the laguna-s-2.1 cell's
@@ -1190,7 +1233,11 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     lowerings: 48 heads over the 464-entry table, 72 padded to 80 over the
     34-entry sub-table with a first key a slot; blocks of 64 pages of rows of
     1024 are 8 MiB of VMEM scratch, which Mosaic takes), no gathered context
-    of either kind, and temporaries of megabytes where the gather's are 2 GB."""
+    of either kind, and temporaries of megabytes where the gather's are 2 GB.
+    With it (``chunk_4_256_kernel``, PR 52) the chunk has one too: 48 heads
+    over the 464-entry table and 72 over the 49-entry sub-table with the
+    window as a static variant, the chunk's vectors made once a kind under
+    its ``kv_gather`` scope, and no gathered context or score of either kind."""
     from seldon_core_tpu.models import moe_decoder as md
     from seldon_core_tpu.ops import moe
     from seldon_core_tpu.serving.kv_pool import window_pool_pages
@@ -1216,8 +1263,8 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, (14000, n_win), 16, jnp.bfloat16)))
     assert [a.shape[:2] for a in pool] == [(1, 14000)] * 2 + [(2, n_win)] * 2
     i32, f32 = jnp.int32, jnp.float32
-    step, chunk = fam.fused_programs("mosaic" if program == "step_kernel" else "")
-    n = 4 if program == "chunk_4_256" else 64
+    step, chunk = fam.fused_programs("mosaic" if program.endswith("_kernel") else "")
+    n = 4 if program.startswith("chunk_4_256") else 64
     bt = (arr((n, 464), i32), arr((n, 464), i32))
     tail = (arr((n,), f32), arr((n,), i32), arr((), i32), arr((), i32))
     if program.startswith("step"):
@@ -1234,7 +1281,7 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
     # a sliding layer's gathered cache is its window's pages (34 or 50 of them), never the table's 464
     assert not re.findall(r"f32\[%d,8,7424,128\][^\n]*/win/" % n, text)
     assert mem.temp_size_in_bytes < 3 << 30
-    if program == "chunk_4_256":
+    if program.startswith("chunk_4_256"):
         # the two expert layers' grouped products (PR 49): a pair a layer over the 2,816 rows of ``held_capacity``,
         # none over all 10,240 assignments
         assert _grouped_product_rows(text) == [2816] * 4
@@ -1244,3 +1291,11 @@ def test_the_two_page_kinds_programs_compile_at_the_long_context_cells_widths(to
         assert re.search(r'op_name="jit\(_fused_step\)/full/kv_gather/', text)  # the lengths and run flags
         assert not re.search(r"\[64,(464|34),16,1024\]|\[64,8,(7424|544),128\]", text)  # no gathered context, either kind
         assert mem.temp_size_in_bytes < 64 << 20
+    if program == "chunk_4_256_kernel":
+        assert fam.chunk_attn("mosaic", 256) == "kernel"
+        calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="jit\(_fused_chunk\)/(full|win)/attn/', text)
+        assert sorted(calls) == ["full", "win", "win"]  # a call a layer, under its kind's scope
+        assert re.search(r'op_name="jit\(_fused_chunk\)/(full|win)/kv_gather/', text)  # the chunk's vectors, once a kind
+        # no gathered context of either kind (464 or 49 pages a row), and no float32 scores
+        assert not re.search(r"\[4,(464|49),16,1024\]|\[4,8,(7424|784),128\]|f32\[\d+,\d+,\d+,(7424|784)\]", text)
+        assert mem.temp_size_in_bytes < 1 << 30
