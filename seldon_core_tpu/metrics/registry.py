@@ -561,14 +561,18 @@ class Metrics(NullMetrics):
         )
         self._decode_round_gap = Histogram(
             "seldon_tpu_decode_round_host_gap_seconds",
-            "Host bubble per decode scheduler round (wall minus device busy)",
+            "Host bubble per decode scheduler round: round wall minus the dispatch "
+            "wall (host call to readback return). The dispatch wall holds the "
+            "launch and return legs and is not device-busy time",
             ["deployment_name"],
             registry=registry,
             buckets=_LATENCY_BUCKETS,
         )
         self._decode_bubble = Gauge(
             "seldon_tpu_decode_bubble_fraction",
-            "Cumulative host-bubble fraction of decode round wall time",
+            "Cumulative host gap between dispatches over decode round wall time. The "
+            "dispatch wall holds the launch and return legs, in which the device "
+            "also idles: not the device's idle share",
             ["deployment_name"],
             registry=registry,
         )

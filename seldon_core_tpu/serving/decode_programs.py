@@ -21,7 +21,7 @@ family's state rows ride beside the pool (``pool.recurrent``: donated and
 put back with ``pool.state``), chain or tree or feature tree, is decided in
 here, once. A call ENQUEUES: it hands the
 donated ``pool.state`` in, puts the program's back, and returns ``(out,
-read)`` — the device handle(s) a timing run may block on and the blocking
+read)`` — the device handle(s) the dispatch waits on before it marks, and the
 host read. Timing and naming a dispatch stay the scheduler's (``_Dispatch``).
 """
 

@@ -104,9 +104,8 @@ scheduling over a vLLM-style PAGED KV pool into the stack:
   pool cannot yet guarantee simply defers to the serial walk after the
   reconcile. Stages are gated per-phase on their own measured cost
   (``_PipelineGate`` — cheap phases are not worth moving across the
-  round boundary). ``ENGINE_DECODE_PIPELINE=off`` or
-  ``ENGINE_FLIGHT_SYNC_TIMING=on`` force the serial loop (ground-truth
-  timing), and greedy output is bit-identical either way.
+  round boundary). ``ENGINE_DECODE_PIPELINE=off`` forces the serial loop,
+  and greedy output is bit-identical either way.
 - Flight recorder (telemetry/flight.py): every scheduler round commits ONE
   compact frame — mode, slot/queue occupancy, admissions/retirements and
   the blocked cause, tokens/accepted/effective depth, device-busy split per
@@ -158,6 +157,7 @@ from seldon_core_tpu import telemetry
 from seldon_core_tpu.telemetry import flight as flight_mod
 from seldon_core_tpu.telemetry import profile as profile_mod
 from seldon_core_tpu.telemetry.flight import (
+    ANN_COPYOUT,
     ANN_DISPATCH,
     ANN_ENQUEUE,
     ANN_IDLE_WAIT,
@@ -181,7 +181,6 @@ from seldon_core_tpu.telemetry.flight import (
     FlightRecorder,
     PhaseTimer,
     decode_pipeline_enabled,
-    sync_timing_enabled,
 )
 from seldon_core_tpu.telemetry.flight import register as flight_register
 from seldon_core_tpu.models.decoder import (
@@ -426,18 +425,27 @@ class _Dispatch:
     after the enqueue->readback mark its ``rdb_ns``. Inside it, a program
     set call (``DecodePrograms``: enqueues, returns ``(out, read)``) goes
     one of two ways: ``with d.enqueue():`` round the call on the loop, the
-    overlap window, then ``await d.readback(read)`` (the pipelined round);
-    or ``await d.run(fn)``, which makes call, mark and read in one piece
-    off the loop (the serial round, the chunk round, the copy ladder).
+    overlap window, then ``await d.readback(out, read)`` (the pipelined
+    round); or ``await d.run(fn)``, which makes call, mark and read in one
+    piece off the loop (the serial round, the chunk round, the copy ladder).
+
+    Either way the blocking read is ``_collect``: the one moment of a
+    dispatch at which the host KNOWS where the device is (``out`` just
+    became ready) is marked there, and the part of ``rdb_ns`` after that
+    mark is the family's ``rdy_ns``: the copy to the host, ``read``'s own
+    work, the return through ``_device_call`` to the loop. That is the
+    host's return leg of the dispatch as a duration on the host's clock;
+    wall less it less the device's time is the launch leg.
 
     Its ``ANN_DISPATCH`` annotation and the ``ANN_ENQUEUE`` /
-    ``ANN_READBACK`` under it say WHICH dispatch they are (``stats``):
-    ``seq``, the scheduler's dispatch serial (monotonic over all families),
-    ``round``, the index the round's frame will commit under, and what the
-    call site noted through ``DecodeScheduler._dispatch(family, **stats)``
-    (a chunk's ``rows`` / ``c`` / ``live`` and the form its program's pool
-    write took, ``write`` "page" | "row"; a step's ``rows`` / ``live``).
-    With no profiler session ``flight.annotate`` drops them."""
+    ``ANN_READBACK`` / ``ANN_COPYOUT`` under it say WHICH dispatch they are
+    (``stats``): ``seq``, the scheduler's dispatch serial (monotonic over
+    all families), ``round``, the index the round's frame will commit
+    under, and what the call site noted through
+    ``DecodeScheduler._dispatch(family, **stats)`` (a chunk's ``rows`` /
+    ``c`` / ``live`` and the form its program's pool write took, ``write``
+    "page" | "row"; a step's ``rows`` / ``live``). With no profiler session
+    ``flight.annotate`` drops them."""
 
     __slots__ = ("s", "family", "t0", "carved", "ann", "noted", "stats")
 
@@ -450,7 +458,7 @@ class _Dispatch:
 
     def __enter__(self):
         s = self.s
-        s._rb_mark_ns = 0
+        s._rb_mark_ns = s._rb_ready_ns = 0
         self.carved = 0
         s._dispatch_seq += 1
         self.stats = {"seq": s._dispatch_seq, "round": s.flight.rounds, **self.noted}
@@ -462,24 +470,39 @@ class _Dispatch:
     def __exit__(self, *exc):
         t2 = time.perf_counter_ns()
         s = self.s
-        mark = s._rb_mark_ns or t2
         s._rb_busy[self.family] += t2 - self.t0 - self.carved
-        s._rb_rdb[self.family] += t2 - mark
+        s._rb_rdb[self.family] += t2 - (s._rb_mark_ns or t2)
+        s._rb_rdy[self.family] += t2 - (s._rb_ready_ns or t2)  # no read, no mark: 0
         self.ann.__exit__(None, None, None)
         return False
 
     def enqueue(self, family: int | None = None) -> _EnqueueSpan:
         return _EnqueueSpan(self, self.family if family is None else family)
 
+    def _collect(self, out, read):
+        """The blocking read, on the calling thread, in two steps: wait
+        until the dispatch's ``out`` is computed, MARK, then ``read()`` it
+        under ``ANN_COPYOUT``. The copy to the host is started before the
+        wait, so it follows the program on the device with no host round
+        trip between them; ``read`` then collects what is already on its
+        way."""
+        for a in jax.tree_util.tree_leaves(out):
+            a.copy_to_host_async()
+        jax.block_until_ready(out)
+        self.s._rb_ready_ns = time.perf_counter_ns()
+        ann = flight_mod.annotate(ANN_COPYOUT[self.family], **self.stats)
+        try:
+            return read()
+        finally:
+            ann.__exit__(None, None, None)
+
     async def run(self, fn):
         """``fn`` enqueues and returns a program set call's ``(out,
         read)``: run it through ``_device_call`` under ``ANN_ENQUEUE``,
         mark the enqueue->readback boundary — the family's wall splits
         there and the thread's annotation turns to ``ANN_READBACK`` — and
-        make the blocking read. Under ENGINE_FLIGHT_SYNC_TIMING the
-        dispatch is blocked on before the mark, so the enqueue column is
-        ground-truth device wall. ``fn`` returning None reads nothing back:
-        the whole call counts as enqueue (the copy ladder)."""
+        make the blocking read (``_collect``). ``fn`` returning None reads
+        nothing back: the whole call counts as enqueue (the copy ladder)."""
         s = self.s
         stats = self.stats
 
@@ -489,13 +512,10 @@ class _Dispatch:
                 queued = fn()
                 if queued is None:
                     return None
-                out, read = queued
-                if s._sync_timing:
-                    jax.block_until_ready(out)
                 s._rb_mark_ns = time.perf_counter_ns()
                 ann.__exit__(None, None, None)
                 ann = flight_mod.annotate(ANN_READBACK[self.family], **stats)
-                return read()
+                return self._collect(*queued)
             finally:
                 ann.__exit__(None, None, None)
 
@@ -509,9 +529,10 @@ class _Dispatch:
                 await asyncio.sleep(stall)
         return out
 
-    async def readback(self, fn):
+    async def readback(self, out, read):
         """The blocking host read of a dispatch the loop enqueued itself
-        (the pipelined rounds): marks, then runs ``fn`` through
+        (the pipelined rounds; ``out``, ``read`` as the program set call
+        returned them): marks, then runs ``_collect`` through
         ``_device_call`` under ``ANN_READBACK``."""
         self.s._rb_mark_ns = time.perf_counter_ns()
         stats = self.stats
@@ -519,7 +540,7 @@ class _Dispatch:
         def call():
             ann = flight_mod.annotate(ANN_READBACK[self.family], **stats)
             try:
-                return fn()
+                return self._collect(out, read)
             finally:
                 ann.__exit__(None, None, None)
 
@@ -1247,16 +1268,11 @@ class DecodeScheduler:
         self.stat_ingress_ns = self.stat_ingress_requests = 0
         self._ingress_committed = (0, 0)
         self._round_ann = None  # the open ANN_ROUND trace annotation
-        # ENGINE_FLIGHT_SYNC_TIMING=on: block on every dispatch so the
-        # per-family flight columns are ground-truth device wall
-        # (calibration runs — throughput pays the pipeline stall)
-        self._sync_timing = sync_timing_enabled()
         # pipelined decode rounds: while round N's step/verify dispatch is
         # in flight, round N+1's host phases run against the SHADOW state
         # below (pending admissions + a snapshot-keyed chunk-input plan),
         # reconciled at readback through _apply_pending and committed at
-        # the single _commit_round funnel. ENGINE_DECODE_PIPELINE=off (or
-        # sync timing, whose ground truth needs the serial loop) forces
+        # the single _commit_round funnel. ENGINE_DECODE_PIPELINE=off forces
         # the serial path; bench's A/B leg flips the attribute per run.
         self.pipeline_enabled = decode_pipeline_enabled()
         self._gate = _PipelineGate()
@@ -2195,7 +2211,8 @@ class DecodeScheduler:
         ``open_round=False`` only where no loop runs: construction)."""
         self._rb_busy = [0, 0, 0, 0, 0]  # ns per flight.FAMILIES entry
         self._rb_rdb = [0, 0, 0, 0, 0]  # blocked-readback share of busy
-        self._rb_mark_ns = 0
+        self._rb_rdy = [0, 0, 0, 0, 0]  # of rdb, after the result was ready
+        self._rb_mark_ns = self._rb_ready_ns = 0
         self._rb_t0 = t_ns if t_ns is not None else time.perf_counter_ns()
         self._rb_admitted = 0
         self._rb_retired = 0
@@ -2358,6 +2375,7 @@ class DecodeScheduler:
                         chunk_rows_kernel=self._rb_chunk_rows_kernel,
                         **self._window_frame(snap),
                         step_counts=self._rb_step_counts,
+                        rdy_ns=tuple(self._rb_rdy),
                         ingress_ns=ingress[0] - self._ingress_committed[0],
                         ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
@@ -2615,9 +2633,8 @@ class DecodeScheduler:
         """Whether this round may run the double-buffered path: the
         ENGINE_DECODE_PIPELINE kill switch (captured at build into
         ``pipeline_enabled`` — bench's A/B leg flips the attribute per
-        run) AND not ENGINE_FLIGHT_SYNC_TIMING, whose ground-truth
-        per-dispatch timing needs the serial loop."""
-        return self.pipeline_enabled and not self._sync_timing
+        run)."""
+        return self.pipeline_enabled
 
     def _overlap_window(self) -> None:
         """Round N+1's host phases, run while round N's dispatch is in
@@ -3054,19 +3071,14 @@ class DecodeScheduler:
         host phases run under it (``_overlap_window``: inside the verify
         family's busy wall, recorded apart as the frame's overlap_ns), and
         only then does the host block on the verify readback. Serial, the
-        pair and its read run in one piece (``d.run``);
-        ENGINE_FLIGHT_SYNC_TIMING blocks after each program so both
-        columns become ground-truth per-dispatch device wall."""
+        pair and its read run in one piece (``d.run``)."""
         programs = self.programs
         t0 = telemetry.now_ns()
         with self._dispatch(F_VERIFY) as d:
 
             def draft():
                 with d.enqueue(F_DRAFT):
-                    proposal = programs.draft(toks, pos, temps, topks, tick)
-                    if self._sync_timing:
-                        jax.block_until_ready(proposal)
-                return proposal
+                    return programs.draft(toks, pos, temps, topks, tick)
 
             def verify(proposal):
                 return programs.verify(
@@ -3077,9 +3089,9 @@ class DecodeScheduler:
             if self._pipeline_on():
                 proposal = draft()
                 with d.enqueue():
-                    _, read = verify(proposal)
+                    queued = verify(proposal)
                 self._overlap_window()
-                out_t, acc = await d.readback(read)
+                out_t, acc = await d.readback(*queued)
             else:
                 out_t, acc = await d.run(lambda: verify(draft()))
         t1 = telemetry.now_ns()
@@ -3171,8 +3183,8 @@ class DecodeScheduler:
         spans the whole enqueue->readback window (the overlap work sits
         INSIDE the device-busy wall — recorded apart as the frame's
         overlap_ns) and rdb is the true post-overlap block. Serial
-        (ENGINE_DECODE_PIPELINE=off, sync timing), call and read run in one
-        piece (``d.run``)."""
+        (ENGINE_DECODE_PIPELINE=off), call and read run in one piece
+        (``d.run``)."""
 
         def step():
             return self.programs.step(bt, toks, pos, temps, topks, tick, rows)
@@ -3181,9 +3193,9 @@ class DecodeScheduler:
             self._rb_active = self.active  # dispatch-time occupancy
             if self._pipeline_on():
                 with d.enqueue():
-                    _, read = step()
+                    queued = step()
                 self._overlap_window()
-                nxt, counted = await d.readback(read)
+                nxt, counted = await d.readback(*queued)
             else:
                 nxt, counted = await d.run(step)
         if counted is not None:
@@ -3192,6 +3204,10 @@ class DecodeScheduler:
         return nxt
 
     async def _run(self) -> None:
+        # while the loop runs, a collection of the interpreter's oldest
+        # generation names itself in a trace and counts on the recorder
+        gc2 = flight_mod.Gc2Watch(self.flight)
+        gc2.install()
         try:
             # register this loop's thread with the process-global sampling
             # profiler (telemetry/profile.py — GET /decode/profile); a
@@ -3454,6 +3470,7 @@ class DecodeScheduler:
             self._waiting.clear()
             self._reset_device_state()
         finally:
+            gc2.remove()
             self._round_mark(False)
 
     def _reset_device_state(self) -> None:

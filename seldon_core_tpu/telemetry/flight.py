@@ -22,9 +22,11 @@ to a bounded ring —
   (``chunk``/``step``/``draft``/``verify``/``copy``) and split again into
   **enqueue vs blocked readback** per family (``rdb_ns``), so on
   async-dispatch backends the draft no longer masquerades as free and the
-  verify column no longer absorbs the whole round pair's wait;
-  ``ENGINE_FLIGHT_SYNC_TIMING=on`` forces per-dispatch completion for
-  ground-truth calibration runs;
+  verify column no longer absorbs the whole round pair's wait; the
+  readback splits once more where the blocking read LEARNS that the
+  result is ready (``rdy_ns``: the copy to the host and the return to the
+  loop, after that mark), so the host's launch and return legs of a
+  dispatch are durations on one clock, always and in the pipelined loop;
 - the host gap attributed per **phase** (``PHASES`` / ``P_*``: admission
   incl. prefix match and allocator reservation, chunk-result scatter, the
   emission/SLO walk, the spec accept walk, the sampled-token walk, the
@@ -65,6 +67,7 @@ Layered on top:
 
 from __future__ import annotations
 
+import gc
 import os
 import sys
 import time
@@ -74,7 +77,6 @@ from seldon_core_tpu.utils.env import (
     ENGINE_DECODE_PIPELINE,
     ENGINE_FLIGHT,
     ENGINE_FLIGHT_FRAMES,
-    ENGINE_FLIGHT_SYNC_TIMING,
 )
 
 # fused program families a round's dispatch wall ("busy") is attributed to; the
@@ -124,6 +126,8 @@ ANN_PHASE = tuple(f"{ANN_PREFIX}phase.{p}" for p in PHASES)  # loop: _PhaseCtx
 ANN_DISPATCH = tuple(f"{ANN_PREFIX}dispatch.{f}" for f in FAMILIES)  # loop: hand-off -> readback return
 ANN_ENQUEUE = tuple(f"{ANN_PREFIX}enqueue.{f}" for f in FAMILIES)  # the calling thread: program call -> enqueued
 ANN_READBACK = tuple(f"{ANN_PREFIX}readback.{f}" for f in FAMILIES)  # the calling thread: the blocking host read
+ANN_COPYOUT = tuple(f"{ANN_PREFIX}copyout.{f}" for f in FAMILIES)  # inside ANN_READBACK: result ready -> the read's return
+ANN_GC2 = ANN_PREFIX + "gc2"  # whichever thread: one collection of the interpreter's oldest generation (Gc2Watch)
 
 
 def annotate(name: str, **kw):
@@ -169,24 +173,9 @@ def flight_enabled(env: dict | None = None) -> bool:
     )
 
 
-def sync_timing_enabled(env: dict | None = None) -> bool:
-    """ENGINE_FLIGHT_SYNC_TIMING=on: force per-dispatch completion so each
-    family's flight column is ground-truth device wall (calibration runs;
-    default off — async dispatch stays pipelined)."""
-    env = env if env is not None else os.environ
-    return str(env.get(ENGINE_FLIGHT_SYNC_TIMING, "off")).strip().lower() in (
-        "on",
-        "1",
-        "true",
-    )
-
-
 def decode_pipeline_enabled(env: dict | None = None) -> bool:
     """ENGINE_DECODE_PIPELINE=off: force the scheduler's SERIAL round loop
-    (round N+1's host phases wait for round N's readback). Default on.
-    Independent of — but composed with — sync timing: the scheduler also
-    forces serial under ENGINE_FLIGHT_SYNC_TIMING, since ground-truth
-    per-dispatch timing needs the unpipelined loop."""
+    (round N+1's host phases wait for round N's readback). Default on."""
     env = env if env is not None else os.environ
     return str(env.get(ENGINE_DECODE_PIPELINE, "on")).strip().lower() not in (
         "off",
@@ -348,8 +337,8 @@ class PhaseTimer:
         trace annotations with no profiler session: ``phases_per_round``
         enter/exit pairs incl. one nested pair (each writes its ANN_PHASE
         annotation), one ANN_ROUND with its two stats, and per dispatch
-        the ANN_DISPATCH / ANN_ENQUEUE / ANN_READBACK triple with a
-        dispatch's stats — what
+        the ANN_DISPATCH / ANN_ENQUEUE / ANN_READBACK triple and the
+        ANN_COPYOUT under the last, with a dispatch's stats — what
         PARITY.md documents beside the frame-append cost and the tier-1
         guard budgets. A served round of 16 generating slots enters
         ``emit_slo`` once per token: ``phases_per_round=40`` is its size."""
@@ -364,7 +353,9 @@ class PhaseTimer:
                 stats = {"seq": i + f, "round": i, "rows": 16, "live": 16}
                 d = annotate(ANN_DISPATCH[f], **stats)
                 annotate(ANN_ENQUEUE[f], **stats).__exit__(None, None, None)
-                annotate(ANN_READBACK[f], **stats).__exit__(None, None, None)
+                r = annotate(ANN_READBACK[f], **stats)
+                annotate(ANN_COPYOUT[f], **stats).__exit__(None, None, None)
+                r.__exit__(None, None, None)
                 d.__exit__(None, None, None)
             with t.phase(P_ACCEPT_WALK):
                 with t.phase(P_EMIT_SLO):
@@ -379,6 +370,13 @@ class FlightFrame:
     FAMILIES (dispatch wall, host call to readback return: enqueue +
     blocked readback per family — not device time); ``rdb_ns`` the
     blocked-readback share of each family (enqueue = busy - rdb);
+    ``rdy_ns`` the part of each family's ``rdb_ns`` AFTER the blocking read
+    learned that the result was ready (``_Dispatch``'s one mark: the copy to
+    the host, the split of the counts, the return through the executor to
+    the loop; the host's RETURN leg of the dispatch, 0 for a dispatch that
+    reads nothing back), so ``0 <= rdy_ns <= rdb_ns <= busy_ns`` per family
+    and a dispatch's wall less its ``rdy`` part less the device's time is
+    its LAUNCH leg, all durations, no second clock;
     ``phase_ns`` the host gap attributed per PHASES entry; ``gap_ns`` the
     round's host bubble (wall - dispatch wall); ``overlap_ns`` the host work
     the PIPELINED loop ran inside a dispatch's busy window (hidden under
@@ -502,6 +500,7 @@ class FlightFrame:
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
         "chunk_rows_held", "chunk_rows_kernel", "moe_grouped_calls", "moe_compact_calls",
         "kv_win_live", "kv_win_released", "kv_win_written", "step_counts",
+        "rdy_ns",
     )
 
     def __init__(
@@ -520,6 +519,7 @@ class FlightFrame:
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
         chunk_rows_held=0, chunk_rows_kernel=0, moe_grouped_calls=0, moe_compact_calls=0,
         kv_win_live=0, kv_win_released=0, kv_win_written=0, step_counts=(),
+        rdy_ns=_ZERO_FAMILIES,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -579,6 +579,7 @@ class FlightFrame:
         self.kv_win_released = kv_win_released
         self.kv_win_written = kv_win_written
         self.step_counts = step_counts
+        self.rdy_ns = rdy_ns
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -608,6 +609,12 @@ class FlightFrame:
             d["rdb_us"] = {
                 FAMILIES[i]: round(ns / 1e3, 1)
                 for i, ns in enumerate(self.rdb_ns)
+                if ns
+            }
+            # of rdb_us, the part after the result was ready (the return leg)
+            d["rdy_us"] = {
+                FAMILIES[i]: round(ns / 1e3, 1)
+                for i, ns in enumerate(self.rdy_ns)
                 if ns
             }
         if any(self.phase_ns):
@@ -758,6 +765,11 @@ class FlightRecorder:
         self.deadline_total = 0
         self.dumps = 0
         self._last_dump_ns = 0
+        # collections of the interpreter's oldest generation while the decode
+        # loop ran, and their summed wall (Gc2Watch; no frame slot: a
+        # collection belongs to no round)
+        self.gc2_count = 0
+        self.gc2_ns_total = 0
         # recency marker (round number of the last SLO breach) so health()
         # reflects the CURRENT state instead of latching on lifetime
         # counters after one incident (blocking recency is read off the
@@ -859,6 +871,7 @@ class FlightRecorder:
         rounds = len(frames)
         busy = [0] * len(FAMILIES)
         rdb = [0] * len(FAMILIES)
+        rdy = [0] * len(FAMILIES)
         phase = [0] * N_PHASES
         gap = 0
         overlap = 0
@@ -875,6 +888,8 @@ class FlightRecorder:
                 busy[i] += ns
             for i, ns in enumerate(f.rdb_ns):
                 rdb[i] += ns
+            for i, ns in enumerate(f.rdy_ns):
+                rdy[i] += ns
             for i, ns in enumerate(f.phase_ns):
                 phase[i] += ns
             gap += f.gap_ns
@@ -920,6 +935,11 @@ class FlightRecorder:
             "readback_ms": {
                 FAMILIES[i]: round(ns / 1e6, 3) for i, ns in enumerate(rdb) if ns
             },
+            # of readback_ms, what came after the result was ready: the
+            # host's return leg of a dispatch (copy out, hop back to the loop)
+            "return_ms": {
+                FAMILIES[i]: round(ns / 1e6, 3) for i, ns in enumerate(rdy) if ns
+            },
             # the host gap decomposed per phase — what a pipelined decode
             # loop would overlap with the in-flight dispatch
             "phase_ms": {
@@ -928,6 +948,10 @@ class FlightRecorder:
             "phase_of_gap": round(sum(phase) / gap, 4) if gap else 0.0,
             "gap_ms": round(gap / 1e6, 3),
             "bubble_fraction": round(gap / wall, 4) if wall else 0.0,
+            # the return leg's share of the rounds' wall: device-idle time
+            # INSIDE the dispatch wall that bubble_fraction cannot hold (the
+            # launch leg is there too; only a device trace gives that one)
+            "return_of_wall": round(sum(rdy) / wall, 4) if wall else 0.0,
             # host work hidden under in-flight dispatches (the pipelined
             # loop's win): overlap_of_gap is the share of the would-be
             # serial gap (gap + overlap) that pipelining hid, and
@@ -945,6 +969,9 @@ class FlightRecorder:
             "admitted": admitted,
             "retired": retired,
             "blocked_rounds": blocked,
+            # lifetime, not windowed: a collection belongs to no frame
+            "gc2_count": self.gc2_count,
+            "gc2_ms_total": round(self.gc2_ns_total / 1e6, 3),
         }
         if promotions:
             out["promotions"] = promotions
@@ -1168,6 +1195,44 @@ class FlightRecorder:
                 )
             )
         return round((time.perf_counter_ns() - t0) / n / 1e3, 3)
+
+
+class Gc2Watch:
+    """A ``gc.callbacks`` hook that names the collections of the
+    interpreter's OLDEST generation: the ones that scan every long-lived
+    object of the process on whatever thread allocated last (this repo once
+    measured 74 ms ones, serving/gc_policy.py, which freezes the warmup's
+    survivors out of them; what traffic allocates since is still scanned).
+    Each opens and closes an ``ANN_GC2`` trace annotation on that thread and
+    adds to the recorder's ``gc2_count`` / ``gc2_ns_total``; younger
+    generations return at once. The scheduler installs one where its loop
+    starts and removes it where the loop stops."""
+
+    __slots__ = ("rec", "_ann", "_t0")
+
+    def __init__(self, rec: FlightRecorder):
+        self.rec = rec
+        self._ann = None
+        self._t0 = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._ann = annotate(ANN_GC2)
+            self._t0 = time.perf_counter_ns()
+        elif self._ann is not None:
+            self.rec.gc2_count += 1
+            self.rec.gc2_ns_total += time.perf_counter_ns() - self._t0
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def install(self) -> None:
+        gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
 
 
 # ----------------------------------------------------------------- registry

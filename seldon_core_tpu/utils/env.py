@@ -67,15 +67,9 @@ ENGINE_ACCESS_LOG = "ENGINE_ACCESS_LOG"  # "json" enables; default off
 # round (PARITY.md "Instrumentation overhead"; on the chip: PERF.md, PR 26).
 ENGINE_FLIGHT = "ENGINE_FLIGHT"  # "off" disables the recorder
 ENGINE_FLIGHT_FRAMES = "ENGINE_FLIGHT_FRAMES"  # ring capacity, default 8192
-# "on" forces per-dispatch completion (block_until_ready after every fused
-# program) so each family's flight column is ground-truth device wall —
-# calibration runs only; default off (async dispatch stays pipelined)
-ENGINE_FLIGHT_SYNC_TIMING = "ENGINE_FLIGHT_SYNC_TIMING"
 # decode-round pipelining kill switch (serving/decode_scheduler.py): "off"
 # forces the SERIAL round loop — round N+1's host phases wait for round N's
-# readback instead of running under the in-flight dispatch. Default on;
-# ENGINE_FLIGHT_SYNC_TIMING=on also forces serial (ground-truth timing
-# needs the unpipelined loop).
+# readback instead of running under the in-flight dispatch. Default on.
 ENGINE_DECODE_PIPELINE = "ENGINE_DECODE_PIPELINE"
 # decode-loop sampling profiler (telemetry/profile.py reads these):
 # always-on low-rate folded-stack sampler over the decode loop's thread,

@@ -918,8 +918,7 @@ async def test_pipeline_reconcile_upgrades_to_post_capture_prefix_hit():
 async def test_pipelined_zero_recompiles_and_kill_switch():
     """Zero-recompile guard with the pipeline on (the enqueue/overlap/
     readback split presents exactly the warmed signatures), and the kill
-    switch semantics: sync-timing forces the serial loop even when the
-    pipeline flag is on."""
+    switch semantics: ``pipeline_enabled`` off forces the serial loop."""
     params = _params()
     ids = _prompts(5, seed=37)
     sched = _scheduler(params, n_slots=3)
@@ -935,12 +934,52 @@ async def test_pipelined_zero_recompiles_and_kill_switch():
     await sched.close()
 
     forced = _scheduler(params, n_slots=2)
-    forced._sync_timing = True  # ENGINE_FLIGHT_SYNC_TIMING=on equivalent
+    forced.pipeline_enabled = False  # ENGINE_DECODE_PIPELINE=off equivalent
     assert not forced._pipeline_on()
     out = await forced.submit(ids[0])
     np.testing.assert_array_equal(out, _oracle(params, ids[:1])[0])
     assert forced.stat_pipelined_rounds == 0
     await forced.close()
+
+
+@pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "serial"])
+@pytest.mark.parametrize("kw", [dict(n_slots=3), dict(n_slots=2, prefill_chunk=4), dict(n_slots=2, spec_k=3)],
+                         ids=["plain", "chunk", "spec"])
+async def test_the_ready_mark_changes_no_token(monkeypatch, kw, pipelined):
+    """ISSUE 53: the blocking read waits, marks, then collects. On one fixed
+    schedule (mixed budgets, a sampled row) the tokens are those of a read
+    made in one piece, as the parent made it, and every reading dispatch of
+    the schedule booked its return leg."""
+    from seldon_core_tpu.serving import decode_scheduler as ds
+    from seldon_core_tpu.telemetry.flight import F_COPY, F_DRAFT
+
+    params = _params()
+    ids = _prompts(5, seed=53)
+    kw = dict(kw)
+    if "spec_k" in kw:
+        kw["draft_params"] = _unrelated_draft()
+
+    async def serve():
+        sched = _scheduler(params, **kw)
+        sched.pipeline_enabled = pipelined
+        outs = await asyncio.gather(
+            *(sched.submit(row, max_new_tokens=2 + i, temperature=0.5 * (i % 2), top_k=i) for i, row in enumerate(ids))
+        )
+        await sched.close()
+        return outs, sched.flight.snapshot()
+
+    split, frames = await serve()
+    monkeypatch.setattr(ds._Dispatch, "_collect", lambda self, out, read: read())  # one piece, no mark
+    whole, unmarked = await serve()
+    for a, b in zip(split, whole):
+        np.testing.assert_array_equal(a, b)
+    for f in frames:
+        for rdy, rdb, busy in zip(f.rdy_ns, f.rdb_ns, f.busy_ns):
+            assert 0 <= rdy <= rdb <= busy
+        assert f.rdy_ns[F_DRAFT] == f.rdy_ns[F_COPY] == 0  # a dispatch that reads nothing books nothing
+        assert sum(f.rdy_ns) > 0 or not sum(f.rdb_ns)  # every round that read, marked
+    assert any(sum(f.rdy_ns) for f in frames)
+    assert all(f.rdy_ns == (0, 0, 0, 0, 0) for f in unmarked)  # no mark, no return leg: never negative, never the wall
 
 
 async def test_a_slow_round_names_what_held_it(caplog):

@@ -399,7 +399,8 @@ async def test_decode_flight_and_health_endpoints():
     rec = FlightRecorder(n_slots=4, name="flight-ep", capacity=32, enabled=True)
     flight_mod.register(rec)
     for i in range(5):
-        rec.record(_frame(i, tokens=3, admitted=(1 if i == 0 else 0)))
+        rec.record(_frame(i, tokens=3, admitted=(1 if i == 0 else 0),
+                          rdb_ns=(0, 600, 0, 0, 0), rdy_ns=(0, 300, 0, 0, 0)))
     rec.note_goodput(12, True)
     rec.note_ttft(True)
 
@@ -416,6 +417,9 @@ async def test_decode_flight_and_health_endpoints():
         assert ep["aggregate"]["rounds"] == 5
         assert ep["aggregate"]["tokens"] == 15
         assert ep["frames"][-1]["busy_us"]["step"] == 1.0
+        # the return leg rides the same body: a frame's, the window's, its share of the wall
+        assert ep["frames"][-1]["rdy_us"] == {"step": 0.3} and ep["aggregate"]["return_ms"] == {"step": 0.002}
+        assert ep["aggregate"]["return_of_wall"] == 0.2 and ep["aggregate"]["gc2_count"] == 0
         r = await client.get("/decode/health")
         assert r.status == 200
         health = (await r.json())["flight-ep"]
@@ -622,7 +626,7 @@ def test_frames_carry_phase_and_readback_split():
         # tolerance for timer-boundary jitter)
         assert sum(f.phase_ns) <= f.gap_ns + 50_000, (f.seq, f.phase_ns, f.gap_ns)
         for i, rdb in enumerate(f.rdb_ns):
-            assert 0 <= rdb <= f.busy_ns[i]
+            assert 0 <= f.rdy_ns[i] <= rdb <= f.busy_ns[i]
     step_frames = [f for f in frames if f.mode == "plain"]
     assert any(sum(f.phase_ns) > 0 for f in step_frames)
     # the step family actually reads tokens back -> nonzero readback split
@@ -664,26 +668,104 @@ def test_spec_frames_attribute_accept_walk_and_verify_readback():
     # the draft column is enqueue-only on the async pair (its wait lands
     # in the verify readback) — never negative, never above busy
     for f in chain:
-        assert f.rdb_ns[flight_mod.F_DRAFT] == 0
-        assert f.rdb_ns[flight_mod.F_VERIFY] <= f.busy_ns[flight_mod.F_VERIFY]
+        assert f.rdb_ns[flight_mod.F_DRAFT] == f.rdy_ns[flight_mod.F_DRAFT] == 0
+        assert 0 < f.rdy_ns[flight_mod.F_VERIFY] <= f.rdb_ns[flight_mod.F_VERIFY] <= f.busy_ns[flight_mod.F_VERIFY]
 
 
-def test_sync_timing_env_mode(monkeypatch):
-    """ENGINE_FLIGHT_SYNC_TIMING=on: per-dispatch completion is forced
-    (calibration ground truth) with the program set unchanged — zero
-    recompiles, frames still commit."""
-    assert not flight_mod.sync_timing_enabled(env={})
-    assert flight_mod.sync_timing_enabled(env={
-        flight_mod.ENGINE_FLIGHT_SYNC_TIMING: "on"
-    })
-    monkeypatch.setenv(flight_mod.ENGINE_FLIGHT_SYNC_TIMING, "on")
+@pytest.mark.parametrize("kw, want", [
+    (dict(), None),  # nothing read back: no split at all, as before
+    (dict(rdb_ns=(0, 600, 0, 0, 0)), {}),  # a read with no mark (a recorder fed by an older caller): rdb alone
+    (dict(rdb_ns=(0, 600, 0, 0, 0), rdy_ns=(0, 250, 0, 0, 0)), {"step": 0.2}),
+    (dict(busy_ns=(900, 1000, 0, 0, 0), rdb_ns=(700, 600, 0, 0, 0), rdy_ns=(300, 250, 0, 0, 0)),
+     {"chunk": 0.3, "step": 0.2}),
+])
+def test_frame_return_leg_round_trip(kw, want):
+    """ISSUE 53: ``rdy_ns`` is a frame slot like ``rdb_ns`` (zeros by
+    default) and the dump carries it as ``rdy_us`` beside ``rdb_us``, under
+    the same condition."""
+    f = _frame(0, **kw)
+    assert len(f.rdy_ns) == len(flight_mod.FAMILIES) and "rdy_ns" in FlightFrame.__slots__
+    d = f.to_dict()
+    assert ("rdb_us" in d) == ("rdy_us" in d) == (want is not None)
+    assert d.get("rdy_us") == want
+
+
+def test_aggregate_return_leg_sums_the_frames():
+    """``return_ms`` per family is the frames' ``rdy_ns`` summed, beside
+    ``readback_ms``; ``return_of_wall`` its share of the rounds' wall, the
+    device-idle time inside the dispatch wall that ``bubble_fraction`` (gap
+    over wall) cannot hold."""
+    rec = FlightRecorder(n_slots=4, name="t", capacity=64, enabled=True)
+    rec.record(_frame(0, busy_ns=(4_000_000, 2_000_000, 0, 0, 0), gap_ns=1_000_000,
+                      rdb_ns=(3_000_000, 1_500_000, 0, 0, 0), rdy_ns=(1_000_000, 500_000, 0, 0, 0)))
+    rec.record(_frame(1, busy_ns=(0, 2_000_000, 0, 0, 0), gap_ns=1_000_000,
+                      rdb_ns=(0, 1_000_000, 0, 0, 0), rdy_ns=(0, 500_000, 0, 0, 0)))
+    rec.record(_frame(2, busy_ns=(0, 0, 0, 0, 1_000_000), gap_ns=1_000_000))  # a copy round reads nothing back
+    agg = rec.aggregate()
+    assert agg["readback_ms"] == {"chunk": 3.0, "step": 2.5}
+    assert agg["return_ms"] == {"chunk": 1.0, "step": 1.0}
+    assert agg["return_of_wall"] == pytest.approx(2.0 / 12.0, abs=1e-4)
+    assert agg["bubble_fraction"] == pytest.approx(3.0 / 12.0, abs=1e-4)
+    assert rec.aggregate(1)["return_ms"] == {} and rec.aggregate(1)["return_of_wall"] == 0.0
+    assert FlightRecorder(n_slots=1, name="e", capacity=16, enabled=True).aggregate()["return_of_wall"] == 0.0
+
+
+def test_oldest_generation_collections_are_named_and_counted(monkeypatch):
+    """ISSUE 53: ``Gc2Watch`` opens and closes ``decode.gc2`` round a
+    collection of generation 2 alone and adds to the recorder's two
+    counters; a younger generation's returns at once; removed, it counts no
+    more."""
+    import gc
+
+    names = []
+
+    class Ann:
+        def __exit__(self, *exc):
+            names.append("end")
+
+    monkeypatch.setattr(flight_mod, "annotate", lambda name, **kw: names.append(name) or Ann())
+    rec = FlightRecorder(n_slots=1, name="gc", capacity=16, enabled=True)
+    watch = flight_mod.Gc2Watch(rec)
+    before = len(gc.callbacks)
+    watch.install()
+    try:
+        gc.collect(0)
+        gc.collect(1)
+        assert rec.gc2_count == 0 and names == []
+        gc.collect()
+        gc.collect(2)
+        assert rec.gc2_count == 2 and rec.gc2_ns_total > 0
+        assert names == [flight_mod.ANN_GC2, "end"] * 2 and flight_mod.ANN_GC2 == "decode.gc2"
+    finally:
+        watch.remove()
+    watch.remove()  # twice is once
+    assert len(gc.callbacks) == before
+    gc.collect()
+    agg = rec.aggregate()
+    assert agg["gc2_count"] == 2 and agg["gc2_ms_total"] == round(rec.gc2_ns_total / 1e6, 3)
+
+
+def test_the_scheduler_watches_collections_while_its_loop_runs():
+    """The hook lives as long as the decode loop does: installed where the
+    loop starts, gone where it stops; a full collection between two requests
+    is on the recorder."""
+    import gc
+
     s = DecodeScheduler(_params(), seq_len=SEQ, max_new_tokens=MAX_NEW, n_slots=2)
-    assert s._sync_timing is True
     s.warmup()
-    _run_requests(s, n=3)
-    assert s.recompiles_since_warmup() == 0
-    assert s.flight.rounds > 0
-    assert any(f.busy_ns[flight_mod.F_STEP] > 0 for f in s.flight.snapshot())
+    rows = _prompts(2)
+    watching = []
+
+    async def go():
+        await s.submit(rows[0])
+        watching.extend(cb for cb in gc.callbacks if isinstance(cb, flight_mod.Gc2Watch) and cb.rec is s.flight)
+        gc.collect()
+        await s.submit(rows[1])
+        await s.close()
+
+    asyncio.run(go())
+    assert len(watching) == 1 and watching[0] not in gc.callbacks
+    assert s.flight.gc2_count >= 1 and s.flight.aggregate()["gc2_count"] == s.flight.gc2_count
 
 
 # ------------------------------------------------------ sampling profiler

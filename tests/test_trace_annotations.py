@@ -19,11 +19,15 @@ What this file pins:
 7. (ISSUE 39) a dispatch's annotations say which dispatch they are: a serial
    over all families, the frame's index, a chunk's ``chunk_buckets`` entry
    and a step's rows; the frame carries the entry (``chunk_c``) and the
-   loop's ingress (``ingress_ns`` / ``ingress_requests``).
+   loop's ingress (``ingress_ns`` / ``ingress_requests``);
+8. (ISSUE 53) a dispatch that reads back marks the moment its result was
+   ready: one ``decode.copyout.<family>`` inside its ``decode.readback.*``
+   with the dispatch's stats, and the frame's ``rdy_ns`` after that mark.
 """
 
 import asyncio
 import glob
+import itertools
 import os
 import threading
 
@@ -53,19 +57,21 @@ class _Recorder:
 
     def __init__(self):
         self.events: list[dict] = []
+        self.clock = itertools.count()  # the order of starts and ends, over all threads
 
     def __call__(self, name, **kw):
-        ev = {"name": name, "kw": kw, "thread": threading.get_ident(), "open": True, "exits": 0}
+        ev = {"name": name, "kw": kw, "thread": threading.get_ident(), "open": True, "exits": 0,
+              "start": next(self.clock)}
         self.events.append(ev)
-        return _Handle(ev)
+        return _Handle(ev, self.clock)
 
     def names(self) -> set:
         return {e["name"] for e in self.events}
 
 
 class _Handle:
-    def __init__(self, ev):
-        self.ev = ev
+    def __init__(self, ev, clock):
+        self.ev, self.clock = ev, clock
 
     def __enter__(self):
         return self
@@ -73,6 +79,7 @@ class _Handle:
     def __exit__(self, *exc):
         self.ev["open"] = False
         self.ev["exits"] += 1
+        self.ev["end"] = next(self.clock)
         return False
 
 
@@ -153,7 +160,8 @@ def test_a_round_names_its_host_states(recorded, config):
     registered = (
         {flight_mod.ANN_ROUND, flight_mod.ANN_IDLE_WAIT, flight_mod.ANN_SSE_WRITE, flight_mod.ANN_INGRESS}
         | set(flight_mod.ANN_PHASE) | set(flight_mod.ANN_DISPATCH)
-        | set(flight_mod.ANN_ENQUEUE) | set(flight_mod.ANN_READBACK)
+        | set(flight_mod.ANN_ENQUEUE) | set(flight_mod.ANN_READBACK) | set(flight_mod.ANN_COPYOUT)
+        | {flight_mod.ANN_GC2}
     )
     assert rec.names() <= registered
     assert all(n.startswith(flight_mod.ANN_PREFIX) for n in registered)
@@ -198,9 +206,10 @@ def test_every_registered_phase_and_family_is_emitted(recorded):
     for i, f in enumerate(FAMILIES):
         assert flight_mod.ANN_DISPATCH[i] == f"decode.dispatch.{f}" and flight_mod.ANN_DISPATCH[i] in seen, f
         assert flight_mod.ANN_ENQUEUE[i] == f"decode.enqueue.{f}" and flight_mod.ANN_ENQUEUE[i] in seen, f
-    # draft and copy dispatches read nothing back; the others do
-    for f in ("chunk", "step", "verify"):
-        assert f"decode.readback.{f}" in seen
+    # draft and copy dispatches read nothing back; the others do, and mark when their result was ready
+    for i, f in enumerate(FAMILIES):
+        assert flight_mod.ANN_COPYOUT[i] == f"decode.copyout.{f}"
+        assert (f"decode.readback.{f}" in seen) == (f"decode.copyout.{f}" in seen) == (f in ("chunk", "step", "verify"))
     assert flight_mod.ANN_IDLE_WAIT in seen  # the loop waited for its first request
 
 
@@ -294,7 +303,16 @@ def test_a_cpu_profiler_session_records_the_annotations(tmp_path):
               for line in plane.lines for e in line.events if e.name.startswith("decode.")]
     names = {e.name for e in events}
     assert {"decode.round", "decode.phase.admit", "decode.phase.emit_slo", "decode.dispatch.step",
-            "decode.enqueue.step", "decode.readback.step", "decode.dispatch.chunk"} <= names
+            "decode.enqueue.step", "decode.readback.step", "decode.copyout.step", "decode.dispatch.chunk"} <= names
+    # the mark lies inside the blocking read, on the trace's own clock, and says which dispatch it was
+    reads = {int(dict(e.stats)["seq"]): e for e in events if e.name == "decode.readback.step"}
+    marks = [e for e in events if e.name == "decode.copyout.step"]
+    assert len(marks) == len(reads) > 0
+    for e in marks:
+        stats = dict(e.stats)
+        r = reads[int(stats["seq"])]
+        assert dict(r.stats) == stats and {"seq", "round"} <= set(stats)
+        assert r.start_ns <= e.start_ns and e.start_ns + e.duration_ns <= r.start_ns + r.duration_ns
     committed = {f.seq for f in s.flight.snapshot()}
     seen = set()
     for e in events:
@@ -330,12 +348,13 @@ def test_annotations_cost_a_check_and_a_shared_noop_without_a_session(monkeypatc
     _drive(s, _shared_prompts(3, shared=0, seed=6))
     assert s.flight.rounds > 0 and s._dispatch_seq > 0 and made == []
     for ann in (flight_mod.annotate(flight_mod.ANN_DISPATCH[0], seq=1, round=0, rows=2, c=4, live=1),
+                flight_mod.annotate(flight_mod.ANN_COPYOUT[1], seq=1, round=0), flight_mod.annotate(flight_mod.ANN_GC2),
                 flight_mod.annotate(flight_mod.ANN_ROUND, round=0, t_ns=0), flight_mod.Ingress()._ann):
         assert ann is flight_mod._NOOP_CTX
     # the counter does count: inside a session every emit constructs one
     monkeypatch.setattr(flight_mod, "_session_on", lambda: True)
     PhaseTimer.measure_overhead(1, phases_per_round=8, dispatches_per_round=2)
-    assert len(made) == 1 + 6 + 2 * 3 + 2  # the round, six flat phases, two triples, the nested pair
+    assert len(made) == 1 + 6 + 2 * 4 + 2  # the round, six flat phases, two triples with their copyout, the nested pair
 
 
 def test_each_sse_flush_is_named(monkeypatch):
@@ -408,7 +427,7 @@ def test_a_dispatch_says_which_it_was(recorded, config):
             assert set(kw) == {"seq", "round"}
     for e in rec.events:
         kind = e["name"][len(pre):].split(".")[0]
-        if kind in ("enqueue", "readback"):
+        if kind in ("enqueue", "readback", "copyout"):
             d = by_seq[e["kw"]["seq"]]
             # a draft's enqueue inside a verify dispatch carries the verify's stats
             assert e["kw"] == d["kw"]
@@ -417,6 +436,41 @@ def test_a_dispatch_says_which_it_was(recorded, config):
     assert any(f.chunk_c for f in frames.values())
     held = sum(f.chunk_rows_held for f in frames.values())
     assert held == s.stat_chunk_rows_held and (held > 0) == (config == "chunk-held-pipelined")
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_a_reading_dispatch_marks_when_its_result_was_ready(recorded, config):
+    """ISSUE 53: one ``decode.copyout.<family>`` a dispatch that reads back,
+    begun and ended INSIDE that dispatch's ``decode.readback.<family>`` on the
+    same thread, with the dispatch's stats; none for a dispatch that reads
+    nothing (a draft prefill, the copy ladder). The frame books what came
+    after the mark: ``0 <= rdy_ns <= rdb_ns <= busy_ns`` per family, above 0
+    exactly where the family read back."""
+    rec, s = recorded[config]
+    pre = flight_mod.ANN_PREFIX
+    reads = {e["kw"]["seq"]: e for e in rec.events if e["name"].startswith(pre + "readback.")}
+    marks = [e for e in rec.events if e["name"].startswith(pre + "copyout.")]
+    assert len(marks) == len(reads) > 0 and {e["kw"]["seq"] for e in marks} == set(reads)
+    for e in marks:
+        r = reads[e["kw"]["seq"]]
+        assert e["name"].rsplit(".", 1)[1] == r["name"].rsplit(".", 1)[1] in ("chunk", "step", "verify")
+        assert e["kw"] == r["kw"] and {"seq", "round"} <= set(e["kw"]) and e["thread"] == r["thread"]
+        assert r["start"] < e["start"] < e["end"] < r["end"]
+    read_in_round: dict[int, set] = {}
+    for e in marks:
+        read_in_round.setdefault(e["kw"]["round"], set()).add(FAMILIES.index(e["name"].rsplit(".", 1)[1]))
+    frames = s.flight.snapshot()
+    for f in frames:
+        for i, (rdy, rdb, busy) in enumerate(zip(f.rdy_ns, f.rdb_ns, f.busy_ns)):
+            assert 0 <= rdy <= rdb <= busy, (f.seq, FAMILIES[i])
+            assert (rdy > 0) == (i in read_in_round.get(f.seq, ())), (f.seq, FAMILIES[i])
+        d = f.to_dict()
+        assert set(d.get("rdy_us", {})) == {FAMILIES[i] for i in read_in_round.get(f.seq, ())} <= set(d.get("rdb_us", {}))
+    agg = s.flight.aggregate()
+    assert agg["return_ms"] == {FAMILIES[i]: round(sum(f.rdy_ns[i] for f in frames) / 1e6, 3)
+                                for i in range(len(FAMILIES)) if any(f.rdy_ns[i] for f in frames)}
+    wall = sum(sum(f.busy_ns) + f.gap_ns for f in frames)
+    assert 0.0 < agg["return_of_wall"] == round(sum(sum(f.rdy_ns) for f in frames) / wall, 4) < 1.0
 
 
 def test_a_submit_books_the_loops_ingress_into_its_round(monkeypatch):
