@@ -1,6 +1,6 @@
-"""Hybrid state-space / attention causal decoder — the generative tier's
+"""Hybrid recurrent / attention causal decoder — the generative tier's
 third family (Granite 4.0-H: ``model_type: granitemoehybrid``; Nemotron-H:
-``model_type: nemotron_h``).
+``model_type: nemotron_h``; Qwen3-Next: ``model_type: qwen3_next``).
 
 What the block has, beside the two families before it:
 
@@ -30,6 +30,23 @@ What the block has, beside the two families before it:
   token takes plus one chip's share of the routed ones under the
   bias-selected sigmoid gate (ops/moe.py ``route_sigmoid_biased``,
   ``moe_held_ffn``), each ``down(relu(up x)^2)`` with no gate projection;
+- the THIRD SHAPE (``qwen3_next``; pattern characters ``D`` and ``G``):
+  every layer is a mixer AND an expert layer, ``x <- x + Mix(n1(x))`` then
+  ``x <- x + Moe(n2(x))``. ``D`` is a gated delta-rule (linear-attention)
+  mixer (``_gdn``; ops/gated_delta.py): q, k, v behind ONE depthwise causal
+  convolution without bias, q and k l2-normalised a head, a float32 MATRIX
+  state ``[value heads, d_k, d_v]`` a layer and sequence that is read before
+  it is written, the step in place and a prefill chunk in the blocked form,
+  then a norm over each head's ``d_v`` and only then the ``silu(z)`` gate
+  (the reverse of Mamba-2's order). ``G`` is gated attention (``_attention``):
+  a doubled query projection whose second half is an element-wise sigmoid
+  gate on the context, a zero-centred RMS norm on q and k a head, rotary on
+  the first ``rotary`` of the head. The expert layer (``_experts_softmax``):
+  softmax over all routed experts then the top k renormalised (ops/moe.py
+  ``route_topk``), gated-SiLU experts, one chip's share of them
+  (``moe_held_ffn``), plus a shared expert times ``sigmoid(w . x)``, one
+  scalar a token. Every norm of this shape is ZERO-CENTRED: ``x .
+  rsqrt(mean(x^2) + eps) . (1 + w)`` in float32 (``_norm``);
 - Granite's multipliers: the embedding times ``embedding_multiplier``, every
   residual branch times ``residual_multiplier``, the head's logits over
   ``logits_scaling`` (a configuration without them passes ones); the head is
@@ -37,7 +54,8 @@ What the block has, beside the two families before it:
 
 The recurrent state is the family's second cache, beside the pages
 (``state_init``; serving/kv_pool.py holds it as ``pool.recurrent``): ROWS of
-state ``[rows, heads, head_dim, state]`` and of conv inputs ``[rows,
+state ``[rows, heads, head_dim, state]`` (a delta-rule layer's ``[rows, value
+heads, d_k, d_v]``) and of conv inputs ``[rows,
 (ssm_conv - 1) * conv_width]`` (time-major and flat: a last axis of 3 would
 pad to a whole lane tile on the chip), float32, ONE ARRAY A MAMBA LAYER of
 each (``state_zeros``): as one ``[ssm_layers, rows, ...]`` array the step's 36
@@ -68,6 +86,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from seldon_core_tpu.models.decoder import (
@@ -86,7 +105,8 @@ from seldon_core_tpu.models.decoder import (
     paged_gqa_attention,
     paged_state_greedy_generate,
 )
-from seldon_core_tpu.models.moe_decoder import _SCORES_BATCH_BYTES, _attend, _rms
+from seldon_core_tpu.models.moe_decoder import SCOPE_ROPE, _SCORES_BATCH_BYTES, _attend, _rms, _rope
+from seldon_core_tpu.ops.gated_delta import gdn_chunk, gdn_step
 from seldon_core_tpu.ops.gqa_decode import gqa_chunk_tiles
 from seldon_core_tpu.ops.moe import (
     HELD_COUNTERS,
@@ -97,6 +117,7 @@ from seldon_core_tpu.ops.moe import (
     lane_tiles,
     moe_held_ffn,
     route_sigmoid_biased,
+    route_topk,
 )
 
 # device scopes this family adds, each nested under a decoder.PAGED_SCOPES
@@ -110,6 +131,16 @@ SCOPE_SSM_CONV = "ssm_conv"
 SCOPE_SSM_SCAN = "ssm_scan"
 SCOPE_SSM_NORM = "ssm_norm"
 SCOPE_SSM_OUT = "ssm_out"
+# a delta-rule layer's, nested the same way: ``qkv/gdn_in``, ``attn/gdn_conv``,
+# ``attn/gdn_scan`` (the step's update or the blocked form, with the state rows'
+# read and write), ``attn_out/gdn_norm``, ``attn_out/gdn_out``; the gated
+# attention's ``qkv/rope`` and ``attn_out/attn_gate``
+SCOPE_GDN_IN = "gdn_in"
+SCOPE_GDN_CONV = "gdn_conv"
+SCOPE_GDN_SCAN = "gdn_scan"
+SCOPE_GDN_NORM = "gdn_norm"
+SCOPE_GDN_OUT = "gdn_out"
+SCOPE_ATTN_GATE = "attn_gate"
 
 # a scan chunk's decay matrix [rows, heads, c, c] in float32 above this goes
 # in blocks of rows (lax.map): the (64, 256) chunk program would hold 1.07 GB
@@ -121,6 +152,11 @@ _SCAN_BLOCK_BYTES = 128 << 20
 _SCAN_PRECISION = lax.Precision.HIGHEST
 # a layer's kind, as ``hybrid_override_pattern`` spells it
 KIND_SSM, KIND_ATTN, KIND_EXPERT = "M", "*", "E"
+# the third shape's two (no published pattern spells them: ``qwen3_next`` gives ``full_attention_interval``): a
+# gated delta-rule mixer, gated attention; an expert layer follows either in the SAME layer
+KIND_GDN, KIND_GATED = "D", "G"
+# what the l2 norm of a delta-rule layer's q and k adds under the root (the published kernels' constant)
+_L2_EPS = 1e-6
 # the gate's denominator adds this to the picks' scores (``nemotron_h``)
 _GATE_EPS = 1e-20
 # the selection bias's std (models/conv_decoder.py ``EXPERT_BIAS_STD``'s
@@ -130,8 +166,8 @@ EXPERT_BIAS_STD = 0.05
 
 @dataclasses.dataclass(frozen=True)
 class HybridDecoderConfig:
-    """The published keys of a Granite-4.0-H or a Nemotron-H decoder
-    (zoo://hybrid_decoder)."""
+    """The published keys of a Granite-4.0-H, a Nemotron-H or a Qwen3-Next
+    decoder (zoo://hybrid_decoder)."""
 
     vocab: int = 512
     hidden: int = 64
@@ -154,6 +190,12 @@ class HybridDecoderConfig:
     experts_per_tok: int = 0
     shared_ffn: int = 0  # moe_shared_expert_intermediate_size
     routed_scale: float = 1.0
+    gdn_key_heads: int = 0  # linear_num_key_heads: a delta-rule layer's q / k heads
+    gdn_value_heads: int = 0  # linear_num_value_heads: its v heads, each with a [gdn_key_dim, gdn_value_dim] state
+    gdn_key_dim: int = 0  # linear_key_head_dim
+    gdn_value_dim: int = 0  # linear_value_head_dim
+    rope_theta: float = 0.0  # the gated attention's rotary base
+    rotary: float = 1.0  # partial_rotary_factor: the share of a head it rotates
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
     attention_multiplier: float = 0.0625
@@ -165,19 +207,23 @@ class HybridDecoderConfig:
         if self.heads % self.kv_heads:
             raise ValueError(f"heads={self.heads} not a multiple of kv_heads={self.kv_heads}")
         if self.pattern:
-            if len(self.pattern) != self.layers or set(self.pattern) - {KIND_SSM, KIND_ATTN, KIND_EXPERT}:
+            single, double = {KIND_SSM, KIND_ATTN, KIND_EXPERT}, {KIND_GDN, KIND_GATED}
+            if len(self.pattern) != self.layers or not (set(self.pattern) <= single or set(self.pattern) <= double):
                 raise ValueError(
                     f"pattern={self.pattern!r}: {self.layers} characters of {KIND_SSM!r} (Mamba-2), "
-                    f"{KIND_ATTN!r} (attention) and {KIND_EXPERT!r} (an expert layer)"
+                    f"{KIND_ATTN!r} (attention) and {KIND_EXPERT!r} (an expert layer), or of {KIND_GDN!r} (a gated "
+                    f"delta-rule mixer) and {KIND_GATED!r} (gated attention), each with an expert layer"
                 )
-            object.__setattr__(self, "attn_layers", tuple(i for i, k in enumerate(self.pattern) if k == KIND_ATTN))
+            object.__setattr__(
+                self, "attn_layers", tuple(i for i, k in enumerate(self.pattern) if k in (KIND_ATTN, KIND_GATED))
+            )
         if any(not 0 <= i < self.layers for i in self.attn_layers):
             raise ValueError(f"attn_layers={self.attn_layers} outside 0..{self.layers - 1}")
         if self.ssm_conv < 2:
             raise ValueError("ssm_conv must be >= 2")
         if self.ssm_heads % self.ssm_groups:
             raise ValueError(f"ssm_heads={self.ssm_heads} not a multiple of ssm_groups={self.ssm_groups}")
-        if KIND_EXPERT in self.pattern and not (
+        if self.expert_layers and not (
             0 < self.experts_per_tok <= self.experts
             and 0 < self.experts_held <= self.experts - self.first_expert
             and self.shared_ffn > 0
@@ -187,6 +233,17 @@ class HybridDecoderConfig:
                 f"(experts_held from first_expert): {self.experts}, {self.experts_per_tok}, {self.shared_ffn}, "
                 f"{self.experts_held} from {self.first_expert}"
             )
+        if KIND_GDN in self.pattern and not (
+            self.gdn_key_heads > 0 and self.gdn_key_dim > 0 and self.gdn_value_dim > 0
+            and self.gdn_value_heads > 0 and self.gdn_value_heads % self.gdn_key_heads == 0
+        ):
+            raise ValueError(
+                "a delta-rule layer needs gdn_key_heads, gdn_key_dim, gdn_value_dim and gdn_value_heads in whole "
+                f"groups of the key heads: {self.gdn_key_heads}, {self.gdn_key_dim}, {self.gdn_value_dim}, "
+                f"{self.gdn_value_heads}"
+            )
+        if KIND_GATED in self.pattern and not (self.rope_theta > 0 and 0 < self.rotary <= 1):
+            raise ValueError(f"gated attention needs rope_theta and rotary in (0, 1]: {self.rope_theta}, {self.rotary}")
 
     @property
     def q_width(self) -> int:
@@ -205,6 +262,22 @@ class HybridDecoderConfig:
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
+    def gdn_key_width(self) -> int:
+        return self.gdn_key_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_width(self) -> int:
+        return self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_width(self) -> int:  # q | k | v behind one convolution
+        return 2 * self.gdn_key_width + self.gdn_value_width
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.rotary)
+
+    @property
     def kinds(self) -> str:
         """A character a layer (``KIND_*``)."""
         return self.pattern or "".join(KIND_ATTN if i in self.attn_layers else KIND_SSM for i in range(self.layers))
@@ -215,16 +288,32 @@ class HybridDecoderConfig:
         return not self.pattern
 
     @property
+    def paired_experts(self) -> bool:
+        """Whether an expert layer follows every mixer in the same layer,
+        under zero-centred norms (a pattern of ``D`` and ``G``: the third
+        shape)."""
+        return KIND_GDN in self.pattern or KIND_GATED in self.pattern
+
+    @property
     def ssm_layers(self) -> int:
         return self.kinds.count(KIND_SSM)
 
     @property
+    def gdn_layers(self) -> int:
+        return self.kinds.count(KIND_GDN)
+
+    @property
+    def rec_layers(self) -> int:
+        """The layers with state rows: Mamba-2's or the delta rule's (a pattern has one of the two)."""
+        return self.ssm_layers + self.gdn_layers
+
+    @property
     def expert_layers(self) -> int:
-        return self.kinds.count(KIND_EXPERT)
+        return self.layers if self.paired_experts else self.kinds.count(KIND_EXPERT)
 
     def cache_index(self, layer: int) -> int:
         """A layer's index in ITS cache: the attention layers count through
-        the KV pool's layers, the Mamba layers through the state's."""
+        the KV pool's layers, the recurrent layers through the state's."""
         return self.kinds[:layer].count(self.kinds[layer])
 
 
@@ -262,9 +351,18 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
     expert's hidden width is STORED in whole lane tiles (ops/moe.py
     ``lane_tiles``: 1856 as 1920, zeros in ``up``'s last columns and
     ``down``'s last rows, so the grouped products run on the megablox kernel;
-    the mathematics is the width's: ``relu(0)^2 = 0``)."""
+    the mathematics is the width's: ``relu(0)^2 = 0``).
+
+    The third shape as ``qwen3_next``'s initialiser draws it: a delta-rule
+    layer's ``A`` uniform in (0, 16] (``A_log`` its log), ``dt_bias`` ones,
+    its convolution uniform like Mamba's and without bias, its gated norm's
+    weight ones; every zero-centred norm's weight zeros (it weighs by ``1 +
+    w``); the rest as above, the experts' ``gate_up`` with gate and up side
+    by side (512 is four whole lane tiles: nothing is padded)."""
     root = jax.random.key(int(seed), impl="rbg")
     h, n, w = cfg.ssm_heads, cfg.ssm_state, cfg.conv_width
+    # a zero-centred norm weighs by 1 + w: zeros where the others hold ones
+    unit = (jnp.zeros if cfg.paired_experts else jnp.ones)((cfg.hidden,), dtype)
 
     def draw(key, shape, std=0.02):
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
@@ -325,27 +423,76 @@ def init_hybrid_decoder(cfg: HybridDecoderConfig, seed: int = 0, dtype=jnp.bfloa
             "shared": expert(ks[4], ks[5], (), cfg.shared_ffn),
         }
 
+    def softmax_experts(ks):
+        """The third shape's expert layer: gated-SiLU experts (``gate_up``:
+        gate and up side by side), the held share of them, a shared one and
+        its one-scalar gate."""
+        gated = lambda k1, k2, lead, width: {  # noqa: E731
+            "gate_up": draw(k1, (*lead, cfg.hidden, 2 * width)), "down": draw(k2, (*lead, width, cfg.hidden))}
+        return {
+            "ln2": unit,
+            "moe": {"router": draw(ks[0], (cfg.hidden, cfg.experts)), **gated(ks[1], ks[2], (cfg.experts_held,), cfg.ffn)},
+            "shared": gated(ks[3], ks[4], (), cfg.shared_ffn),
+            "shared_gate": draw(ks[5], (cfg.hidden,)),
+        }
+
+    @jax.jit
+    def gdn_layer(key):
+        ks = jax.random.split(key, 11)
+        bound = 1.0 / math.sqrt(cfg.ssm_conv)
+        return {
+            "ln1": unit,
+            "gdn_in": draw(ks[0], (cfg.hidden, cfg.gdn_conv_width + cfg.gdn_value_width)),  # q | k | v | z
+            "gdn_ba": draw(ks[1], (cfg.hidden, 2 * cfg.gdn_value_heads)),  # b | a
+            "conv_w": jax.random.uniform(ks[2], (cfg.ssm_conv, cfg.gdn_conv_width), jnp.float32, -bound, bound).astype(dtype),
+            "dt_bias": jnp.ones((cfg.gdn_value_heads,), dtype),
+            # A uniform in (0, 16]
+            "A_log": jnp.log(16.0 * (1.0 - jax.random.uniform(ks[3], (cfg.gdn_value_heads,), jnp.float32))).astype(dtype),
+            "gdn_norm": jnp.ones((cfg.gdn_value_dim,), dtype),
+            "gdn_out": draw(ks[4], (cfg.gdn_value_width, cfg.hidden)),
+            **softmax_experts(ks[5:]),
+        }
+
+    @jax.jit
+    def gated_attn_layer(key):
+        ks = jax.random.split(key, 8)
+        return {
+            "ln1": unit,
+            # a head's query and its gate side by side ([q_h | gate_h] a head), then k, then v
+            "attn_qkv": draw(ks[0], (cfg.hidden, 2 * cfg.q_width + 2 * cfg.kv_width)),
+            "q_norm": jnp.zeros((cfg.head_dim,), dtype),
+            "k_norm": jnp.zeros((cfg.head_dim,), dtype),
+            "attn_o": draw(ks[1], (cfg.q_width, cfg.hidden)),
+            **softmax_experts(ks[2:]),
+        }
+
     params = {
         "tok_emb": jax.jit(lambda k: draw(k, (cfg.vocab, cfg.hidden), 1.0 if cfg.untied else 0.004))(
             jax.random.fold_in(root, 1 << 20)
         ),
-        "ln_f": jnp.ones((cfg.hidden,), dtype),
+        "ln_f": unit,
     }
     if cfg.untied:
         params["lm_head"] = jax.jit(lambda k: draw(k, (cfg.hidden, cfg.vocab)))(jax.random.fold_in(root, (1 << 20) + 1))
-    layer = {KIND_ATTN: attn_layer, KIND_SSM: ssm_layer, KIND_EXPERT: expert_layer}
+    layer = {KIND_ATTN: attn_layer, KIND_SSM: ssm_layer, KIND_EXPERT: expert_layer, KIND_GDN: gdn_layer,
+             KIND_GATED: gated_attn_layer}
     params["layers"] = [layer[k](jax.random.fold_in(root, i)) for i, k in enumerate(cfg.kinds)]
     return params
 
 
 def state_zeros(cfg: HybridDecoderConfig, rows: int) -> tuple:
     """The zeroed state cache, float32, the row at axis 0: one state array
-    [rows, heads, head_dim, state] a Mamba layer, then one conv array [rows,
-    (ssm_conv - 1) * conv_width] a Mamba layer (``2 * ssm_layers`` arrays:
-    layer i's are ``rec[i]`` and ``rec[ssm_layers + i]``)."""
-    n = cfg.ssm_layers
-    state = (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-    conv = (rows, (cfg.ssm_conv - 1) * cfg.conv_width)
+    [rows, heads, head_dim, state] a Mamba layer ([rows, value heads, d_k,
+    d_v] a delta-rule layer), then one conv array [rows, (ssm_conv - 1) *
+    conv_width] a layer (``2 * rec_layers`` arrays: recurrent layer i's are
+    ``rec[i]`` and ``rec[rec_layers + i]``)."""
+    n = cfg.rec_layers
+    if cfg.gdn_layers:  # the delta rule's matrix a value head, and its q | k | v conv inputs
+        state = (rows, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim)
+        conv = (rows, (cfg.ssm_conv - 1) * cfg.gdn_conv_width)
+    else:
+        state = (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        conv = (rows, (cfg.ssm_conv - 1) * cfg.conv_width)
     return tuple(jnp.zeros(state if i < n else conv, jnp.float32) for i in range(2 * n))
 
 
@@ -414,6 +561,38 @@ def _valid(n: int, m: int, counts, rows):
     return valid
 
 
+def _norm(cfg: HybridDecoderConfig, w, x):
+    """The configuration's RMS norm over x's last axis: ``_rms``, or for the
+    third shape the zero-centred one, ``x . rsqrt(mean(x^2) + eps) . (1 +
+    w)``, all of it in float32 before the cast back."""
+    if not cfg.paired_experts:
+        return _rms(w, x, cfg.rms_eps)
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + cfg.rms_eps)
+    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def _causal_conv(conv_in, xin, w, bias, valid):
+    """The depthwise causal convolution of a recurrent mixer over the
+    dispatch's inputs xin [n, m, width] behind the cached last k - 1 ones
+    (conv_in [n, (k - 1) * width], time-major and flat), w [k, width], an
+    optional bias. Returns (silu of the convolution [n, m, width] float32,
+    the cache after the dispatch: the k - 1 inputs that end at the row's
+    last real one, all of the old cache where ``valid`` [n, m] has none)."""
+    f32 = jnp.float32
+    n, m, width = xin.shape
+    k = w.shape[0]
+    # the last k - 1 inputs, then the dispatch's own: [n, k - 1 + m, w]
+    seq = jnp.concatenate([conv_in.reshape(n, k - 1, width), xin.astype(f32)], axis=1)
+    cw = w.astype(f32)
+    # (the bias is cast before the taps are summed: the order granite's hashed lowered text has)
+    taps = lambda: sum(cw[j] * seq[:, j : j + m] for j in range(k))  # noqa: E731
+    act = jax.nn.silu(taps() if bias is None else bias.astype(f32) + taps())
+    last = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    conv_out = jax.vmap(lambda s, at: lax.dynamic_slice_in_dim(s, at, k - 1))(seq, last)
+    return act, conv_out.reshape(n, (k - 1) * width)
+
+
 def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_rows):
     """The Mamba-2 mixer over x[n, m, d], Mamba layer ``si``'s state and conv
     arrays of ``rec`` (``state_zeros``). The step (``state_rows`` None; m = 1): batch row r is
@@ -430,7 +609,7 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
         # layer (4.8 GB of gathered state at 64 rows)
         x, state, conv = lax.optimization_barrier((x, state, conv))
     n, m, _ = x.shape
-    h, hd, ns, w, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.conv_width, cfg.ssm_conv
+    h, hd, ns, w = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.conv_width
     # the head axis: (heads,) under one B/C group, (groups, heads of a group) under more (head i reads group
     # i // heads of a group), and B and C [.., groups, N] beside it; one group reshapes nothing
     grp = (cfg.ssm_groups,) if cfg.ssm_groups > 1 else ()
@@ -443,16 +622,7 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
     with jax.named_scope(SCOPE_ATTN):
         with jax.named_scope(SCOPE_SSM_CONV):
             conv_in = conv[:n] if state_rows is None else conv[state_rows[0]]
-            # the last k - 1 inputs, then the dispatch's own: [n, k - 1 + m, w]
-            seq = jnp.concatenate([conv_in.reshape(n, k - 1, w), xbc.astype(f32)], axis=1)
-            cw = p["conv_w"].astype(f32)
-            act = p["conv_b"].astype(f32) + sum(cw[j] * seq[:, j : j + m] for j in range(k))
-            xbc = jax.nn.silu(act)
-            # the cache after the dispatch: the k - 1 inputs that end at the
-            # row's last real one (all of the old cache where it has none)
-            last = jnp.sum(valid, axis=1, dtype=jnp.int32)
-            conv_out = jax.vmap(lambda s, at: lax.dynamic_slice_in_dim(s, at, k - 1))(seq, last)
-            conv_out = conv_out.reshape(n, (k - 1) * w)
+            xbc, conv_out = _causal_conv(conv_in, xbc, p["conv_w"], p["conv_b"], valid)
         with jax.named_scope(SCOPE_SSM_SCAN):
             xs, b, c = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + (w - cfg.d_inner) // 2], axis=-1)
             xs = xs.reshape(n, m, *hs, hd)
@@ -488,6 +658,89 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
     return out, rec
 
 
+def _gdn(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_rows):
+    """The gated delta-rule mixer over x[n, m, d], delta-rule layer ``si``'s
+    state and conv arrays of ``rec``; the rows it reads and writes as
+    ``_mamba`` has them (the step in place where ``rows``; a chunk by
+    ``state_rows``, positions past ``counts`` leaving state and conv cache as
+    they were: decay 1 and beta 0). The state, the decay, beta, both l2 norms
+    and the gated norm are float32. Returns (the mixer's output [n, m, d],
+    rec)."""
+    state, conv = rec[si], rec[cfg.rec_layers + si]
+    if state_rows is not None:
+        x, state, conv = lax.optimization_barrier((x, state, conv))  # ``_mamba``: a layer's rows when its input exists
+    n, m, _ = x.shape
+    hk, hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+    r, kw = hv // hk, cfg.gdn_key_width
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_QKV), jax.named_scope(SCOPE_GDN_IN):
+        h = _norm(cfg, p["ln1"], x)
+        qkv, z = jnp.split(h @ p["gdn_in"].astype(x.dtype), [cfg.gdn_conv_width], axis=-1)
+        b, a = jnp.split(h @ p["gdn_ba"].astype(x.dtype), 2, axis=-1)
+    valid = _valid(n, m, counts, rows)
+    with jax.named_scope(SCOPE_ATTN):
+        with jax.named_scope(SCOPE_GDN_CONV):
+            conv_in = conv[:n] if state_rows is None else conv[state_rows[0]]
+            qkv, conv_out = _causal_conv(conv_in, qkv, p["conv_w"], None, valid)
+        with jax.named_scope(SCOPE_GDN_SCAN):
+            q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
+            q, k = q.reshape(n, m, hk, dk), k.reshape(n, m, hk, dk)
+            q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + _L2_EPS) * dk**-0.5
+            k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + _L2_EPS)
+            v = v.reshape(n, m, hk, r, dv)
+            # log alpha = -exp(A_log) softplus(a + dt_bias) <= 0; 0 (and beta 0) where the row stands
+            log_alpha = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + p["dt_bias"].astype(f32))
+            log_alpha = jnp.where(valid[..., None], log_alpha, 0.0).reshape(n, m, hk, r)
+            beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0).reshape(n, m, hk, r)
+            if state_rows is None:
+                # over EVERY row of the state array, the rows past the slots (snapshots, the zero row) with k 0,
+                # decay 1 and beta 0, which leave them as they were to the bit: the state is read by two fusions
+                # (the sums, the update), and a ``state[:n]`` both read was materialised first, 0.42 of a layer's
+                # 1.06 ms at 64 slots (my chip run, PR 57); whole, the update aliases the donated array
+                total = state.shape[0]
+                whole = lambda t: jnp.pad(t[:, 0], ((0, total - n), *[(0, 0)] * (t.ndim - 2)))  # noqa: E731
+                y, s_out = gdn_step(
+                    state.astype(f32).reshape(total, hk, r, dk, dv), *(whole(t) for t in (q, k, v, log_alpha, beta))
+                )
+                y = y[:n, None]
+                state = s_out.reshape(total, hv, dk, dv).astype(state.dtype)
+                conv = conv.at[:n].set(conv_out)
+            else:
+                s_in = state[state_rows[0]].astype(f32).reshape(n, hk, r, dk, dv)
+                y, s_out = gdn_chunk(s_in, q, k, v, log_alpha, beta)
+                for to in (state_rows[1], state_rows[2]):
+                    state = state.at[to].set(s_out.reshape(n, hv, dk, dv), mode="drop")
+                    conv = conv.at[to].set(conv_out, mode="drop")
+    with jax.named_scope(SCOPE_ATTN_OUT):
+        with jax.named_scope(SCOPE_GDN_NORM):
+            # the norm over each head's d_v first (a plain weight, not zero-centred), then the gate
+            y = y.reshape(n, m, hv, dv)
+            y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps) * p["gdn_norm"].astype(f32)
+            g = (y.reshape(n, m, hv * dv) * jax.nn.silu(z.astype(f32))).astype(x.dtype)
+        with jax.named_scope(SCOPE_GDN_OUT):
+            out = g @ p["gdn_out"].astype(x.dtype)
+    rec = tuple(state if i == si else conv if i == cfg.rec_layers + si else a for i, a in enumerate(rec))
+    return out, rec
+
+
+def _experts_softmax(cfg: HybridDecoderConfig, p, x, valid):
+    """The third shape's expert layer over x[n, m, d] (after its own norm
+    ``ln2``): the shared expert times ``sigmoid(shared_gate . n)``, one scalar
+    a token, plus the routed experts held here under the softmax gate: softmax
+    over ALL ``experts`` in float32, the top ``experts_per_tok``, renormalised
+    over the picks (ops/moe.py ``route_topk``); a pick on an expert another
+    chip holds adds nothing. Returns (the layer's output [n, m, d],
+    counters[6]: ``moe_held_ffn``)."""
+    n, m, d = x.shape
+    h = _norm(cfg, p["ln2"], x).reshape(n * m, d)
+    with jax.named_scope(SCOPE_SHARED_EXPERT):
+        gate = jax.nn.sigmoid(jnp.sum(h.astype(jnp.float32) * p["shared_gate"].astype(jnp.float32), axis=-1, keepdims=True))
+        shared = expert_mlp(p["shared"], h) * gate.astype(x.dtype)
+    gates, experts = route_topk(p["moe"]["router"], h, cfg.experts_per_tok)
+    y, cnt = moe_held_ffn(p["moe"], h, gates, experts, cfg.first_expert, valid.reshape(-1))
+    return (shared + y).reshape(x.shape), cnt
+
+
 def _experts(cfg: HybridDecoderConfig, p, x, valid):
     """An expert layer over x[n, m, d]: the shared expert, which every token
     takes ungated, plus the routed experts held here. The router scores ALL
@@ -508,17 +761,31 @@ def _experts(cfg: HybridDecoderConfig, p, x, valid):
 
 
 def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, counts, reads=None, interpret=False):
-    """Grouped-query attention without positions over pool layer ``ki``:
+    """Grouped-query attention over pool layer ``ki``, without positions, or
+    the third shape's gated one (a layer with ``q_norm``: a sigmoid gate
+    beside each head's query, q and k normed a head, rotary on the head's first
+    ``rotary_dim`` dimensions before the cache write):
     K and V scatter through the block tables and attention reads them back,
     like the other families' write-then-read: through the gather, or, where
     the step was given ``reads`` (``decoder._paged_step_reads``), through
     ops/gqa_decode.py's kernel, which reads the pages where they lie.
     Returns (the mixer's output [n, m, d], pool)."""
     n, m, _ = x.shape
+    gated = "q_norm" in p  # the third shape's: [q_h | gate_h] a head, q and k normed a head, partial rotary
     with jax.named_scope(SCOPE_QKV):
-        qkv = _rms(p["ln1"], x, cfg.rms_eps) @ p["attn_qkv"].astype(x.dtype)
-        q, k, v = jnp.split(qkv, [cfg.q_width, cfg.q_width + cfg.kv_width], axis=-1)
-        q = q.reshape(n, m, cfg.heads, cfg.head_dim)
+        qkv = _norm(cfg, p["ln1"], x) @ p["attn_qkv"].astype(x.dtype)
+        q_width = cfg.q_width * (2 if gated else 1)
+        q, k, v = jnp.split(qkv, [q_width, q_width + cfg.kv_width], axis=-1)
+        q = q.reshape(n, m, cfg.heads, -1)
+        if gated:
+            q, gate = jnp.split(q, 2, axis=-1)
+            q = _norm(cfg, p["q_norm"], q)
+            k = _norm(cfg, p["k_norm"], k.reshape(n, m, cfg.kv_heads, cfg.head_dim))
+            with jax.named_scope(SCOPE_ROPE):
+                rot = cfg.rotary_dim
+                inv_freq = (cfg.rope_theta ** (-2.0 * np.arange(rot // 2, dtype=np.float64) / rot)).astype(np.float32)
+                q_pos = positions[:, None] + jnp.arange(m, dtype=positions.dtype)[None, :]
+                q, k = _rope(q, q_pos, inv_freq, 1.0), _rope(k, q_pos, inv_freq, 1.0).reshape(n, m, cfg.kv_width)
     pool = _paged_write(pool, ki, k, v, bt, positions, counts)
     scale = cfg.attention_multiplier
     if reads is not None:
@@ -536,6 +803,9 @@ def _attention(cfg: HybridDecoderConfig, ki: int, p, x, pool, bt, positions, cou
             else:
                 ctx = _attend(q, ck, cv, visible, scale=scale)
     with jax.named_scope(SCOPE_ATTN_OUT):
+        if gated:
+            with jax.named_scope(SCOPE_ATTN_GATE):
+                ctx = ctx * jax.nn.sigmoid(gate.reshape(n, m, cfg.q_width).astype(jnp.float32)).astype(ctx.dtype)
         return ctx @ p["attn_o"].astype(x.dtype), pool
 
 
@@ -572,19 +842,27 @@ def _forward(
                 with jax.named_scope(SCOPE_MOE_COMBINE):
                     cnt = cnt + c
             continue
-        if kind == KIND_ATTN:
+        if kind in (KIND_ATTN, KIND_GATED):
             mix, pool = _attention(cfg, ci, p, x, pool, bt, positions, counts, reads, attn_kernel == "interpret")
+        elif kind == KIND_GDN:
+            mix, rec = _gdn(cfg, ci, p, x, rec, counts, rows, state_rows)
         else:
             mix, rec = _mamba(cfg, ci, p, x, rec, counts, rows, state_rows)
         with jax.named_scope(SCOPE_ATTN_OUT):
             x = x + mix * jnp.asarray(res, x.dtype)
+        if cfg.paired_experts:
+            with jax.named_scope(SCOPE_MLP):
+                y, c = _experts_softmax(cfg, p, x, real)
+                x = x + y * jnp.asarray(res, x.dtype)
+                with jax.named_scope(SCOPE_MOE_COMBINE):
+                    cnt = cnt + c
         if cfg.paired:
             with jax.named_scope(SCOPE_MLP):
                 gu = _rms(p["ln2"], x, cfg.rms_eps) @ p["mlp_in"].astype(x.dtype)
                 g, u = jnp.split(gu, 2, axis=-1)
                 x = x + ((jax.nn.silu(g) * u) @ p["mlp_out"].astype(x.dtype)) * jnp.asarray(res, x.dtype)
     with jax.named_scope(SCOPE_LM_HEAD):
-        last = _rms(params["ln_f"], x if pick is None else jnp.take_along_axis(x, pick[:, None, None], axis=1), cfg.rms_eps)
+        last = _norm(cfg, params["ln_f"], x if pick is None else jnp.take_along_axis(x, pick[:, None, None], axis=1))
         if cfg.untied:
             logits = jnp.matmul(last, params["lm_head"].astype(x.dtype), preferred_element_type=jnp.float32)
         else:  # the tied head: the embedding's rows again
@@ -640,7 +918,7 @@ class HybridDecoder:
         return (*held, "ssm_rows", "attn_run_pages")
 
     def decoder_dims(self, params: dict) -> dict:
-        if ("lm_head" in params) != self.cfg.untied or not any("ssm_in" in p for p in params["layers"]):
+        if ("lm_head" in params) != self.cfg.untied or not any("ssm_in" in p or "gdn_in" in p for p in params["layers"]):
             raise FamilyNotServed("not a hybrid decoder's parameters (models/hybrid_decoder.py layout)")
         c = self.cfg
         return {

@@ -556,6 +556,12 @@ def build_hybrid_decoder(
     shared_ffn: int = 0,
     routed_scale: float = 1.0,
     untied: bool = False,
+    gdn_key_heads: int = 0,
+    gdn_value_heads: int = 0,
+    gdn_key_dim: int = 0,
+    gdn_value_dim: int = 0,
+    rope_theta: float = 0.0,
+    rotary: float = 1.0,
     embedding_multiplier: float = 12.0,
     residual_multiplier: float = 0.22,
     attention_multiplier: float = 0.0625,
@@ -568,15 +574,25 @@ def build_hybrid_decoder(
     **unknown,
 ) -> ModelSpec:
     """The generative tier's third decoder family (models/hybrid_decoder.py,
-    Granite 4.0-H and Nemotron-H): Mamba-2 layers with a recurrent state
+    Granite 4.0-H, Nemotron-H and Qwen3-Next): Mamba-2 layers with a recurrent state
     (``ssm_groups`` B/C groups), grouped-query attention without positions,
     Granite's four multipliers (a model without them passes ones), a tied
     head unless ``untied``. ``attn_layers`` says which layer is what, one of
-    two ways: comma-separated indices (Granite: those layers attend, every
+    three ways: comma-separated indices (Granite: those layers attend, every
     other is Mamba-2, and a dense gated-SiLU MLP of width ``ffn`` pairs with
     every mixer), or the published ``hybrid_override_pattern`` (Nemotron-H: a
     character a layer, ``M`` Mamba-2, ``*`` attention, ``E`` an expert
-    layer; a layer is that ONE sublayer). An expert layer is a shared expert
+    layer; a layer is that ONE sublayer), or a pattern of ``D`` and ``G``
+    (the third shape, ``qwen3_next``: every layer a mixer AND an expert layer
+    under zero-centred norms; ``D`` a gated delta-rule mixer of
+    ``gdn_key_heads`` / ``gdn_value_heads`` heads of ``gdn_key_dim`` /
+    ``gdn_value_dim`` behind a convolution of ``ssm_conv`` taps, whose state
+    is a float32 matrix a value head; ``G`` attention with a sigmoid output
+    gate, q and k normed a head and rotary (``rope_theta``) on the first
+    ``rotary`` of the head; its expert layer the softmax top-k gate over
+    gated-SiLU experts plus a sigmoid-gated shared expert; ``qwen3_next``
+    publishes ``full_attention_interval`` n: every n-th character ``G``, the
+    others ``D``). An ``E`` expert layer is a shared expert
     of width ``shared_ffn`` plus the top ``experts_per_tok`` of ``experts``
     routed ones of width ``ffn`` under the bias-selected sigmoid gate times
     ``routed_scale``, squared-ReLU and ungated; ``experts_held`` (0: all)
@@ -621,6 +637,8 @@ def build_hybrid_decoder(
         ssm_conv=int(ssm_conv), ssm_groups=int(ssm_groups), untied=bool_param(untied), experts=int(experts),
         experts_held=int(experts_held) or int(experts), first_expert=int(first_expert),
         experts_per_tok=int(experts_per_tok), shared_ffn=int(shared_ffn), routed_scale=float(routed_scale),
+        gdn_key_heads=int(gdn_key_heads), gdn_value_heads=int(gdn_value_heads), gdn_key_dim=int(gdn_key_dim),
+        gdn_value_dim=int(gdn_value_dim), rope_theta=float(rope_theta), rotary=float(rotary),
         embedding_multiplier=float(embedding_multiplier),
         residual_multiplier=float(residual_multiplier),
         attention_multiplier=float(attention_multiplier),

@@ -127,14 +127,19 @@ def gqa_tiles(row_width: int, heads: int, kv_heads: int, page_size: int, dtype) 
     into the MXU as they are stored), rows of whole 128-lane tiles, pages of
     whole sublane tiles (16 rows of a two-byte float: a page is the
     destination of one DMA and a slice of the block the MXU takes), a head
-    that divides a lane tile, and query heads in whole groups of two or more
+    that divides a lane tile or is whole lane tiles (256: the step's
+    block-diagonal query spans a kv head's lanes whatever their number, the
+    chunk's kernel takes the head's tiles as one, ``_tile_lanes``), and query
+    heads in whole groups of two or more
     (48 / 8, 72 / 8: the block-diagonal query's rows are padded to whole
     sublane tiles, ``_block_diagonal``; a group of ONE is
     ops/paged_attention.py's geometry, not this kernel's)."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize != 2:
         return False
-    if row_width % _LANES or row_width % kv_heads or _LANES % (row_width // kv_heads):
+    if row_width % _LANES or row_width % kv_heads:
+        return False
+    if _LANES % (row_width // kv_heads) and (row_width // kv_heads) % _LANES:
         return False
     return page_size % 16 == 0 and heads % kv_heads == 0 and heads > kv_heads
 
@@ -475,17 +480,19 @@ def gqa_decode_attention(
 CHUNK_BLOCK_PAGES = 64
 
 
-def _tile_lanes(row_width: int) -> int:
+def _tile_lanes(row_width: int, kv_heads: int) -> int:
     """Lanes of one tile of a pool row as the chunk's kernel takes it: 128
-    (a row narrower than that, the interpreter's tests, is one tile)."""
-    return min(_LANES, row_width)
+    (a row narrower than that, the interpreter's tests, is one tile), or a
+    whole kv head where that is wider (head 256: two lane tiles taken as one,
+    the scores summed over both in the product itself)."""
+    return max(min(_LANES, row_width), row_width // kv_heads)
 
 
 def _chunk_query_block(queries: int, heads: int, kv_heads: int, row_width: int) -> int:
     """Queries of one work item of the chunk's kernel: ops/mla.py
     ``_query_block`` over the score rows a query adds to a LANE TILE's
     product (its group's heads, of every kv head the tile holds)."""
-    return mla._query_block(queries, _tile_lanes(row_width) * heads // row_width)
+    return mla._query_block(queries, _tile_lanes(row_width, kv_heads) * heads // row_width)
 
 
 def gqa_chunk_tiles(kernel: str, queries: int, heads: int, kv_heads: int, head_dim: int) -> bool:
@@ -499,7 +506,7 @@ def gqa_chunk_tiles(kernel: str, queries: int, heads: int, kv_heads: int, head_d
     family's chunk program and its ``chunk_attn`` (the scheduler's count of
     the dispatches that took the kernel) both ask."""
     row_width = kv_heads * head_dim
-    lanes = _tile_lanes(row_width)
+    lanes = _tile_lanes(row_width, kv_heads)
     if not kernel or queries < 2 or heads % kv_heads or row_width % lanes or lanes % head_dim:
         return False
     if kernel == "interpret":
@@ -537,7 +544,7 @@ def _chunk_kernel(
     q_ref, k_hbm, v_hbm,  # row i's query block j [1, 1, R, w] (``_tile_rows``); the two planes, left in HBM
     o_ref,  # its normalised context, every row over every lane tile [1, 1, R, w]
     kbuf, vbuf, top_ref, sum_ref, acc_ref, sem, cur,  # scratch
-    *, page_size: int, run: int, block_runs: int, scale: float, tq: int, window: int,
+    *, page_size: int, run: int, block_runs: int, scale: float, tq: int, window: int, lanes: int,
 ):
     """Grid step (i, j) is query block j of row i: ``tq`` queries by all
     heads, laid out a lane tile (score row r of a tile is query ``j * tq + r
@@ -548,7 +555,8 @@ def _chunk_kernel(
     next, else the first block of the next work item (ops/mla.py
     ``_chunk_kernel``, whose scalars these are). A key block's rows, fetched
     once, serve every lane tile in turn: the tile's group rows ``[R, 128]``
-    against the block's 128 lanes. Every other grid step writes zeros and
+    against the block's 128 lanes (``lanes``, static: ``_tile_lanes``; a head
+    of 256 is one tile of 256). Every other grid step writes zeros and
     touches neither the planes nor the MXU.
 
     ``window`` (static; 0: none): a key weighs exactly 0 for a query with
@@ -560,7 +568,6 @@ def _chunk_kernel(
     block = run * block_runs
     keys = block * page_size
     rows, w = q_ref.shape[2], q_ref.shape[3]
-    lanes = _tile_lanes(w)
     # (scalars by ``lax``: nothing here is negative, and a ``//`` or a ``jnp.minimum`` of traced scalars is a
     # dozen equations and a nested call each, which a kernel traced once a ladder entry and page kind pays in set-up)
 
@@ -676,7 +683,7 @@ def _tile_rows(q, kv_heads: int, tq: int):
     tile go through the MXU as one block-diagonal pair, exact zeros added;
     ``d`` 128: nothing is padded)."""
     n, m, heads, d = q.shape
-    hp, r = _tile_lanes(kv_heads * d) // d, heads // kv_heads
+    hp, r = _tile_lanes(kv_heads * d, kv_heads) // d, heads // kv_heads
     by_tile = q.reshape(n, m // tq, tq, kv_heads // hp, hp, r, d).transpose(0, 1, 4, 5, 2, 3, 6)  # [.., u, a, x, t, d]
     own = jnp.eye(hp, dtype=bool)[:, None, None, None, :, None]  # [u, 1, 1, 1, u', 1]
     wide = jnp.where(own, by_tile[..., None, :], jnp.zeros((), q.dtype))  # [.., u, a, x, t, u', d]
@@ -689,7 +696,7 @@ def _own_tile_lanes(ctx, heads: int, kv_heads: int, tq: int):
     tile, heads merged."""
     n, blocks, _, w = ctx.shape
     d = w // kv_heads
-    hp, r = _tile_lanes(w) // d, heads // kv_heads
+    hp, r = _tile_lanes(w, kv_heads) // d, heads // kv_heads
     wide = ctx.reshape(n, blocks, hp, r, tq, kv_heads // hp, hp, d)
     own = jnp.eye(hp, dtype=bool)[:, None, None, None, :, None]
     # one term of the sum is the head's own, the others exact zeros
@@ -759,10 +766,10 @@ def _chunk_call(q, pool_k, pool_v, layer, bt, lengths, q_first, counts, next_liv
     block = run * block_runs
     if runs.shape != (n, blocks * block_runs):
         raise ValueError(f"runs {list(runs.shape)} for {blocks} blocks of {block_runs} groups (chunk_reads)")
-    lanes = _tile_lanes(w)
+    lanes = _tile_lanes(w, kv_heads)
     rows, n_tiles = tq * lanes * heads // w, w // lanes
     kernel = functools.partial(
-        _chunk_kernel, page_size=ps, run=run, block_runs=block_runs, scale=scale, tq=tq, window=window
+        _chunk_kernel, page_size=ps, run=run, block_runs=block_runs, scale=scale, tq=tq, window=window, lanes=lanes
     )
 
     def q_block(i, j, _layer, _bt, _len, _pos, cnt, *_):
@@ -772,7 +779,7 @@ def _chunk_call(q, pool_k, pool_v, layer, bt, lengths, q_first, counts, next_liv
     item = jnp.dtype(pool_k.dtype).itemsize
     vmem = (
         4 * block * ps * w * item  # K and V, the block in use and the one in flight
-        + 3 * n_tiles * rows * _LANES * 4  # maximum, sum (a lane tile a column) and context
+        + 3 * n_tiles * rows * lanes * 4  # maximum, sum (a lane tile a column) and context
         + 4 * rows * w * item  # the query block and its output, each in two buffers
         + 4 * rows * block * ps * 4  # a tile's scores and probabilities in flight
     )

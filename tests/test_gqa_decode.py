@@ -191,7 +191,8 @@ def test_two_byte_pool_takes_the_probabilities_in_three_terms():
         (512, 32, 8, 16, jnp.int8, False),
         (512, 32, 8, 8, jnp.bfloat16, False),  # a page under a two-byte sublane tile
         (320, 20, 5, 16, jnp.bfloat16, False),  # rows of 2.5 lane tiles
-        (768, 24, 4, 16, jnp.bfloat16, False),  # heads of 192 do not divide a tile
+        (768, 24, 4, 16, jnp.bfloat16, False),  # heads of 192 neither divide a tile nor are whole tiles
+        (512, 16, 2, 16, jnp.bfloat16, True),  # the qwen3-next-80b-a3b cell: heads of 256, two lane tiles each
         (512, 8, 8, 16, jnp.bfloat16, False),  # 8 query heads: half a sublane tile of the query
     ],
 )
@@ -327,6 +328,25 @@ def test_groups_of_six_and_nine_heads_of_128_match_the_gather_path(heads):
     np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
     want = _window_gather_attention(q, pool, 0, bt, positions, 6, d**-0.5)
     got, _reads = _window_kernel(q, pool, 0, bt, positions, None, 6, d**-0.5)
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+
+
+def test_heads_of_two_lane_tiles_match_the_gather_path():
+    """The qwen3-next-80b-a3b cell's 16 query heads over 2 K/V heads of 256
+    (rows of 512): a head's numbers span two lane tiles of the block-diagonal
+    query, the scores are summed over both in the one product."""
+    rng = np.random.default_rng(57)
+    d, kv, heads = 256, 2, 16
+    pool = tuple(jnp.asarray(rng.standard_normal((1, 12, PS, kv * d)), jnp.float32) for _ in range(2))
+    bt = jnp.asarray([[1, 2, 3, 4, 5], [11, 9, 10, 7, 8]], jnp.int32)
+    positions = jnp.asarray([5 * PS - 1, 2 * PS + 1], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((2, heads, d)), jnp.float32)
+    ck, cv = _paged_gather(pool, 0, bt, kv)
+    visible = jnp.arange(ck.shape[2])[None, None, :] <= positions[:, None, None]
+    want = np.asarray(_attend(q[:, None], ck, cv, visible, scale=d**-0.5)[:, 0])
+    reads = gqa.step_reads(bt, positions, None, PS)
+    got = np.asarray(gqa.gqa_decode_attention(q, pool[0], pool[1], 0, bt, *reads, scale=d**-0.5, interpret=True))
+    assert got.shape == (2, heads * d)
     np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
 
 
@@ -528,7 +548,7 @@ def _small_query_blocks(monkeypatch, heads, kv_heads, d, tq=4):
     """Key blocks of 4 table entries and query blocks of ``tq`` queries: a
     chunk of ``M`` is two work items a row."""
     monkeypatch.setattr(gqa, "CHUNK_BLOCK_PAGES", 4)
-    monkeypatch.setattr(mla_ops, "CHUNK_Q_ROWS", tq * (min(128, kv_heads * d) // d) * heads // kv_heads)
+    monkeypatch.setattr(mla_ops, "CHUNK_Q_ROWS", tq * (gqa._tile_lanes(kv_heads * d, kv_heads) // d) * heads // kv_heads)
     assert gqa._chunk_query_block(M, heads, kv_heads, kv_heads * d) == tq
 
 
@@ -603,6 +623,28 @@ def test_chunk_kernel_matches_the_gather_path(kind, group, d, monkeypatch):
     assert runs.tolist() == (whole if kind == "runs" else [[0] * 6] * 5)
 
 
+@pytest.mark.parametrize("kind", ["runs", "scattered"])
+def test_chunk_kernel_takes_a_head_of_two_lane_tiles_as_one_tile(kind, monkeypatch):
+    """Heads of 256 (the qwen3-next-80b-a3b cell's 16 / 2): a K/V head is the
+    kernel's tile of 256 lanes (``_tile_lanes``), one product sums the scores
+    over both lane tiles; the same dispatch as the narrower heads' case."""
+    kv_heads, heads, d = 2, 16, 256
+    assert gqa._tile_lanes(kv_heads * d, kv_heads) == 256 and gqa._tile_lanes(512, 8) == 128 and gqa._tile_lanes(32, 2) == 32
+    _small_query_blocks(monkeypatch, heads, kv_heads, d)
+    rng = np.random.default_rng(44)
+    pool = tuple(jnp.asarray(rng.standard_normal((L, 64, PS, kv_heads * d)), jnp.float32) for _ in range(2))
+    ids = 1 + np.arange(5 * PAGES).reshape(5, PAGES)
+    if kind == "scattered":
+        ids = 1 + rng.permutation(63)[: 5 * PAGES].reshape(5, PAGES)
+        ids = np.where(np.diff(ids, axis=1, append=-5) == 1, ids[:, ::-1], ids)
+    bt = jnp.asarray(ids, jnp.int32)
+    positions, counts = jnp.asarray(CHUNK_POSITIONS, jnp.int32), jnp.asarray(CHUNK_COUNTS, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((5, M, heads, d)), jnp.float32)
+    got, _ = _chunk_kernel(q, pool, 1, bt, positions, counts, d**-0.5)
+    assert got.shape == (5, M, heads * d)
+    _agree_where_read(got, _chunk_gather(q, pool, 1, bt, positions, d**-0.5), counts)
+
+
 def test_chunk_kernel_reads_no_row_past_a_length_and_none_of_a_padding_rows_table(monkeypatch):
     """NaN in K and V on the junk page, on every row past each row's length
     (the tail of its last page, fetched with it; every later page, never
@@ -671,7 +713,8 @@ def test_gqa_chunk_tiles_names_what_the_chunk_kernel_takes():
     the kernel under "mosaic"; no kernel, one query, a head that does not
     divide a lane tile and query heads outside whole groups do not; the
     interpreter takes any whole groups."""
-    cells = {"H.full": (48, 8, 128), "H.win": (72, 8, 128), "C": (32, 4, 128), "I": (32, 2, 128), "D, F": (32, 8, 64)}
+    cells = {"H.full": (48, 8, 128), "H.win": (72, 8, 128), "C": (32, 4, 128), "I": (32, 2, 128), "D, F": (32, 8, 64),
+             "J": (16, 2, 256)}
     for heads, kv_heads, d in cells.values():
         assert all(gqa.gqa_chunk_tiles("mosaic", c, heads, kv_heads, d) for c in (16, 64, 256))
         assert gqa._chunk_query_block(256, heads, kv_heads, kv_heads * d) in (64, 128)

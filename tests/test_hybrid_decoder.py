@@ -899,3 +899,374 @@ def test_the_zoo_entry_reads_the_pattern_or_the_indices_and_refuses_what_it_does
         get_model("hybrid_decoder", n_groups=8, hybrid_override_pattern="ME")
     with pytest.raises(ValueError, match="pattern="):
         get_model("hybrid_decoder", layers=2, attn_layers="M-")  # '-', a dense MLP alone, is no kind this family has
+
+
+# ---------------------------------------------------------------------------------------------------
+# The family's THIRD SHAPE (Qwen3-Next, PR 57): every layer a mixer AND an expert layer under zero-centred
+# norms; the mixer a gated delta rule (a float32 matrix state a value head, read before it is written) or
+# gated attention (sigmoid output gate, q/k norms a head, rotary on a quarter of the head); softmax top-k
+# over a SHARE of gated-SiLU experts plus a sigmoid-gated shared expert. Held to
+# benchmarks/reference/qwen3-next-80b-a3b.py at hidden 64: 2 key / 4 value heads of 8, 4 / 2 attention
+# heads of 16 (4 rotated dimensions), 16 routed experts of 24 of which 4 (from 4) are held, top 3.
+
+QPATTERN = "DDDG"
+QCFG = hd.HybridDecoderConfig(
+    vocab=96, hidden=64, layers=4, pattern=QPATTERN, heads=4, kv_heads=2, head_dim=16, ffn=24, untied=True,
+    experts=16, experts_held=4, first_expert=4, experts_per_tok=3, shared_ffn=40, gdn_key_heads=2,
+    gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8, rope_theta=1e7, rotary=0.25, embedding_multiplier=1.0,
+    residual_multiplier=1.0, attention_multiplier=0.25, logits_scaling=1.0, rms_eps=1e-6,
+)
+QPUBLISHED = {
+    "full_attention_interval": 4, "num_hidden_layers": 4, "rms_norm_eps": 1e-6, "linear_num_key_heads": 2,
+    "linear_num_value_heads": 4, "num_key_value_heads": 2, "rope_theta": 1e7, "partial_rotary_factor": 0.25,
+    "num_experts_per_tok": 3, "share": {"first_expert": 4},
+}
+QFAM = hd.hybrid_family(QCFG)
+# float32 sums in another order (the blocked delta rule adds a block's writes as matrix products, the
+# reference a token at a time; the paged attention walks pages): logits of O(1) agree to ~1e-5
+QATOL = 3e-5
+
+
+def _load_qref():
+    """The reference as a NEW module object (``_load_nref``'s reason)."""
+    path = os.path.join(ROOT, "benchmarks", "reference", "qwen3-next-80b-a3b.py")
+    spec = importlib.util.spec_from_file_location("bench_reference_qwen3_next_80b_a3b", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def qref():
+    return _load_qref()
+
+
+def _qlively(params, seed=3):
+    """The family's draw with what random weights at this width leave
+    invisible made visible: every matrix six times larger, the zero-centred
+    norms' weights drawn round zero (so that ``1 + w`` is not ``1``), the
+    gated norm's round one, the shared expert's gate vector large enough to
+    leave one half."""
+    keys = iter(jax.random.split(jax.random.key(seed), 128))
+
+    def leaf(path, a):
+        name = path[-1].key
+        if name in ("ln1", "ln2", "ln_f", "q_norm", "k_norm", "gdn_norm"):
+            return ((name == "gdn_norm") + 0.3 * jax.random.normal(next(keys), a.shape)).astype(a.dtype)
+        return a if name in ("tok_emb", "conv_w", "dt_bias", "A_log") else a * 6
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+@pytest.fixture(scope="module")
+def qweights():
+    f32 = _qlively(hd.init_hybrid_decoder(QCFG, seed=5, dtype=jnp.float32))
+    return {jnp.float32: f32, jnp.bfloat16: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), f32)}
+
+
+def _qref_logits(ref, params, ids, precision="highest"):
+    return np.asarray(
+        ref.logits(params, np.asarray(ids)[None], 0, n_head=QCFG.heads, precision=precision, config=QPUBLISHED)
+    )[0]
+
+
+def test_the_third_shape_pairs_every_mixer_with_an_expert_layer_and_names_its_caches():
+    assert QCFG.kinds == QPATTERN and QCFG.paired_experts and not QCFG.paired and QCFG.attn_layers == (3,)
+    assert (QCFG.gdn_layers, QCFG.ssm_layers, QCFG.rec_layers, QCFG.expert_layers) == (3, 0, 3, 4)
+    assert [QCFG.cache_index(i) for i in range(4)] == [0, 1, 2, 0]
+    assert (QCFG.gdn_conv_width, QCFG.rotary_dim) == (2 * 16 + 32, 4)
+    params = hd.init_hybrid_decoder(QCFG, seed=0, dtype=jnp.float32)
+    experts = ["ln2", "moe", "shared", "shared_gate"]
+    assert sorted(params["layers"][0]) == sorted(
+        ["A_log", "conv_w", "dt_bias", "gdn_ba", "gdn_in", "gdn_norm", "gdn_out", "ln1", *experts])  # no conv bias, no D
+    assert sorted(params["layers"][3]) == sorted(["attn_o", "attn_qkv", "k_norm", "ln1", "q_norm", *experts])
+    d, g = params["layers"][0], params["layers"][3]
+    assert d["gdn_in"].shape == (64, 16 + 16 + 32 + 32) and d["gdn_ba"].shape == (64, 8) and d["conv_w"].shape == (4, 64)
+    assert g["attn_qkv"].shape == (64, 2 * 64 + 2 * 32)  # a gate beside each head's query
+    assert sorted(d["moe"]) == ["down", "gate_up", "router"] and d["moe"]["gate_up"].shape == (4, 64, 48)
+    assert d["shared"]["gate_up"].shape == (64, 80) and d["shared_gate"].shape == (64,)
+    # zero-centred norms weigh by 1 + w: drawn as zeros; the gated norm's plain weight as ones; A in (0, 16]
+    assert not np.asarray(d["ln1"]).any() and not np.asarray(g["q_norm"]).any() and not np.asarray(params["ln_f"]).any()
+    assert np.asarray(d["gdn_norm"]).all() and np.all(np.exp(np.asarray(d["A_log"])) <= 16.0)
+    rec = QFAM.state_init(params, 7)
+    assert [a.shape for a in rec] == [(7, 4, 8, 8)] * 3 + [(7, 3 * 64)] * 3 and all(a.dtype == jnp.float32 for a in rec)
+    assert QFAM.decoder_dims(params)["kv_layers"] == 1
+    assert QFAM.frame_counters[-2:] == ("ssm_rows", "attn_run_pages") and len(QFAM.frame_counters) == 8
+    with pytest.raises(ValueError, match="pattern="):
+        dataclasses.replace(QCFG, pattern="DM*G")  # the two shapes do not mix
+    with pytest.raises(ValueError, match="a delta-rule layer needs"):
+        dataclasses.replace(QCFG, gdn_value_heads=3)
+    with pytest.raises(ValueError, match="gated attention needs"):
+        dataclasses.replace(QCFG, rope_theta=0.0)
+    with pytest.raises(ValueError, match="an expert layer needs"):
+        dataclasses.replace(QCFG, shared_ffn=0)
+
+
+@pytest.mark.parametrize(
+    "chunks", [(16,), (8, 8), (4, 4, 4, 4), (9, 2, 1, 6), (27,)], ids=["one", "two", "four", "inside_conv_reach", "padded_block"]
+)
+def test_third_shape_cold_prefill_then_decode_equals_reference_float32(qref, qweights, chunks):
+    """Cold prefill in 1, 2 and 4 chunks (and splits inside the convolution's
+    reach, and a 27-token chunk whose block is padded to 28), then decode:
+    every position's logits, the blocked delta rule then the step's update,
+    rotary by position through the pages, the expert layers' masked form."""
+    ids, params = _ids(), qweights[jnp.float32]
+    got, _, rec, _ = _serve(params, ids, chunks=chunks, fam=QFAM)
+    np.testing.assert_allclose(got, _qref_logits(qref, params, ids), atol=QATOL)
+    assert not any(np.asarray(a[ZERO]).any() for a in rec)  # the zero row stays zero
+
+
+@pytest.mark.parametrize("shared", [8, 20])
+def test_third_shape_prefix_hit_from_a_snapshot_equals_reference_float32(qref, qweights, shared):
+    """A hit maps the entry's pages and restores its snapshot rows (the matrix
+    states and the q | k | v conv inputs), then chunks, then decode."""
+    params = qweights[jnp.float32]
+    a, b = _ids(0), _ids(1)
+    b[:shared] = a[:shared]
+    _, pool, rec, pages = _serve(params, a, chunks=(shared, 6), snap_at=shared, fam=QFAM)
+    got, _, _, _ = _serve(params, b, chunks=(5, 3), start=(pool, rec, pages, shared), fam=QFAM)
+    np.testing.assert_allclose(got[shared:], _qref_logits(qref, params, b)[shared:], atol=QATOL)
+
+
+def _gate_before_the_norm(r):
+    def gated_norm(o, z, w, eps):
+        g = o * jax.nn.silu(z)
+        return w * (g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps))
+
+    r._gated_norm = gated_norm
+
+
+def _plain_norm(r):
+    """A missing ``(1 + w)``: the weight multiplies as the other shapes' does."""
+    def norm(w, x, eps, act):
+        xf = x.astype(jnp.float32)
+        return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * w.astype(jnp.float32)).astype(act)
+
+    r._norm = norm
+
+
+def _no_read_before_the_write(r):
+    """Plain gated linear attention: the write does not take off what the state holds for the key."""
+    orig = r._delta_rule
+
+    def delta_rule(p, x, **kw):
+        return orig({**p, "gdn_ba": p["gdn_ba"].at[:, : kw["value_heads"]].set(0.0)}, x, **kw)  # beta 1/2 everywhere
+
+    r._delta_rule = delta_rule
+
+
+QFAULTS = {
+    # in the reference (a fresh module object a case): what the program computes must NOT equal these
+    "missing_one_plus_w": _plain_norm,
+    "gate_before_the_norm": _gate_before_the_norm,
+    "rotary_over_the_whole_head": lambda r: setattr(r, "_rotary_dims", lambda head_dim, factor: head_dim),
+    "shared_expert_ungated": lambda r: setattr(r, "_shared_gate", lambda w, n2: jnp.ones((n2.shape[0], 1), jnp.float32)),
+    "q_and_k_not_l2_normalised": lambda r: setattr(r, "_l2", lambda x: x),
+    "beta_from_no_input": _no_read_before_the_write,
+}
+
+
+@pytest.mark.parametrize("fault", sorted(QFAULTS))
+def test_third_shape_planted_fault_in_the_mathematics_fails(qweights, fault):
+    params, ids = qweights[jnp.float32], _ids()
+    got, _, _, _ = _serve(params, ids, chunks=(9, 2, 1, 6), fam=QFAM)
+    faulty = _load_qref()
+    QFAULTS[fault](faulty)
+    assert np.abs(got - _qref_logits(faulty, params, ids)).max() > 100 * QATOL
+
+
+def test_third_shape_state_rows_in_bfloat16_fail_the_float32_comparison(qref, qweights):
+    """The control the chip run repeats: the same programs over state rows
+    kept in bfloat16 (a program writes a row in the array's dtype) miss the
+    reference by orders of the tolerance."""
+    params, ids = qweights[jnp.float32], _ids()
+    pool = QFAM.paged_kv_init(params, 1 + 2 * (CTX // PS), PS, jnp.float32)
+    rec = tuple(a.astype(jnp.bfloat16) if a.ndim == 4 else a for a in QFAM.state_init(params, DROP))
+    got, _, rec, _ = _serve(params, ids, chunks=(16,), fam=QFAM, start=(pool, rec, np.zeros((0,), np.int64), 0))
+    assert rec[0].dtype == jnp.bfloat16
+    # 2.7e-3 after 40 tokens (it grows with every write of the state): ninety times the tolerance
+    assert np.abs(got - _qref_logits(qref, params, ids)).max() > 50 * QATOL
+
+
+def test_third_shape_step_advances_the_rows_that_generate_and_no_other(qweights):
+    """Slot 1 prefills 9 tokens, rides three steps as a junk row while slots 0
+    and 2 generate, then prefills on: its matrix states, conv inputs and
+    logits are those of a lone prefill, bit for bit; the zero row stays zero."""
+    params = qweights[jnp.float32]
+    ids = _ids(3)
+    lone, _, rec_lone, _ = _serve(params, ids[:20], chunks=(9, 11), fam=QFAM)
+    pages = CTX // PS
+    pool = QFAM.paged_kv_init(params, 1 + 3 * pages, PS, jnp.float32)
+    rec = QFAM.state_init(params, DROP)
+    bt = np.zeros((3, pages), np.int32)
+    bt[1] = 1 + np.arange(pages)
+    bt[0], bt[2] = 1 + pages + np.arange(pages), 1 + 2 * pages + np.arange(pages)
+
+    def chunk(pos, c, read):
+        toks = np.zeros((3, 11), np.int32)
+        toks[1, :c] = ids[pos : pos + c]
+        rows3 = np.array([[ZERO, read, ZERO], [DROP, 1, DROP], [DROP, DROP, DROP]], np.int32)
+        return QFAM.paged_forward(
+            params, pool, rec, jnp.asarray(bt), jnp.asarray(toks), jnp.array([0, pos, 0], jnp.int32),
+            counts=jnp.array([0, c, 0], jnp.int32), state_rows=jnp.asarray(rows3),
+        )
+
+    _, pool, rec, counted = chunk(0, 9, ZERO)
+    assert counted[-2:].tolist() == [1, 0] and int(counted[0]) == 9  # one row's state advanced, nine rows routed
+    mid = [np.asarray(r[1]) for r in rec]
+    for t in range(3):
+        _, pool, rec, counted = QFAM.paged_forward(
+            params, pool, rec, jnp.asarray(bt), jnp.array([[5], [0], [6]], jnp.int32),
+            jnp.array([t, 9, t], jnp.int32), rows=jnp.array([True, False, True]),
+        )
+        assert int(counted[-2]) == 2 and int(counted[0]) == 2
+    for before, after in zip(mid, rec):
+        np.testing.assert_array_equal(before, np.asarray(after[1]))
+    assert np.asarray(rec[0][0]).any() and np.asarray(rec[0][2]).any()  # the others did advance
+    logits, pool, rec, _ = chunk(9, 11, 1)
+    np.testing.assert_array_equal(np.asarray(logits[1, :11]), lone[9:20])
+    for got, want in zip(rec, rec_lone):
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        assert not np.asarray(got[ZERO]).any()
+
+
+@pytest.mark.parametrize("path", ["cold", "hit"])
+def test_third_shape_bfloat16_serving_within_the_harness_delta(qref, qweights, path):
+    """Greedy tokens served in bfloat16 through both caches (float32 state
+    rows), judged as benchmarks/harness/correct.py judges a run; the same path
+    misses the float32 tolerance by orders (the bar is tight enough)."""
+    params = qweights[jnp.bfloat16]
+    ids, first = _ids(2), 23
+    start, chunks = None, (12, 12)
+    if path == "hit":
+        _, pool, rec, pages = _serve(params, ids, chunks=(8,), dtype=jnp.bfloat16, snap_at=8, fam=QFAM)
+        start, chunks = (pool, rec, pages, 8), (8, 8)
+    served = list(ids[: first + 1])
+    while len(served) < CTX:
+        got, _, _, _ = _serve(params, np.asarray(served, np.int32), chunks=chunks, dtype=jnp.bfloat16, start=start, fam=QFAM)
+        served.append(int(got[len(served) - 1].argmax()))
+    exact, noisy = (_qref_logits(qref, params, served, p)[None, first:] for p in ("highest", "default"))
+    verdict = judge_generated([served], exact, noisy, first)
+    assert verdict["ok"], verdict
+    if path == "cold":
+        assert np.abs(got[first:] - exact[0][: len(got) - first]).max() > 100 * QATOL
+
+
+def _qexperts(seed=0):
+    ks = jax.random.split(jax.random.key(seed), 8)
+    d, f, fs = QCFG.hidden, QCFG.ffn, QCFG.shared_ffn
+    return {
+        "ln2": 0.3 * jax.random.normal(ks[5], (d,)),
+        "moe": {"router": jax.random.normal(ks[0], (d, 16)) * 0.5,
+                "gate_up": jax.random.normal(ks[2], (16, d, 2 * f)) * 0.1, "down": jax.random.normal(ks[3], (16, f, d)) * 0.1},
+        "shared": {"gate_up": jax.random.normal(ks[4], (d, 2 * fs)) * 0.1, "down": jax.random.normal(ks[6], (fs, d)) * 0.1},
+        "shared_gate": jax.random.normal(ks[7], (d,)) * 0.3,
+    }
+
+
+def test_sixteen_shares_routed_parts_and_the_gated_shared_expert_once_sum_to_the_uncut_layer(qref):
+    """Sixteen chips of one expert each (the deployment's sixteen that share a
+    layer): their routed parts (a pick on an absent expert adds nothing, the
+    gates over all three picks) plus the gated shared expert and the residual
+    counted ONCE equal the reference's uncut layer; and share by share, the
+    reference given the same share."""
+    p = _qexperts()
+    share = lambda s: {**p["moe"], "gate_up": p["moe"]["gate_up"][s : s + 1], "down": p["moe"]["down"][s : s + 1]}  # noqa: E731
+    x = jax.random.normal(jax.random.key(8), (24, QCFG.hidden))
+    uncut = qref._experts(p, x, first_expert=0, top_k=3, eps=1e-6, act="float32")
+    n2 = qref._norm(p["ln2"], x, 1e-6, jnp.float32)
+    gates, experts = moe.route_topk(p["moe"]["router"], n2, 3)
+    parts = [moe.moe_held_ffn(share(s), n2, gates, experts, s) for s in range(16)]
+    shared = moe.expert_mlp(p["shared"], n2) * jax.nn.sigmoid(n2 @ p["shared_gate"])[:, None]
+    total = x + shared + sum(y for y, _ in parts)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=5e-6)
+    assert sum(int(c[3]) for _, c in parts) == 24 * 3  # every pick landed on exactly one chip
+    for s, (y, _) in enumerate(parts):
+        want = qref.routed_ffn(share(s), n2, first_expert=s, top_k=3, act="float32")
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-6)
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 1.0, rtol=1e-6)  # normalised over all the picks, wherever they land
+
+
+def test_third_shape_wide_chunk_runs_the_blocked_rule_over_many_blocks_and_the_experts_compact(qref, qweights):
+    """A (2, 160) dispatch: three blocks of 64 (the last padded) through the
+    blocked delta rule with the state carried, 320 rows through the compact
+    grouped expert form; every real position's logits equal the reference."""
+    params = qweights[jnp.float32]
+    ids = np.random.default_rng(4).integers(0, QCFG.vocab, (2, 160)).astype(np.int32)
+    pages = 160 // PS
+    pool = QFAM.paged_kv_init(params, 1 + 2 * pages, PS, jnp.float32)
+    rec = QFAM.state_init(params, DROP)
+    bt = 1 + np.arange(2 * pages, dtype=np.int32).reshape(2, pages)
+    rows3 = np.array([[ZERO, ZERO], [0, 1], [DROP, DROP]], np.int32)
+    counts = jnp.array([160, 150], jnp.int32)
+    logits, _, _, counted = jax.jit(QFAM.paged_forward)(
+        params, pool, rec, jnp.asarray(bt), jnp.asarray(ids), jnp.zeros((2,), jnp.int32), counts=counts,
+        state_rows=jnp.asarray(rows3))
+    for r, c in enumerate((160, 150)):
+        np.testing.assert_allclose(np.asarray(logits[r, :c]), _qref_logits(qref, params, ids[r, :c]), atol=1e-4)
+    counted = np.asarray(counted)
+    assert counted[0] == 310 and counted[4:6].tolist() == [4, 4] and counted[6:].tolist() == [2, 0]
+
+
+def _qzoo(**kw):
+    from seldon_core_tpu.models.zoo import get_model
+
+    return get_model("hybrid_decoder", **{**dict(
+        vocab=96, hidden=64, layers=4, attn_layers=QPATTERN, heads=4, kv_heads=2,
+        head_dim=16, ffn=24, untied="true", experts=16, experts_held=4, first_expert=4, experts_per_tok=3, shared_ffn=40,
+        gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8, rope_theta=1e7, rotary=0.25,
+        embedding_multiplier=1.0, residual_multiplier=1.0, attention_multiplier=0.25, logits_scaling=1.0, rms_eps=1e-6,
+        seq=SEQ, max_new_tokens=MAX_NEW, param_dtype="float32", seed=11), **kw})
+
+
+async def test_scheduler_serves_the_third_shape_through_the_ladder(qref):
+    """Through the zoo entry and ``DecodeScheduler``: the chunk ladder, matrix state rows, snapshot
+    restores at a prefix hit, the held-expert counts in the frames beside
+    ``ssm_rows``; the served tokens against the reference's logits as the
+    harness judges them, the zero row still zero, and never a recompile."""
+    ms = _qzoo()
+    assert ms.generative["family"].cfg == QCFG
+    params = _qlively(ms.params)
+    sched = ds.DecodeScheduler(
+        params, seq_len=SEQ, max_new_tokens=MAX_NEW, family=ms.generative["family"], n_slots=4, prefix_slots=2,
+        prefill_chunk=8, kv_page_size=PS)
+    sched.warmup()
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, 96, (6, SEQ)).astype(np.int32)
+    prompts[1:, :16] = prompts[0, :16]
+    first = await sched.submit(prompts[0], cache_prefix=16)
+    rest = await asyncio.gather(*(sched.submit(p) for p in prompts[1:]))
+    served = [[int(t) for t in out] for out in [first, *rest]]  # prompt, then the generated tokens
+    assert all(s[:SEQ] == p.tolist() for s, p in zip(served, prompts))
+    exact = np.stack([_qref_logits(qref, params, s)[SEQ - 1 :] for s in served])
+    verdict = judge_generated(served, exact, exact, SEQ - 1)
+    assert verdict["ok"] and verdict["tokens_judged"] == 6 * MAX_NEW, verdict
+    assert (sched.stat_prefix_hits, sched.stat_prefix_captures) == (5, 1)
+    assert sched.recompiles_since_warmup() == 0
+    frames = sched.flight.snapshot()
+    assert sum(f.state_restores for f in frames) == 5 and sum(f.state_captures for f in frames) == 1
+    steps = [f for f in frames if f.busy_ns[0] == 0 and f.ssm_rows]
+    assert steps and all(f.ssm_rows == f.active == f.moe_rows for f in steps)  # junk rows are not counted
+    assert all(f.moe_experts_hit <= 4 * 4 and f.moe_local_picks <= 4 * 3 * f.moe_rows for f in steps)
+    assert any(f.moe_experts_hit for f in steps)
+    assert {"ssm", "moe"} <= set(steps[0].to_dict())
+    sched.pool.alloc.check()
+    assert not any(np.asarray(a[sched.pool.zero_row]).any() for a in sched.pool.recurrent)
+    await sched.close()
+
+
+@pytest.mark.parametrize("what", ["speculation", "decode_mesh", "kv_int8", "host_tier", "prefix_export"])
+def test_what_the_third_shape_does_not_serve_is_refused_by_name(what):
+    from seldon_core_tpu.models.decoder import require_served
+
+    with pytest.raises(FamilyNotServed, match="not served for the 'hybrid' decoder family"):
+        require_served(QFAM, what)
+
+
+def test_the_zoo_entry_reads_the_third_shapes_pattern_and_refuses_the_published_key_names():
+    assert _qzoo().generative["family"].cfg.kinds == "DDDG"
+    assert _qzoo(attn_layers="DGDG").generative["family"].cfg.kinds == "DGDG"
+    with pytest.raises(ValueError, match=r"does not know the parameter\(s\) \['full_attention_interval', 'linear_num_key_heads'\]"):
+        _qzoo(linear_num_key_heads=2, full_attention_interval=4)
+    with pytest.raises(ValueError, match="pattern="):
+        _qzoo(attn_layers="DD*G")  # the third shape's characters do not mix with the second's

@@ -697,6 +697,80 @@ def test_hybrid_family_with_single_sublayers_compiles_in_place_at_nemotron_nano_
         _assert_step_reads_the_pool_through_the_kernel(text, 1)
 
 
+@pytest.mark.parametrize("program", ["step_kernel", "chunk_4_256_kernel"])
+def test_hybrid_family_third_shape_compiles_in_place_at_qwen3_next_widths(topo, program, monkeypatch):
+    """The hybrid family's third shape (PR 57) at the qwen3-next-80b-a3b
+    cell's widths, one period of the pattern (3 gated delta-rule layers of
+    16 / 32 heads of 128 with a [32, 128, 128] float32 matrix state a row, 1
+    gated attention layer of 16 / 2 heads of 256, every one with an expert
+    layer that holds 32 of 512 gated-SiLU experts of 512 and a gated shared
+    one): the fused step of 64 slots and the (4, 256) chunk compile for the
+    chip WITH the grouped-query kernels at a head of two lane tiles; pool and
+    state rows come back aliased and nothing the size of the state is copied;
+    the chunk's expert layers run the megablox kernel over ``held_capacity``
+    rows and no ``ragged-dot``; the delta rule's scopes are in the text."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+    from seldon_core_tpu.ops import moe
+    from seldon_core_tpu.ops.gqa_decode import gqa_chunk_tiles, gqa_tiles
+
+    assert gqa_tiles(512, 16, 2, 16, jnp.bfloat16) and gqa_chunk_tiles("mosaic", 256, 16, 2, 256)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = hd.HybridDecoderConfig(
+        vocab=18992, hidden=2048, layers=4, pattern="DDDG", heads=16, kv_heads=2, head_dim=256, ffn=512, untied=True,
+        experts=512, experts_held=32, experts_per_tok=10, shared_ffn=512, gdn_key_heads=16, gdn_value_heads=32,
+        gdn_key_dim=128, gdn_value_dim=128, rope_theta=1e7, rotary=0.25, embedding_multiplier=1.0,
+        residual_multiplier=1.0, attention_multiplier=256**-0.5, logits_scaling=1.0, rms_eps=1e-6, max_len=262144,
+    )
+    fam = hd.hybrid_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: hd.init_hybrid_decoder(cfg, 0, jnp.bfloat16)))
+    assert params["layers"][0]["gdn_in"].shape == (2048, 12288) and params["layers"][3]["attn_qkv"].shape == (2048, 9216)
+    assert params["layers"][0]["moe"]["gate_up"].shape == (32, 2048, 1024)
+    n, rows_total = 64, 64 + 4 + 1
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 9400, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
+    assert pool[0].shape == (1, 9400, 16, 512) and len(rec) == 6
+    assert rec[0].shape == (rows_total, 32, 128, 128) and rec[3].shape == (rows_total, 3 * 8192)
+    step, chunk = fam.fused_programs("mosaic")
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "step_kernel":
+        args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+        fn = step
+    else:
+        r, c = 4, 256
+        args = (arr((r, 144), i32), arr((r, c), i32), arr((r,), i32), arr((r,), i32), arr((r,), f32),
+                arr((r,), i32), arr((), i32), arr((), i32), arr((3, r), i32))
+        fn = chunk
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, rec, *args).compile()
+    donated = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in (*pool, *rec))
+    assert compiled.memory_analysis().alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    state = re.escape("f32[%d,32,128,128]" % rows_total)
+    copies = [ln for ln in text.splitlines() if re.search(r"= " + state + r"\S* copy\(", ln)]
+    assert not copies, copies[:2]
+    kind = "step" if program == "step_kernel" else "chunk"
+    for outer, inner in (("qkv", "gdn_in"), ("attn", "gdn_conv"), ("attn", "gdn_scan"), ("attn_out", "gdn_norm"),
+                         ("attn_out", "gdn_out"), ("qkv", "rope"), ("attn_out", "attn_gate"), ("mlp", "shared_expert"),
+                         ("mlp", "moe_experts"), ("mlp", "moe_router")):
+        assert re.search(r'op_name="jit\(_fused_%s\)/%s/([^"/]+/)*%s/' % (kind, outer, inner), text), inner
+    assert "ragged-dot" not in text
+    if kind == "chunk":
+        cap = moe.held_capacity(4 * 256 * 10, 32, 512)
+        assert cap == 1536 and _grouped_product_rows(text) == [cap, cap] * 4  # gate_up then down, an expert layer
+        assert text.count("gqa_chunk_attention") >= 1
+    else:
+        assert _grouped_product_rows(text) == []  # 64 rows: the masked form
+        _assert_step_reads_the_pool_through_the_kernel(text, 1)
+
+
 def _ungated_lines(text: str) -> list[str]:
     """The compiled module's instructions that run whatever a ``conditional``
     decides: the entry computation's and those of every computation it
@@ -1187,6 +1261,8 @@ GQA_CHUNK_CELLS = {
     "lfm2_4_256": (4, 256, 32, 8, 64, 144, 0),
     "lfm2_2_16": (2, 16, 32, 8, 64, 144, 0),
     "granite_2_64": (2, 64, 32, 8, 64, 48, 0),
+    "qwen3_next_4_256": (4, 256, 16, 2, 256, 144, 0),  # a head of two lane tiles: one tile of 256 (PR 57)
+    "qwen3_next_2_16": (2, 16, 16, 2, 256, 144, 0),
 }
 
 

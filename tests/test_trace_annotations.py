@@ -596,3 +596,36 @@ def test_the_hybrid_programs_nest_the_expert_layer_under_mlp_beside_the_state_sc
     for scope in decoder.PAGED_SCOPES:
         assert f"/{scope}/" in text, scope
     assert "/shared_expert/" not in text.replace("/mlp/shared_expert/", "")  # nowhere but under mlp
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_the_hybrid_third_shape_nests_the_delta_rule_and_the_gate_under_the_old_scopes(program):
+    """A configuration of the third shape (PR 57): the gated delta rule under
+    ``gdn_*`` names nested as the Mamba-2 ones are, the gated attention's
+    ``qkv/rope`` and ``attn_out/attn_gate``, the expert layer under ``mlp`` by
+    ``ops/moe.py``'s names with the shared expert's gate among them: a reader
+    of the nine old scopes still sees all of the time."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+    from tests.test_hybrid_decoder import QCFG
+
+    fam = hd.hybrid_family(QCFG)
+    params = hd.init_hybrid_decoder(QCFG, 0, jnp.float32)
+    pool, rec = fam.paged_kv_init(params, 8, 4), fam.state_init(params, 4)
+    n = 2
+    bt = jnp.zeros((n, 4), jnp.int32)
+    vec, temps = jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32)
+    step, chunk = fam.fused_programs()
+    if program == "step":
+        args = (params, pool, rec, bt, vec, vec, temps, vec, 0, jnp.int32(1), jnp.ones((n,), bool))
+    else:
+        args = (params, pool, rec, bt, jnp.zeros((n, 4), jnp.int32), vec, vec, temps, vec, 0, jnp.int32(1),
+                jnp.zeros((3, n), jnp.int32))
+    text = jax.jit(step if program == "step" else chunk).lower(*args).compile().as_text()
+    for scope in ("qkv/gdn_in", "attn/gdn_conv", "attn/gdn_scan", "attn_out/gdn_norm", "attn_out/gdn_out", "qkv/rope",
+                  "attn_out/attn_gate", "mlp/shared_expert", "mlp/moe_router", "mlp/moe_dispatch", "mlp/moe_experts",
+                  "mlp/moe_combine"):
+        assert f"/{scope}/" in text, scope
+    for scope in decoder.PAGED_SCOPES:
+        assert f"/{scope}/" in text, scope
+    assert "/gdn_scan/" not in text.replace("/attn/gdn_scan/", "")  # nowhere but under attn
+    assert "/ssm_scan/" not in text  # the Mamba-2 names belong to the other two shapes
