@@ -106,7 +106,8 @@ from seldon_core_tpu.models.decoder import (
     paged_state_greedy_generate,
 )
 from seldon_core_tpu.models.moe_decoder import SCOPE_ROPE, _SCORES_BATCH_BYTES, _attend, _rms, _rope
-from seldon_core_tpu.ops.gated_delta import gdn_chunk, gdn_step
+from seldon_core_tpu.ops.gated_delta import gdn_chunk, gdn_chunk_rows, gdn_step, gdn_step_rows
+from seldon_core_tpu.ops.gated_delta import kernel_mode as gdn_kernel_mode
 from seldon_core_tpu.ops.gqa_decode import gqa_chunk_tiles
 from seldon_core_tpu.ops.moe import (
     HELD_COUNTERS,
@@ -658,6 +659,11 @@ def _mamba(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_row
     return out, rec
 
 
+# (configuration, "step" | "chunk") -> {delta-rule layer: whether ``_gdn``'s newest trace of that layer in that kind of
+# program took ops/gated_delta.py's kernel}: written where a program is traced, read by ``HybridDecoder.gdn_passes``
+_GDN_TRACED: dict = {}
+
+
 def _gdn(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_rows):
     """The gated delta-rule mixer over x[n, m, d], delta-rule layer ``si``'s
     state and conv arrays of ``rec``; the rows it reads and writes as
@@ -692,11 +698,29 @@ def _gdn(cfg: HybridDecoderConfig, si: int, p, x, rec, counts, rows, state_rows)
             log_alpha = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + p["dt_bias"].astype(f32))
             log_alpha = jnp.where(valid[..., None], log_alpha, 0.0).reshape(n, m, hk, r)
             beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0).reshape(n, m, hk, r)
-            if state_rows is None:
-                # over EVERY row of the state array, the rows past the slots (snapshots, the zero row) with k 0,
-                # decay 1 and beta 0, which leave them as they were to the bit: the state is read by two fusions
-                # (the sums, the update), and a ``state[:n]`` both read was materialised first, 0.42 of a layer's
-                # 1.06 ms at 64 slots (my chip run, PR 57); whole, the update aliases the donated array
+            kernel = gdn_kernel_mode(dk, dv, state.dtype)  # static: the platform, the head's tiles, the rows' type
+            # what this trace of the layer runs, for ``HybridDecoder.gdn_passes`` (the frames' count of what ran)
+            _GDN_TRACED.setdefault((cfg, "step" if state_rows is None else "chunk"), {})[si] = bool(kernel)
+            if kernel:
+                # a head's matrix passes through VMEM once, read from and written to the rows where they lie
+                if state_rows is None:
+                    y, state = gdn_step_rows(
+                        state, *(t[:, 0] for t in (q, k, v, log_alpha, beta)), interpret=kernel == "interpret"
+                    )
+                    y = y[:, None]
+                    conv = conv.at[:n].set(conv_out)
+                else:
+                    y, state = gdn_chunk_rows(
+                        state, state_rows, q, k, v, log_alpha, beta, interpret=kernel == "interpret"
+                    )
+                    for to in (state_rows[1], state_rows[2]):
+                        conv = conv.at[to].set(conv_out, mode="drop")
+            elif state_rows is None:
+                # the plain form, over EVERY row of the state array, the rows past the slots (snapshots, the
+                # zero row) with k 0, decay 1 and beta 0, which leave them as they were to the bit: the state is
+                # read by two fusions (the sums, the update), and a ``state[:n]`` both read was materialised
+                # first, 0.42 of a layer's 1.06 ms at 64 slots (my chip run, PR 57); whole, the update aliases
+                # the donated array
                 total = state.shape[0]
                 whole = lambda t: jnp.pad(t[:, 0], ((0, total - n), *[(0, 0)] * (t.ndim - 2)))  # noqa: E731
                 y, s_out = gdn_step(
@@ -916,6 +940,16 @@ class HybridDecoder:
         over the experts HELD (ops/moe.py ``moe_held_ffn``'s six)."""
         held = ("moe_rows", "moe_experts_hit", "moe_load_max", *HELD_COUNTERS) if self.cfg.expert_layers else ()
         return (*held, "ssm_rows", "attn_run_pages")
+
+    def gdn_passes(self, kind: str) -> tuple:
+        """(the delta-rule layer passes of one ``kind`` dispatch, "step" or
+        "chunk"; those among them that run in ops/gated_delta.py's kernels),
+        the second as ``_gdn`` decided it layer by layer where the program
+        was traced (``_GDN_TRACED``: from the state array it was handed, not
+        asked again here), so 0 before the first trace; (0, 0) for a
+        configuration without such layers. FlightFrame ``gdn_passes`` /
+        ``gdn_kernel_passes``."""
+        return self.cfg.gdn_layers, sum(_GDN_TRACED.get((self.cfg, kind), {}).values())
 
     def decoder_dims(self, params: dict) -> dict:
         if ("lm_head" in params) != self.cfg.untied or not any("ssm_in" in p or "gdn_in" in p for p in params["layers"]):

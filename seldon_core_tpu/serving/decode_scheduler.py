@@ -829,6 +829,8 @@ class DecodeScheduler:
         # refuses by name (FamilyNotServed)
         self.family = decoder_family(family)
         self._frame_counters = tuple(self.family.frame_counters)
+        # a family with delta-rule layers: their passes a "step" or "chunk" dispatch and those in a kernel, as traced
+        self._gdn_passes = getattr(self.family, "gdn_passes", None)
         if draft_params is not None or spec_k > 0 or str(spec_tree or "").strip():
             require_served(self.family, "speculation")
         if tp_width(mesh_axes) > 1:
@@ -2250,6 +2252,7 @@ class DecodeScheduler:
         if self._frame_counters:
             self._rb_counts = np.zeros(len(self._frame_counters), np.int64)
         self._rb_step_counts = ()
+        self._rb_gdn = [0, 0]  # the delta-rule layer passes of the round's step and chunk dispatches, those in a kernel
         # stale shadow admissions (a round error between the overlap
         # window and the reconcile): the normal flow drains the list at
         # _apply_pending before the round commits, so anything still here
@@ -2376,6 +2379,8 @@ class DecodeScheduler:
                         **self._window_frame(snap),
                         step_counts=self._rb_step_counts,
                         rdy_ns=tuple(self._rb_rdy),
+                        gdn_passes=self._rb_gdn[0],
+                        gdn_kernel_passes=self._rb_gdn[1],
                         ingress_ns=ingress[0] - self._ingress_committed[0],
                         ingress_requests=ingress[1] - self._ingress_committed[1],
                         **(
@@ -2973,6 +2978,7 @@ class DecodeScheduler:
         )
         if counted is not None:
             self._rb_counts += counted
+            self._count_gdn("chunk")
         t1 = telemetry.now_ns()
         self.stat_chunk_dispatches += 1
         self.stat_chunk_rows_held += held
@@ -3201,7 +3207,17 @@ class DecodeScheduler:
         if counted is not None:
             self._rb_counts += counted
             self._rb_step_counts = tuple(counted.tolist())  # the step's own, beside the round's sum
+            self._count_gdn("step")
         return nxt
+
+    def _count_gdn(self, kind: str) -> None:
+        """A counted ``kind`` dispatch ("step" | "chunk") of a family with
+        delta-rule layers: its layer passes and those that its program runs
+        in a kernel (the family's ``gdn_passes``), onto the round's frame."""
+        if self._gdn_passes is not None:
+            passes, kernel = self._gdn_passes(kind)
+            self._rb_gdn[0] += passes
+            self._rb_gdn[1] += kernel
 
     async def _run(self) -> None:
         # while the loop runs, a collection of the interpreter's oldest

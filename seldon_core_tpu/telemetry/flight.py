@@ -467,7 +467,13 @@ class FlightFrame:
     dispatch; ``chunk_rows_kernel`` of the round's ``chunk_rows_live``, those
     whose attention ran in a chunk kernel (``DecodePrograms.chunk_attn``:
     static a program, so all of a dispatch's or none), 0 where the chunk
-    walked or gathered; ``kv_win_live`` where the pool has a window page
+    walked or gathered; ``gdn_passes`` / ``gdn_kernel_passes`` the delta-rule
+    layer passes of the round's step and chunk dispatches (a configuration
+    with gated delta-rule layers: one pass a layer a dispatch) and those
+    among them that ran in ops/gated_delta.py's kernels (the family's
+    ``gdn_passes(kind)``: what ``_gdn`` decided where the program was traced; the
+    plain forms on the CPU backend and off the lane tile), 0 / 0 for a
+    configuration without such layers; ``kv_win_live`` where the pool has a window page
     kind (serving/kv_pool.py ``WindowPages``: a family with sliding-window
     layers) the window-kind pages some slot maps at the commit (``kv_live``
     then reads the full kind alone), ``kv_win_written`` the window-kind pages
@@ -500,7 +506,7 @@ class FlightFrame:
         "chunk_c", "ingress_ns", "ingress_requests", "conv_rows", "attn_run_pages", "mhc_resid_ppm",
         "chunk_rows_held", "chunk_rows_kernel", "moe_grouped_calls", "moe_compact_calls",
         "kv_win_live", "kv_win_released", "kv_win_written", "step_counts",
-        "rdy_ns",
+        "rdy_ns", "gdn_passes", "gdn_kernel_passes",
     )
 
     def __init__(
@@ -519,7 +525,7 @@ class FlightFrame:
         chunk_c=0, ingress_ns=0, ingress_requests=0, conv_rows=0, attn_run_pages=0, mhc_resid_ppm=0,
         chunk_rows_held=0, chunk_rows_kernel=0, moe_grouped_calls=0, moe_compact_calls=0,
         kv_win_live=0, kv_win_released=0, kv_win_written=0, step_counts=(),
-        rdy_ns=_ZERO_FAMILIES,
+        rdy_ns=_ZERO_FAMILIES, gdn_passes=0, gdn_kernel_passes=0,
     ):
         self.seq = seq
         self.t_ns = t_ns
@@ -580,6 +586,8 @@ class FlightFrame:
         self.kv_win_written = kv_win_written
         self.step_counts = step_counts
         self.rdy_ns = rdy_ns
+        self.gdn_passes = gdn_passes
+        self.gdn_kernel_passes = gdn_kernel_passes
 
     def to_dict(self) -> dict:
         d: dict = {
@@ -674,6 +682,8 @@ class FlightFrame:
             d["moe_local_picks"] = self.moe_local_picks
         if self.moe_grouped_calls:
             d["moe_compact"] = [self.moe_compact_calls, self.moe_grouped_calls]
+        if self.gdn_passes:
+            d["gdn_passes"] = [self.gdn_kernel_passes, self.gdn_passes]
         if self.mla_pages_read:
             d["mla_pages"] = [self.mla_run_pages, self.mla_pages_read]
         if self.mhc_resid_ppm:

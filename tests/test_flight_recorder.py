@@ -947,3 +947,13 @@ def test_frame_carries_state_rows_and_held_expert_counts_together(kw, want):
     rec.record(f)
     got = rec.snapshot()[0].to_dict()
     assert {k: got[k] for k in keys if k in got} == want
+
+
+@pytest.mark.parametrize("passes,kernel,shown", [(0, 0, None), (36, 0, [0, 36]), (36, 36, [36, 36])],
+                         ids=["no_delta_rule_layers", "plain_forms", "kernels"])
+def test_a_frame_carries_the_delta_rule_passes_and_those_in_a_kernel(passes, kernel, shown):
+    """ISSUE 58: the delta-rule layer passes of the round's dispatches and those that ran in
+    ops/gated_delta.py's kernels; in the frame's dict only for a configuration with such layers."""
+    assert {"gdn_passes", "gdn_kernel_passes"} <= set(FlightFrame.__slots__) and "``gdn_kernel_passes``" in FlightFrame.__doc__
+    f = _frame(0, gdn_passes=passes, gdn_kernel_passes=kernel)
+    assert (f.gdn_passes, f.gdn_kernel_passes, f.to_dict().get("gdn_passes")) == (passes, kernel, shown)

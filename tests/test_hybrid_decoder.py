@@ -1255,6 +1255,59 @@ async def test_scheduler_serves_the_third_shape_through_the_ladder(qref):
     await sched.close()
 
 
+def test_third_shape_step_kernel_advances_the_rows_that_generate_and_no_other(qweights, monkeypatch):
+    """Slots 0 and 2 generate while slot 1 rides masked: its matrix states
+    come back to the bit, and so does every row past the slots."""
+    monkeypatch.setattr(hd, "gdn_kernel_mode", lambda *a: "interpret")
+    params = qweights[jnp.float32]
+    pages = CTX // PS
+    pool = QFAM.paged_kv_init(params, 1 + 3 * pages, PS, jnp.float32)
+    rec = tuple(jnp.asarray(np.random.default_rng(i).normal(size=a.shape), a.dtype) for i, a in enumerate(QFAM.state_init(params, DROP)))
+    bt = 1 + np.arange(3 * pages, dtype=np.int32).reshape(3, pages)
+    _, _, new, counted = QFAM.paged_forward(
+        params, pool, rec, jnp.asarray(bt), jnp.array([[5], [0], [6]], jnp.int32), jnp.zeros((3,), jnp.int32),
+        rows=jnp.array([True, False, True]),
+    )
+    assert int(counted[-2]) == 2
+    for before, after in zip(rec, new):
+        np.testing.assert_array_equal(np.asarray(before[1]), np.asarray(after[1]))
+        np.testing.assert_array_equal(np.asarray(before[3:]), np.asarray(after[3:]))
+        assert not np.array_equal(np.asarray(before[0]), np.asarray(after[0]))
+
+
+@pytest.mark.parametrize("kernel", ["", "interpret"], ids=["plain", "kernels"])
+async def test_scheduler_counts_the_delta_rule_passes_that_ran_in_a_kernel(qref, monkeypatch, kernel):
+    """``FlightFrame.gdn_passes`` / ``gdn_kernel_passes``: three delta-rule
+    layers a step or chunk dispatch, all of them in the kernels or none
+    (``HybridDecoder.gdn_passes``: what ``_gdn`` traced), and the kernels serve
+    the tokens the reference's logits allow."""
+    monkeypatch.setattr(hd, "gdn_kernel_mode", lambda *a: kernel)
+    jax.clear_caches()  # the other form's trace of the same programs is not this one's
+    ms = _qzoo()
+    fam = ms.generative["family"]
+    assert FAM.gdn_passes("step") == FAM.gdn_passes("chunk") == (0, 0)
+    params = _qlively(ms.params)
+    sched = ds.DecodeScheduler(
+        params, seq_len=SEQ, max_new_tokens=MAX_NEW, family=fam, n_slots=2, prefix_slots=1, prefill_chunk=8, kv_page_size=PS)
+    sched.warmup()
+    prompts = np.random.default_rng(0).integers(0, 96, (3, SEQ)).astype(np.int32)
+    served = [[int(t) for t in out] for out in await asyncio.gather(*(sched.submit(p) for p in prompts))]
+    exact = np.stack([_qref_logits(qref, params, s)[SEQ - 1 :] for s in served])
+    verdict = judge_generated(served, exact, exact, SEQ - 1)
+    assert verdict["ok"] and verdict["tokens_judged"] == 3 * MAX_NEW, verdict
+    assert sched.recompiles_since_warmup() == 0
+    frames = [f for f in sched.flight.snapshot() if f.busy_ns[0] or f.busy_ns[1]]
+    assert frames and any(f.chunk_rows for f in frames)
+    # what ``_gdn`` decided where the programs were traced, from the state rows it was handed
+    assert fam.gdn_passes("step") == fam.gdn_passes("chunk") == (3, 3 if kernel else 0)
+    for f in frames:
+        dispatches = (1 if f.chunk_rows else 0) + (1 if f.step_counts else 0)
+        assert f.gdn_passes == 3 * dispatches > 0 and f.gdn_kernel_passes == (f.gdn_passes if kernel else 0)
+        assert f.to_dict()["gdn_passes"] == [f.gdn_kernel_passes, f.gdn_passes]
+    await sched.close()
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("what", ["speculation", "decode_mesh", "kv_int8", "host_tier", "prefix_export"])
 def test_what_the_third_shape_does_not_serve_is_refused_by_name(what):
     from seldon_core_tpu.models.decoder import require_served

@@ -771,6 +771,70 @@ def test_hybrid_family_third_shape_compiles_in_place_at_qwen3_next_widths(topo, 
         _assert_step_reads_the_pool_through_the_kernel(text, 1)
 
 
+@pytest.mark.parametrize("program", ["step", "chunk_2_256", "chunk_4_256"])
+def test_hybrid_family_third_shape_compiles_with_the_delta_rule_kernels_at_qwen3_next_widths(topo, program, monkeypatch):
+    """The third shape's programs as a TPU runs them (PR 58): where
+    ``gated_delta.kernel_mode`` answers "mosaic" (16 / 32 heads of 128, float32
+    state rows) the delta-rule layers' step and chunk are ops/gated_delta.py's
+    kernels, one call a layer under ``attn/gdn_scan``, the ``[69, 32, 128,
+    128]`` state arrays aliased in place: no copy, slice or
+    dynamic-update-slice of one anywhere in the program, and Mosaic takes the
+    kernels inside the VMEM they ask for."""
+    from seldon_core_tpu.models import hybrid_decoder as hd
+    from seldon_core_tpu.ops import gated_delta as gd
+    from seldon_core_tpu.ops import moe
+
+    assert gd._VMEM_LIMIT <= 100 << 20  # what the kernels may ask of the chip's 128 MiB
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(hd, "gdn_kernel_mode", lambda dk, dv, dtype: "mosaic")  # ``kernel_mode``'s answer on the chip
+    jax.clear_caches()  # the plain form's trace of the same programs is not this one's
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = hd.HybridDecoderConfig(
+        vocab=18992, hidden=2048, layers=4, pattern="DDDG", heads=16, kv_heads=2, head_dim=256, ffn=512, untied=True,
+        experts=512, experts_held=32, experts_per_tok=10, shared_ffn=512, gdn_key_heads=16, gdn_value_heads=32,
+        gdn_key_dim=128, gdn_value_dim=128, rope_theta=1e7, rotary=0.25, embedding_multiplier=1.0,
+        residual_multiplier=1.0, attention_multiplier=256**-0.5, logits_scaling=1.0, rms_eps=1e-6, max_len=262144,
+    )
+    fam = hd.hybrid_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: hd.init_hybrid_decoder(cfg, 0, jnp.bfloat16)))
+    n, rows_total = 64, 64 + 4 + 1
+    pool = on_chip(jax.eval_shape(lambda: fam.paged_kv_init(params, 9400, 16, jnp.bfloat16)))
+    rec = on_chip(jax.eval_shape(lambda: fam.state_init(params, rows_total)))
+    assert rec[0].shape == (rows_total, 32, 128, 128) and rec[0].dtype == jnp.float32
+    step, chunk = fam.fused_programs("mosaic")
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "step":
+        args = (arr((n, 144), i32), arr((n,), i32), arr((n,), i32), arr((n,), f32), arr((n,), i32), arr((), i32),
+                arr((), i32), arr((n,), jnp.bool_))
+        fn, kernel = step, "gdn_step"
+    else:
+        r, c = (int(x) for x in program.split("_")[1:])
+        args = (arr((r, 144), i32), arr((r, c), i32), arr((r,), i32), arr((r,), i32), arr((r,), f32),
+                arr((r,), i32), arr((), i32), arr((), i32), arr((3, r), i32))
+        fn, kernel = chunk, "gdn_chunk"
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, rec, *args).compile()
+    finally:
+        jax.clear_caches()
+    donated = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in (*pool, *rec))
+    assert compiled.memory_analysis().alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    state = re.escape("f32[%d,32,128,128]" % rows_total)
+    moved = [ln for ln in text.splitlines() if re.search(r"= " + state + r"\S* (copy|slice|dynamic-slice|dynamic-update-slice|scatter|gather)\(", ln)]
+    assert not moved, moved[:2]
+    calls = [ln for ln in text.splitlines() if "custom-call" in ln and 'custom_call_target="tpu_custom_call"' in ln
+             and re.search(r'op_name="jit\(_fused_%s\)/attn/([^"/]+/)*gdn_scan/' % ("step" if program == "step" else "chunk"), ln)]
+    assert len(calls) == 3 and all(re.search(state, ln) for ln in calls), len(calls)  # a layer's array in, the same out
+    assert text.count(kernel) >= 3
+
+
 def _ungated_lines(text: str) -> list[str]:
     """The compiled module's instructions that run whatever a ``conditional``
     decides: the entry computation's and those of every computation it
